@@ -2,8 +2,8 @@
 
 An AST-based lint subsystem with project-specific rules: plan determinism
 (LDT001-003), jit purity (LDT101-102), concurrency hygiene (LDT201-203),
-resource ownership (LDT301), jax-compat enforcement (LDT401), cross-module
-wire-protocol consistency (LDT501), the whole-program concurrency model
+resource ownership (LDT301), cross-module wire-protocol consistency
+(LDT501), the whole-program concurrency model
 (``concmodel.py``): lock-order deadlock cycles (LDT1001), cross-thread
 unsynchronized shared state (LDT1002), dispatcher exhaustiveness over the
 protocol's MSG_* vocabulary (LDT1003) — and, layered on the same

@@ -28,11 +28,8 @@ class CheckConfig:
     disable: List[str] = dataclasses.field(default_factory=list)  # rule ids
     # Baseline of grandfathered findings (``ldt check --update-baseline``).
     baseline: str = ".ldt-baseline.json"
-    # LDT401: the one module allowed to import version-moved jax symbols.
+    # LDT801: the one module allowed to re-export the placement primitives.
     compat_module: str = "lance_distributed_training_tpu/parallel/_compat.py"
-    compat_symbols: List[str] = dataclasses.field(
-        default_factory=lambda: ["shard_map", "pcast", "axis_size"]
-    )
     # LDT202: where an unbounded queue.Queue() is an error (streaming paths
     # whose backpressure contract depends on bounded queues).
     queue_paths: List[str] = dataclasses.field(
@@ -244,7 +241,6 @@ def load_config(root: str) -> CheckConfig:
         "disable": "disable",
         "baseline": "baseline",
         "compat-module": "compat_module",
-        "compat-symbols": "compat_symbols",
         "queue-paths": "queue_paths",
         "protocol-module": "protocol_module",
         "protocol-versions": "protocol_versions",

@@ -65,9 +65,8 @@ __all__ = [
     "build_mesh_model",
 ]
 
-# Resolved qualnames that wrap a function for device compilation. shard_map
-# and pcast/axis_size route through parallel/_compat in this repo, so dotted
-# tails are matched for those.
+# Resolved qualnames that wrap a function for device compilation; pjit and
+# shard_map are matched by dotted tail, however they were imported.
 _JIT_QNAMES = {"jax.jit", "jit", "jax.pmap", "pmap", "pjit",
                "jax.experimental.pjit.pjit"}
 _JIT_TAILS = (".pjit", ".shard_map")
@@ -442,7 +441,7 @@ class MeshModel:
                                     e.col_offset, "PartitionSpec",
                                 ))
                 elif tail in _COLLECTIVES and (
-                    qn.startswith("jax.") or "_compat" in qn or qn == tail
+                    qn.startswith("jax.") or qn == tail
                 ):
                     cands: List[ast.AST] = []
                     pos = _COLLECTIVES[tail]

@@ -8,7 +8,6 @@ README "Static analysis" for a worked example.
 """
 
 from . import (  # noqa: F401  (import for registration side effect)
-    compat,
     concurrency,
     copies,
     determinism,

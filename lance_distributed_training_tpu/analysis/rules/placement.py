@@ -11,7 +11,7 @@ This rule rejects direct calls to the H2D primitives — ``jax.device_put``
 and ``make_array_from_single_device_arrays`` (however imported from jax) —
 in the ``hot-paths`` modules from ``[tool.ldt-check]``, outside the two
 modules allowed to own them: ``data/placement.py`` (the plane) and
-``parallel/_compat.py`` (the version shim both primitives are re-exported
+``parallel/_compat.py`` (the one door both primitives are re-exported
 from). Calls routed through the shim (``from ..parallel._compat import
 device_put``) resolve to the compat module's dotted name and are legal;
 the import map distinguishes them from jax's, so no suppression comments
@@ -34,7 +34,6 @@ from ..core import Finding, ModuleInfo, Rule, register
 _H2D_QUALNAMES = {
     "jax.device_put",
     "jax.make_array_from_single_device_arrays",
-    "jax.experimental.array.make_array_from_single_device_arrays",
     "jax.make_array_from_process_local_data",
 }
 

@@ -28,7 +28,7 @@ shares):
   cannot see (``witness_pruned``); a message exercised without the field
   ever appearing upgrades the finding to *reproduced*.
 * **LDT1404 out-of-module-framing** — raw ``struct.pack``/``unpack``/
-  ``Struct`` byte-framing outside the protocol module (the LDT401/LDT801
+  ``Struct`` byte-framing outside the protocol module (the LDT801
   vocabulary shape): framing drift in two places is how two builds stop
   agreeing on a length prefix.
 

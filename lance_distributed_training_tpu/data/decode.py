@@ -136,19 +136,17 @@ class ImageClassificationDecoder:
         self._native = None
         self._native_arrow = None
         if self.use_native:
-            try:
-                from ..native import (
-                    batch_decode_jpeg,
-                    batch_decode_jpeg_arrow,
-                    native_available,
-                )
+            from ..native import (
+                batch_decode_jpeg,
+                batch_decode_jpeg_arrow,
+                native_available,
+            )
 
-                if native_available():
-                    self._native = batch_decode_jpeg
-                    self._native_arrow = batch_decode_jpeg_arrow
-            except Exception:
-                self._native = None
-                self._native_arrow = None
+            # A decoder that cannot be built raises here (with g++'s
+            # message): training at PIL's rate is chosen, never fallen into.
+            if native_available():
+                self._native = batch_decode_jpeg
+                self._native_arrow = batch_decode_jpeg_arrow
 
     # Picklable for process-pool workers (the ctypes binding can't cross the
     # process boundary; each worker re-binds its own).
@@ -346,8 +344,8 @@ def decoder_for_task(task_type: str, image_size: int = 224,
     ``device_decode`` selects the entropy-split decoder
     (:mod:`.device_decode`): the host emits half-decoded coefficient pages
     and the dense back half runs as the jitted device kernel
-    (:mod:`..ops.jpeg_device`) — classification only; degrades to the
-    pixel path with one warning when the native extractor is absent.
+    (:mod:`..ops.jpeg_device`) — classification only; raises when the
+    native extractor is switched off or cannot be built.
 
     The text tasks' ragged plane (r15, :mod:`.token_pack`): ``token_pack``
     (a :class:`~.token_pack.TokenPackConfig`) selects the ragged emit —
@@ -360,9 +358,9 @@ def decoder_for_task(task_type: str, image_size: int = 224,
     contract."""
     if task_type == "classification":
         if device_decode:
-            from .device_decode import coeff_decoder_or_fallback
+            from .device_decode import CoeffImageDecoder
 
-            return coeff_decoder_or_fallback(
+            return CoeffImageDecoder(
                 image_size=image_size, buffer_pool=buffer_pool
             )
         return ImageClassificationDecoder(

@@ -19,12 +19,8 @@ fewer recompiles and better page reuse at the price of more padding bytes
 on the wire. ``chunk_blocks`` is exposed as the ``coeff_chunk`` autotune
 Tunable (mandatory lo/hi, LDT1101).
 
-Degraded paths:
+Degraded path:
 
-* native library unavailable (no g++/libjpeg, ``LDT_DISABLE_NATIVE``) —
-  :func:`coeff_decoder_or_fallback` warns ONCE and hands back the plain
-  pixel decoder; the trainer's transform stage passes pixel batches
-  through, so the run proceeds on the r11 host path.
 * a row the extractor cannot take (non-4:2:0 sampling, CMYK, corrupt-for-
   libjpeg bytes) is PIL-decoded and re-encoded to baseline 4:2:0 JPEG,
   then extracted again (``decode_coeff_reencode_total``); a row that still
@@ -49,9 +45,7 @@ import pyarrow as pa
 from ..obs.costs import note_cost
 from ..obs.registry import default_registry
 
-__all__ = ["CoeffImageDecoder", "coeff_decoder_or_fallback"]
-
-_WARNED_NO_NATIVE = False
+__all__ = ["CoeffImageDecoder"]
 
 
 def _round_up(blocks: int, chunk: int) -> int:
@@ -63,10 +57,10 @@ class CoeffImageDecoder:
     """JPEG-bytes + label columns → coefficient-page batch dict.
 
     Output keys: ``jpeg_coef_y/cb/cr``, ``jpeg_quant``, ``jpeg_geom``
-    (:data:`~..ops.jpeg_device.COEFF_KEYS`) plus ``label``. Construct via
-    :func:`coeff_decoder_or_fallback` (or ``decode.decoder_for_task(...,
-    device_decode=True)``) so the native-unavailable case degrades instead
-    of raising mid-epoch.
+    (:data:`~..ops.jpeg_device.COEFF_KEYS`) plus ``label``. Raises at
+    construction when the native extractor is switched off
+    (``LDT_DISABLE_NATIVE``) or cannot be built: a run that asked for
+    device decode never proceeds on the host pixel path instead.
     """
 
     def __init__(
@@ -96,8 +90,8 @@ class CoeffImageDecoder:
 
         if not native_jpeg.native_available():
             raise RuntimeError(
-                "native coefficient extraction unavailable (ABI v3 "
-                "library failed to build/load)"
+                "device_decode needs the native coefficient extractor and "
+                "LDT_DISABLE_NATIVE switches it off"
             )
         self._native = native_jpeg
         reg = default_registry()
@@ -348,46 +342,3 @@ class CoeffImageDecoder:
                 dtype=np.int32,
             )
         return out
-
-
-def coeff_decoder_or_fallback(
-    image_size: int = 224,
-    image_column: str = "image",
-    label_column: Optional[str] = "label",
-    buffer_pool=None,
-    chunk_blocks: int = 4,
-):
-    """A :class:`CoeffImageDecoder`, or — when the native extractor is
-    unavailable — the plain PIL/pixel decoder with a ONE-TIME warning.
-    The trainer's transform stage passes pixel batches through untouched,
-    so the degraded run is exactly the ``--no_device_decode`` host path."""
-    global _WARNED_NO_NATIVE
-    try:
-        return CoeffImageDecoder(
-            image_size=image_size,
-            image_column=image_column,
-            label_column=label_column,
-            buffer_pool=buffer_pool,
-            chunk_blocks=chunk_blocks,
-        )
-    except RuntimeError:
-        if not _WARNED_NO_NATIVE:
-            _WARNED_NO_NATIVE = True
-            import warnings
-
-            warnings.warn(
-                "device_decode requested but the native coefficient "
-                "extractor is unavailable (g++/libjpeg missing or "
-                "LDT_DISABLE_NATIVE set) — falling back to the host PIL "
-                "pixel path for this run",
-                stacklevel=2,
-            )
-        from .decode import ImageClassificationDecoder
-
-        return ImageClassificationDecoder(
-            image_size=image_size,
-            image_column=image_column,
-            label_column=label_column,
-            use_native=False,
-            buffer_pool=buffer_pool,
-        )

@@ -1,26 +1,34 @@
 """ctypes binding + lazy build for the native batch JPEG decoder.
 
 No pybind11 in this environment; the C ABI (`ldt_decode_batch`) is bound via
-ctypes. The shared library is compiled from ``ldt_decode.cpp`` on first use
-(cached next to the source); any failure degrades gracefully to the PIL path
-in :mod:`..data.decode`.
+ctypes. The shared library is compiled from ``ldt_decode.cpp`` on first use,
+next to the source, under a name keyed by what went into it (the source's
+content, the compile command, this host's CPU — see :func:`library_path`).
+A build or load failure raises :class:`NativeBuildError` with the compiler's
+message; the PIL path in :mod:`..data.decode` is reached only by asking for
+it (``LDT_DISABLE_NATIVE=1`` or ``use_native=False``), never by a failure.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
+import tempfile
 import threading
 from typing import Optional, Sequence
 
 import numpy as np
 
 __all__ = [
+    "NativeBuildError",
     "batch_decode_jpeg",
     "batch_decode_jpeg_arrow",
     "batch_probe_jpeg",
     "batch_extract_coeffs",
+    "library_path",
     "native_available",
     "payload_pointers",
     "arrow_pointers",
@@ -28,83 +36,89 @@ __all__ = [
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "ldt_decode.cpp")
-_LIB_PATH = os.path.join(_HERE, "_ldt_decode.so")
 _ABI_VERSION = 3
-# Fallback build target when the package directory is read-only (system
-# pip installs): a per-user cache, keyed by ABI so upgrades never collide.
-_CACHE_LIB = os.path.join(
-    os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache"
-    ),
-    "ldt-native",
-    f"_ldt_decode_abi{_ABI_VERSION}.so",
-)
+_COMPILE = ("g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC")
+_LINK = ("-ljpeg", "-pthread")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_load_failed = False
 
 
-def _build(target: str) -> bool:
-    # Link into a temp file, then rename over the target: the replaced path
-    # gets a NEW inode, so a later dlopen cannot be deduplicated against a
-    # stale handle that was opened from the old file.
-    tmp = target + ".tmp"
-    cmd = [
-        "g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-        _SRC, "-o", tmp, "-ljpeg", "-pthread",
-    ]
+class NativeBuildError(RuntimeError):
+    """The decoder library could not be built or does not match its binding."""
+
+
+def _cpu_identity() -> str:
+    """What ``-march=native`` compiles for: this host's CPU model and its
+    feature flags. A library built for another CPU can die with SIGILL."""
     try:
-        os.makedirs(os.path.dirname(target), exist_ok=True)
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, target)
-        return True
-    except (subprocess.SubprocessError, OSError):
-        return False
-
-
-def _load_or_build(path: str) -> Optional[ctypes.CDLL]:
-    """Load ``path`` (building/rebuilding from ``_SRC`` as needed); None on
-    any failure — the caller then tries the next candidate location."""
-    needs_build = not os.path.exists(path) or (
-        os.path.getmtime(path) < os.path.getmtime(_SRC)
-    )
-    if needs_build and not _build(path):
-        return None
-    try:
-        lib = ctypes.CDLL(path)
-        if lib.ldt_decode_abi_version() != _ABI_VERSION:
-            if not _build(path):
-                return None
-            lib = ctypes.CDLL(path)
-            if lib.ldt_decode_abi_version() != _ABI_VERSION:
-                # Rebuilt from source yet still mismatched: the source
-                # itself is a different ABI generation — don't bind.
-                return None
+        with open("/proc/cpuinfo", encoding="utf-8") as f:
+            lines = {
+                line for line in f
+                if line.startswith(("model name", "flags", "Features"))
+            }
     except OSError:
-        return None
-    return lib
+        lines = set()
+    return platform.machine() + "".join(sorted(lines))
+
+
+def library_path() -> str:
+    """Where this host's build of ``ldt_decode.cpp`` lives: inside the
+    checkout, named by a digest of the source, the compile command and the
+    CPU. A copied tree's timestamps say nothing, so identity is content: a
+    library from an older source or another machine has another name and
+    is never loaded."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_COMPILE + _LINK).encode())
+    h.update(_cpu_identity().encode())
+    return os.path.join(_HERE, f"_ldt_decode-{h.hexdigest()[:16]}.so")
+
+
+def _build(target: str) -> None:
+    # Link into a private temp file, then rename over the target: concurrent
+    # builders (spawned decode workers) never see a half-written library,
+    # and the loser of the race just replaces it with its twin.
+    fd, tmp = tempfile.mkstemp(dir=_HERE, prefix="_ldt_decode-",
+                               suffix=".tmp")
+    os.close(fd)
+    cmd = [*_COMPILE, _SRC, "-o", tmp, *_LINK]
+    try:
+        try:
+            proc = subprocess.run(cmd, capture_output=True, text=True,
+                                  timeout=120)
+        except (OSError, subprocess.TimeoutExpired) as e:
+            raise NativeBuildError(
+                f"could not run `{' '.join(cmd)}`: {e} (LDT_DISABLE_NATIVE=1 "
+                "selects the PIL decoder on a machine with no toolchain)"
+            ) from e
+        if proc.returncode != 0:
+            raise NativeBuildError(
+                f"`{' '.join(cmd)}` exited {proc.returncode}:\n{proc.stderr}"
+            )
+        os.chmod(tmp, 0o755)  # mkstemp's 0600 would hide it from other users
+        os.replace(tmp, target)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _load_failed
+    global _lib
     with _lock:
-        if _lib is not None or _load_failed:
+        if _lib is not None or os.environ.get("LDT_DISABLE_NATIVE"):
             return _lib
-        if os.environ.get("LDT_DISABLE_NATIVE"):
-            _load_failed = True
-            return None
-        # Prefer the package dir (repo checkouts, rootful installs); fall
-        # back to the per-user cache when it is not writable — a system pip
-        # install must not silently lose the native decoder.
-        lib = None
-        for path in (_LIB_PATH, _CACHE_LIB):
-            lib = _load_or_build(path)
-            if lib is not None:
-                break
-        if lib is None:
-            _load_failed = True
-            return None
+        path = library_path()
+        if not os.path.exists(path):
+            _build(path)
+        lib = ctypes.CDLL(path)
+        if lib.ldt_decode_abi_version() != _ABI_VERSION:
+            raise NativeBuildError(
+                f"{path} reports ABI {lib.ldt_decode_abi_version()}, this "
+                f"binding expects {_ABI_VERSION}: ldt_decode.cpp and "
+                "native/jpeg.py disagree"
+            )
         lib.ldt_decode_batch.restype = ctypes.c_int
         lib.ldt_decode_batch.argtypes = [
             ctypes.POINTER(ctypes.c_char_p),
@@ -155,6 +169,8 @@ def _load() -> Optional[ctypes.CDLL]:
 
 
 def native_available() -> bool:
+    """False only when ``LDT_DISABLE_NATIVE`` opts out; a decoder that
+    should be there and cannot be built raises instead."""
     return _load() is not None
 
 

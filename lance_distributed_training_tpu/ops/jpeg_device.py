@@ -229,8 +229,7 @@ def make_batch_transform(out_size: int = 224):
     replaces a coefficient batch's ``jpeg_*`` leaves with the decoded
     ``image`` and passes every other leaf (label, ``_weight``, token
     columns) through untouched. Pixel batches (the ``--no_device_decode``
-    arm, or the degraded PIL path) pass through whole, so one transform
-    handle serves both arms. The downstream normalize/augment
+    arm) pass through whole, so one transform handle serves both arms. The downstream normalize/augment
     (:mod:`.image`, inside the task's jitted step) consumes the result
     exactly as it consumes a host-decoded batch."""
     decode = make_coeff_decode_fn(out_size)

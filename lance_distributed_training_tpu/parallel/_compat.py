@@ -1,152 +1,38 @@
-"""Version-tolerant imports for jax APIs that moved between releases.
+"""The one door to the host-to-device placement surface.
 
-The package must import cleanly across the jax versions the fleet actually
-runs (the container pins one version; TPU pods often pin another):
-
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-  top-level ``jax.shard_map`` (~0.6); importing the new location on an
-  older jax is an ImportError that takes the whole package down (every
-  test module's collection died on it — the exact failure this module
-  exists to prevent).
-* ``lax.pcast`` (replication-cast for shard_map's varying-type checking)
-  does not exist on older jax; there the equivalent is to disable the
-  per-output replication check (``check_rep=False``) and make ``pcast``
-  the identity — the program is unchanged, only the static type
-  annotation differs.
-* the **placement primitives** ``device_put`` and
-  ``make_array_from_single_device_arrays`` are re-exported here so the
-  placement plane (``data/placement.py``) and ``parallel/mesh.py`` have one
-  door to the H2D surface: the signatures are stable on 0.4.37 but the
-  assembly entry point moved around earlier 0.4.x releases
-  (``jax.experimental.array`` era), and funnelling every caller through the
-  shim is what lets the LDT801 lint reject stray ``jax.device_put`` calls
-  on hot paths (a synchronous consumer-thread ``device_put`` is exactly the
-  stall the placement plane exists to remove).
-
-Import from here, never from jax directly, for any symbol listed in
-``__all__``.
+``device_put`` and the two array-assembly entry points are re-exported
+here so the placement plane (``data/placement.py``) and ``parallel/mesh.py``
+reach H2D through a single module: that is what lets the LDT801 lint
+reject stray ``jax.device_put`` calls on hot paths (a synchronous
+consumer-thread ``device_put`` is exactly the stall the placement plane
+exists to remove), and what lets the compile/transfer witness count real
+H2D traffic per caller site.
 """
 
 from __future__ import annotations
 
 import jax
-from jax import lax
 
 from ..utils import compiletrack
 
 __all__ = [
-    "shard_map",
-    "pcast",
-    "axis_size",
     "device_put",
     "make_array_from_single_device_arrays",
     "make_array_from_process_local_data",
 ]
 
-# Placement primitives (see module docstring). Plain aliases on every jax
-# this container runs; the try/except keeps package import alive on the
-# early-0.4 releases where assembly lived under jax.experimental.array.
-# ``device_put`` doubles as the compile/transfer witness's one H2D door:
-# with LDT_COMPILE_SANITIZER=1 every placement through the shim is counted
-# per caller site (depth=3 — the user's ``device_put(`` line), which is what
-# lets ``ldt check --compile-witness`` report real H2D traffic next to the
-# static LDT801 funnel discipline.
+make_array_from_single_device_arrays = jax.make_array_from_single_device_arrays
+make_array_from_process_local_data = jax.make_array_from_process_local_data
+
 _raw_device_put = jax.device_put
 
 
 def device_put(x, *args, **kwargs):
+    # With LDT_COMPILE_SANITIZER=1 every placement through this door is
+    # counted per caller site (depth=3 — the user's ``device_put(`` line),
+    # so ``ldt check --compile-witness`` reports real H2D traffic next to
+    # the static LDT801 funnel discipline.
     if compiletrack.enabled():
         compiletrack.track_transfer(
             "h2d", getattr(x, "nbytes", 0) or 0, depth=3)
     return _raw_device_put(x, *args, **kwargs)
-
-try:
-    make_array_from_single_device_arrays = (
-        jax.make_array_from_single_device_arrays
-    )
-except AttributeError:  # pragma: no cover — pre-0.4.7 fallback
-    from jax.experimental.array import (  # type: ignore[no-redef]
-        make_array_from_single_device_arrays,
-    )
-
-try:
-    make_array_from_process_local_data = (
-        jax.make_array_from_process_local_data
-    )
-except AttributeError:  # pragma: no cover — pre-0.4.31: emulate via the
-    # per-device assembly (the process-local helper is itself sugar for it)
-    def make_array_from_process_local_data(sharding, local_data):
-        import numpy as np
-
-        x = np.asarray(local_data)
-        gshape = list(x.shape)
-        if gshape:
-            import jax as _jax
-
-            gshape[0] *= _jax.process_count()
-        imap = sharding.addressable_devices_indices_map(tuple(gshape))
-        starts = [(idx[0].start or 0) if idx else 0 for idx in imap.values()]
-        offset = min(starts) if starts else 0
-        shards = []
-        for d, idx in imap.items():
-            idx = tuple(idx)
-            if idx:
-                first = slice(
-                    (idx[0].start or 0) - offset,
-                    (idx[0].stop if idx[0].stop is not None
-                     else gshape[0]) - offset,
-                )
-                idx = (first,) + idx[1:]
-            shards.append(device_put(x[idx], d))
-        return make_array_from_single_device_arrays(
-            tuple(gshape), sharding, shards
-        )
-
-try:  # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map_new
-
-    shard_map = _shard_map_new
-    _HAS_NEW_SHARD_MAP = True
-except ImportError:  # older jax: experimental namespace
-    from functools import wraps
-
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    _HAS_NEW_SHARD_MAP = False
-
-    @wraps(_shard_map_old)
-    def shard_map(f, *args, **kwargs):
-        # Old shard_map's check_rep rejects programs written for the new
-        # varying-type system (pcast below degrades to identity, so scan
-        # carries would fail the replication check); disable it unless the
-        # caller asked for it explicitly.
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_old(f, *args, **kwargs)
-
-
-if hasattr(lax, "axis_size"):
-    axis_size = lax.axis_size
-else:
-    def axis_size(axis_name):
-        # psum of a Python literal constant-folds to the static axis size at
-        # trace time (the documented jax shortcut), so the result is usable
-        # as a fori_loop bound / permutation length exactly like the new API.
-        return lax.psum(1, axis_name)
-
-
-if hasattr(lax, "pcast"):
-    pcast = lax.pcast
-elif hasattr(lax, "pvary") and _HAS_NEW_SHARD_MAP:
-    # Transitional releases: pvary covers the replicated->varying direction
-    # (the only one this codebase uses).
-    def pcast(x, axis_name, to="varying"):
-        if to != "varying":
-            raise NotImplementedError(
-                "this jax only supports pcast(..., to='varying')"
-            )
-        return lax.pvary(x, axis_name)
-else:
-    # Old jax: no varying-type system; shard_map above runs with
-    # check_rep=False, so the annotation is unnecessary — identity.
-    def pcast(x, axis_name, to="varying"):  # noqa: ARG001 - signature parity
-        return x

@@ -25,10 +25,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ._compat import pcast, shard_map
 
 __all__ = ["pipeline_apply", "stack_stage_params"]
 
@@ -121,7 +119,7 @@ def pipeline_apply(
         # Initial carry must carry the 'pipe'-varying type (the body's output
         # does, via axis_index/ppermute) — pcast marks it so scan's carry
         # types line up under shard_map's manual-axes checking.
-        init = pcast(
+        init = lax.pcast(
             jnp.zeros_like(micro[0]), (pipe_axis,), to="varying"
         )
         _, outs = lax.scan(body, init, jnp.arange(ticks))

@@ -26,10 +26,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ._compat import axis_size, shard_map
 
 __all__ = ["ring_attention", "make_ring_attention"]
 
@@ -73,7 +71,7 @@ def ring_attention(
     [B, H, S_local, D]. ``mask`` (optional) is the local KEY-side validity
     block [B, 1, 1, S_local] — it travels the ring with k/v.
     """
-    ring_size = axis_size(axis_name)
+    ring_size = lax.axis_size(axis_name)
     perm = [(i, (i + 1) % ring_size) for i in range(ring_size)]
 
     # Carries derived from q/k so their varying-axis types match the loop

@@ -2,8 +2,8 @@
 # CI entrypoint: the static-analysis gate, then the tier-1 tests.
 #
 # Stage 1 — `ldt check`: the AST lint over the package (determinism, jit
-# purity, concurrency hygiene, resource ownership, compat enforcement,
-# protocol consistency, obs hygiene). Fails fast: a lint finding costs
+# purity, concurrency hygiene, resource ownership, protocol consistency,
+# obs hygiene). Fails fast: a lint finding costs
 # seconds to see here and minutes to rediscover inside a test run.
 # Stage 2 — telemetry exporter smoke: a short-lived `serve-data` with
 # --metrics_port, one loopback client pass, then fetch /metrics and
@@ -118,7 +118,7 @@ cd "$(dirname "$0")/.."
 
 echo "== ldt check =="
 # Standalone runner: the gate must run even when the training package fails
-# to import (catching exactly that is LDT401's job).
+# to import.
 python scripts/ldt_check.py
 
 echo "== telemetry exporter smoke =="
@@ -190,8 +190,8 @@ echo "== fleet smoke (coordinator + 2 servers, SIGKILL mid-stream) =="
 timeout -k 10 420 env JAX_PLATFORMS=cpu PYTHONPATH=. python scripts/fleet_smoke.py
 
 echo "== placement smoke (mesh-native global batches + H2D telemetry) =="
-# 2-simulated-process shard parity on 8 forced CPU devices (the
-# _bench_init.force_cpu XLA_FLAGS fallback), placed-vs-sync bit parity,
+# 2-simulated-process shard parity on 8 forced CPU devices
+# (_bench_init.force_cpu), placed-vs-sync bit parity,
 # and the trainer_h2d_ms series scraped from a live /metrics.
 timeout -k 10 300 env PYTHONPATH=. python scripts/placement_smoke.py
 

@@ -3,9 +3,8 @@
 
 The console `ldt check` imports the full training package (the top-level
 __init__ eagerly imports jax/flax and the whole stack). That is fine day to
-day, but the lint gate's flagship job is catching the import-breaking
-regression class (LDT401: version-moved jax symbols) — and a gate that dies
-with the ImportError it exists to diagnose is useless exactly when needed.
+day, but a gate that dies with an ImportError from the stack it lints is
+useless exactly when needed.
 
 The analysis package itself is stdlib-only, so this runner registers a
 synthetic parent package (name + __path__, no __init__ execution) and then

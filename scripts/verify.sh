@@ -4,7 +4,7 @@
 #   1. `ldt check` — the AST-based distributed-training lint gate (exits
 #      non-zero on new findings; see README "Static analysis"). Run via the
 #      standalone runner so the gate still works when the training package
-#      itself fails to import (the LDT401 regression class).
+#      itself fails to import.
 #   2. The tier-1 command from ROADMAP.md verbatim: fast-tier tests on a
 #      simulated 8-device CPU mesh, collection errors tolerated per-module,
 #      pass-count echoed for the driver.

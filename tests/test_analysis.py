@@ -393,43 +393,6 @@ def test_ldt301_accepts_ownership_stories(tmp_path):
     assert findings == []
 
 
-# -- LDT401 compat enforcement ----------------------------------------------
-
-
-def test_ldt401_flags_direct_imports_outside_shim(tmp_path):
-    findings = run_rules(
-        tmp_path,
-        {
-            "pkg/ring.py": """\
-                from jax.experimental.shard_map import shard_map
-                from jax import lax
-
-                def size(name):
-                    return lax.axis_size(name)
-            """,
-            "pkg/_compat.py": """\
-                from jax import lax
-                pcast = getattr(lax, "pcast", None)
-            """,
-        },
-        compat_module="pkg/_compat.py",
-    )
-    assert sorted(rule_ids(findings)) == ["LDT401", "LDT401"]
-    assert all(f.path == "pkg/ring.py" for f in findings)
-
-
-def test_ldt401_accepts_shim_import(tmp_path):
-    findings = run_rules(
-        tmp_path,
-        {
-            "pkg/ring.py": "from ._compat import shard_map, pcast\n",
-            "pkg/_compat.py": "shard_map = pcast = None\n",
-        },
-        compat_module="pkg/_compat.py",
-    )
-    assert findings == []
-
-
 # -- LDT501 protocol consistency --------------------------------------------
 
 

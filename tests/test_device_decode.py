@@ -11,7 +11,6 @@ same coefficient pages in, same bytes out, every run.
 """
 
 import io
-import warnings
 
 import numpy as np
 import pyarrow as pa
@@ -25,7 +24,6 @@ from lance_distributed_training_tpu.data.decode import (
 )
 from lance_distributed_training_tpu.data.device_decode import (
     CoeffImageDecoder,
-    coeff_decoder_or_fallback,
 )
 from lance_distributed_training_tpu.data.pipeline import (
     MapStylePipeline,
@@ -163,22 +161,15 @@ def test_weight_column_passes_through(image_table):
 # -- degraded paths ---------------------------------------------------------
 
 
-def test_fallback_warns_once_when_native_unavailable(monkeypatch):
-    import lance_distributed_training_tpu.data.device_decode as dd
-
+def test_device_decode_raises_when_native_switched_off(monkeypatch):
+    """A run that asked for device decode never proceeds on the host
+    pixel path: with the extractor opted out the decoder refuses."""
     monkeypatch.setattr(
         "lance_distributed_training_tpu.native.jpeg.native_available",
         lambda: False,
     )
-    monkeypatch.setattr(dd, "_WARNED_NO_NATIVE", False)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        first = coeff_decoder_or_fallback(image_size=SIZE)
-        second = coeff_decoder_or_fallback(image_size=SIZE)
-    assert isinstance(first, ImageClassificationDecoder)
-    assert isinstance(second, ImageClassificationDecoder)
-    relevant = [w for w in caught if "device_decode" in str(w.message)]
-    assert len(relevant) == 1  # warned exactly once for the run
+    with pytest.raises(RuntimeError, match="LDT_DISABLE_NATIVE"):
+        decoder_for_task("classification", SIZE, device_decode=True)
 
 
 def test_corrupt_row_degrades_to_gray(image_table):

@@ -20,25 +20,92 @@ def _jpeg(arr):
     return b.getvalue()
 
 
-def test_readonly_package_dir_falls_back_to_cache(tmp_path, monkeypatch):
-    """A system pip install puts the package in a read-only directory; the
-    lazy g++ build must fall back to the per-user cache instead of silently
-    losing the native decoder."""
+@pytest.fixture()
+def fresh_native(tmp_path, monkeypatch):
+    """The loader pointed at a private copy of the source in ``tmp_path``,
+    with no library loaded yet."""
+    import shutil
+
     from lance_distributed_training_tpu.native import jpeg as jmod
 
-    cache = tmp_path / "cache" / "_ldt_decode_abi_test.so"
-    monkeypatch.setattr(jmod, "_LIB_PATH", "/proc/ldt-unwritable/_x.so")
-    monkeypatch.setattr(jmod, "_CACHE_LIB", str(cache))
+    src = tmp_path / "ldt_decode.cpp"
+    shutil.copy(jmod._SRC, src)
+    monkeypatch.setattr(jmod, "_HERE", str(tmp_path))
+    monkeypatch.setattr(jmod, "_SRC", str(src))
     monkeypatch.setattr(jmod, "_lib", None)
-    monkeypatch.setattr(jmod, "_load_failed", False)
+    monkeypatch.delenv("LDT_DISABLE_NATIVE", raising=False)
+    return jmod, src
+
+
+def test_library_identity_follows_source_content(fresh_native):
+    """Same source, command and CPU → same name; one changed byte of the
+    source → another name, so an older build can never be the one loaded."""
+    jmod, src = fresh_native
+    first = jmod.library_path()
+    assert first == jmod.library_path()
+    assert first.startswith(str(src.parent))  # inside the checkout
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    assert jmod.library_path() != first
+
+
+def test_library_identity_follows_command_and_cpu(fresh_native, monkeypatch):
+    jmod, _ = fresh_native
+    base = jmod.library_path()
+    monkeypatch.setattr(jmod, "_COMPILE", jmod._COMPILE + ("-DX",))
+    assert jmod.library_path() != base
+    monkeypatch.setattr(jmod, "_cpu_identity", lambda: "another machine")
+    assert jmod.library_path() != base
+
+
+def test_foreign_library_at_old_path_is_not_loaded(fresh_native):
+    """A stale or foreign ``_ldt_decode.so`` (the pre-keyed name, trusted by
+    mtime) riding along in a copied tree is ignored: the loader builds its
+    own library under the keyed name and binds that."""
+    jmod, src = fresh_native
+    stale = src.parent / "_ldt_decode.so"
+    stale.write_bytes(b"\x7fELF not a library this CPU can run")
     lib = jmod._load()
     assert lib is not None
-    assert cache.exists()
-    # The fallback library decodes correctly end to end.
+    assert lib._name == jmod.library_path()
+    assert jmod.library_path() != str(stale)
+    assert stale.read_bytes().startswith(b"\x7fELF not")  # untouched
     rng = np.random.default_rng(0)
     payload = _jpeg((rng.random((48, 48, 3)) * 255).astype(np.uint8))
     out, failed = jmod.batch_decode_jpeg([payload], 32)
     assert out.shape == (1, 32, 32, 3) and not failed.any()
+
+
+def test_failed_build_raises_with_compiler_stderr(fresh_native):
+    """No silent PIL: a decoder that cannot be built raises, and the error
+    carries g++'s own message."""
+    from lance_distributed_training_tpu.data.decode import (
+        ImageClassificationDecoder,
+    )
+
+    jmod, src = fresh_native
+    src.write_text("this is not C++ @@@\n")
+    with pytest.raises(jmod.NativeBuildError) as err:
+        jmod.native_available()
+    assert "error" in str(err.value) and "ldt_decode.cpp" in str(err.value)
+    with pytest.raises(jmod.NativeBuildError):
+        ImageClassificationDecoder(image_size=32)
+    # PIL is reached only by asking for it.
+    assert ImageClassificationDecoder(
+        image_size=32, use_native=False)._native is None
+
+
+def test_missing_compiler_raises_and_names_the_opt_out(fresh_native,
+                                                       monkeypatch):
+    jmod, _ = fresh_native
+    monkeypatch.setattr(jmod, "_COMPILE", ("g++-not-installed",))
+    with pytest.raises(jmod.NativeBuildError, match="LDT_DISABLE_NATIVE"):
+        jmod.native_available()
+
+
+def test_disable_env_selects_pil(fresh_native, monkeypatch):
+    jmod, _ = fresh_native
+    monkeypatch.setenv("LDT_DISABLE_NATIVE", "1")
+    assert jmod.native_available() is False
 
 
 def test_decode_shapes_and_determinism():

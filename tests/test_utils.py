@@ -5,6 +5,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from lance_distributed_training_tpu.models import get_task
 from lance_distributed_training_tpu.trainer import TrainConfig, create_train_state
@@ -80,46 +81,71 @@ def test_metric_logger_jsonl_fallback(tmp_path, monkeypatch):
 
 
 class TestCompileCache:
-    """maybe_enable_compile_cache: accelerator-only, config-gated."""
+    """maybe_enable_compile_cache: whoever launches the process places the
+    cache; the code sets a directory only when nobody did."""
 
-    def test_never_on_cpu(self):
-        from lance_distributed_training_tpu.trainer import (
-            maybe_enable_compile_cache,
-        )
-
-        assert maybe_enable_compile_cache("cpu") is None
-
-    def test_disabled_by_flag(self):
-        from lance_distributed_training_tpu.trainer import (
-            maybe_enable_compile_cache,
-        )
-
-        assert maybe_enable_compile_cache("tpu", enabled=False) is None
-
-    def test_applies_dir_on_accelerator(self, monkeypatch, tmp_path):
+    @staticmethod
+    def _recorded_updates(monkeypatch):
         import lance_distributed_training_tpu.trainer as tm
-        from lance_distributed_training_tpu.trainer import (
-            maybe_enable_compile_cache,
-        )
 
         calls = {}
         monkeypatch.setattr(
             tm.jax.config, "update", lambda k, v: calls.__setitem__(k, v)
         )
-        cache_dir = str(tmp_path / "cache")
-        assert maybe_enable_compile_cache("tpu", cache_dir) == cache_dir
-        assert calls["jax_compilation_cache_dir"] == cache_dir
-        assert calls["jax_persistent_cache_min_compile_time_secs"] == 1.0
+        return tm, calls
 
-    def test_expands_user_dir(self, monkeypatch):
+    def test_variable_set_means_no_directory_set_in_code(self, monkeypatch,
+                                                         tmp_path):
+        tm, calls = self._recorded_updates(monkeypatch)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        for platform in ("tpu", "cpu"):
+            # Reports what JAX itself holds; sets nothing on either platform.
+            assert tm.maybe_enable_compile_cache(platform) == (
+                tm.jax.config.jax_compilation_cache_dir
+            )
+        assert tm.maybe_enable_compile_cache("tpu", enabled=False) == (
+            tm.jax.config.jax_compilation_cache_dir
+        )
+        assert calls == {}
+
+    def test_unset_on_tpu_is_the_checkout_cache(self, monkeypatch):
         import os
 
-        import lance_distributed_training_tpu.trainer as tm
-        from lance_distributed_training_tpu.trainer import (
-            maybe_enable_compile_cache,
-        )
+        tm, calls = self._recorded_updates(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        expected = os.path.join(repo, ".jax_cache")
+        assert tm.maybe_enable_compile_cache("tpu") == expected
+        assert calls == {"jax_compilation_cache_dir": expected}
+        # A fixed path: the same on every call, nothing of pid or time.
+        assert tm.maybe_enable_compile_cache("tpu") == expected
 
-        monkeypatch.setattr(tm.jax.config, "update", lambda k, v: None)
-        assert maybe_enable_compile_cache("tpu", "~/cc") == os.path.expanduser(
-            "~/cc"
-        )
+    def test_unset_on_cpu_is_none(self, monkeypatch):
+        tm, calls = self._recorded_updates(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert tm.maybe_enable_compile_cache("cpu") is None
+        assert tm.maybe_enable_compile_cache("tpu", enabled=False) is None
+        assert calls == {}
+
+    def test_test_process_itself_runs_uncached(self):
+        """conftest drops the variable before importing jax, so the tier-1
+        process never round-trips XLA:CPU executables through a cache."""
+        import os
+
+        import jax
+
+        assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_train_config_has_no_cache_dir_knob(self):
+        import dataclasses
+
+        from lance_distributed_training_tpu.cli import build_parser
+        from lance_distributed_training_tpu.trainer import TrainConfig
+
+        fields = {f.name for f in dataclasses.fields(TrainConfig)}
+        assert "compile_cache_dir" not in fields
+        with pytest.raises(SystemExit):
+            build_parser().parse_args(
+                ["--dataset_path", "/d", "--compile_cache_dir", "/x"]
+            )
