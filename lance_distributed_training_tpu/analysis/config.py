@@ -71,7 +71,6 @@ class CheckConfig:
         default_factory=lambda: [
             "lance_distributed_training_tpu/obs/*",
             "lance_distributed_training_tpu/utils/metrics.py",
-            "lance_distributed_training_tpu/utils/profiling.py",
             "lance_distributed_training_tpu/service/*",
             "lance_distributed_training_tpu/data/pipeline.py",
             "lance_distributed_training_tpu/data/workers.py",

@@ -324,7 +324,8 @@ class DataPipeline:
                     # Worker-pool path: the producer only waits on results,
                     # so this is the pipelined arrival gap, not decode CPU.
                     decode_ms = (time.monotonic_ns() - t0) / 1e6
-                    q.put((make_lineage(seq, decode_ms), out))
+                    with span("pipeline.wait_out", batch_seq=seq):
+                        q.put((make_lineage(seq, decode_ms), out))
             else:
                 for off, item in enumerate(plan):
                     seq = base + off
@@ -347,7 +348,8 @@ class DataPipeline:
                                 for v in out.values()
                             ),
                         )
-                    q.put((make_lineage(seq, decode_ms), out))
+                    with span("pipeline.wait_out", batch_seq=seq):
+                        q.put((make_lineage(seq, decode_ms), out))
             q.put(_SENTINEL)
         except BaseException as exc:  # surface worker errors to the consumer
             q.put(exc)
@@ -482,7 +484,9 @@ class DataPipeline:
                     # decode_ms here covers decode + device_put dispatch —
                     # both run in the producer on this path.
                     decode_ms = (time.monotonic_ns() - t0) / 1e6
-                    queues[k].put((make_lineage(seq, decode_ms), out))
+                    with span("pipeline.wait_out", batch_seq=seq,
+                              producer=k):
+                        queues[k].put((make_lineage(seq, decode_ms), out))
                 queues[k].put(_SENTINEL)
             except BaseException as exc:  # surface errors to the consumer
                 queues[k].put(exc)

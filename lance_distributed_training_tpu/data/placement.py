@@ -60,7 +60,7 @@ import numpy as np
 import jax
 
 from ..obs.registry import MetricsRegistry, default_registry
-from ..obs.spans import span
+from ..obs.spans import end_phase, phase
 from ..parallel._compat import (
     device_put,
     make_array_from_process_local_data,
@@ -282,15 +282,23 @@ class PlacementPlane:
         stop = threading.Event()
 
         def produce() -> None:
+            # This thread is always in one of three phases that tile its
+            # time (obs/spans.py): wait_input (pulling the next host batch:
+            # starved by read and decode), h2d (dispatch, not transfer),
+            # wait_ring (put on a full ring: ahead of the trainer). A
+            # train.loader gap on the loop thread resolves to whichever
+            # this thread was inside meanwhile.
             try:
+                phase("placement.wait_input")
                 it = iter(inner)
                 try:
                     for seq, host in enumerate(it):
                         if stop.is_set():
                             return
                         t0 = time.monotonic_ns()
-                        with span("placement.h2d", batch_seq=seq):
-                            dev = self.place_batch(host)
+                        phase("placement.h2d", batch_seq=seq)
+                        dev = self.place_batch(host)
+                        phase("placement.wait_ring", batch_seq=seq)
                         dt_ms = (time.monotonic_ns() - t0) / 1e6
                         self._h2d_hist.observe(dt_ms)
                         self.counters.add("h2d_s", dt_ms / 1e3)
@@ -300,7 +308,9 @@ class PlacementPlane:
                         # transfer-complete), not at consumer pickup.
                         self._release(host)
                         q.put(dev)
+                        phase("placement.wait_input")
                         self._set_depth(q.qsize())
+                    phase("placement.wait_ring")
                     q.put(_SENTINEL)
                 finally:
                     close = getattr(it, "close", None)
@@ -308,6 +318,8 @@ class PlacementPlane:
                         close()
             except BaseException as exc:  # surface to the consumer
                 q.put(exc)
+            finally:
+                end_phase()
 
         thread = threading.Thread(
             target=produce, daemon=True, name="ldt-placement"

@@ -94,8 +94,7 @@ Causal-tracing & SLO series (r18 — README "Causal tracing & SLOs"):
   counters plus ``cost_decode_ms`` / ``cost_entropy_ms`` /
   ``cost_token_len`` histograms;
 * :mod:`.critpath` — per-batch dominant-segment attribution + straggler
-  table (``ldt trace critical-path``; per-epoch summary in the trainer's
-  ``critpath_*`` metrics);
+  table (``ldt trace critical-path``);
 * :mod:`.slo` — declared SLOs (``LDT_SLOS``) with multi-window burn-rate
   gauges: ``slo_<name>`` + ``slo_<name>_burn_<window>`` on ``/metrics``,
   ``slo`` block on ``/healthz``; the fleet half aggregates member
@@ -134,7 +133,10 @@ from .spans import (  # noqa: F401
     SpanTracer,
     chrome_trace,
     default_tracer,
+    end_phase,
+    phase,
     span,
+    watch_xla_compiles,
 )
 from .tracectx import (  # noqa: F401
     child,
@@ -160,6 +162,9 @@ __all__ = [
     "chrome_trace",
     "default_tracer",
     "span",
+    "phase",
+    "end_phase",
+    "watch_xla_compiles",
     "make_lineage",
     "observe_wire_lineage",
     "observe_local_lineage",

@@ -11,8 +11,8 @@ surfaces:
   (:func:`trace_main`).
 * **XPlane passthrough** — every span also enters a
   ``jax.profiler.TraceAnnotation`` when jax is importable, so the same
-  regions appear on the host timeline of a ``jax.profiler`` trace
-  (``utils/profiling.trace``). No-op (and no jax import cost) otherwise.
+  regions appear on the host timeline of a ``jax.profiler`` trace whose
+  host tracer is on. No-op (and no jax import cost) otherwise.
 * **cross-process JSONL** — set ``LDT_TRACE_PATH`` (or pass ``jsonl_path``)
   and completed spans append to a JSONL file one event per line; ``ldt
   trace export --spans that-file`` stitches any number of processes'
@@ -50,6 +50,9 @@ __all__ = [
     "SpanTracer",
     "default_tracer",
     "span",
+    "phase",
+    "end_phase",
+    "watch_xla_compiles",
     "chrome_trace",
     "trace_main",
 ]
@@ -147,9 +150,9 @@ class SpanTracer:
         Yields the span's attrs dict so attributes only known mid-block
         (``cache_hit``, result sizes) can be added before the span
         closes: ``with span("x") as a: a["hit"] = True``."""
-        stack = self._stack()
         span_id = next(self._ids)
-        parent_id = stack[-1] if stack else 0
+        parent_id = self._innermost()
+        stack = self._stack()
         stack.append(span_id)
         annotation = _annotation(name)
         start = time.monotonic_ns()
@@ -167,6 +170,62 @@ class SpanTracer:
                 parent_id=parent_id, thread_id=threading.get_ident() % 2**31,
                 pid=os.getpid(), attrs=attrs or None,
             ))
+
+    def _innermost(self) -> int:
+        """Id of the calling thread's innermost open span, else of its
+        current phase, else 0 (root)."""
+        stack = self._stack()
+        if stack:
+            return stack[-1]
+        current = getattr(self._local, "phase", None)
+        return current[2] if current is not None else 0
+
+    def phase(self, name: str, **attrs) -> dict:
+        """End the calling thread's current phase and begin ``name`` on the
+        same clock reading, so a thread's phases tile its time by
+        construction: each phase's ``end_ns`` IS the next one's
+        ``start_ns``. A phase is an ordinary :class:`Span` (root of its
+        thread; spans opened inside it get it as parent), recorded when the
+        next ``phase()`` or :meth:`end_phase` closes it. Returns the attrs
+        dict, as :meth:`span` yields it."""
+        self._switch_phase(name, attrs)
+        return attrs
+
+    def end_phase(self) -> None:
+        """End the calling thread's current phase and begin none."""
+        self._switch_phase(None, None)
+
+    def _switch_phase(self, name: Optional[str], attrs) -> None:
+        now = time.monotonic_ns()
+        previous = getattr(self._local, "phase", None)
+        self._local.phase = None
+        if previous is not None:
+            p_name, p_start, p_id, p_attrs, p_annotation = previous
+            if p_annotation is not None:
+                p_annotation.__exit__(None, None, None)
+            self._record(Span(
+                name=p_name, start_ns=p_start, end_ns=now, span_id=p_id,
+                parent_id=0, thread_id=threading.get_ident() % 2**31,
+                pid=os.getpid(), attrs=p_attrs or None,
+            ))
+        if name is not None:
+            annotation = _annotation(name)
+            if annotation is not None:
+                annotation.__enter__()
+            self._local.phase = (name, now, next(self._ids), attrs,
+                                 annotation)
+
+    def record_complete(self, name: str, start_ns: int, end_ns: int,
+                        **attrs) -> None:
+        """Record a span whose duration is only reported after the fact
+        (a compile, by jax.monitoring): the times are kept as given, the
+        parent is whatever the calling thread is inside right now."""
+        self._record(Span(
+            name=name, start_ns=int(start_ns), end_ns=int(end_ns),
+            span_id=next(self._ids), parent_id=self._innermost(),
+            thread_id=threading.get_ident() % 2**31, pid=os.getpid(),
+            attrs=attrs or None,
+        ))
 
     def _record(self, span: Span) -> None:
         dropped = 0
@@ -296,6 +355,55 @@ def span(name: str, **attrs):
     """Record a region on the process-wide tracer — the one-liner the
     instrumented modules use: ``with span("svc.decode", step=n): …``."""
     return default_tracer().span(name, **attrs)
+
+
+def phase(name: str, **attrs) -> dict:
+    """Begin phase ``name`` on the calling thread, ending its current one
+    on the same clock reading (:meth:`SpanTracer.phase`)."""
+    return default_tracer().phase(name, **attrs)
+
+
+def end_phase() -> None:
+    """End the calling thread's current phase (:meth:`SpanTracer.end_phase`)."""
+    default_tracer().end_phase()
+
+
+# -- XLA compiles, seen by the program itself --------------------------------
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_LISTENER_ON = False  # jax.monitoring has no unregister: once a process
+
+
+def watch_xla_compiles() -> None:
+    """Register (once per process) a ``jax.monitoring`` listener that turns
+    every backend compile — or load from the persistent compile cache —
+    into an ``xla.compile`` span (attr ``fun_name``; parent = the phase or
+    span the compiling thread is inside, so the step number comes with it)
+    and into the counters ``xla_compiles_total`` /
+    ``xla_compile_seconds_total``. JAX reports the duration when the compile
+    ends, on the compiling thread: the span is back-dated from there."""
+    global _COMPILE_LISTENER_ON
+    with _DEFAULT_LOCK:
+        if _COMPILE_LISTENER_ON:
+            return
+        _COMPILE_LISTENER_ON = True
+    import jax.monitoring
+
+    from .registry import default_registry
+
+    def on_duration(event: str, duration: float, **kw) -> None:
+        if event != _COMPILE_EVENT:
+            return
+        end = time.monotonic_ns()
+        default_tracer().record_complete(
+            "xla.compile", end - int(duration * 1e9), end,
+            fun_name=str(kw.get("fun_name", "?")),
+        )
+        registry = default_registry()
+        registry.counter("xla_compiles_total").inc()
+        registry.counter("xla_compile_seconds_total").inc(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 # -- `ldt trace` CLI ---------------------------------------------------------

@@ -177,6 +177,7 @@ def _resize_one(img, sy0, sy1, wy, sx0, sx1, wx):
 
 
 @partial(jax.jit, static_argnames=("out_size",))
+@jax.named_scope("device_decode")  # metadata only: names it in a device trace
 def decode_coeff_batch(
     coef_y: jax.Array,
     coef_cb: jax.Array,

@@ -64,6 +64,7 @@ def is_packed_input(batch) -> bool:
 
 
 @partial(jax.jit, static_argnames=("rows", "pack_len"))
+@jax.named_scope("pack")  # metadata only: names the kernel in a device trace
 def pack_token_batch(
     values: jax.Array,
     offsets: jax.Array,

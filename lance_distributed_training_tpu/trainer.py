@@ -38,7 +38,8 @@ from flax.training import train_state
 
 from .data.format import Dataset
 from .models.tasks import Task, get_task
-from .obs.spans import span as obs_span
+from .obs.spans import end_phase, watch_xla_compiles
+from .obs.spans import phase as obs_phase
 from .parallel.mesh import (
     batch_sharding,
     get_mesh,
@@ -495,8 +496,13 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
     def step(state: TrainState, batch, rng):
         def loss_of(params):
             variables = dict(_variables(state), params=params)
-            outputs, new_state = task.forward(variables, batch, True, rng)
-            return task.loss(outputs, batch), new_state
+            # Scopes are metadata only: they name every instruction's
+            # op_name (jvp(forward), transpose(jvp(forward)), optimizer) so
+            # a device trace splits the step by phase and by flax module.
+            with jax.named_scope("forward"):
+                outputs, new_state = task.forward(variables, batch, True, rng)
+            with jax.named_scope("loss"):
+                return task.loss(outputs, batch), new_state
 
         (loss, new_model_state), grads = jax.value_and_grad(
             loss_of, has_aux=True
@@ -509,9 +515,12 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
             # all-gather instead of a full all-reduce per device. A pure
             # re-layout — gradient VALUES are unchanged.
             grads = jax.lax.with_sharding_constraint(grads, grad_sharding)
-        state = state.apply_gradients(grads=grads)
-        if new_model_state is not None and "batch_stats" in new_model_state:
-            state = state.replace(batch_stats=new_model_state["batch_stats"])
+        with jax.named_scope("optimizer"):
+            state = state.apply_gradients(grads=grads)
+            if (new_model_state is not None
+                    and "batch_stats" in new_model_state):
+                state = state.replace(
+                    batch_stats=new_model_state["batch_stats"])
         if grad_norm:
             # Global norm of THIS micro-batch's gradient (a few extra sum-
             # reductions XLA fuses into the backward) — divergence telemetry
@@ -1037,6 +1046,16 @@ def maybe_enable_compile_cache(platform: str, *,
     round-trip is unsound for shard_map collective programs and across
     hosts (see tests/conftest.py).
     """
+    if os.environ.get("LDT_TRACE_PATH"):
+        # A traced run reads the step's scopes (forward, optimizer, ...) from
+        # the executable's metadata, and JAX leaves metadata out of the
+        # cache key: an executable that a build without these scopes wrote
+        # to a shared cache would be loaded with ITS names (seen on the
+        # v5e, PR 24). Traced runs key by metadata too, so they compile, or
+        # find, executables that carry this build's names; untraced runs
+        # keep the default key and whatever the cache already holds.
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True)
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return jax.config.jax_compilation_cache_dir
     if not enabled or platform == "cpu":
@@ -1075,7 +1094,21 @@ class _CkptJournal:
 
 
 def train(config: TrainConfig) -> dict:
-    """The single training entry point. Returns final metrics."""
+    """The single training entry point. Returns final metrics.
+
+    From entry to return the calling thread is in exactly one phase
+    (``obs/spans.py``): ``startup.*`` up to the first ``train.loader``, the
+    loop's flat ``train.*`` phases, ``train.shutdown`` at the end. They tile
+    the thread's time, so a trace reader never has to guess what the loop
+    was doing in a gap."""
+    obs_phase("startup.devices")
+    try:
+        return _train(config)
+    finally:
+        end_phase()
+
+
+def _train(config: TrainConfig) -> dict:
     if config.val_fraction:
         # Validate the combo BEFORE any dataset I/O so a bad config fails
         # with its own message, not a dataset-open error.
@@ -1214,6 +1247,7 @@ def train(config: TrainConfig) -> dict:
         devices = devices[:1]
     cache_dir = maybe_enable_compile_cache(devices[0].platform,
                                            enabled=config.compile_cache)
+    watch_xla_compiles()  # xla.compile spans + xla_compiles_total
     mesh = get_mesh(
         devices,
         model_parallelism=config.model_parallelism,
@@ -1221,6 +1255,7 @@ def train(config: TrainConfig) -> dict:
         pipe_parallelism=config.pipeline_parallelism,
     )
 
+    obs_phase("startup.dataset")
     if config.data_format != "columnar":
         dataset = None
     elif config.data_service_addr or config.coordinator_addr:
@@ -1299,6 +1334,7 @@ def train(config: TrainConfig) -> dict:
             * config.epochs
             * max(config.data_echo, 1)  # echoes are real optimizer steps
         )
+    obs_phase("startup.state")  # init program, then the (lazy) step builders
     state, state_sharding = create_sharded_train_state(
         init_rng, task, config, mesh, rules,
         fsdp_axis="data" if config.fsdp else None,
@@ -1390,6 +1426,7 @@ def train(config: TrainConfig) -> dict:
     if config.checkpoint_dir:
         from .utils.checkpoint import CheckpointManager, unpack_rng_key
 
+        obs_phase("startup.restore")
         ckpt = CheckpointManager(config.checkpoint_dir)
         if config.resume:
             restored = ckpt.restore_latest(state)
@@ -1454,6 +1491,10 @@ def train(config: TrainConfig) -> dict:
     folder_fp = None  # folder-corpus fingerprint, computed once per run
     tuner = None
     run_exc: Optional[BaseException] = None
+    # Exporter, worker pool, cache and autotuner, then the first loader up
+    # to its first next(): one phase (the pools take 2-12 ms where none is
+    # asked for; a worker pool's spawn shows here).
+    obs_phase("startup.loader")
     try:
         # Everything that can fail lives inside the try — a bind failure on
         # the exporter port, the metrics_port log write, or a pool-spawn
@@ -1691,6 +1732,10 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         # Mid-epoch resume cursor: batches of THIS epoch already consumed
         # by the checkpointed run (first epoch after a restart only).
         resume_step = resume_epoch_step if epoch == start_epoch else 0
+        if epoch > start_epoch:
+            # The run's first loader build belongs to startup.loader; later
+            # ones are the second half of an epoch turnover.
+            obs_phase("train.epoch_start", epoch=epoch)
         replay_it = dev_cache.replay_iter(
             epoch, start_epoch,
             shuffled=config.shuffle or config.loader_style == "map",
@@ -1741,29 +1786,34 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         epoch_batches = resume_step  # host batches consumed this epoch
         while True:
             timer.loader_start()
-            with obs_span("train.loader", step=global_step):
-                batch = next(it, None)
-            timer.loader_stop()
+            obs_phase("train.loader", step=global_step,
+                      epoch_step=epoch_step)
+            batch = next(it, None)
             if batch is None:
+                obs_phase("train.epoch_end", epoch=epoch)
+                timer.loader_stop()
                 break
+            obs_phase("train.bookkeep")
+            timer.loader_stop()
             if transform is not None:
                 # Coefficient pages → image, on device (dispatch-timed;
                 # async backends execute it inside the step window).
                 sample = epoch_batches % 16 == 0
                 raw = batch
                 t0 = time.monotonic_ns()
-                with obs_span("train.transform", step=global_step):
-                    batch = transform(raw)
-                    decoded = batch is not raw
-                    if sample and decoded and probe_key in batch:
-                        # Await the sampled kernel run so the device-cost
-                        # histogram records execution, not dispatch:
-                        # fetching any element forces the producing kernel
-                        # to finish. Degraded/padded batches pass through
-                        # `raw` unchanged and are never sampled.
-                        leaf = batch[probe_key]
-                        _ = int(leaf[(0,) * leaf.ndim])
+                obs_phase("train.transform", step=global_step)
+                batch = transform(raw)
+                decoded = batch is not raw
+                if sample and decoded and probe_key in batch:
+                    # Await the sampled kernel run so the device-cost
+                    # histogram records execution, not dispatch. Waiting
+                    # on the leaf compiles nothing and copies nothing (an
+                    # element fetch compiled a slice per grid shape, inside
+                    # the window it measured). Degraded/padded batches pass
+                    # through `raw` unchanged and are never sampled.
+                    jax.block_until_ready(batch[probe_key])
                 dt_ms = (time.monotonic_ns() - t0) / 1e6
+                obs_phase("train.bookkeep")
                 transform_hist.observe(dt_ms)
                 if sample and decoded:
                     if global_step > 0:
@@ -1816,12 +1866,13 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 # same host batch (TrainConfig.data_echo).
                 rng, step_rng = jax.random.split(rng)
                 timer.step_start()
-                with obs_span("train.step", step=global_step):
-                    if config.log_grad_norm:
-                        state, loss, gnorm = train_step(state, batch, step_rng)
-                    else:
-                        state, loss = train_step(state, batch, step_rng)
-                        gnorm = None
+                obs_phase("train.step", step=global_step)  # dispatch only
+                if config.log_grad_norm:
+                    state, loss, gnorm = train_step(state, batch, step_rng)
+                else:
+                    state, loss = train_step(state, batch, step_rng)
+                    gnorm = None
+                obs_phase("train.bookkeep")
                 loss_sum = loss_sum + loss
                 # Bound the async dispatch queue (each in-flight step pins
                 # its global batch on device) — independent of logging, so
@@ -1836,7 +1887,10 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                     config.log_every
                     and (global_step + 1) % config.log_every == 0
                 ):
+                    # The loop thread waiting for the device.
+                    obs_phase("train.drain", step=global_step)
                     _ = float(loss)  # ldt: ignore[LDT1704] -- deliberate bounded drain: fetch at sync_every/log points keeps dispatch depth finite
+                    obs_phase("train.bookkeep")
                 timer.step_stop()
                 global_step += 1
                 epoch_step += 1
@@ -1856,6 +1910,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                     # The wall-clock rate (not the dispatch-time upper
                     # bound) leads the progress line, so it agrees with the
                     # epoch metrics' wall-clock rate on async backends.
+                    obs_phase("train.log", step=global_step)
                     w = timer.window(batch_size=config.batch_size)
                     wt = w["loader_s"] + w["step_s"]
                     entry = {
@@ -1905,6 +1960,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                             entry["images_per_sec"] / config.data_echo
                         )
                     logger.log(entry, to_wandb=False)
+                    obs_phase("train.bookkeep")
                 if stop:
                     break
             # Step boundary: the journal always pairs the post-step model
@@ -1956,6 +2012,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 # generator so producer threads and the placement ring
                 # observe the stop flag, drain, and release their
                 # BufferPool leases.
+                obs_phase("train.epoch_end", epoch=epoch)
                 if hasattr(it, "close"):
                     it.close()
                 break
@@ -1993,38 +2050,6 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             epoch_metrics["unique_images_per_sec"] = (
                 epoch_metrics["images_per_sec"] / config.data_echo
             )
-        # Critical-path attribution over the epoch's in-ring spans
-        # (obs/critpath.py): which segment dominated the traced batch
-        # chains, plus the top-3 straggler item keys for the cost ledger.
-        # Only loopback/local runs see full chains (remote roots live in
-        # the server's tracer); failure-isolated — telemetry must never
-        # fail an epoch.
-        try:
-            from .obs.critpath import analyze as _critpath_analyze
-            from .obs.spans import default_tracer
-
-            _attrs = _critpath_analyze(
-                [s.to_event() for s in default_tracer().spans]
-            )
-            if _attrs:
-                epoch_metrics["critpath_coverage_pct"] = round(
-                    sum(a["coverage_pct"] for a in _attrs) / len(_attrs), 2
-                )
-                _dominants: dict = {}
-                for a in _attrs:
-                    _dominants[a["dominant"]] = (
-                        _dominants.get(a["dominant"], 0) + 1
-                    )
-                epoch_metrics["critpath_dominant"] = max(
-                    _dominants, key=_dominants.get
-                )
-                _stragglers = [
-                    str(a["item"])[:16] for a in _attrs[:3] if a.get("item")
-                ]
-                if _stragglers:
-                    epoch_metrics["straggler_items"] = ",".join(_stragglers)
-        except Exception:  # noqa: BLE001
-            pass
         if config.eval_every and (epoch + 1) % config.eval_every == 0:
             val_loader = _build_eval_loader(
                 config, eval_dataset, mesh, index_pool=eval_pool,
@@ -2054,6 +2079,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         if stop:
             break
 
+    obs_phase("train.shutdown")  # final eval, then train()'s teardown
     results["history"] = history
     results["steps"] = global_step  # train steps executed this run
     results["global_step"] = journal.abs_step  # absolute, across restarts

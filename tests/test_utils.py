@@ -1,4 +1,4 @@
-"""Checkpoint + profiling + metrics utility tests."""
+"""Checkpoint + metrics utility tests."""
 
 import os
 
@@ -9,7 +9,7 @@ import pytest
 
 from lance_distributed_training_tpu.models import get_task
 from lance_distributed_training_tpu.trainer import TrainConfig, create_train_state
-from lance_distributed_training_tpu.utils import MetricLogger, StepProfile, StepTimer
+from lance_distributed_training_tpu.utils import MetricLogger, StepTimer
 from lance_distributed_training_tpu.utils.checkpoint import CheckpointManager
 
 
@@ -42,19 +42,6 @@ def test_checkpoint_max_to_keep(tmp_path):
     assert mgr.latest_step() == 3
     assert set(mgr.manager.all_steps()) == {2, 3}
     mgr.close()
-
-
-def test_step_profile_breakdown():
-    prof = StepProfile()
-    import time
-
-    with prof.phase("loader"):
-        time.sleep(0.01)
-    with prof.phase("step"):
-        time.sleep(0.03)
-    s = prof.summary()
-    assert s["loader_s"] > 0 and s["step_s"] > s["loader_s"]
-    assert abs(s["loader_pct"] + s["step_pct"] - 100.0) < 1e-6
 
 
 def test_step_timer_stall_pct():
