@@ -848,6 +848,12 @@ class DeviceReplayCache:
         )
         return self._filling
 
+    def expects_replay(self) -> bool:
+        """Asked once an epoch's fill is armed: will the NEXT epoch replay
+        (this one is filling, or the set is already there)? A refusal at
+        the first batch can still turn a yes into streaming."""
+        return self.enabled and (self._filling or bool(self._batches))
+
     def admit(self, batch, total_steps: int) -> Optional[dict]:
         """Offer one consumed batch to the fill. Returns ``None`` when
         admitted (or when not filling); a ``{projected, budget}`` dict

@@ -14,11 +14,20 @@ shards + ``prefetch_size`` device buffers):
   ``make_array_from_single_device_arrays`` (both primitives imported from
   ``parallel/_compat.py``; LDT801 rejects direct ``jax.device_put`` on hot
   paths so this funnel stays the only one).
-* :meth:`PlacementPlane.iter_placed` — a dedicated **placement thread**
+* the **ring** (:func:`_read_chain`) — a dedicated **placement thread**
   pulls decoded host batches from the upstream pipeline, places them, and
   keeps a depth-configurable (default 2) ring of device-resident batches
   ahead of the consumer, so ``next(loader)`` returns an already-transferred
   array and step N's compute overlaps batch N+1's DMA.
+* the **epoch handover** — a loader that was given a successor
+  (:meth:`PlacedLoader.set_successor`) does not end its ring with its
+  epoch: once the thread has pulled the last host batch of epoch e it puts
+  a boundary marker into the ring, builds epoch e+1's loader and goes on
+  filling the *same* ring from it, so the next epoch's producer threads
+  start while the trainer still consumes this one's tail and its first
+  ``next`` finds a batch already placed. One ring, one bound: the batches
+  on the device never exceed the ring's depth across the boundary, and
+  epoch e+1's producers start only after epoch e's are done.
 * :class:`PlacedLoader` — the thin wrapper ``trainer._build_loader`` puts
   around all five pipelines (``DataPipeline``, ``MapStylePipeline``,
   ``FolderDataPipeline``, ``RemoteLoader``, ``FleetLoader``): they now
@@ -45,7 +54,9 @@ per-step progress lines as ``h2d_pct``.
 
 Thread & queue policy (LDT201/LDT202): the placement thread is daemon, the
 ring queue is bounded at ``depth``, and teardown is drain-then-join — the
-same discipline as ``data/pipeline.py``.
+same discipline as ``data/pipeline.py``. The ring is read through a
+generator whose ``finally`` is that teardown, so closing it, or dropping
+the last loader that holds it, stops the thread whichever epoch it is in.
 """
 
 from __future__ import annotations
@@ -53,7 +64,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Iterator, Optional
+from typing import Callable, Iterator, NamedTuple, Optional
 
 import numpy as np
 
@@ -71,7 +82,131 @@ from ..utils.metrics import ServiceCounters
 
 __all__ = ["PlacementPlane", "PlacedLoader"]
 
-_SENTINEL = object()
+
+class _EpochEnd:
+    """Boundary marker in the ring: every batch of the epoch before it has
+    gone by. ``successor`` is the loader whose batches follow it in the same
+    ring (``None``: the chain, and the thread, end here); ``error`` is what
+    building that loader raised, kept for whoever asks for the successor."""
+
+    __slots__ = ("successor", "error")
+
+    def __init__(self, successor, error):
+        self.successor = successor
+        self.error = error
+
+
+class _Ring(NamedTuple):
+    """A running ring as one loader hands it to the next: the bounded queue
+    (so the successor can see whether a batch is ready) and the generator
+    that reads it and owns the thread's teardown."""
+
+    q: AdjustableQueue
+    batches: Iterator
+
+
+def _read_chain(first: list, q: AdjustableQueue) -> Iterator:
+    """Iterate a chain of loaders' host batches as already-placed global
+    arrays, an :class:`_EpochEnd` after each loader's last. ``first`` is
+    ``[loader]``: the thread takes the loader out, so that this generator
+    (which a loader may come to hold, see ``PlacedLoader.take_successor``)
+    keeps no loader alive.
+
+    A dedicated placement thread pulls from ``loader.inner``, places each
+    batch (async H2D dispatch), releases the host pages' pool leases, and
+    fills the bounded ring ``q``; the consumer pops ready arrays. When the
+    inner loader is exhausted (its producer threads have ended by then) the
+    thread builds the loader's successor, puts the marker and reads on from
+    the successor; without one it puts the marker and ends. Each loader's
+    plane places its own batches, owns the ring's bound while the thread
+    reads from it (the ring starts every epoch at that plane's ``depth``)
+    and counts them. Teardown is drain-then-join, and the inner iterator is
+    closed from the placement thread so upstream producer threads observe
+    their stop flags.
+    """
+    stop = threading.Event()
+
+    def produce() -> None:
+        # This thread is always in one of three phases that tile its
+        # time (obs/spans.py): wait_input (pulling the next host batch:
+        # starved by read and decode; building a successor is in here),
+        # h2d (dispatch, not transfer), wait_ring (put on a full ring:
+        # ahead of the trainer). A train.loader gap on the loop thread
+        # resolves to whichever this thread was inside meanwhile.
+        try:
+            phase("placement.wait_input")
+            loader = first.pop()
+            while loader is not None:
+                plane = loader.plane
+                q.set_maxsize(plane.depth)
+                plane._live.install([q])
+                it = iter(loader.inner)
+                try:
+                    for seq, host in enumerate(it):
+                        if stop.is_set():
+                            return
+                        t0 = time.monotonic_ns()
+                        phase("placement.h2d", batch_seq=seq)
+                        dev = plane.place_batch(host)
+                        phase("placement.wait_ring", batch_seq=seq)
+                        dt_ms = (time.monotonic_ns() - t0) / 1e6
+                        plane._h2d_hist.observe(dt_ms)
+                        plane.counters.add("h2d_s", dt_ms / 1e3)
+                        plane.counters.add("batches_placed")
+                        # Transfers dispatched: leases go back NOW (the
+                        # pool's refcount sweep defers actual recycling to
+                        # transfer-complete), not at consumer pickup.
+                        plane._release(host)
+                        q.put(dev)
+                        phase("placement.wait_input")
+                        plane._set_depth(q.qsize())
+                finally:
+                    plane._live.clear()
+                    close = getattr(it, "close", None)
+                    if close is not None:
+                        close()
+                successor = error = None
+                try:
+                    successor = loader.build_successor()
+                except Exception as exc:  # belongs to the next epoch
+                    error = exc
+                phase("placement.wait_ring")
+                q.put(_EpochEnd(successor, error))
+                loader = successor
+                if loader is not None:
+                    phase("placement.wait_input")
+        except BaseException as exc:  # surface to the consumer
+            q.put(exc)
+        finally:
+            end_phase()
+
+    plane = first[0].plane
+    thread = threading.Thread(
+        target=produce, daemon=True, name="ldt-placement"
+    )
+    thread.start()
+    try:
+        while True:
+            item = q.get()
+            plane._set_depth(q.qsize())
+            if isinstance(item, BaseException):
+                raise item
+            yield item
+            if isinstance(item, _EpochEnd):
+                if item.successor is None:
+                    return
+                plane = item.successor.plane
+    finally:
+        stop.set()
+        # Drain so a blocked put() can observe the stop flag. Drained
+        # items are device batches (host leases already released at
+        # dispatch) — dropping them frees HBM via ordinary GC.
+        while thread.is_alive():
+            try:
+                q.get_nowait()
+            except queue.Empty:
+                thread.join(timeout=0.1)
+        plane._set_depth(0)
 
 
 class PlacementPlane:
@@ -265,88 +400,6 @@ class PlacementPlane:
         if self.buffer_pool is not None:
             self.buffer_pool.release_batch(host_batch)
 
-    # -- the ring ----------------------------------------------------------
-
-    def iter_placed(self, inner) -> Iterator:
-        """Iterate ``inner``'s host batches as already-placed global arrays.
-
-        A dedicated placement thread pulls from ``inner``, places each batch
-        (async H2D dispatch), releases the host pages' pool leases, and
-        fills a bounded ring of ``depth`` device-resident batches; the
-        consumer pops ready arrays. Teardown is drain-then-join, and the
-        inner iterator is closed from the placement thread so upstream
-        producer threads observe their stop flags.
-        """
-        q: "queue.Queue" = AdjustableQueue(self.depth)
-        self._live.install([q])
-        stop = threading.Event()
-
-        def produce() -> None:
-            # This thread is always in one of three phases that tile its
-            # time (obs/spans.py): wait_input (pulling the next host batch:
-            # starved by read and decode), h2d (dispatch, not transfer),
-            # wait_ring (put on a full ring: ahead of the trainer). A
-            # train.loader gap on the loop thread resolves to whichever
-            # this thread was inside meanwhile.
-            try:
-                phase("placement.wait_input")
-                it = iter(inner)
-                try:
-                    for seq, host in enumerate(it):
-                        if stop.is_set():
-                            return
-                        t0 = time.monotonic_ns()
-                        phase("placement.h2d", batch_seq=seq)
-                        dev = self.place_batch(host)
-                        phase("placement.wait_ring", batch_seq=seq)
-                        dt_ms = (time.monotonic_ns() - t0) / 1e6
-                        self._h2d_hist.observe(dt_ms)
-                        self.counters.add("h2d_s", dt_ms / 1e3)
-                        self.counters.add("batches_placed")
-                        # Transfers dispatched: leases go back NOW (the
-                        # pool's refcount sweep defers actual recycling to
-                        # transfer-complete), not at consumer pickup.
-                        self._release(host)
-                        q.put(dev)
-                        phase("placement.wait_input")
-                        self._set_depth(q.qsize())
-                    phase("placement.wait_ring")
-                    q.put(_SENTINEL)
-                finally:
-                    close = getattr(it, "close", None)
-                    if close is not None:
-                        close()
-            except BaseException as exc:  # surface to the consumer
-                q.put(exc)
-            finally:
-                end_phase()
-
-        thread = threading.Thread(
-            target=produce, daemon=True, name="ldt-placement"
-        )
-        thread.start()
-        try:
-            while True:
-                item = q.get()
-                self._set_depth(q.qsize())
-                if item is _SENTINEL:
-                    return
-                if isinstance(item, BaseException):
-                    raise item
-                yield item
-        finally:
-            stop.set()
-            self._live.clear()
-            # Drain so a blocked put() can observe the stop flag. Drained
-            # items are device batches (host leases already released at
-            # dispatch) — dropping them frees HBM via ordinary GC.
-            while thread.is_alive():
-                try:
-                    q.get_nowait()
-                except queue.Empty:
-                    thread.join(timeout=0.1)
-            self._set_depth(0)
-
     def _set_depth(self, n: int) -> None:
         # One write: the ServiceCounters gauge lands in the registry under
         # placement_buffer_depth (the /metrics series) AND in the
@@ -361,13 +414,27 @@ class PlacedLoader:
     """A pipeline that yields host batches, placed through a
     :class:`PlacementPlane`. Delegates ``len``/``set_epoch``; exposes the
     inner loader's ``counters`` (svc_*/fleet_* windows) unchanged plus the
-    plane's ``placement_counters`` for ``StepTimer.attach_counters``."""
+    plane's ``placement_counters`` for ``StepTimer.attach_counters``.
+
+    One iteration is one epoch. A loader that was given a successor
+    (:meth:`set_successor`) leaves its ring running at its epoch's end:
+    :meth:`take_successor` then returns the next epoch's loader, which the
+    ring is already reading, and iterating that loader goes on popping the
+    same ring. ``handover`` says what an iteration found at its first
+    ``next``: ``"warm"`` (a batch of this epoch was already in the ring) or
+    ``"cold"`` (the ring was empty: the thread had only just started, or
+    the pipeline was behind)."""
 
     def __init__(self, plane: PlacementPlane, inner):
         self.plane = plane
         self.inner = inner
         self._start = 0
         self._yielded = 0
+        self.handover: Optional[str] = None
+        self._build_successor: Optional[Callable[[], "PlacedLoader"]] = None
+        self._ring: Optional[_Ring] = None  # a predecessor's, reading us
+        # (marker, ring) at the end of an epoch whose ring went on
+        self._handed: Optional[tuple] = None
 
     def __len__(self) -> int:
         return len(self.inner)
@@ -421,11 +488,70 @@ class PlacedLoader:
     def placement_counters(self) -> ServiceCounters:
         return self.plane.counters
 
+    # -- epoch handover ------------------------------------------------------
+
+    def set_successor(
+        self, build: Optional[Callable[[], "PlacedLoader"]]
+    ) -> None:
+        """Name what the ring reads once this loader's inner loader is
+        exhausted: ``build()`` returns the next epoch's loader (anything
+        that delegates to a :class:`PlacedLoader`, as a ``LoaderGraph``
+        with a ``Place`` node does). It runs on the placement thread, after
+        this epoch's producer threads have ended. Set it before the epoch
+        is nearly read: a ring that finds none at that moment ends."""
+        self._build_successor = build
+
+    def build_successor(self) -> Optional["PlacedLoader"]:
+        build = self._build_successor
+        return build() if build is not None else None
+
+    def take_successor(self) -> Optional["PlacedLoader"]:
+        """After an iteration that ran to its end: the successor the ring
+        went on to, ready to be iterated (once), or ``None`` when the ring
+        ended with this epoch. Raises what building the successor raised.
+        Until it is taken the running ring belongs to THIS loader, so
+        dropping the loader stops the thread."""
+        handed, self._handed = self._handed, None
+        if handed is None:
+            return None
+        end, ring = handed
+        if end.error is not None:
+            raise end.error
+        end.successor._adopt(ring)
+        return end.successor
+
+    def _adopt(self, ring: _Ring) -> None:
+        # A method, not an attribute set by the predecessor: a successor
+        # may be a LoaderGraph, which delegates calls to its PlacedLoader.
+        self._ring = ring
+
     def __iter__(self) -> Iterator:
+        # Not itself a generator: the ring moves into the epoch's frame
+        # here, so an iterator dropped before its first next still stops
+        # the thread of a ring it had adopted.
+        ring, self._ring = self._ring, None
+        return self._epoch(ring)
+
+    def _epoch(self, ring: Optional[_Ring]) -> Iterator:
         # Count from the cursor THIS wrapper was loaded with — never from
         # the inner loader's privates (any state_dict-compliant inner
         # works, including future composed loaders).
         self._yielded = self._start
-        for batch in self.plane.iter_placed(self.inner):
-            self._yielded += 1
-            yield batch
+        self._handed = None
+        if ring is None:  # nothing reads this loader yet: start a ring
+            q = AdjustableQueue(self.plane.depth)
+            ring = _Ring(q, _read_chain([self], q))
+        self.handover = "cold" if ring.q.empty() else "warm"
+        try:
+            for item in ring.batches:
+                if isinstance(item, _EpochEnd):
+                    if item.successor is not None or item.error is not None:
+                        self._handed = (item, ring)
+                    if item.successor is not None:
+                        ring = None  # it goes on with the successor
+                    return
+                self._yielded += 1
+                yield item
+        finally:
+            if ring is not None:
+                ring.batches.close()

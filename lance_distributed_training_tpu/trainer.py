@@ -38,6 +38,7 @@ from flax.training import train_state
 
 from .data.format import Dataset
 from .models.tasks import Task, get_task
+from .obs.registry import default_registry
 from .obs.spans import end_phase, watch_xla_compiles
 from .obs.spans import phase as obs_phase
 from .parallel.mesh import (
@@ -1504,7 +1505,6 @@ def _train(config: TrainConfig) -> dict:
                    to_wandb=False)
         if config.metrics_port is not None and jax.process_index() == 0:
             from .obs.http import MetricsHTTPServer
-            from .obs.registry import default_registry
 
             from .obs.slo import SLOTracker
 
@@ -1669,7 +1669,6 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         # segment/position ids — the text-path twin of the device-decode
         # stage below (mutually exclusive by task type). Padded batches
         # (the control arm, and every eval loader) pass through whole.
-        from .obs.registry import default_registry
         from .ops.token_device import make_pack_transform
 
         # Packed grids come out of the replicated-input kernel replicated;
@@ -1682,7 +1681,6 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         device_ms_hist = default_registry().histogram("pack_device_ms")
         probe_key = "input_ids"
     if config.device_decode:
-        from .obs.registry import default_registry
         from .ops.jpeg_device import make_batch_transform
 
         transform = make_batch_transform(config.image_size)
@@ -1728,6 +1726,15 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         else None
     )
     stop = False  # set by max_steps; ends the epoch loop after bookkeeping
+    build_loader = partial(
+        _build_loader, config, dataset, mesh, workers=worker_pool,
+        index_pool=index_pool, batch_cache=batch_cache, folder_fp=folder_fp,
+    )
+    handovers = {
+        state: default_registry().counter(f"epoch_handover_{state}_total")
+        for state in ("warm", "cold")
+    }
+    loader = None
     for epoch in range(start_epoch, config.epochs):
         # Mid-epoch resume cursor: batches of THIS epoch already consumed
         # by the checkpointed run (first epoch after a restart only).
@@ -1741,20 +1748,39 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             shuffled=config.shuffle or config.loader_style == "map",
         )
         replay = replay_it is not None
+        # Partial-epoch exclusion (PR 7) lives in the cache plane now: a
+        # resumed epoch never seeds the replay set.
+        filling = dev_cache.start_fill(replay, resume_step)
         if replay:
             it = replay_it
             loader = None
         else:
-            loader = _build_loader(config, dataset, mesh, epoch, worker_pool,
-                                   index_pool=index_pool,
-                                   batch_cache=batch_cache,
-                                   folder_fp=folder_fp)
+            # Epoch handover (data/placement.py): the previous epoch's ring
+            # may already be reading this epoch's loader, and then its
+            # first batches are placed. Otherwise (first epoch, after a
+            # replay, the synchronous arm) the pipeline starts cold here.
+            take = getattr(loader, "take_successor", None)
+            loader = (take() if take is not None else None) or build_loader(
+                epoch=epoch)
             if resume_step:
                 # Position the loader at the cursor: the rebuilt plan is
                 # deterministic, so the tail it serves is bit-identical to
                 # what the uninterrupted run would have consumed.
                 loader.load_state_dict({"epoch": epoch, "step": resume_step})
             it = iter(loader)
+            # What this epoch's ring reads next. It gets nothing, and ends
+            # with the epoch, in the last epoch, in one that max_steps
+            # ends, and before a device_cache replay (which has no loader).
+            ends_run = epoch + 1 >= config.epochs or (
+                0 < config.max_steps <= global_step
+                + (len(loader) - resume_step) * max(config.data_echo, 1)
+            )
+            if (
+                not ends_run
+                and not dev_cache.expects_replay()
+                and hasattr(loader, "set_successor")
+            ):
+                loader.set_successor(partial(build_loader, epoch=epoch + 1))
         # RemoteLoader exposes ServiceCounters: merge its stall/queue window
         # into per-step progress lines so loader-stall% stays attributable
         # (client receive stall vs server queue vs H2D vs device); a
@@ -1776,9 +1802,6 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 loader, worker_pool, _loader_buffer_pool(config),
                 batch_cache,
             ) if loader is not None else [])
-        # Partial-epoch exclusion (PR 7) lives in the cache plane now: a
-        # resumed epoch never seeds the replay set.
-        filling = dev_cache.start_fill(replay, resume_step)
         timer.reset()
         epoch_start = time.perf_counter()
         loss_sum = jnp.zeros((), jnp.float32)  # stays on device all epoch
@@ -2040,6 +2063,13 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             ),
             "loader_stall_pct": timer.loader_stall_pct,
         }
+        handover = getattr(loader, "handover", None)
+        if handover is not None:
+            # The ring at this epoch's first next: "warm" (a batch was
+            # already placed) or "cold" (the loop waited for the pipeline
+            # to start).
+            epoch_metrics["epoch_handover"] = handover
+            handovers[handover].inc()
         # Phase-latency distribution (run-wide fixed-bucket histograms):
         # the p95/p99 tail the mean loader_stall_pct hides.
         epoch_metrics.update(timer.percentiles())

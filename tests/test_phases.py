@@ -268,27 +268,105 @@ def test_train_leaves_loop_and_placement_threads_tiled(tracer, image_dataset):
             and s.parent_id == first_step.span_id
             and "step" in s.attrs["fun_name"]]
 
-    # a placement thread an epoch (the second may get the first's id): two
-    # runs of phases without a seam, each from its first wait to its last
-    everything = sorted((s for s in got if s.name in PLACEMENT_NAMES),
-                        key=lambda s: (s.start_ns, s.end_ns))
-    assert main not in {s.thread_id for s in everything}
-    lives = [[everything[0]]]
-    for a, b in zip(everything, everything[1:]):
-        if a.end_ns == b.start_ns:
-            lives[-1].append(b)
-        else:
-            lives.append([b])
-    assert len(lives) == 2
-    for own in lives:
-        assert own[0].name == "placement.wait_input"
-        assert own[-1].name == "placement.wait_ring"
-        assert [s.attrs["batch_seq"] for s in own
-                if s.name == "placement.h2d"] == list(range(5))
+    # one placement thread for the run: the first epoch's ring goes on to
+    # the second's loader, so its phases tile across the boundary, from the
+    # first wait to the last marker's put, with no end and restart between
+    (own,) = by_thread(got, PLACEMENT_NAMES)
+    assert main not in {s.thread_id for s in own}
+    assert_tiles(own)
+    assert own[0].name == "placement.wait_input"
+    assert own[-1].name == "placement.wait_ring"
+    assert [s.attrs["batch_seq"] for s in own
+            if s.name == "placement.h2d"] == 2 * list(range(5))
     # producers: every decoded batch is followed by its hand-over
     decode = [s for s in got if s.name == "pipeline.decode"]
     waits = [s for s in got if s.name == "pipeline.wait_out"]
     assert len(decode) == len(waits) == 10
+
+
+def _ring_threads():
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith(("ldt-placement", "ldt-producer"))]
+
+
+def _hold_epoch_lines_until_placed(monkeypatch, epochs):
+    """Make "warm" a fact and not a race: an epoch's metrics line (written
+    on the loop thread between the boundary marker and the next epoch's
+    first next) waits, bounded, until the ring holds a batch. Only the
+    next epoch's can be in it by then. The last epoch has no successor."""
+    import time
+
+    from lance_distributed_training_tpu.utils.metrics import MetricLogger
+
+    depth = default_registry().gauge("placement_buffer_depth")
+    real = MetricLogger.log
+
+    def log(self, entry, *a, **kw):
+        if "epoch_time" in entry and entry["epoch"] < epochs - 1:
+            deadline = time.monotonic() + 60
+            while depth.value < 1:
+                assert time.monotonic() < deadline, "no successor batch"
+                time.sleep(0.005)
+        return real(self, entry, *a, **kw)
+
+    monkeypatch.setattr(MetricLogger, "log", log)
+
+
+@pytest.mark.parametrize("shuffle", [False, True])
+def test_handover_keeps_the_steps_and_warms_every_later_epoch(
+        tracer, image_dataset, tmp_path, monkeypatch, shuffle):
+    """Three epochs: the per-step record (step, batch hash, loss) is the
+    one the per-epoch cold rebuild gives; at every boundary the loop passes
+    epoch_end, epoch_start and the first loader wait, in that order and
+    tiling; one placement thread tiles across both boundaries; the first
+    epoch starts cold and every later one warm."""
+    from lance_distributed_training_tpu.data.placement import PlacedLoader
+    from lance_distributed_training_tpu.trainer import train
+    from lance_distributed_training_tpu.utils import chaos
+
+    def run(name):
+        monkeypatch.setenv(chaos.TRACE_ENV, str(tmp_path / name))
+        results = train(_image_run_config(image_dataset, epochs=3,
+                                          shuffle=shuffle, seed=5))
+        monkeypatch.delenv(chaos.TRACE_ENV)
+        assert not _ring_threads()
+        return results, chaos.read_trace(str(tmp_path / name))
+
+    with monkeypatch.context() as cold:
+        # the parent's behaviour: no loader ever gets a successor, so every
+        # epoch rebuilds and starts its own ring
+        cold.setattr(PlacedLoader, "set_successor", lambda self, build: None)
+        rebuilt, want = run("rebuilt.jsonl")
+    assert [h["epoch_handover"] for h in rebuilt["history"]] == 3 * ["cold"]
+
+    counters = {state: default_registry().counter(
+        f"epoch_handover_{state}_total") for state in ("warm", "cold")}
+    before = {state: c.value for state, c in counters.items()}
+    _hold_epoch_lines_until_placed(monkeypatch, epochs=3)
+    tracer.clear()
+    results, got = run("chained.jsonl")
+    assert len(got) == 15 and got == want
+    assert [t["epoch"] for t in got] == [0] * 5 + [1] * 5 + [2] * 5
+    assert results["loss"] == rebuilt["loss"]
+    assert [h["epoch_handover"] for h in results["history"]] == [
+        "cold", "warm", "warm"]
+    assert {state: c.value - before[state]
+            for state, c in counters.items()} == {"cold": 1, "warm": 2}
+
+    spans = tracer.spans()
+    (loop,) = by_thread(spans, LOOP_NAMES)
+    assert_tiles(loop)
+    names = [s.name for s in loop]
+    boundaries = [i for i, n in enumerate(names) if n == "train.epoch_start"]
+    assert [loop[i].attrs["epoch"] for i in boundaries] == [1, 2]
+    for i in boundaries:
+        assert names[i - 1:i + 2] == ["train.epoch_end", "train.epoch_start",
+                                      "train.loader"]
+        assert loop[i + 1].attrs["epoch_step"] == 0
+    (own,) = by_thread(spans, PLACEMENT_NAMES)
+    assert_tiles(own)
+    assert [s.attrs["batch_seq"] for s in own
+            if s.name == "placement.h2d"] == 3 * list(range(5))
 
 
 def test_train_that_raises_leaves_no_phase_open(tracer, tmp_path):

@@ -168,7 +168,7 @@ def test_leases_release_on_transfer_dispatch_not_pickup():
             yield batch
 
     seen = 0
-    for batch in plane.iter_placed(leased_batches(6)):
+    for batch in plane.wrap(leased_batches(6)):
         seen += 1
         # depth=1 ring: upstream holds at most the batch being placed plus
         # the generator's in-flight one; everything older was released.
@@ -195,11 +195,204 @@ def test_abandoned_iterator_mid_ring_leaks_nothing():
             page[...] = rng.integers(0, 255, (8, 4, 4, 3))
             yield {"image": page}
 
-    it = plane.iter_placed(leased_batches(10))
+    it = iter(plane.wrap(leased_batches(10)))
     first = next(it)
     assert isinstance(first["image"], jax.Array)
     it.close()  # abandon mid-ring: generator finally drains + joins
     del it, first
+    stats = _drain_pool(pool)
+    assert stats["outstanding"] == 0 and stats["pending"] == 0
+
+
+# -- epoch handover: one ring across the epoch boundary ------------------------
+
+
+def _ring_threads():
+    import threading
+
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith(("ldt-placement", "ldt-producer"))]
+
+
+def _wait_for(cond, what, timeout=60.0):
+    """Bounded wait for another thread to get somewhere; fails, never
+    hangs, when it does not."""
+    import time
+
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.005)
+
+
+def _epoch_loaders(dataset, epochs, *, shuffle=False, depth=2, pool=None,
+                   decode=None, registry=None, batch=16):
+    """One cold loader an epoch, as the trainer's per-epoch rebuild makes
+    them: a plane and a pipeline each."""
+    mesh = get_mesh()
+    decode = decode or ImageClassificationDecoder(image_size=32)
+    registry = registry if registry is not None else MetricsRegistry()
+    return [
+        PlacementPlane(mesh, registry=registry, depth=depth,
+                       buffer_pool=pool).wrap(make_train_pipeline(
+                           dataset, "batch", batch, 0, 1, decode,
+                           producers=2, shuffle=shuffle, seed=3, epoch=e,
+                           buffer_pool=pool))
+        for e in range(epochs)
+    ]
+
+
+def _chain(loaders):
+    """Each loader's successor is the next; the last has none."""
+    for a, b in zip(loaders, loaders[1:]):
+        a.set_successor(lambda b=b: b)
+    return loaders
+
+
+def _host(batch):
+    return {k: np.asarray(v) for k, v in batch.items()}
+
+
+@pytest.mark.parametrize("shuffle", [False, True])
+def test_chained_epochs_serve_what_cold_rebuilds_serve(image_dataset,
+                                                       monkeypatch, shuffle):
+    """Three epochs through one ring: batch k of every epoch is byte for
+    byte what a cold loader of that epoch serves, the cursor at every step
+    boundary is the unchained one (read-ahead into the next epoch is never
+    part of it), the ring never holds more than its depth, and a resume
+    from a mid-epoch cursor of the last epoch serves the chained tail."""
+    depths = []
+    real = PlacementPlane._set_depth
+    monkeypatch.setattr(
+        PlacementPlane, "_set_depth",
+        lambda self, n: (depths.append(n), real(self, n))[1])
+    cold = [[(_host(b), loader.state_dict()) for b in loader]
+            for loader in _epoch_loaders(image_dataset, 3, shuffle=shuffle)]
+    cold_ends = depths.count(0)
+    chained = _chain(_epoch_loaders(image_dataset, 3, shuffle=shuffle))
+    loader, got = chained[0], []
+    while loader is not None:
+        got.append([(_host(b), loader.state_dict()) for b in loader])
+        assert loader.state_dict()["step"] == len(got[-1])
+        loader = loader.take_successor()
+    assert len(got) == 3 and [len(e) for e in got] == [len(e) for e in cold]
+    for want_epoch, got_epoch in zip(cold, got):
+        for (want, want_sd), (have, have_sd) in zip(want_epoch, got_epoch):
+            assert have_sd == want_sd
+            assert set(have) == set(want)
+            for key in want:
+                np.testing.assert_array_equal(have[key], want[key])
+    if shuffle:  # the epochs differ, so the order above meant something
+        assert any(
+            not np.array_equal(a[0]["label"], b[0]["label"])
+            for a, b in zip(got[0], got[1]))
+    assert max(depths) <= 2  # one ring of depth 2, across both boundaries
+    assert depths.count(0) - cold_ends >= 1  # ... reset when the chain ends
+    assert [l.handover for l in chained][0] == "cold"
+    assert not _ring_threads()
+
+    resumed = _epoch_loaders(image_dataset, 3, shuffle=shuffle)[2]
+    resumed.load_state_dict({"epoch": 2, "step": 4})
+    tail = [_host(b) for b in resumed]
+    assert len(tail) == len(got[2]) - 4
+    for have, (want, _) in zip(tail, got[2][4:]):
+        for key in want:
+            np.testing.assert_array_equal(have[key], want[key])
+
+
+def test_successor_is_placed_before_it_is_asked_for(image_dataset):
+    """The point of the handover: by the time the consumer turns to the
+    next epoch a batch of it is in the ring, through the same thread."""
+    import threading
+
+    first, second = _chain(_epoch_loaders(image_dataset, 2))
+    seen = set()
+    for _ in first:
+        seen |= {t.ident for t in threading.enumerate()
+                 if t.name == "ldt-placement"}
+    assert first.handover == "cold"  # nothing had read it before
+    handed = first._handed  # (marker, ring): the ring went on
+    assert handed is not None and handed[0].successor is second
+    _wait_for(lambda: not handed[1].q.empty(), "the successor's first batch")
+    assert first.take_successor() is second
+    assert first.take_successor() is None  # handed over once
+    n = 0
+    for _ in second:
+        n += 1
+        seen |= {t.ident for t in threading.enumerate()
+                 if t.name == "ldt-placement"}
+    assert second.handover == "warm"
+    assert n == len(second)
+    assert len(seen) == 1  # one placement thread served both epochs
+    assert second.take_successor() is None  # no successor: the ring ended
+    assert not _ring_threads()
+
+
+def test_close_with_a_started_successor_leaks_nothing(image_dataset):
+    """``close()`` one batch before the end of an epoch whose successor
+    the ring is already reading: both epochs' threads end, every lease
+    comes back, and the successor was never handed to anyone."""
+    pool = BufferPool(registry=MetricsRegistry())
+    first, second = _chain(_epoch_loaders(image_dataset, 2, pool=pool))
+    it = iter(first)
+    for _ in range(len(first) - 1):
+        next(it)
+    _wait_for(lambda: any(t.startswith("ldt-producer")
+                          and t != "ldt-producer" for t in _ring_threads())
+              and second.plane.counters.snapshot().get(
+                  "placement_batches_placed", 0) >= 1,
+              "the successor's producers and its first placed batch")
+    it.close()
+    assert not _ring_threads()
+    assert first.take_successor() is None
+    del it
+    stats = _drain_pool(pool)
+    assert stats["outstanding"] == 0 and stats["pending"] == 0
+
+
+def test_dropped_successor_stops_the_ring(image_dataset):
+    """A successor that was started and never consumed: dropping the
+    loaders (as a loop that dies between two epochs does) stops the thread
+    and returns the leases; no close() is owed."""
+    pool = BufferPool(registry=MetricsRegistry())
+    first, second = _chain(_epoch_loaders(image_dataset, 2, pool=pool))
+    for _ in first:
+        pass
+    _wait_for(lambda: second.plane.counters.snapshot().get(
+        "placement_batches_placed", 0) >= 1, "the successor's first batch")
+    assert _ring_threads()
+    del first, second
+    gc.collect()
+    _wait_for(lambda: not _ring_threads(), "the ring's threads to end")
+    stats = _drain_pool(pool)
+    assert stats["outstanding"] == 0 and stats["pending"] == 0
+
+
+@pytest.mark.parametrize("fails", ["decode", "build"])
+def test_successor_error_belongs_to_the_successor(image_dataset, fails):
+    """An error while building or reading epoch e+1 never disturbs epoch
+    e: a decode error is raised at the successor's first ``next``, a build
+    error where the successor is asked for; either way no thread and no
+    lease is left."""
+    pool = BufferPool(registry=MetricsRegistry())
+
+    def bad_decode(table):
+        raise RuntimeError("boom in the next epoch")
+
+    first, = _epoch_loaders(image_dataset, 1, pool=pool)
+    if fails == "decode":
+        second, = _epoch_loaders(image_dataset, 1, pool=pool,
+                                 decode=bad_decode)
+        first.set_successor(lambda: second)
+    else:
+        def build():
+            raise RuntimeError("boom in the next epoch")
+
+        first.set_successor(build)
+    assert sum(1 for _ in first) == len(first)  # the whole epoch, untouched
+    with pytest.raises(RuntimeError, match="boom in the next epoch"):
+        next(iter(first.take_successor()))
+    _wait_for(lambda: not _ring_threads(), "the ring's threads to end")
     stats = _drain_pool(pool)
     assert stats["outstanding"] == 0 and stats["pending"] == 0
 
