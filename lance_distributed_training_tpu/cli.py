@@ -164,7 +164,16 @@ def build_parser() -> argparse.ArgumentParser:
                    help="single-device debug mode (reference --no_ddp)")
     p.add_argument("--no_wandb", action="store_true")
     p.add_argument("--model_name", type=str, default=None,
-                   help="default per task: resnet50 / bert_base / clip_resnet50_bert")
+                   help="default per task: resnet50 / bert_base / gpt_base / "
+                        "clip_resnet50_bert; causal_lm also has olmoe_1b_7b "
+                        "(OLMoE-1B-7B at its published sizes: rotary RMSNorm "
+                        "decoder, 64 dropless SwiGLU experts, 8 a token) and "
+                        "olmoe_tiny")
+    p.add_argument("--num_layers", type=int, default=0,
+                   help=">0: this many layers of a masked_lm/causal_lm "
+                        "transformer preset in place of its own depth, at "
+                        "every published width (one chip's share of a model "
+                        "that does not fit); 0 keeps the preset's depth")
     p.add_argument("--no_compile_cache", action="store_true",
                    help="set up no persistent XLA compile cache (by default "
                         "accelerator runs cache under <checkout>/.jax_cache; "
@@ -856,6 +865,7 @@ def main(argv=None) -> dict:
         no_ddp=args.no_ddp,
         no_wandb=args.no_wandb,
         model_name=args.model_name,
+        num_layers=args.num_layers,
         pretrained=args.pretrained,
         compile_cache=not args.no_compile_cache,
         image_size=args.image_size,

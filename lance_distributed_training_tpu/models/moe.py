@@ -1,29 +1,35 @@
-"""Mixture-of-Experts MLP — expert parallelism for the transformer arm.
+"""Mixture-of-Experts feed-forward layers for the transformer stacks.
 
-Beyond the reference's scope (DP-only, SURVEY.md §2.3), built the TPU way:
-top-1 switch routing expressed entirely as einsums over a dense dispatch
-tensor — no scatter/gather, no data-dependent shapes, so XLA tiles everything
-onto the MXU and the SPMD partitioner shards the expert dimension over the
-mesh's ``'model'`` axis (see ``MOE_RULES`` in :mod:`..parallel.sharding`):
-each device group holds ``num_experts / tp`` experts and the dispatch einsum
-becomes the expert all-to-all.
+Two layers, one for each thing the repo does with experts today:
 
-Routing follows the Switch Transformer recipe: top-1 expert per token, fixed
-per-expert capacity ``ceil(capacity_factor * tokens / num_experts)`` (static
-shape!), overflow tokens pass through the residual unchanged, and a
-load-balance auxiliary loss (fraction-routed × mean-probability per expert)
-is exposed via ``sow`` for the task loss to pick up.
+* :class:`MoEMLP` — the **sharded** form (``--num_experts`` on the BERT/GPT
+  presets): top-1 switch routing with a per-expert capacity, expressed
+  entirely as einsums over a dense ``[T, E, C]`` dispatch tensor, so the SPMD
+  partitioner shards the expert dimension over the mesh's ``'model'`` axis
+  (``MOE_RULES`` in :mod:`..parallel.sharding`) and the dispatch einsum
+  becomes the expert all-to-all. Overflow tokens skip the layer, and the
+  dispatch tensors grow with ``T * E * C``: right for a few experts over a
+  mesh, impossible at a published sparse model's shape (8,192 tokens, 64
+  experts, 8 a token: terabytes).
+* :class:`DroplessMoE` — the **one-chip, published-shape** form (the OLMoE
+  presets): top-k routing with no capacity and no dropped token; the
+  ``T * k`` assignments are sorted by expert, the tokens gathered, the three
+  SwiGLU products run as grouped matrix multiplications over the ragged
+  groups (``jax.lax.ragged_dot``), and each token's k results gathered back
+  through the inverse permutation and summed with their weights.
+  Nothing larger than ``[T * k, width]`` exists. One dispatch for both is
+  decided where experts first span chips at a published shape (ROADMAP R3).
 """
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Callable
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-__all__ = ["MoEMLP"]
+__all__ = ["MoEMLP", "DroplessMoE"]
 
 
 class MoEMLP(nn.Module):
@@ -102,3 +108,106 @@ class MoEMLP(nn.Module):
         self.sow("aux_loss", "load_balance",
                  e * jnp.sum(frac * mean_prob))
         return y
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation ``perm`` of the rows with its
+    ``inverse``: the cotangent is ``g[inverse]``, a gather again, where the
+    transpose of a plain gather is a scatter-add that cannot know its
+    indices are distinct."""
+    return jnp.take(x, perm, axis=0)
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inverse: (jnp.take(x, perm, axis=0), (perm, inverse)),
+    lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
+
+
+class DroplessMoE(nn.Module):
+    """Top-k routed SwiGLU experts without capacity: ``[B, S, H] -> [B, S, H]``,
+    ``Σ_k p_k · down_k(silu(gate_k(x)) · up_k(x))`` over each token's
+    ``experts_per_token`` largest router probabilities ``p_k`` (the softmax
+    values themselves, not renormalised), no biases.
+
+    Static in shape: every token has exactly k assignments, so the sorted
+    list has ``T * k`` rows whatever the routing; only the group sizes are
+    data. Sows the two auxiliary terms of the OLMoE paper into ``aux_loss``
+    (``load_balance`` = ``E · Σ_e f_e · P_e`` and ``router_z`` =
+    ``mean(logsumexp(logits)²)``, both over live tokens, unweighted) and the
+    experts' assignment counts into ``moe_stats``; ``live`` [B, S] marks the
+    tokens that count (None: all).
+    """
+
+    num_experts: int
+    expert_dim: int
+    experts_per_token: int
+    dtype: Any = jnp.bfloat16
+    kernel_init: Callable = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x, live=None):
+        b, s, h = x.shape
+        t, e, k = b * s, self.num_experts, self.experts_per_token
+        tokens = x.reshape(t, h)
+        w_gate, w_up, w_down = (
+            self.param(name, self.kernel_init, shape, jnp.float32)
+            for name, shape in (("w_gate", (e, h, self.expert_dim)),
+                                ("w_up", (e, h, self.expert_dim)),
+                                ("w_down", (e, self.expert_dim, h))))
+
+        with jax.named_scope("moe.router"):
+            # f32 throughout: on a TPU an f32 product at default precision
+            # is one bf16 pass, and the eighth and ninth expert of a token
+            # are often closer than that.
+            logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                              param_dtype=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST,
+                              kernel_init=self.kernel_init, name="router")(
+                                  tokens.astype(jnp.float32))
+            probs = nn.softmax(logits, axis=-1)  # [T, E]
+            top_p, top_e = jax.lax.top_k(probs, k)  # [T, k]
+
+        with jax.named_scope("moe.dispatch"):
+            # Stable sort of the T*k assignments by expert: row i of the
+            # sorted list is assignment order[i], of token order[i] // k.
+            flat_e = top_e.reshape(t * k)
+            order = jnp.argsort(flat_e, stable=True)
+            inverse = jnp.zeros_like(order).at[order].set(
+                jnp.arange(t * k, dtype=order.dtype), unique_indices=True)
+            ends = jnp.searchsorted(jnp.take(flat_e, order), jnp.arange(e),
+                                    side="right").astype(jnp.int32)
+            group_sizes = jnp.diff(ends, prepend=0)
+            xs = _permute_rows(
+                jnp.repeat(tokens.astype(self.dtype), k, axis=0), order,
+                inverse)
+
+        with jax.named_scope("moe.experts"):
+            gate = jax.lax.ragged_dot(xs, w_gate.astype(self.dtype),
+                                      group_sizes)
+            up = jax.lax.ragged_dot(xs, w_up.astype(self.dtype), group_sizes)
+            out = jax.lax.ragged_dot(nn.silu(gate) * up,
+                                     w_down.astype(self.dtype), group_sizes)
+
+        with jax.named_scope("moe.combine"):
+            # Each token's k rows come back through the inverse permutation
+            # and are summed with their weights in f32: the scatter-add of
+            # the sorted rows, without the scatter.
+            y = _permute_rows(out, inverse, order).reshape(t, k, h)
+            y = (y.astype(jnp.float32) * top_p[..., None]).sum(1)
+
+        w = (jnp.ones((t,), jnp.float32) if live is None
+             else live.reshape(t).astype(jnp.float32))
+        n = jnp.maximum(w.sum(), 1.0)
+        # f_e: the share of the live tokens' assignments that went to e (a
+        # running sum over the sorted rows, read at the groups' ends);
+        # P_e: the mean router probability of e over live tokens.
+        run = jnp.concatenate([jnp.zeros((1,), jnp.float32), jnp.cumsum(
+            jnp.take(w, order // k))])
+        frac = (run[ends] - run[ends - group_sizes]) / (n * k)
+        mean_prob = (probs * w[:, None]).sum(0) / n
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        self.sow("aux_loss", "load_balance", e * jnp.sum(frac * mean_prob))
+        self.sow("aux_loss", "router_z", jnp.sum(lse * lse * w) / n)
+        self.sow("moe_stats", "group_sizes", group_sizes)
+        return y.astype(self.dtype).reshape(b, s, h)

@@ -27,7 +27,15 @@ import optax
 from ..ops.image import normalize_images, random_flip
 from . import resnet as _resnet
 from .clip import CLIP, clip_contrastive_loss, clip_resnet50_bert, clip_tiny
-from .transformer import bert_base, bert_small, gpt_base, gpt_small
+from .transformer import (
+    TransformerDecoder,
+    bert_base,
+    bert_small,
+    gpt_base,
+    gpt_small,
+    olmoe_1b_7b,
+    olmoe_tiny,
+)
 
 __all__ = ["Task", "get_task", "TASK_REGISTRY"]
 
@@ -41,6 +49,9 @@ class Task:
     loss: Callable  # (outputs, batch) -> scalar
     metric: Callable  # (outputs, batch) -> per-example float array
     metric_name: str = "accuracy"
+    stats: Optional[Callable] = None  # (outputs) -> {name: scalar} that the
+    # train step returns beside the loss (the expert layer's load); None
+    # for a task with nothing to report
 
 
 # ---------------------------------------------------------------- classification
@@ -113,19 +124,28 @@ def _classification_task(num_classes: int, model_name: str, image_size: int,
 
 
 # ---------------------------------------------------------------- masked LM
+def _depth(num_layers: int) -> dict:
+    """``num_layers`` as a constructor argument; 0 keeps the preset's."""
+    if num_layers < 0:
+        raise ValueError(f"num_layers must be >= 0, got {num_layers}")
+    return {"num_layers": num_layers} if num_layers else {}
+
+
 def _masked_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                     mask_prob: float = 0.15, mask_id: int = 1,
                     attention_fn: Optional[Callable] = None,
                     remat: bool = False, num_experts: int = 0,
                     moe_every: int = 2,
-                    aux_loss_weight: float = 0.01) -> Task:
+                    aux_loss_weight: float = 0.01,
+                    num_layers: int = 0) -> Task:
     ctor = {"bert_base": bert_base, "bert_small": bert_small}.get(model_name)
     if ctor is None:
         raise ValueError(f"Invalid model name: {model_name} "
                          "(have ['bert_base', 'bert_small'])")
     model = ctor(vocab_size=vocab_size or 30522, max_len=seq_len,
                  attention_fn=attention_fn, remat=remat,
-                 num_experts=num_experts, moe_every=moe_every)
+                 num_experts=num_experts, moe_every=moe_every,
+                 **_depth(num_layers))
 
     def init_variables(rng):
         ids = jnp.zeros((1, seq_len), jnp.int32)
@@ -191,27 +211,76 @@ def _masked_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
 
 
 # ---------------------------------------------------------------- causal LM
+# preset -> (constructor, its own vocabulary, weight of each auxiliary term
+# its expert layers sow). The GPT presets have expert layers only under
+# --num_experts (MoEMLP); every OLMoE layer is one (DroplessMoE), with the
+# two weights of the paper (arXiv:2409.02060, section 4.1).
+_SWITCH_AUX = {"load_balance": 0.01}
+_OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
+_CAUSAL_LMS: dict = {
+    "gpt_base": (gpt_base, 50257, _SWITCH_AUX),
+    "gpt_small": (gpt_small, 50257, _SWITCH_AUX),
+    "olmoe_1b_7b": (olmoe_1b_7b, 50304, _OLMOE_AUX),
+    "olmoe_tiny": (olmoe_tiny, 512, _OLMOE_AUX),
+}
+
+
+def _weighted_aux(sown: dict, weights: dict):
+    """Σ over the terms' names of weight × (that term summed over the
+    layers that sowed it)."""
+    sums: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+        name = next(k.key for k in path
+                    if getattr(k, "key", None) in weights)
+        sums[name] = sums.get(name, 0.0) + leaf
+    return sum((weights[n] * v for n, v in sums.items()),
+               jnp.zeros((), jnp.float32))
+
+
+def _expert_load(sown: dict) -> dict:
+    """The step's expert-load scalars from the layers' assignment counts
+    (``moe_stats``/``group_sizes``, [E] a layer): all assignments, and the
+    busiest and the mean expert over all layers."""
+    sizes = jnp.stack(jax.tree_util.tree_leaves(sown)).astype(jnp.float32)
+    return {"moe_assignments": sizes.sum(), "moe_expert_load_max": sizes.max(),
+            "moe_expert_load_mean": sizes.mean()}
+
+
 def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                     attention_fn: Optional[Callable] = None,
                     remat: bool = False, num_experts: int = 0,
-                    moe_every: int = 2,
-                    aux_loss_weight: float = 0.01) -> Task:
-    """Decoder-only next-token prediction (GPT family) over the same packed
+                    moe_every: int = 2, num_layers: int = 0) -> Task:
+    """Decoder-only next-token prediction (the GPT presets on the encoder
+    trunk, the OLMoE presets on the decoder stack) over the same packed
     token columns as masked-LM (``create_text_token_dataset``) — the text arm
     beyond the reference's vision-only scope, sharing the trainer, samplers
     and storage unchanged."""
-    ctor = {"gpt_base": gpt_base, "gpt_small": gpt_small}.get(model_name)
-    if ctor is None:
+    if model_name not in _CAUSAL_LMS:
         raise ValueError(f"Invalid model name: {model_name} "
-                         "(have ['gpt_base', 'gpt_small'])")
-    model = ctor(vocab_size=vocab_size or 50257, max_len=seq_len,
-                 attention_fn=attention_fn, remat=remat,
-                 num_experts=num_experts, moe_every=moe_every)
+                         f"(have {sorted(_CAUSAL_LMS)})")
+    ctor, own_vocab, aux_weights = _CAUSAL_LMS[model_name]
+    dropless = ctor.func is TransformerDecoder  # every layer has experts
+    kwargs = dict(vocab_size=vocab_size or own_vocab,
+                  attention_fn=attention_fn, remat=remat,
+                  **_depth(num_layers))
+    if dropless:  # rotary positions: no table to size by seq_len
+        if num_experts:
+            raise ValueError(
+                f"{model_name} has its own expert layers; --num_experts "
+                "adds switch experts to the BERT/GPT presets only")
+    else:
+        kwargs.update(max_len=seq_len, num_experts=num_experts,
+                      moe_every=moe_every)
+    model = ctor(**kwargs)
+    sows = (["aux_loss", "moe_stats"] if dropless
+            else ["aux_loss"] if num_experts > 0 else [])
 
     def init_variables(rng):
         ids = jnp.zeros((1, seq_len), jnp.int32)
-        return model.init(rng, ids, jnp.ones((1, seq_len), jnp.int8),
-                          train=False)
+        variables = model.init(rng, ids, jnp.ones((1, seq_len), jnp.int8),
+                               train=False)
+        # what the expert layers sow at init is not state
+        return {"params": variables["params"]} if dropless else variables
 
     def forward(variables, batch, train, rng):
         ids = batch["input_ids"].astype(jnp.int32)
@@ -220,21 +289,21 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
         # sequence boundaries; positions restart per packed sequence.
         seg = batch.get("segment_ids")
         pos = batch.get("position_ids")
-        aux = jnp.zeros((), jnp.float32)
-        if train and num_experts > 0:
+        if train and sows:
             logits, sown = model.apply(
-                variables, ids, mask, train=True, mutable=["aux_loss"],
+                variables, ids, mask, train=True, mutable=sows,
                 segment_ids=seg, position_ids=pos,
             )
-            for leaf in jax.tree_util.tree_leaves(sown.get("aux_loss", {})):
-                aux = aux + leaf
-        else:
-            logits = model.apply(variables, ids, mask, train=train,
-                                 segment_ids=seg, position_ids=pos)
-        return (logits, aux), None
+            aux = _weighted_aux(sown.get("aux_loss", {}), aux_weights)
+            if dropless:
+                return (logits, aux, _expert_load(sown["moe_stats"])), None
+            return (logits, aux), None
+        logits = model.apply(variables, ids, mask, train=train,
+                             segment_ids=seg, position_ids=pos)
+        return (logits, jnp.zeros((), jnp.float32)), None
 
     def _shifted(outputs, batch):
-        logits, aux = outputs
+        logits, aux = outputs[:2]
         ids = batch["input_ids"].astype(jnp.int32)
         # Predict token t+1 from positions <= t; weight by the target's
         # validity so padding after a final partial pack contributes nothing.
@@ -251,9 +320,7 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
     def loss(outputs, batch):
         logits, targets, w, aux = _shifted(outputs, batch)
         raw = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
-        return (raw * w).sum() / jnp.maximum(w.sum(), 1.0) + (
-            aux_loss_weight * aux
-        )
+        return (raw * w).sum() / jnp.maximum(w.sum(), 1.0) + aux
 
     def metric(outputs, batch):
         logits, targets, w, _aux = _shifted(outputs, batch)
@@ -261,7 +328,8 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
         return (hit * w).sum(-1) / jnp.maximum(w.sum(-1), 1.0)
 
     return Task("causal_lm", model, init_variables, forward, loss, metric,
-                metric_name="next_token_accuracy")
+                metric_name="next_token_accuracy",
+                stats=(lambda outputs: outputs[2]) if dropless else None)
 
 
 # ------------------------------------------------------- pipelined masked LM
@@ -443,11 +511,20 @@ def get_task(
     pp_microbatches: int = 4,
     mesh=None,
     param_dtype=None,
+    num_layers: int = 0,
 ) -> Task:
     """``vocab_size=None`` means "the model's own default" (bert_*: 30522,
-    clip_tiny: 1000, clip_resnet50_bert: 30522); explicit values always
-    apply verbatim. ``param_dtype`` overrides the parameter/optimizer-state
-    dtype (ResNet family only; e.g. ``jnp.bfloat16`` halves weight HBM)."""
+    gpt_*: 50257, olmoe_1b_7b: 50304, olmoe_tiny: 512, clip_tiny: 1000,
+    clip_resnet50_bert: 30522); explicit values always apply verbatim.
+    ``param_dtype`` overrides the parameter/optimizer-state dtype (ResNet
+    family only; e.g. ``jnp.bfloat16`` halves weight HBM). ``num_layers``
+    overrides a transformer preset's depth (0 keeps it): one chip's share
+    of a published model is a few of its layers at every published width."""
+    if num_layers and (task_type not in ("masked_lm", "causal_lm")
+                       or pipeline_parallelism > 1):
+        raise ValueError(
+            "num_layers applies to the masked_lm and causal_lm transformer "
+            "presets (without pipeline_parallelism)")
     if task_type == "classification":
         return _classification_task(
             num_classes, model_name or "resnet50", image_size, augment,
@@ -466,7 +543,8 @@ def get_task(
             )
         return _masked_lm_task(vocab_size, model_name or "bert_base", seq_len,
                                attention_fn=attention_fn, remat=remat,
-                               num_experts=num_experts, moe_every=moe_every)
+                               num_experts=num_experts, moe_every=moe_every,
+                               num_layers=num_layers)
     if task_type == "causal_lm":
         if pipeline_parallelism > 1:
             raise ValueError(
@@ -474,7 +552,8 @@ def get_task(
             )
         return _causal_lm_task(vocab_size, model_name or "gpt_base", seq_len,
                                attention_fn=attention_fn, remat=remat,
-                               num_experts=num_experts, moe_every=moe_every)
+                               num_experts=num_experts, moe_every=moe_every,
+                               num_layers=num_layers)
     if task_type == "contrastive":
         return _contrastive_task(
             model_name or "clip_resnet50_bert", image_size, seq_len,

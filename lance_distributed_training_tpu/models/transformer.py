@@ -18,10 +18,13 @@ from functools import partial
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
-__all__ = ["TransformerEncoder", "bert_base", "bert_small", "gpt_base",
-           "gpt_small", "dot_product_attention"]
+__all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
+           "bert_small", "gpt_base", "gpt_small", "olmoe_1b_7b",
+           "olmoe_tiny", "dot_product_attention", "RMSNorm",
+           "rotary_embedding"]
 
 
 def dot_product_attention(q, k, v, mask=None, dtype=jnp.bfloat16,
@@ -57,23 +60,93 @@ def _accepts_segment_ids(fn) -> bool:
         return False
 
 
+def _attention_masks(attention_mask, segment_ids, attention_fn):
+    """``(mask, segment_ids)`` as the blocks' attention takes them, for both
+    stacks: the key-validity mask [B, 1, 1, S], or for packed rows either the
+    ids themselves (an ``attention_fn`` that takes them) or the dense block
+    mask [B, 1, S, S]."""
+    mask = None
+    if attention_mask is not None:
+        # [B, S] -> [B, 1, 1, S]: keys masked out, broadcast over queries.
+        mask = attention_mask[:, None, None, :].astype(bool)
+    if segment_ids is None:
+        return mask, None
+    if attention_fn is not None and _accepts_segment_ids(attention_fn):
+        # Segment-native attention (the Pallas flash kernel): pass the ids
+        # straight through; they carry validity too.
+        return None, segment_ids
+    # Dense path: lower segments to the block mask [B,1,S,S] —
+    # same-segment-and-live; supersedes the validity mask.
+    from ..ops.flash import segment_attention_mask
+
+    return segment_attention_mask(segment_ids), None
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis: statistics
+    in f32, result in ``dtype``, a learned f32 scale and no bias."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones_init(),
+                           (x.shape[-1],), jnp.float32)
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
+
+
+def rotary_embedding(x, positions, theta: float):
+    """Rotary position embedding over the whole head dimension, in f32:
+    ``x`` [B, S, H, D], ``positions`` [B, S] or [S]. Half-rotation pairing
+    (element ``i`` turns with element ``i + D/2``, as the published decoder
+    implementations pair them), angle ``position * theta^(-2i/D)``."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[..., None] * freq  # [(B,) S, D/2]
+    cos = jnp.cos(angle)[..., None, :]
+    sin = jnp.sin(angle)[..., None, :]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
 class SelfAttention(nn.Module):
     num_heads: int
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
     causal: bool = False  # decoder (GPT) attention; custom attention_fns
     # must bind their own causality (e.g. make_flash_attention(causal=True))
+    use_bias: bool = True
+    qk_norm_eps: float = 0.0  # >0: RMSNorm with a learned scale on the
+    # whole query and key projections, before the split into heads
+    rope_theta: float = 0.0  # >0: rotary positions on queries and keys
+    kernel_init: Callable = nn.linear.default_kernel_init
 
     @nn.compact
-    def __call__(self, x, mask=None, segment_ids=None):
+    def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
         b, s, h = x.shape
         head_dim = h // self.num_heads
         dense = partial(
-            nn.DenseGeneral, dtype=self.dtype, param_dtype=jnp.float32
+            nn.DenseGeneral, dtype=self.dtype, param_dtype=jnp.float32,
+            use_bias=self.use_bias, kernel_init=self.kernel_init,
         )
         q = dense(features=(self.num_heads, head_dim), name="query")(x)
         k = dense(features=(self.num_heads, head_dim), name="key")(x)
         v = dense(features=(self.num_heads, head_dim), name="value")(x)
+        if self.qk_norm_eps > 0:
+            norm = partial(RMSNorm, self.qk_norm_eps, self.dtype)
+            q = norm(name="q_norm")(q.reshape(b, s, h)).reshape(q.shape)
+            k = norm(name="k_norm")(k.reshape(b, s, h)).reshape(k.shape)
+        if self.rope_theta > 0:
+            # Packed rows carry positions that restart with each document;
+            # otherwise a row is one sequence from 0.
+            pos = jnp.arange(s) if position_ids is None else position_ids
+            q = rotary_embedding(q, pos, self.rope_theta)
+            k = rotary_embedding(k, pos, self.rope_theta)
         # [B, S, H, D] -> [B, H, S, D]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         attn = self.attention_fn or partial(
@@ -166,25 +239,8 @@ class TransformerEncoder(nn.Module):
         else:
             x = x + pos_embed[:s].astype(self.dtype)
 
-        mask = None
-        if attention_mask is not None:
-            # [B, S] -> [B, 1, 1, S]: keys masked out, broadcast over queries.
-            mask = attention_mask[:, None, None, :].astype(bool)
-        seg_kwarg = None
-        if segment_ids is not None:
-            if self.attention_fn is not None and _accepts_segment_ids(
-                self.attention_fn
-            ):
-                # Segment-native attention (the Pallas flash kernel): pass
-                # the ids straight through; they carry validity too.
-                seg_kwarg = segment_ids
-                mask = None
-            else:
-                # Dense path: lower segments to the block mask [B,1,S,S] —
-                # same-segment-and-live; supersedes the validity mask.
-                from ..ops.flash import segment_attention_mask
-
-                mask = segment_attention_mask(segment_ids)
+        mask, seg_kwarg = _attention_masks(attention_mask, segment_ids,
+                                           self.attention_fn)
 
         block = EncoderBlock
         if self.remat:
@@ -210,6 +266,94 @@ class TransformerEncoder(nn.Module):
         return logits
 
 
+class DecoderBlock(nn.Module):
+    """Pre-norm decoder layer ``x + attn(rmsnorm(x))``, ``x + moe(rmsnorm(x))``
+    with rotary, bias-free attention and a dropless expert layer."""
+
+    num_heads: int
+    expert_dim: int
+    num_experts: int
+    experts_per_token: int
+    norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    init_std: float = 0.02
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x, mask=None, segment_ids=None, position_ids=None,
+                 live=None):
+        from .moe import DroplessMoE
+
+        norm = partial(RMSNorm, self.norm_eps, self.dtype)
+        init = nn.initializers.truncated_normal(self.init_std)
+        y = norm(name="ln_attn")(x)
+        with jax.named_scope("attention"):
+            y = SelfAttention(self.num_heads, self.dtype,
+                              attention_fn=self.attention_fn, causal=True,
+                              use_bias=False, qk_norm_eps=self.norm_eps,
+                              rope_theta=self.rope_theta, kernel_init=init,
+                              name="attn")(y, mask, segment_ids, position_ids)
+        x = x + y
+        y = DroplessMoE(self.num_experts, self.expert_dim,
+                        self.experts_per_token, self.dtype, kernel_init=init,
+                        name="moe")(norm(name="ln_mlp")(x), live)
+        return x + y
+
+
+class TransformerDecoder(nn.Module):
+    """Decoder-only stack of today's open models' kind (OLMoE's layer):
+    RMSNorm, rotary positions instead of a position table, no biases, an
+    expert layer in every block, a final RMSNorm and an untied ``lm_head``.
+    Same call signature as :class:`TransformerEncoder`, so the ``causal_lm``
+    task drives either; logits ``[B, S, vocab]`` in f32.
+    """
+
+    vocab_size: int
+    hidden_size: int
+    num_layers: int
+    num_heads: int
+    expert_dim: int
+    num_experts: int
+    experts_per_token: int
+    norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    init_std: float = 0.02
+    dtype: Any = jnp.bfloat16
+    remat: bool = False
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, input_ids, attention_mask=None, train: bool = True,
+                 segment_ids=None, position_ids=None):
+        init = nn.initializers.truncated_normal(self.init_std)
+        x = nn.Embed(self.vocab_size, self.hidden_size,
+                     param_dtype=jnp.float32, embedding_init=init,
+                     name="tok_embed")(input_ids).astype(self.dtype)
+        live = None if attention_mask is None else attention_mask > 0
+        mask, seg_kwarg = _attention_masks(attention_mask, segment_ids,
+                                           self.attention_fn)
+        block = DecoderBlock
+        if self.remat:
+            block = nn.remat(DecoderBlock, static_argnums=())
+        for i in range(self.num_layers):
+            x = block(self.num_heads, self.expert_dim, self.num_experts,
+                      self.experts_per_token, self.norm_eps, self.rope_theta,
+                      self.init_std, self.dtype,
+                      attention_fn=self.attention_fn,
+                      name=f"layer_{i}")(x, mask, seg_kwarg, position_ids,
+                                         live)
+        x = RMSNorm(self.norm_eps, self.dtype, name="ln_final")(x)
+        with jax.named_scope("lm_head"):
+            # bf16 operands on the matrix unit, f32 sums and f32 logits.
+            return nn.Dense(
+                self.vocab_size, use_bias=False, dtype=self.dtype,
+                param_dtype=jnp.float32, kernel_init=init,
+                dot_general=partial(jax.lax.dot_general,
+                                    preferred_element_type=jnp.float32),
+                name="lm_head")(x)
+
+
 bert_base = partial(TransformerEncoder, hidden_size=768, num_layers=12,
                     num_heads=12, mlp_dim=3072)
 bert_small = partial(TransformerEncoder, hidden_size=256, num_layers=4,
@@ -220,3 +364,11 @@ gpt_base = partial(TransformerEncoder, hidden_size=768, num_layers=12,
                    num_heads=12, mlp_dim=3072, causal=True)
 gpt_small = partial(TransformerEncoder, hidden_size=256, num_layers=4,
                     num_heads=4, mlp_dim=1024, causal=True)
+# OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json; Muennighoff et
+# al., arXiv:2409.02060): 16 layers of 64 experts, 8 a token, none shared.
+olmoe_1b_7b = partial(TransformerDecoder, hidden_size=2048, num_layers=16,
+                      num_heads=16, expert_dim=1024, num_experts=64,
+                      experts_per_token=8)
+olmoe_tiny = partial(TransformerDecoder, hidden_size=64, num_layers=2,
+                     num_heads=4, expert_dim=32, num_experts=8,
+                     experts_per_token=2)

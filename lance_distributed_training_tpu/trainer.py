@@ -179,6 +179,9 @@ class TrainConfig:
     image_size: int = 224
     seq_len: int = 128  # masked_lm / contrastive text length
     vocab_size: Optional[int] = None  # None = the model's own default
+    num_layers: int = 0  # >0: this many layers of a transformer preset in
+    # place of its own depth (one chip's share of a published model, at
+    # every published width); 0 keeps the preset's
     prefetch: int = 2
     producer_threads: int = 4  # decode-producer threads; with the placement
     # plane off (--no_global_batch) these also pipeline the per-batch H2D
@@ -344,6 +347,7 @@ def _task_from_config(config: TrainConfig, mesh=None) -> Task:
         image_size=config.image_size,
         seq_len=config.seq_len,
         vocab_size=config.vocab_size,
+        num_layers=config.num_layers,
         augment=config.augment,
         attention_fn=attention_fn,
         remat=config.remat,
@@ -492,6 +496,10 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
     (e.g. ``P('data', 'seq')``) to lay token batches out for context
     parallelism. The SPMD partitioner derives every collective from these
     annotations — no communication code here.
+
+    The step returns ``(state, loss)``, then the gradient norm under
+    ``grad_norm``, then ``task.stats``' dictionary of scalars where the task
+    has one (the expert layer's load).
     """
 
     def step(state: TrainState, batch, rng):
@@ -503,9 +511,11 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
             with jax.named_scope("forward"):
                 outputs, new_state = task.forward(variables, batch, True, rng)
             with jax.named_scope("loss"):
-                return task.loss(outputs, batch), new_state
+                loss = task.loss(outputs, batch)
+            stats = task.stats(outputs) if task.stats is not None else None
+            return loss, (new_state, stats)
 
-        (loss, new_model_state), grads = jax.value_and_grad(
+        (loss, (new_model_state, stats)), grads = jax.value_and_grad(
             loss_of, has_aux=True
         )(state.params)
         if grad_sharding is not None:
@@ -528,8 +538,14 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
             # (--log_grad_norm). With grad_accum > 1 the optimizer clips the
             # accumulated MEAN inside MultiSteps (smoother than this), which
             # is not observable from here.
-            return state, loss, optax.global_norm(grads)
-        return state, loss
+            extras = (optax.global_norm(grads),)
+        else:
+            extras = ()
+        if stats is not None:
+            # What the task reports of the step (the expert layer's load):
+            # scalars beside the loss, read at log points, never a sync.
+            extras += (stats,)
+        return (state, loss) + extras
 
     repl = replicated_sharding(mesh)
     state_sh = state_sharding if state_sharding is not None else repl
@@ -539,7 +555,8 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
         data = NamedSharding(mesh, batch_spec)
     else:
         data = batch_sharding(mesh)
-    out_sh = (state_sh, repl, repl) if grad_norm else (state_sh, repl)
+    out_sh = (state_sh, repl) + (repl,) * (
+        int(grad_norm) + int(task.stats is not None))
     jitted = jax.jit(
         step,
         in_shardings=(state_sh, data, repl),
@@ -1640,6 +1657,34 @@ def _train(config: TrainConfig) -> dict:
             logger.close()
 
 
+class _ExpertLoad:
+    """The expert layer's load, from the scalars the step returns beside the
+    loss (``Task.stats``) to ``obs/registry``: ``moe_assignments_total``
+    counts every step's token-to-expert assignments (summed on the device,
+    fetched at log points), ``moe_expert_load_max`` / ``_mean`` are the
+    busiest and the mean expert of the step just logged. The loss fetch has
+    already waited for that step, so nothing here waits again. A dropless
+    layer has nothing to drop, so there is no drop counter."""
+
+    def __init__(self):
+        self._assigned = None
+        self._last = None
+
+    def add(self, stats) -> None:
+        n = stats["moe_assignments"]
+        self._assigned = n if self._assigned is None else self._assigned + n
+        self._last = stats
+
+    def publish(self) -> None:
+        if self._last is None:
+            return
+        registry = default_registry()
+        registry.counter("moe_assignments_total").inc(float(self._assigned))  # ldt: ignore[LDT1704] -- log-point fetch of a scalar the drained step produced
+        for name in ("moe_expert_load_max", "moe_expert_load_mean"):
+            registry.gauge(name).set(float(self._last[name]))  # ldt: ignore[LDT1704] -- same log-point fetch
+        self._assigned = self._last = None
+
+
 def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 eval_step, logger, timer, worker_pool, ckpt, start_epoch,
                 total_start, n_devices, results, global_step, profiling,
@@ -1649,6 +1694,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 batch_cache=None, folder_fp=None):
     if journal is None:
         journal = _CkptJournal(resume_global_step)
+    moe_load = _ExpertLoad()
     # Device-decode transform stage (--device_decode): one jitted kernel
     # call replacing a batch's coefficient pages with the decoded image —
     # device work dispatched from the consumer thread, so it overlaps the
@@ -1890,13 +1936,12 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 rng, step_rng = jax.random.split(rng)
                 timer.step_start()
                 obs_phase("train.step", step=global_step)  # dispatch only
-                if config.log_grad_norm:
-                    state, loss, gnorm = train_step(state, batch, step_rng)
-                else:
-                    state, loss = train_step(state, batch, step_rng)
-                    gnorm = None
+                state, loss, *extras = train_step(state, batch, step_rng)
+                gnorm = extras.pop(0) if config.log_grad_norm else None
                 obs_phase("train.bookkeep")
                 loss_sum = loss_sum + loss
+                if extras:  # a task with step stats: the expert layer's
+                    moe_load.add(extras[0])
                 # Bound the async dispatch queue (each in-flight step pins
                 # its global batch on device) — independent of logging, so
                 # neither log_every=0 nor a huge log_every can unbound
@@ -1974,6 +2019,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                         )
                     if gnorm is not None:
                         entry["grad_norm"] = round(float(gnorm), 4)  # ldt: ignore[LDT1704] -- log-interval divergence telemetry, rides the loss drain
+                    moe_load.publish()
                     if config.data_echo > 1:
                         # The windowed rate counts echoed steps; report the
                         # unique-data rate next to it (as the epoch metrics
