@@ -200,7 +200,11 @@ class TransformerEncoder(nn.Module):
 
     ``__call__(input_ids, attention_mask, train)`` → logits ``[B, S, vocab]``
     (tied to the input embedding — standard weight tying keeps the head off
-    the parameter budget).
+    the parameter budget). The two halves can be called apart, on the same
+    parameters: ``return_hidden=True`` stops after the final norm and gives
+    the hidden states ``[B, S, H]``, and ``hidden=<[.., H] array>`` applies
+    the tied head to that array alone (``input_ids`` is not read), so a
+    task can run the head on the positions it has targets for.
     """
 
     vocab_size: int
@@ -220,10 +224,18 @@ class TransformerEncoder(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, train: bool = True,
-                 segment_ids=None, position_ids=None):
-        b, s = input_ids.shape
+                 segment_ids=None, position_ids=None,
+                 return_hidden: bool = False, hidden=None):
         embed = nn.Embed(self.vocab_size, self.hidden_size,
                          param_dtype=jnp.float32, name="tok_embed")
+
+        def tied_head(x):
+            # Tied MLM head: project back onto the embedding table.
+            return embed.attend(x.astype(jnp.float32))
+
+        if hidden is not None:
+            return tied_head(hidden)
+        b, s = input_ids.shape
         pos_embed = self.param(
             "pos_embed", nn.initializers.normal(0.02),
             (self.max_len, self.hidden_size), jnp.float32,
@@ -259,11 +271,9 @@ class TransformerEncoder(nn.Module):
                       name=f"layer_{i}")(x, mask, seg_kwarg)
         x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
                          name="ln_final")(x)
-        if self.head == "none":
+        if self.head == "none" or return_hidden:
             return x  # final hidden states [B, S, H] (e.g. the CLIP text tower)
-        # Tied MLM head: project back onto the embedding table.
-        logits = embed.attend(x.astype(jnp.float32))
-        return logits
+        return tied_head(x)
 
 
 class DecoderBlock(nn.Module):

@@ -481,7 +481,8 @@ def _variables(state: TrainState) -> dict:
 
 def make_train_step(task: Task, mesh, *, donate: bool = True,
                     state_sharding=None, batch_spec=None,
-                    grad_norm: bool = False, grad_sharding=None):
+                    grad_norm: bool = False, stats: bool = False,
+                    grad_sharding=None):
     """Build the jitted sharded train step.
 
     Pure DP (the reference's scope): state replicated (``P()``), every batch
@@ -498,8 +499,10 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
     annotations — no communication code here.
 
     The step returns ``(state, loss)``, then the gradient norm under
-    ``grad_norm``, then ``task.stats``' dictionary of scalars where the task
-    has one (the expert layer's load).
+    ``grad_norm``, then under ``stats`` the dictionary of scalars that
+    ``task.stats`` reports of the step (the expert layer's load, the
+    masked-LM head's fill; empty for a task with none). What comes back is
+    the caller's choice and never the task's.
     """
 
     def step(state: TrainState, batch, rng):
@@ -512,10 +515,11 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
                 outputs, new_state = task.forward(variables, batch, True, rng)
             with jax.named_scope("loss"):
                 loss = task.loss(outputs, batch)
-            stats = task.stats(outputs) if task.stats is not None else None
-            return loss, (new_state, stats)
+            reported = (task.stats(outputs)
+                        if stats and task.stats is not None else {})
+            return loss, (new_state, reported)
 
-        (loss, (new_model_state, stats)), grads = jax.value_and_grad(
+        (loss, (new_model_state, reported)), grads = jax.value_and_grad(
             loss_of, has_aux=True
         )(state.params)
         if grad_sharding is not None:
@@ -541,10 +545,10 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
             extras = (optax.global_norm(grads),)
         else:
             extras = ()
-        if stats is not None:
-            # What the task reports of the step (the expert layer's load):
-            # scalars beside the loss, read at log points, never a sync.
-            extras += (stats,)
+        if stats:
+            # What the task reports of the step: scalars beside the loss,
+            # read at log points, never a sync.
+            extras += (reported,)
         return (state, loss) + extras
 
     repl = replicated_sharding(mesh)
@@ -555,8 +559,7 @@ def make_train_step(task: Task, mesh, *, donate: bool = True,
         data = NamedSharding(mesh, batch_spec)
     else:
         data = batch_sharding(mesh)
-    out_sh = (state_sh, repl) + (repl,) * (
-        int(grad_norm) + int(task.stats is not None))
+    out_sh = (state_sh, repl) + (repl,) * (int(grad_norm) + int(stats))
     jitted = jax.jit(
         step,
         in_shardings=(state_sh, data, repl),
@@ -1403,7 +1406,8 @@ def _train(config: TrainConfig) -> dict:
         )
     train_step = make_train_step(
         task, mesh, state_sharding=state_sharding, batch_spec=batch_spec,
-        grad_norm=config.log_grad_norm, grad_sharding=grad_sharding,
+        grad_norm=config.log_grad_norm, stats=True,
+        grad_sharding=grad_sharding,
     )
     eval_step = make_eval_step(
         task, mesh, state_sharding=state_sharding, batch_spec=batch_spec
@@ -1657,32 +1661,40 @@ def _train(config: TrainConfig) -> dict:
             logger.close()
 
 
-class _ExpertLoad:
-    """The expert layer's load, from the scalars the step returns beside the
-    loss (``Task.stats``) to ``obs/registry``: ``moe_assignments_total``
-    counts every step's token-to-expert assignments (summed on the device,
-    fetched at log points), ``moe_expert_load_max`` / ``_mean`` are the
-    busiest and the mean expert of the step just logged. The loss fetch has
-    already waited for that step, so nothing here waits again. A dropless
-    layer has nothing to drop, so there is no drop counter."""
+class _StepStats:
+    """A task's step stats, from the scalars the step returns beside the
+    loss (``Task.stats``) to ``obs/registry`` under their own names: a
+    ``*_total`` is a counter, summed on the device over the steps since the
+    last log point (``moe_assignments_total``: every step's token-to-expert
+    assignments; ``mlm_selected_tokens_total`` / ``mlm_head_fallback_total``:
+    the masked positions, and the steps whose batch overflowed the gathered
+    head); any other name is a gauge of the step just logged, which rides
+    the log line too (``moe_expert_load_max`` / ``_mean``,
+    ``mlm_head_capacity_tokens``, ``mlm_head_fill_pct``). The loss fetch
+    has already waited for that step, so nothing here waits again."""
 
     def __init__(self):
-        self._assigned = None
+        self._sums = None
         self._last = None
 
     def add(self, stats) -> None:
-        n = stats["moe_assignments"]
-        self._assigned = n if self._assigned is None else self._assigned + n
-        self._last = stats
+        sums = {k: v for k, v in stats.items() if k.endswith("_total")}
+        if self._sums is not None:
+            sums = {k: self._sums[k] + v for k, v in sums.items()}
+        self._sums, self._last = sums, stats
 
-    def publish(self) -> None:
+    def publish(self, entry: dict) -> None:
         if self._last is None:
             return
         registry = default_registry()
-        registry.counter("moe_assignments_total").inc(float(self._assigned))  # ldt: ignore[LDT1704] -- log-point fetch of a scalar the drained step produced
-        for name in ("moe_expert_load_max", "moe_expert_load_mean"):
-            registry.gauge(name).set(float(self._last[name]))  # ldt: ignore[LDT1704] -- same log-point fetch
-        self._assigned = self._last = None
+        for name, total in self._sums.items():
+            registry.counter(name).inc(float(total))  # ldt: ignore[LDT1704] -- log-point fetch of a scalar the drained step produced
+        for name, value in self._last.items():
+            if name not in self._sums:
+                value = float(value)  # ldt: ignore[LDT1704] -- same log-point fetch
+                registry.gauge(name).set(value)
+                entry[name] = round(value, 4)
+        self._sums = self._last = None
 
 
 def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
@@ -1694,7 +1706,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 batch_cache=None, folder_fp=None):
     if journal is None:
         journal = _CkptJournal(resume_global_step)
-    moe_load = _ExpertLoad()
+    step_stats = _StepStats()
     # Device-decode transform stage (--device_decode): one jitted kernel
     # call replacing a batch's coefficient pages with the decoded image —
     # device work dispatched from the consumer thread, so it overlaps the
@@ -1940,8 +1952,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 gnorm = extras.pop(0) if config.log_grad_norm else None
                 obs_phase("train.bookkeep")
                 loss_sum = loss_sum + loss
-                if extras:  # a task with step stats: the expert layer's
-                    moe_load.add(extras[0])
+                step_stats.add(extras[0])
                 # Bound the async dispatch queue (each in-flight step pins
                 # its global batch on device) — independent of logging, so
                 # neither log_every=0 nor a huge log_every can unbound
@@ -2019,7 +2030,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                         )
                     if gnorm is not None:
                         entry["grad_norm"] = round(float(gnorm), 4)  # ldt: ignore[LDT1704] -- log-interval divergence telemetry, rides the loss drain
-                    moe_load.publish()
+                    step_stats.publish(entry)
                     if config.data_echo > 1:
                         # The windowed rate counts echoed steps; report the
                         # unique-data rate next to it (as the epoch metrics

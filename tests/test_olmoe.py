@@ -388,10 +388,10 @@ def test_step_reports_expert_load_and_no_drop_counter(bf16_task, variables,
                                                       batch):
     outputs, _ = bf16_task.forward(variables, batch, True, None)
     stats = bf16_task.stats(outputs)
-    assert set(stats) == {"moe_assignments", "moe_expert_load_max",
+    assert set(stats) == {"moe_assignments_total", "moe_expert_load_max",
                           "moe_expert_load_mean"}
     layers = 2
-    assert float(stats["moe_assignments"]) == ROWS * SEQ * TOP_K * layers
+    assert float(stats["moe_assignments_total"]) == ROWS * SEQ * TOP_K * layers
     assert float(stats["moe_expert_load_mean"]) == ROWS * SEQ * TOP_K / 8
     assert float(stats["moe_expert_load_max"]) >= float(
         stats["moe_expert_load_mean"])

@@ -215,7 +215,16 @@ def test_compiled_step_carries_the_three_scopes(task_type):
     names = set(re.findall(r'op_name="([^"]*)"', _hlo_of_step(task, batch)))
     for scope in ("jvp(forward)", "transpose(jvp(forward))", "optimizer"):
         assert any(scope in n.split("/") for n in names), scope
-    assert any("loss" in n for n in names)
+    # the masked-LM task applies its head and its loss to the masked
+    # positions inside the forward, under a scope of its own
+    if task_type == "classification":
+        assert any("loss" in n for n in names)
+    else:  # and computes the head's gradients there with its value, inside
+        # the one conditional: the backward products read ``transpose(``
+        # further down their name, which is what a trace's phases go by
+        head = [n for n in names if "mlm_head" in n.split("/")]
+        assert any("transpose(" not in n for n in head)
+        assert any("transpose(" in n and "dot_general" in n for n in head)
     # flax's module names ride under the scope: the blocks are named
     assert any(re.search(r"jvp\(forward\)/\w+/\w+", n) for n in names)
 
