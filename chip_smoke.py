@@ -163,7 +163,7 @@ def host_batches(uri: str, decode, global_batch: int, steps: int):
 
     pipe = make_train_pipeline(
         Dataset(uri), "batch", global_batch, 0, 1, decode,
-        device_put_fn=None, prefetch=2, producers=4,
+        prefetch=2, producers=4,
     )
     it = iter(pipe)
     try:

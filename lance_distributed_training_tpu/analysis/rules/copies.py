@@ -1,9 +1,9 @@
 """Copy-hygiene rule (LDT701).
 
 The r6 zero-copy batch plane exists because redundant materialisation
-between pipeline stages — not decode math — capped loader throughput
-(`PERF_NOTES_r05.md` §1). The cheapest way to reintroduce that tax is one
-innocent-looking call on a hot path:
+between pipeline stages — not decode math — capped loader throughput.
+The cheapest way to reintroduce that tax is one innocent-looking call on a
+hot path:
 
 * ``col.to_pylist()`` — materialises a Python ``bytes`` object per row of
   an Arrow binary column (the reference's per-batch pattern this repo was

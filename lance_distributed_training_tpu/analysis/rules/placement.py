@@ -2,10 +2,10 @@
 
 The r7 placement plane (``data/placement.py``) exists because every loader
 used to end in a private ``jax.device_put`` on the consumer thread — the
-step then waited on the H2D transfer instead of overlapping it (~97%
-loader stall in BENCH_AB_r05). The cheapest way to reintroduce that stall
-is one innocent ``jax.device_put(batch)`` in a hot-path module: it works,
-it is synchronous, and nothing measures it separately.
+step then waited on the H2D transfer instead of overlapping it. The
+cheapest way to reintroduce that stall is one innocent
+``jax.device_put(batch)`` in a hot-path module: it works, it is
+synchronous, and nothing measures it separately.
 
 This rule rejects direct calls to the H2D primitives — ``jax.device_put``
 and ``make_array_from_single_device_arrays`` (however imported from jax) —

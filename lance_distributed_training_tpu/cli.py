@@ -191,17 +191,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--prefetch", type=int, default=2)
     p.add_argument("--producer_threads", type=int, default=4,
                    help="decode-producer threads (cross-batch decode "
-                        "overlap; with --no_global_batch they also "
-                        "pipeline the per-batch H2D copy)")
+                        "overlap)")
     p.add_argument("--placement_depth", type=int, default=2,
                    help="device-resident global batches the placement "
                         "plane keeps transferred ahead of the step "
                         "(default 2 = double-buffered H2D)")
-    p.add_argument("--no_global_batch", action="store_true",
-                   help="disable the async placement plane: assemble the "
-                        "global batch with a synchronous device_put on the "
-                        "consumer thread (pre-r7 control arm; batches stay "
-                        "bit-identical, H2D lands inside loader stall)")
     p.add_argument("--no_autotune", action="store_true",
                    help="disable the closed-loop pipeline autotuner (tune/) "
                         "— run the exact fixed-knob configuration (workers/"
@@ -873,7 +867,6 @@ def main(argv=None) -> dict:
         vocab_size=args.vocab_size,
         prefetch=args.prefetch,
         producer_threads=args.producer_threads,
-        global_batch=not args.no_global_batch,
         placement_depth=args.placement_depth,
         autotune=not args.no_autotune,
         autotune_interval_s=args.autotune_interval_s,

@@ -47,7 +47,6 @@ from .graph import (  # noqa: F401
     Buffers,
     Cache,
     Decode,
-    DevicePut,
     EvalSource,
     FleetTransport,
     FolderSource,

@@ -1,7 +1,7 @@
 """Pooled-buffer memory plane: recycled decode pages + shared-memory IPC.
 
-The r5 A/B (`PERF_NOTES_r05.md` §1) showed every loader arm bottoming out at
-the host's decode+copy rate. Two of the copies are pure overhead:
+Every loader arm bottoms out at the host's decode+copy rate. Two of the
+copies are pure overhead:
 
 * **output-buffer faulting** — each decoded batch faulted a fresh
   ``np.empty`` (~38 MB at 512×224px), so the kernel zero-fills new pages on

@@ -7,8 +7,9 @@
 and per-image geometry (layout documented in :mod:`..ops.jpeg_device`) —
 leaving everything dense to the jitted device kernel. The host does only
 the inherently sequential Huffman/entropy work (``jpeg_read_coefficients``
-via ``native/ldt_decode.cpp`` ABI v3), which is what the seed's
-BENCH_DECODE_SCALING_r04 bottleneck analysis said to stop doing on the CPU.
+via ``native/ldt_decode.cpp`` ABI v3); the dense back half (dequantise,
+IDCT, colour, resize) is the part of decode that does not have to stay on
+the host's cores.
 
 Canonical page geometry: pages are padded to a per-decoder block grid that
 grows monotonically to the largest image seen, rounded UP to

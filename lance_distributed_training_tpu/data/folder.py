@@ -70,7 +70,6 @@ class FolderDataPipeline:
         process_index: int,
         process_count: int,
         decode_fn: Callable,
-        device_put_fn: Optional[Callable] = None,
         *,
         loader_style: str = "map",
         shuffle: bool = True,
@@ -109,7 +108,6 @@ class FolderDataPipeline:
         self.process_index = process_index
         self.process_count = process_count
         self.decode_fn = decode_fn
-        self.device_put_fn = device_put_fn
         self.shuffle = shuffle
         self.seed = seed
         self.epoch = epoch
@@ -215,7 +213,6 @@ class FolderDataPipeline:
             dataset=None,  # read_fn closes over self.samples instead
             plan=self._index_batches(),
             decode_fn=self.decode_fn,
-            device_put_fn=self.device_put_fn,
             prefetch=self.prefetch,
             read_fn=lambda _ds, idx: self._read(idx),
             workers=self.workers,

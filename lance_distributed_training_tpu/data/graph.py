@@ -30,8 +30,9 @@ Vocabulary — typed nodes, one per concern:
 * **Transport** — where the stream crosses a process boundary:
   :class:`InProcess` (none), :class:`ServiceTransport` (one DataService),
   :class:`FleetTransport` (coordinator-striped fleet).
-* **DevicePut** / **Place** — the synchronous H2D closure (control arm) or
-  the r6 placement plane owning H2D on its own thread.
+* **Place** — the placement plane owning H2D on its own thread. A graph
+  without it yields host batches (the server-side graphs, loader tests):
+  an engine never touches a device.
 
 :class:`LoaderGraph` composes nodes into one loader with the contract every
 consumer already speaks: ``__iter__``/``__len__``, ``state_dict``/
@@ -76,7 +77,6 @@ __all__ = [
     "InProcess",
     "ServiceTransport",
     "FleetTransport",
-    "DevicePut",
     "Place",
     "LoaderGraph",
     "canonical_graphs",
@@ -551,20 +551,6 @@ class FleetTransport(Transport):
         return f"fleet coordinator={self.coordinator_addr}{suffix}"
 
 
-class DevicePut(Node):
-    """Synchronous H2D closure on the consumer thread (the control arm);
-    ``fn=None`` yields host batches — the default since r7, where
-    :class:`Place` owns H2D downstream."""
-
-    kind = "device_put"
-
-    def __init__(self, fn: Optional[Callable] = None):
-        self.fn = fn
-
-    def detail(self) -> str:
-        return "sync-closure" if self.fn is not None else "host-batches"
-
-
 class Place(Node):
     """The r6 placement plane: a ring of in-flight device batches placed by
     a dedicated H2D thread; owns the consumed-batch cursor when present."""
@@ -585,7 +571,7 @@ class Place(Node):
 
 _SINGLETON_KINDS = (
     "source", "decode", "cache", "pool", "buffers", "prefetch",
-    "transport", "device_put", "place",
+    "transport", "place",
 )
 
 
@@ -740,12 +726,10 @@ class LoaderGraph:
         prefetch = self.node("prefetch") or Prefetch()
         pool = self.node("pool") or Pool()
         buffers = self.node("buffers") or Buffers()
-        put = self.node("device_put") or DevicePut()
         cache = self.node("cache") or Cache()
         return {
             "decode_fn": decode.decode_fn,
             "columns": decode.columns,
-            "device_put_fn": put.fn,
             "prefetch": prefetch.depth,
             "producers": prefetch.producers,
             "workers": pool.workers,
@@ -793,8 +777,7 @@ class LoaderGraph:
                 ),
             )
         return DataPipeline(
-            src.dataset, plan, c["decode_fn"], c["device_put_fn"],
-            c["prefetch"],
+            src.dataset, plan, c["decode_fn"], c["prefetch"],
             read_fn=_with_columns(_range_read, c["columns"]),
             workers=c["workers"], producers=c["producers"],
             buffer_pool=c["buffer_pool"], plan_cache=plan_cache,
@@ -807,7 +790,7 @@ class LoaderGraph:
         c = self._common()
         return MapStylePipeline(
             src.dataset, src.batch_size, src.process_index,
-            src.process_count, c["decode_fn"], c["device_put_fn"],
+            src.process_count, c["decode_fn"],
             shuffle=src.shuffle, seed=src.seed, epoch=src.epoch,
             drop_last=src.drop_last, prefetch=c["prefetch"],
             workers=c["workers"], producers=c["producers"],
@@ -826,7 +809,7 @@ class LoaderGraph:
         c = self._common()
         return FolderDataPipeline(
             src.root, src.batch_size, src.process_index,
-            src.process_count, c["decode_fn"], c["device_put_fn"],
+            src.process_count, c["decode_fn"],
             loader_style=src.loader_style, shuffle=src.shuffle,
             seed=src.seed, epoch=src.epoch, drop_last=src.drop_last,
             prefetch=c["prefetch"], workers=c["workers"],
@@ -874,7 +857,7 @@ class LoaderGraph:
                 ),
             )
         return DataPipeline(
-            None, plan, _decode, c["device_put_fn"], c["prefetch"],
+            None, plan, _decode, c["prefetch"],
             read_fn=_read, producers=c["producers"],
             buffer_pool=c["buffer_pool"], plan_cache=plan_cache,
         )
@@ -884,7 +867,6 @@ class LoaderGraph:
         decode = self.node("decode") or Decode()
         prefetch = self.node("prefetch") or Prefetch()
         buffers = self.node("buffers") or Buffers()
-        put = self.node("device_put") or DevicePut()
         common = dict(
             sampler_type=src.sampler_type,
             shuffle=src.shuffle,
@@ -906,13 +888,13 @@ class LoaderGraph:
 
             return FleetLoader(
                 transport.coordinator_addr, src.batch_size,
-                src.process_index, src.process_count, put.fn, **common,
+                src.process_index, src.process_count, **common,
             )
         from ..service.client import RemoteLoader
 
         return RemoteLoader(
             transport.addr, src.batch_size, src.process_index,
-            src.process_count, put.fn, **common,
+            src.process_count, **common,
         )
 
     # -- describe (no compile) ---------------------------------------------

@@ -1,4 +1,4 @@
-"""Input pipeline: read plan → decode → prefetch → sharded global batch.
+"""Input pipeline: read plan → decode → prefetch → host batch.
 
 This is the north-star component (SURVEY.md §7.3). It replaces, in one class,
 the reference's:
@@ -18,8 +18,7 @@ plane** (:mod:`.placement`) — the trainer wraps every pipeline in a
 async H2D, and double-buffers device-resident global batches, so the DMA
 for step N+1 overlaps the device compute of step N. That overlap — not a
 faster kernel — is what drives loader-stall below the 2% BASELINE target.
-The ``device_put_fn`` parameter remains as the synchronous escape hatch
-(the ``--no_global_batch`` control arm, and direct library callers).
+A pipeline yields host numpy batches and never touches a device.
 
 Thread & queue policy (enforced by ``ldt check`` LDT201/LDT202): producer
 threads are ``daemon=True`` (a wedged decode must never block interpreter
@@ -110,7 +109,7 @@ def _with_columns(read_fn: Callable, columns) -> Callable:
 
 
 class DataPipeline:
-    """Iterate device-ready batches for THIS process's shard of the data.
+    """Iterate host batches for THIS process's shard of the data.
 
     Since r16 this class is the runtime engine beneath a
     :class:`~.graph.LoaderGraph` assembly (``LanceSource → Decode →
@@ -123,11 +122,6 @@ class DataPipeline:
         (map-style), interpreted by ``read_fn``.
     decode_fn: Table → dict of host numpy arrays (the ``to_tensor_fn`` /
         ``collate_fn`` plugin point, ``/root/reference/README.md:28,60``).
-    device_put_fn: host batch dict → device batch (a closure over
-        ``make_global_batch(mesh)``), run synchronously on the consumer
-        thread; ``None`` yields host numpy batches — the default since r7,
-        where the placement plane (:mod:`.placement`) owns H2D on its own
-        thread downstream of this pipeline.
     prefetch: queue depth of decoded batches kept ahead of the consumer.
     producers: number of producer threads decoding plan items concurrently
         (results still yielded in plan order). With one producer there is no
@@ -151,7 +145,6 @@ class DataPipeline:
         dataset: Dataset,
         plan: Sequence,
         decode_fn: Callable[[pa.Table], dict[str, np.ndarray]],
-        device_put_fn: Optional[Callable[[dict], dict]] = None,
         prefetch: int = 2,
         read_fn: Callable[[Dataset, object], pa.Table] = _range_read,
         workers=None,
@@ -163,7 +156,6 @@ class DataPipeline:
         self.dataset = dataset
         self.plan = list(plan)
         self.decode_fn = decode_fn
-        self.device_put_fn = device_put_fn
         self.prefetch = max(1, prefetch)
         self.read_fn = read_fn
         self.workers = workers
@@ -179,10 +171,10 @@ class DataPipeline:
         self.plan_cache = plan_cache
         # Buffer plane (data/buffers.py): the pool the decoder leased its
         # output pages from (and the WorkerPool its copy-out pages). This
-        # pipeline owns the RELEASE side: leases go back after device_put
-        # dispatch (the H2D copy is enqueued; the pool's refcount guard
-        # protects aliased/in-flight buffers) or, for host-batch consumers,
-        # after the yield returns. Falls back to the decoder's own pool so
+        # pipeline owns the RELEASE side: leases go back after the yield
+        # returns (the placement plane downstream has dispatched the H2D
+        # copy by then; the pool's refcount guard protects aliased or
+        # in-flight buffers). Falls back to the decoder's own pool so
         # direct constructions recycle too.
         self.buffer_pool = (
             buffer_pool if buffer_pool is not None
@@ -364,9 +356,8 @@ class DataPipeline:
             warnings.warn(
                 "producers>1 has no effect with a WorkerPool: worker "
                 "processes already decode in parallel (and H2D lives in "
-                "the placement plane, or on the consumer thread for the "
-                "sync device_put_fn arm). Drop num_workers to use "
-                "producer threads instead.",
+                "the placement plane). Drop num_workers to use producer "
+                "threads instead.",
                 stacklevel=2,
             )
         if self.workers is not None and (
@@ -413,21 +404,10 @@ class DataPipeline:
                 # count already names the NEXT batch to serve (contract in
                 # the module docstring).
                 self._yielded += 1
-                host = batch
-                if self.device_put_fn is not None:
-                    # device_put on the consumer thread: enqueues an async H2D
-                    # DMA; the next decode proceeds in the producer meanwhile.
-                    batch = self.device_put_fn(host)
-                    # H2D dispatched: the pooled pages go back now (the
-                    # pool recycles only once jax drops its reference).
-                    self._release_host(host)
-                    host = None
                 yield batch
-                if host is not None:
-                    # Host-batch consumer (loader-only benches, tests): the
-                    # yield returned, the consumer had its turn — release;
-                    # any reference it kept defers recycling, not safety.
-                    self._release_host(host)
+                # The yield returned, the consumer had its turn — release;
+                # any reference it kept defers recycling, not safety.
+                self._release_host(batch)
         finally:
             stop.set()
             self._live.clear()
@@ -447,13 +427,7 @@ class DataPipeline:
         total buffered depth ≈ ``max(prefetch, producers)``. Daemon threads +
         the drain in ``finally`` mean a hung decode can never block
         interpreter exit (plain ``ThreadPoolExecutor`` workers would — its
-        atexit hook joins them).
-
-        ``device_put_fn`` runs IN the producer threads here (unlike the
-        single-producer path), so the host→device copy pipelines across
-        producers instead of serialising on the consumer. device_put is thread-safe and purely
-        data-dependent, so cross-thread dispatch order doesn't matter; the
-        consumer still yields in plan order."""
+        atexit hook joins them)."""
         n = self.producers
         per = max(1, -(-max(self.prefetch, n) // n))
         queues = [AdjustableQueue(per) for _ in range(n)]
@@ -472,17 +446,6 @@ class DataPipeline:
                     t0 = time.monotonic_ns()
                     with span("pipeline.decode", batch_seq=seq, producer=k):
                         out = self._decode_item(item)
-                        if self.device_put_fn is not None:
-                            host = out
-                            out = self.device_put_fn(host)
-                            # Leases return in the producer here — same
-                            # thread that dispatched the H2D copy, so the
-                            # page is back in the pool before this thread's
-                            # next decode leases one.
-                            self._release_host(host)
-                            del host
-                    # decode_ms here covers decode + device_put dispatch —
-                    # both run in the producer on this path.
                     decode_ms = (time.monotonic_ns() - t0) / 1e6
                     with span("pipeline.wait_out", batch_seq=seq,
                               producer=k):
@@ -519,24 +482,20 @@ class DataPipeline:
                 observe_local_lineage(self.registry, lineage)
                 self._yielded += 1
                 yield batch
-                if self.device_put_fn is None:
-                    # Host-batch consumers: release after the consumer's
-                    # turn (device batches were released in the producer).
-                    self._release_host(batch)
+                # Release after the consumer's turn.
+                self._release_host(batch)
         finally:
             stop.set()
             self._live.clear()
             # Drain so blocked put()s can observe the stop flag (releasing
-            # drained host batches' pool leases; device batches were
-            # released in their producer already).
+            # drained batches' pool leases).
             while any(t.is_alive() for t in threads):
                 for q in queues:
                     try:
                         item = q.get_nowait()
                     except queue.Empty:
                         continue
-                    if self.device_put_fn is None:
-                        self._release_drained(item)
+                    self._release_drained(item)
                 for t in threads:
                     t.join(timeout=0.05)
 
@@ -548,7 +507,6 @@ def make_train_pipeline(
     process_index: int,
     process_count: int,
     decode_fn: Callable,
-    device_put_fn: Optional[Callable] = None,
     prefetch: int = 2,
     check_deadlock: bool = True,
     workers=None,
@@ -580,7 +538,6 @@ def make_train_pipeline(
         Buffers,
         Cache,
         Decode,
-        DevicePut,
         InProcess,
         LanceSource,
         LoaderGraph,
@@ -597,7 +554,6 @@ def make_train_pipeline(
         Pool(workers),
         Buffers(buffer_pool),
         Prefetch(prefetch, producers=producers),
-        DevicePut(device_put_fn),
         InProcess(),
     )
     graph.compile()
@@ -611,7 +567,6 @@ def make_eval_pipeline(
     process_index: int,
     process_count: int,
     decode_fn: Callable,
-    device_put_fn: Optional[Callable] = None,
     *,
     prefetch: int = 2,
     producers: int = 1,
@@ -647,7 +602,6 @@ def make_eval_pipeline(
         Buffers,
         Cache,
         Decode,
-        DevicePut,
         EvalSource,
         InProcess,
         LoaderGraph,
@@ -661,7 +615,6 @@ def make_eval_pipeline(
         Cache(batch_cache, dataset_fingerprint=dataset_fingerprint),
         Buffers(buffer_pool),
         Prefetch(prefetch, producers=producers),
-        DevicePut(device_put_fn),
         InProcess(),
     )
     graph.compile()
@@ -669,7 +622,7 @@ def make_eval_pipeline(
 
 
 class MapStylePipeline:
-    """Random-access pipeline: permuted indices → ``take`` → decode → device.
+    """Random-access pipeline: permuted indices → ``take`` → decode.
 
     Parity with ``SafeLanceDataset`` + ``DistributedSampler`` +
     ``get_safe_loader`` (``/root/reference/lance_map_style.py:54-69``);
@@ -688,7 +641,6 @@ class MapStylePipeline:
         process_index: int,
         process_count: int,
         decode_fn: Callable,
-        device_put_fn: Optional[Callable] = None,
         *,
         shuffle: bool = True,
         seed: int = 0,
@@ -708,7 +660,6 @@ class MapStylePipeline:
         self.process_index = process_index
         self.process_count = process_count
         self.decode_fn = decode_fn
-        self.device_put_fn = device_put_fn
         self.shuffle = shuffle
         self.seed = seed
         self.epoch = epoch
@@ -825,7 +776,6 @@ class MapStylePipeline:
             self.dataset,
             self._index_batches(),
             self.decode_fn,
-            self.device_put_fn,
             self.prefetch,
             read_fn=_with_columns(_take_read, self.columns),
             workers=self.workers,
@@ -855,7 +805,6 @@ def make_map_style_pipeline(dataset: Dataset, *args, **kwargs) -> "LoaderGraph":
         Buffers,
         Cache,
         Decode,
-        DevicePut,
         InProcess,
         LoaderGraph,
         MapStyleSource,
@@ -881,7 +830,6 @@ def make_map_style_pipeline(dataset: Dataset, *args, **kwargs) -> "LoaderGraph":
         Pool(a["workers"]),
         Buffers(a["buffer_pool"]),
         Prefetch(a["prefetch"], producers=a["producers"]),
-        DevicePut(a["device_put_fn"]),
         InProcess(),
     )
     graph.compile()

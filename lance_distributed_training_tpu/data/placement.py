@@ -1,12 +1,13 @@
 """Placement plane: host batches → global device arrays, H2D off the step.
 
-Before r7 every loader ended the same way: the *consumer thread* called a
-private ``device_put_fn`` closure on each host batch, so the train step sat
-behind the H2D transfer it was about to consume — BENCH_AB_r05 measured
-~97% ``train_loader_stall_pct`` across all four 1-core arms, and a chunk of
-that stall was transfer, not decode. This module is the one shared exit
-from host memory (the alpa ``DataLoader`` pattern in SNIPPETS.md: per-device
-shards + ``prefetch_size`` device buffers):
+The one policy: *an engine yields host batches and never touches a device;
+this plane places them and returns their leases.* A step must not sit
+behind the H2D transfer of the batch it is about to consume, so the
+transfer is dispatched from a thread of its own, ahead of the consumer (on
+the chip the ring is full 81–98% of the window: PERF.md section 5,
+``placement.wait_ring``). This module is the one shared exit from host
+memory (the alpa ``DataLoader`` pattern in SNIPPETS.md: per-device shards +
+``prefetch_size`` device buffers):
 
 * :class:`PlacementPlane` — slices each host batch per **local device**
   along the mesh's data axis, dispatches one async ``device_put`` per
@@ -30,9 +31,8 @@ shards + ``prefetch_size`` device buffers):
   epoch e+1's producers start only after epoch e's are done.
 * :class:`PlacedLoader` — the thin wrapper ``trainer._build_loader`` puts
   around all five pipelines (``DataPipeline``, ``MapStylePipeline``,
-  ``FolderDataPipeline``, ``RemoteLoader``, ``FleetLoader``): they now
-  yield HOST batches and this plane owns placement, instead of five
-  private ``device_put_fn`` closures owning it five times.
+  ``FolderDataPipeline``, ``RemoteLoader``, ``FleetLoader``): they
+  yield HOST batches and this plane alone owns placement.
 
 Buffer-plane contract: the placement thread releases each host batch's
 :class:`~.buffers.BufferPool` leases immediately after the per-device

@@ -40,7 +40,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Callable, Iterator, Optional, Sequence
+from typing import Iterator, Optional, Sequence
 
 from ..obs.lineage import observe_wire_lineage
 from ..obs.registry import MetricsRegistry, default_registry
@@ -354,7 +354,6 @@ class FleetLoader:
         batch_size: int,
         process_index: int,
         process_count: int,
-        device_put_fn: Optional[Callable[[dict], dict]] = None,
         *,
         sampler_type: str = "batch",
         shuffle: bool = False,
@@ -385,7 +384,6 @@ class FleetLoader:
         self.batch_size = batch_size
         self.process_index = process_index
         self.process_count = process_count
-        self.device_put_fn = device_put_fn
         self.sampler_type = sampler_type
         self.shuffle = shuffle
         self.seed = seed
@@ -884,14 +882,8 @@ class FleetLoader:
                 if isinstance(item, BaseException):
                     raise item
                 self._yielded += 1
-                host = item
-                if self.device_put_fn is not None:
-                    item = self.device_put_fn(host)
-                    self._release(host)
-                    host = None
                 yield item
-                if host is not None:
-                    self._release(host)
+                self._release(item)
         finally:
             stop.set()
             self._live.clear()

@@ -77,11 +77,10 @@ COEFF_KEYS = (
 
 # Pinned host-vs-device parity envelope (max abs u8 difference) on the
 # canonical corpora (tests/test_device_decode.py, scripts/
-# device_decode_smoke.py, bench_device_decode.py): sources below the DCT
+# device_decode_smoke.py): sources below the DCT
 # draft threshold (< 2× target on both dims), so the host arm decodes at
 # full scale and the two arms differ only in IDCT method, one truncated
-# resize weight product, and the PIL-retry rows' requantisation. The bench
-# record stores the measured value next to this bound.
+# resize weight product, and the PIL-retry rows' requantisation.
 HOST_PARITY_MAX_ABS_DIFF = 16
 
 # 8-point DCT-III basis, 11-bit fixed point: B[x, u] = c(u)/2 ·

@@ -147,7 +147,7 @@ def make_global_batch(
 
     This is the *synchronous* placement primitive (and the bit-parity
     reference the placement plane's tests pin against); the trainer's
-    default path is :class:`~..data.placement.PlacementPlane`, which
+    path is :class:`~..data.placement.PlacementPlane`, which
     dispatches the same transfers from a background thread so they overlap
     the step. ``device_put`` routes through ``_compat`` — the one H2D door
     LDT801 allows outside ``data/placement.py``.

@@ -10,8 +10,7 @@ as the shard, so each training host streams exactly its slice of the
 global batch — no redundant bytes over the wire — and the trainer wraps
 this loader in the placement plane (:mod:`~..data.placement`), which
 assembles the NamedSharding global array with double-buffered async H2D.
-``device_put_fn`` remains the synchronous escape hatch
-(``--no_global_batch``).
+This loader yields host batches and never touches a device.
 
 Robustness: a background receiver thread prefetches frames into the same
 bounded-queue discipline ``DataPipeline`` uses; every received step is ACKed,
@@ -30,7 +29,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Callable, Iterator, Optional, Sequence
+from typing import Iterator, Optional, Sequence
 
 from ..obs.lineage import observe_wire_lineage
 from ..obs.registry import MetricsRegistry, default_registry
@@ -68,7 +67,6 @@ class RemoteLoader:
         batch_size: int,
         process_index: int,
         process_count: int,
-        device_put_fn: Optional[Callable[[dict], dict]] = None,
         *,
         sampler_type: str = "batch",
         shuffle: bool = False,
@@ -96,7 +94,6 @@ class RemoteLoader:
         self.batch_size = batch_size
         self.process_index = process_index
         self.process_count = process_count
-        self.device_put_fn = device_put_fn
         self.sampler_type = sampler_type
         self.shuffle = shuffle
         self.seed = seed
@@ -557,17 +554,10 @@ class RemoteLoader:
                 if isinstance(item, BaseException):
                     raise item
                 self._yielded += 1
-                host = item
-                if self.device_put_fn is not None:
-                    item = self.device_put_fn(host)
-                    # H2D dispatched: pooled pages go back (the pool's
-                    # refcount guard covers aliased / in-flight buffers).
-                    self._release(host)
-                    host = None
                 yield item
-                if host is not None:
-                    # Host-batch consumers: release after their turn.
-                    self._release(host)
+                # Release after the consumer's turn (the pool's refcount
+                # guard covers aliased / in-flight buffers).
+                self._release(item)
         finally:
             stop.set()
             self._live.clear()

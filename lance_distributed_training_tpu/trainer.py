@@ -44,7 +44,6 @@ from .obs.spans import phase as obs_phase
 from .parallel.mesh import (
     batch_sharding,
     get_mesh,
-    make_global_batch,
     maybe_initialize_distributed,
     process_topology,
     replicated_sharding,
@@ -183,19 +182,13 @@ class TrainConfig:
     # place of its own depth (one chip's share of a published model, at
     # every published width); 0 keeps the preset's
     prefetch: int = 2
-    producer_threads: int = 4  # decode-producer threads; with the placement
-    # plane off (--no_global_batch) these also pipeline the per-batch H2D
-    # copy across threads
-    global_batch: bool = True  # route every loader through the placement
-    # plane (data/placement.py): a dedicated thread slices each host batch
-    # per local device, dispatches async H2D, and keeps placement_depth
-    # device-resident global batches ahead of the step — next(loader)
-    # returns an already-transferred array. False = the pre-r7 control arm:
-    # a synchronous make_global_batch closure on the consumer thread
-    # (bit-identical batches, H2D counted inside loader stall).
+    producer_threads: int = 4  # decode-producer threads
     placement_depth: int = 2  # device-resident batches the placement ring
-    # keeps ahead of the step; 2 double-buffers (one consumed, one in
-    # flight), more pins extra HBM for little added overlap
+    # (data/placement.py: one thread slices each host batch per local
+    # device and dispatches async H2D, so next(loader) returns an
+    # already-transferred array) keeps ahead of the step; 2 double-buffers
+    # (one consumed, one in flight), more pins extra HBM for little added
+    # overlap
     autotune: bool = True  # closed-loop pipeline autotuning (tune/): a
     # background controller snapshots windowed obs/ deltas each interval,
     # attributes the bottleneck, and actuates live knobs — decode worker
@@ -731,13 +724,9 @@ def _make_worker_pool(config: TrainConfig, dataset, mesh=None):
 
 
 def _make_placement(config: TrainConfig, mesh):
-    """The run's :class:`~.data.placement.PlacementPlane` — ``None`` when
-    the synchronous control arm (``--no_global_batch``) is selected. One
-    plane per loader build; the plane shares the process BufferPool with
-    the decode side so leases released at transfer dispatch warm the next
-    decode."""
-    if not config.global_batch or mesh is None:
-        return None
+    """The run's :class:`~.data.placement.PlacementPlane`. One plane per
+    loader build; the plane shares the process BufferPool with the decode
+    side so leases released at transfer dispatch warm the next decode."""
     from .data.placement import PlacementPlane
 
     return PlacementPlane(
@@ -759,18 +748,9 @@ def _build_loader(config: TrainConfig, dataset, mesh, epoch: int = 0,
             f"{process_count} processes"
         )
     decode = _decoder_for(config, mesh=mesh)
-    # Placement: default is the async plane (host batches out of the
-    # pipelines, one placement thread owning H2D); the control arm keeps
-    # the legacy synchronous closure on the consumer thread.
+    # Placement: host batches out of the engines, one placement thread
+    # owning H2D.
     plane = _make_placement(config, mesh)
-    if plane is not None:
-        put = None
-    else:
-        put = partial(
-            make_global_batch,
-            mesh=mesh,
-            seq_axis="seq" if config.seq_parallelism > 1 else None,
-        )
 
     # Every arm is ONE LoaderGraph assembly (data/graph.py): the source/
     # transport choice is the only thing that varies; decode boundary,
@@ -779,7 +759,6 @@ def _build_loader(config: TrainConfig, dataset, mesh, epoch: int = 0,
         Buffers,
         Cache,
         Decode,
-        DevicePut,
         FleetTransport,
         FolderSource,
         InProcess,
@@ -793,18 +772,16 @@ def _build_loader(config: TrainConfig, dataset, mesh, epoch: int = 0,
     )
 
     def _assemble(source, decode_node, *mid):
-        nodes = [source, decode_node, *mid,
-                 Buffers(_loader_buffer_pool(config)), DevicePut(put)]
-        if plane is not None:
-            nodes.append(Place(plane))
-        graph = LoaderGraph(*nodes)
+        graph = LoaderGraph(source, decode_node, *mid,
+                            Buffers(_loader_buffer_pool(config)),
+                            Place(plane))
         graph.compile()
         return graph
 
     if config.data_service_addr or config.coordinator_addr:
         # Disaggregated input plane: decode runs in remote DataService
-        # processes; this process only streams host batches and dispatches
-        # device_put. The servers build the identical epoch Plan (same
+        # processes; this process only streams host batches and places
+        # them. The servers build the identical epoch Plan (same
         # LanceSource.shard_plans), so batches match local training
         # bit-for-bit on the same seed — whether one server
         # (ServiceTransport) or a coordinated fleet striped across N of
@@ -990,15 +967,6 @@ def _build_eval_loader(config: TrainConfig, dataset, mesh, index_pool=None,
 
     process_index, process_count = process_topology()
     decode = _decoder_for(config, for_eval=True)
-    plane = _make_placement(config, mesh)
-    if plane is not None:
-        put = None
-    else:
-        put = partial(
-            make_global_batch,
-            mesh=mesh,
-            seq_axis="seq" if config.seq_parallelism > 1 else None,
-        )
     if config.data_format == "folder":
         from .data.authoring import _folder_samples
         from .data.folder import read_sample_batch
@@ -1037,7 +1005,6 @@ def _build_eval_loader(config: TrainConfig, dataset, mesh, index_pool=None,
         process_index,
         process_count,
         decode,
-        put,
         prefetch=config.prefetch,
         producers=config.producer_threads,
         index_pool=index_pool,
@@ -1045,7 +1012,7 @@ def _build_eval_loader(config: TrainConfig, dataset, mesh, index_pool=None,
         batch_cache=batch_cache,
         dataset_fingerprint=dataset_fp,
     )
-    return plane.wrap(loader) if plane is not None else loader
+    return _make_placement(config, mesh).wrap(loader)
 
 
 # The package's parent directory: the repo root of a checkout. A fixed path,
@@ -1816,10 +1783,10 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             # Epoch handover (data/placement.py): the previous epoch's ring
             # may already be reading this epoch's loader, and then its
             # first batches are placed. Otherwise (first epoch, after a
-            # replay, the synchronous arm) the pipeline starts cold here.
-            take = getattr(loader, "take_successor", None)
-            loader = (take() if take is not None else None) or build_loader(
-                epoch=epoch)
+            # replay) the pipeline starts cold here.
+            loader = (
+                loader.take_successor() if loader is not None else None
+            ) or build_loader(epoch=epoch)
             if resume_step:
                 # Position the loader at the cursor: the rebuilt plan is
                 # deterministic, so the tail it serves is bit-identical to
@@ -1833,21 +1800,16 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 0 < config.max_steps <= global_step
                 + (len(loader) - resume_step) * max(config.data_echo, 1)
             )
-            if (
-                not ends_run
-                and not dev_cache.expects_replay()
-                and hasattr(loader, "set_successor")
-            ):
+            if not ends_run and not dev_cache.expects_replay():
                 loader.set_successor(partial(build_loader, epoch=epoch + 1))
         # RemoteLoader exposes ServiceCounters: merge its stall/queue window
         # into per-step progress lines so loader-stall% stays attributable
-        # (client receive stall vs server queue vs H2D vs device); a
-        # PlacedLoader additionally exposes the placement plane's counters
-        # (placement_h2d_s → the h2d_pct progress field). None detaches.
+        # (client receive stall vs server queue vs H2D vs device), next to
+        # the placement plane's counters (placement_h2d_s → the h2d_pct
+        # progress field). None (a device_cache replay epoch) detaches.
         timer.attach_counters(
-            getattr(loader, "counters", None) if loader is not None else None,
-            getattr(loader, "placement_counters", None)
-            if loader is not None else None,
+            loader.counters if loader is not None else None,
+            loader.placement_counters if loader is not None else None,
         )
         if tuner is not None:
             # Register this epoch's live knobs (the loader is rebuilt per
@@ -2050,7 +2012,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             journal.state = state
             journal.rng = rng
             journal.abs_step = resume_global_step + global_step
-            if loader is not None and hasattr(loader, "state_dict"):
+            if loader is not None:
                 cursor_base = dict(loader.state_dict())
                 cursor_base.setdefault("epoch", epoch)
             else:
@@ -2120,7 +2082,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             ),
             "loader_stall_pct": timer.loader_stall_pct,
         }
-        handover = getattr(loader, "handover", None)
+        handover = loader.handover if loader is not None else None
         if handover is not None:
             # The ring at this epoch's first next: "warm" (a batch was
             # already placed) or "cold" (the loop waited for the pipeline

@@ -10,8 +10,8 @@ registered as :class:`~.tunable.Tunable`\\ s — decode worker count
 (``WorkerPool.resize``), prefetch depth (all loaders), buffer-pool page
 budget, placement ring depth, fleet stripe width. Actuation changes
 *capacity*, never content: the batch stream stays bit-identical in value
-and order through any decision (pinned by the parity tests +
-``bench_autotune.py``), and ``--no_autotune`` runs the exact fixed-knob
+and order through any decision (pinned by the parity tests and
+``scripts/autotune_smoke.py``), and ``--no_autotune`` runs the exact fixed-knob
 pipeline of r8 and earlier.
 
 Decisions are deterministic and testable: set ``LDT_AUTOTUNE_TRACE=<path>``

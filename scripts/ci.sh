@@ -19,8 +19,8 @@
 # with exit 0, and /dev/shm must end clean.
 # Stage 5 — placement smoke (scripts/placement_smoke.py): 8 XLA-forced CPU
 # devices, a 2-simulated-process shard parity check, global batch
-# shape/sharding through the async placement plane (bit-identical to the
-# sync control arm), and trainer_h2d_ms / placement_buffer_depth on
+# shape/sharding through the async placement plane (bit-identical to
+# make_global_batch over the same host batches), and trainer_h2d_ms / placement_buffer_depth on
 # /metrics.
 # Stage 6 — preemption smoke (scripts/preempt_smoke.py): a real trainer
 # subprocess SIGKILLed after exactly N steps (deterministic chaos,
@@ -190,9 +190,9 @@ echo "== fleet smoke (coordinator + 2 servers, SIGKILL mid-stream) =="
 timeout -k 10 420 env JAX_PLATFORMS=cpu PYTHONPATH=. python scripts/fleet_smoke.py
 
 echo "== placement smoke (mesh-native global batches + H2D telemetry) =="
-# 2-simulated-process shard parity on 8 forced CPU devices
-# (_bench_init.force_cpu), placed-vs-sync bit parity,
-# and the trainer_h2d_ms series scraped from a live /metrics.
+# 2-simulated-process shard parity on 8 forced CPU devices (the script
+# sets JAX_PLATFORMS and XLA_FLAGS itself), placed-vs-make_global_batch
+# bit parity, and the trainer_h2d_ms series scraped from a live /metrics.
 timeout -k 10 300 env PYTHONPATH=. python scripts/placement_smoke.py
 
 echo "== preemption smoke (SIGKILL resume fidelity + SIGTERM drain) =="

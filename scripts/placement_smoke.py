@@ -6,8 +6,8 @@ What it asserts (the r7 acceptance surface, in one short run):
 1. the trainer's default loader path yields **global** ``jax.Array``
    batches — full global shape, ``P('data')`` sharding, per-device shards
    of ``batch/8`` rows — through the async placement plane;
-2. the placed stream is **bit-identical** to the synchronous
-   ``make_global_batch`` control arm (``--no_global_batch``);
+2. the placed stream is **bit-identical** to the reference function
+   ``make_global_batch`` applied to the same host batches;
 3. two *simulated* training processes (process_index 0 and 1 of 2 — real
    multi-process needs a jax.distributed rendezvous CI doesn't have)
    produce disjoint host shards whose concatenation equals the
@@ -30,9 +30,13 @@ import shutil
 import tempfile
 import urllib.request
 
-from _bench_init import force_cpu
-
-force_cpu(8)
+# Before the first ``import jax``, as tests/conftest.py does.
+os.environ["JAX_PLATFORMS"] = "cpu"
+_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _flags:
+    os.environ["XLA_FLAGS"] = (
+        _flags + " --xla_force_host_platform_device_count=8"
+    ).strip()
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
@@ -75,17 +79,18 @@ def main() -> None:
     mesh = get_mesh()
     decode = ImageClassificationDecoder(image_size=32)
     try:
-        # 1+2: placed global batches, bit-identical to the sync arm.
+        # 1+2: placed global batches, bit-identical to the reference
+        # function over the same host batches.
         plane = PlacementPlane(mesh, depth=2)
         placed = list(plane.wrap(
             make_train_pipeline(dataset, "batch", BATCH, 0, 1, decode)
         ))
-        sync = list(make_train_pipeline(
-            dataset, "batch", BATCH, 0, 1, decode,
-            device_put_fn=lambda b: make_global_batch(b, mesh),
+        host_full = list(make_train_pipeline(
+            dataset, "batch", BATCH, 0, 1, decode
         ))
-        assert placed and len(placed) == len(sync)
-        for got, want in zip(placed, sync):
+        want_full = [make_global_batch(b, mesh) for b in host_full]
+        assert placed and len(placed) == len(want_full)
+        for got, want in zip(placed, want_full):
             assert got["image"].shape == (BATCH, 32, 32, 3)
             assert got["image"].sharding.spec == P("data"), (
                 got["image"].sharding
@@ -100,9 +105,6 @@ def main() -> None:
 
         # 3: two simulated processes — disjoint shards that reassemble the
         # single-process stream, and a disjoint covering stripe mapping.
-        host_full = list(make_train_pipeline(
-            dataset, "batch", BATCH, 0, 1, decode
-        ))
         shards = [
             list(make_train_pipeline(dataset, "batch", BATCH // 2, p, 2,
                                      decode))

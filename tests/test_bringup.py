@@ -3,7 +3,6 @@ which device it ran on, fall back without saying so, or claim a backend
 from a process that is not the chip's holder. CPU-only twins of what
 ``chip_smoke.py`` checks on the TPU."""
 
-import json
 import os
 import subprocess
 import sys
@@ -177,7 +176,7 @@ def test_importing_the_package_initialises_no_backend():
         "import lance_distributed_training_tpu.cli\n"
         "import lance_distributed_training_tpu.data.workers\n"
         "import lance_distributed_training_tpu.service.server\n"
-        "import chip_smoke, bench, bench_suite, _bench_init\n"
+        "import chip_smoke\n"
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge.backends_are_initialized()\n"
     )
@@ -209,49 +208,23 @@ def test_serve_data_serves_without_a_backend(image_dataset):
     assert proc.returncode == 0, proc.stderr[-2000:]
 
 
-# -- bench entry points ------------------------------------------------------
+# -- the benchmark's recorded fixtures ----------------------------------------
 
 
-def test_bench_claim_raises_off_tpu_unless_cpu_was_asked_for():
-    proc = _python(
-        "import _bench_init as b\n"
-        "try:\n"
-        "    b.init_devices()\n"
-        "except RuntimeError as e:\n"
-        "    assert \"platform='cpu'\" in str(e), e\n"
-        "else:\n"
-        "    raise SystemExit('claimed a CPU without being asked')\n"
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    proc = _python(
-        "import _bench_init as b\n"
-        "b.force_cpu(2)\n"
-        "_, devices = b.init_devices()\n"
-        "assert len(devices) == 2 and devices[0].platform == 'cpu'\n"
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-
-
-def test_bench_peak_is_an_error_for_an_unknown_device_kind(root_scripts):
-    import bench
-
-    assert bench.peak_tflops_for("TPU v5 lite") == 197.0
-    with pytest.raises(RuntimeError, match="cpu"):
-        bench.peak_tflops_for("cpu")
-
-
-def test_bench_suite_exits_nonzero_when_a_child_fails():
-    """No accelerator here and none asked for: the child fails its claim,
-    leaves its structured line, and the parent's exit code says so."""
+@pytest.mark.parametrize("script,closing", [
+    ("check_reduce.py", "reduction ok"),
+    ("check_scopes.py", "scopes ok"),
+])
+def test_benchmark_fixture_check_passes(script, closing):
+    """The reducers the ledger's per-layer numbers come from still read
+    their recorded traces as they did when the fixtures were taken."""
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench_suite.py"), "c4-bert"],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "BENCH_SMALL": "1"},
+        [sys.executable, os.path.join(REPO, "benchmark", script)], cwd=REPO,
+        capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
-    assert proc.returncode != 0
-    record = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert record["metric"] == "c4-bert"
-    assert "platform='cpu'" in record["error"]["last_error"]
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == closing
 
 
 # -- chip_smoke.py -----------------------------------------------------------

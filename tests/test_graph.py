@@ -22,7 +22,6 @@ from lance_distributed_training_tpu.data.graph import (
     Buffers,
     Cache,
     Decode,
-    DevicePut,
     EvalSource,
     FleetTransport,
     FolderSource,
@@ -242,7 +241,7 @@ def test_parity_lance_iterable(image_dataset, tmp_path, cache_on):
     try:
         plan = make_plan("batch", image_dataset.fragment_rows(), 16, 0, 1,
                          shuffle=False, seed=0, epoch=0)
-        legacy = DataPipeline(image_dataset, plan, _decoder(), None, 2)
+        legacy = DataPipeline(image_dataset, plan, _decoder(), 2)
         full = _digests(legacy)
         assert len(full) >= 4
         assert _digests(graph()) == full
@@ -251,8 +250,7 @@ def test_parity_lance_iterable(image_dataset, tmp_path, cache_on):
         head, cursor = _consume(graph(), 2)
         assert head == full[:2] and cursor == {"step": 2}
         assert _digests(graph(resume=2)) == full[2:]
-        legacy_resumed = DataPipeline(image_dataset, plan, _decoder(),
-                                      None, 2)
+        legacy_resumed = DataPipeline(image_dataset, plan, _decoder(), 2)
         legacy_resumed.load_state_dict(cursor)
         assert _digests(legacy_resumed) == full[2:]
     finally:
@@ -276,7 +274,7 @@ def test_parity_map_style(image_dataset, tmp_path, cache_on):
 
     try:
         legacy = MapStylePipeline(image_dataset, 16, 0, 1, _decoder(),
-                                  None, seed=7,
+                                  seed=7,
                                   columns=["image", "label"],
                                   batch_cache=cache)
         full = _digests(legacy)
@@ -287,7 +285,7 @@ def test_parity_map_style(image_dataset, tmp_path, cache_on):
         assert _digests(graph(resume=2)) == full[2:]
         # set_epoch reshuffles identically through both paths
         reshuffled = MapStylePipeline(image_dataset, 16, 0, 1, _decoder(),
-                                      None, seed=7,
+                                      seed=7,
                                       columns=["image", "label"])
         reshuffled.set_epoch(3)
         g2 = graph()
@@ -398,7 +396,7 @@ def test_parity_device_decode(image_dataset, tmp_path, cache_on):
     try:
         plan = make_plan("batch", image_dataset.fragment_rows(), 16, 0, 1,
                          shuffle=False, seed=0, epoch=0)
-        full = _digests(DataPipeline(image_dataset, plan, dec(), None, 2))
+        full = _digests(DataPipeline(image_dataset, plan, dec(), 2))
         assert _digests(graph()) == full
         if cache_on:
             assert _digests(graph()) == full
@@ -442,7 +440,7 @@ def test_parity_token_pack(tmp_path, cache_on):
     try:
         plan = make_plan("batch", ds.fragment_rows(), 16, 0, 1,
                          shuffle=False, seed=0, epoch=0)
-        full = _digests(DataPipeline(ds, plan, dec(), None, 2))
+        full = _digests(DataPipeline(ds, plan, dec(), 2))
         assert len(full) >= 4
         assert _digests(graph()) == full
         assert _digests(graph(resume=2)) == full[2:]

@@ -113,9 +113,22 @@ def _relative(got, want) -> float:
     return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
 
 
-def _spread_error(task, ref, variables, batch) -> float:
+def _eager(fn, *args):
+    return fn(*args)
+
+
+def _one_program(fn, *args):
+    """``fn`` as one jitted program, waited for. For the interpreted
+    kernel: its callbacks dispatch operations of their own, an eager caller
+    goes on dispatching from the test's thread meanwhile, and on the CPU's
+    one execution queue the two can wait for each other for good (one run
+    in five of the whole suite under six workers stopped here)."""
+    return jax.block_until_ready(jax.jit(fn)(*args))
+
+
+def _spread_error(task, ref, variables, batch, run=_eager) -> float:
     """The benchmark's statistic (``benchmark/run.py`` ``check_model``)."""
-    got = task.forward(variables, batch, False, None)[0][0]
+    got = run(lambda v: task.forward(v, batch, False, None)[0][0], variables)
     want = ref.forward(variables, batch)
     live = ref.live(batch, want)[..., None]
     n = live.sum() * want.shape[-1]
@@ -148,12 +161,16 @@ def reference_grads(ref, variables, batch):
     return _groups(jax.grad(lambda v: ref.loss(v, batch))(variables))
 
 
-def _program_grads(task, variables, batch):
+def _program_loss(task, batch):
     def loss(v):
         outputs, _ = task.forward(v, batch, True, None)
         return task.loss(outputs, batch)
 
-    return _groups(jax.grad(loss)(variables))
+    return loss
+
+
+def _program_grads(task, variables, batch, run=_eager):
+    return _groups(run(jax.grad(_program_loss(task, batch)), variables))
 
 
 GROUPS = ("router", "w_gate", "w_up", "w_down", "query", "key", "value",
@@ -315,7 +332,8 @@ def kernel_grads(kernel_task, variables, kernel_batch):
     from jax.experimental.pallas import tpu as pltpu
 
     with pltpu.force_tpu_interpret_mode():
-        return _program_grads(kernel_task, variables, kernel_batch)
+        return _program_grads(kernel_task, variables, kernel_batch,
+                              run=_one_program)
 
 
 @pytest.fixture(scope="module")
@@ -328,10 +346,10 @@ def test_logits_and_loss_with_the_flash_kernel_match_reference(
     from jax.experimental.pallas import tpu as pltpu
 
     with pltpu.force_tpu_interpret_mode():
-        assert _spread_error(
-            kernel_task, ref, variables, kernel_batch) < F32_TOL
-        outputs, _ = kernel_task.forward(variables, kernel_batch, True, None)
-    got = kernel_task.loss(outputs, kernel_batch)
+        assert _spread_error(kernel_task, ref, variables, kernel_batch,
+                             run=_one_program) < F32_TOL
+        got = _one_program(_program_loss(kernel_task, kernel_batch),
+                           variables)
     want = ref.loss(variables, kernel_batch)
     assert abs(float(got) - float(want)) < F32_TOL * float(want)
 

@@ -11,11 +11,12 @@ from lance_distributed_training_tpu.data import (
     DataPipeline,
     ImageClassificationDecoder,
     MapStylePipeline,
+    PlacementPlane,
     make_train_pipeline,
     numeric_decoder,
     write_dataset,
 )
-from lance_distributed_training_tpu.parallel import get_mesh, make_global_batch
+from lance_distributed_training_tpu.parallel import get_mesh
 
 
 def test_decoder_shapes_and_dtypes(image_table):
@@ -52,11 +53,10 @@ def test_two_process_batches_disjoint(image_dataset):
 def test_pipeline_device_put_sharded(image_dataset):
     mesh = get_mesh()
     assert len(jax.devices()) == 8  # conftest forced 8 CPU devices
-    pipe = make_train_pipeline(
+    pipe = PlacementPlane(mesh).wrap(make_train_pipeline(
         image_dataset, "batch", 16, 0, 1,
         ImageClassificationDecoder(image_size=32),
-        device_put_fn=lambda b: make_global_batch(b, mesh),
-    )
+    ))
     batch = next(iter(pipe))
     assert isinstance(batch["image"], jax.Array)
     assert batch["image"].sharding.spec == P("data")

@@ -173,23 +173,20 @@ def test_fresh_client_resumes_from_explicit_cursor(image_dataset, service):
 
 
 def test_device_put_contract(image_dataset, service):
-    """With device_put_fn bound, the trainer-visible contract is the same
-    sharded global jax.Array as every other loader."""
+    """Behind the placement plane, the trainer-visible contract is the
+    same sharded global jax.Array as every other loader."""
     import jax
     from jax.sharding import PartitionSpec as JP
 
-    from lance_distributed_training_tpu.parallel import (
-        get_mesh,
-        make_global_batch,
-    )
+    from lance_distributed_training_tpu.data import PlacementPlane
+    from lance_distributed_training_tpu.parallel import get_mesh
 
-    mesh = get_mesh()
-    loader = _loader(
-        service, device_put_fn=lambda b: make_global_batch(b, mesh)
-    )
+    loader = PlacementPlane(get_mesh()).wrap(_loader(service))
     batch = next(iter(loader))
     assert isinstance(batch["image"], jax.Array)
     assert batch["image"].sharding.spec == JP("data")
+    # 16 rows over 8 devices -> shard of 2 per device.
+    assert batch["image"].addressable_shards[0].data.shape[0] == 2
 
 
 def test_early_stop_drains_cleanly(image_dataset, service):

@@ -447,7 +447,7 @@ def test_placement_passes_host_meta_and_replicates_ragged():
     assert np.array_equal(
         np.asarray(values), batch["input_ids" + VALUES_SUFFIX]
     )
-    # make_global_batch (the --no_global_batch arm) agrees bit-for-bit.
+    # make_global_batch (the reference function) agrees bit-for-bit.
     global_batch = make_global_batch(batch, mesh)
     for k in batch:
         assert np.array_equal(np.asarray(placed[k]),
