@@ -21,7 +21,7 @@ whether ``block_until_ready`` waits. The last line of stdout is one JSON
 object, printed only when every check passed; any failure exits non-zero.
 
 ``--also`` adds opt-in checks, each a few steps through the same CLI:
-``flash`` (Pallas attention against dense, bert_base at seq 512),
+``flash`` (Pallas attention, forced and chosen, against dense, bert_base at seq 512),
 ``device_decode`` and ``token_pack`` (device kernels against their host
 twins), ``workers`` (spawned decode workers beside the chip's holder),
 ``service`` (a ``serve-data`` child on the trainer's host) and
@@ -406,17 +406,32 @@ def also_flash(workdir: str, ctx: dict) -> str:
             "--task_type", "masked_lm", "--model_name", ctx["text_model"],
             "--seq_len", str(seq), "--batch_size", str(batch),
             "--epochs", "1", "--max_steps", str(steps), "--no_eval_at_end"]
+    from lance_distributed_training_tpu.ops import flash as flash_ops
+
     res_f, trace_f, _, secs_f = run_train(
         workdir, "flash", argv + ["--flash_attention"])
     flash = check_losses(trace_f, res_f, steps)
-    res_d, trace_d, _, secs_d = run_train(workdir, "dense", argv)
+    # without the flag the rule picks the kernel too (since PR 29)
+    res_c, trace_c, _, secs_c = run_train(workdir, "chosen", argv)
+    chosen = check_losses(trace_c, res_c, steps)
+    check(res_c.get("attention_fused") == float(ctx["backend"] == "tpu"),
+          "without the flag the rule chose the kernel at 512 x 64 "
+          f"(attention_fused={res_c.get('attention_fused')})")
+    rule = flash_ops.fused_attention_applies
+    flash_ops.fused_attention_applies = lambda *a, **k: False  # dense arm
+    try:
+        res_d, trace_d, _, secs_d = run_train(workdir, "dense", argv)
+    finally:
+        flash_ops.fused_attention_applies = rule
     dense = check_losses(trace_d, res_d, steps)
-    rel = abs(flash[0] - dense[0]) / abs(dense[0])
-    check(rel <= 2e-2,
-          f"step-1 loss flash {flash[0]:.5f} vs dense {dense[0]:.5f} "
-          f"(rel {rel:.2e} <= 2e-2)")
-    return (f"flash {secs_f:.0f}s dense {secs_d:.0f}s; losses flash "
-            f"{[round(x, 4) for x in flash]} dense "
+    for name, arm in (("flash", flash), ("chosen", chosen)):
+        rel = abs(arm[0] - dense[0]) / abs(dense[0])
+        check(rel <= 2e-2,
+              f"step-1 loss {name} {arm[0]:.5f} vs dense {dense[0]:.5f} "
+              f"(rel {rel:.2e} <= 2e-2)")
+    return (f"flash {secs_f:.0f}s chosen {secs_c:.0f}s dense {secs_d:.0f}s; "
+            f"losses flash {[round(x, 4) for x in flash]} chosen "
+            f"{[round(x, 4) for x in chosen]} dense "
             f"{[round(x, 4) for x in dense]}")
 
 

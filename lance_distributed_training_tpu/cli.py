@@ -297,8 +297,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--moe_every", type=int, default=2,
                    help="MoE MLP on every Nth block")
     p.add_argument("--flash_attention", action="store_true",
-                   help="Pallas fused attention kernel on a TPU; off a TPU "
-                        "the flag selects exact dense attention")
+                   help="force the Pallas fused attention kernel on a TPU "
+                        "(the kernel or an error; off a TPU exact dense "
+                        "attention). Without the flag a sequence model gets "
+                        "the kernel where its shapes and mesh allow it "
+                        "(ops/flash.py fused_attention_applies)")
     p.add_argument("--checkpoint_dir", type=str, default=None,
                    help="orbax checkpoint root; resumes from the latest "
                         "checkpoint when one exists")

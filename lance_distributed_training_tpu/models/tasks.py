@@ -641,6 +641,15 @@ def get_task(
             num_classes, model_name or "resnet50", image_size, augment,
             param_dtype=param_dtype,
         )
+    if (task_type in ("masked_lm", "causal_lm") and attention_fn is None
+            and pipeline_parallelism <= 1):
+        # Nobody chose an attention path (no --flash_attention, no ring):
+        # the fused kernel where the shapes each call sees and the mesh
+        # allow it, dense attention elsewhere (ops/flash.py has the rule).
+        from ..ops.flash import make_flash_attention
+
+        attention_fn = make_flash_attention(
+            causal=task_type == "causal_lm", mesh=mesh, forced=False)
     if task_type == "masked_lm":
         if pipeline_parallelism > 1:
             if attention_fn is not None or num_experts:

@@ -175,10 +175,11 @@ class EncoderBlock(nn.Module):
     def __call__(self, x, mask=None, segment_ids=None):
         norm = partial(nn.LayerNorm, dtype=self.dtype, param_dtype=jnp.float32)
         y = norm(name="ln_attn")(x)
-        y = SelfAttention(self.num_heads, self.dtype,
-                          attention_fn=self.attention_fn,
-                          causal=self.causal, name="attn")(y, mask,
-                                                           segment_ids)
+        with jax.named_scope("attention"):
+            y = SelfAttention(self.num_heads, self.dtype,
+                              attention_fn=self.attention_fn,
+                              causal=self.causal, name="attn")(y, mask,
+                                                               segment_ids)
         x = x + y
         y = norm(name="ln_mlp")(x)
         if self.num_experts > 0:

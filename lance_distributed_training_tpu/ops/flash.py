@@ -1,33 +1,41 @@
-"""Pallas flash attention — fused TPU attention for the transformer tasks.
+"""Fused attention for the transformer tasks: the score matrix stays in VMEM.
 
-The reference has no attention at all (vision-only); this framework's text
-arm defaults to XLA einsum attention (:func:`..models.transformer.
-dot_product_attention`), which materialises the [B, H, S, S] score matrix in
-HBM. For long sequences the fused Pallas kernel
-(``jax.experimental.pallas.ops.tpu.flash_attention``, forward + backward)
-keeps scores in VMEM tiles instead — O(S) HBM traffic, the standard
-flash-attention memory profile — and runs on the MXU via Mosaic.
+Dense attention (:func:`..models.transformer.dot_product_attention`) writes
+an f32 ``[B, H, S, S]`` score tensor to HBM, reads it back for the softmax
+and keeps the weights for the backward pass. Two Pallas kernels avoid that:
+
+* :func:`short_attention`, this file's own, for sequences of up to
+  ``SHORT_SEQ`` tokens (BERT's 512): a head's whole row block of scores fits
+  VMEM, so the softmax is the plain one and the backward pass is one kernel
+  that keeps nothing but q, k and v;
+* the library's blocked kernel with online softmax
+  (``jax.experimental.pallas.ops.tpu.flash_attention``) for longer ones
+  (OLMoE's 4,096).
 
 ``make_flash_attention()`` returns a drop-in ``attention_fn`` for
-:class:`..models.transformer.SelfAttention`:
+:class:`..models.transformer.SelfAttention`, in two strengths:
 
-* on TPU: the Pallas kernel, or an error if it cannot be had; the
-  key-validity mask is lowered to segment ids (valid tokens form segment 1,
-  padding segment 0, so valid queries never attend padding; padding queries
-  attend only padding, and their outputs are dead — the MLM loss masks them),
-* off TPU (CPU tests, simulated meshes): exact dense attention, chosen by
-  the platform and never by a failed import.
+* ``forced`` (``--flash_attention``): on a TPU the kernel, or an error if it
+  cannot be had; off a TPU (CPU tests, simulated meshes) exact dense
+  attention, chosen by the platform and never by a failed import;
+* not forced (what ``models.get_task`` binds when nobody chose an attention
+  path): the kernel where :func:`fused_attention_applies` allows it for the
+  shapes a call sees, dense attention elsewhere.
 
-Composition note: the kernel sees one device's tile; over a data- (and
+The key-validity mask is lowered to segment ids (valid tokens form segment
+1, padding segment 0, so valid queries never attend padding; padding queries
+attend only padding, and their outputs are dead: the loss masks them);
+packed rows pass their segment ids straight through.
+
+Composition note: a kernel sees one device's tile; over a data- (and
 tensor-) parallel mesh it runs under ``shard_map``, since XLA cannot
 partition a Mosaic call. For sequence parallelism use
-:mod:`..parallel.ring_attention` instead — the two are alternative
-``attention_fn`` values, selected by the trainer (``--flash_attention`` vs
-``--seq_parallelism``).
+:mod:`..parallel.ring_attention` instead, which ``--seq_parallelism`` binds.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -35,7 +43,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["make_flash_attention", "segment_attention_mask"]
+__all__ = ["make_flash_attention", "segment_attention_mask",
+           "short_attention"]
 
 
 def segment_attention_mask(segment_ids: jax.Array) -> jax.Array:
@@ -53,15 +62,255 @@ def segment_attention_mask(segment_ids: jax.Array) -> jax.Array:
     return same & live
 
 
+# -- the short-sequence kernel ------------------------------------------------
+#
+# Up to SHORT_SEQ keys a head's whole score row block [block_q, S] lives in
+# VMEM (1 MB in f32 at 512 x 512), so the softmax is the plain one: no online
+# rescaling, no statistics kept for the backward pass. The backward kernel
+# recomputes the weights from q and k once and makes dq, dk and dv from that
+# one recomputation (five products a head where the library's dq and dkv
+# kernels make seven and exponentiate twice); its residuals are q, k and v.
+# The kernels read and write ``[B, S, H * D]``, the layout the projections
+# produce and consume, so no transpose or 64-lane-padded copy stands between
+# them: a grid step takes one row's slab of several heads, a head is a lane
+# slice of it, and the mask, which is the same for all of them, is built once
+# a step. Arithmetic as the dense path's: bf16 operands, f32 accumulation,
+# f32 softmax, weights rounded to bf16 for their products.
+
+SHORT_SEQ = 1024
+_LANES, _SUBLANES = 128, 8
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)  # not -inf: exp is finite
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _score_bias(qid_ref, kid_ref, start, rows, seq, causal):
+    """Additive f32 ``[rows, seq]`` mask of the queries ``start..start+rows``
+    (0 where a query may attend a key: same segment id, and not ahead of it
+    when causal), or None where every pair may."""
+    mask = None
+    if qid_ref is not None:
+        q_ids = jnp.tile(qid_ref[0, start:start + rows, :],
+                         (1, seq // _LANES))  # [rows, seq]
+        mask = q_ids == kid_ref[0, :1, :]  # against [1, seq]
+    if causal:
+        row = start + jax.lax.broadcasted_iota(jnp.int32, (rows, seq), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, seq), 1)
+        mask = col <= row if mask is None else mask & (col <= row)
+    return None if mask is None else jnp.where(mask, 0.0, _MASKED)
+
+
+def _weights(q, k, bias, scale):
+    """Softmax weights ``[rows, seq]`` in f32 of one head's query block."""
+    s = jax.lax.dot_general(q, k, _NT,
+                            preferred_element_type=jnp.float32) * scale
+    if bias is not None:
+        s = s + bias
+    p = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
+    return p * (1.0 / jnp.sum(p, axis=1, keepdims=True))
+
+
+def _split_refs(refs, n_in, has_ids):
+    ins, rest = refs[:n_in], refs[n_in:]
+    ids = rest[:2] if has_ids else (None, None)
+    return ins, ids, rest[2 if has_ids else 0:]
+
+
+def _short_fwd_kernel(*refs, scale, causal, block_q, dim, has_ids):
+    (q_ref, k_ref, v_ref), (qid_ref, kid_ref), (o_ref,) = _split_refs(
+        refs, 3, has_ids)
+    seq, width = q_ref.shape[1], q_ref.shape[2]
+    for start in range(0, seq, block_q):
+        rows = slice(start, start + block_q)
+        bias = _score_bias(qid_ref, kid_ref, start, block_q, seq, causal)
+        for lanes in (slice(at, at + dim) for at in range(0, width, dim)):
+            v = v_ref[0, :, lanes]
+            p = _weights(q_ref[0, rows, lanes], k_ref[0, :, lanes], bias,
+                         scale)
+            o_ref[0, rows, lanes] = jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32
+            ).astype(o_ref.dtype)
+
+
+def _short_bwd_kernel(*refs, scale, causal, block_q, dim, has_ids):
+    ((q_ref, k_ref, v_ref, do_ref), (qid_ref, kid_ref),
+     (dq_ref, dk_ref, dv_ref, *acc)) = _split_refs(refs, 4, has_ids)
+    seq, width = q_ref.shape[1], q_ref.shape[2]
+    for start in range(0, seq, block_q):
+        rows = slice(start, start + block_q)
+        bias = _score_bias(qid_ref, kid_ref, start, block_q, seq, causal)
+        for lanes in (slice(at, at + dim) for at in range(0, width, dim)):
+            q, do = q_ref[0, rows, lanes], do_ref[0, rows, lanes]
+            k, v = k_ref[0, :, lanes], v_ref[0, :, lanes]
+            p = _weights(q, k, bias, scale)
+            dp = jax.lax.dot_general(do, v, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - jnp.sum(p * dp, axis=1, keepdims=True))
+                  ).astype(q.dtype)  # the scale goes on the products of it
+            dq_ref[0, rows, lanes] = (scale * jnp.dot(
+                ds, k, preferred_element_type=jnp.float32)
+            ).astype(dq_ref.dtype)
+            dv = jax.lax.dot_general(p.astype(do.dtype), do, _TN,
+                                     preferred_element_type=jnp.float32)
+            dk = scale * jax.lax.dot_general(
+                ds, q, _TN, preferred_element_type=jnp.float32)
+            if acc:  # several query blocks: summed in f32 scratch
+                dk_acc, dv_acc = acc
+                if start:
+                    dk, dv = dk + dk_acc[:, lanes], dv + dv_acc[:, lanes]
+                if start + block_q < seq:
+                    dk_acc[:, lanes], dv_acc[:, lanes] = dk, dv
+                    continue
+            dk_ref[0, :, lanes] = dk.astype(dk_ref.dtype)
+            dv_ref[0, :, lanes] = dv.astype(dv_ref.dtype)
+
+
+def _heads_per_step(heads: int, seq: int, dim: int) -> int:
+    """Heads a grid step takes: a divisor of ``heads`` whose lanes are whole
+    128-lane tiles (or all of them), the most with up to 2,048 query rows a
+    step, else the fewest."""
+    fit = [g for g in range(1, heads + 1)
+           if heads % g == 0 and (g * dim % _LANES == 0 or g == heads)]
+    return max((g for g in fit if g * seq <= 2048), default=fit[0])
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3, 4, 5, 6, 7, 8))
+def _short_call(kernel_fn, arrays, ids, n_out, heads, causal, block_q,
+                products, heads_per_step=None):
+    """One of the two kernels over ``[B, S, H * D]`` arrays, the layout the
+    projections write and read: a grid step takes one row's ``[S, G * D]``
+    slab of G heads, and a head is a lane slice of it. Jitted, so that a
+    model's layers share one trace and one Mosaic lowering of each kernel
+    (a warm start still traces and lowers its programs: 24 calls a BERT
+    step, 0.1 s of host time each when every layer lowers its own)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, width = arrays[0].shape
+    d = width // heads
+    g = heads_per_step or _heads_per_step(heads, s, d)
+    block_q = min(block_q, s)
+    if s % _LANES or s % block_q or heads % g:
+        raise ValueError(
+            f"short-sequence attention takes sequences that are multiples "
+            f"of {_LANES} (and of block_q {block_q}); got {s}")
+    tile = pl.BlockSpec((1, s, g * d), lambda i, j: (i, 0, j))
+    in_specs, operands = [tile] * len(arrays), list(arrays)
+    if ids is not None:
+        # as the library lays them out: the queries' ids down the sublanes
+        # of a lane-wide tile, the keys' ids along the lanes
+        operands += [jnp.broadcast_to(ids[:, :, None], (b, s, _LANES)),
+                     jnp.broadcast_to(ids[:, None, :], (b, _SUBLANES, s))]
+        in_specs += [pl.BlockSpec((1, s, _LANES), lambda i, j: (i, 0, 0)),
+                     pl.BlockSpec((1, _SUBLANES, s), lambda i, j: (i, 0, 0))]
+    scratch = []
+    if n_out == 3 and block_q < s:
+        scratch = [pltpu.VMEM((s, g * d), jnp.float32)] * 2
+    shape = jax.ShapeDtypeStruct(arrays[0].shape, arrays[0].dtype)
+    out = pl.pallas_call(
+        functools.partial(kernel_fn, scale=1.0 / float(d) ** 0.5,
+                          causal=causal, block_q=block_q, dim=d,
+                          has_ids=ids is not None),
+        grid=(b, heads // g), in_specs=in_specs,
+        out_specs=[tile] * n_out, out_shape=[shape] * n_out,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 * 2 ** 20),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * products * b * s * s * width,
+            transcendentals=b * heads * s * s,
+            bytes_accessed=(len(arrays) + n_out) * b * s * width
+            * arrays[0].dtype.itemsize),
+        name=kernel_fn.__name__.strip("_").removesuffix("_kernel"),
+    )(*operands)
+    return out[0] if n_out == 1 else tuple(out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _short_attention(q, k, v, ids, heads, causal, block_q, heads_per_step):
+    return _short_call(_short_fwd_kernel, (q, k, v), ids, 1, heads, causal,
+                       block_q, 2, heads_per_step)
+
+
+def _short_attention_fwd(q, k, v, ids, heads, causal, block_q,
+                         heads_per_step):
+    out = _short_attention(q, k, v, ids, heads, causal, block_q,
+                           heads_per_step)
+    return out, (q, k, v, ids)
+
+
+def _short_attention_bwd(heads, causal, block_q, heads_per_step, residuals,
+                         do):
+    q, k, v, ids = residuals
+    return *_short_call(_short_bwd_kernel, (q, k, v, do.astype(q.dtype)),
+                        ids, 3, heads, causal, block_q, 5,
+                        heads_per_step), None
+
+
+_short_attention.defvjp(_short_attention_fwd, _short_attention_bwd)
+
+
+def short_attention(q, k, v, segment_ids=None, *, causal: bool = False,
+                    block_q: int = 512, heads_per_step: Optional[int] = None):
+    """Fused attention for sequences of up to ``SHORT_SEQ`` tokens: q, k, v
+    ``[B, H, S, D]`` (S a multiple of 128), ``segment_ids`` ``[B, S]`` int32
+    or None (a token attends the tokens of its own id), output like q. The
+    score matrix never leaves VMEM, forward or backward.
+
+    The kernels work on ``[B, S, H * D]``, so the transposes here undo the
+    ones ``SelfAttention`` makes around its attention function and XLA drops
+    both: the projections feed the kernel, and read its result, as they are."""
+    b, h, s, d = q.shape
+    ids = None if segment_ids is None else segment_ids.astype(jnp.int32)
+    out = _short_attention(
+        *(t.transpose(0, 2, 1, 3).reshape(b, s, h * d) for t in (q, k, v)),
+        ids, h, causal, block_q, heads_per_step)
+    return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+
+
+MIN_FUSED_SEQ = 256  # timed on the v5e at 16,384 tokens of 12 heads x 64
+# (PERF.md section 6, PR 29): at 128 dense attention is level with the kernel
+# (1.17 against 1.18-1.37 ms a layer), at 256 the kernel is 1.95 times ahead
+
+
+def fused_attention_applies(seq: int, head_dim: int, mesh=None,
+                            platform: Optional[str] = None) -> bool:
+    """The rule by which a sequence model that was given no attention
+    function gets the fused kernel: on a TPU, for a sequence of whole
+    128-key blocks from ``MIN_FUSED_SEQ`` up and heads of whole 64-lane
+    halves (BERT's 64, OLMoE's 128), over one device or a mesh that only
+    has a ``'data'`` axis (a ``'model'`` axis has met no chip). Everything
+    else is dense attention, as before: the CPU, a ViT's 197 tokens, a
+    tensor-parallel mesh, and several devices with no mesh to say how the
+    batch is split."""
+    if (platform or jax.default_backend()) != "tpu":
+        return False
+    if seq < MIN_FUSED_SEQ or seq % _LANES or head_dim % 64:
+        return False
+    if mesh is None:
+        return jax.device_count() == 1
+    return tuple(mesh.axis_names) == ("data",)
+
+
 def make_flash_attention(block_q: int = 512, block_k: int = 512,
-                         causal: bool = False, mesh=None):
+                         causal: bool = False, mesh=None,
+                         forced: bool = True):
     """Build an ``attention_fn(q, k, v, mask=None, dtype=None)``.
 
     q/k/v are [B, H, S, D]; mask (optional) is the key-validity mask
     [B, 1, 1, S] produced by :class:`..models.transformer.TransformerEncoder`.
-    ``causal=True`` selects the kernel's fused autoregressive masking (the
-    decoder/GPT path) — the kernel then also skips the fully-masked upper
-    blocks, the usual ~2x flash speedup for causal attention.
+    ``causal=True`` selects the kernels' fused autoregressive masking (the
+    decoder/GPT path) — the library kernel then also skips the fully-masked
+    upper blocks, the usual ~2x flash speedup for causal attention.
+
+    ``forced`` is ``--flash_attention``: on a TPU the kernel or an error,
+    whatever the shapes. Without it (what ``get_task`` binds when no
+    attention function was chosen) each call takes the kernel where
+    :func:`fused_attention_applies` says so for the shapes it sees, and
+    dense attention elsewhere. Either way sequences of up to ``SHORT_SEQ``
+    whole blocks run :func:`short_attention`, longer ones the library's
+    blocked kernel.
 
     ``mesh`` is the trainer's device mesh. XLA cannot partition a Mosaic
     kernel ("wrap the call in a shard_map"), so on more than one device the
@@ -72,14 +321,19 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
     (``model.init``'s batch of 1; a train or eval batch always divides) runs
     the same kernel whole on every device.
     """
-    use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
+    platform = jax.default_backend()  # read once: the calls obey this one
+    use_pallas = platform == "tpu"
+    if use_pallas and forced:
         # No try/except: on a TPU a missing kernel module is an error, not
         # a reason to run dense attention under the flag's name.
-        from jax.experimental.pallas.ops.tpu import flash_attention as fa
+        from jax.experimental.pallas.ops.tpu import flash_attention  # noqa: F401
 
     def kernel(q, k, v, ids):
         seq = q.shape[2]
+        if seq <= SHORT_SEQ and seq % _LANES == 0:
+            return short_attention(q, k, v, ids, causal=causal)
+        from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
         sizes = fa.BlockSizes(
             block_q=min(block_q, seq),
             block_k_major=min(block_k, seq),
@@ -115,8 +369,13 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
     if over_mesh:
         split, whole = per_device("data"), per_device(None)
 
+    def fused(seq: int, head_dim: int) -> bool:
+        """Does a call with these shapes run the kernel?"""
+        return use_pallas and (forced or fused_attention_applies(
+            seq, head_dim, mesh, platform))
+
     def attention_fn(q, k, v, mask=None, dtype=None, segment_ids=None):
-        if not use_pallas:
+        if not fused(q.shape[2], q.shape[3]):
             from ..models.transformer import dot_product_attention
 
             if segment_ids is not None:
@@ -140,4 +399,5 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
         tiles = q.shape[0] % mesh.shape["data"] == 0
         return (split if tiles else whole)(q, k, v, ids)
 
+    attention_fn.fused = fused  # train() logs it; the call above obeys it
     return attention_fn

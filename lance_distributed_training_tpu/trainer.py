@@ -258,7 +258,9 @@ class TrainConfig:
     model_parallelism: int = 1  # tensor-parallel degree ('model' mesh axis)
     seq_parallelism: int = 1  # context-parallel degree ('seq' axis, ring attn)
     remat: bool = False  # rematerialize transformer blocks (long-context)
-    flash_attention: bool = False  # Pallas fused attention (TPU; dense elsewhere)
+    flash_attention: bool = False  # force the Pallas attention kernel (on a
+    # TPU the kernel or an error, dense elsewhere); without it get_task
+    # binds the kernel where shapes and mesh allow (ops/flash.py)
     num_experts: int = 0  # >0: switch-MoE transformer blocks (expert parallel)
     moe_every: int = 2  # MoE on every Nth block
     pipeline_parallelism: int = 1  # GPipe stages over a 'pipe' mesh axis
@@ -350,6 +352,22 @@ def _task_from_config(config: TrainConfig, mesh=None) -> Task:
         pp_microbatches=config.pp_microbatches,
         mesh=mesh,
     )
+
+
+def _attention_fused(task: Task, config: TrainConfig) -> Optional[float]:
+    """1.0 where a sequence model's attention at ``seq_len`` runs the fused
+    kernel, 0.0 where it runs dense (or ring) attention, None for a task
+    without attention of its own to choose: asked of the function the model
+    was bound (``ops.flash.make_flash_attention``), which decides each call
+    by the same test. Published as the gauge ``attention_fused``."""
+    if config.task_type not in ("masked_lm", "causal_lm"):
+        return None
+    model = task.model
+    fused = getattr(getattr(model, "attention_fn", None), "fused", None)
+    on = bool(fused and fused(config.seq_len,
+                              model.hidden_size // model.num_heads))
+    default_registry().gauge("attention_fused").set(float(on))
+    return float(on)
 
 
 def lr_schedule_fn(config: TrainConfig, total_steps: Optional[int] = None):
@@ -1274,6 +1292,7 @@ def _train(config: TrainConfig) -> dict:
         else None
     )
     task = _task_from_config(config, mesh)
+    attention_fused = _attention_fused(task, config)
 
     rng = jax.random.key(config.seed)
     rng, init_rng = jax.random.split(rng)
@@ -1489,8 +1508,12 @@ def _train(config: TrainConfig) -> dict:
         # the exporter port, the metrics_port log write, or a pool-spawn
         # error must all still run the finally (logger/ckpt close, and the
         # exporter's bound port once started).
-        logger.log({**device_info, "compile_cache_dir": cache_dir},
-                   to_wandb=False)
+        start_line = {**device_info, "compile_cache_dir": cache_dir}
+        if attention_fused is not None:
+            start_line["attention"] = (
+                "fused kernel" if attention_fused
+                else "ring" if config.seq_parallelism > 1 else "dense")
+        logger.log(start_line, to_wandb=False)
         if config.metrics_port is not None and jax.process_index() == 0:
             from .obs.http import MetricsHTTPServer
 
@@ -1571,8 +1594,11 @@ def _train(config: TrainConfig) -> dict:
             resume_global_step=resume_global_step,
             preempt=preempt, chaos=chaos, trace=trace, journal=journal,
             tuner=tuner, batch_cache=batch_cache, folder_fp=folder_fp,
+            attention_fused=attention_fused,
         )
         results.update(device_info)
+        if attention_fused is not None:
+            results["attention_fused"] = attention_fused
         return results
     except BaseException as exc:
         run_exc = exc
@@ -1670,7 +1696,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 index_pool=None, lr_fn=None, val_pool=None, *,
                 resume_epoch_step=0, resume_global_step=0, preempt=None,
                 chaos=None, trace=None, journal=None, tuner=None,
-                batch_cache=None, folder_fp=None):
+                batch_cache=None, folder_fp=None, attention_fused=None):
     if journal is None:
         journal = _CkptJournal(resume_global_step)
     step_stats = _StepStats()
@@ -1993,6 +2019,8 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                     if gnorm is not None:
                         entry["grad_norm"] = round(float(gnorm), 4)  # ldt: ignore[LDT1704] -- log-interval divergence telemetry, rides the loss drain
                     step_stats.publish(entry)
+                    if attention_fused is not None:
+                        entry["attention_fused"] = attention_fused
                     if config.data_echo > 1:
                         # The windowed rate counts echoed steps; report the
                         # unique-data rate next to it (as the epoch metrics

@@ -1,0 +1,363 @@
+"""The attention path a sequence model gets when nobody chose one
+(``ops/flash.py``: ``fused_attention_applies``, ``make_flash_attention(
+forced=False)``, bound by ``get_task``), and the short-sequence kernel it
+runs up to 1,024 tokens (``short_attention``).
+
+On the CPU the rule keeps dense attention, so the kernel's side is built
+under a stubbed platform and run in TPU interpret mode; each such call is one
+jitted program that is waited for (``tests/test_olmoe.py`` tells why). All
+comparisons are float32 against float32: the two paths differ in the order
+of their sums only.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from lance_distributed_training_tpu.models import get_task, tasks
+from lance_distributed_training_tpu.models.transformer import (
+    bert_small,
+    dot_product_attention,
+)
+from lance_distributed_training_tpu.ops import flash
+from lance_distributed_training_tpu.parallel import get_mesh
+
+F32_TOL = 2e-4  # summation order only (measured 1e-7 to 2e-6)
+SEQ, ROWS, VOCAB = 256, 4, 512
+RNG = jax.random.PRNGKey(29)
+
+
+def _one_program(fn, *args):
+    return jax.block_until_ready(jax.jit(fn)(*args))
+
+
+def _meshes():
+    return {"none": None,
+            "one device": get_mesh(jax.devices()[:1]),
+            "data": get_mesh(jax.devices()[:4]),
+            "data x model": get_mesh(jax.devices()[:4], model_parallelism=2)}
+
+
+# -- the rule ----------------------------------------------------------------
+
+RULE = [
+    # platform, mesh, seq, head width, fused?
+    ("cpu", "one device", 512, 64, False),  # every tier-1 test
+    ("tpu", "one device", 512, 64, True),  # the BERT cells
+    ("tpu", "one device", 4096, 128, True),  # OLMoE's shapes, no flag
+    ("tpu", "data", 512, 64, True),  # four chips: shard_map over 'data'
+    ("tpu", "one device", 256, 64, True),  # the lower edge, as timed:
+    ("tpu", "one device", 128, 64, False),  # here dense is level with it
+    ("tpu", "one device", 64, 64, False),  # under one block of keys
+    ("tpu", "one device", 197, 64, False),  # ViT-B/16: not whole blocks
+    ("tpu", "one device", 77, 64, False),  # CLIP's text tower
+    ("tpu", "one device", 512, 48, False),  # a head that fills no half tile
+    ("tpu", "data x model", 512, 64, False),  # 'model' has met no chip
+    ("tpu", "none", 512, 64, False),  # eight devices, nobody said how split
+    ("gpu", "one device", 512, 64, False),
+]
+
+
+@pytest.mark.parametrize("platform,mesh,seq,head_dim,fused", RULE)
+def test_the_rule(platform, mesh, seq, head_dim, fused):
+    assert flash.fused_attention_applies(
+        seq, head_dim, _meshes()[mesh], platform) is fused
+
+
+def test_without_a_mesh_one_device_is_fused(monkeypatch):
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert flash.fused_attention_applies(512, 64, None, "tpu")
+
+
+@pytest.mark.parametrize("forced", [True, False])
+def test_the_flag_still_forces(monkeypatch, forced):
+    """``--flash_attention`` is the kernel whatever the shapes (or the
+    kernel's own error); without it the same shapes stay dense."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    attention = flash.make_flash_attention(
+        mesh=_meshes()["one device"], forced=forced)
+    assert attention.fused(197, 64) is forced
+    assert attention.fused(512, 64) is True
+    q = jnp.zeros((1, 2, 197, 64), jnp.float32)
+    traced = str(jax.make_jaxpr(attention)(q, q, q))
+    assert ("pallas_call" in traced) is forced  # the chip's compiler judges
+
+
+def test_off_the_tpu_nothing_is_fused_flag_or_not():
+    for forced in (True, False):
+        attention = flash.make_flash_attention(forced=forced)
+        assert attention.fused(512, 64) is False
+
+
+@pytest.mark.parametrize("task_type,model,causal", [
+    ("masked_lm", "bert_small", False),
+    ("causal_lm", "gpt_small", True),
+    ("causal_lm", "olmoe_tiny", True),
+])
+def test_get_task_binds_the_choice_and_carries_causality(
+        monkeypatch, task_type, model, causal):
+    seen = {}
+    make = flash.make_flash_attention
+
+    def spy(**kwargs):
+        seen.update(kwargs)
+        return make(**kwargs)
+
+    monkeypatch.setattr(flash, "make_flash_attention", spy)
+    mesh = _meshes()["one device"]
+    task = get_task(task_type, model_name=model, seq_len=SEQ, mesh=mesh)
+    assert seen == {"causal": causal, "mesh": mesh, "forced": False}
+    assert task.model.attention_fn.fused(SEQ, 64) is False  # the CPU
+
+
+def test_a_chosen_attention_function_is_left_alone(monkeypatch):
+    monkeypatch.setattr(flash, "make_flash_attention", lambda **_: 1 / 0)
+    given = functools.partial(dot_product_attention, dtype=jnp.float32)
+    task = get_task("masked_lm", model_name="bert_small", seq_len=SEQ,
+                    attention_fn=given)
+    assert task.model.attention_fn is given
+    get_task("classification", model_name="resnet18", num_classes=10)
+
+
+# -- the kernel against the dense function -----------------------------------
+
+
+def _segments(seq):
+    pos = np.arange(seq)
+    ids = np.where(pos < seq // 3, 1, np.where(pos < seq - seq // 5, 2, 0))
+    return jnp.asarray(np.stack([ids, np.ones(seq, np.int64)]), jnp.int32)
+
+
+@pytest.mark.parametrize("block_q", [256, 128])  # one block; accumulated
+@pytest.mark.parametrize("ids", [True, False])
+@pytest.mark.parametrize("causal", [False, True])
+def test_short_attention_forward_and_backward_equal_dense(
+        causal, ids, block_q):
+    from jax.experimental.pallas import tpu as pltpu
+
+    shape = (2, 2, 256, 64)
+    q, k, v, w = (jax.random.normal(key, shape, jnp.float32)
+                  for key in jax.random.split(jax.random.key(0), 4))
+    seg = _segments(shape[2]) if ids else None
+    mask = flash.segment_attention_mask(seg) if ids else None
+    live = ((seg > 0)[:, None, :, None] if ids
+            else jnp.ones((2, 1, 256, 1), bool))  # dead queries mean nothing
+
+    def dense(q, k, v):
+        return jnp.where(live, dot_product_attention(
+            q, k, v, mask=mask, dtype=jnp.float32, causal=causal), 0)
+
+    def kernel(q, k, v):
+        return jnp.where(live, flash.short_attention(
+            q, k, v, seg, causal=causal, block_q=block_q), 0)
+
+    def both(q, k, v):
+        return [(fn(q, k, v), jax.grad(lambda *a: (fn(*a) * w).sum(),
+                                       argnums=(0, 1, 2))(q, k, v))
+                for fn in (dense, kernel)]
+
+    with pltpu.force_tpu_interpret_mode():
+        (want, want_grads), (got, got_grads) = _one_program(both, q, k, v)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    for g, wnt in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, wnt, atol=2e-5, rtol=1e-5)
+
+
+def test_short_attention_refuses_what_it_cannot_tile():
+    q = jnp.zeros((1, 2, 200, 64), jnp.float32)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        jax.eval_shape(flash.short_attention, q, q, q)
+
+
+# -- the chip's compiler, without the chip -----------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e host (a compile-only client: nothing runs
+    and no backend is registered). Described inside the fixture, never at
+    import: only the worker that is given this file loads the library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu here, or it is held elsewhere
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((32, 12, 512, 64), False),  # c4-bert-prepacked's step
+    ((24, 12, 512, 64), False),  # c4-bert-ragged's larger grid
+    ((16, 12, 1024, 64), False),  # two query blocks: the f32 scratch
+    ((8, 16, 1024, 128), True),  # heads of a whole tile, causal
+    ((64, 12, 256, 64), True),  # the rule's lower edge
+])
+def test_short_attention_compiles_for_a_v5e_at_real_widths(one_chip, shape,
+                                                           causal):
+    """Mosaic's own checks (tiling, lane slices of a head, VMEM) on the
+    forward and the backward kernel, which interpret mode does not make."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((shape[0], shape[2]), jnp.int32,
+                               sharding=one_chip)
+
+    def loss(q, k, v, ids):
+        return flash.short_attention(q, k, v, ids, causal=causal).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, ids).compile()  # the gradient alone needs no forward
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+# -- BERT's encoder with the chosen kernel -----------------------------------
+
+
+def _padded(lengths):
+    ids = jax.random.randint(jax.random.PRNGKey(1), (ROWS, SEQ), 2, VOCAB)
+    live = jnp.arange(SEQ)[None, :] < jnp.asarray(lengths)[:, None]
+    return {"input_ids": jnp.where(live, ids, 0),
+            "attention_mask": live.astype(jnp.int8)}
+
+
+def _packed():
+    """Rows of two documents: segments from 1, 0 on the padding, positions
+    that restart with each document."""
+    batch = _padded((256, 240, 128, 10))
+    cuts = [(100, 256), (7, 240), (127, 128), (2, 10)]
+    seg = np.zeros((ROWS, SEQ), np.int32)
+    pos = np.zeros((ROWS, SEQ), np.int32)
+    for row, (b, end) in enumerate(cuts):
+        seg[row, :b], seg[row, b:end] = 1, 2
+        pos[row, :b], pos[row, b:end] = np.arange(b), np.arange(end - b)
+    return dict(batch, segment_ids=jnp.asarray(seg),
+                position_ids=jnp.asarray(pos))
+
+
+BATCHES = {"all-ones mask": lambda: _padded((SEQ,) * ROWS),
+           "padded rows": lambda: _padded((256, 180, 17, 1)),
+           "packed rows": _packed}
+GROUPS = ("query", "key", "value", "out", "mlp_in", "mlp_out", "norms",
+          "tok_embed", "pos_embed")
+
+
+def _groups(tree) -> dict:
+    out: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        keys = [k.key for k in path if hasattr(k, "key")]
+        name = next((k for k in GROUPS if k in keys), "norms")
+        out.setdefault(name, []).append(jnp.ravel(leaf))
+    return {k: jnp.concatenate(v) for k, v in out.items()}
+
+
+def _relative(got, want) -> float:
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+@pytest.fixture(scope="module")
+def encoders():
+    """``bert_small``'s widths (two layers) in float32, twice on the same
+    parameters: dense attention named outright (the path before PR 29), and
+    what ``get_task`` binds by itself on a one-chip TPU."""
+    make = functools.partial(get_task, "masked_lm", model_name="bert_small",
+                             seq_len=SEQ, vocab_size=VOCAB, num_layers=2)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(tasks, "bert_small",
+                      functools.partial(bert_small, dtype=jnp.float32))
+        dense = make(attention_fn=functools.partial(
+            dot_product_attention, dtype=jnp.float32))
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        chosen = make(mesh=get_mesh(jax.devices()[:1]))
+    assert chosen.model.attention_fn.fused(SEQ, 64)
+    return dense, chosen, dense.init_variables(jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def compared(encoders):
+    """Each batch run once, one program: eval logits, the train loss and
+    its gradient, on both paths."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    dense, chosen, variables = encoders
+    done = {}
+
+    def run(name):
+        if name not in done:
+            batch = BATCHES[name]()
+
+            def one(task, v):
+                logits = task.forward(v, batch, False, None)[0][0]
+                live = (batch["attention_mask"] > 0)[..., None]
+
+                def loss(v):
+                    return task.loss(task.forward(v, batch, True, RNG)[0],
+                                     batch)
+
+                value, grads = jax.value_and_grad(loss)(v)
+                return jnp.where(live, logits, 0), value, grads
+
+            with pltpu.force_tpu_interpret_mode():
+                done[name] = _one_program(
+                    lambda v: (one(dense, v), one(chosen, v)), variables)
+        return done[name]
+
+    return run
+
+
+@pytest.mark.parametrize("name", BATCHES)
+def test_encoder_logits_and_loss_with_the_chosen_kernel_equal_dense(
+        compared, name):
+    (want, want_loss, _), (got, got_loss, _) = compared(name)
+    spread = float(jnp.std(want))
+    assert float(jnp.abs(got - want).max()) < F32_TOL * spread
+    assert abs(float(got_loss) - float(want_loss)) < F32_TOL * float(
+        want_loss)
+
+
+@pytest.mark.parametrize("group", GROUPS)
+@pytest.mark.parametrize("name", BATCHES)
+def test_encoder_gradient_with_the_chosen_kernel_equals_dense(
+        compared, name, group):
+    (_, _, want), (_, _, got) = compared(name)
+    assert _relative(_groups(got)[group], _groups(want)[group]) < F32_TOL
+
+
+# -- train() says which path it took -----------------------------------------
+
+
+def test_train_logs_the_attention_path_and_sets_the_gauge(tmp_path,
+                                                          monkeypatch):
+    import json
+
+    from lance_distributed_training_tpu import cli
+    from lance_distributed_training_tpu.data import create_text_token_dataset
+    from lance_distributed_training_tpu.obs.registry import default_registry
+
+    rng = np.random.default_rng(0)
+    docs = [rng.integers(2, 64, 128).tolist() for _ in range(40)]
+    uri = str(tmp_path / "tok")
+    create_text_token_dataset(uri, docs, seq_len=128, fragment_size=64)
+    metrics_path = tmp_path / "metrics.jsonl"
+    monkeypatch.setenv("LDT_METRICS_PATH", str(metrics_path))
+    default_registry().gauge("attention_fused").set(-1.0)
+    cli.main([
+        "train", "--dataset_path", uri, "--task_type", "masked_lm",
+        "--model_name", "bert_small", "--num_layers", "1", "--seq_len", "128",
+        "--vocab_size", "64", "--batch_size", "8", "--epochs", "1",
+        "--max_steps", "4", "--log_every", "2", "--no_ddp", "--no_wandb",
+        "--no_eval_at_end", "--no_autotune"])
+    assert default_registry().gauge("attention_fused").value == 0.0
+    lines = [json.loads(line) for line in open(metrics_path)]
+    assert [ln["attention"] for ln in lines if "attention" in ln] == ["dense"]
+    steps = [ln for ln in lines if "images_per_sec_dispatch" in ln]
+    assert len(steps) == 2  # the gauge rides every log line
+    assert all(ln["attention_fused"] == 0.0 for ln in steps)

@@ -303,20 +303,25 @@ def kernel_task():
     """``olmoe_tiny`` in float32 with ``ops/flash.py``'s Pallas kernel as its
     attention, as ``--flash_attention`` builds it on a TPU (causal, blocks no
     longer than the row); calls run under ``force_tpu_interpret_mode``."""
-    from lance_distributed_training_tpu.ops.flash import make_flash_attention
+    from lance_distributed_training_tpu.ops import flash
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(jax, "default_backend", lambda: "tpu")
-        attention = make_flash_attention(causal=True)
+        attention = flash.make_flash_attention(causal=True)
     name = "olmoe_tiny_f32_kernel"
     tasks._CAUSAL_LMS[name] = (
         functools.partial(olmoe_tiny, dtype=jnp.float32), VOCAB,
         tasks._OLMOE_AUX)
-    try:
-        yield get_task("causal_lm", model_name=name, seq_len=KERNEL_SEQ,
-                       attention_fn=attention)
-    finally:
-        del tasks._CAUSAL_LMS[name]
+    with pytest.MonkeyPatch.context() as patch:
+        # the cell's 4,096 tokens run the library's blocked kernel; keep
+        # these 128 on it (tests/test_attention_choice.py holds the
+        # short-sequence kernel that would take them)
+        patch.setattr(flash, "SHORT_SEQ", 0)
+        try:
+            yield get_task("causal_lm", model_name=name, seq_len=KERNEL_SEQ,
+                           attention_fn=attention)
+        finally:
+            del tasks._CAUSAL_LMS[name]
 
 
 @pytest.fixture(scope="module")
