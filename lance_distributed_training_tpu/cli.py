@@ -167,13 +167,25 @@ def build_parser() -> argparse.ArgumentParser:
                    help="default per task: resnet50 / bert_base / gpt_base / "
                         "clip_resnet50_bert; causal_lm also has olmoe_1b_7b "
                         "(OLMoE-1B-7B at its published sizes: rotary RMSNorm "
-                        "decoder, 64 dropless SwiGLU experts, 8 a token) and "
-                        "olmoe_tiny")
+                        "decoder, 64 dropless SwiGLU experts, 8 a token), "
+                        "moonlight_16b_a3b (Moonlight-16B-A3B: latent "
+                        "attention, a leading dense layer, 64 experts with "
+                        "6 a token by sigmoid scores beside a shared one) "
+                        "and olmoe_tiny, moonlight_tiny")
     p.add_argument("--num_layers", type=int, default=0,
                    help=">0: this many layers of a masked_lm/causal_lm "
                         "transformer preset in place of its own depth, at "
                         "every published width (one chip's share of a model "
                         "that does not fit); 0 keeps the preset's depth")
+    p.add_argument("--expert_share", type=str, default=None,
+                   metavar="RANK/RANKS",
+                   help="the experts of each dropless expert layer (olmoe_*, "
+                        "moonlight_*) that this process holds as rank RANK "
+                        "of RANKS that share the layer: E/RANKS of them from "
+                        "RANK*E/RANKS on. The router stays whole; what absent "
+                        "experts would add is left out. With --vocab_size as "
+                        "the vocabulary's slice and --num_layers, one chip's "
+                        "share of an expert-parallel job. Default: all")
     p.add_argument("--no_compile_cache", action="store_true",
                    help="set up no persistent XLA compile cache (by default "
                         "accelerator runs cache under <checkout>/.jax_cache; "
@@ -863,6 +875,7 @@ def main(argv=None) -> dict:
         no_wandb=args.no_wandb,
         model_name=args.model_name,
         num_layers=args.num_layers,
+        expert_share=args.expert_share,
         pretrained=args.pretrained,
         compile_cache=not args.no_compile_cache,
         image_size=args.image_size,

@@ -1,7 +1,8 @@
-"""Mixture-of-Experts feed-forward layers for the transformer stacks.
+"""Feed-forward layers of the transformer stacks: a plain SwiGLU and two
+Mixture-of-Experts layers.
 
-Two layers, one for each thing the repo does with experts today:
-
+* :class:`SwiGLU` — ``down(silu(gate(x)) · up(x))``, no biases: a decoder's
+  dense layer and an expert layer's shared expert.
 * :class:`MoEMLP` — the **sharded** form (``--num_experts`` on the BERT/GPT
   presets): top-1 switch routing with a per-expert capacity, expressed
   entirely as einsums over a dense ``[T, E, C]`` dispatch tensor, so the SPMD
@@ -11,14 +12,18 @@ Two layers, one for each thing the repo does with experts today:
   dispatch tensors grow with ``T * E * C``: right for a few experts over a
   mesh, impossible at a published sparse model's shape (8,192 tokens, 64
   experts, 8 a token: terabytes).
-* :class:`DroplessMoE` — the **one-chip, published-shape** form (the OLMoE
-  presets): top-k routing with no capacity and no dropped token; the
-  ``T * k`` assignments are sorted by expert, the tokens gathered, the three
-  SwiGLU products run as grouped matrix multiplications over the ragged
-  groups (``jax.lax.ragged_dot``), and each token's k results gathered back
-  through the inverse permutation and summed with their weights.
-  Nothing larger than ``[T * k, width]`` exists. One dispatch for both is
-  decided where experts first span chips at a published shape (ROADMAP R3).
+* :class:`DroplessMoE` — the **published-shape** form (the OLMoE and
+  Moonlight presets): top-k routing with no capacity and no dropped token;
+  the ``T * k`` assignments are sorted by expert, the tokens gathered, the
+  three SwiGLU products run as grouped matrix multiplications over the
+  ragged groups (``jax.lax.ragged_dot``), and each token's k results
+  gathered back through the inverse permutation and summed with their
+  weights. Nothing larger than ``[T * k, width]`` exists. It holds all of
+  its experts (OLMoE on one chip) or is told which contiguous range of them
+  it holds (one rank's share of an expert-parallel layer: the router stays
+  whole, the layer computes what its own experts give and leaves out what
+  the absent ones would add; the exchange that brings a deployment's rank
+  the other ranks' tokens is not here: ROADMAP D12).
 """
 
 from __future__ import annotations
@@ -29,7 +34,27 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-__all__ = ["MoEMLP", "DroplessMoE"]
+__all__ = ["SwiGLU", "MoEMLP", "DroplessMoE"]
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) · up(x))`` of width ``mlp_dim``, no biases: bf16
+    operands on the matrix unit, f32 parameters."""
+
+    mlp_dim: int
+    dtype: Any = jnp.bfloat16
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            param_dtype=jnp.float32,
+                            kernel_init=self.kernel_init, name=name)
+
+        y = nn.silu(dense(self.mlp_dim, "gate")(x)) * dense(
+            self.mlp_dim, "up")(x)
+        return dense(x.shape[-1], "down")(y)
 
 
 class MoEMLP(nn.Module):
@@ -124,19 +149,95 @@ _permute_rows.defvjp(
     lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
 
 
+@jax.custom_vjp
+def _rows_out(x, src, live, pos, valid):
+    """The first ``len(src)`` rows of the sorted list from the tokens ``x``
+    [T, H]: row r is ``x[src[r]]`` where ``live[r]`` (the row is in a held
+    expert's group) and zeros elsewhere. ``pos`` [T, k] is where each
+    token's assignments sit in the list and ``valid`` [T, k] which of them
+    are in a group: the cotangent is gathered through them, as
+    :func:`_permute_rows`'s is through the inverse."""
+    return jnp.where(live[:, None], jnp.take(x, src, axis=0), 0)
+
+
+def _rows_out_bwd(res, g):
+    pos, valid = res
+    back = jnp.take(g, jnp.minimum(pos, g.shape[0] - 1), axis=0)  # [T, k, H]
+    return (jnp.where(valid[..., None], back, 0).sum(1), None, None, None,
+            None)
+
+
+_rows_out.defvjp(
+    lambda x, src, live, pos, valid: (_rows_out(x, src, live, pos, valid),
+                                      (pos, valid)),
+    _rows_out_bwd)
+
+
+@jax.custom_vjp
+def _rows_back(out, pos, valid, head, live):
+    """Each token's k results from the sorted rows ``out`` [R, H]:
+    ``out[pos[t, j]]`` where ``valid[t, j]``, zeros for an assignment that
+    landed on no held expert. ``head`` [R] is the assignment each sorted
+    row belongs to and ``live`` [R] whether it is in a group: the cotangent
+    of ``out`` is gathered through them."""
+    back = jnp.take(out, jnp.minimum(pos, out.shape[0] - 1), axis=0)
+    return jnp.where(valid[..., None], back, 0)
+
+
+def _rows_back_bwd(res, g):
+    head, live = res
+    rows = jnp.take(g.reshape(-1, g.shape[-1]), head, axis=0)
+    return jnp.where(live[:, None], rows, 0), None, None, None, None
+
+
+_rows_back.defvjp(
+    lambda out, pos, valid, head, live: (
+        _rows_back(out, pos, valid, head, live), (head, live)),
+    _rows_back_bwd)
+
+
 class DroplessMoE(nn.Module):
     """Top-k routed SwiGLU experts without capacity: ``[B, S, H] -> [B, S, H]``,
-    ``Σ_k p_k · down_k(silu(gate_k(x)) · up_k(x))`` over each token's
-    ``experts_per_token`` largest router probabilities ``p_k`` (the softmax
-    values themselves, not renormalised), no biases.
+    ``Σ_k w_k · down_k(silu(gate_k(x)) · up_k(x))`` over each token's
+    ``experts_per_token`` chosen experts, no biases, plus ``shared(x)``
+    where ``shared_dim`` > 0 (one :class:`SwiGLU` every token passes).
+
+    Two published routers. ``scoring`` ``"softmax"`` (OLMoE): the k largest
+    router probabilities, weighted by the softmax values themselves.
+    ``"sigmoid"`` (the DeepSeek-V3 family, Moonlight): ``s = sigmoid(logits)``,
+    the k largest of ``s + b`` are chosen, where ``b`` is a selection bias
+    that takes no gradient (``router_state``/``bias``; it moves against the
+    load by ``bias_update_rate`` after each training step, over the tokens
+    this layer saw), and the weights are the chosen ``s``, without ``b``,
+    divided by their sum under ``norm_topk`` and times ``routed_scale``.
+
+    ``held_experts`` > 0 tells the layer which experts it holds: that many
+    from ``first_expert`` on, one rank's share of an expert-parallel layer.
+    The router keeps its ``num_experts`` outputs and its k a token; the
+    layer has the held experts' matrices only, sorts the held assignments to
+    the front of the list, multiplies those, and gives the rest no weight:
+    what the absent experts would add is left out of the result.
 
     Static in shape: every token has exactly k assignments, so the sorted
     list has ``T * k`` rows whatever the routing; only the group sizes are
-    data. Sows the two auxiliary terms of the OLMoE paper into ``aux_loss``
-    (``load_balance`` = ``E · Σ_e f_e · P_e`` and ``router_z`` =
-    ``mean(logsumexp(logits)²)``, both over live tokens, unweighted) and the
-    experts' assignment counts into ``moe_stats``; ``live`` [B, S] marks the
-    tokens that count (None: all).
+    data. Under a share only the rows in a held expert's group are real
+    (``k * held / E`` a token at even routing), so the layer builds twice
+    that many rows, and in a step whose routing sends more than that here
+    it builds all ``T * k`` instead, the bound no routing exceeds (a
+    ``lax.cond`` on the count: exact either way, no token is ever dropped;
+    ``moe_stats``/``over_usual`` says which). The gather, the products and
+    the sum back are then recomputed in the backward pass, so that a layer
+    keeps its ``[T, H]`` input and not the rows of every intermediate.
+
+    Sows its auxiliary terms into ``aux_loss`` (softmax: the OLMoE paper's ``load_balance`` = ``E · Σ_e f_e
+    · P_e`` and ``router_z`` = ``mean(logsumexp(logits)²)``; sigmoid: the
+    sequence-wise ``seq_balance``, the same product with ``P`` the scores
+    over their sum, per row and averaged; all over live tokens, unweighted)
+    and into ``moe_stats`` the experts' assignment counts (``group_sizes``,
+    all ``num_experts``), under a share those of the held experts
+    (``held_sizes``, ``over_usual``), and with a bias its largest magnitude
+    (``bias_abs_max``); ``live`` [B, S] marks the tokens that count (None:
+    all).
     """
 
     num_experts: int
@@ -144,17 +245,28 @@ class DroplessMoE(nn.Module):
     experts_per_token: int
     dtype: Any = jnp.bfloat16
     kernel_init: Callable = nn.initializers.lecun_normal()
+    scoring: str = "softmax"
+    norm_topk: bool = False
+    routed_scale: float = 1.0
+    bias_update_rate: float = 0.0  # > 0: the selection bias and its update
+    shared_dim: int = 0
+    first_expert: int = 0
+    held_experts: int = 0  # 0: all of them
 
     @nn.compact
     def __call__(self, x, live=None):
         b, s, h = x.shape
         t, e, k = b * s, self.num_experts, self.experts_per_token
+        first, held = self.first_expert, self.held_experts or e
+        a_share = held < e  # one rank's share: some assignments are absent
         tokens = x.reshape(t, h)
         w_gate, w_up, w_down = (
             self.param(name, self.kernel_init, shape, jnp.float32)
-            for name, shape in (("w_gate", (e, h, self.expert_dim)),
-                                ("w_up", (e, h, self.expert_dim)),
-                                ("w_down", (e, self.expert_dim, h))))
+            for name, shape in (("w_gate", (held, h, self.expert_dim)),
+                                ("w_up", (held, h, self.expert_dim)),
+                                ("w_down", (held, self.expert_dim, h))))
+        w = (jnp.ones((t,), jnp.float32) if live is None
+             else live.reshape(t).astype(jnp.float32))
 
         with jax.named_scope("moe.router"):
             # f32 throughout: on a TPU an f32 product at default precision
@@ -165,49 +277,166 @@ class DroplessMoE(nn.Module):
                               precision=jax.lax.Precision.HIGHEST,
                               kernel_init=self.kernel_init, name="router")(
                                   tokens.astype(jnp.float32))
-            probs = nn.softmax(logits, axis=-1)  # [T, E]
-            top_p, top_e = jax.lax.top_k(probs, k)  # [T, k]
+            if self.scoring == "softmax":
+                probs = nn.softmax(logits, axis=-1)  # [T, E]
+                top_p, top_e = jax.lax.top_k(probs, k)  # [T, k]
+            else:
+                probs = nn.sigmoid(logits)
+                chosen_by = probs
+                if self.bias_update_rate > 0:
+                    bias = self.variable("router_state", "bias", jnp.zeros,
+                                         (e,), jnp.float32)
+                    chosen_by = probs + bias.value
+                top_e = jax.lax.top_k(chosen_by, k)[1]
+                top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+                if self.norm_topk:
+                    top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+                top_p = top_p * self.routed_scale
+
+        def experts(xs, group_sizes, w_gate, w_up, w_down):
+            with jax.named_scope("moe.experts"):
+                gate = jax.lax.ragged_dot(xs, w_gate.astype(self.dtype),
+                                          group_sizes)
+                up = jax.lax.ragged_dot(xs, w_up.astype(self.dtype),
+                                        group_sizes)
+                return jax.lax.ragged_dot(nn.silu(gate) * up,
+                                          w_down.astype(self.dtype),
+                                          group_sizes)
 
         with jax.named_scope("moe.dispatch"):
             # Stable sort of the T*k assignments by expert: row i of the
             # sorted list is assignment order[i], of token order[i] // k.
+            # Under a share the held experts' come first, in the held
+            # experts' order, and the absent ones' after every group.
             flat_e = top_e.reshape(t * k)
+            if a_share:
+                flat_e = jnp.where(
+                    (flat_e >= first) & (flat_e < first + held),
+                    flat_e - first, held)
             order = jnp.argsort(flat_e, stable=True)
             inverse = jnp.zeros_like(order).at[order].set(
                 jnp.arange(t * k, dtype=order.dtype), unique_indices=True)
-            ends = jnp.searchsorted(jnp.take(flat_e, order), jnp.arange(e),
+            ends = jnp.searchsorted(jnp.take(flat_e, order), jnp.arange(held),
                                     side="right").astype(jnp.int32)
             group_sizes = jnp.diff(ends, prepend=0)
-            xs = _permute_rows(
-                jnp.repeat(tokens.astype(self.dtype), k, axis=0), order,
-                inverse)
 
-        with jax.named_scope("moe.experts"):
-            gate = jax.lax.ragged_dot(xs, w_gate.astype(self.dtype),
-                                      group_sizes)
-            up = jax.lax.ragged_dot(xs, w_up.astype(self.dtype), group_sizes)
-            out = jax.lax.ragged_dot(nn.silu(gate) * up,
-                                     w_down.astype(self.dtype), group_sizes)
+        over = jnp.zeros((), jnp.float32)
+        if not a_share:
+            with jax.named_scope("moe.dispatch"):
+                xs = _permute_rows(
+                    jnp.repeat(tokens.astype(self.dtype), k, axis=0), order,
+                    inverse)
+            out = experts(xs, group_sizes, w_gate, w_up, w_down)
+            with jax.named_scope("moe.combine"):
+                # Each token's k rows come back through the inverse
+                # permutation and are summed with their weights in f32: the
+                # scatter-add of the sorted rows, without the scatter.
+                y = _permute_rows(out, inverse, order).reshape(t, k, h)
+                y = (y.astype(jnp.float32) * top_p[..., None]).sum(1)
+        else:
+            # One rank's share: only the rows in a held expert's group are
+            # real, ends[-1] of them, first in the list. ``rows`` of the list
+            # are built: the rows past the groups enter as zeros, leave as
+            # zeros, and pass no gradient to the tokens they stand for.
+            pos = inverse.reshape(t, k)
+            valid = pos < ends[-1]
 
-        with jax.named_scope("moe.combine"):
-            # Each token's k rows come back through the inverse permutation
-            # and are summed with their weights in f32: the scatter-add of
-            # the sorted rows, without the scatter.
-            y = _permute_rows(out, inverse, order).reshape(t, k, h)
-            y = (y.astype(jnp.float32) * top_p[..., None]).sum(1)
+            def with_rows(rows):
+                def run(tokens, top_p, w_gate, w_up, w_down, order, pos,
+                        valid, ends, group_sizes):
+                    with jax.named_scope("moe.dispatch"):
+                        head = order[:rows]
+                        live_rows = jnp.arange(rows) < ends[-1]
+                        xs = _rows_out(tokens.astype(self.dtype), head // k,
+                                       live_rows, pos, valid)
+                    out = experts(xs, group_sizes, w_gate, w_up, w_down)
+                    with jax.named_scope("moe.combine"):
+                        y = _rows_back(out, pos, valid, head, live_rows)
+                        return (y.astype(jnp.float32)
+                                * top_p[..., None]).sum(1)
+                return run
 
-        w = (jnp.ones((t,), jnp.float32) if live is None
-             else live.reshape(t).astype(jnp.float32))
+            # Twice the rows of an even routing, in whole tiles, and in a
+            # step whose routing sends more than that here, the worst case:
+            # all T*k, which no routing exceeds. Exact either way. The choice
+            # is its own differentiation rule: it keeps its inputs, and the
+            # branch taken recomputes its rows in the backward pass (from
+            # outside, a ``cond`` hands the backward pass every branch's
+            # residuals, the worst case's among them, filled with zeros).
+            # Everything traced is an argument, the integers too: a rule
+            # that closed over them would leak them under ``--remat``.
+            usual = min(-(-2 * t * k * held // (e * 128)) * 128, t * k)
+            over = (ends[-1] > usual).astype(jnp.float32)
+
+            def is_over(inputs):  # from the arguments' own ``ends``
+                return inputs[8][-1] > usual
+
+            @jax.custom_vjp
+            def routed(*inputs):
+                return jax.lax.cond(is_over(inputs), with_rows(t * k),
+                                    with_rows(usual), *inputs)
+
+            def routed_bwd(inputs, g):
+                def back(rows):
+                    def run(inputs, g):
+                        diff, ints = inputs[:5], inputs[5:]
+                        return jax.vjp(lambda *d: with_rows(rows)(
+                            *d, *ints), *diff)[1](g)
+                    return run
+                grads = jax.lax.cond(is_over(inputs), back(t * k),
+                                     back(usual), inputs, g)
+                return (*grads, *(None,) * 5)
+
+            routed.defvjp(lambda *inputs: (routed(*inputs), inputs),
+                          routed_bwd)
+            y = routed(tokens, top_p, w_gate, w_up, w_down, order, pos, valid,
+                       ends, group_sizes)
+
         n = jnp.maximum(w.sum(), 1.0)
-        # f_e: the share of the live tokens' assignments that went to e (a
-        # running sum over the sorted rows, read at the groups' ends);
-        # P_e: the mean router probability of e over live tokens.
-        run = jnp.concatenate([jnp.zeros((1,), jnp.float32), jnp.cumsum(
-            jnp.take(w, order // k))])
-        frac = (run[ends] - run[ends - group_sizes]) / (n * k)
-        mean_prob = (probs * w[:, None]).sum(0) / n
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        self.sow("aux_loss", "load_balance", e * jnp.sum(frac * mean_prob))
-        self.sow("aux_loss", "router_z", jnp.sum(lse * lse * w) / n)
-        self.sow("moe_stats", "group_sizes", group_sizes)
-        return y.astype(self.dtype).reshape(b, s, h)
+        if a_share or self.scoring != "softmax":
+            # [T, E]: which experts each token chose, held here or not
+            chose = jax.nn.one_hot(top_e, e, dtype=jnp.float32).sum(1)
+            load = chose.sum(0).astype(jnp.int32)
+            live_load = (chose * w[:, None]).sum(0)
+        else:
+            # all held: the sorted rows' groups are the experts' loads, and
+            # the live ones a running sum over them read at the groups' ends
+            run = jnp.concatenate([jnp.zeros((1,), jnp.float32), jnp.cumsum(
+                jnp.take(w, order // k))])
+            load, live_load = group_sizes, run[ends] - run[ends - group_sizes]
+        if self.scoring == "softmax":
+            # f_e: the share of the live tokens' assignments that went to e;
+            # P_e: the mean router probability of e over live tokens.
+            frac = live_load / (n * k)
+            mean_prob = (probs * w[:, None]).sum(0) / n
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            self.sow("aux_loss", "load_balance",
+                     e * jnp.sum(frac * mean_prob))
+            self.sow("aux_loss", "router_z", jnp.sum(lse * lse * w) / n)
+        else:
+            # per row (a sequence): f the experts' share of its live tokens'
+            # assignments times E, P the mean over them of the scores over
+            # their sum (DeepSeek-V3, arXiv:2412.19437, section 2.1.2)
+            rows_n = jnp.maximum(w.reshape(b, s).sum(1), 1.0)[:, None]
+            frac = (chose * w[:, None]).reshape(b, s, e).sum(1) / (rows_n * k)
+            share = (probs / probs.sum(-1, keepdims=True) * w[:, None]
+                     ).reshape(b, s, e).sum(1) / rows_n
+            self.sow("aux_loss", "seq_balance",
+                     e * jnp.mean(jnp.sum(frac * share, axis=-1)))
+        if self.scoring != "softmax" and self.bias_update_rate > 0:
+            self.sow("moe_stats", "bias_abs_max", jnp.abs(bias.value).max())
+            if (self.is_mutable_collection("router_state")
+                    and not self.is_initializing()):
+                # after the step: up where the live load was under the mean
+                bias.value = bias.value + self.bias_update_rate * jnp.sign(
+                    live_load.mean() - live_load)
+        self.sow("moe_stats", "group_sizes", load)
+        if self.held_experts:
+            self.sow("moe_stats", "held_sizes", group_sizes)
+            self.sow("moe_stats", "over_usual", over)
+        y = y.astype(self.dtype)
+        if self.shared_dim:
+            with jax.named_scope("moe.shared"):
+                y = y + SwiGLU(self.shared_dim, self.dtype, self.kernel_init,
+                               name="shared")(tokens)
+        return y.reshape(b, s, h)

@@ -33,6 +33,8 @@ from .transformer import (
     bert_small,
     gpt_base,
     gpt_small,
+    moonlight_16b_a3b,
+    moonlight_tiny,
     olmoe_1b_7b,
     olmoe_tiny,
 )
@@ -324,14 +326,19 @@ def _masked_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
 # preset -> (constructor, its own vocabulary, weight of each auxiliary term
 # its expert layers sow). The GPT presets have expert layers only under
 # --num_experts (MoEMLP); every OLMoE layer is one (DroplessMoE), with the
-# two weights of the paper (arXiv:2409.02060, section 4.1).
+# two weights of the paper (arXiv:2409.02060, section 4.1); Moonlight's
+# (all but its first) sow the sequence-wise balance term, with DeepSeek-V3's
+# weight (arXiv:2412.19437, section 4.2: alpha 0.0001).
 _SWITCH_AUX = {"load_balance": 0.01}
 _OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
+_MOONLIGHT_AUX = {"seq_balance": 0.0001}
 _CAUSAL_LMS: dict = {
     "gpt_base": (gpt_base, 50257, _SWITCH_AUX),
     "gpt_small": (gpt_small, 50257, _SWITCH_AUX),
     "olmoe_1b_7b": (olmoe_1b_7b, 50304, _OLMOE_AUX),
     "olmoe_tiny": (olmoe_tiny, 512, _OLMOE_AUX),
+    "moonlight_16b_a3b": (moonlight_16b_a3b, 163840, _MOONLIGHT_AUX),
+    "moonlight_tiny": (moonlight_tiny, 512, _MOONLIGHT_AUX),
 }
 
 
@@ -348,21 +355,63 @@ def _weighted_aux(sown: dict, weights: dict):
 
 
 def _expert_load(sown: dict) -> dict:
-    """The step's expert-load scalars from the layers' assignment counts
-    (``moe_stats``/``group_sizes``, [E] a layer): all assignments, and the
-    busiest and the mean expert over all layers."""
-    sizes = jnp.stack(jax.tree_util.tree_leaves(sown)).astype(jnp.float32)
-    return {"moe_assignments_total": sizes.sum(),
-            "moe_expert_load_max": sizes.max(),
-            "moe_expert_load_mean": sizes.mean()}
+    """The step's expert-load scalars from what the expert layers sow into
+    ``moe_stats``: from their assignment counts (``group_sizes``, [E] a
+    layer) all assignments, and the busiest and the mean expert over all
+    layers; under a share the same of the experts held here
+    (``held_sizes``: ``moe_local_*``) and how many layers built the
+    worst-case list (``over_usual``); with a selection bias its largest
+    magnitude over the layers."""
+    by_name: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+        by_name.setdefault(path[-2].key, []).append(leaf)
+
+    def load(name, total, prefix):
+        sizes = jnp.stack(by_name[name]).astype(jnp.float32)
+        return {total: sizes.sum(), f"{prefix}_max": sizes.max(),
+                f"{prefix}_mean": sizes.mean()}
+
+    out = load("group_sizes", "moe_assignments_total", "moe_expert_load")
+    if "held_sizes" in by_name:
+        out.update(load("held_sizes", "moe_local_assignments_total",
+                        "moe_local_load"))
+        # layers whose held rows overflowed the usual list this step
+        out["moe_local_fallback_total"] = jnp.stack(
+            by_name["over_usual"]).sum()
+    if "bias_abs_max" in by_name:
+        out["moe_router_bias_abs_max"] = jnp.stack(
+            by_name["bias_abs_max"]).max()
+    return out
+
+
+def _expert_share(share: Optional[str], ctor) -> tuple:
+    """``--expert_share r/n`` as DroplessMoE's fields: rank ``r`` of ``n``
+    that share each expert layer holds the ``E / n`` experts from ``r * E /
+    n`` on."""
+    if share is None:
+        return ()
+    try:
+        rank, ranks = (int(x) for x in share.split("/"))
+    except ValueError:
+        raise ValueError(f"expert_share is 'rank/ranks', got {share!r}") \
+            from None
+    experts = ctor.keywords["num_experts"]
+    if not 0 <= rank < ranks or experts % ranks:
+        raise ValueError(
+            f"expert_share {share}: rank in [0, ranks), and ranks divides "
+            f"the preset's {experts} experts")
+    held = experts // ranks
+    return (("first_expert", rank * held), ("held_experts", held))
 
 
 def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                     attention_fn: Optional[Callable] = None,
                     remat: bool = False, num_experts: int = 0,
-                    moe_every: int = 2, num_layers: int = 0) -> Task:
+                    moe_every: int = 2, num_layers: int = 0,
+                    expert_share: Optional[str] = None) -> Task:
     """Decoder-only next-token prediction (the GPT presets on the encoder
-    trunk, the OLMoE presets on the decoder stack) over the same packed
+    trunk, the OLMoE and Moonlight presets on the decoder stack) over the
+    same packed
     token columns as masked-LM (``create_text_token_dataset``) — the text arm
     beyond the reference's vision-only scope, sharing the trainer, samplers
     and storage unchanged."""
@@ -379,19 +428,31 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
             raise ValueError(
                 f"{model_name} has its own expert layers; --num_experts "
                 "adds switch experts to the BERT/GPT presets only")
+        kwargs["moe"] = ctor.keywords.get("moe", ()) + _expert_share(
+            expert_share, ctor)
     else:
+        if expert_share is not None:
+            raise ValueError("expert_share states which of a dropless "
+                             "preset's experts are held (olmoe_*, "
+                             "moonlight_*)")
         kwargs.update(max_len=seq_len, num_experts=num_experts,
                       moe_every=moe_every)
     model = ctor(**kwargs)
-    sows = (["aux_loss", "moe_stats"] if dropless
+    sows = (["aux_loss", "moe_stats", "router_state"] if dropless
             else ["aux_loss"] if num_experts > 0 else [])
 
     def init_variables(rng):
         ids = jnp.zeros((1, seq_len), jnp.int32)
         variables = model.init(rng, ids, jnp.ones((1, seq_len), jnp.int8),
                                train=False)
-        # what the expert layers sow at init is not state
-        return {"params": variables["params"]} if dropless else variables
+        if not dropless:
+            return variables
+        # what the expert layers sow at init is not state; the routers'
+        # selection bias is, and rides where a train state keeps a model's
+        # non-trainable collection
+        return {"params": variables["params"],
+                **({"batch_stats": variables["router_state"]}
+                   if "router_state" in variables else {})}
 
     def forward(variables, batch, train, rng):
         ids = batch["input_ids"].astype(jnp.int32)
@@ -400,6 +461,9 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
         # sequence boundaries; positions restart per packed sequence.
         seg = batch.get("segment_ids")
         pos = batch.get("position_ids")
+        if dropless and "batch_stats" in variables:
+            variables = {"params": variables["params"],
+                         "router_state": variables["batch_stats"]}
         if train and sows:
             logits, sown = model.apply(
                 variables, ids, mask, train=True, mutable=sows,
@@ -407,7 +471,10 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
             )
             aux = _weighted_aux(sown.get("aux_loss", {}), aux_weights)
             if dropless:
-                return (logits, aux, _expert_load(sown["moe_stats"])), None
+                # the bias as the routers left it: the step's new state
+                state = ({"batch_stats": sown["router_state"]}
+                         if "router_state" in sown else None)
+                return (logits, aux, _expert_load(sown["moe_stats"])), state
             return (logits, aux), None
         logits = model.apply(variables, ids, mask, train=train,
                              segment_ids=seg, position_ids=pos)
@@ -623,14 +690,23 @@ def get_task(
     mesh=None,
     param_dtype=None,
     num_layers: int = 0,
+    expert_share: Optional[str] = None,
 ) -> Task:
     """``vocab_size=None`` means "the model's own default" (bert_*: 30522,
-    gpt_*: 50257, olmoe_1b_7b: 50304, olmoe_tiny: 512, clip_tiny: 1000,
-    clip_resnet50_bert: 30522); explicit values always apply verbatim.
+    gpt_*: 50257, olmoe_1b_7b: 50304, moonlight_16b_a3b: 163840, olmoe_tiny
+    and moonlight_tiny: 512, clip_tiny: 1000, clip_resnet50_bert: 30522);
+    explicit values always apply verbatim.
     ``param_dtype`` overrides the parameter/optimizer-state dtype (ResNet
     family only; e.g. ``jnp.bfloat16`` halves weight HBM). ``num_layers``
     overrides a transformer preset's depth (0 keeps it): one chip's share
-    of a published model is a few of its layers at every published width."""
+    of a published model is a few of its layers at every published width,
+    and ``expert_share`` (``"rank/ranks"``, the dropless causal_lm presets)
+    the experts of each layer that this rank of an expert-parallel job
+    holds; with ``vocab_size`` as its slice of the vocabulary that is the
+    share a configuration states."""
+    if expert_share is not None and task_type != "causal_lm":
+        raise ValueError("expert_share applies to the causal_lm presets "
+                         "with dropless expert layers")
     if num_layers and (task_type not in ("masked_lm", "causal_lm")
                        or pipeline_parallelism > 1):
         raise ValueError(
@@ -673,7 +749,8 @@ def get_task(
         return _causal_lm_task(vocab_size, model_name or "gpt_base", seq_len,
                                attention_fn=attention_fn, remat=remat,
                                num_experts=num_experts, moe_every=moe_every,
-                               num_layers=num_layers)
+                               num_layers=num_layers,
+                               expert_share=expert_share)
     if task_type == "contrastive":
         return _contrastive_task(
             model_name or "clip_resnet50_bert", image_size, seq_len,

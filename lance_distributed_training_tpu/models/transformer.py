@@ -23,8 +23,8 @@ import jax.numpy as jnp
 
 __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "bert_small", "gpt_base", "gpt_small", "olmoe_1b_7b",
-           "olmoe_tiny", "dot_product_attention", "RMSNorm",
-           "rotary_embedding"]
+           "olmoe_tiny", "moonlight_16b_a3b", "moonlight_tiny",
+           "dot_product_attention", "RMSNorm", "rotary_embedding"]
 
 
 def dot_product_attention(q, k, v, mask=None, dtype=jnp.bfloat16,
@@ -35,7 +35,7 @@ def dot_product_attention(q, k, v, mask=None, dtype=jnp.bfloat16,
     ``causal=True`` adds the autoregressive lower-triangular mask (decoder
     attention) on top of any key-validity ``mask``.
     """
-    d = q.shape[-1]
+    d = q.shape[-1]  # v's last dimension may differ (latent attention)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
     scores = scores / jnp.sqrt(d).astype(jnp.float32)
     if causal:
@@ -162,6 +162,62 @@ class SelfAttention(nn.Module):
         return dense(features=h, axis=-1, name="out")(out)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3, Moonlight), without a
+    query compression (``q_lora_rank`` null): keys and values are expanded
+    from one ``kv_rank``-wide normed latent per token, and position enters
+    through ``rope_dim`` rotary elements of each query head and one rotary key
+    head that all heads share. A head's queries and keys are ``nope_dim +
+    rope_dim`` wide and its values ``v_dim``, so the attention function sees
+    ``d_qk != d_v``; scores are over ``sqrt(nope_dim + rope_dim)``."""
+
+    num_heads: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
+        b, s, h = x.shape
+        n, nope = self.num_heads, self.nope_dim
+        dense = partial(nn.DenseGeneral, dtype=self.dtype,
+                        param_dtype=jnp.float32, use_bias=False,
+                        kernel_init=self.kernel_init)
+        with jax.named_scope("mla.project"):
+            q = dense(features=(n, nope + self.rope_dim), name="query")(x)
+            latent = dense(features=self.kv_rank + self.rope_dim,
+                           name="kv_a")(x)
+            kv = dense(features=(n, nope + self.v_dim), name="kv_b")(
+                RMSNorm(self.norm_eps, self.dtype, name="kv_norm")(
+                    latent[..., :self.kv_rank]))
+            pos = jnp.arange(s) if position_ids is None else position_ids
+            q_pe = rotary_embedding(q[..., nope:], pos, self.rope_theta)
+            k_pe = rotary_embedding(latent[:, :, None, self.kv_rank:], pos,
+                                    self.rope_theta)
+            q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                k_pe, (b, s, n, self.rope_dim))], axis=-1)
+            # [B, S, H, D] -> [B, H, S, D]
+            q, k, v = (t.transpose(0, 2, 1, 3)
+                       for t in (q, k, kv[..., nope:]))
+        attn = self.attention_fn or partial(
+            dot_product_attention, dtype=self.dtype, causal=True)
+        with jax.named_scope("mla.kernel"):
+            if segment_ids is not None:
+                out = attn(q, k, v, mask=mask, segment_ids=segment_ids)
+            else:
+                out = attn(q, k, v, mask=mask)
+        with jax.named_scope("mla.project"):
+            return dense(features=h, axis=(-2, -1), name="out")(
+                out.transpose(0, 2, 1, 3))
+
+
 class EncoderBlock(nn.Module):
     num_heads: int
     mlp_dim: int
@@ -278,8 +334,12 @@ class TransformerEncoder(nn.Module):
 
 
 class DecoderBlock(nn.Module):
-    """Pre-norm decoder layer ``x + attn(rmsnorm(x))``, ``x + moe(rmsnorm(x))``
-    with rotary, bias-free attention and a dropless expert layer."""
+    """Pre-norm decoder layer ``x + mixer(rmsnorm(x))``, ``x + ffn(rmsnorm(x))``
+    with rotary, bias-free attention. The two choices a layer makes are
+    fields: ``latent`` (None: :class:`SelfAttention` with a norm on queries
+    and keys, OLMoE's; a ``(kv_rank, nope_dim, rope_dim, v_dim)``:
+    :class:`LatentAttention`) and ``dense_dim`` (0: the dropless expert layer
+    that ``moe`` describes; > 0: a dense SwiGLU of that width)."""
 
     num_heads: int
     expert_dim: int
@@ -290,32 +350,49 @@ class DecoderBlock(nn.Module):
     init_std: float = 0.02
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
+    latent: Optional[tuple] = None
+    dense_dim: int = 0
+    moe: tuple = ()  # further fields of DroplessMoE, as (name, value) pairs
 
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, position_ids=None,
                  live=None):
-        from .moe import DroplessMoE
+        from .moe import DroplessMoE, SwiGLU
 
         norm = partial(RMSNorm, self.norm_eps, self.dtype)
         init = nn.initializers.truncated_normal(self.init_std)
         y = norm(name="ln_attn")(x)
         with jax.named_scope("attention"):
-            y = SelfAttention(self.num_heads, self.dtype,
-                              attention_fn=self.attention_fn, causal=True,
-                              use_bias=False, qk_norm_eps=self.norm_eps,
-                              rope_theta=self.rope_theta, kernel_init=init,
-                              name="attn")(y, mask, segment_ids, position_ids)
+            if self.latent is None:
+                mixer = SelfAttention(
+                    self.num_heads, self.dtype, attention_fn=self.attention_fn,
+                    causal=True, use_bias=False, qk_norm_eps=self.norm_eps,
+                    rope_theta=self.rope_theta, kernel_init=init, name="attn")
+            else:
+                mixer = LatentAttention(
+                    self.num_heads, *self.latent, self.norm_eps,
+                    self.rope_theta, self.dtype, self.attention_fn, init,
+                    name="attn")
+            y = mixer(y, mask, segment_ids, position_ids)
         x = x + y
-        y = DroplessMoE(self.num_experts, self.expert_dim,
-                        self.experts_per_token, self.dtype, kernel_init=init,
-                        name="moe")(norm(name="ln_mlp")(x), live)
+        y = norm(name="ln_mlp")(x)
+        if self.dense_dim:
+            with jax.named_scope("mlp.dense"):
+                y = SwiGLU(self.dense_dim, self.dtype, init, name="mlp")(y)
+        else:
+            y = DroplessMoE(self.num_experts, self.expert_dim,
+                            self.experts_per_token, self.dtype,
+                            kernel_init=init, name="moe",
+                            **dict(self.moe))(y, live)
         return x + y
 
 
 class TransformerDecoder(nn.Module):
-    """Decoder-only stack of today's open models' kind (OLMoE's layer):
-    RMSNorm, rotary positions instead of a position table, no biases, an
-    expert layer in every block, a final RMSNorm and an untied ``lm_head``.
+    """Decoder-only stack of today's open models' kind: RMSNorm, rotary
+    positions instead of a position table, no biases, a final RMSNorm and an
+    untied ``lm_head``. Each layer is a :class:`DecoderBlock`; OLMoE's are
+    all alike (ordinary attention, an expert layer), Moonlight's take latent
+    attention, and a dense SwiGLU in the first ``dense_layers`` of them.
     Same call signature as :class:`TransformerEncoder`, so the ``causal_lm``
     task drives either; logits ``[B, S, vocab]`` in f32.
     """
@@ -333,6 +410,18 @@ class TransformerDecoder(nn.Module):
     dtype: Any = jnp.bfloat16
     remat: bool = False
     attention_fn: Optional[Callable] = None
+    latent: Optional[tuple] = None  # DecoderBlock's, for every layer
+    dense_layers: int = 0  # this many leading layers are dense, dense_dim wide
+    dense_dim: int = 0
+    moe: tuple = ()  # DecoderBlock's, for every expert layer
+
+    @property
+    def attention_head_dim(self) -> int:
+        """Width of a head's queries and keys, what the attention function
+        chooses its path by."""
+        if self.latent is None:
+            return self.hidden_size // self.num_heads
+        return self.latent[1] + self.latent[2]
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, train: bool = True,
@@ -351,7 +440,9 @@ class TransformerDecoder(nn.Module):
             x = block(self.num_heads, self.expert_dim, self.num_experts,
                       self.experts_per_token, self.norm_eps, self.rope_theta,
                       self.init_std, self.dtype,
-                      attention_fn=self.attention_fn,
+                      attention_fn=self.attention_fn, latent=self.latent,
+                      dense_dim=self.dense_dim if i < self.dense_layers else 0,
+                      moe=self.moe,
                       name=f"layer_{i}")(x, mask, seg_kwarg, position_ids,
                                          live)
         x = RMSNorm(self.norm_eps, self.dtype, name="ln_final")(x)
@@ -383,3 +474,21 @@ olmoe_1b_7b = partial(TransformerDecoder, hidden_size=2048, num_layers=16,
 olmoe_tiny = partial(TransformerDecoder, hidden_size=64, num_layers=2,
                      num_heads=4, expert_dim=32, num_experts=8,
                      experts_per_token=2)
+# Moonlight-16B-A3B (moonshotai/Moonlight-16B-A3B config.json, model_type
+# deepseek_v3): 27 layers of latent attention (16 heads, keys 128 + 64 rotary,
+# values 128, from a 512-wide latent); the first layer a dense SwiGLU of
+# 11,264, the others 64 experts of 1,408 with 6 a token by sigmoid scores and
+# a selection bias (gamma 0.001: DeepSeek-V3, arXiv:2412.19437), renormalised,
+# times 2.446, beside one shared SwiGLU of 2 x 1,408.
+_MOONLIGHT_ROUTER = (("scoring", "sigmoid"), ("norm_topk", True),
+                     ("routed_scale", 2.446), ("bias_update_rate", 0.001))
+moonlight_16b_a3b = partial(
+    TransformerDecoder, hidden_size=2048, num_layers=27, num_heads=16,
+    expert_dim=1408, num_experts=64, experts_per_token=6, rope_theta=50000.0,
+    latent=(512, 128, 64, 128), dense_layers=1, dense_dim=11264,
+    moe=_MOONLIGHT_ROUTER + (("shared_dim", 2816),))
+moonlight_tiny = partial(
+    TransformerDecoder, hidden_size=64, num_layers=3, num_heads=4,
+    expert_dim=32, num_experts=8, experts_per_token=2, rope_theta=50000.0,
+    latent=(32, 16, 8, 16), dense_layers=1, dense_dim=128,
+    moe=_MOONLIGHT_ROUTER + (("shared_dim", 32),))

@@ -10,7 +10,11 @@ and keeps the weights for the backward pass. Two Pallas kernels avoid that:
   that keeps nothing but q, k and v;
 * the library's blocked kernel with online softmax
   (``jax.experimental.pallas.ops.tpu.flash_attention``) for longer ones
-  (OLMoE's 4,096).
+  (OLMoE's 4,096);
+* :func:`unequal_attention` for heads whose queries and keys are wider than
+  their values (latent attention: 192 and 128), which that kernel refuses
+  ("V model dimension unequal to KV model dimension unsupported"): the
+  library's splash kernel, which takes them as they are, at any length.
 
 ``make_flash_attention()`` returns a drop-in ``attention_fn`` for
 :class:`..models.transformer.SelfAttention`, in two strengths:
@@ -44,7 +48,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["make_flash_attention", "segment_attention_mask",
-           "short_attention"]
+           "short_attention", "unequal_attention"]
 
 
 def segment_attention_mask(segment_ids: jax.Array) -> jax.Array:
@@ -269,24 +273,85 @@ def short_attention(q, k, v, segment_ids=None, *, causal: bool = False,
     return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
 
+# -- heads of unequal width ----------------------------------------------------
+#
+# Latent attention's heads carry 128 + 64 rotary elements in queries and keys
+# and 128 in values. Padding v to 192 would satisfy the blocked kernel above
+# and waste a third of the context product; the library's splash kernel takes
+# ``d_qk != d_v`` (blocked online softmax, the causal upper blocks skipped,
+# logsumexp kept as ``[H, S]``). Its backward runs as two kernels (dkv, dq):
+# the fused one writes dq once per key block, 1.6 GB a row of 8,192 tokens.
+
+
+@functools.lru_cache(maxsize=8)
+def _splash_kernel(heads: int, seq: int, causal: bool, block_q: int,
+                   block_kv: int):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    one = (sm.CausalMask if causal else sm.FullMask)((seq, seq))
+    bq, bkv = min(block_q, seq), min(block_kv, seq)
+    sizes = sk.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkv,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
+        block_q_dq=bq, block_kv_dq=bkv, use_fused_bwd_kernel=False)
+    # the kernel object holds its block tables as arrays: made while a
+    # program is traced, they must be values and not that trace's tracers,
+    # or the next program to find the object here meets a leaked tracer
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mha(sm.MultiHeadMask([one] * heads),
+                                  block_sizes=sizes, head_shards=1,
+                                  q_seq_shards=1)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_kv"))
+def unequal_attention(q, k, v, segment_ids=None, *, causal: bool = False,
+                      block_q: int = 512, block_kv: int = 512):
+    """Fused attention for heads whose values are narrower than their
+    queries and keys: q, k ``[B, H, S, Dqk]``, v ``[B, H, S, Dv]`` (S a
+    multiple of 128), ``segment_ids`` ``[B, S]`` int32 or None, output
+    ``[B, H, S, Dv]``; scores over ``sqrt(Dqk)``. Jitted, so a model's
+    layers share one trace and one lowering of its three kernels."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    _, heads, seq, d = q.shape
+    kernel = _splash_kernel(heads, seq, causal, block_q, block_kv)
+    # the kernel has no scale of its own
+    q = (q.astype(jnp.float32) * (1.0 / float(d) ** 0.5)).astype(q.dtype)
+    ids = None if segment_ids is None else segment_ids.astype(jnp.int32)
+    # the kernel takes one row; rows are few at these lengths, and a vmap
+    # over them is more than Pallas's interpreter follows
+    return jnp.stack([
+        kernel(q[i], k[i], v[i], None if ids is None
+               else sk.SegmentIds(q=ids[i], kv=ids[i]))
+        for i in range(q.shape[0])])
+
+
 MIN_FUSED_SEQ = 256  # timed on the v5e at 16,384 tokens of 12 heads x 64
 # (PERF.md section 6, PR 29): at 128 dense attention is level with the kernel
 # (1.17 against 1.18-1.37 ms a layer), at 256 the kernel is 1.95 times ahead
 
 
 def fused_attention_applies(seq: int, head_dim: int, mesh=None,
-                            platform: Optional[str] = None) -> bool:
+                            platform: Optional[str] = None,
+                            value_dim: Optional[int] = None) -> bool:
     """The rule by which a sequence model that was given no attention
     function gets the fused kernel: on a TPU, for a sequence of whole
     128-key blocks from ``MIN_FUSED_SEQ`` up and heads of whole 64-lane
-    halves (BERT's 64, OLMoE's 128), over one device or a mesh that only
+    halves (BERT's 64, OLMoE's 128, latent attention's 192 with values of
+    ``value_dim`` 128), over one device or a mesh that only
     has a ``'data'`` axis (a ``'model'`` axis has met no chip). Everything
     else is dense attention, as before: the CPU, a ViT's 197 tokens, a
     tensor-parallel mesh, and several devices with no mesh to say how the
     batch is split."""
     if (platform or jax.default_backend()) != "tpu":
         return False
-    if seq < MIN_FUSED_SEQ or seq % _LANES or head_dim % 64:
+    if seq < MIN_FUSED_SEQ or seq % _LANES or head_dim % 64 or (
+            value_dim or head_dim) % 64:
         return False
     if mesh is None:
         return jax.device_count() == 1
@@ -310,7 +375,8 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
     :func:`fused_attention_applies` says so for the shapes it sees, and
     dense attention elsewhere. Either way sequences of up to ``SHORT_SEQ``
     whole blocks run :func:`short_attention`, longer ones the library's
-    blocked kernel.
+    blocked kernel, and heads whose values are narrower than their keys
+    :func:`unequal_attention`.
 
     ``mesh`` is the trainer's device mesh. XLA cannot partition a Mosaic
     kernel ("wrap the call in a shard_map"), so on more than one device the
@@ -330,6 +396,9 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
 
     def kernel(q, k, v, ids):
         seq = q.shape[2]
+        if q.shape[3] != v.shape[3]:
+            return unequal_attention(q, k, v, ids, causal=causal,
+                                     block_q=block_q, block_kv=block_k)
         if seq <= SHORT_SEQ and seq % _LANES == 0:
             return short_attention(q, k, v, ids, causal=causal)
         from jax.experimental.pallas.ops.tpu import flash_attention as fa
@@ -369,13 +438,14 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
     if over_mesh:
         split, whole = per_device("data"), per_device(None)
 
-    def fused(seq: int, head_dim: int) -> bool:
+    def fused(seq: int, head_dim: int,
+              value_dim: Optional[int] = None) -> bool:
         """Does a call with these shapes run the kernel?"""
         return use_pallas and (forced or fused_attention_applies(
-            seq, head_dim, mesh, platform))
+            seq, head_dim, mesh, platform, value_dim))
 
     def attention_fn(q, k, v, mask=None, dtype=None, segment_ids=None):
-        if not fused(q.shape[2], q.shape[3]):
+        if not fused(q.shape[2], q.shape[3], v.shape[3]):
             from ..models.transformer import dot_product_attention
 
             if segment_ids is not None:

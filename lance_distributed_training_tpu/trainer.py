@@ -66,7 +66,9 @@ __all__ = [
 
 
 class TrainState(train_state.TrainState):
-    """TrainState + mutable batch-norm statistics (None for stateless models)."""
+    """TrainState + the model's non-trainable collection that a training
+    step updates: batch-norm statistics, or the selection bias of sigmoid
+    routers (None for stateless models)."""
 
     batch_stats: Any = None
 
@@ -181,6 +183,10 @@ class TrainConfig:
     num_layers: int = 0  # >0: this many layers of a transformer preset in
     # place of its own depth (one chip's share of a published model, at
     # every published width); 0 keeps the preset's
+    expert_share: Optional[str] = None  # "rank/ranks": the experts of each
+    # dropless layer that this rank of an expert-parallel job holds (the
+    # router stays whole); with vocab_size as the vocabulary's slice, the
+    # share of a stated deployment that this chip runs. None: all of them
     prefetch: int = 2
     producer_threads: int = 4  # decode-producer threads
     placement_depth: int = 2  # device-resident batches the placement ring
@@ -343,6 +349,7 @@ def _task_from_config(config: TrainConfig, mesh=None) -> Task:
         seq_len=config.seq_len,
         vocab_size=config.vocab_size,
         num_layers=config.num_layers,
+        expert_share=config.expert_share,
         augment=config.augment,
         attention_fn=attention_fn,
         remat=config.remat,
@@ -364,8 +371,8 @@ def _attention_fused(task: Task, config: TrainConfig) -> Optional[float]:
         return None
     model = task.model
     fused = getattr(getattr(model, "attention_fn", None), "fused", None)
-    on = bool(fused and fused(config.seq_len,
-                              model.hidden_size // model.num_heads))
+    on = bool(fused and fused(config.seq_len, getattr(
+        model, "attention_head_dim", model.hidden_size // model.num_heads)))
     default_registry().gauge("attention_fused").set(float(on))
     return float(on)
 
@@ -1664,7 +1671,8 @@ class _StepStats:
     head); any other name is a gauge of the step just logged, which rides
     the log line too (``moe_expert_load_max`` / ``_mean``,
     ``mlm_head_capacity_tokens``, ``mlm_head_fill_pct``). The loss fetch
-    has already waited for that step, so nothing here waits again."""
+    has already waited for that step, so nothing here waits again; all of
+    them come to the host in one transfer."""
 
     def __init__(self):
         self._sums = None
@@ -1677,17 +1685,28 @@ class _StepStats:
         self._sums, self._last = sums, stats
 
     def publish(self, entry: dict) -> None:
-        if self._last is None:
-            return
+        if not self._last:  # no step since the last log point, or a task
+            return  # with nothing to report
         registry = default_registry()
-        for name, total in self._sums.items():
-            registry.counter(name).inc(float(total))  # ldt: ignore[LDT1704] -- log-point fetch of a scalar the drained step produced
-        for name, value in self._last.items():
-            if name not in self._sums:
-                value = float(value)  # ldt: ignore[LDT1704] -- same log-point fetch
+        # one array, one fetch: the device has just been drained, and every
+        # round trip (one a scalar, 6 ms each on the v5e's host) is time in
+        # which it has nothing to run
+        names = list(self._sums) + [n for n in self._last
+                                    if n not in self._sums]
+        values = np.asarray(_pack_scalars(  # ldt: ignore[LDT1704] -- log-point fetch of scalars the drained step produced
+            [self._sums.get(n, self._last[n]) for n in names]))
+        for name, value in zip(names, values.tolist()):
+            if name in self._sums:
+                registry.counter(name).inc(value)
+            else:
                 registry.gauge(name).set(value)
                 entry[name] = round(value, 4)
         self._sums = self._last = None
+
+
+@jax.jit
+def _pack_scalars(scalars):
+    return jnp.stack([jnp.asarray(x, jnp.float32) for x in scalars])
 
 
 def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,

@@ -219,6 +219,33 @@ def test_short_attention_compiles_for_a_v5e_at_real_widths(one_chip, shape,
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("rows,with_ids", [(1, True), (2, False)])
+def test_unequal_attention_compiles_for_a_v5e_at_moonlights_widths(
+        one_chip, rows, with_ids):
+    """Latent attention's heads as the Moonlight cell runs them: 16 heads,
+    8,192 tokens, queries and keys of 192, values of 128, unpadded: the
+    forward kernel and the two backward kernels, with and without segment
+    ids, and no ``[B, H, S, S]`` tensor anywhere in the program."""
+    qk = jax.ShapeDtypeStruct((rows, 16, 8192, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((rows, 16, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((rows, 8192), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, ids):
+        out = flash.unequal_attention(q, k, v, ids if with_ids else None,
+                                      causal=True)
+        assert out.shape == v.shape
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v, ids).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3 * rows
+    assert "8192,8192]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # -- BERT's encoder with the chosen kernel -----------------------------------
 
 

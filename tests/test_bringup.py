@@ -227,6 +227,116 @@ def test_benchmark_fixture_check_passes(script, closing):
     assert proc.stdout.strip().splitlines()[-1] == closing
 
 
+# -- the Moonlight cell's readers and its rehearsal ---------------------------
+
+_STEP = "jit(step)/jvp(forward)/TransformerDecoder/layer_1/"
+_BACK = "jit(step)/transpose(jvp(forward))/TransformerDecoder/layer_1/"
+# op_name -> ps in one run of the step: a hand-made plane with the scopes
+# the Moonlight layers name (models/transformer.py, models/moe.py)
+_MOONLIGHT_OPS = {
+    _STEP + "attention/attn/mla.project/query/dot_general": 2_000_000_000,
+    _STEP + "attention/attn/mla.kernel/splash_mha_fwd": 20_000_000_000,
+    _BACK + "attention/attn/mla.kernel/splash_mha_dkv": 40_000_000_000,
+    _STEP + "moe/moe.shared/shared/gate/dot_general": 3_000_000_000,
+    _STEP + "moe/moe.router/router/dot_general": 100_000_000,
+    _STEP + "moe/moe.dispatch/sort": 400_000_000,
+    _BACK + "moe/checkpoint/moe.experts/mul": 4_000_000_000,
+    "ragged-dot-none": 12_000_000_000,
+    _BACK + "moe/moe.combine/mul": 250_000_000,
+    "jit(step)/jvp(forward)/TransformerDecoder/layer_0/mlp.dense/mlp/up/"
+    "dot_general": 5_000_000_000,
+    "jit(step)/optimizer/add": 1_000_000_000,
+}
+_MOONLIGHT_WANT = {  # ms a step, or the share the reader makes of them
+    "mla_attention_ms": 62.0,
+    "mla_kernel_roofline_pct": None,  # computed below from the flops file
+    "moe_shared_ms": 3.0,
+    "moe_local_routed_ms": 16.75,
+    "moe_local_experts_roofline_pct": None,
+    "moe_local_load_max_over_mean": 1.5,
+}
+
+
+def _moonlight_ctx(ops: dict) -> dict:
+    """What ``benchmark/run.py`` hands a reader, around a plane with two
+    runs of ``jit_step(7)`` whose operations are ``ops``."""
+    import json
+
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import run
+
+    config = json.load(open(os.path.join(
+        REPO, "benchmark", "configs", "moonlight-16b-a3b-c4.json")))
+    metadata = {1: {"name": "jit_step(7)", "tf_op": "", "program_id": None,
+                    "category": ""}}
+    events = []
+    for i, (name, ps) in enumerate(ops.items(), start=2):
+        metadata[i] = {"name": f"%op.{i}", "tf_op": name, "program_id": 7,
+                       "category": ""}
+        events.append((i, ps))
+    run_ps = sum(ops.values()) + 1000
+    plane_ops, at = [], 0
+    for start in (0, run_ps):
+        at = start
+        for i, ps in events:
+            plane_ops.append([i, at, ps])
+            at += ps
+    points = [{"t": t, "counters": {"moe_local_load_max": 1152.0,
+                                    "moe_local_load_mean": 768.0}}
+              for t in (10, 20, 30)]
+    return {
+        "_scope_plane": {"metadata": metadata, "ops": plane_ops,
+                         "modules": [[1, 0, run_ps], [1, run_ps, run_ps]]},
+        "cell": {"name": "c4-moonlight-ep8-prepacked-8k", "config": config},
+        "chips": 1, "steps": 100, "window_ns": (10, 30),
+        "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        "flops": run.load_module("flops", "moonlight-16b-a3b-c4"),
+        "counters": {"moe_local_assignments_total": 100 * 5 * 6144.0},
+        "step_shapes": [{"input_ids": (1, 8192)}], "all_step_shapes": [],
+        "log_points": points,
+    }
+
+
+@pytest.mark.parametrize("metric", sorted(_MOONLIGHT_WANT))
+def test_moonlight_reader_reads_the_scopes_the_layers_name(metric):
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import run
+
+    ctx = _moonlight_ctx(_MOONLIGHT_OPS)
+    value = run.load_module("layer_metrics", metric).read(ctx)
+    want = _MOONLIGHT_WANT[metric]
+    model, flops = ctx["cell"]["config"]["model"], ctx["flops"]
+    if metric == "mla_kernel_roofline_pct":
+        want = 100 * flops.attention_flops(model, 1, 8192) / 197e12 / 0.060
+    if metric == "moe_local_experts_roofline_pct":
+        want = 100 * flops.expert_flops(model, 5 * 6144.0) / 197e12 / 0.016
+    assert value == pytest.approx(want, rel=1e-6)
+    assert 0 < value < 100 or metric.endswith("_ms") or "load" in metric
+    # on a program without these scopes and counters (the parent): nothing,
+    # and no error
+    bare = _moonlight_ctx({"jit(step)/jvp(forward)/TransformerDecoder/"
+                           "layer_0/attn/dot_general": 1_000_000})
+    bare["counters"], bare["log_points"] = {}, [
+        {"t": 20, "counters": {}}]
+    assert run.load_module("layer_metrics", metric).read(bare) is None
+
+
+def test_moonlight_cell_rehearses_end_to_end_on_the_cpu():
+    """``benchmark/run.py``'s whole path for the cell at the tiny preset,
+    untraced and traced: the generator, the model check against the
+    reference under a share, ``train`` with ``--expert_share``, the log-point
+    clock, the stop, the readers."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "rehearse.py"),
+         "--cells", "c4-moonlight-ep8-prepacked-8k", "--checks", "0"],
+        cwd=REPO, capture_output=True, text=True, timeout=900,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == "rehearsal ok"
+    assert "moe_local_load_max_over_mean" in proc.stdout
+
+
 # -- chip_smoke.py -----------------------------------------------------------
 
 
