@@ -28,7 +28,7 @@ Mixture-of-Experts layers.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -149,51 +149,86 @@ _permute_rows.defvjp(
     lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
 
 
+class _Way(NamedTuple):
+    """Where the ``R`` built rows of a rank's sorted list and the ``T`` tokens
+    find each other: integers and booleans only."""
+
+    head: jax.Array  # [R] the assignment (token * k + slot) a row stands for
+    live: jax.Array  # [R] the row is in a held expert's group
+    pos: jax.Array  # [T, k] where each assignment sits in the whole list
+    valid: jax.Array  # [T, k] it sits in a held expert's group
+
+
+def _sum_slots(rows, way, weights=None):
+    """Each token's live rows summed in f32, [R, H] -> [T, H] f32, each row
+    times its assignment's weight (``weights`` [T, k]) first if given: one
+    loop over the k slots, each turn a gather of ``[T, H]`` in the rows' own
+    type with a token's absent slot left out. Nothing is ``[T, k, H]``, and
+    nothing is rounded between the product and the sum. (Written out as k
+    gathers it ran no faster and cost the compiler 10 s a start: PERF.md.)"""
+    t, k = way.pos.shape
+    pos = jnp.minimum(way.pos, rows.shape[0] - 1).T  # [k, T]
+    held = way.valid.T
+    scale = None if weights is None else jnp.asarray(weights).T
+
+    def slot(j, y):
+        row = jnp.take(rows, pos[j], axis=0).astype(jnp.float32)
+        if scale is not None:
+            row = row * scale[j][:, None]
+        return y + jnp.where(held[j][:, None], row, 0)
+
+    return jax.lax.fori_loop(
+        0, k, slot, jnp.zeros((t, rows.shape[1]), jnp.float32))
+
+
 @jax.custom_vjp
-def _rows_out(x, src, live, pos, valid):
-    """The first ``len(src)`` rows of the sorted list from the tokens ``x``
-    [T, H]: row r is ``x[src[r]]`` where ``live[r]`` (the row is in a held
-    expert's group) and zeros elsewhere. ``pos`` [T, k] is where each
-    token's assignments sit in the list and ``valid`` [T, k] which of them
-    are in a group: the cotangent is gathered through them, as
-    :func:`_permute_rows`'s is through the inverse."""
-    return jnp.where(live[:, None], jnp.take(x, src, axis=0), 0)
-
-
-def _rows_out_bwd(res, g):
-    pos, valid = res
-    back = jnp.take(g, jnp.minimum(pos, g.shape[0] - 1), axis=0)  # [T, k, H]
-    return (jnp.where(valid[..., None], back, 0).sum(1), None, None, None,
-            None)
-
-
-_rows_out.defvjp(
-    lambda x, src, live, pos, valid: (_rows_out(x, src, live, pos, valid),
-                                      (pos, valid)),
-    _rows_out_bwd)
+def _tokens_to_rows(x, way):
+    """The built rows from the tokens ``x`` [T, H]: row r is the token its
+    assignment belongs to where the row is live, zeros elsewhere. The
+    transpose of :func:`_rows_to_tokens`, and the other way round: each is
+    the other's cotangent."""
+    k = way.pos.shape[1]
+    return jnp.where(way.live[:, None], jnp.take(x, way.head // k, axis=0), 0)
 
 
 @jax.custom_vjp
-def _rows_back(out, pos, valid, head, live):
-    """Each token's k results from the sorted rows ``out`` [R, H]:
-    ``out[pos[t, j]]`` where ``valid[t, j]``, zeros for an assignment that
-    landed on no held expert. ``head`` [R] is the assignment each sorted
-    row belongs to and ``live`` [R] whether it is in a group: the cotangent
-    of ``out`` is gathered through them."""
-    back = jnp.take(out, jnp.minimum(pos, out.shape[0] - 1), axis=0)
-    return jnp.where(valid[..., None], back, 0)
+def _rows_to_tokens(rows, way):
+    """Each token the sum of its live rows, [R, H] -> [T, H], summed in f32
+    and returned in the rows' type."""
+    return _sum_slots(rows, way).astype(rows.dtype)
 
 
-def _rows_back_bwd(res, g):
-    head, live = res
-    rows = jnp.take(g.reshape(-1, g.shape[-1]), head, axis=0)
-    return jnp.where(live[:, None], rows, 0), None, None, None, None
+_tokens_to_rows.defvjp(
+    lambda x, way: (_tokens_to_rows(x, way), way),
+    lambda way, g: (_rows_to_tokens(g, way), None))
+_rows_to_tokens.defvjp(
+    lambda rows, way: (_rows_to_tokens(rows, way), way),
+    lambda way, g: (_tokens_to_rows(g, way), None))
 
 
-_rows_back.defvjp(
-    lambda out, pos, valid, head, live: (
-        _rows_back(out, pos, valid, head, live), (head, live)),
-    _rows_back_bwd)
+@jax.custom_vjp
+def _sum_back(out, top_p, way):
+    """The weighted sum back, [T, H] f32: the sorted rows ``out`` [R, H]
+    each times its assignment's ``top_p`` [T, k] in f32, summed to their
+    tokens. Its cotangents come from the R rows that :func:`_tokens_to_rows`
+    makes of the ``[T, H]`` one, and ``top_p``'s is a gather of R dot
+    products: nothing is ``[T, k, H]``."""
+    return _sum_slots(out, way, top_p)
+
+
+def _sum_back_bwd(res, g):
+    out, top_p, way = res
+    g_rows = _tokens_to_rows(g, way)  # [R, H] f32
+    d_out = jnp.take(top_p.reshape(-1), way.head)[:, None] * g_rows
+    d_row = (out.astype(jnp.float32) * g_rows).sum(-1)  # [R]
+    d_top_p = jnp.where(way.valid, jnp.take(
+        d_row, jnp.minimum(way.pos, out.shape[0] - 1)), 0)
+    return d_out.astype(out.dtype), d_top_p.astype(top_p.dtype), None
+
+
+_sum_back.defvjp(
+    lambda out, top_p, way: (_sum_back(out, top_p, way), (out, top_p, way)),
+    _sum_back_bwd)
 
 
 class DroplessMoE(nn.Module):
@@ -225,9 +260,17 @@ class DroplessMoE(nn.Module):
     that many rows, and in a step whose routing sends more than that here
     it builds all ``T * k`` instead, the bound no routing exceeds (a
     ``lax.cond`` on the count: exact either way, no token is ever dropped;
-    ``moe_stats``/``over_usual`` says which). The gather, the products and
-    the sum back are then recomputed in the backward pass, so that a layer
-    keeps its ``[T, H]`` input and not the rows of every intermediate.
+    ``moe_stats``/``over_usual`` says which, ``row_fill`` how much of the
+    built list was live). Between the ``[T, H]`` tokens and the ``[R, H]``
+    built rows lie two operations that are each other's transposes: tokens
+    -> rows (a live row is its token, a dead one zeros) and rows -> tokens
+    (a token is the sum of its live rows, in f32, slot after slot). The sum
+    back weights each row by its assignment's ``top_p`` in f32 on the way, and
+    its cotangents come from the R rows of the ``[T, H]`` one: nothing
+    shaped ``[T, k, H]`` is gathered, multiplied or broadcast, forward or
+    backward. The gather, the products and the sum back are recomputed in
+    the backward pass, so that a layer keeps its ``[T, H]`` input and not
+    the rows of every intermediate.
 
     Sows its auxiliary terms into ``aux_loss`` (softmax: the OLMoE paper's ``load_balance`` = ``E · Σ_e f_e
     · P_e`` and ``router_z`` = ``mean(logsumexp(logits)²)``; sigmoid: the
@@ -235,9 +278,9 @@ class DroplessMoE(nn.Module):
     over their sum, per row and averaged; all over live tokens, unweighted)
     and into ``moe_stats`` the experts' assignment counts (``group_sizes``,
     all ``num_experts``), under a share those of the held experts
-    (``held_sizes``, ``over_usual``), and with a bias its largest magnitude
-    (``bias_abs_max``); ``live`` [B, S] marks the tokens that count (None:
-    all).
+    (``held_sizes``, ``over_usual``, ``row_fill``), and with a bias its
+    largest magnitude (``bias_abs_max``); ``live`` [B, S] marks the tokens
+    that count (None: all).
     """
 
     num_experts: int
@@ -321,6 +364,7 @@ class DroplessMoE(nn.Module):
             group_sizes = jnp.diff(ends, prepend=0)
 
         over = jnp.zeros((), jnp.float32)
+        fill = jnp.full((), 100.0)  # live rows over built rows, in percent
         if not a_share:
             with jax.named_scope("moe.dispatch"):
                 xs = _permute_rows(
@@ -345,15 +389,12 @@ class DroplessMoE(nn.Module):
                 def run(tokens, top_p, w_gate, w_up, w_down, order, pos,
                         valid, ends, group_sizes):
                     with jax.named_scope("moe.dispatch"):
-                        head = order[:rows]
-                        live_rows = jnp.arange(rows) < ends[-1]
-                        xs = _rows_out(tokens.astype(self.dtype), head // k,
-                                       live_rows, pos, valid)
+                        way = _Way(order[:rows], jnp.arange(rows) < ends[-1],
+                                   pos, valid)
+                        xs = _tokens_to_rows(tokens.astype(self.dtype), way)
                     out = experts(xs, group_sizes, w_gate, w_up, w_down)
                     with jax.named_scope("moe.combine"):
-                        y = _rows_back(out, pos, valid, head, live_rows)
-                        return (y.astype(jnp.float32)
-                                * top_p[..., None]).sum(1)
+                        return _sum_back(out, top_p, way)
                 return run
 
             # Twice the rows of an even routing, in whole tiles, and in a
@@ -367,6 +408,7 @@ class DroplessMoE(nn.Module):
             # that closed over them would leak them under ``--remat``.
             usual = min(-(-2 * t * k * held // (e * 128)) * 128, t * k)
             over = (ends[-1] > usual).astype(jnp.float32)
+            fill = 100.0 * ends[-1] / jnp.where(over > 0, t * k, usual)
 
             def is_over(inputs):  # from the arguments' own ``ends``
                 return inputs[8][-1] > usual
@@ -434,6 +476,7 @@ class DroplessMoE(nn.Module):
         if self.held_experts:
             self.sow("moe_stats", "held_sizes", group_sizes)
             self.sow("moe_stats", "over_usual", over)
+            self.sow("moe_stats", "row_fill", fill)
         y = y.astype(self.dtype)
         if self.shared_dim:
             with jax.named_scope("moe.shared"):

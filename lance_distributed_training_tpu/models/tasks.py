@@ -360,8 +360,9 @@ def _expert_load(sown: dict) -> dict:
     layer) all assignments, and the busiest and the mean expert over all
     layers; under a share the same of the experts held here
     (``held_sizes``: ``moe_local_*``) and how many layers built the
-    worst-case list (``over_usual``); with a selection bias its largest
-    magnitude over the layers."""
+    worst-case list (``over_usual``) and how full the built lists were
+    (``row_fill``: live rows over built rows, in percent, mean over the
+    layers); with a selection bias its largest magnitude over the layers."""
     by_name: dict = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
         by_name.setdefault(path[-2].key, []).append(leaf)
@@ -378,6 +379,7 @@ def _expert_load(sown: dict) -> dict:
         # layers whose held rows overflowed the usual list this step
         out["moe_local_fallback_total"] = jnp.stack(
             by_name["over_usual"]).sum()
+        out["moe_local_row_fill_pct"] = jnp.stack(by_name["row_fill"]).mean()
     if "bias_abs_max" in by_name:
         out["moe_router_bias_abs_max"] = jnp.stack(
             by_name["bias_abs_max"]).max()

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lance_distributed_training_tpu.models import get_task, tasks
+from lance_distributed_training_tpu.models import get_task, moe, tasks
 from lance_distributed_training_tpu.models.moe import DroplessMoE, SwiGLU
 from lance_distributed_training_tpu.models.transformer import moonlight_tiny
 
@@ -519,6 +519,173 @@ def test_absent_assignments_pass_no_gradient_to_their_tokens(whole_layer):
     assert np.isfinite(np.asarray(grad)).all()
 
 
+# -- between the tokens and the built rows ------------------------------------
+
+WAY_T, WAY_K, WAY_E, WAY_HELD, WAY_H = 64, 6, 16, 8, 16
+
+
+def _way_of(top_e, rows):
+    """The layer's own dispatch of a routing ``top_e`` [T, k] when it holds
+    the first ``WAY_HELD`` experts: the first ``rows`` of the sorted list."""
+    t, k = top_e.shape
+    flat = jnp.minimum(top_e.reshape(t * k), WAY_HELD)
+    order = jnp.argsort(flat, stable=True)
+    pos = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=order.dtype)).reshape(t, k)
+    live_rows = (flat < WAY_HELD).sum()
+    return moe._Way(order[:rows], jnp.arange(rows) < live_rows, pos,
+                    pos < live_rows)
+
+
+def _a_routing(case):
+    top_e = jax.lax.top_k(jax.random.uniform(
+        jax.random.key(3), (WAY_T, WAY_E)), WAY_K)[1]
+    top_e = top_e.at[0].set(jnp.arange(WAY_K))  # all six on held experts
+    top_e = top_e.at[1].set(WAY_HELD + jnp.arange(WAY_K))  # none
+    rows = WAY_T * WAY_K if case == "every_row_built" else 256
+    way = _way_of(top_e, rows)
+    live = int(way.live.sum())
+    assert live == int(way.valid.sum())
+    count = way.valid.sum(1)
+    assert {"dead_rows_at_the_end": 0 < live < rows and not bool(
+                way.live[live:].any()),
+            "all_six_slots_held": int(count[0]) == WAY_K,
+            "no_slot_held": int(count[1]) == 0,
+            "every_row_built": rows == WAY_T * WAY_K}[case]
+    return way, rows
+
+
+@pytest.mark.parametrize("case", ["dead_rows_at_the_end", "all_six_slots_held",
+                                  "no_slot_held", "every_row_built"])
+def test_the_two_ways_are_each_others_transposes(case):
+    """What autodiff makes of tokens -> rows as it is written (a scatter-add)
+    is rows -> tokens, and of rows -> tokens (k gathers, scatter-adds) tokens
+    -> rows: f32, the order of at most six terms apart."""
+    way, rows = _a_routing(case)
+    x = jax.random.normal(jax.random.key(4), (WAY_T, WAY_H))
+    r = jax.random.normal(jax.random.key(5), (rows, WAY_H))
+    plain_out, plain_back = moe._tokens_to_rows.fun, moe._rows_to_tokens.fun
+    np.testing.assert_allclose(
+        np.asarray(jax.vjp(lambda x: plain_out(x, way), x)[1](r)[0]),
+        np.asarray(moe._rows_to_tokens(r, way)), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(jax.vjp(lambda r: plain_back(r, way), r)[1](x)[0]),
+        np.asarray(moe._tokens_to_rows(x, way)), rtol=1e-5, atol=1e-5)
+    # and the rules as they stand: <out(x), r> = <x, back(r)>
+    assert float((moe._tokens_to_rows(x, way) * r).sum()) == pytest.approx(
+        float((x * moe._rows_to_tokens(r, way)).sum()), rel=1e-4)
+    # a token's rows are those of its own held assignments, nothing else
+    want = np.zeros((WAY_T, WAY_H), np.float32)
+    live, token = np.asarray(way.live), np.asarray(way.head) // WAY_K
+    np.add.at(want, token[live], np.asarray(r)[live])
+    np.testing.assert_allclose(np.asarray(moe._rows_to_tokens(r, way)), want,
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["dead_rows_at_the_end", "every_row_built"])
+def test_weighted_sum_back_has_the_gradients_of_its_own_arithmetic(case):
+    from jax.test_util import check_grads
+
+    way, rows = _a_routing(case)
+    out = jax.random.normal(jax.random.key(6), (rows, WAY_H))
+    top_p = jax.random.uniform(jax.random.key(7), (WAY_T, WAY_K),
+                               minval=0.1)
+    check_grads(lambda out, top_p: moe._sum_back(out, top_p, way),
+                (out, top_p), order=1, modes=["rev"], atol=2e-2, rtol=2e-2)
+    want = (jnp.where(way.valid[..., None], jnp.take(
+        out, jnp.minimum(way.pos, rows - 1), axis=0), 0)
+        * top_p[..., None]).sum(1)  # each token gathers its k results
+    np.testing.assert_allclose(np.asarray(moe._sum_back(out, top_p, way)),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def _shapes_in(jaxpr, seen):
+    """Every value's shape in a jaxpr and in the jaxprs of its equations
+    (a ``cond``'s branches, a jitted call, a differentiation rule)."""
+    def inner(value):
+        if hasattr(value, "jaxpr") and hasattr(value, "consts"):
+            yield value.jaxpr
+        elif hasattr(value, "eqns"):
+            yield value
+        elif isinstance(value, (tuple, list)):
+            for v in value:
+                yield from inner(v)
+
+    for eqn in jaxpr.eqns:
+        for var in (*eqn.invars, *eqn.outvars):
+            if hasattr(var.aval, "shape"):
+                seen.add(tuple(var.aval.shape))
+        for value in eqn.params.values():
+            for sub in inner(value):
+                _shapes_in(sub, seen)
+    return seen
+
+
+def test_nothing_under_a_share_is_tokens_by_slots_by_width():
+    """The forward and the backward pass of the layer under a share hold no
+    value shaped [T, k, H], whatever its type: a token does not gather its k
+    results, and no cotangent is broadcast over the slots."""
+    t, h = 2 * 512, 64
+    x = jax.random.normal(jax.random.key(0), (2, 512, h))
+    layer = _expert_layer(first_expert=2, held_experts=2, shared_dim=0)
+    variables = layer.init(jax.random.key(1), x)
+
+    def program(params, x):
+        return (layer.apply(dict(variables, params=params), x) ** 2).sum()
+
+    traced = jax.make_jaxpr(jax.value_and_grad(program, argnums=(0, 1)))(
+        variables["params"], x)
+    shapes = _shapes_in(traced.jaxpr, set())
+    assert (1024, h) in shapes  # the list twice an even share long: it saw
+    assert (t * TOP_K, h) in shapes  # the branches, the worst case's too
+    assert (t, TOP_K, h) not in shapes
+    assert (t, TOP_K, EXPERTS) in shapes  # what it would have looked like
+    forward = jax.make_jaxpr(program)(variables["params"], x)
+    assert (t, TOP_K, h) not in _shapes_in(forward.jaxpr, set())
+
+
+ALL_HELD_TEXT = (
+    "daf3e6c178e24877f7941af9e55f75595196b236e423bd23bf04b48e8627cafc")
+
+
+def test_layer_that_holds_all_its_experts_lowers_as_it_did():
+    """OLMoE's branch (every expert held) is left as it was: the lowered
+    text of its forward and backward pass hashes as at PR 30. Pin it anew
+    only with a change that means to touch that branch."""
+    import hashlib
+
+    layer = DroplessMoE(num_experts=8, expert_dim=32, experts_per_token=2,
+                        dtype=jnp.float32)
+    x = jax.ShapeDtypeStruct((4, 32, 64), jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.key(0), jnp.zeros(x.shape))["params"])
+
+    def program(params, x):
+        return (layer.apply({"params": params}, x) ** 2).sum()
+
+    text = jax.jit(jax.value_and_grad(program, argnums=(0, 1))).lower(
+        params, x).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == ALL_HELD_TEXT
+
+
+@pytest.mark.parametrize("bias_on_held,built", [(10.0, 2048), (0.0, 1024)])
+def test_row_fill_is_the_live_rows_over_the_rows_built(bias_on_held, built):
+    """A step that sends every assignment here builds all T x k rows, and
+    the fill is the live rows over those; the usual list's is over its own
+    length."""
+    x = jax.random.normal(jax.random.key(0), (2, 512, 64))
+    layer = _expert_layer(first_expert=2, held_experts=2, shared_dim=0)
+    params = layer.init(jax.random.key(1), x)["params"]
+    bias = jnp.zeros((EXPERTS,)).at[2:4].set(bias_on_held)
+    _, sown = layer.apply({"params": params, "router_state": {"bias": bias}},
+                          x, mutable=["moe_stats", "aux_loss"])
+    stats = sown["moe_stats"]
+    live = int(stats["held_sizes"][0].sum())
+    assert float(stats["over_usual"][0]) == float(built == 2048)
+    assert 0 < live <= built
+    assert float(stats["row_fill"][0]) == pytest.approx(100 * live / built)
+
+
 def test_shared_expert_and_dense_layer_are_one_module():
     x = jax.random.normal(jax.random.key(0), (2, 8, 64))
     module = SwiGLU(32, jnp.float32)
@@ -574,13 +741,18 @@ def test_step_reports_the_share_and_no_drop_counter(bf16_task, variables,
         "moe_assignments_total", "moe_expert_load_max", "moe_expert_load_mean",
         "moe_local_assignments_total", "moe_local_load_max",
         "moe_local_load_mean", "moe_local_fallback_total",
-        "moe_router_bias_abs_max"}
+        "moe_local_row_fill_pct", "moe_router_bias_abs_max"}
     layers, tokens = 2, ROWS * SEQ
     assert float(stats["moe_assignments_total"]) == tokens * TOP_K * layers
     assert 0 < float(stats["moe_local_assignments_total"]) < float(
         stats["moe_assignments_total"])
     assert float(stats["moe_local_load_mean"]) == pytest.approx(
         float(stats["moe_local_assignments_total"]) / (layers * 4))
+    # 128 tokens x 2, of which a half lands here at even routing: 128 live
+    # rows in the 256 each layer builds, 50% give or take this routing
+    assert float(stats["moe_local_row_fill_pct"]) == pytest.approx(
+        100 * float(stats["moe_local_assignments_total"]) / (layers * 256))
+    assert 25 < float(stats["moe_local_row_fill_pct"]) < 75
 
 
 def test_train_step_carries_the_bias_in_the_train_state(bf16_task, variables,
