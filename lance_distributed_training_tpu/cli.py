@@ -186,6 +186,16 @@ def build_parser() -> argparse.ArgumentParser:
                         "experts would add is left out. With --vocab_size as "
                         "the vocabulary's slice and --num_layers, one chip's "
                         "share of an expert-parallel job. Default: all")
+    p.add_argument("--layer_span", type=str, default=None,
+                   metavar="FIRST:END",
+                   help="the published layers [FIRST, END) that this process "
+                        "holds of a preset whose layers differ by kind "
+                        "(phi4_mini_flash*: 14:20 is M, S, M*, F*, G, X, one "
+                        "of each), as a pipeline stage would; a layer keeps "
+                        "its published index, and a span in which a G or X "
+                        "layer has no M* or F* before it is refused. With "
+                        "--vocab_size as the vocabulary's slice, one chip's "
+                        "share of a stated deployment. Default: all layers")
     p.add_argument("--no_compile_cache", action="store_true",
                    help="set up no persistent XLA compile cache (by default "
                         "accelerator runs cache under <checkout>/.jax_cache; "
@@ -876,6 +886,7 @@ def main(argv=None) -> dict:
         model_name=args.model_name,
         num_layers=args.num_layers,
         expert_share=args.expert_share,
+        layer_span=args.layer_span,
         pretrained=args.pretrained,
         compile_cache=not args.no_compile_cache,
         image_size=args.image_size,

@@ -35,6 +35,8 @@ from .transformer import (
     gpt_small,
     moonlight_16b_a3b,
     moonlight_tiny,
+    phi4_mini_flash,
+    phi4_mini_flash_tiny,
     olmoe_1b_7b,
     olmoe_tiny,
 )
@@ -339,6 +341,8 @@ _CAUSAL_LMS: dict = {
     "olmoe_tiny": (olmoe_tiny, 512, _OLMOE_AUX),
     "moonlight_16b_a3b": (moonlight_16b_a3b, 163840, _MOONLIGHT_AUX),
     "moonlight_tiny": (moonlight_tiny, 512, _MOONLIGHT_AUX),
+    "phi4_mini_flash": (phi4_mini_flash, 200064, {}),
+    "phi4_mini_flash_tiny": (phi4_mini_flash_tiny, 512, {}),
 }
 
 
@@ -354,6 +358,15 @@ def _weighted_aux(sown: dict, weights: dict):
                jnp.zeros((), jnp.float32))
 
 
+def _sown_by_name(sown: dict) -> dict:
+    """What the layers sowed into one collection, by the name it was sown
+    under: a list with one entry a layer that sowed it, in layer order."""
+    by_name: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+        by_name.setdefault(path[-2].key, []).append(leaf)
+    return by_name
+
+
 def _expert_load(sown: dict) -> dict:
     """The step's expert-load scalars from what the expert layers sow into
     ``moe_stats``: from their assignment counts (``group_sizes``, [E] a
@@ -363,9 +376,7 @@ def _expert_load(sown: dict) -> dict:
     worst-case list (``over_usual``) and how full the built lists were
     (``row_fill``: live rows over built rows, in percent, mean over the
     layers); with a selection bias its largest magnitude over the layers."""
-    by_name: dict = {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
-        by_name.setdefault(path[-2].key, []).append(leaf)
+    by_name = _sown_by_name(sown)
 
     def load(name, total, prefix):
         sizes = jnp.stack(by_name[name]).astype(jnp.float32)
@@ -384,6 +395,41 @@ def _expert_load(sown: dict) -> dict:
         out["moe_router_bias_abs_max"] = jnp.stack(
             by_name["bias_abs_max"]).max()
     return out
+
+
+def _mixer_stats(sown: dict, scan_fused: bool) -> dict:
+    """A SambaY stack's step scalars from what its mixers sow into
+    ``mixer_stats``: the largest magnitude in a state-space layer's state at
+    a row's end, the least and the largest differential lambda over the
+    attention layers, and whether the scan runs its kernel."""
+    by_name = _sown_by_name(sown)
+    out = {"ssm_scan_fused": jnp.float32(scan_fused)}
+    if "ssm_state_abs_max" in by_name:
+        out["ssm_state_abs_max"] = jnp.stack(
+            by_name["ssm_state_abs_max"]).max()
+    if "diff_lambda" in by_name:
+        lam = jnp.stack(by_name["diff_lambda"])
+        out.update(diff_lambda_min=lam.min(), diff_lambda_max=lam.max())
+    return out
+
+
+def _layer_span(span: Optional[str], ctor) -> dict:
+    """``--layer_span first:end`` as the stack's fields: the published
+    layers ``[first, end)`` of a preset whose layers differ by kind."""
+    if span is None:
+        return {}
+    if not ctor.keywords.get("layer_kinds"):
+        raise ValueError("layer_span states which published layers of a "
+                         "preset with layers of several kinds are held "
+                         "(phi4_mini_flash*); --num_layers cuts the others")
+    try:
+        first, end = (int(x) for x in span.split(":"))
+    except ValueError:
+        raise ValueError(f"layer_span is 'first:end', got {span!r}") from None
+    if not 0 <= first < end <= len(ctor.keywords["layer_kinds"]):
+        raise ValueError(f"layer_span {span} is not inside the preset's "
+                         f"{len(ctor.keywords['layer_kinds'])} layers")
+    return {"first_layer": first, "num_layers": end - first}
 
 
 def _expert_share(share: Optional[str], ctor) -> tuple:
@@ -410,10 +456,11 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                     attention_fn: Optional[Callable] = None,
                     remat: bool = False, num_experts: int = 0,
                     moe_every: int = 2, num_layers: int = 0,
-                    expert_share: Optional[str] = None) -> Task:
+                    expert_share: Optional[str] = None,
+                    layer_span: Optional[str] = None) -> Task:
     """Decoder-only next-token prediction (the GPT presets on the encoder
-    trunk, the OLMoE and Moonlight presets on the decoder stack) over the
-    same packed
+    trunk, the OLMoE, Moonlight and Phi-4-mini-flash presets on the decoder
+    stack) over the same packed
     token columns as masked-LM (``create_text_token_dataset``) — the text arm
     beyond the reference's vision-only scope, sharing the trainer, samplers
     and storage unchanged."""
@@ -421,32 +468,47 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
         raise ValueError(f"Invalid model name: {model_name} "
                          f"(have {sorted(_CAUSAL_LMS)})")
     ctor, own_vocab, aux_weights = _CAUSAL_LMS[model_name]
-    dropless = ctor.func is TransformerDecoder  # every layer has experts
+    decoder = ctor.func is TransformerDecoder  # no table to size by seq_len
+    hybrid = bool(ctor.keywords.get("layer_kinds"))  # mixers differ by layer
+    dropless = decoder and not hybrid  # every layer but the dense has experts
+    if num_layers and layer_span is not None:
+        raise ValueError("num_layers and layer_span both state the depth")
     kwargs = dict(vocab_size=vocab_size or own_vocab,
                   attention_fn=attention_fn, remat=remat,
-                  **_depth(num_layers))
-    if dropless:  # rotary positions: no table to size by seq_len
+                  **_depth(num_layers), **_layer_span(layer_span, ctor))
+    if expert_share is not None and not dropless:
+        raise ValueError("expert_share states which of a dropless "
+                         "preset's experts are held (olmoe_*, "
+                         "moonlight_*)")
+    if decoder:
         if num_experts:
             raise ValueError(
-                f"{model_name} has its own expert layers; --num_experts "
+                f"{model_name} has its own "
+                f"{'expert ' if dropless else ''}layers; --num_experts "
                 "adds switch experts to the BERT/GPT presets only")
-        kwargs["moe"] = ctor.keywords.get("moe", ()) + _expert_share(
-            expert_share, ctor)
+        if dropless:
+            kwargs["moe"] = ctor.keywords.get("moe", ()) + _expert_share(
+                expert_share, ctor)
     else:
-        if expert_share is not None:
-            raise ValueError("expert_share states which of a dropless "
-                             "preset's experts are held (olmoe_*, "
-                             "moonlight_*)")
         kwargs.update(max_len=seq_len, num_experts=num_experts,
                       moe_every=moe_every)
     model = ctor(**kwargs)
+    scan_fused = False
+    if hybrid:
+        from ..ops.scan import scan_fused_applies
+
+        scans = model.scan_shape  # a span that cannot run is refused here
+        scan_fused = bool(scans) and scan_fused_applies(seq_len, *scans)
     sows = (["aux_loss", "moe_stats", "router_state"] if dropless
+            else ["mixer_stats"] if hybrid
             else ["aux_loss"] if num_experts > 0 else [])
 
     def init_variables(rng):
         ids = jnp.zeros((1, seq_len), jnp.int32)
         variables = model.init(rng, ids, jnp.ones((1, seq_len), jnp.int8),
                                train=False)
+        if hybrid:  # what the mixers sow at init is not state
+            return {"params": variables["params"]}
         if not dropless:
             return variables
         # what the expert layers sow at init is not state; the routers'
@@ -477,6 +539,9 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                 state = ({"batch_stats": sown["router_state"]}
                          if "router_state" in sown else None)
                 return (logits, aux, _expert_load(sown["moe_stats"])), state
+            if hybrid:
+                return (logits, aux, _mixer_stats(sown["mixer_stats"],
+                                                  scan_fused)), None
             return (logits, aux), None
         logits = model.apply(variables, ids, mask, train=train,
                              segment_ids=seg, position_ids=pos)
@@ -509,7 +574,7 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
 
     return Task("causal_lm", model, init_variables, forward, loss, metric,
                 metric_name="next_token_accuracy",
-                stats=(lambda outputs: outputs[2]) if dropless else None)
+                stats=(lambda outputs: outputs[2]) if decoder else None)
 
 
 # ------------------------------------------------------- pipelined masked LM
@@ -693,10 +758,12 @@ def get_task(
     param_dtype=None,
     num_layers: int = 0,
     expert_share: Optional[str] = None,
+    layer_span: Optional[str] = None,
 ) -> Task:
     """``vocab_size=None`` means "the model's own default" (bert_*: 30522,
-    gpt_*: 50257, olmoe_1b_7b: 50304, moonlight_16b_a3b: 163840, olmoe_tiny
-    and moonlight_tiny: 512, clip_tiny: 1000, clip_resnet50_bert: 30522);
+    gpt_*: 50257, olmoe_1b_7b: 50304, moonlight_16b_a3b: 163840,
+    phi4_mini_flash: 200064, olmoe_tiny, moonlight_tiny and
+    phi4_mini_flash_tiny: 512, clip_tiny: 1000, clip_resnet50_bert: 30522);
     explicit values always apply verbatim.
     ``param_dtype`` overrides the parameter/optimizer-state dtype (ResNet
     family only; e.g. ``jnp.bfloat16`` halves weight HBM). ``num_layers``
@@ -704,11 +771,16 @@ def get_task(
     of a published model is a few of its layers at every published width,
     and ``expert_share`` (``"rank/ranks"``, the dropless causal_lm presets)
     the experts of each layer that this rank of an expert-parallel job
-    holds; with ``vocab_size`` as its slice of the vocabulary that is the
-    share a configuration states."""
+    holds; ``layer_span`` (``"first:end"``, the presets whose layers differ
+    by kind: phi4_mini_flash*) the published layers a pipeline stage holds;
+    with ``vocab_size`` as its slice of the vocabulary that is the share a
+    configuration states."""
     if expert_share is not None and task_type != "causal_lm":
         raise ValueError("expert_share applies to the causal_lm presets "
                          "with dropless expert layers")
+    if layer_span is not None and task_type != "causal_lm":
+        raise ValueError("layer_span applies to the causal_lm presets whose "
+                         "layers differ by kind (phi4_mini_flash*)")
     if num_layers and (task_type not in ("masked_lm", "causal_lm")
                        or pipeline_parallelism > 1):
         raise ValueError(
@@ -752,7 +824,8 @@ def get_task(
                                attention_fn=attention_fn, remat=remat,
                                num_experts=num_experts, moe_every=moe_every,
                                num_layers=num_layers,
-                               expert_share=expert_share)
+                               expert_share=expert_share,
+                               layer_span=layer_span)
     if task_type == "contrastive":
         return _contrastive_task(
             model_name or "clip_resnet50_bert", image_size, seq_len,

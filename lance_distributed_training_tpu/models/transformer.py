@@ -14,6 +14,7 @@ sequence-parallel ring variant (:mod:`..parallel.ring_attention`) can swap in.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "bert_small", "gpt_base", "gpt_small", "olmoe_1b_7b",
            "olmoe_tiny", "moonlight_16b_a3b", "moonlight_tiny",
+           "phi4_mini_flash", "phi4_mini_flash_tiny", "sambay_layers",
            "dot_product_attention", "RMSNorm", "rotary_embedding"]
 
 
@@ -218,6 +220,175 @@ class LatentAttention(nn.Module):
                 out.transpose(0, 2, 1, 3))
 
 
+class DifferentialAttention(nn.Module):
+    """Differential attention (arXiv:2410.05258) over grouped heads, without
+    a position term, as SambaY's attention layers run it (arXiv:2507.06607):
+    differential head ``i`` takes the query heads ``(2i, 2i+1)`` and, with
+    ``j = i // (num_heads / kv_heads)``, the key heads ``(2j, 2j+1)`` and the
+    value ``[V_2j, V_2j+1]``, twice a head wide:
+
+        o_i = (softmax(Q_2i K_2j' / sqrt(d)) - lam softmax(Q_2i+1 K_2j+1'
+               / sqrt(d))) V,   o_i <- (1 - lam_init) RMSNorm(o_i)
+
+    with ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init`` from four
+    learned vectors and ``lam_init = 0.8 - 0.6 exp(-0.3 depth)``, ``depth``
+    the layer's published index. The attention function sees ``num_heads``
+    query heads ``head_dim`` wide over ``kv_heads`` key heads and ``kv_heads
+    / 2`` value heads twice as wide (grouped heads, ``d_qk != d_v``), with
+    ``window`` > 0 for a causal band. ``shared`` None: keys and values are
+    this layer's own and are returned for later layers; else they are the
+    ``(k, v)`` an earlier layer returned (SambaY's cross-decoder), and this
+    layer has no key or value projection."""
+
+    num_heads: int
+    kv_heads: int
+    depth: int
+    window: int = 0
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, x, mask=None, segment_ids=None, shared=None):
+        b, s, h = x.shape
+        n, g = self.num_heads, self.kv_heads
+        d, rep = h // n, n // g
+        dense = partial(nn.DenseGeneral, dtype=self.dtype,
+                        param_dtype=jnp.float32, use_bias=False,
+                        kernel_init=self.kernel_init)
+        # query heads in the order (pair of key heads, which softmax, which
+        # differential head of the pair's group): every key head then serves
+        # `rep` neighbouring query heads, every value 2 x `rep`
+        q = dense(features=(n, d), name="query")(x).reshape(
+            b, s, g // 2, rep, 2, d).transpose(0, 2, 4, 3, 1, 5).reshape(
+            b, n, s, d)
+        if shared is None:
+            k = dense(features=(g, d), name="key")(x).transpose(0, 2, 1, 3)
+            v = dense(features=(g // 2, 2 * d), name="value")(x).transpose(
+                0, 2, 1, 3)
+        else:
+            k, v = shared
+        attn = self.attention_fn
+        if attn is None:  # dense off a TPU; knows windows and grouped heads
+            from ..ops.flash import make_flash_attention
+
+            attn = make_flash_attention(causal=True, forced=False)
+        kwargs = {"window": self.window} if self.window else {}
+        if segment_ids is not None:
+            kwargs["segment_ids"] = segment_ids
+        with jax.named_scope("attn.window" if self.window else "attn.full"
+                             if shared is None else "attn.cross"):
+            out = attn(q, k, v, mask=mask, **kwargs)
+        with jax.named_scope("diff.combine"):
+            lam_init = 0.8 - 0.6 * math.exp(-0.3 * self.depth)
+
+            def vec(name):
+                return self.param(name, nn.initializers.normal(0.1), (d,),
+                                  jnp.float32)
+
+            lam = (jnp.exp(jnp.sum(vec("lambda_q1") * vec("lambda_k1")))
+                   - jnp.exp(jnp.sum(vec("lambda_q2") * vec("lambda_k2")))
+                   + lam_init)
+            self.sow("mixer_stats", "diff_lambda", lam)
+            out = out.reshape(b, g // 2, 2, rep, s, 2 * d).astype(jnp.float32)
+            out = (out[:, :, 0] - lam * out[:, :, 1]).reshape(
+                b, n // 2, s, 2 * d).transpose(0, 2, 1, 3)
+            out = (1.0 - lam_init) * RMSNorm(
+                self.norm_eps, jnp.float32, name="sub_norm")(out)
+            out = out.astype(self.dtype).reshape(b, s, h)
+        return dense(features=h, axis=-1, name="out")(out), (k, v)
+
+
+class MambaMixer(nn.Module):
+    """Mamba-1's mixer (arXiv:2312.00752): ``[x; z] = u W_in``; ``x <-
+    silu(conv(x) + b_c)``, depthwise and causal over ``conv`` tokens; ``[dl;
+    B; C] = x W_x``; ``dt = softplus(dl W_dt + b_dt)``; the selective scan
+    with ``A = -exp(A_log)`` (:mod:`..ops.scan`); ``m = scan + D x``; ``out =
+    (m silu(z)) W_out``. ``dt``, the exponent, the state and the sum over
+    the states in float32, the products' operands in ``dtype``. Returns
+    ``(out, m)``: SambaY's gated memory units read ``m``, the scan's output
+    before the gate."""
+
+    inner: int
+    states: int
+    conv: int
+    dt_rank: int
+    dtype: Any = jnp.bfloat16
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, u):
+        from ..ops.scan import selective_scan
+
+        n, r = self.states, self.dt_rank
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=jnp.float32, kernel_init=self.kernel_init)
+        f32_out = partial(jax.lax.dot_general,
+                          preferred_element_type=jnp.float32)
+        with jax.named_scope("ssm.project"):
+            xz = dense(2 * self.inner, name="in_proj")(u)
+            x, z = xz[..., :self.inner], xz[..., self.inner:]
+        with jax.named_scope("ssm.conv"):
+            edge = 1.0 / math.sqrt(self.conv)  # a depthwise Conv1d's own
+            taps = self.param(
+                "conv_kernel", lambda key, shape, dtype: jax.random.uniform(
+                    key, shape, dtype, -edge, edge),
+                (self.conv, self.inner), jnp.float32)
+            bias = self.param("conv_bias", nn.initializers.zeros_init(),
+                              (self.inner,), jnp.float32)
+            back = jnp.pad(x, ((0, 0), (self.conv - 1, 0), (0, 0)))
+            x = nn.silu(sum(
+                back[:, k:k + x.shape[1]].astype(jnp.float32) * taps[k]
+                for k in range(self.conv)) + bias).astype(self.dtype)
+        with jax.named_scope("ssm.project"):
+            dbc = dense(r + 2 * n, name="x_proj", dot_general=f32_out)(x)
+            dt = jax.nn.softplus(dense(
+                self.inner, name="dt_proj", dot_general=f32_out)(
+                dbc[..., :r].astype(self.dtype)) + self.param(
+                "dt_bias", _dt_bias_init, (self.inner,), jnp.float32))
+        a = -jnp.exp(self.param(
+            "A_log", lambda key, shape, dtype: jnp.log(jnp.broadcast_to(
+                jnp.arange(1, shape[1] + 1, dtype=dtype), shape)),
+            (self.inner, n), jnp.float32))
+        skip = self.param("D", nn.initializers.ones_init(), (self.inner,),
+                          jnp.float32)
+        with jax.named_scope("ssm.scan"):
+            y, last = selective_scan(x, dt, a, dbc[..., r:r + n],
+                                     dbc[..., r + n:])
+        self.sow("mixer_stats", "ssm_state_abs_max", jnp.abs(last).max())
+        with jax.named_scope("ssm.gate"):
+            m = (y.astype(jnp.float32) + skip * x.astype(jnp.float32)
+                 ).astype(self.dtype)
+            gated = m * nn.silu(z)
+        with jax.named_scope("ssm.project"):
+            return dense(u.shape[-1], name="out_proj")(gated), m
+
+
+def _dt_bias_init(key, shape, dtype):
+    """Mamba's: the inverse softplus of a step drawn log-uniformly from
+    [1e-3, 1e-1], so that ``softplus(b_dt)`` starts there."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype) * (
+        math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+class GatedMemoryUnit(nn.Module):
+    """SambaY's gated memory unit (arXiv:2507.06607): ``(m silu(u W_1))
+    W_2``, with ``m`` an earlier layer's scan output at the same token in
+    place of a scan of its own."""
+
+    dtype: Any = jnp.bfloat16
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, u, memory):
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=jnp.float32, kernel_init=self.kernel_init)
+        gate = nn.silu(dense(memory.shape[-1], name="in_proj")(u))
+        return dense(u.shape[-1], name="out_proj")(memory * gate)
+
+
 class EncoderBlock(nn.Module):
     num_heads: int
     mlp_dim: int
@@ -334,12 +505,20 @@ class TransformerEncoder(nn.Module):
 
 
 class DecoderBlock(nn.Module):
-    """Pre-norm decoder layer ``x + mixer(rmsnorm(x))``, ``x + ffn(rmsnorm(x))``
-    with rotary, bias-free attention. The two choices a layer makes are
-    fields: ``latent`` (None: :class:`SelfAttention` with a norm on queries
-    and keys, OLMoE's; a ``(kv_rank, nope_dim, rope_dim, v_dim)``:
-    :class:`LatentAttention`) and ``dense_dim`` (0: the dropless expert layer
-    that ``moe`` describes; > 0: a dense SwiGLU of that width)."""
+    """Pre-norm decoder layer ``x + mixer(norm(x))``, ``x + ffn(norm(x))``,
+    bias-free. The choices a layer makes are fields. ``kind`` is its mixer:
+    ``""`` rotary attention, by ``latent`` (None: :class:`SelfAttention` with
+    a norm on queries and keys, OLMoE's; a ``(kv_rank, nope_dim, rope_dim,
+    v_dim)``: :class:`LatentAttention`); or one of SambaY's five
+    (arXiv:2507.06607, which ``hybrid`` sizes): ``"M"`` a :class:`MambaMixer`,
+    ``"M*"`` one that hands on its scan output, ``"S"`` window and ``"F*"``
+    full :class:`DifferentialAttention`, the latter handing on its keys and
+    values, ``"G"`` a :class:`GatedMemoryUnit` over M*'s output, ``"X"``
+    differential attention over F*'s keys and values. ``dense_dim`` is the
+    feed-forward (0: the dropless expert layer that ``moe`` describes; > 0:
+    a dense SwiGLU of that width), ``layer_norm`` the norm (LayerNorm, else
+    RMSNorm). The call takes and returns, beside ``x``, what is handed on:
+    ``(m, (k, v))``, either None until its layer has run."""
 
     num_heads: int
     expert_dim: int
@@ -353,27 +532,59 @@ class DecoderBlock(nn.Module):
     latent: Optional[tuple] = None
     dense_dim: int = 0
     moe: tuple = ()  # further fields of DroplessMoE, as (name, value) pairs
+    kind: str = ""
+    depth: int = 0  # the layer's published index
+    hybrid: tuple = ()  # (kv_heads, window, inner, states, conv, dt_rank)
+    layer_norm: bool = False
+
+    def _hybrid_mixer(self, y, mask, segment_ids, handed, init):
+        """``(mixer's output, what is handed on)`` of a SambaY layer."""
+        memory, keys_values = handed
+        kv_heads, window, *ssm = self.hybrid
+        if self.kind in ("M", "M*"):
+            y, m = MambaMixer(*ssm, self.dtype, init, name="ssm")(y)
+            return y, (m if self.kind == "M*" else memory, keys_values)
+        if self.kind == "G":
+            with jax.named_scope("gmu"):
+                return GatedMemoryUnit(self.dtype, init, name="gmu")(
+                    y, memory), handed
+        with jax.named_scope("attention"):
+            y, own = DifferentialAttention(
+                self.num_heads, kv_heads, self.depth,
+                window if self.kind == "S" else 0, self.norm_eps, self.dtype,
+                self.attention_fn, init, name="attn")(
+                y, mask, segment_ids,
+                keys_values if self.kind == "X" else None)
+        return y, (memory, own if self.kind == "F*" else keys_values)
 
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, position_ids=None,
-                 live=None):
+                 live=None, handed=(None, None)):
         from .moe import DroplessMoE, SwiGLU
 
         norm = partial(RMSNorm, self.norm_eps, self.dtype)
+        if self.layer_norm:
+            norm = partial(nn.LayerNorm, epsilon=self.norm_eps,
+                           dtype=self.dtype, param_dtype=jnp.float32)
         init = nn.initializers.truncated_normal(self.init_std)
         y = norm(name="ln_attn")(x)
-        with jax.named_scope("attention"):
-            if self.latent is None:
-                mixer = SelfAttention(
-                    self.num_heads, self.dtype, attention_fn=self.attention_fn,
-                    causal=True, use_bias=False, qk_norm_eps=self.norm_eps,
-                    rope_theta=self.rope_theta, kernel_init=init, name="attn")
-            else:
-                mixer = LatentAttention(
-                    self.num_heads, *self.latent, self.norm_eps,
-                    self.rope_theta, self.dtype, self.attention_fn, init,
-                    name="attn")
-            y = mixer(y, mask, segment_ids, position_ids)
+        if self.kind:
+            y, handed = self._hybrid_mixer(y, mask, segment_ids, handed, init)
+        else:
+            with jax.named_scope("attention"):
+                if self.latent is None:
+                    mixer = SelfAttention(
+                        self.num_heads, self.dtype,
+                        attention_fn=self.attention_fn, causal=True,
+                        use_bias=False, qk_norm_eps=self.norm_eps,
+                        rope_theta=self.rope_theta, kernel_init=init,
+                        name="attn")
+                else:
+                    mixer = LatentAttention(
+                        self.num_heads, *self.latent, self.norm_eps,
+                        self.rope_theta, self.dtype, self.attention_fn, init,
+                        name="attn")
+                y = mixer(y, mask, segment_ids, position_ids)
         x = x + y
         y = norm(name="ln_mlp")(x)
         if self.dense_dim:
@@ -384,15 +595,20 @@ class DecoderBlock(nn.Module):
                             self.experts_per_token, self.dtype,
                             kernel_init=init, name="moe",
                             **dict(self.moe))(y, live)
-        return x + y
+        return x + y, handed
 
 
 class TransformerDecoder(nn.Module):
-    """Decoder-only stack of today's open models' kind: RMSNorm, rotary
-    positions instead of a position table, no biases, a final RMSNorm and an
-    untied ``lm_head``. Each layer is a :class:`DecoderBlock`; OLMoE's are
-    all alike (ordinary attention, an expert layer), Moonlight's take latent
+    """Decoder-only stack of today's open models' kind: pre-norm, no
+    position table, no biases, a final norm and a head. Each layer is a
+    :class:`DecoderBlock`; OLMoE's are all alike (RMSNorm, rotary attention,
+    an expert layer, an untied ``lm_head``), Moonlight's take latent
     attention, and a dense SwiGLU in the first ``dense_layers`` of them.
+    A stack with ``layer_kinds`` is SambaY's (Phi-4-mini-flash): the mixer
+    differs by layer, LayerNorm, no position term at all, the head tied to
+    the embedding; ``first_layer`` says which published layers are held
+    (``num_layers`` of them from there: one pipeline stage's), and the two
+    tensors that M* and F* hand on ride from layer to layer beside ``x``.
     Same call signature as :class:`TransformerEncoder`, so the ``causal_lm``
     task drives either; logits ``[B, S, vocab]`` in f32.
     """
@@ -414,37 +630,97 @@ class TransformerDecoder(nn.Module):
     dense_layers: int = 0  # this many leading layers are dense, dense_dim wide
     dense_dim: int = 0
     moe: tuple = ()  # DecoderBlock's, for every expert layer
+    layer_kinds: tuple = ()  # every published layer's DecoderBlock.kind
+    first_layer: int = 0  # published index of the first layer held here
+    hybrid: tuple = ()  # DecoderBlock's, for every layer with a kind
+
+    @property
+    def held_kinds(self) -> tuple:
+        """The kinds of the layers held here, in order ("" without
+        ``layer_kinds``); a G or X with no M* or F* before it is refused."""
+        if not self.layer_kinds:
+            return ("",) * self.num_layers
+        span = f"{self.first_layer}:{self.first_layer + self.num_layers}"
+        kinds = self.layer_kinds[self.first_layer:
+                                 self.first_layer + self.num_layers]
+        if len(kinds) != self.num_layers or self.first_layer < 0:
+            raise ValueError(f"layer span {span} is not inside the model's "
+                             f"{len(self.layer_kinds)} layers")
+        for needs, source in (("G", "M*"), ("X", "F*")):
+            if needs in kinds and source not in kinds[:kinds.index(needs)]:
+                at = self.first_layer + kinds.index(needs)
+                raise ValueError(
+                    f"layer span {span}: layer {at} is a {needs} layer and "
+                    f"reads what the {source} layer (layer "
+                    f"{self.layer_kinds.index(source)}) hands on, which the "
+                    "span does not hold before it")
+        return kinds
+
+    @property
+    def attention_shapes(self) -> tuple:
+        """``(head_dim, value_dim)`` of every kind of attention the held
+        layers run: what the attention function chooses its path by, layer
+        by layer. Empty for a span without attention."""
+        if self.layer_kinds:
+            d = self.hidden_size // self.num_heads
+            return ((d, 2 * d),) if set(self.held_kinds) & {
+                "S", "F*", "X"} else ()
+        if self.latent is None:
+            return ((self.hidden_size // self.num_heads,) * 2,)
+        return ((self.latent[1] + self.latent[2], self.latent[3]),)
+
+    @property
+    def scan_shape(self) -> Optional[tuple]:
+        """``(channels, states)`` of the selective scan the held layers run
+        (what :func:`..ops.scan.scan_fused_applies` chooses its path by), or
+        None for a stack that holds no state-space layer."""
+        if set(self.held_kinds) & {"M", "M*"}:
+            return tuple(self.hybrid[2:4])
+        return None
 
     @property
     def attention_head_dim(self) -> int:
-        """Width of a head's queries and keys, what the attention function
-        chooses its path by."""
-        if self.latent is None:
-            return self.hidden_size // self.num_heads
-        return self.latent[1] + self.latent[2]
+        """Width of a head's queries and keys where every held attention
+        layer has the same (:attr:`attention_shapes` is the whole answer)."""
+        (head_dim, _), = set(self.attention_shapes)
+        return head_dim
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, train: bool = True,
                  segment_ids=None, position_ids=None):
         init = nn.initializers.truncated_normal(self.init_std)
-        x = nn.Embed(self.vocab_size, self.hidden_size,
-                     param_dtype=jnp.float32, embedding_init=init,
-                     name="tok_embed")(input_ids).astype(self.dtype)
+        embed = nn.Embed(self.vocab_size, self.hidden_size,
+                         param_dtype=jnp.float32, embedding_init=init,
+                         name="tok_embed")
+        x = embed(input_ids).astype(self.dtype)
         live = None if attention_mask is None else attention_mask > 0
         mask, seg_kwarg = _attention_masks(attention_mask, segment_ids,
                                            self.attention_fn)
         block = DecoderBlock
         if self.remat:
             block = nn.remat(DecoderBlock, static_argnums=())
-        for i in range(self.num_layers):
-            x = block(self.num_heads, self.expert_dim, self.num_experts,
-                      self.experts_per_token, self.norm_eps, self.rope_theta,
-                      self.init_std, self.dtype,
-                      attention_fn=self.attention_fn, latent=self.latent,
-                      dense_dim=self.dense_dim if i < self.dense_layers else 0,
-                      moe=self.moe,
-                      name=f"layer_{i}")(x, mask, seg_kwarg, position_ids,
-                                         live)
+        hybrid = bool(self.layer_kinds)
+        handed = (None, None)
+        for i, kind in enumerate(self.held_kinds):
+            x, handed = block(
+                self.num_heads, self.expert_dim, self.num_experts,
+                self.experts_per_token, self.norm_eps, self.rope_theta,
+                self.init_std, self.dtype,
+                attention_fn=self.attention_fn, latent=self.latent,
+                dense_dim=self.dense_dim if i < self.dense_layers else 0,
+                moe=self.moe, kind=kind, depth=self.first_layer + i,
+                hybrid=self.hybrid, layer_norm=hybrid,
+                name=f"layer_{i}")(x, mask, seg_kwarg, position_ids, live,
+                                   handed)
+        if hybrid:
+            x = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                             param_dtype=jnp.float32, name="ln_final")(x)
+            with jax.named_scope("lm_head"):
+                # tied: the held rows of the embedding, bf16 operands on
+                # the matrix unit, f32 sums and f32 logits
+                return jnp.einsum(
+                    "bsh,vh->bsv", x, embed.embedding.astype(self.dtype),
+                    preferred_element_type=jnp.float32)
         x = RMSNorm(self.norm_eps, self.dtype, name="ln_final")(x)
         with jax.named_scope("lm_head"):
             # bf16 operands on the matrix unit, f32 sums and f32 logits.
@@ -492,3 +768,38 @@ moonlight_tiny = partial(
     expert_dim=32, num_experts=8, experts_per_token=2, rope_theta=50000.0,
     latent=(32, 16, 8, 16), dense_layers=1, dense_dim=128,
     moe=_MOONLIGHT_ROUTER + (("shared_dim", 32),))
+
+
+def sambay_layers(layers: int) -> tuple:
+    """SambaY's layout over ``layers`` layers (arXiv:2507.06607; ``mb_per_
+    layer`` 2: every second mixer is a state-space one). The first half, the
+    self-decoder: even layers Mamba (M), odd ones window attention (S). Then
+    a Mamba layer that also hands on its scan output (M*) and full attention
+    whose keys and values are kept (F*). The rest, the cross-decoder: even
+    layers gated memory units over M*'s output (G), odd ones attention with
+    their own queries over F*'s keys and values (X)."""
+    half = layers // 2
+    return tuple(
+        ("M", "S")[i % 2] if i < half else "M*" if i == half
+        else "F*" if i == half + 1 else ("G", "X")[i % 2]
+        for i in range(layers))
+
+
+# Phi-4-mini-flash-reasoning (microsoft/Phi-4-mini-flash-reasoning
+# config.json, model_type phi4flash; Ren et al., arXiv:2507.06607): 32 SambaY
+# layers (9 Mamba of which layer 16 is M*, 8 window-512, layer 17 F*, 7 gated
+# memory units, 7 cross-attention), 40 query heads over 20 key heads of 64 in
+# differential attention, a SwiGLU of 10,240 in every layer, LayerNorm 1e-5,
+# no position term, the head tied. The config has no Mamba sizes: inner 2 x
+# hidden, 16 states, a convolution over 4, dt_rank hidden / 16 (Mamba's own
+# convention; the benchmark's configuration lists them as assumed).
+phi4_mini_flash = partial(
+    TransformerDecoder, hidden_size=2560, num_layers=32, num_heads=40,
+    expert_dim=0, num_experts=0, experts_per_token=0, rope_theta=0.0,
+    dense_layers=32, dense_dim=10240, layer_kinds=sambay_layers(32),
+    hybrid=(20, 512, 5120, 16, 4, 160))
+phi4_mini_flash_tiny = partial(
+    TransformerDecoder, hidden_size=64, num_layers=8, num_heads=8,
+    expert_dim=0, num_experts=0, experts_per_token=0, rope_theta=0.0,
+    dense_layers=8, dense_dim=128, layer_kinds=sambay_layers(8),
+    hybrid=(4, 16, 128, 8, 4, 4))

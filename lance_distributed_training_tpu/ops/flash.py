@@ -11,10 +11,13 @@ and keeps the weights for the backward pass. Two Pallas kernels avoid that:
 * the library's blocked kernel with online softmax
   (``jax.experimental.pallas.ops.tpu.flash_attention``) for longer ones
   (OLMoE's 4,096);
-* :func:`unequal_attention` for heads whose queries and keys are wider than
-  their values (latent attention: 192 and 128), which that kernel refuses
-  ("V model dimension unequal to KV model dimension unsupported"): the
-  library's splash kernel, which takes them as they are, at any length.
+* :func:`unequal_attention` for heads whose queries and keys are not as wide
+  as their values (latent attention: 192 and 128; differential attention: 64
+  and 128), which that kernel refuses ("V model dimension unequal to KV
+  model dimension unsupported"): the library's splash kernel, which takes
+  them as they are, at any length, and whose mask may be a causal band
+  (``window``: blocks outside the band are skipped as the causal ones are).
+  Keys and values may come in fewer heads than the queries (grouped heads).
 
 ``make_flash_attention()`` returns a drop-in ``attention_fn`` for
 :class:`..models.transformer.SelfAttention`, in two strengths:
@@ -283,15 +286,19 @@ def short_attention(q, k, v, segment_ids=None, *, causal: bool = False,
 # the fused one writes dq once per key block, 1.6 GB a row of 8,192 tokens.
 
 
-@functools.lru_cache(maxsize=8)
+@functools.lru_cache(maxsize=None)  # a few KB of block tables a mask: every
+# mask a process builds stays, so no program's kernel is built twice
 def _splash_kernel(heads: int, seq: int, causal: bool, block_q: int,
-                   block_kv: int):
+                   block_kv: int, window: int = 0):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
     )
 
-    one = (sm.CausalMask if causal else sm.FullMask)((seq, seq))
+    if window:  # a query sees itself and the window - 1 keys before it
+        one = sm.LocalMask((seq, seq), (window - 1, 0), 0)
+    else:
+        one = (sm.CausalMask if causal else sm.FullMask)((seq, seq))
     bq, bkv = min(block_q, seq), min(block_kv, seq)
     sizes = sk.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=bkv,
@@ -306,20 +313,33 @@ def _splash_kernel(heads: int, seq: int, causal: bool, block_q: int,
                                   q_seq_shards=1)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_kv"))
+def _expand_heads(t, heads: int):
+    """Grouped heads: ``t`` ``[B, G, S, D]`` with each of its ``G`` heads
+    repeated for the ``heads / G`` query heads that share it, in order."""
+    return t if t.shape[1] == heads else jnp.repeat(
+        t, heads // t.shape[1], axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_kv",
+                                             "window"))
 def unequal_attention(q, k, v, segment_ids=None, *, causal: bool = False,
-                      block_q: int = 512, block_kv: int = 512):
-    """Fused attention for heads whose values are narrower than their
-    queries and keys: q, k ``[B, H, S, Dqk]``, v ``[B, H, S, Dv]`` (S a
-    multiple of 128), ``segment_ids`` ``[B, S]`` int32 or None, output
-    ``[B, H, S, Dv]``; scores over ``sqrt(Dqk)``. Jitted, so a model's
-    layers share one trace and one lowering of its three kernels."""
+                      block_q: int = 512, block_kv: int = 512,
+                      window: int = 0):
+    """Fused attention for heads whose values are not as wide as their
+    queries and keys: q ``[B, H, S, Dqk]``, k ``[B, Hk, S, Dqk]``, v ``[B,
+    Hv, S, Dv]`` (S a multiple of 128; ``Hk`` and ``Hv`` divide ``H``: a key
+    or value head serves the query heads of its group), ``segment_ids`` ``[B,
+    S]`` int32 or None, output ``[B, H, S, Dv]``; scores over ``sqrt(Dqk)``.
+    ``window`` > 0: causal, and a query sees the ``window`` keys up to its
+    own. Jitted, so a model's layers share one trace and one lowering of its
+    three kernels."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
     )
 
     _, heads, seq, d = q.shape
-    kernel = _splash_kernel(heads, seq, causal, block_q, block_kv)
+    kernel = _splash_kernel(heads, seq, causal, block_q, block_kv, window)
+    k, v = _expand_heads(k, heads), _expand_heads(v, heads)
     # the kernel has no scale of its own
     q = (q.astype(jnp.float32) * (1.0 / float(d) ** 0.5)).astype(q.dtype)
     ids = None if segment_ids is None else segment_ids.astype(jnp.int32)
@@ -342,12 +362,16 @@ def fused_attention_applies(seq: int, head_dim: int, mesh=None,
     """The rule by which a sequence model that was given no attention
     function gets the fused kernel: on a TPU, for a sequence of whole
     128-key blocks from ``MIN_FUSED_SEQ`` up and heads of whole 64-lane
-    halves (BERT's 64, OLMoE's 128, latent attention's 192 with values of
-    ``value_dim`` 128), over one device or a mesh that only
-    has a ``'data'`` axis (a ``'model'`` axis has met no chip). Everything
-    else is dense attention, as before: the CPU, a ViT's 197 tokens, a
-    tensor-parallel mesh, and several devices with no mesh to say how the
-    batch is split."""
+    halves, over one device or a mesh that only has a ``'data'`` axis (a
+    ``'model'`` axis has met no chip). The shapes it serves today: BERT's
+    64-wide heads up to 1,024 tokens (``short_attention``), OLMoE's 128 at
+    4,096 (the library's blocked kernel), and, with ``value_dim`` another
+    width than ``head_dim``, ``unequal_attention``: latent attention's 192
+    with values of 128, and differential attention's 64 with values of 128
+    in 40 query heads over 20 key heads, causal, in a window of 512 or over
+    F*'s keys. Everything else is dense attention, as before: the CPU, a
+    ViT's 197 tokens, a tensor-parallel mesh, and several devices with no
+    mesh to say how the batch is split."""
     if (platform or jax.default_backend()) != "tpu":
         return False
     if seq < MIN_FUSED_SEQ or seq % _LANES or head_dim % 64 or (
@@ -394,11 +418,17 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
         # a reason to run dense attention under the flag's name.
         from jax.experimental.pallas.ops.tpu import flash_attention  # noqa: F401
 
-    def kernel(q, k, v, ids):
+    def kernel(q, k, v, ids, window=0):
         seq = q.shape[2]
         if q.shape[3] != v.shape[3]:
             return unequal_attention(q, k, v, ids, causal=causal,
-                                     block_q=block_q, block_kv=block_k)
+                                     block_q=block_q, block_kv=block_k,
+                                     window=window)
+        if window or k.shape[1] != q.shape[1]:
+            raise NotImplementedError(
+                "a window or grouped heads with values as wide as the keys "
+                "has no kernel here (differential attention's are 64 and "
+                "128: unequal_attention)")
         if seq <= SHORT_SEQ and seq % _LANES == 0:
             return short_attention(q, k, v, ids, causal=causal)
         from jax.experimental.pallas.ops.tpu import flash_attention as fa
@@ -424,19 +454,18 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
         )
         return out.astype(q.dtype)
 
-    def per_device(batch_axis):
+    def per_device(batch_axis, window=0):
         qkv = P(batch_axis, "model" if "model" in mesh.axis_names else None,
                 None, None)
         # check_vma off: the library kernel's out_shape declares no vma,
         # which pallas_call rejects under the check.
         return shard_map(
-            kernel, mesh=mesh, in_specs=(qkv, qkv, qkv, P(batch_axis, None)),
+            functools.partial(kernel, window=window), mesh=mesh,
+            in_specs=(qkv, qkv, qkv, P(batch_axis, None)),
             out_specs=qkv, check_vma=False,
         )
 
     over_mesh = use_pallas and mesh is not None and mesh.size > 1
-    if over_mesh:
-        split, whole = per_device("data"), per_device(None)
 
     def fused(seq: int, head_dim: int,
               value_dim: Optional[int] = None) -> bool:
@@ -444,7 +473,10 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
         return use_pallas and (forced or fused_attention_applies(
             seq, head_dim, mesh, platform, value_dim))
 
-    def attention_fn(q, k, v, mask=None, dtype=None, segment_ids=None):
+    def attention_fn(q, k, v, mask=None, dtype=None, segment_ids=None,
+                     window=0):
+        """``window`` > 0 (a causal function only): a query sees the
+        ``window`` keys up to its own. k and v may have fewer heads than q."""
         if not fused(q.shape[2], q.shape[3], v.shape[3]):
             from ..models.transformer import dot_product_attention
 
@@ -453,8 +485,13 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
                 # key-validity mask (it encodes validity AND segment
                 # boundaries); causal still composes inside.
                 mask = segment_attention_mask(segment_ids)
-            return dot_product_attention(q, k, v, mask=mask, dtype=q.dtype,
-                                         causal=causal)
+            if window:
+                at = jnp.arange(q.shape[2])
+                band = (at[:, None] - at[None, :] < window)[None, None]
+                mask = band if mask is None else mask & band
+            return dot_product_attention(
+                q, _expand_heads(k, q.shape[1]), _expand_heads(v, q.shape[1]),
+                mask=mask, dtype=q.dtype, causal=causal)
         ids = None
         if segment_ids is not None:
             # The kernel's native packed-sequence form: tokens attend only
@@ -465,9 +502,9 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
         elif mask is not None:
             ids = mask.reshape(mask.shape[0], mask.shape[-1]).astype(jnp.int32)
         if not over_mesh:
-            return kernel(q, k, v, ids)
+            return kernel(q, k, v, ids, window)
         tiles = q.shape[0] % mesh.shape["data"] == 0
-        return (split if tiles else whole)(q, k, v, ids)
+        return per_device("data" if tiles else None, window)(q, k, v, ids)
 
     attention_fn.fused = fused  # train() logs it; the call above obeys it
     return attention_fn

@@ -187,6 +187,9 @@ class TrainConfig:
     # dropless layer that this rank of an expert-parallel job holds (the
     # router stays whole); with vocab_size as the vocabulary's slice, the
     # share of a stated deployment that this chip runs. None: all of them
+    layer_span: Optional[str] = None  # "first:end": the published layers
+    # [first, end) that this pipeline stage holds of a preset whose layers
+    # differ by kind (phi4_mini_flash*); None: all of them
     prefetch: int = 2
     producer_threads: int = 4  # decode-producer threads
     placement_depth: int = 2  # device-resident batches the placement ring
@@ -350,6 +353,7 @@ def _task_from_config(config: TrainConfig, mesh=None) -> Task:
         vocab_size=config.vocab_size,
         num_layers=config.num_layers,
         expert_share=config.expert_share,
+        layer_span=config.layer_span,
         augment=config.augment,
         attention_fn=attention_fn,
         remat=config.remat,
@@ -363,18 +367,36 @@ def _task_from_config(config: TrainConfig, mesh=None) -> Task:
 
 def _attention_fused(task: Task, config: TrainConfig) -> Optional[float]:
     """1.0 where a sequence model's attention at ``seq_len`` runs the fused
-    kernel, 0.0 where it runs dense (or ring) attention, None for a task
-    without attention of its own to choose: asked of the function the model
-    was bound (``ops.flash.make_flash_attention``), which decides each call
-    by the same test. Published as the gauge ``attention_fused``."""
+    kernel (in every layer that has attention, where the layers differ), 0.0
+    where it runs dense (or ring) attention, None for a task without
+    attention of its own to choose (a span of layers that holds none
+    included): asked of the function the model was bound
+    (``ops.flash.make_flash_attention``), which decides each call by the
+    same test. Published as the gauge ``attention_fused``."""
     if config.task_type not in ("masked_lm", "causal_lm"):
         return None
     model = task.model
     fused = getattr(getattr(model, "attention_fn", None), "fused", None)
-    on = bool(fused and fused(config.seq_len, getattr(
-        model, "attention_head_dim", model.hidden_size // model.num_heads)))
+    shapes = getattr(model, "attention_shapes", (
+        (model.hidden_size // model.num_heads,) * 2,))
+    if not shapes:
+        return None
+    on = bool(fused and all(fused(config.seq_len, *s) for s in shapes))
     default_registry().gauge("attention_fused").set(float(on))
     return float(on)
+
+
+def _scan_path(task: Task, config: TrainConfig) -> Optional[str]:
+    """How a stack's state-space layers run their scan at ``seq_len`` (by
+    ``ops.scan.scan_fused_applies``, the test each call makes), None for a
+    model that holds none."""
+    scans = getattr(task.model, "scan_shape", None)
+    if not scans:
+        return None
+    from .ops.scan import scan_fused_applies
+
+    return ("fused kernel" if scan_fused_applies(config.seq_len, *scans)
+            else "chunked")
 
 
 def lr_schedule_fn(config: TrainConfig, total_steps: Optional[int] = None):
@@ -1520,6 +1542,9 @@ def _train(config: TrainConfig) -> dict:
             start_line["attention"] = (
                 "fused kernel" if attention_fused
                 else "ring" if config.seq_parallelism > 1 else "dense")
+        scan_path = _scan_path(task, config)
+        if scan_path:
+            start_line["scan"] = scan_path
         logger.log(start_line, to_wandb=False)
         if config.metrics_port is not None and jax.process_index() == 0:
             from .obs.http import MetricsHTTPServer

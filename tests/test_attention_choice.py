@@ -69,6 +69,19 @@ def test_the_rule(platform, mesh, seq, head_dim, fused):
         seq, head_dim, _meshes()[mesh], platform) is fused
 
 
+@pytest.mark.parametrize("seq,head_dim,value_dim,fused", [
+    (8192, 192, 128, True),  # latent attention: Moonlight's cell
+    (8192, 64, 128, True),  # differential attention, window, full and cross
+    (8192, 64, 96, False),  # a value that fills no half tile
+    (8192, 48, 128, False),
+    (200, 64, 128, False),  # not whole blocks
+])
+def test_the_rule_for_values_of_another_width(seq, head_dim, value_dim,
+                                              fused):
+    assert flash.fused_attention_applies(
+        seq, head_dim, _meshes()["one device"], "tpu", value_dim) is fused
+
+
 def test_without_a_mesh_one_device_is_fused(monkeypatch):
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     assert flash.fused_attention_applies(512, 64, None, "tpu")
@@ -244,6 +257,78 @@ def test_unequal_attention_compiles_for_a_v5e_at_moonlights_widths(
     assert text.count("tpu_custom_call") >= 3 * rows
     assert "8192,8192]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("window", [512, 0])
+def test_unequal_attention_compiles_for_a_v5e_at_phi4_flashs_widths(
+        one_chip, window):
+    """Differential attention's heads as the Phi-4-mini-flash cell runs
+    them: 40 query heads of 64 over 20 key heads and 10 values of 128, 8,192
+    tokens, in a band of 512 (S) and causal (F*, X): three kernels, no
+    ``[B, H, S, S]`` tensor, and the band's block tables are its own."""
+    def spec(heads, width):
+        return jax.ShapeDtypeStruct((1, heads, 8192, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash.unequal_attention(q, k, v, causal=True, window=window)
+        assert out.shape == (1, 40, 8192, 128)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec(40, 64), spec(20, 64), spec(10, 128)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "8192,8192]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert flash._splash_kernel(40, 8192, True, 512, 512, window) \
+        is flash._splash_kernel(40, 8192, True, 512, 512, window)
+    assert flash._splash_kernel(40, 8192, True, 512, 512, 512) \
+        is not flash._splash_kernel(40, 8192, True, 512, 512, 0)
+
+
+@pytest.mark.parametrize("window", [0, 100, 128, 300])
+def test_unequal_attention_in_a_window_over_grouped_heads_equals_dense(
+        window):
+    """4 query heads of 64 over 2 key heads and 1 value of 128, 512 tokens in
+    blocks of 128 (a window of 100 leaves whole blocks out), segment ids:
+    forward and gradients against dense attention under the same mask."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    seq = 512
+    keys = jax.random.split(jax.random.key(window), 4)
+    q = jax.random.normal(keys[0], (2, 4, seq, 64), jnp.float32)
+    k = jax.random.normal(keys[1], (2, 2, seq, 64), jnp.float32)
+    v = jax.random.normal(keys[2], (2, 1, seq, 128), jnp.float32)
+    w = jax.random.normal(keys[3], (2, 4, seq, 128), jnp.float32)
+    seg = _segments(seq)
+    live = (seg > 0)[:, None, :, None]  # dead queries mean nothing
+    at = jnp.arange(seq)
+    mask = flash.segment_attention_mask(seg)
+    if window:
+        mask = mask & (at[:, None] - at[None, :] < window)[None, None]
+
+    def dense(q, k, v):
+        return jnp.where(live, dot_product_attention(
+            q, jnp.repeat(k, 2, 1), jnp.repeat(v, 4, 1), mask=mask,
+            dtype=jnp.float32, causal=True), 0)
+
+    def kernel(q, k, v):
+        return jnp.where(live, flash.unequal_attention(
+            q, k, v, seg, causal=True, block_q=128, block_kv=128,
+            window=window), 0)
+
+    def both(q, k, v):
+        return [(fn(q, k, v), jax.grad(lambda *a: (fn(*a) * w).sum(),
+                                       argnums=(0, 1, 2))(q, k, v))
+                for fn in (dense, kernel)]
+
+    with pltpu.force_tpu_interpret_mode():
+        (want, want_grads), (got, got_grads) = _one_program(both, q, k, v)
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    for g, wnt in zip(got_grads, want_grads):
+        assert g.shape == wnt.shape
+        np.testing.assert_allclose(g, wnt, atol=5e-5, rtol=1e-5)
 
 
 # -- BERT's encoder with the chosen kernel -----------------------------------
