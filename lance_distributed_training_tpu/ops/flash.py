@@ -18,6 +18,10 @@ and keeps the weights for the backward pass. Two Pallas kernels avoid that:
   them as they are, at any length, and whose mask may be a causal band
   (``window``: blocks outside the band are skipped as the causal ones are).
   Keys and values may come in fewer heads than the queries (grouped heads).
+  Its forward, dkv and dq kernels each run the block sizes
+  :func:`splash_tiling` has for the call's shapes: what the chip timed
+  fastest for them, else the untuned square blocks of 512, counted in
+  ``attention_tiling_fallback_total``; there is no flag for it.
 
 ``make_flash_attention()`` returns a drop-in ``attention_fn`` for
 :class:`..models.transformer.SelfAttention`, in two strengths:
@@ -43,15 +47,16 @@ partition a Mosaic call. For sequence parallelism use
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["make_flash_attention", "segment_attention_mask",
-           "short_attention", "unequal_attention"]
+__all__ = ["SplashTiling", "make_flash_attention", "segment_attention_mask",
+           "short_attention", "splash_tiling", "splash_tilings_built",
+           "unequal_attention"]
 
 
 def segment_attention_mask(segment_ids: jax.Array) -> jax.Array:
@@ -282,33 +287,136 @@ def short_attention(q, k, v, segment_ids=None, *, causal: bool = False,
 # and 128 in values. Padding v to 192 would satisfy the blocked kernel above
 # and waste a third of the context product; the library's splash kernel takes
 # ``d_qk != d_v`` (blocked online softmax, the causal upper blocks skipped,
-# logsumexp kept as ``[H, S]``). Its backward runs as two kernels (dkv, dq):
-# the fused one writes dq once per key block, 1.6 GB a row of 8,192 tokens.
+# logsumexp kept as ``[H, S]``). It is three kernels with a tiling each, and
+# what the v5e ran fastest (PERF.md section 6, PR 34) is not one block for
+# all: the forward wants query blocks of 1,024 to 2,048 with the scores made
+# 256 keys at a time, dkv and dq square blocks of 1,024: 13.50 ms a Moonlight
+# layer for the whole call where blocks of 512 take 15.34. Under a window
+# smaller and larger blocks are both slower, and square blocks of 512 stay.
+#
+# The library's fused backward (one kernel computes the scores once for dk,
+# dv and dq) is faster again, 12.04 ms at key blocks of 1,024, and no entry
+# takes it: it writes dq as one partial a key block in the queries' dtype
+# (eight bf16 partials a row of 8,192 tokens, 403 MB for Moonlight's 16
+# heads of 192, for each row of the batch) and XLA sums them, where the dq
+# kernel accumulates over all key blocks in f32 scratch and rounds once.
+# That is a lower intermediate precision, not another order of f32 sums
+# (PERF.md section 6 has dq's error against an f32 reference for both).
+
+
+class SplashTiling(NamedTuple):
+    """The library's ``BlockSizes``, a kernel at a time: ``fwd`` is
+    ``(block_q, block_kv, block_kv_compute)``, ``dkv`` the same three of the
+    dkv kernel, ``dq`` ``(block_q_dq, block_kv_dq)``. ``dq`` None is the
+    fused backward, for a test or a timing: dkv's kernel then writes dq's
+    partials too, one a key block and rounded to the queries' dtype."""
+    fwd: tuple
+    dkv: tuple
+    dq: Optional[tuple]
+
+    @property
+    def blocks(self) -> tuple:
+        """Every block size, in the order of ``BlockSizes``'s fields."""
+        return (*self.fwd, *self.dkv, *(self.dq or ()))
+
+
+class _Shape(NamedTuple):
+    """What a call can observe, and so what a tiling is chosen from (not the
+    rows of the batch: the kernels take one row at a time)."""
+    seq: int
+    d_qk: int
+    d_v: int
+    heads: int
+    causal: bool
+    window: int
+
+
+def _square(block: int) -> SplashTiling:
+    return SplashTiling((block,) * 3, (block,) * 3, (block,) * 2)
+
+
+# What the v5e ran fastest, kernel by kernel, at the shapes the cells call
+# (scripts/splash_tiling_sweep.py; its tables are in PERF.md section 6), each
+# inside its cell's step too: a tiling that compiles alone can be refused
+# there (scoped VMEM), so an entry is one a step has run.
+_TIMED = {
+    # Moonlight's latent attention
+    _Shape(8192, 192, 128, 16, True, 0): SplashTiling(
+        (1024, 1024, 256), (1024, 1024, 512), (1024, 1024)),
+    # Phi-4-mini-flash's differential attention: F* and X, then S's band
+    _Shape(8192, 64, 128, 40, True, 0): SplashTiling(
+        (2048, 2048, 256), (1024, 1024, 1024), (1024, 1024)),
+    _Shape(8192, 64, 128, 40, True, 512): _square(512),
+}
+
+
+def splash_tiling(seq: int, d_qk: int, d_v: int, heads: int, causal: bool,
+                  window: int = 0):
+    """``(tiling, timed)`` for one call's shapes: the entry the chip timed
+    for exactly these shapes, else the untuned square blocks of 512 (of 256
+    or 128 where 512 does not divide ``seq``), which every shape so far has
+    compiled and run."""
+    shape = _Shape(seq, d_qk, d_v, heads, causal, window)
+    if shape in _TIMED:
+        return _TIMED[shape], True
+    return _square(next((b for b in (512, 256) if seq % b == 0),
+                        _LANES)), False
+
+
+_built: list = []
+
+
+def splash_tilings_built() -> list:
+    """A log line for each kernel built since the last call: its shapes, the
+    tiling it got, and whether the chip timed that tiling at these shapes
+    (``train()`` logs them at its next log point)."""
+    lines = _built[:]
+    del _built[:len(lines)]
+    return lines
 
 
 @functools.lru_cache(maxsize=None)  # a few KB of block tables a mask: every
 # mask a process builds stays, so no program's kernel is built twice
-def _splash_kernel(heads: int, seq: int, causal: bool, block_q: int,
-                   block_kv: int, window: int = 0):
+def _splash_kernel(shape: _Shape, tiling: Optional[SplashTiling] = None):
+    """The library's kernel object for one call's shapes, at the tiling
+    :func:`splash_tiling` has for them (a test may hand it one)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
     )
 
-    if window:  # a query sees itself and the window - 1 keys before it
-        one = sm.LocalMask((seq, seq), (window - 1, 0), 0)
+    from ..obs.registry import default_registry
+
+    source = "given"
+    if tiling is None:
+        tiling, timed = splash_tiling(*shape)
+        source = "timed" if timed else "rule"
+    # counts kernels that run a tiling nobody timed at their shapes: a new
+    # model that reads non-zero here is running untuned
+    default_registry().counter("attention_tiling_fallback_total").inc(
+        source == "rule")
+    _built.append({
+        "attention_tiling": " ".join(f"{k}={v}" for k, v in zip(
+            shape._fields, shape)),
+        "fwd": "/".join(map(str, tiling.fwd)),
+        "dkv": "/".join(map(str, tiling.dkv)),
+        "dq": "/".join(map(str, tiling.dq)) if tiling.dq else "fused in dkv",
+        "source": source})
+    seq = shape.seq
+    if shape.window:  # a query sees itself and the window - 1 keys before it
+        one = sm.LocalMask((seq, seq), (shape.window - 1, 0), 0)
     else:
-        one = (sm.CausalMask if causal else sm.FullMask)((seq, seq))
-    bq, bkv = min(block_q, seq), min(block_kv, seq)
-    sizes = sk.BlockSizes(
-        block_q=bq, block_kv=bkv, block_kv_compute=bkv,
-        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
-        block_q_dq=bq, block_kv_dq=bkv, use_fused_bwd_kernel=False)
+        one = (sm.CausalMask if shape.causal else sm.FullMask)((seq, seq))
+    sizes = sk.BlockSizes(  # without dq's blocks: the fused backward
+        **dict(zip(("block_q", "block_kv", "block_kv_compute", "block_q_dkv",
+                    "block_kv_dkv", "block_kv_dkv_compute", "block_q_dq",
+                    "block_kv_dq"), tiling.blocks)),
+        use_fused_bwd_kernel=tiling.dq is None)
     # the kernel object holds its block tables as arrays: made while a
     # program is traced, they must be values and not that trace's tracers,
     # or the next program to find the object here meets a leaked tracer
     with jax.ensure_compile_time_eval():
-        return sk.make_splash_mha(sm.MultiHeadMask([one] * heads),
+        return sk.make_splash_mha(sm.MultiHeadMask([one] * shape.heads),
                                   block_sizes=sizes, head_shards=1,
                                   q_seq_shards=1)
 
@@ -320,25 +428,27 @@ def _expand_heads(t, heads: int):
         t, heads // t.shape[1], axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_kv",
-                                             "window"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "tiling"))
 def unequal_attention(q, k, v, segment_ids=None, *, causal: bool = False,
-                      block_q: int = 512, block_kv: int = 512,
-                      window: int = 0):
+                      window: int = 0,
+                      tiling: Optional[SplashTiling] = None):
     """Fused attention for heads whose values are not as wide as their
     queries and keys: q ``[B, H, S, Dqk]``, k ``[B, Hk, S, Dqk]``, v ``[B,
     Hv, S, Dv]`` (S a multiple of 128; ``Hk`` and ``Hv`` divide ``H``: a key
     or value head serves the query heads of its group), ``segment_ids`` ``[B,
     S]`` int32 or None, output ``[B, H, S, Dv]``; scores over ``sqrt(Dqk)``.
     ``window`` > 0: causal, and a query sees the ``window`` keys up to its
-    own. Jitted, so a model's layers share one trace and one lowering of its
-    three kernels."""
+    own. The kernels' block sizes come from the shapes
+    (:func:`splash_tiling`); ``tiling`` is for a test or a timing that wants
+    another. Jitted, so a model's layers share one trace and one lowering of
+    its three kernels."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
     )
 
     _, heads, seq, d = q.shape
-    kernel = _splash_kernel(heads, seq, causal, block_q, block_kv, window)
+    kernel = _splash_kernel(
+        _Shape(seq, d, v.shape[3], heads, causal, window), tiling)
     k, v = _expand_heads(k, heads), _expand_heads(v, heads)
     # the kernel has no scale of its own
     q = (q.astype(jnp.float32) * (1.0 / float(d) ** 0.5)).astype(q.dtype)
@@ -422,7 +532,6 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
         seq = q.shape[2]
         if q.shape[3] != v.shape[3]:
             return unequal_attention(q, k, v, ids, causal=causal,
-                                     block_q=block_q, block_kv=block_k,
                                      window=window)
         if window or k.shape[1] != q.shape[1]:
             raise NotImplementedError(

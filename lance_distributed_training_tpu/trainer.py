@@ -41,6 +41,7 @@ from .models.tasks import Task, get_task
 from .obs.registry import default_registry
 from .obs.spans import end_phase, watch_xla_compiles
 from .obs.spans import phase as obs_phase
+from .ops.flash import splash_tilings_built
 from .parallel.mesh import (
     batch_sharding,
     get_mesh,
@@ -2065,6 +2066,10 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                     step_stats.publish(entry)
                     if attention_fused is not None:
                         entry["attention_fused"] = attention_fused
+                        # the steps so far were traced: each splash kernel
+                        # they built says once what tiling it runs
+                        for line in splash_tilings_built():
+                            logger.log(line, to_wandb=False)
                     if config.data_echo > 1:
                         # The windowed rate counts echoed steps; report the
                         # unique-data rate next to it (as the epoch metrics

@@ -236,14 +236,17 @@ def test_short_attention_compiles_for_a_v5e_at_real_widths(one_chip, shape,
 def test_unequal_attention_compiles_for_a_v5e_at_moonlights_widths(
         one_chip, rows, with_ids):
     """Latent attention's heads as the Moonlight cell runs them: 16 heads,
-    8,192 tokens, queries and keys of 192, values of 128, unpadded: the
-    forward kernel and the two backward kernels, with and without segment
-    ids, and no ``[B, H, S, S]`` tensor anywhere in the program."""
+    8,192 tokens, queries and keys of 192, values of 128, unpadded, at the
+    tiling the function chooses for these shapes (one the chip timed): the
+    forward kernel and the backward's, with and without segment ids, and no
+    ``[B, H, S, S]`` tensor anywhere in the program."""
     qk = jax.ShapeDtypeStruct((rows, 16, 8192, 192), jnp.bfloat16,
                               sharding=one_chip)
     v = jax.ShapeDtypeStruct((rows, 16, 8192, 128), jnp.bfloat16,
                              sharding=one_chip)
     ids = jax.ShapeDtypeStruct((rows, 8192), jnp.int32, sharding=one_chip)
+    tiling, timed = flash.splash_tiling(8192, 192, 128, 16, True)
+    assert timed
 
     def loss(q, k, v, ids):
         out = flash.unequal_attention(q, k, v, ids if with_ids else None,
@@ -254,7 +257,7 @@ def test_unequal_attention_compiles_for_a_v5e_at_moonlights_widths(
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         qk, qk, v, ids).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3 * rows
+    assert text.count("tpu_custom_call") >= (3 if tiling.dq else 2) * rows
     assert "8192,8192]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
@@ -264,11 +267,15 @@ def test_unequal_attention_compiles_for_a_v5e_at_phi4_flashs_widths(
         one_chip, window):
     """Differential attention's heads as the Phi-4-mini-flash cell runs
     them: 40 query heads of 64 over 20 key heads and 10 values of 128, 8,192
-    tokens, in a band of 512 (S) and causal (F*, X): three kernels, no
-    ``[B, H, S, S]`` tensor, and the band's block tables are its own."""
+    tokens, in a band of 512 (S) and causal (F*, X), each at the tiling the
+    function chooses for it (timed, and not the same): no ``[B, H, S, S]``
+    tensor, and the band's block tables are its own."""
     def spec(heads, width):
         return jax.ShapeDtypeStruct((1, heads, 8192, width), jnp.bfloat16,
                                     sharding=one_chip)
+
+    tiling, timed = flash.splash_tiling(8192, 64, 128, 40, True, window)
+    assert timed
 
     def loss(q, k, v):
         out = flash.unequal_attention(q, k, v, causal=True, window=window)
@@ -278,21 +285,83 @@ def test_unequal_attention_compiles_for_a_v5e_at_phi4_flashs_widths(
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         spec(40, 64), spec(20, 64), spec(10, 128)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= (3 if tiling.dq else 2)
     assert "8192,8192]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
-    assert flash._splash_kernel(40, 8192, True, 512, 512, window) \
-        is flash._splash_kernel(40, 8192, True, 512, 512, window)
-    assert flash._splash_kernel(40, 8192, True, 512, 512, 512) \
-        is not flash._splash_kernel(40, 8192, True, 512, 512, 0)
+    shape = flash._Shape(8192, 64, 128, 40, True, window)
+    assert flash._splash_kernel(shape) is flash._splash_kernel(shape)
+    assert flash._splash_kernel(shape) is not flash._splash_kernel(
+        shape._replace(window=512 - window))
 
 
-@pytest.mark.parametrize("window", [0, 100, 128, 300])
-def test_unequal_attention_in_a_window_over_grouped_heads_equals_dense(
-        window):
-    """4 query heads of 64 over 2 key heads and 1 value of 128, 512 tokens in
-    blocks of 128 (a window of 100 leaves whole blocks out), segment ids:
-    forward and gradients against dense attention under the same mask."""
+# -- the tiling a call's shapes get -------------------------------------------
+
+CELL_SHAPES = [  # seq, d_qk, d_v, heads, causal, window
+    (8192, 192, 128, 16, True, 0),  # Moonlight's latent attention
+    (8192, 64, 128, 40, True, 0),  # Phi-4-mini-flash, F* and X
+    (8192, 64, 128, 40, True, 512),  # Phi-4-mini-flash, S
+]
+UNTIMED_SHAPES = [  # and the square block each runs
+    ((4096, 192, 128, 16, True, 0), 512),  # a shorter row of the same heads
+    ((8192, 128, 64, 8, True, 0), 512),  # other widths
+    ((1024, 64, 128, 8, True, 256), 512),  # another band
+    ((8192, 64, 128, 40, False, 0), 512),  # no causal mask was timed
+    ((640, 64, 128, 4, True, 0), 128),  # neither 512 nor 256 divides it
+    ((768, 64, 128, 4, True, 0), 256),
+    ((128, 24, 16, 2, True, 0), 128),  # the tiny presets' one block
+]
+
+
+@pytest.mark.parametrize("shape,square", [(s, 0) for s in CELL_SHAPES]
+                         + UNTIMED_SHAPES)
+def test_the_tiling_of_a_shape_is_one_the_kernels_take(shape, square):
+    """Every block divides the sequence in whole 128-lane tiles, a compute
+    block divides its key block, and the choice is a pure function: the
+    shapes' own timed entry, else square blocks that every shape so far has
+    compiled with. A timed entry keeps dq's f32 accumulation: two backward
+    kernels, never the fused one with its partials in the queries' dtype."""
+    seq = shape[0]
+    tiling, timed = flash.splash_tiling(*shape)
+    assert timed is (shape in CELL_SHAPES)
+    assert all(b % 128 == 0 and seq % b == 0 for b in tiling.blocks), tiling
+    assert tiling.fwd[1] % tiling.fwd[2] == 0
+    assert tiling.dkv[1] % tiling.dkv[2] == 0
+    assert tiling.dq is not None
+    if not timed:
+        assert tiling == flash._square(square)
+    assert flash.splash_tiling(*shape) == (tiling, timed)
+
+
+@pytest.mark.parametrize(
+    "shape", CELL_SHAPES + [s for s, _ in UNTIMED_SHAPES[:3]])
+def test_a_shapes_kernel_is_built_once_and_a_window_has_its_own(shape):
+    from lance_distributed_training_tpu.obs.registry import default_registry
+
+    key = flash._Shape(*shape)
+    fallbacks = default_registry().counter("attention_tiling_fallback_total")
+    flash._splash_kernel.cache_clear()
+    flash.splash_tilings_built()
+    before = fallbacks.value
+    kernel = flash._splash_kernel(key)
+    assert flash._splash_kernel(key) is kernel
+    other = flash._splash_kernel(key._replace(window=key.window or 256))
+    banded = key.window > 0
+    assert (other is kernel) is banded
+    # a kernel built by the rule counts once, a cached one never again
+    untimed = shape not in CELL_SHAPES
+    assert fallbacks.value - before == untimed + (not banded)
+    lines = flash.splash_tilings_built()
+    assert len(lines) == 2 - banded
+    assert lines[0]["source"] == ("rule" if untimed else "timed")
+    assert f"seq={key.seq} d_qk={key.d_qk} d_v={key.d_v}" in lines[0][
+        "attention_tiling"]
+    assert flash.splash_tilings_built() == []
+
+
+def _windowed_grouped_heads_equal_dense(window, tiling):
+    """4 query heads of 64 over 2 key heads and 1 value of 128, 512 tokens,
+    segment ids: forward and gradients against dense attention under the
+    same mask."""
     from jax.experimental.pallas import tpu as pltpu
 
     seq = 512
@@ -315,8 +384,7 @@ def test_unequal_attention_in_a_window_over_grouped_heads_equals_dense(
 
     def kernel(q, k, v):
         return jnp.where(live, flash.unequal_attention(
-            q, k, v, seg, causal=True, block_q=128, block_kv=128,
-            window=window), 0)
+            q, k, v, seg, causal=True, window=window, tiling=tiling), 0)
 
     def both(q, k, v):
         return [(fn(q, k, v), jax.grad(lambda *a: (fn(*a) * w).sum(),
@@ -329,6 +397,104 @@ def test_unequal_attention_in_a_window_over_grouped_heads_equals_dense(
     for g, wnt in zip(got_grads, want_grads):
         assert g.shape == wnt.shape
         np.testing.assert_allclose(g, wnt, atol=5e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("window", [0, 100, 128, 300])
+def test_unequal_attention_in_a_window_over_grouped_heads_equals_dense(
+        window):
+    """In square blocks of 128 (a window of 100 leaves whole blocks out)."""
+    _windowed_grouped_heads_equal_dense(window, flash._square(128))
+
+
+@pytest.mark.parametrize("window,tiling", [
+    # a kernel's blocks are its own, none of them square
+    (0, flash.SplashTiling((128, 256, 128), (256, 512, 128), (256, 128))),
+    (100, flash.SplashTiling((256, 512, 256), (128, 256, 128), (512, 256))),
+    # the fused backward, as the causal cells run it: dq in partials
+    (0, flash.SplashTiling((256, 256, 128), (128, 256, 256), None)),
+    (300, flash.SplashTiling((128, 128, 128), (256, 128, 128), None)),
+])
+def test_unequal_attention_in_blocks_of_each_kernels_own_equals_dense(
+        window, tiling):
+    _windowed_grouped_heads_equal_dense(window, tiling)
+
+
+@pytest.mark.parametrize("heads,d_qk", [((4, 2, 1), 64), ((2, 2, 2), 192)])
+def test_the_fused_backward_rounds_dq_where_two_kernels_do_not(heads, d_qk):
+    """bf16 arrays, as the cells feed them, against dense attention in f32
+    on the same values. Two backward kernels give the same dq whatever their
+    blocks: dq is summed over the key blocks in f32 and rounded once. The
+    library's fused backward writes a partial a key block in the queries'
+    dtype (four here), so its dq alone lies further out: why no timed entry
+    takes it (PERF.md section 6, PR 34, has the chip's reading). If the
+    last line fails, the library keeps those partials wider than it did."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    seq = 512
+    keys = jax.random.split(jax.random.key(d_qk), 4)
+    q, k, v, w = (jax.random.normal(key, (1, h, seq, d), jnp.bfloat16)
+                  for key, h, d in zip(keys, (*heads, heads[0]),
+                                       (d_qk, d_qk, 128, 128)))
+    tilings = {
+        "square": flash._square(128),
+        "two kernels": flash.SplashTiling((256, 256, 128), (128, 256, 128),
+                                          (256, 128)),
+        "fused": flash.SplashTiling((256, 256, 128), (128, 128, 128), None)}
+
+    def gradients(fn, *arrays):
+        return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
+                        argnums=(0, 1, 2))(*arrays)
+
+    def dense(q, k, v):
+        return dot_product_attention(
+            q, jnp.repeat(k, heads[0] // heads[1], 1),
+            jnp.repeat(v, heads[0] // heads[2], 1), dtype=jnp.float32,
+            causal=True)
+
+    def all_forms(q, k, v):
+        want = gradients(dense, *(t.astype(jnp.float32) for t in (q, k, v)))
+        return want, {name: gradients(functools.partial(
+            flash.unequal_attention, causal=True, tiling=tiling), q, k, v)
+            for name, tiling in tilings.items()}
+
+    with pltpu.force_tpu_interpret_mode():
+        want, got = _one_program(all_forms, q, k, v)
+    error = {name: [_relative(g.astype(jnp.float32), wnt)
+                    for g, wnt in zip(grads, want)]
+             for name, grads in got.items()}
+    assert all(e < 0.01 for es in error.values() for e in es), error
+    np.testing.assert_allclose(error["two kernels"], error["square"],
+                               rtol=0.01)
+    np.testing.assert_allclose(error["fused"][1:], error["square"][1:],
+                               rtol=0.01)
+    assert error["fused"][0] > 1.02 * error["two kernels"][0], error
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((32, 12, 512, 64), False),  # c4-bert-prepacked
+    ((24, 12, 512, 64), False),  # c4-bert-ragged
+    ((2, 16, 4096, 128), True),  # c4-olmoe-prepacked-4k
+])
+def test_heads_of_equal_width_never_reach_the_splash_kernel(
+        monkeypatch, shape, causal):
+    """The cells whose heads are as wide in values as in keys run
+    ``short_attention`` or the library's blocked kernel: no entry of the
+    splash kernels' table, and no later edit to it, can move them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "_splash_kernel", lambda *_: 1 / 0)
+    monkeypatch.setattr(flash, "splash_tiling", lambda *_: 1 / 0)
+    attention = flash.make_flash_attention(
+        causal=causal, mesh=_meshes()["one device"], forced=False)
+    assert attention.fused(*shape[2:])
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((shape[0], shape[2]), jnp.int32)
+    traced = str(jax.make_jaxpr(
+        lambda q, k, v, ids: attention(q, k, v, segment_ids=ids))(
+            q, q, q, ids))
+    assert "pallas_call" in traced and "splash" not in traced
+    with pytest.raises(ZeroDivisionError):  # the guard guards
+        k = jax.ShapeDtypeStruct((*shape[:3], 2 * shape[3]), jnp.bfloat16)
+        jax.make_jaxpr(lambda q, k, v: attention(k, k, v))(q, k, q)
 
 
 # -- BERT's encoder with the chosen kernel -----------------------------------
@@ -446,13 +612,13 @@ def test_encoder_gradient_with_the_chosen_kernel_equals_dense(
 # -- train() says which path it took -----------------------------------------
 
 
-def test_train_logs_the_attention_path_and_sets_the_gauge(tmp_path,
-                                                          monkeypatch):
+def _train_a_few_steps(tmp_path, monkeypatch) -> list:
+    """``bert_small``'s one layer for four steps on the CPU, logged every
+    two; the run's JSONL lines."""
     import json
 
     from lance_distributed_training_tpu import cli
     from lance_distributed_training_tpu.data import create_text_token_dataset
-    from lance_distributed_training_tpu.obs.registry import default_registry
 
     rng = np.random.default_rng(0)
     docs = [rng.integers(2, 64, 128).tolist() for _ in range(40)]
@@ -460,16 +626,44 @@ def test_train_logs_the_attention_path_and_sets_the_gauge(tmp_path,
     create_text_token_dataset(uri, docs, seq_len=128, fragment_size=64)
     metrics_path = tmp_path / "metrics.jsonl"
     monkeypatch.setenv("LDT_METRICS_PATH", str(metrics_path))
-    default_registry().gauge("attention_fused").set(-1.0)
     cli.main([
         "train", "--dataset_path", uri, "--task_type", "masked_lm",
         "--model_name", "bert_small", "--num_layers", "1", "--seq_len", "128",
         "--vocab_size", "64", "--batch_size", "8", "--epochs", "1",
         "--max_steps", "4", "--log_every", "2", "--no_ddp", "--no_wandb",
         "--no_eval_at_end", "--no_autotune"])
+    return [json.loads(line) for line in open(metrics_path)]
+
+
+def test_train_logs_the_attention_path_and_sets_the_gauge(tmp_path,
+                                                          monkeypatch):
+    from lance_distributed_training_tpu.obs.registry import default_registry
+
+    default_registry().gauge("attention_fused").set(-1.0)
+    lines = _train_a_few_steps(tmp_path, monkeypatch)
     assert default_registry().gauge("attention_fused").value == 0.0
-    lines = [json.loads(line) for line in open(metrics_path)]
     assert [ln["attention"] for ln in lines if "attention" in ln] == ["dense"]
     steps = [ln for ln in lines if "images_per_sec_dispatch" in ln]
     assert len(steps) == 2  # the gauge rides every log line
     assert all(ln["attention_fused"] == 0.0 for ln in steps)
+
+
+def test_train_logs_the_tiling_of_each_kernel_built_once(tmp_path,
+                                                         monkeypatch):
+    """Kernels are built while a step is traced; the loop's next log point
+    says what each got (here two are built by hand: the CPU's attention is
+    dense), before that point's own line and never again."""
+    flash._splash_kernel.cache_clear()
+    flash.splash_tilings_built()
+    flash._splash_kernel(flash._Shape(8192, 192, 128, 16, True, 0))
+    flash._splash_kernel(flash._Shape(1024, 192, 128, 16, True, 0))
+    lines = _train_a_few_steps(tmp_path, monkeypatch)
+    said = [n for n, ln in enumerate(lines) if "attention_tiling" in ln]
+    first = next(n for n, ln in enumerate(lines)
+                 if "images_per_sec_dispatch" in ln)
+    assert said == [first - 2, first - 1]
+    assert [lines[n]["source"] for n in said] == ["timed", "rule"]
+    assert lines[said[0]]["attention_tiling"] == (
+        "seq=8192 d_qk=192 d_v=128 heads=16 causal=True window=0")
+    assert lines[said[0]]["dq"] == "1024/1024"
+    assert lines[said[1]]["fwd"] == lines[said[1]]["dkv"] == "512/512/512"
