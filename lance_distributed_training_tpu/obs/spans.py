@@ -16,7 +16,8 @@ surfaces:
 * **cross-process JSONL** — set ``LDT_TRACE_PATH`` (or pass ``jsonl_path``)
   and completed spans append to a JSONL file one event per line; ``ldt
   trace export --spans that-file`` stitches any number of processes'
-  files into one Perfetto-loadable trace.
+  files into one Perfetto-loadable trace. The file is written in batches
+  (durability: :meth:`SpanTracer.flush`).
 
 Clocks: span durations come from ``time.monotonic_ns`` (LDT601 forbids
 ``time.time()`` here); the JSONL/export timestamps are the same monotonic
@@ -35,6 +36,7 @@ export`` can report how much the source processes truncated.
 
 from __future__ import annotations
 
+import atexit
 import dataclasses
 import itertools
 import json
@@ -121,6 +123,11 @@ class SpanTracer:
     one JSON line, the durable form ``ldt trace export`` consumes.
     """
 
+    # The JSONL is written in batches: lines wait in memory until this many
+    # bytes are pending or the oldest pending line is this old.
+    FLUSH_BYTES = 1 << 16
+    FLUSH_AGE_NS = 250_000_000
+
     def __init__(self, capacity: int = 4096,
                  jsonl_path: Optional[str] = None):
         self._lock = threading.Lock()  # ring buffer only — never held for IO
@@ -131,6 +138,9 @@ class SpanTracer:
         self._ids = itertools.count(1)  # GIL-atomic: id allocation is lockless
         self._jsonl = None
         self._jsonl_path = jsonl_path or os.environ.get("LDT_TRACE_PATH")
+        self._pending: list = []  # JSONL lines not yet written (_io_lock)
+        self._pending_bytes = 0
+        self._pending_since = 0  # monotonic ns of the oldest pending line
         self._dropped = 0  # spans pushed off the full ring (see dropped)
 
     # -- recording ---------------------------------------------------------
@@ -244,10 +254,10 @@ class SpanTracer:
             default_registry().counter("spans_dropped_total").inc()
         if self._jsonl_path is None:
             return
-        # Serialize + flush outside the ring lock: a stalled disk slows the
-        # writer, not every thread opening a span. Flush-per-span is the
-        # durability contract (`ldt trace export` must see spans from
-        # processes that died mid-run).
+        # Serialize outside the ring lock, and write in batches: a stalled
+        # disk slows the thread whose record fills the batch, not every
+        # thread opening a span, and a span costs no system call of its own
+        # (durability: see flush).
         line = json.dumps(span.to_event()) + "\n"
         if dropped and (dropped & (dropped - 1)) == 0:
             # Cumulative drop marker at power-of-two counts: the ring in
@@ -260,30 +270,65 @@ class SpanTracer:
                 "ts": span.end_ns / 1e3,
                 "args": {"dropped": dropped},
             }) + "\n"
+        now = time.monotonic_ns()
         with self._io_lock:
             if self._jsonl_path is None:
                 return
-            if self._jsonl is None:
-                try:
-                    self._jsonl = open(self._jsonl_path, "a")
-                except OSError:
-                    self._jsonl_path = None  # never retry a bad path
-                    return
-                # One wall/monotonic anchor pair per (process, open):
-                # what lets `ldt trace export` place this process's
-                # monotonic timestamps on the shared wall timeline. An
-                # epoch stamp crossing processes — the LDT601-sanctioned
-                # use (see obs/lineage.py's clock policy).
-                self._jsonl.write(json.dumps({
-                    "name": "ldt.clock_sync", "ph": "M",
-                    "pid": os.getpid(), "tid": 0, "ts": 0,
-                    "args": {
-                        "wall_ns": time.time_ns(),
-                        "mono_ns": time.monotonic_ns(),
-                    },
-                }) + "\n")
-            self._jsonl.write(line)
-            self._jsonl.flush()
+            if not self._pending:
+                self._pending_since = now
+            self._pending.append(line)
+            self._pending_bytes += len(line)
+            # the first line opens the file at once, so a live reader finds
+            # it and its clock anchor as soon as the process records
+            if self._jsonl is None \
+                    or self._pending_bytes >= self.FLUSH_BYTES \
+                    or now - self._pending_since >= self.FLUSH_AGE_NS:
+                self._write_pending()
+
+    def _write_pending(self) -> None:
+        """Write and flush what is pending; ``_io_lock`` is held."""
+        if self._jsonl_path is None or not self._pending:
+            return
+        if self._jsonl is None:
+            try:
+                self._jsonl = open(self._jsonl_path, "a")
+            except OSError:
+                self._jsonl_path = None  # never retry a bad path
+                self._pending.clear()
+                return
+            # Whatever is pending when the interpreter exits is written
+            # then; close() takes the hook back.
+            atexit.register(self.close)
+            # One wall/monotonic anchor pair per (process, open):
+            # what lets `ldt trace export` place this process's
+            # monotonic timestamps on the shared wall timeline. An
+            # epoch stamp crossing processes — the LDT601-sanctioned
+            # use (see obs/lineage.py's clock policy).
+            self._jsonl.write(json.dumps({
+                "name": "ldt.clock_sync", "ph": "M",
+                "pid": os.getpid(), "tid": 0, "ts": 0,
+                "args": {
+                    "wall_ns": time.time_ns(),
+                    "mono_ns": time.monotonic_ns(),
+                },
+            }) + "\n")
+        self._jsonl.write("".join(self._pending))
+        self._jsonl.flush()
+        self._pending.clear()
+        self._pending_bytes = 0
+
+    def flush(self) -> None:
+        """Write every completed span to the JSONL file now. Durability
+        without it: a line reaches the file when ``FLUSH_BYTES`` are
+        pending, when a later span completes and finds the oldest pending
+        line ``FLUSH_AGE_NS`` (a quarter of a second) old, at
+        :meth:`close`, and when the interpreter exits. So a reader of a
+        live file sees a busy process at most a quarter of a second behind
+        and a quiet one up to its last flush; a process killed outright
+        (SIGKILL, ``os._exit``) loses what was pending, at most the spans of
+        its last quarter second of activity."""
+        with self._io_lock:
+            self._write_pending()
 
     # -- reading / export --------------------------------------------------
 
@@ -321,10 +366,13 @@ class SpanTracer:
         racing shutdown) still enter the ring buffer but no longer reopen
         the JSONL file."""
         with self._io_lock:
+            self._write_pending()
             self._jsonl_path = None
+            self._pending.clear()
             if self._jsonl is not None:
                 self._jsonl.close()
                 self._jsonl = None
+                atexit.unregister(self.close)
 
 
 def chrome_trace(events: List[dict]) -> dict:

@@ -26,7 +26,10 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import statistics
+import threading
 import time
+from collections import deque
 from functools import partial
 from typing import Any, Optional
 
@@ -39,8 +42,9 @@ from flax.training import train_state
 from .data.format import Dataset
 from .models.tasks import Task, get_task
 from .obs.registry import default_registry
-from .obs.spans import end_phase, watch_xla_compiles
+from .obs.spans import default_tracer, end_phase, watch_xla_compiles
 from .obs.spans import phase as obs_phase
+from .obs.spans import span as obs_span
 from .ops.flash import splash_tilings_built
 from .parallel.mesh import (
     batch_sharding,
@@ -1735,6 +1739,131 @@ def _pack_scalars(scalars):
     return jnp.stack([jnp.asarray(x, jnp.float32) for x in scalars])
 
 
+class _StepsInFlight:
+    """The steps the loop has dispatched and not yet seen finished, counted
+    without a wait: the loss array of each, oldest first, asked
+    ``is_ready()`` (no block, no transfer) where a ``train.step`` phase
+    begins and where it ends. Counter ``train_steps_dispatched_total``;
+    counter ``train_dispatch_starved_total``: steps whose dispatch began
+    with nothing in flight although the loop had not just emptied the queue
+    itself (a ``train.drain``, an epoch's start, the sampled transform
+    await), so the chips had run dry because the host was late; gauge
+    ``train_steps_in_flight_max``: the most in flight after a dispatch since
+    the last log point, which is how a run learns the runtime's limit."""
+
+    def __init__(self):
+        registry = default_registry()
+        self._pending: deque = deque()
+        self._emptied = True  # the run's first step finds an empty queue
+        self._dispatched = registry.counter("train_steps_dispatched_total")
+        self._starved = registry.counter("train_dispatch_starved_total")
+        self._max_gauge = registry.gauge("train_steps_in_flight_max")
+        self._max_after = 0
+        self._min_began = None
+
+    def _poll(self) -> int:
+        pending = self._pending
+        while pending and pending[0].is_ready():
+            pending.popleft()
+        return len(pending)
+
+    def began(self) -> int:
+        """A step's dispatch begins: the steps still running or queued."""
+        n = self._poll()
+        if not self._emptied:
+            if n == 0:
+                self._starved.inc()
+            if self._min_began is None or n < self._min_began:
+                self._min_began = n
+        self._emptied = False
+        self._dispatched.inc()
+        return n
+
+    def dispatched(self, loss) -> int:
+        """The dispatch has returned ``loss``: the steps in flight now."""
+        self._pending.append(loss)
+        n = self._poll()
+        self._max_after = max(self._max_after, n)
+        return n
+
+    def emptied(self) -> None:
+        """The loop has just waited for the newest thing it dispatched, so
+        every step before it is done: the next dispatch finds the queue
+        empty by the loop's own doing."""
+        self._pending.clear()
+        self._emptied = True
+
+    def publish(self, entry: dict):
+        """At a log point: the gauge rides the log line and the interval's
+        extremes start again. Returns the fewest in flight where a dispatch
+        began, over the interval's steps before which the loop had not
+        emptied the queue itself; None if it had none."""
+        self._max_gauge.set(self._max_after)
+        entry["train_steps_in_flight_max"] = self._max_after
+        fewest, self._max_after, self._min_began = self._min_began, 0, None
+        return fewest
+
+
+class _SlowIntervals:
+    """A log interval that took over ``FACTOR`` times the run's median
+    seconds a step leaves a record, traced or not: counter
+    ``log_interval_slow_total`` and one ``slow_interval`` line through the
+    logger, its ``by_span`` summed from the tracer's ring (which every run
+    keeps) over the loop thread's phases and the ``loop.*`` spans under
+    them since the last log point. The median is over the earlier
+    intervals of the same kind, the last ``KEEP`` of them, and an interval
+    is judged from the third of its kind on: one that holds an epoch
+    turnover is held against those that held one (where ``log_every`` is an
+    epoch, every interval does). An interval in which something compiled is
+    judged like any other, its line says how many ``compiles``, and it is
+    left out of the median. Runs at log points only and reads what is
+    recorded anyway."""
+
+    FACTOR = 1.5
+    KEEP = 101
+    clock = staticmethod(time.monotonic_ns)  # the spans' clock
+
+    def __init__(self):
+        registry = default_registry()
+        self._slow = registry.counter("log_interval_slow_total")
+        self._compiles = registry.counter("xla_compiles_total")
+        self._earlier = {False: deque(maxlen=self.KEEP),
+                         True: deque(maxlen=self.KEEP)}
+        self._mark = None  # the last log point
+
+    def check(self, step: int, epoch: int, in_flight_min, logger) -> None:
+        tracer = default_tracer()
+        mark = (self.clock(), step, epoch, self._compiles.value,
+                tracer.dropped)
+        last, self._mark = self._mark, mark
+        if last is None or step <= last[1]:
+            return
+        seconds = (mark[0] - last[0]) / 1e9
+        steps = step - last[1]
+        compiles = int(mark[3] - last[3])
+        earlier = self._earlier[epoch != last[2]]
+        median = statistics.median(earlier) if len(earlier) >= 2 else None
+        if not compiles:
+            earlier.append(seconds / steps)
+        if median is None or seconds / steps <= self.FACTOR * median:
+            return
+        self._slow.inc()
+        thread = threading.get_ident() % 2**31
+        by_span: dict = {}
+        for s in tracer.spans():
+            if s.thread_id == thread and s.start_ns >= last[0] \
+                    and s.name.startswith(("train.", "loop.")):
+                by_span[s.name] = by_span.get(s.name, 0.0) \
+                    + (s.end_ns - s.start_ns) / 1e9
+        logger.log({"slow_interval": {
+            "steps": [last[1], step], "seconds": round(seconds, 6),
+            "median_seconds": round(median * steps, 6),
+            "by_span": {k: round(v, 6) for k, v in sorted(by_span.items())},
+            "in_flight_min": in_flight_min, "compiles": compiles,
+            "spans_dropped": mark[4] - last[4],
+        }}, to_wandb=False)
+
+
 def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 eval_step, logger, timer, worker_pool, ckpt, start_epoch,
                 total_start, n_devices, results, global_step, profiling,
@@ -1745,18 +1874,19 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
     if journal is None:
         journal = _CkptJournal(resume_global_step)
     step_stats = _StepStats()
+    flight = _StepsInFlight()
+    slow = _SlowIntervals()
     # Device-decode transform stage (--device_decode): one jitted kernel
     # call replacing a batch's coefficient pages with the decoded image —
     # device work dispatched from the consumer thread, so it overlaps the
-    # previous step's compute exactly like the H2D ring does. Timed into
-    # trainer_transform_ms (dispatch time; the device cost itself lands
-    # inside the step's execution window on async backends). Pixel batches
+    # previous step's compute exactly like the H2D ring does. Its dispatch
+    # is the train.transform phase (the device cost itself lands inside
+    # the step's execution window on async backends). Pixel batches
     # (the --no_device_decode arm) pass through, so one handle covers both
     # arms. Applied BEFORE the device_cache fill: the cache then holds
     # finished image batches, decoding each coefficient page exactly once
     # per run.
     transform = None
-    transform_hist = None
     device_ms_hist = None
     probe_key = "image"  # leaf the sampled transform-await fetches from
     if config.token_pack:
@@ -1773,14 +1903,12 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         transform = make_pack_transform(
             batch_sharding=batch_sharding(mesh) if mesh is not None else None
         )
-        transform_hist = default_registry().histogram("trainer_transform_ms")
         device_ms_hist = default_registry().histogram("pack_device_ms")
         probe_key = "input_ids"
     if config.device_decode:
         from .ops.jpeg_device import make_batch_transform
 
         transform = make_batch_transform(config.image_size)
-        transform_hist = default_registry().histogram("trainer_transform_ms")
         # decode_device_ms: the kernel's REAL device cost, sampled — every
         # 16th batch the transform is awaited to completion and timed (one
         # sync per 16 steps; the other 15 stay fully async). This is what
@@ -1914,28 +2042,32 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 # async backends execute it inside the step window).
                 sample = epoch_batches % 16 == 0
                 raw = batch
-                t0 = time.monotonic_ns()
                 obs_phase("train.transform", step=global_step)
-                batch = transform(raw)
-                decoded = batch is not raw
-                if sample and decoded and probe_key in batch:
-                    # Await the sampled kernel run so the device-cost
-                    # histogram records execution, not dispatch. Waiting
-                    # on the leaf compiles nothing and copies nothing (an
-                    # element fetch compiled a slice per grid shape, inside
-                    # the window it measured). Degraded/padded batches pass
-                    # through `raw` unchanged and are never sampled.
-                    jax.block_until_ready(batch[probe_key])
-                dt_ms = (time.monotonic_ns() - t0) / 1e6
-                obs_phase("train.bookkeep")
-                transform_hist.observe(dt_ms)
-                if sample and decoded:
+                t0 = time.monotonic_ns() if sample else 0
+                with obs_span("loop.transform_dispatch", step=global_step):
+                    batch = transform(raw)
+                if sample and batch is not raw:
+                    if probe_key in batch:
+                        # Await the sampled kernel run so the device-cost
+                        # histogram records execution, not dispatch. Waiting
+                        # on the leaf compiles nothing and copies nothing
+                        # (an element fetch compiled a slice per grid shape,
+                        # inside the window it measured). Degraded/padded
+                        # batches pass through `raw` unchanged and are
+                        # never sampled. Every earlier step is done by then:
+                        # the wait empties the queue as a drain does.
+                        with obs_span("loop.transform_await",
+                                      step=global_step):
+                            jax.block_until_ready(batch[probe_key])
+                        flight.emptied()
                     if global_step > 0:
                         # Skip the run's first sample: it pays the kernel's
                         # XLA compile, which would dominate the histogram's
                         # p50 and skew the autotuner's decode_split toward
                         # device_transform_bound on cold starts.
-                        device_ms_hist.observe(dt_ms)
+                        device_ms_hist.observe(
+                            (time.monotonic_ns() - t0) / 1e6)
+                obs_phase("train.bookkeep")
             epoch_batches += 1
             if filling:
                 refused = dev_cache.admit(batch, len(loader))
@@ -1978,14 +2110,20 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 # Data echoing: each echo re-splits the rng, so on-device
                 # augmentation / MLM masking differ between echoes of the
                 # same host batch (TrainConfig.data_echo).
-                rng, step_rng = jax.random.split(rng)
+                with obs_span("loop.rng_split", step=global_step):
+                    rng, step_rng = jax.random.split(rng)
                 timer.step_start()
-                obs_phase("train.step", step=global_step)  # dispatch only
+                # dispatch only
+                at_step = obs_phase("train.step", step=global_step)
+                at_step["in_flight"] = flight.began()
                 state, loss, *extras = train_step(state, batch, step_rng)
+                at_step["in_flight_after"] = flight.dispatched(loss)
                 gnorm = extras.pop(0) if config.log_grad_norm else None
                 obs_phase("train.bookkeep")
-                loss_sum = loss_sum + loss
-                step_stats.add(extras[0])
+                with obs_span("loop.loss_sum", step=global_step):
+                    loss_sum = loss_sum + loss
+                with obs_span("loop.stats_add", step=global_step):
+                    step_stats.add(extras[0])
                 # Bound the async dispatch queue (each in-flight step pins
                 # its global batch on device) — independent of logging, so
                 # neither log_every=0 nor a huge log_every can unbound
@@ -2003,6 +2141,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                     obs_phase("train.drain", step=global_step)
                     _ = float(loss)  # ldt: ignore[LDT1704] -- deliberate bounded drain: fetch at sync_every/log points keeps dispatch depth finite
                     obs_phase("train.bookkeep")
+                    flight.emptied()
                 timer.step_stop()
                 global_step += 1
                 epoch_step += 1
@@ -2023,34 +2162,36 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                     # bound) leads the progress line, so it agrees with the
                     # epoch metrics' wall-clock rate on async backends.
                     obs_phase("train.log", step=global_step)
-                    w = timer.window(batch_size=config.batch_size)
-                    wt = w["loader_s"] + w["step_s"]
-                    entry = {
-                        "step": global_step,
-                        "epoch": epoch,
-                        "loss": round(float(loss), 4),  # ldt: ignore[LDT1704] -- log-interval telemetry fetch of the already-drained scalar
-                        "images_per_sec": w["images_per_sec_wall"],
-                        "images_per_sec_dispatch":
-                            w["images_per_sec_dispatch"],
-                        "loader_stall_pct": (
-                            100.0 * w["loader_s"] / wt if wt else 0.0
-                        ),
-                    }
-                    if "placement_h2d_s" in w:
-                        # H2D dispatch time this window (runs on the
-                        # placement thread, overlapping the step) as a
-                        # share of the same loader+step denominator — the
-                        # transfer cost the pre-r7 accounting folded
-                        # invisibly into loader_stall_pct.
-                        entry["h2d_pct"] = (
-                            100.0 * w["placement_h2d_s"] / wt if wt else 0.0
-                        )
-                    # Data-service windows (RemoteLoader counters attached
-                    # to the timer): svc_client_stall_s, svc_reconnects, …
-                    entry.update({
-                        k: round(v, 4) if isinstance(v, float) else v
-                        for k, v in w.items() if k.startswith("svc_")
-                    })
+                    with obs_span("loop.log_entry", step=global_step):
+                        w = timer.window(batch_size=config.batch_size)
+                        wt = w["loader_s"] + w["step_s"]
+                        entry = {
+                            "step": global_step,
+                            "epoch": epoch,
+                            "loss": round(float(loss), 4),  # ldt: ignore[LDT1704] -- log-interval telemetry fetch of the already-drained scalar
+                            "images_per_sec": w["images_per_sec_wall"],
+                            "images_per_sec_dispatch":
+                                w["images_per_sec_dispatch"],
+                            "loader_stall_pct": (
+                                100.0 * w["loader_s"] / wt if wt else 0.0
+                            ),
+                        }
+                        if "placement_h2d_s" in w:
+                            # H2D dispatch time this window (runs on the
+                            # placement thread, overlapping the step) as a
+                            # share of the same loader+step denominator — the
+                            # transfer cost the pre-r7 accounting folded
+                            # invisibly into loader_stall_pct.
+                            entry["h2d_pct"] = (
+                                100.0 * w["placement_h2d_s"] / wt
+                                if wt else 0.0
+                            )
+                        # Data-service windows (RemoteLoader counters
+                        # attached to the timer): svc_client_stall_s, …
+                        entry.update({
+                            k: round(v, 4) if isinstance(v, float) else v
+                            for k, v in w.items() if k.startswith("svc_")
+                        })
                     if lr_fn is not None:
                         # Schedules count optimizer updates, not
                         # micro-steps; base_step carries the restored
@@ -2058,12 +2199,15 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                         updates = (base_step + global_step) // max(
                             config.grad_accum, 1
                         )
-                        entry["lr"] = float(
-                            lr_fn(updates) if callable(lr_fn) else lr_fn
-                        )
+                        with obs_span("loop.log_lr", step=global_step):
+                            entry["lr"] = float(
+                                lr_fn(updates) if callable(lr_fn) else lr_fn
+                            )
                     if gnorm is not None:
                         entry["grad_norm"] = round(float(gnorm), 4)  # ldt: ignore[LDT1704] -- log-interval divergence telemetry, rides the loss drain
-                    step_stats.publish(entry)
+                    with obs_span("loop.stats_fetch", step=global_step):
+                        step_stats.publish(entry)
+                    in_flight_min = flight.publish(entry)
                     if attention_fused is not None:
                         entry["attention_fused"] = attention_fused
                         # the steps so far were traced: each splash kernel
@@ -2078,7 +2222,9 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                         entry["unique_images_per_sec"] = (
                             entry["images_per_sec"] / config.data_echo
                         )
-                    logger.log(entry, to_wandb=False)
+                    with obs_span("loop.log_write", step=global_step):
+                        logger.log(entry, to_wandb=False)
+                    slow.check(global_step, epoch, in_flight_min, logger)
                     obs_phase("train.bookkeep")
                 if stop:
                     break
@@ -2086,46 +2232,49 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             # state with the cursor naming the NEXT batch (the loader's
             # state_dict reads "batches handed out", which at this point
             # equals batches consumed — see the data/pipeline.py contract).
-            journal.state = state
-            journal.rng = rng
-            journal.abs_step = resume_global_step + global_step
-            if loader is not None:
-                cursor_base = dict(loader.state_dict())
-                cursor_base.setdefault("epoch", epoch)
-            else:
-                # device_cache replay arm: the cached stream is the FROZEN
-                # epoch-0 batch set under a cache-local permutation — for
-                # shuffled/map configs a cacheless restart building the
-                # fresh epoch-e plan would serve a DIFFERENT set/order, so
-                # a mid-epoch cursor here would silently skip and repeat
-                # samples. Pin the epoch start instead: a restart re-runs
-                # this epoch from storage — deterministic over-training of
-                # up to one epoch, never silently lost data.
-                cursor_base = {"epoch": epoch, "step": 0}
-            journal.cursor_base = cursor_base
-            if (
-                ckpt is not None
-                and config.checkpoint_every_steps > 0
-                and journal.abs_step
-                >= journal.saved_step + config.checkpoint_every_steps
-            ):
-                # Async step checkpoint (the epoch-boundary save awaits via
-                # ckpt.close()); ">= saved + N" rather than "% N" so
-                # data_echo's multi-step jumps can't skip the trigger.
-                if ckpt.save(journal.abs_step, state,
-                             cursor=journal.make_cursor()):
-                    journal.saved_step = journal.abs_step
-            if chaos is not None:
-                chaos.on_step(global_step)
-            if preempt is not None and preempt.requested and not stop:
-                # Orchestrated preemption (SIGTERM): the in-flight step has
-                # finished; drain the loader/placement ring below and let
-                # train()'s finally take the awaited emergency checkpoint.
-                journal.preempted = True
-                logger.log({"preempted": True,
-                            "at_step": journal.abs_step,
-                            "epoch": epoch}, to_wandb=False)
-                stop = True
+            with obs_span("loop.cursor", step=global_step):
+                journal.state = state
+                journal.rng = rng
+                journal.abs_step = resume_global_step + global_step
+                if loader is not None:
+                    cursor_base = dict(loader.state_dict())
+                    cursor_base.setdefault("epoch", epoch)
+                else:
+                    # device_cache replay arm: the cached stream is the
+                    # FROZEN epoch-0 batch set under a cache-local
+                    # permutation — for shuffled/map configs a cacheless
+                    # restart building the fresh epoch-e plan would serve a
+                    # DIFFERENT set/order, so a mid-epoch cursor here would
+                    # silently skip and repeat samples. Pin the epoch start
+                    # instead: a restart re-runs this epoch from storage —
+                    # deterministic over-training of up to one epoch, never
+                    # silently lost data.
+                    cursor_base = {"epoch": epoch, "step": 0}
+                journal.cursor_base = cursor_base
+                if (
+                    ckpt is not None
+                    and config.checkpoint_every_steps > 0
+                    and journal.abs_step
+                    >= journal.saved_step + config.checkpoint_every_steps
+                ):
+                    # Async step checkpoint (the epoch-boundary save awaits
+                    # via ckpt.close()); ">= saved + N" rather than "% N" so
+                    # data_echo's multi-step jumps can't skip the trigger.
+                    if ckpt.save(journal.abs_step, state,
+                                 cursor=journal.make_cursor()):
+                        journal.saved_step = journal.abs_step
+                if chaos is not None:
+                    chaos.on_step(global_step)
+                if preempt is not None and preempt.requested and not stop:
+                    # Orchestrated preemption (SIGTERM): the in-flight step
+                    # has finished; drain the loader/placement ring below and
+                    # let train()'s finally take the awaited emergency
+                    # checkpoint.
+                    journal.preempted = True
+                    logger.log({"preempted": True,
+                                "at_step": journal.abs_step,
+                                "epoch": epoch}, to_wandb=False)
+                    stop = True
             if stop:
                 # max_steps / preemption mid-epoch: close the loader's
                 # generator so producer threads and the placement ring
@@ -2142,6 +2291,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         # device work (and the epoch's mean loss needs the value anyway).
         loss_sum_host = float(loss_sum)  # ldt: ignore[LDT1704] -- epoch-boundary fetch: the D2H is what guarantees epoch_time covers all device work
         epoch_time = time.perf_counter() - epoch_start
+        flight.emptied()  # the fetch waited for the epoch's last step
         steps = timer.steps
         epoch_metrics = {
             "epoch": epoch,

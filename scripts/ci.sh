@@ -39,7 +39,7 @@
 # JPEG entropy split on forced-CPU devices — host-vs-device parity within
 # the pinned envelope with bit-identical device-arm repeats, a live
 # /metrics scrape of the decode_entropy_ms / decode_device_ms /
-# trainer_transform_ms / decode_*_bytes_total series during a real
+# decode_*_bytes_total series during a real
 # --device_decode train run, and zero BufferPool-lease or /dev/shm leaks
 # under LDT_LEAK_SANITIZER=1.
 # Stage 7c — batch-cache smoke (scripts/cache_smoke.py): a real two-epoch

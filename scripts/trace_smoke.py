@@ -19,6 +19,14 @@ observability plane end-to-end, on real subprocess artifacts:
   queue-wait percentiles merged from BOTH members' heartbeat histograms
   (``fleet_queue_wait_p99_ms`` live on its ``/metrics``).
 
+Durability of the span files it reads (``obs/spans.py``, batched since PR
+35): a process writes its file when 64 KiB of lines are pending, when a span
+completes a quarter of a second after the oldest pending one, at
+``SpanTracer.flush()`` / ``close()`` and at interpreter exit; its first span
+opens the file at once. The three subprocesses have exited (SIGTERM drain,
+normal exit) before their files are read, so those are complete; this
+process's own tracer (the coordinator's) is flushed by hand before the merge.
+
 Equivalent by hand:
     LDT_TRACE_PATH=coord.jsonl ldt coordinator --port 8470 &
     LDT_TRACE_PATH=srv0.jsonl LDT_COST_PATH=cost0.jsonl \
@@ -219,6 +227,9 @@ def main() -> None:
         # Quiesce the in-process coordinator too, so coord.jsonl is not
         # being appended to while the merge below reads it.
         coord.stop()
+        from lance_distributed_training_tpu.obs.spans import default_tracer
+
+        default_tracer().flush()  # the file is written in batches
 
         jsonls = [tmp / "coord.jsonl", tmp / "srv0.jsonl",
                   tmp / "srv1.jsonl", tmp / "train.jsonl"]

@@ -416,8 +416,353 @@ def test_probe_compiles_nothing_for_a_second_grid_shape(tracer, tmp_path):
     got = tracer.spans()
     transforms = {s.span_id for s in got if s.name == "train.transform"}
     assert len(transforms) == 17
+    # a compile's parent is the innermost span it happened in: since PR 35
+    # the transform's dispatch and its sampled await inside the phase
+    inside = transforms | {s.span_id for s in got
+                           if s.name.startswith("loop.transform_")
+                           and s.parent_id in transforms}
     compiled = {s.attrs["fun_name"] for s in got if s.name == "xla.compile"
-                and s.parent_id in transforms}
+                and s.parent_id in inside}
     assert any("pack_token_batch" in name for name in compiled)
     assert not [name for name in compiled
                 if "slice" in name or "squeeze" in name], compiled
+
+
+# -- the calls inside the phases, steps in flight, slow intervals (PR 35) -----
+
+LOOP_CALLS = {
+    "train.bookkeep": {"loop.rng_split", "loop.loss_sum", "loop.stats_add",
+                       "loop.cursor"},
+    "train.transform": {"loop.transform_dispatch", "loop.transform_await"},
+    "train.log": {"loop.log_entry", "loop.log_lr", "loop.stats_fetch",
+                  "loop.log_write"},
+}
+
+
+def _fake_clock(jump_at=None):
+    """A log-point clock for ``_SlowIntervals``: a second a call, behind the
+    spans' clock (so the ring's spans count as since the last log point),
+    and ten seconds more at call ``jump_at``."""
+    import time
+
+    base = time.monotonic_ns() - 10**12
+    calls = []
+
+    def clock():
+        calls.append(1)
+        late = 10 if jump_at is not None and len(calls) > jump_at else 0
+        return base + (len(calls) + late) * 10**9
+
+    return clock
+
+
+@pytest.fixture(scope="module")
+def even_run(tmp_path_factory, image_table):
+    """One tiny run for the cases below: ten steps in one epoch, a log point
+    every second step, its log points exactly a second apart by a stubbed
+    clock. Yields spans, results, metrics lines and counter differences."""
+    import json
+
+    from lance_distributed_training_tpu import trainer
+    from lance_distributed_training_tpu.data import write_dataset
+
+    tmp = tmp_path_factory.mktemp("even")
+    dataset = write_dataset(image_table, tmp / "ds", mode="create",
+                            max_rows_per_file=100)
+    names = ("train_steps_dispatched_total", "train_dispatch_starved_total",
+             "log_interval_slow_total")
+    registry = default_registry()
+    before = {n: registry.counter(n).value for n in names}
+    fresh = SpanTracer(capacity=1 << 16)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(spans_mod, "_DEFAULT", fresh)
+        patch.setattr(trainer._SlowIntervals, "clock",
+                      staticmethod(_fake_clock()))
+        patch.setenv("LDT_METRICS_PATH", str(tmp / "metrics.jsonl"))
+        results = trainer.train(_image_run_config(
+            dataset, batch_size=24, epochs=1))
+    lines = [json.loads(x) for x in open(tmp / "metrics.jsonl")]
+    return {"spans": fresh.spans(), "results": results, "lines": lines,
+            "counters": {n: registry.counter(n).value - before[n]
+                         for n in names},
+            "gauge": registry.gauge("train_steps_in_flight_max").value}
+
+
+def test_every_loop_call_lies_inside_a_phase_of_its_thread(even_run):
+    got = even_run["spans"]
+    assert even_run["results"]["steps"] == 10
+    phases = {s.span_id: s for s in got if s.name.startswith("train.")}
+    calls = [s for s in got if s.name.startswith("loop.")]
+    for s in calls:
+        parent = phases[s.parent_id]  # a phase, and none of startup.*
+        assert s.name in LOOP_CALLS[parent.name], (s.name, parent.name)
+        assert parent.thread_id == s.thread_id
+        assert parent.start_ns <= s.start_ns <= s.end_ns <= parent.end_ns
+        assert "step" in s.attrs
+    per_step = {"loop.rng_split", "loop.loss_sum", "loop.stats_add",
+                "loop.cursor"}
+    counts = {n: sum(1 for s in calls if s.name == n)
+              for n in per_step | {"loop.log_entry", "loop.stats_fetch",
+                                   "loop.log_write"}}
+    assert counts == {**dict.fromkeys(per_step, 10), "loop.log_entry": 5,
+                      "loop.stats_fetch": 5, "loop.log_write": 5}
+    # each per-step call once a step, under that step's number
+    for name in per_step:
+        steps = sorted(s.attrs["step"] for s in calls if s.name == name)
+        assert steps == list(range(10)) or steps == list(range(1, 11)), name
+
+
+def test_loop_and_placement_threads_still_tile_with_calls_inside(even_run):
+    (loop,) = by_thread(even_run["spans"], LOOP_NAMES)
+    assert_tiles(loop)
+    assert {s.parent_id for s in loop} == {0}
+    (own,) = by_thread(even_run["spans"], PLACEMENT_NAMES)
+    assert_tiles(own)
+    # nothing named train.* was opened inside a phase
+    assert not [s.name for s in even_run["spans"]
+                if s.name.startswith("train.") and s.parent_id]
+
+
+def test_steps_in_flight_ride_the_step_phase_and_the_counters(even_run):
+    (loop,) = by_thread(even_run["spans"], LOOP_NAMES)
+    steps = [s for s in loop if s.name == "train.step"]
+    assert len(steps) == 10
+    assert all(s.attrs["in_flight"] >= 0 for s in steps)
+    assert all(0 <= s.attrs["in_flight_after"] <= s.attrs["in_flight"] + 1
+               for s in steps)
+    names = [s.name for s in loop]
+    after_drain = [loop[names.index("train.step", i)]
+                   for i, n in enumerate(names[:-1]) if n == "train.drain"
+                   and "train.step" in names[i:]]
+    assert len(after_drain) == 4  # five drains, the last ends the run
+    assert [s.attrs["in_flight"] for s in after_drain] == [0] * 4
+    assert steps[0].attrs["in_flight"] == 0
+    counters = even_run["counters"]
+    assert counters["train_steps_dispatched_total"] == 10
+    # a step after a drain is never starved: at most the other five are
+    assert 0 <= counters["train_dispatch_starved_total"] <= 5
+    progress = [ln for ln in even_run["lines"]
+                if "images_per_sec_dispatch" in ln]
+    assert len(progress) == 5
+    # two steps between drains: never more than two in flight
+    assert all(0 <= ln["train_steps_in_flight_max"] <= 2 for ln in progress)
+    assert even_run["gauge"] == progress[-1]["train_steps_in_flight_max"]
+
+
+def test_an_even_run_logs_no_slow_interval(even_run):
+    assert even_run["counters"]["log_interval_slow_total"] == 0
+    assert not [ln for ln in even_run["lines"] if "slow_interval" in ln]
+
+
+def test_an_interval_made_slow_logs_one_line_and_counts_once(
+        tracer, image_dataset, tmp_path, monkeypatch):
+    """The same run with ten seconds put into its fourth interval by the
+    stubbed clock: one ``slow_interval`` line, steps 6 to 8, held against
+    the two intervals before it in which nothing compiled (the first holds
+    the step's compile and is left out of the median), its spans summed by
+    name from the ring."""
+    import json
+
+    from lance_distributed_training_tpu import trainer
+
+    slow = default_registry().counter("log_interval_slow_total")
+    before = slow.value
+    monkeypatch.setattr(trainer._SlowIntervals, "clock",
+                        staticmethod(_fake_clock(jump_at=3)))
+    monkeypatch.setenv("LDT_METRICS_PATH", str(tmp_path / "metrics.jsonl"))
+    trainer.train(_image_run_config(image_dataset, batch_size=24, epochs=1))
+    assert slow.value - before == 1
+    lines = [json.loads(x) for x in open(tmp_path / "metrics.jsonl")]
+    (line,) = [ln["slow_interval"] for ln in lines if "slow_interval" in ln]
+    assert set(line) == {"steps", "seconds", "median_seconds", "by_span",
+                         "in_flight_min", "compiles", "spans_dropped"}
+    assert line["steps"] == [6, 8]
+    assert (line["seconds"], line["median_seconds"]) == (11.0, 1.0)
+    assert line["compiles"] == 0 and line["spans_dropped"] == 0
+    assert line["in_flight_min"] is None or line["in_flight_min"] >= 0
+    assert {"train.loader", "train.step", "train.drain", "train.bookkeep",
+            "train.log", "loop.rng_split", "loop.loss_sum", "loop.stats_add",
+            "loop.cursor", "loop.log_write"} <= set(
+                line["by_span"])
+    assert all(n.startswith(("train.", "loop.")) for n in line["by_span"])
+    # the line is the loop thread's: nothing of the placement thread in it
+    assert not set(line["by_span"]) & PLACEMENT_NAMES
+
+
+class _Lines:
+    def __init__(self):
+        self.lines = []
+
+    def log(self, entry, **kw):
+        self.lines.append(entry)
+
+
+@pytest.mark.parametrize("case", ["third_on", "turnover_against_its_like",
+                                  "compile_left_out_of_the_median"])
+def test_slow_intervals_are_judged_against_their_like(case, monkeypatch):
+    from lance_distributed_training_tpu import trainer
+
+    times = iter(())
+    monkeypatch.setattr(trainer._SlowIntervals, "clock",
+                        staticmethod(lambda: next(times)))
+    slow = trainer._SlowIntervals()
+    compiles = default_registry().counter("xla_compiles_total")
+    out = _Lines()
+    s = 10**9
+    if case == "third_on":
+        # (log point at second, step, epoch): the second interval is ten
+        # times the first and is not judged, the fourth is
+        points = [(0, 0, 0), (1, 2, 0), (11, 4, 0), (12, 6, 0), (30, 8, 0),
+                  (31, 10, 0)]
+        want = [[6, 8]]
+    elif case == "turnover_against_its_like":
+        # every third interval holds an epoch's turnover and takes 5 s:
+        # held against the other turnovers it is even; the last takes 9
+        points = [(0, 0, 0), (1, 2, 0), (2, 4, 0), (7, 6, 1), (8, 8, 1),
+                  (9, 10, 1), (14, 12, 2), (15, 14, 2), (16, 16, 2),
+                  (25, 18, 3)]
+        want = [[16, 18]]
+    else:
+        # the third interval compiles and takes 20 s: its line says so, and
+        # the fifth is still held against a median of 1 s
+        points = [(0, 0, 0), (1, 2, 0), (2, 4, 0), (22, 6, 0), (23, 8, 0),
+                  (25, 10, 0)]
+        want = [[4, 6], [8, 10]]
+    times = iter([p[0] * s for p in points])
+    for i, (_, step, epoch) in enumerate(points):
+        if case == "compile_left_out_of_the_median" and step == 6:
+            compiles.inc(2)
+        slow.check(step, epoch, None, out)
+    got = [ln["slow_interval"] for ln in out.lines]
+    assert [ln["steps"] for ln in got] == want
+    if case == "compile_left_out_of_the_median":
+        assert [ln["compiles"] for ln in got] == [2, 0]
+        assert [ln["median_seconds"] for ln in got] == [1.0, 1.0]
+
+
+class _Loss:
+    def __init__(self):
+        self.done = False
+
+    def is_ready(self):
+        return self.done
+
+
+def test_starved_counts_an_empty_queue_the_loop_did_not_empty():
+    from lance_distributed_training_tpu import trainer
+
+    registry = default_registry()
+    names = ("train_steps_dispatched_total", "train_dispatch_starved_total")
+    before = {n: registry.counter(n).value for n in names}
+    flight = trainer._StepsInFlight()
+    a, b, c, d = (_Loss() for _ in range(4))
+    assert flight.began() == 0  # the run's first step: not starved
+    assert flight.dispatched(a) == 1
+    assert flight.began() == 1
+    assert flight.dispatched(b) == 2
+    a.done = b.done = True  # the device ran dry behind the host's back
+    assert flight.began() == 0  # starved
+    assert flight.dispatched(c) == 1
+    entry = {}
+    assert flight.publish(entry) == 0  # the fewest in flight at a dispatch
+    assert entry == {"train_steps_in_flight_max": 2}
+    flight.emptied()  # a drain
+    assert flight.began() == 0  # the loop emptied it itself: not starved
+    c.done = True
+    assert flight.dispatched(d) == 1
+    assert flight.publish({}) is None  # no step but the one after the drain
+    assert {n: registry.counter(n).value - before[n] for n in names} == {
+        "train_steps_dispatched_total": 4, "train_dispatch_starved_total": 1}
+
+
+def test_probed_transform_has_its_dispatch_and_its_await_named(tracer,
+                                                               tmp_path):
+    """Under --token_pack every batch's transform has its dispatch as a
+    span, the sampled one its await too, and the step after an await finds
+    nothing in flight without being counted as starved for it."""
+    from lance_distributed_training_tpu.data.authoring import (
+        create_variable_length_token_dataset,
+    )
+    from lance_distributed_training_tpu.trainer import TrainConfig, train
+
+    path = tmp_path / "toks"
+    create_variable_length_token_dataset(
+        str(path), rows=544, vocab_size=64, max_len=32, mean_len=8)
+    train(TrainConfig(
+        dataset_path=str(path), task_type="masked_lm",
+        model_name="bert_small", vocab_size=64, seq_len=32, batch_size=32,
+        epochs=1, no_wandb=True, eval_at_end=False, token_pack=True,
+        pack_rows_multiple=2, log_every=0, autotune=False, no_ddp=True))
+    got = tracer.spans()
+    transforms = {s.span_id: s for s in got if s.name == "train.transform"}
+    dispatches = [s for s in got if s.name == "loop.transform_dispatch"]
+    awaits = [s for s in got if s.name == "loop.transform_await"]
+    assert len(transforms) == len(dispatches) == 17
+    assert [s.attrs["step"] for s in awaits] == [0, 16]  # every 16th batch
+    for s in dispatches + awaits:
+        assert s.parent_id in transforms
+    for s in awaits:
+        first = next(d for d in dispatches if d.parent_id == s.parent_id)
+        assert first.end_ns <= s.start_ns
+    steps = {s.attrs["step"]: s for s in got if s.name == "train.step"}
+    assert [steps[s.attrs["step"]].attrs["in_flight"] for s in awaits] == [
+        0, 0]
+
+
+# -- the span file is written in batches (PR 35) ------------------------------
+
+
+def _names_in(path):
+    import json
+
+    return [json.loads(x)["name"] for x in path.read_text().splitlines()]
+
+
+@pytest.mark.parametrize("by", ["size", "age", "flush", "close"])
+def test_span_file_is_written_in_batches(tmp_path, by):
+    path = tmp_path / "spans.jsonl"
+    tr = SpanTracer(jsonl_path=str(path))
+    tr.FLUSH_BYTES, tr.FLUSH_AGE_NS = 1 << 30, 1 << 62
+    with tr.span("first"):
+        pass
+    # the first span opens the file: a live reader finds the clock anchor
+    assert _names_in(path) == ["ldt.clock_sync", "first"]
+    for i in range(3):
+        with tr.span(f"held{i}"):
+            pass
+    assert _names_in(path) == ["ldt.clock_sync", "first"]
+    if by == "size":
+        tr.FLUSH_BYTES = 1
+    elif by == "age":
+        tr.FLUSH_AGE_NS = 0
+    if by in ("size", "age"):
+        with tr.span("last"):
+            pass
+        want = ["held0", "held1", "held2", "last"]
+    else:
+        getattr(tr, by)()
+        want = ["held0", "held1", "held2"]
+    assert _names_in(path) == ["ldt.clock_sync", "first"] + want
+    tr.close()
+    tr.close()  # idempotent
+    with tr.span("after close"):  # the ring still takes it, the file not
+        pass
+    assert _names_in(path) == ["ldt.clock_sync", "first"] + want
+    assert tr.spans()[-1].name == "after close"
+
+
+def test_benchmark_loop_readers_check_passes():
+    """The five readers of the loop's calls read the hand-made spans as
+    worked out by eye, and None on a span file from before PR 35."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, "benchmark", "check_loop.py")],
+        cwd=repo, capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == "loop ok"
