@@ -452,6 +452,24 @@ def _expert_share(share: Optional[str], ctor) -> tuple:
     return (("first_expert", rank * held), ("held_experts", held))
 
 
+@jax.custom_vjp
+def _last_position_unlearned(logits):
+    """``[B, S, V]`` logits as they are; their cotangent with the last
+    position's row at exactly 0. That position has no target, and what the
+    log-sum-exp's backward makes of a weight of 0 there is 0 x softmax: nan
+    where the logits are not finite. Written as an update of that one row,
+    in place, and not as a select over the grid: XLA fuses a select into both
+    of the head's backward products and takes the softmax's exponentials
+    once more in each (2 ms of the OLMoE cell's step; chip, PR 36), where
+    the update leaves one cotangent written once in the products' bf16."""
+    return logits
+
+
+_last_position_unlearned.defvjp(
+    lambda logits: (logits, None),
+    lambda _, ct: (ct.at[:, -1].set(0),))
+
+
 def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                     attention_fn: Optional[Callable] = None,
                     remat: bool = False, num_experts: int = 0,
@@ -463,7 +481,10 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
     stack) over the same packed
     token columns as masked-LM (``create_text_token_dataset``) — the text arm
     beyond the reference's vision-only scope, sharing the trainer, samplers
-    and storage unchanged."""
+    and storage unchanged. The shift by one token is applied to the targets
+    and their weights and never to the ``[B, S, V]`` logits: a slice of
+    ``S - 1`` rows makes XLA re-lay them (and their cotangent) off the
+    layout the head wrote, in loops where ``V`` is no multiple of 128."""
     if model_name not in _CAUSAL_LMS:
         raise ValueError(f"Invalid model name: {model_name} "
                          f"(have {sorted(_CAUSAL_LMS)})")
@@ -560,12 +581,19 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
             # packed sequence is a junction, not a prediction — weight it
             # out, so the packed loss matches per-sequence semantics.
             w = w * (seg[:, 1:] == seg[:, :-1]).astype(jnp.float32)
-        return logits[:, :-1], targets, w, aux
+        # The shift lives in these two [B, S] arrays: the last position has
+        # no target, so it gets one of weight 0 and the logits stay whole.
+        last = ((0, 0), (0, 1))
+        return logits, jnp.pad(targets, last), jnp.pad(w, last), aux
 
     def loss(outputs, batch):
         logits, targets, w, aux = _shifted(outputs, batch)
-        raw = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
-        return (raw * w).sum() / jnp.maximum(w.sum(), 1.0) + aux
+        raw = optax.softmax_cross_entropy_with_integer_labels(
+            _last_position_unlearned(logits), targets)
+        # selected, not multiplied: the last position's term is not the
+        # loss's even where its logits are not finite
+        return (jnp.where(w > 0, raw * w, 0.0).sum()
+                / jnp.maximum(w.sum(), 1.0) + aux)
 
     def metric(outputs, batch):
         logits, targets, w, _aux = _shifted(outputs, batch)

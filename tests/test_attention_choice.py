@@ -13,6 +13,7 @@ of their sums only.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -292,6 +293,45 @@ def test_unequal_attention_compiles_for_a_v5e_at_phi4_flashs_widths(
     assert flash._splash_kernel(shape) is flash._splash_kernel(shape)
     assert flash._splash_kernel(shape) is not flash._splash_kernel(
         shape._replace(window=512 - window))
+
+
+@pytest.mark.parametrize("rows,seq,hidden,vocab,tied", [
+    (1, 8192, 2560, 25008, True),  # c4-phi4flash-vp8-prepacked-8k's head
+    (2, 4096, 2048, 50304, False),  # c4-olmoe-prepacked-4k's
+])
+def test_the_causal_loss_never_re_lays_the_logits_for_a_v5e(
+        one_chip, rows, seq, hidden, vocab, tied):
+    """The head's product, the causal task's own loss and their gradients at
+    two cells' shapes: the logits are read on the grid the head wrote. A
+    shift applied to them made ``[.., seq - 1, vocab]``, which XLA re-laid in
+    two ``while`` loops of ``dynamic-update-slice`` where the vocabulary is no
+    multiple of 128 (Phi-4's eighth, 25,008) and in a sliced copy where it is
+    (OLMoE). The cotangent is still written once (``_last_position_unlearned``
+    tells why)."""
+    task = get_task("causal_lm", model_name="gpt_small", seq_len=16,
+                    vocab_size=64)  # the loss knows no model
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, table, batch):
+        logits = jnp.einsum("bsh,vh->bsv" if tied else "bsh,hv->bsv", x,
+                            table.astype(x.dtype),
+                            preferred_element_type=jnp.float32)
+        return task.loss((logits, jnp.zeros((), jnp.float32)), batch)
+
+    ids = spec((rows, seq), jnp.int32)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        spec((rows, seq, hidden), jnp.bfloat16),
+        spec((vocab, hidden) if tied else (hidden, vocab), jnp.float32),
+        {"input_ids": ids, "attention_mask": spec((rows, seq), jnp.int8),
+         "segment_ids": ids}).compile().as_text()
+    assert " while(" not in text
+    # the soft-max's exponentials are taken for its sum and for the one
+    # cotangent, not once more inside each of the head's backward products
+    assert text.count(" exponential(") == 2
+    assert not re.search(rf"\[[0-9,]*\b({seq - 1},{vocab}|{vocab},{seq - 1})\b",
+                         text)
 
 
 # -- the tiling a call's shapes get -------------------------------------------
