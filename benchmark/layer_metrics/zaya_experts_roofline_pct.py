@@ -1,0 +1,12 @@
+"""Share of the chip's roofline that the held experts' grouped products reach
+with one expert a token: 8 groups of 2,048-wide experts over a list of every
+token, of which the half routed to a held expert is live. Read as
+``moe_local_experts_roofline_pct`` reads Moonlight's (that file says how): the
+least time the chip could take for them, from ``expert_flops`` and
+``expert_bytes`` of ``benchmark/flops/<config>.py`` at the rows the program
+counted in groups (the window's ``moe_local_assignments_total`` over its
+steps), over the device time of ``moe.experts`` and the kernel XLA makes of
+``ragged_dot``; the list's dead rows and the backward pass's recomputation of
+the three forward products count against the share."""
+
+from layer_metrics.moe_local_experts_roofline_pct import read  # noqa: F401

@@ -170,8 +170,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "decoder, 64 dropless SwiGLU experts, 8 a token), "
                         "moonlight_16b_a3b (Moonlight-16B-A3B: latent "
                         "attention, a leading dense layer, 64 experts with "
-                        "6 a token by sigmoid scores beside a shared one) "
-                        "and olmoe_tiny, moonlight_tiny")
+                        "6 a token by sigmoid scores beside a shared one), "
+                        "phi4_mini_flash (Phi-4-mini-flash-reasoning: "
+                        "SambaY's Mamba, differential attention and gated "
+                        "memory layers), zaya1_8b (ZAYA1-8B: attention in a "
+                        "compressed latent with causal convolutions, 8 query "
+                        "heads over 2 key/value heads, an MLP router with a "
+                        "state carried from layer to layer, 1 of 16 experts "
+                        "a token) and olmoe_tiny, moonlight_tiny, "
+                        "phi4_mini_flash_tiny, zaya_tiny")
     p.add_argument("--num_layers", type=int, default=0,
                    help=">0: this many layers of a masked_lm/causal_lm "
                         "transformer preset in place of its own depth, at "
@@ -180,12 +187,14 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--expert_share", type=str, default=None,
                    metavar="RANK/RANKS",
                    help="the experts of each dropless expert layer (olmoe_*, "
-                        "moonlight_*) that this process holds as rank RANK "
-                        "of RANKS that share the layer: E/RANKS of them from "
-                        "RANK*E/RANKS on. The router stays whole; what absent "
-                        "experts would add is left out. With --vocab_size as "
-                        "the vocabulary's slice and --num_layers, one chip's "
-                        "share of an expert-parallel job. Default: all")
+                        "moonlight_*, zaya*) that this process holds as rank "
+                        "RANK of RANKS that share the layer: E/RANKS of them "
+                        "from RANK*E/RANKS on (moonlight_16b_a3b 0/8: experts "
+                        "0-7 of 64; zaya1_8b 0/2: experts 0-7 of 16). The "
+                        "router stays whole; what absent experts would add "
+                        "is left out. With --vocab_size as the vocabulary's "
+                        "slice and --num_layers, one chip's share of an "
+                        "expert-parallel job. Default: all")
     p.add_argument("--layer_span", type=str, default=None,
                    metavar="FIRST:END",
                    help="the published layers [FIRST, END) that this process "

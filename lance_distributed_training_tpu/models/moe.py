@@ -12,9 +12,9 @@ Mixture-of-Experts layers.
   dispatch tensors grow with ``T * E * C``: right for a few experts over a
   mesh, impossible at a published sparse model's shape (8,192 tokens, 64
   experts, 8 a token: terabytes).
-* :class:`DroplessMoE` — the **published-shape** form (the OLMoE and
-  Moonlight presets): top-k routing with no capacity and no dropped token;
-  the ``T * k`` assignments are sorted by expert, the tokens gathered, the
+* :class:`DroplessMoE` — the **published-shape** form (the OLMoE,
+  Moonlight and ZAYA1 presets): top-k routing with no capacity and no dropped
+  token; the ``T * k`` assignments are sorted by expert, the tokens gathered, the
   three SwiGLU products run as grouped matrix multiplications over the
   ragged groups (``jax.lax.ragged_dot``), and each token's k results
   gathered back through the inverse permutation and summed with their
@@ -23,7 +23,10 @@ Mixture-of-Experts layers.
   it holds (one rank's share of an expert-parallel layer: the router stays
   whole, the layer computes what its own experts give and leaves out what
   the absent ones would add; the exchange that brings a deployment's rank
-  the other ranks' tokens is not here: ROADMAP D12).
+  the other ranks' tokens is not here: ROADMAP D12). Its router is one
+  product of its own, or the logits the caller hands it:
+* :class:`StateRouter` — ZAYA1's, an MLP over a narrow state that is mixed
+  with the previous layer's and handed on to the next.
 """
 
 from __future__ import annotations
@@ -34,7 +37,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-__all__ = ["SwiGLU", "MoEMLP", "DroplessMoE"]
+__all__ = ["SwiGLU", "MoEMLP", "DroplessMoE", "StateRouter"]
 
 
 class SwiGLU(nn.Module):
@@ -177,8 +180,8 @@ def _sum_slots(rows, way, weights=None):
             row = row * scale[j][:, None]
         return y + jnp.where(held[j][:, None], row, 0)
 
-    return jax.lax.fori_loop(
-        0, k, slot, jnp.zeros((t, rows.shape[1]), jnp.float32))
+    zero = jnp.zeros((t, rows.shape[1]), jnp.float32)
+    return slot(0, zero) if k == 1 else jax.lax.fori_loop(0, k, slot, zero)
 
 
 @jax.custom_vjp
@@ -231,20 +234,86 @@ _sum_back.defvjp(
     _sum_back_bwd)
 
 
+def _f32_dense(features: int, kernel_init, name: str):
+    """A bias-free product of a router, f32 throughout: on a TPU an f32
+    product at default precision is one bf16 pass, and the eighth and ninth
+    expert of a token are often closer than that."""
+    return nn.Dense(features, use_bias=False, dtype=jnp.float32,
+                    param_dtype=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST,
+                    kernel_init=kernel_init, name=name)
+
+
+class _RouterMLP(nn.Module):
+    """:class:`StateRouter`'s norm and three layers, f32: ``r -> logits``."""
+
+    num_experts: int
+    width: int
+    norm_eps: float
+    kernel_init: Callable
+
+    @nn.compact
+    def __call__(self, r):
+        from .transformer import RMSNorm
+
+        y = RMSNorm(self.norm_eps, jnp.float32, name="norm")(r)
+        for name in ("mlp_1", "mlp_2"):
+            y = nn.gelu(_f32_dense(self.width, self.kernel_init, name)(y),
+                        approximate=False)
+        return _f32_dense(self.num_experts, self.kernel_init, "mlp_3")(y)
+
+
+class StateRouter(nn.Module):
+    """ZAYA's router (arXiv:2511.17127): the normed tokens go down to
+    ``width`` dimensions, the state the previous layer's router left is
+    mixed in channel by channel, and a three-layer MLP over the normed state
+    gives the ``num_experts`` logits:
+
+        r_l = u W_r + g_l * r_{l-1}
+        logits = W_3 gelu(W_2 gelu(W_1 rms(r_l)))
+
+    ``(u [B, S, H], r_{l-1} [B, S, width] or None: zeros) -> (logits [B, S,
+    E], r_l)``, both f32; no biases. :class:`DroplessMoE` takes the logits in
+    place of its own product's; the caller hands ``r_l`` to the next layer.
+    The backward pass keeps ``r_l`` and makes the MLP's six f32 arrays of
+    ``[T, width]`` again (0.23 GiB of a six-layer stack at 8,192 tokens)."""
+
+    num_experts: int
+    width: int
+    norm_eps: float = 1e-5
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, u, carried=None):
+        r = _f32_dense(self.width, self.kernel_init, "down")(
+            u.astype(jnp.float32))
+        mix = self.param("depth_mix", nn.initializers.ones_init(),
+                         (self.width,), jnp.float32)
+        if carried is not None:  # the first layer held: r_{-1} = 0
+            r = r + mix * carried
+        return nn.remat(_RouterMLP)(self.num_experts, self.width,
+                                    self.norm_eps, self.kernel_init,
+                                    name="mlp")(r), r
+
+
 class DroplessMoE(nn.Module):
     """Top-k routed SwiGLU experts without capacity: ``[B, S, H] -> [B, S, H]``,
     ``Σ_k w_k · down_k(silu(gate_k(x)) · up_k(x))`` over each token's
     ``experts_per_token`` chosen experts, no biases, plus ``shared(x)``
     where ``shared_dim`` > 0 (one :class:`SwiGLU` every token passes).
 
-    Two published routers. ``scoring`` ``"softmax"`` (OLMoE): the k largest
-    router probabilities, weighted by the softmax values themselves.
-    ``"sigmoid"`` (the DeepSeek-V3 family, Moonlight): ``s = sigmoid(logits)``,
-    the k largest of ``s + b`` are chosen, where ``b`` is a selection bias
-    that takes no gradient (``router_state``/``bias``; it moves against the
-    load by ``bias_update_rate`` after each training step, over the tokens
-    this layer saw), and the weights are the chosen ``s``, without ``b``,
-    divided by their sum under ``norm_topk`` and times ``routed_scale``.
+    The logits are the layer's own (one bias-free f32 product, ``router``)
+    or, where the call is given ``logits`` [B, S, E], the caller's (ZAYA1's
+    :class:`StateRouter`, whose state rides between layers). Two scorings.
+    ``"softmax"`` (OLMoE, ZAYA1): the k largest router probabilities, weighted
+    by the softmax values themselves. ``"sigmoid"`` (the DeepSeek-V3 family,
+    Moonlight): ``s = sigmoid(logits)``, the weights the chosen ``s`` divided
+    by their sum under ``norm_topk`` and times ``routed_scale``. Under either,
+    ``bias_update_rate`` > 0 (Moonlight, ZAYA1) chooses the k largest of ``s +
+    b`` where ``b`` is a selection bias that takes no gradient
+    (``router_state``/``bias``; it moves against the load by that rate after
+    each training step, over the tokens this layer saw); the weights never
+    see ``b``.
 
     ``held_experts`` > 0 tells the layer which experts it holds: that many
     from ``first_expert`` on, one rank's share of an expert-parallel layer.
@@ -261,7 +330,9 @@ class DroplessMoE(nn.Module):
     it builds all ``T * k`` instead, the bound no routing exceeds (a
     ``lax.cond`` on the count: exact either way, no token is ever dropped;
     ``moe_stats``/``over_usual`` says which, ``row_fill`` how much of the
-    built list was live). Between the ``[T, H]`` tokens and the ``[R, H]``
+    built list was live; where twice an even share is already ``T * k``, one
+    expert a token with half of them held, there is the one list and no
+    ``cond``). Between the ``[T, H]`` tokens and the ``[R, H]``
     built rows lie two operations that are each other's transposes: tokens
     -> rows (a live row is its token, a dead one zeros) and rows -> tokens
     (a token is the sum of its live rows, in f32, slot after slot). The sum
@@ -278,9 +349,10 @@ class DroplessMoE(nn.Module):
     over their sum, per row and averaged; all over live tokens, unweighted)
     and into ``moe_stats`` the experts' assignment counts (``group_sizes``,
     all ``num_experts``), under a share those of the held experts
-    (``held_sizes``, ``over_usual``, ``row_fill``), and with a bias its
-    largest magnitude (``bias_abs_max``); ``live`` [B, S] marks the tokens
-    that count (None: all).
+    (``held_sizes``, ``over_usual``, ``row_fill``), with a bias its
+    largest magnitude (``bias_abs_max``), and with one expert a token the mean
+    weight it got (``top1_prob``); ``live`` [B, S] marks the tokens that count
+    (None: all).
     """
 
     num_experts: int
@@ -297,7 +369,7 @@ class DroplessMoE(nn.Module):
     held_experts: int = 0  # 0: all of them
 
     @nn.compact
-    def __call__(self, x, live=None):
+    def __call__(self, x, live=None, logits=None):
         b, s, h = x.shape
         t, e, k = b * s, self.num_experts, self.experts_per_token
         first, held = self.first_expert, self.held_experts or e
@@ -312,26 +384,23 @@ class DroplessMoE(nn.Module):
              else live.reshape(t).astype(jnp.float32))
 
         with jax.named_scope("moe.router"):
-            # f32 throughout: on a TPU an f32 product at default precision
-            # is one bf16 pass, and the eighth and ninth expert of a token
-            # are often closer than that.
-            logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
-                              param_dtype=jnp.float32,
-                              precision=jax.lax.Precision.HIGHEST,
-                              kernel_init=self.kernel_init, name="router")(
-                                  tokens.astype(jnp.float32))
-            if self.scoring == "softmax":
-                probs = nn.softmax(logits, axis=-1)  # [T, E]
-                top_p, top_e = jax.lax.top_k(probs, k)  # [T, k]
+            if logits is None:  # the layer's own router: one product
+                logits = _f32_dense(e, self.kernel_init, "router")(
+                    tokens.astype(jnp.float32))
             else:
-                probs = nn.sigmoid(logits)
-                chosen_by = probs
-                if self.bias_update_rate > 0:
-                    bias = self.variable("router_state", "bias", jnp.zeros,
-                                         (e,), jnp.float32)
-                    chosen_by = probs + bias.value
-                top_e = jax.lax.top_k(chosen_by, k)[1]
+                logits = logits.reshape(t, e)
+            softmax = self.scoring == "softmax"
+            probs = (nn.softmax(logits, axis=-1) if softmax
+                     else nn.sigmoid(logits))  # [T, E]
+            if self.bias_update_rate > 0:
+                # chosen by s + b, weighted by s alone
+                bias = self.variable("router_state", "bias", jnp.zeros,
+                                     (e,), jnp.float32)
+                top_e = jax.lax.top_k(probs + bias.value, k)[1]
                 top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+            else:
+                top_p, top_e = jax.lax.top_k(probs, k)  # [T, k]
+            if not softmax:
                 if self.norm_topk:
                     top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
                 top_p = top_p * self.routed_scale
@@ -410,13 +479,20 @@ class DroplessMoE(nn.Module):
             over = (ends[-1] > usual).astype(jnp.float32)
             fill = 100.0 * ends[-1] / jnp.where(over > 0, t * k, usual)
 
-            def is_over(inputs):  # from the arguments' own ``ends``
-                return inputs[8][-1] > usual
+            def either(make, inputs, *args):
+                """``make(rows)(*args)`` with the worst-case list if the
+                step's routing is over the usual one (by the arguments' own
+                ``ends``), else with the usual; where twice an even share is
+                already every assignment (one expert a token, half of them
+                held) there is one list and no ``cond``."""
+                if usual == t * k:
+                    return make(usual)(*args)
+                return jax.lax.cond(inputs[8][-1] > usual, make(t * k),
+                                    make(usual), *args)
 
             @jax.custom_vjp
             def routed(*inputs):
-                return jax.lax.cond(is_over(inputs), with_rows(t * k),
-                                    with_rows(usual), *inputs)
+                return either(with_rows, inputs, *inputs)
 
             def routed_bwd(inputs, g):
                 def back(rows):
@@ -425,9 +501,7 @@ class DroplessMoE(nn.Module):
                         return jax.vjp(lambda *d: with_rows(rows)(
                             *d, *ints), *diff)[1](g)
                     return run
-                grads = jax.lax.cond(is_over(inputs), back(t * k),
-                                     back(usual), inputs, g)
-                return (*grads, *(None,) * 5)
+                return (*either(back, inputs, inputs, g), *(None,) * 5)
 
             routed.defvjp(lambda *inputs: (routed(*inputs), inputs),
                           routed_bwd)
@@ -465,7 +539,7 @@ class DroplessMoE(nn.Module):
                      ).reshape(b, s, e).sum(1) / rows_n
             self.sow("aux_loss", "seq_balance",
                      e * jnp.mean(jnp.sum(frac * share, axis=-1)))
-        if self.scoring != "softmax" and self.bias_update_rate > 0:
+        if self.bias_update_rate > 0:
             self.sow("moe_stats", "bias_abs_max", jnp.abs(bias.value).max())
             if (self.is_mutable_collection("router_state")
                     and not self.is_initializing()):
@@ -473,6 +547,8 @@ class DroplessMoE(nn.Module):
                 bias.value = bias.value + self.bias_update_rate * jnp.sign(
                     live_load.mean() - live_load)
         self.sow("moe_stats", "group_sizes", load)
+        if k == 1:  # the one expert's weight is the whole layer's scale
+            self.sow("moe_stats", "top1_prob", (top_p[:, 0] * w).sum() / n)
         if self.held_experts:
             self.sow("moe_stats", "held_sizes", group_sizes)
             self.sow("moe_stats", "over_usual", over)

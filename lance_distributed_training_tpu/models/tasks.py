@@ -39,6 +39,8 @@ from .transformer import (
     phi4_mini_flash_tiny,
     olmoe_1b_7b,
     olmoe_tiny,
+    zaya1_8b,
+    zaya_tiny,
 )
 
 __all__ = ["Task", "get_task", "TASK_REGISTRY"]
@@ -330,7 +332,8 @@ def _masked_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
 # --num_experts (MoEMLP); every OLMoE layer is one (DroplessMoE), with the
 # two weights of the paper (arXiv:2409.02060, section 4.1); Moonlight's
 # (all but its first) sow the sequence-wise balance term, with DeepSeek-V3's
-# weight (arXiv:2412.19437, section 4.2: alpha 0.0001).
+# weight (arXiv:2412.19437, section 4.2: alpha 0.0001). ZAYA1's are balanced
+# by the selection bias alone: what they sow has no weight here.
 _SWITCH_AUX = {"load_balance": 0.01}
 _OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
 _MOONLIGHT_AUX = {"seq_balance": 0.0001}
@@ -343,17 +346,20 @@ _CAUSAL_LMS: dict = {
     "moonlight_tiny": (moonlight_tiny, 512, _MOONLIGHT_AUX),
     "phi4_mini_flash": (phi4_mini_flash, 200064, {}),
     "phi4_mini_flash_tiny": (phi4_mini_flash_tiny, 512, {}),
+    "zaya1_8b": (zaya1_8b, 262272, {}),
+    "zaya_tiny": (zaya_tiny, 512, {}),
 }
 
 
 def _weighted_aux(sown: dict, weights: dict):
     """Σ over the terms' names of weight × (that term summed over the
-    layers that sowed it)."""
+    layers that sowed it); a term without a weight is left out."""
     sums: dict = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
-        name = next(k.key for k in path
-                    if getattr(k, "key", None) in weights)
-        sums[name] = sums.get(name, 0.0) + leaf
+        name = next((k.key for k in path
+                     if getattr(k, "key", None) in weights), None)
+        if name is not None:
+            sums[name] = sums.get(name, 0.0) + leaf
     return sum((weights[n] * v for n, v in sums.items()),
                jnp.zeros((), jnp.float32))
 
@@ -375,7 +381,8 @@ def _expert_load(sown: dict) -> dict:
     (``held_sizes``: ``moe_local_*``) and how many layers built the
     worst-case list (``over_usual``) and how full the built lists were
     (``row_fill``: live rows over built rows, in percent, mean over the
-    layers); with a selection bias its largest magnitude over the layers."""
+    layers); with a selection bias its largest magnitude over the layers;
+    with one expert a token the mean weight that expert got."""
     by_name = _sown_by_name(sown)
 
     def load(name, total, prefix):
@@ -394,22 +401,35 @@ def _expert_load(sown: dict) -> dict:
     if "bias_abs_max" in by_name:
         out["moe_router_bias_abs_max"] = jnp.stack(
             by_name["bias_abs_max"]).max()
+    if "top1_prob" in by_name:
+        out["router_top1_prob_mean"] = jnp.stack(by_name["top1_prob"]).mean()
     return out
 
 
-def _mixer_stats(sown: dict, scan_fused: bool) -> dict:
-    """A SambaY stack's step scalars from what its mixers sow into
-    ``mixer_stats``: the largest magnitude in a state-space layer's state at
-    a row's end, the least and the largest differential lambda over the
-    attention layers, and whether the scan runs its kernel."""
+def _mixer_stats(sown: dict, scan_fused: Optional[bool] = None) -> dict:
+    """A stack's step scalars from what its mixers and layers sow into
+    ``mixer_stats``. SambaY's: the largest magnitude in a state-space
+    layer's state at a row's end, the least and the largest differential
+    lambda over the attention layers, and whether the scan runs its kernel
+    (``scan_fused``). ZAYA's: the largest key temperature, and the least and
+    the largest of the scales on the residual sums' two sides."""
     by_name = _sown_by_name(sown)
-    out = {"ssm_scan_fused": jnp.float32(scan_fused)}
+    out = {}
+    if scan_fused is not None:
+        out["ssm_scan_fused"] = jnp.float32(scan_fused)
     if "ssm_state_abs_max" in by_name:
         out["ssm_state_abs_max"] = jnp.stack(
             by_name["ssm_state_abs_max"]).max()
     if "diff_lambda" in by_name:
         lam = jnp.stack(by_name["diff_lambda"])
         out.update(diff_lambda_min=lam.min(), diff_lambda_max=lam.max())
+    if "cca_key_temperature" in by_name:
+        out["cca_key_temperature_max"] = jnp.stack(
+            by_name["cca_key_temperature"]).max()
+    if "residual_scale" in by_name:
+        scale = jnp.stack(by_name["residual_scale"])
+        out.update(residual_scale_min=scale.min(),
+                   residual_scale_max=scale.max())
     return out
 
 
@@ -477,8 +497,8 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                     expert_share: Optional[str] = None,
                     layer_span: Optional[str] = None) -> Task:
     """Decoder-only next-token prediction (the GPT presets on the encoder
-    trunk, the OLMoE, Moonlight and Phi-4-mini-flash presets on the decoder
-    stack) over the same packed
+    trunk, the OLMoE, Moonlight, Phi-4-mini-flash and ZAYA1 presets on the
+    decoder stack) over the same packed
     token columns as masked-LM (``create_text_token_dataset``) — the text arm
     beyond the reference's vision-only scope, sharing the trainer, samplers
     and storage unchanged. The shift by one token is applied to the targets
@@ -490,8 +510,10 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                          f"(have {sorted(_CAUSAL_LMS)})")
     ctor, own_vocab, aux_weights = _CAUSAL_LMS[model_name]
     decoder = ctor.func is TransformerDecoder  # no table to size by seq_len
-    hybrid = bool(ctor.keywords.get("layer_kinds"))  # mixers differ by layer
-    dropless = decoder and not hybrid  # every layer but the dense has experts
+    kinds = bool(ctor.keywords.get("layer_kinds"))  # mixers that sow stats
+    sambay = bool(ctor.keywords.get("hybrid"))
+    # every layer but the leading dense ones has dropless experts
+    dropless = decoder and ctor.keywords["num_experts"] > 0
     if num_layers and layer_span is not None:
         raise ValueError("num_layers and layer_span both state the depth")
     kwargs = dict(vocab_size=vocab_size or own_vocab,
@@ -500,7 +522,7 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
     if expert_share is not None and not dropless:
         raise ValueError("expert_share states which of a dropless "
                          "preset's experts are held (olmoe_*, "
-                         "moonlight_*)")
+                         "moonlight_*, zaya*)")
     if decoder:
         if num_experts:
             raise ValueError(
@@ -514,26 +536,24 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
         kwargs.update(max_len=seq_len, num_experts=num_experts,
                       moe_every=moe_every)
     model = ctor(**kwargs)
-    scan_fused = False
-    if hybrid:
+    scan_fused = None
+    if sambay:
         from ..ops.scan import scan_fused_applies
 
         scans = model.scan_shape  # a span that cannot run is refused here
         scan_fused = bool(scans) and scan_fused_applies(seq_len, *scans)
-    sows = (["aux_loss", "moe_stats", "router_state"] if dropless
-            else ["mixer_stats"] if hybrid
-            else ["aux_loss"] if num_experts > 0 else [])
+    sows = ((["aux_loss", "moe_stats", "router_state"] if dropless
+             else ["aux_loss"] if num_experts > 0 else [])
+            + ["mixer_stats"] * kinds)
 
     def init_variables(rng):
         ids = jnp.zeros((1, seq_len), jnp.int32)
         variables = model.init(rng, ids, jnp.ones((1, seq_len), jnp.int8),
                                train=False)
-        if hybrid:  # what the mixers sow at init is not state
-            return {"params": variables["params"]}
-        if not dropless:
+        if not decoder:
             return variables
-        # what the expert layers sow at init is not state; the routers'
-        # selection bias is, and rides where a train state keeps a model's
+        # what the layers sow at init is not state; the routers' selection
+        # bias is, and rides where a train state keeps a model's
         # non-trainable collection
         return {"params": variables["params"],
                 **({"batch_stats": variables["router_state"]}
@@ -555,15 +575,17 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                 segment_ids=seg, position_ids=pos,
             )
             aux = _weighted_aux(sown.get("aux_loss", {}), aux_weights)
+            if not decoder:
+                return (logits, aux), None
+            stats = {}
             if dropless:
-                # the bias as the routers left it: the step's new state
-                state = ({"batch_stats": sown["router_state"]}
-                         if "router_state" in sown else None)
-                return (logits, aux, _expert_load(sown["moe_stats"])), state
-            if hybrid:
-                return (logits, aux, _mixer_stats(sown["mixer_stats"],
-                                                  scan_fused)), None
-            return (logits, aux), None
+                stats.update(_expert_load(sown["moe_stats"]))
+            if kinds:
+                stats.update(_mixer_stats(sown["mixer_stats"], scan_fused))
+            # the bias as the routers left it: the step's new state
+            state = ({"batch_stats": sown["router_state"]}
+                     if "router_state" in sown else None)
+            return (logits, aux, stats), state
         logits = model.apply(variables, ids, mask, train=train,
                              segment_ids=seg, position_ids=pos)
         return (logits, jnp.zeros((), jnp.float32)), None
@@ -790,8 +812,9 @@ def get_task(
 ) -> Task:
     """``vocab_size=None`` means "the model's own default" (bert_*: 30522,
     gpt_*: 50257, olmoe_1b_7b: 50304, moonlight_16b_a3b: 163840,
-    phi4_mini_flash: 200064, olmoe_tiny, moonlight_tiny and
-    phi4_mini_flash_tiny: 512, clip_tiny: 1000, clip_resnet50_bert: 30522);
+    phi4_mini_flash: 200064, zaya1_8b: 262272, olmoe_tiny, moonlight_tiny,
+    phi4_mini_flash_tiny and zaya_tiny: 512, clip_tiny: 1000,
+    clip_resnet50_bert: 30522);
     explicit values always apply verbatim.
     ``param_dtype`` overrides the parameter/optimizer-state dtype (ResNet
     family only; e.g. ``jnp.bfloat16`` halves weight HBM). ``num_layers``

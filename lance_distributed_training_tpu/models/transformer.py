@@ -26,7 +26,8 @@ __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "bert_small", "gpt_base", "gpt_small", "olmoe_1b_7b",
            "olmoe_tiny", "moonlight_16b_a3b", "moonlight_tiny",
            "phi4_mini_flash", "phi4_mini_flash_tiny", "sambay_layers",
-           "dot_product_attention", "RMSNorm", "rotary_embedding"]
+           "zaya1_8b", "zaya_tiny", "dot_product_attention", "RMSNorm",
+           "rotary_embedding"]
 
 
 def dot_product_attention(q, k, v, mask=None, dtype=jnp.bfloat16,
@@ -100,11 +101,17 @@ class RMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
 
 
-def rotary_embedding(x, positions, theta: float):
-    """Rotary position embedding over the whole head dimension, in f32:
-    ``x`` [B, S, H, D], ``positions`` [B, S] or [S]. Half-rotation pairing
-    (element ``i`` turns with element ``i + D/2``, as the published decoder
-    implementations pair them), angle ``position * theta^(-2i/D)``."""
+def rotary_embedding(x, positions, theta: float, width: int = 0):
+    """Rotary position embedding, in f32: ``x`` [B, S, H, D], ``positions``
+    [B, S] or [S]. Over the whole head dimension, or with ``width`` > 0 over
+    its first ``width`` elements, the others left as they are (a partial
+    rotary factor: ``width = factor * D``). Half-rotation pairing (element
+    ``i`` turns with element ``i + width/2``, as the published decoder
+    implementations pair them), angle ``position * theta^(-2i/width)``."""
+    if 0 < width < x.shape[-1]:
+        return jnp.concatenate([
+            rotary_embedding(x[..., :width], positions, theta),
+            x[..., width:]], axis=-1)
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = positions.astype(jnp.float32)[..., None] * freq  # [(B,) S, D/2]
@@ -298,6 +305,121 @@ class DifferentialAttention(nn.Module):
                 self.norm_eps, jnp.float32, name="sub_norm")(out)
             out = out.astype(self.dtype).reshape(b, s, h)
         return dense(features=h, axis=-1, name="out")(out), (k, v)
+
+
+class ConvolutionalAttention(nn.Module):
+    """Compressed convolutional attention (CCA, arXiv:2510.04476) as ZAYA1
+    runs it: queries, keys and values live in a latent narrower than the
+    residual stream (``num_heads`` query heads over ``kv_heads`` key and
+    value heads, each ``head_dim`` wide), and are mixed along the sequence
+    before the scores:
+
+        q~ = u W_q,  k~ = u W_k,  v = [u_t W_v0 ; u_{t-1} W_v1]
+        conv(z) = C1(C0(z)):  C0(z)_t = a0 * z_{t-1} + a1 * z_t   a channel
+                              C1(z)_t = z_{t-1} A0_h + z_t A1_h   a head
+        q = conv(q~) + (q~ + rep(k~)) / 2
+        k = conv(k~) + (k~ + groupmean(q~)) / 2
+        q <- sqrt(d) q / |q|,   k <- tau_g sqrt(d) k / |k|
+
+    then rotary positions on the first ``rotary_dim`` elements of each head,
+    causal softmax attention over ``sqrt(d)`` with query head ``h`` on key
+    and value head ``h // (num_heads / kv_heads)``, and ``W_o`` from the
+    latent back to the stream. The value shift: the first half of the value
+    heads come from this token, the second half from the one before
+    (``u_{-1} = 0``). ``tau_g`` is a learned temperature a key head, from 1.
+    No biases. What lies between the projections and the kernel (``cca.mix``)
+    is recomputed in the backward pass. Nothing here knows where a document
+    ends inside a row: the convolutions and the value shift run across it
+    (ROADMAP R4)."""
+
+    num_heads: int
+    kv_heads: int
+    head_dim: int
+    rotary_dim: int
+    rope_theta: float = 10000.0
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
+        b, s, h = x.shape
+        n, g, d = self.num_heads, self.kv_heads, self.head_dim
+        dense = partial(nn.DenseGeneral, dtype=self.dtype,
+                        param_dtype=jnp.float32, use_bias=False,
+                        kernel_init=self.kernel_init)
+        with jax.named_scope("cca.project"):
+            q0 = dense(features=(n, d), name="query")(x)
+            k0 = dense(features=(g, d), name="key")(x)
+            v0 = dense(features=(g, d), name="value")(x)
+
+        def taps(name, shape, fan_in):
+            edge = 1.0 / math.sqrt(fan_in)  # a Conv1d's own
+            return self.param(
+                name, lambda key, shape, dtype: jax.random.uniform(
+                    key, shape, dtype, -edge, edge), shape, jnp.float32)
+
+        # [tap, head, channel] and [tap, head, in, out]; tap 0 is the token
+        # before, tap 1 this token
+        convs = {name: (taps(f"{name}_conv0", (2, heads, d), 2),
+                        taps(f"{name}_conv1", (2, heads, d, d), 2 * d))
+                 for name, heads in (("q", n), ("k", g))}
+        temperature = self.param("key_temperature",
+                                 nn.initializers.ones_init(), (g,),
+                                 jnp.float32)
+        self.sow("mixer_stats", "cca_key_temperature", temperature)
+        pos = jnp.arange(s) if position_ids is None else position_ids
+
+        def before(t):  # t_{-1} = 0
+            return jnp.pad(t, ((0, 0), (1, 0), (0, 0), (0, 0)))[:, :-1]
+
+        def conv(z, depthwise, within):
+            """``C1(C0(z))`` in f32: C0's sum in f32, C1 one product a head
+            over both taps with operands in ``dtype``."""
+            heads = z.shape[2]
+            z = z.astype(jnp.float32)
+            z = (depthwise[0] * before(z) + depthwise[1] * z).astype(
+                self.dtype)
+            return jnp.einsum(
+                "bsnd,nde->bsne", jnp.concatenate([before(z), z], axis=-1),
+                within.transpose(1, 0, 2, 3).reshape(
+                    heads, 2 * d, d).astype(self.dtype)).astype(jnp.float32)
+
+        def unit(t):  # |t| = sqrt(d) over a head
+            return t * jax.lax.rsqrt(
+                jnp.mean(t * t, -1, keepdims=True) + 1e-12)
+
+        @jax.checkpoint  # a dozen f32 passes over the latent: the backward
+        # pass makes them again from the three projections and keeps none
+        def mix(q0, k0, v0, convs, temperature, pos):
+            q32, k32 = q0.astype(jnp.float32), k0.astype(jnp.float32)
+            q = conv(q0, *convs["q"]) + 0.5 * (
+                q32 + jnp.repeat(k32, n // g, axis=2))
+            k = conv(k0, *convs["k"]) + 0.5 * (
+                k32 + q32.reshape(b, s, g, n // g, d).mean(3))
+            q = rotary_embedding(unit(q), pos, self.rope_theta,
+                                 self.rotary_dim).astype(self.dtype)
+            k = rotary_embedding(unit(k) * temperature[:, None], pos,
+                                 self.rope_theta,
+                                 self.rotary_dim).astype(self.dtype)
+            v = jnp.concatenate([v0[:, :, :g // 2],
+                                 before(v0[:, :, g // 2:])], axis=2)
+            # [B, S, H, D] -> [B, H, S, D]
+            return tuple(t.transpose(0, 2, 1, 3) for t in (q, k, v))
+
+        with jax.named_scope("cca.mix"):
+            q, k, v = mix(q0, k0, v0, convs, temperature, pos)
+        attn = self.attention_fn
+        if attn is None:  # dense off a TPU; knows grouped heads
+            from ..ops.flash import make_flash_attention
+
+            attn = make_flash_attention(causal=True, forced=False)
+        kwargs = {} if segment_ids is None else {"segment_ids": segment_ids}
+        with jax.named_scope("cca.kernel"):
+            out = attn(q, k, v, mask=mask, **kwargs)
+        with jax.named_scope("cca.out"):
+            return dense(features=h, axis=(-2, -1), name="out")(
+                out.transpose(0, 2, 1, 3))
 
 
 class MambaMixer(nn.Module):
@@ -514,11 +636,16 @@ class DecoderBlock(nn.Module):
     ``"M*"`` one that hands on its scan output, ``"S"`` window and ``"F*"``
     full :class:`DifferentialAttention`, the latter handing on its keys and
     values, ``"G"`` a :class:`GatedMemoryUnit` over M*'s output, ``"X"``
-    differential attention over F*'s keys and values. ``dense_dim`` is the
-    feed-forward (0: the dropless expert layer that ``moe`` describes; > 0:
-    a dense SwiGLU of that width), ``layer_norm`` the norm (LayerNorm, else
-    RMSNorm). The call takes and returns, beside ``x``, what is handed on:
-    ``(m, (k, v))``, either None until its layer has run."""
+    differential attention over F*'s keys and values; or ``"C"``, ZAYA1's
+    layer (arXiv:2511.17127, which ``cca`` sizes): a
+    :class:`ConvolutionalAttention`, an expert layer whose router is a
+    :class:`..moe.StateRouter` with a state handed from layer to layer, and
+    learned scales and shifts on both sides of both residual sums.
+    ``dense_dim`` is the feed-forward (0: the dropless expert layer that
+    ``moe`` describes; > 0: a dense SwiGLU of that width), ``layer_norm`` the
+    norm (LayerNorm, else RMSNorm). The call takes and returns, beside ``x``,
+    what is handed on: ``(m, (k, v), r)``, each None until its layer has
+    run."""
 
     num_heads: int
     expert_dim: int
@@ -536,14 +663,15 @@ class DecoderBlock(nn.Module):
     depth: int = 0  # the layer's published index
     hybrid: tuple = ()  # (kv_heads, window, inner, states, conv, dt_rank)
     layer_norm: bool = False
+    cca: tuple = ()  # (kv_heads, head_dim, rotary_dim, router_dim)
 
     def _hybrid_mixer(self, y, mask, segment_ids, handed, init):
         """``(mixer's output, what is handed on)`` of a SambaY layer."""
-        memory, keys_values = handed
+        memory, keys_values, state = handed
         kv_heads, window, *ssm = self.hybrid
         if self.kind in ("M", "M*"):
             y, m = MambaMixer(*ssm, self.dtype, init, name="ssm")(y)
-            return y, (m if self.kind == "M*" else memory, keys_values)
+            return y, (m if self.kind == "M*" else memory, keys_values, state)
         if self.kind == "G":
             with jax.named_scope("gmu"):
                 return GatedMemoryUnit(self.dtype, init, name="gmu")(
@@ -555,12 +683,26 @@ class DecoderBlock(nn.Module):
                 self.attention_fn, init, name="attn")(
                 y, mask, segment_ids,
                 keys_values if self.kind == "X" else None)
-        return y, (memory, own if self.kind == "F*" else keys_values)
+        return y, (memory, own if self.kind == "F*" else keys_values, state)
+
+    def _merge(self, x, y, name):
+        """The residual sum ``x + y``; a ZAYA layer's is ``(a_r x + b_r) +
+        (a_o y + b_o)`` in f32, with learned vectors, a from 1 and b from 0."""
+        if self.kind != "C":
+            return x + y
+        ones, zeros = nn.initializers.ones_init(), nn.initializers.zeros_init()
+        a_r, b_r, a_o, b_o = (
+            self.param(f"{name}_{part}", init, x.shape[-1:], jnp.float32)
+            for part, init in (
+                ("stream_scale", ones), ("stream_shift", zeros),
+                ("branch_scale", ones), ("branch_shift", zeros)))
+        self.sow("mixer_stats", "residual_scale", jnp.stack([a_r, a_o]))
+        return ((a_r * x + b_r) + (a_o * y + b_o)).astype(self.dtype)
 
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, position_ids=None,
-                 live=None, handed=(None, None)):
-        from .moe import DroplessMoE, SwiGLU
+                 live=None, handed=(None, None, None)):
+        from .moe import DroplessMoE, StateRouter, SwiGLU
 
         norm = partial(RMSNorm, self.norm_eps, self.dtype)
         if self.layer_norm:
@@ -568,7 +710,13 @@ class DecoderBlock(nn.Module):
                            dtype=self.dtype, param_dtype=jnp.float32)
         init = nn.initializers.truncated_normal(self.init_std)
         y = norm(name="ln_attn")(x)
-        if self.kind:
+        if self.kind == "C":
+            with jax.named_scope("attention"):
+                y = ConvolutionalAttention(
+                    self.num_heads, *self.cca[:3], self.rope_theta,
+                    self.dtype, self.attention_fn, init, name="attn")(
+                    y, mask, segment_ids, position_ids)
+        elif self.kind:
             y, handed = self._hybrid_mixer(y, mask, segment_ids, handed, init)
         else:
             with jax.named_scope("attention"):
@@ -585,17 +733,24 @@ class DecoderBlock(nn.Module):
                         self.rope_theta, self.dtype, self.attention_fn, init,
                         name="attn")
                 y = mixer(y, mask, segment_ids, position_ids)
-        x = x + y
+        x = self._merge(x, y, "attn")
         y = norm(name="ln_mlp")(x)
         if self.dense_dim:
             with jax.named_scope("mlp.dense"):
                 y = SwiGLU(self.dense_dim, self.dtype, init, name="mlp")(y)
         else:
+            logits = None  # the expert layer's own router
+            if self.kind == "C":
+                with jax.named_scope("moe.router"):
+                    logits, state = StateRouter(
+                        self.num_experts, self.cca[3], self.norm_eps, init,
+                        name="router")(y, handed[2])
+                handed = (*handed[:2], state)
             y = DroplessMoE(self.num_experts, self.expert_dim,
                             self.experts_per_token, self.dtype,
                             kernel_init=init, name="moe",
-                            **dict(self.moe))(y, live)
-        return x + y, handed
+                            **dict(self.moe))(y, live, logits)
+        return self._merge(x, y, "mlp"), handed
 
 
 class TransformerDecoder(nn.Module):
@@ -604,11 +759,14 @@ class TransformerDecoder(nn.Module):
     :class:`DecoderBlock`; OLMoE's are all alike (RMSNorm, rotary attention,
     an expert layer, an untied ``lm_head``), Moonlight's take latent
     attention, and a dense SwiGLU in the first ``dense_layers`` of them.
-    A stack with ``layer_kinds`` is SambaY's (Phi-4-mini-flash): the mixer
-    differs by layer, LayerNorm, no position term at all, the head tied to
-    the embedding; ``first_layer`` says which published layers are held
-    (``num_layers`` of them from there: one pipeline stage's), and the two
-    tensors that M* and F* hand on ride from layer to layer beside ``x``.
+    A stack with ``layer_kinds`` names every published layer's kind:
+    SambaY's (Phi-4-mini-flash) differ by layer, under LayerNorm
+    (``layer_norm``), with no position term at all; ZAYA1's are all ``"C"``,
+    under RMSNorm. Both tie the head to the embedding (``tied_head``).
+    ``first_layer`` says which published layers are held (``num_layers`` of
+    them from there: one pipeline stage's), and what a layer hands on (M*'s
+    and F*'s tensors, a router's state) rides from layer to layer beside
+    ``x``.
     Same call signature as :class:`TransformerEncoder`, so the ``causal_lm``
     task drives either; logits ``[B, S, vocab]`` in f32.
     """
@@ -632,7 +790,10 @@ class TransformerDecoder(nn.Module):
     moe: tuple = ()  # DecoderBlock's, for every expert layer
     layer_kinds: tuple = ()  # every published layer's DecoderBlock.kind
     first_layer: int = 0  # published index of the first layer held here
-    hybrid: tuple = ()  # DecoderBlock's, for every layer with a kind
+    hybrid: tuple = ()  # DecoderBlock's, for every SambaY layer
+    cca: tuple = ()  # DecoderBlock's, for every "C" layer
+    layer_norm: bool = False  # LayerNorm in place of RMSNorm, everywhere
+    tied_head: bool = False  # the head is the embedding, no matrix of its own
 
     @property
     def held_kinds(self) -> tuple:
@@ -661,6 +822,8 @@ class TransformerDecoder(nn.Module):
         """``(head_dim, value_dim)`` of every kind of attention the held
         layers run: what the attention function chooses its path by, layer
         by layer. Empty for a span without attention."""
+        if self.cca:
+            return ((self.cca[1],) * 2,)
         if self.layer_kinds:
             d = self.hidden_size // self.num_heads
             return ((d, 2 * d),) if set(self.held_kinds) & {
@@ -699,8 +862,7 @@ class TransformerDecoder(nn.Module):
         block = DecoderBlock
         if self.remat:
             block = nn.remat(DecoderBlock, static_argnums=())
-        hybrid = bool(self.layer_kinds)
-        handed = (None, None)
+        handed = (None, None, None)
         for i, kind in enumerate(self.held_kinds):
             x, handed = block(
                 self.num_heads, self.expert_dim, self.num_experts,
@@ -709,21 +871,20 @@ class TransformerDecoder(nn.Module):
                 attention_fn=self.attention_fn, latent=self.latent,
                 dense_dim=self.dense_dim if i < self.dense_layers else 0,
                 moe=self.moe, kind=kind, depth=self.first_layer + i,
-                hybrid=self.hybrid, layer_norm=hybrid,
-                name=f"layer_{i}")(x, mask, seg_kwarg, position_ids, live,
-                                   handed)
-        if hybrid:
+                hybrid=self.hybrid, layer_norm=self.layer_norm,
+                cca=self.cca, name=f"layer_{i}")(
+                    x, mask, seg_kwarg, position_ids, live, handed)
+        if self.layer_norm:
             x = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
                              param_dtype=jnp.float32, name="ln_final")(x)
-            with jax.named_scope("lm_head"):
-                # tied: the held rows of the embedding, bf16 operands on
-                # the matrix unit, f32 sums and f32 logits
+        else:
+            x = RMSNorm(self.norm_eps, self.dtype, name="ln_final")(x)
+        with jax.named_scope("lm_head"):
+            # bf16 operands on the matrix unit, f32 sums and f32 logits
+            if self.tied_head:  # the held rows of the embedding
                 return jnp.einsum(
                     "bsh,vh->bsv", x, embed.embedding.astype(self.dtype),
                     preferred_element_type=jnp.float32)
-        x = RMSNorm(self.norm_eps, self.dtype, name="ln_final")(x)
-        with jax.named_scope("lm_head"):
-            # bf16 operands on the matrix unit, f32 sums and f32 logits.
             return nn.Dense(
                 self.vocab_size, use_bias=False, dtype=self.dtype,
                 param_dtype=jnp.float32, kernel_init=init,
@@ -797,9 +958,29 @@ phi4_mini_flash = partial(
     TransformerDecoder, hidden_size=2560, num_layers=32, num_heads=40,
     expert_dim=0, num_experts=0, experts_per_token=0, rope_theta=0.0,
     dense_layers=32, dense_dim=10240, layer_kinds=sambay_layers(32),
-    hybrid=(20, 512, 5120, 16, 4, 160))
+    hybrid=(20, 512, 5120, 16, 4, 160), layer_norm=True, tied_head=True)
 phi4_mini_flash_tiny = partial(
     TransformerDecoder, hidden_size=64, num_layers=8, num_heads=8,
     expert_dim=0, num_experts=0, experts_per_token=0, rope_theta=0.0,
     dense_layers=8, dense_dim=128, layer_kinds=sambay_layers(8),
-    hybrid=(4, 16, 128, 8, 4, 4))
+    hybrid=(4, 16, 128, 8, 4, 4), layer_norm=True, tied_head=True)
+# ZAYA1-8B (Zyphra/ZAYA1-8B config.json, model_type zaya; arXiv:2510.04476 for
+# the attention, arXiv:2511.17127 for the rest): 40 layers alike, each
+# compressed convolutional attention (8 query heads over 2 key/value heads of
+# 128 in a latent, two causal 2-tap convolutions, rotary on half of a head,
+# theta 5e6) and 16 SwiGLU experts of 2,048, one a token by a softmax over an
+# MLP router of width 256 whose state rides from layer to layer, chosen under a
+# selection bias (the program's rule; the config names no rate: DeepSeek-V3's
+# 0.001 for sigmoid scores of mean 0.5, scaled to a softmax over 16 of mean
+# 1/16); learned scales on both residual sums; RMSNorm 1e-5; the head tied.
+_ZAYA_ROUTER = (("bias_update_rate", 0.0001),)
+zaya1_8b = partial(
+    TransformerDecoder, hidden_size=2048, num_layers=40, num_heads=8,
+    expert_dim=2048, num_experts=16, experts_per_token=1,
+    rope_theta=5000000.0, moe=_ZAYA_ROUTER, layer_kinds=("C",) * 40,
+    cca=(2, 128, 64, 256), tied_head=True)
+zaya_tiny = partial(
+    TransformerDecoder, hidden_size=64, num_layers=3, num_heads=4,
+    expert_dim=32, num_experts=8, experts_per_token=1, rope_theta=5000000.0,
+    moe=_ZAYA_ROUTER, layer_kinds=("C",) * 3, cca=(2, 16, 8, 32),
+    tied_head=True)

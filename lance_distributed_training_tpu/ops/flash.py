@@ -17,7 +17,11 @@ and keeps the weights for the backward pass. Two Pallas kernels avoid that:
   model dimension unsupported"): the library's splash kernel, which takes
   them as they are, at any length, and whose mask may be a causal band
   (``window``: blocks outside the band are skipped as the causal ones are).
-  Keys and values may come in fewer heads than the queries (grouped heads).
+  Keys and values may come in fewer heads than the queries (grouped heads),
+  and grouped heads take this kernel whatever their widths: for 8 query
+  heads over 2 key and value heads of 128 (compressed convolutional
+  attention) it ran 1.28 times as fast as the blocked kernel on repeated
+  keys and values (PERF.md section 6, PR 38).
   Its forward, dkv and dq kernels each run the block sizes
   :func:`splash_tiling` has for the call's shapes: what the chip timed
   fastest for them, else the untuned square blocks of 512, counted in
@@ -347,6 +351,11 @@ _TIMED = {
     _Shape(8192, 64, 128, 40, True, 0): SplashTiling(
         (2048, 2048, 256), (1024, 1024, 1024), (1024, 1024)),
     _Shape(8192, 64, 128, 40, True, 512): _square(512),
+    # ZAYA1's compressed convolutional attention: 8 query heads over 2 key
+    # and value heads, all 128 wide (4.14 ms a layer where square 512s take
+    # 4.58 and the library's blocked kernel on repeated keys 5.29 to 5.79)
+    _Shape(8192, 128, 128, 8, True, 0): SplashTiling(
+        (1024, 1024, 512), (1024, 1024, 512), (1024, 1024)),
 }
 
 
@@ -433,10 +442,11 @@ def unequal_attention(q, k, v, segment_ids=None, *, causal: bool = False,
                       window: int = 0,
                       tiling: Optional[SplashTiling] = None):
     """Fused attention for heads whose values are not as wide as their
-    queries and keys: q ``[B, H, S, Dqk]``, k ``[B, Hk, S, Dqk]``, v ``[B,
-    Hv, S, Dv]`` (S a multiple of 128; ``Hk`` and ``Hv`` divide ``H``: a key
-    or value head serves the query heads of its group), ``segment_ids`` ``[B,
-    S]`` int32 or None, output ``[B, H, S, Dv]``; scores over ``sqrt(Dqk)``.
+    queries and keys, or that come in groups: q ``[B, H, S, Dqk]``, k ``[B,
+    Hk, S, Dqk]``, v ``[B, Hv, S, Dv]`` (S a multiple of 128; ``Hk`` and
+    ``Hv`` divide ``H``: a key or value head serves the query heads of its
+    group), ``segment_ids`` ``[B, S]`` int32 or None, output ``[B, H, S,
+    Dv]``; scores over ``sqrt(Dqk)``.
     ``window`` > 0: causal, and a query sees the ``window`` keys up to its
     own. The kernels' block sizes come from the shapes
     (:func:`splash_tiling`); ``tiling`` is for a test or a timing that wants
@@ -479,7 +489,10 @@ def fused_attention_applies(seq: int, head_dim: int, mesh=None,
     width than ``head_dim``, ``unequal_attention``: latent attention's 192
     with values of 128, and differential attention's 64 with values of 128
     in 40 query heads over 20 key heads, causal, in a window of 512 or over
-    F*'s keys. Everything else is dense attention, as before: the CPU, a
+    F*'s keys; and ``unequal_attention`` again for heads in groups whose
+    values are as wide as their keys: compressed convolutional attention's 8
+    query heads over 2 key and value heads of 128, causal, at 8,192 tokens.
+    Everything else is dense attention, as before: the CPU, a
     ViT's 197 tokens, a tensor-parallel mesh, and several devices with no
     mesh to say how the batch is split."""
     if (platform or jax.default_backend()) != "tpu":
@@ -509,7 +522,8 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
     :func:`fused_attention_applies` says so for the shapes it sees, and
     dense attention elsewhere. Either way sequences of up to ``SHORT_SEQ``
     whole blocks run :func:`short_attention`, longer ones the library's
-    blocked kernel, and heads whose values are narrower than their keys
+    blocked kernel, and heads whose values are not as wide as their keys, or
+    whose keys and values are fewer than the queries,
     :func:`unequal_attention`.
 
     ``mesh`` is the trainer's device mesh. XLA cannot partition a Mosaic
@@ -530,14 +544,14 @@ def make_flash_attention(block_q: int = 512, block_k: int = 512,
 
     def kernel(q, k, v, ids, window=0):
         seq = q.shape[2]
-        if q.shape[3] != v.shape[3]:
+        if q.shape[3] != v.shape[3] or k.shape[1] != q.shape[1]:
             return unequal_attention(q, k, v, ids, causal=causal,
                                      window=window)
-        if window or k.shape[1] != q.shape[1]:
+        if window:
             raise NotImplementedError(
-                "a window or grouped heads with values as wide as the keys "
-                "has no kernel here (differential attention's are 64 and "
-                "128: unequal_attention)")
+                "a window over heads as many and as wide in values as in "
+                "keys has no kernel here (differential attention's are 64 "
+                "and 128 in groups: unequal_attention)")
         if seq <= SHORT_SEQ and seq % _LANES == 0:
             return short_attention(q, k, v, ids, causal=causal)
         from jax.experimental.pallas.ops.tpu import flash_attention as fa

@@ -48,6 +48,9 @@ SHAPES = {
     "moonlight": dict(q=(16, 192), k=(16, 192), v=(16, 128), window=0),
     "phi4_causal": dict(q=(40, 64), k=(20, 64), v=(10, 128), window=0),
     "phi4_window": dict(q=(40, 64), k=(20, 64), v=(10, 128), window=512),
+    # heads as wide in values as in keys, in groups: the library's blocked
+    # kernel with the keys and values repeated is timed beside the splash ones
+    "zaya": dict(q=(8, 128), k=(2, 128), v=(2, 128), window=0),
 }
 KERNELS = ("fwd", "dkv", "dq")
 CALLS = 20  # after one call of warm-up
@@ -88,8 +91,23 @@ def make_program(index, shape, tiling, sharding=None):
     from lance_distributed_training_tpu.ops import flash
 
     def loss(q, k, v, ids):
-        out = flash.unequal_attention(q, k, v, ids, causal=True,
-                                      window=shape["window"], tiling=tiling)
+        if isinstance(tiling, int):  # the library's blocked kernel
+            from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+            k, v = (flash._expand_heads(t, q.shape[1]) for t in (k, v))
+            out = fa.flash_attention(
+                q, k, v, segment_ids=fa.SegmentIds(q=ids, kv=ids),
+                sm_scale=q.shape[-1] ** -0.5, causal=True,
+                block_sizes=fa.BlockSizes(
+                    block_b=1, **dict.fromkeys((
+                        "block_q", "block_k_major", "block_k",
+                        "block_q_major_dkv", "block_k_major_dkv",
+                        "block_k_dkv", "block_q_dkv", "block_k_major_dq",
+                        "block_k_dq", "block_q_dq"), tiling)))
+        else:
+            out = flash.unequal_attention(
+                q, k, v, ids, causal=True, window=shape["window"],
+                tiling=tiling)
         return out.astype(jnp.float32).sum()
 
     def program(q, k, v, ids):
@@ -191,7 +209,8 @@ class Sweep:
         for tiling in tilings:
             self.index += 1
             row = {"shape": self.name, "stage": label, "index": self.index,
-                   "fwd": tiling.fwd, "dkv": tiling.dkv, "dq": tiling.dq}
+                   **(dict.fromkeys(KERNELS, (tiling,))
+                      if isinstance(tiling, int) else tiling._asdict())}
             rows.append(row)
             t0 = time.monotonic()
             try:
@@ -255,6 +274,9 @@ def sweep_shape(name, compile_only, sharding):
     base = sweep.stage("today", [tiling_of(default, default, default)])
     if not compile_only and not base[0].get("fwd_ms"):
         raise SystemExit(f"no splash kernel found in the trace: {base}")
+    if SHAPES[name]["q"][1] == SHAPES[name]["v"][1]:
+        # program ms is what compares: its kernels have other names
+        sweep.stage("blocked", [512, 1024, 2048])
     blocks = sweep.stage("blocks", [tiling_of(t, t, t) for t in triples])
     refused = [r["fwd"] for r in blocks if "error" in r]
     if refused:

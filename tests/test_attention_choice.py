@@ -295,6 +295,31 @@ def test_unequal_attention_compiles_for_a_v5e_at_phi4_flashs_widths(
         shape._replace(window=512 - window))
 
 
+def test_unequal_attention_compiles_for_a_v5e_at_zaya1s_widths(one_chip):
+    """Compressed convolutional attention's heads as the ZAYA1 cell runs
+    them: 8 query heads over 2 key and value heads, all 128 wide, 8,192
+    tokens, causal, at the tiling timed for the shape: the three kernels, and
+    no ``[B, H, S, S]`` tensor."""
+    def spec(heads):
+        return jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    tiling, timed = flash.splash_tiling(8192, 128, 128, 8, True)
+    assert timed and tiling.dq is not None
+
+    def loss(q, k, v):
+        out = flash.unequal_attention(q, k, v, causal=True)
+        assert out.shape == (1, 8, 8192, 128)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec(8), spec(2), spec(2)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "8192,8192]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("rows,seq,hidden,vocab,tied", [
     (1, 8192, 2560, 25008, True),  # c4-phi4flash-vp8-prepacked-8k's head
     (2, 4096, 2048, 50304, False),  # c4-olmoe-prepacked-4k's
@@ -340,6 +365,7 @@ CELL_SHAPES = [  # seq, d_qk, d_v, heads, causal, window
     (8192, 192, 128, 16, True, 0),  # Moonlight's latent attention
     (8192, 64, 128, 40, True, 0),  # Phi-4-mini-flash, F* and X
     (8192, 64, 128, 40, True, 512),  # Phi-4-mini-flash, S
+    (8192, 128, 128, 8, True, 0),  # ZAYA1's heads in groups, equal widths
 ]
 UNTIMED_SHAPES = [  # and the square block each runs
     ((4096, 192, 128, 16, True, 0), 512),  # a shorter row of the same heads
@@ -535,6 +561,69 @@ def test_heads_of_equal_width_never_reach_the_splash_kernel(
     with pytest.raises(ZeroDivisionError):  # the guard guards
         k = jax.ShapeDtypeStruct((*shape[:3], 2 * shape[3]), jnp.bfloat16)
         jax.make_jaxpr(lambda q, k, v: attention(k, k, v))(q, k, q)
+
+
+# -- heads in groups, as wide in values as in keys ----------------------------
+
+
+@pytest.mark.parametrize("platform,kernel", [("tpu", True), ("cpu", False)])
+def test_grouped_heads_of_equal_width_take_the_kernel_on_a_tpu_alone(
+        monkeypatch, platform, kernel):
+    """8 query heads over 2 key and value heads of 128 at 8,192 tokens (ZAYA1's
+    cell): the rule says kernel from the shapes, the call obeys it with the
+    splash kernels (this shape raised ``NotImplementedError`` before PR 38),
+    and off the chip the same call is dense attention over repeated heads."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    attention = flash.make_flash_attention(
+        causal=True, mesh=_meshes()["one device"], forced=False)
+    assert attention.fused(8192, 128, 128) is kernel
+    q = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.bfloat16)
+    traced = str(jax.make_jaxpr(attention)(q, kv, kv))
+    assert ("pallas_call" in traced and "splash" in traced) is kernel
+    assert jax.eval_shape(attention, q, kv, kv).shape == q.shape
+
+
+def test_grouped_heads_of_equal_width_in_the_kernel_equal_dense(monkeypatch):
+    """The call the rule makes on a TPU, in interpret mode, against dense
+    attention over repeated keys and values: forward and gradients, with a
+    key-validity mask (the last row's tail is padding)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    seq = 256
+    keys = jax.random.split(jax.random.key(38), 4)
+    q = jax.random.normal(keys[0], (2, 4, seq, 128), jnp.float32)
+    k = jax.random.normal(keys[1], (2, 2, seq, 128), jnp.float32)
+    v = jax.random.normal(keys[2], (2, 2, seq, 128), jnp.float32)
+    w = jax.random.normal(keys[3], q.shape, jnp.float32)
+    valid = jnp.arange(seq)[None, :] < jnp.asarray([seq, seq - 40])[:, None]
+    mask = valid[:, None, None, :]
+    live = valid[:, None, :, None]  # dead queries mean nothing
+    with monkeypatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        chosen = flash.make_flash_attention(
+            causal=True, mesh=_meshes()["one device"], forced=False)
+
+    def dense(q, k, v):
+        return jnp.where(live, dot_product_attention(
+            q, jnp.repeat(k, 2, 1), jnp.repeat(v, 2, 1), mask=mask,
+            dtype=jnp.float32, causal=True), 0)
+
+    def kernel(q, k, v):
+        return jnp.where(live, chosen(q, k, v, mask=mask), 0)
+
+    def both(q, k, v):
+        return [(fn(q, k, v), jax.grad(lambda *a: (fn(*a) * w).sum(),
+                                       argnums=(0, 1, 2))(q, k, v))
+                for fn in (dense, kernel)]
+
+    assert "splash" in str(jax.make_jaxpr(kernel)(q, k, v))
+    with pltpu.force_tpu_interpret_mode():
+        (want, want_grads), (got, got_grads) = _one_program(both, q, k, v)
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    for g, wnt in zip(got_grads, want_grads):
+        assert g.shape == wnt.shape
+        np.testing.assert_allclose(g, wnt, atol=5e-5, rtol=1e-5)
 
 
 # -- BERT's encoder with the chosen kernel -----------------------------------

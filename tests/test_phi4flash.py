@@ -184,10 +184,10 @@ def test_each_kind_of_layer_alone_matches_reference(ref, kind):
     block = transformer.DecoderBlock(
         8, 0, 0, 0, dtype=jnp.float32, dense_dim=128, kind=kind, depth=depth,
         hybrid=(4, 16, 128, 8, 4, 4), layer_norm=True)
-    handed = (memory, (k, v))
+    handed = (memory, (k, v), None)  # no router state: no layer here has one
     params = ref.perturb(block.init(keys[4], x, handed=handed),
                          jax.random.key(8))["params"]
-    (got, (got_m, got_kv)), _ = _one_program(lambda p: block.apply(
+    (got, (got_m, got_kv, _)), _ = _one_program(lambda p: block.apply(
         {"params": p}, x, handed=handed, mutable=["mixer_stats"]), params)
 
     def reference(p):  # it keeps keys and values [B, S, heads, d]
