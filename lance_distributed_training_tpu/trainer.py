@@ -1700,9 +1700,11 @@ class _StepStats:
     the masked positions, and the steps whose batch overflowed the gathered
     head); any other name is a gauge of the step just logged, which rides
     the log line too (``moe_expert_load_max`` / ``_mean``,
-    ``mlm_head_capacity_tokens``, ``mlm_head_fill_pct``). The loss fetch
-    has already waited for that step, so nothing here waits again; all of
-    them come to the host in one transfer."""
+    ``mlm_head_capacity_tokens``, ``mlm_head_fill_pct``). All of them come
+    to the host in one array: ``pack`` enqueues its program right behind the
+    log point's step, ahead of the next one, so it is ready when that step's
+    loss is, and ``publish`` fetches it after the loss fetch has waited for
+    the step: nothing here waits for anything enqueued later."""
 
     def __init__(self):
         self._sums = None
@@ -1714,24 +1716,37 @@ class _StepStats:
             sums = {k: self._sums[k] + v for k, v in sums.items()}
         self._sums, self._last = sums, stats
 
-    def publish(self, entry: dict) -> None:
-        if not self._last:  # no step since the last log point, or a task
-            return  # with nothing to report
-        registry = default_registry()
-        # one array, one fetch: the device has just been drained, and every
-        # round trip (one a scalar, 6 ms each on the v5e's host) is time in
-        # which it has nothing to run
+    def pack(self):
+        """At a log point, its step just dispatched: the sums up to it and
+        its gauges go into one device array (one program, no wait), and the
+        sums start anew with the next step. Returns what ``publish`` takes;
+        None for a task with nothing to report."""
+        if not self._last:
+            return None
         names = list(self._sums) + [n for n in self._last
                                     if n not in self._sums]
-        values = np.asarray(_pack_scalars(  # ldt: ignore[LDT1704] -- log-point fetch of scalars the drained step produced
-            [self._sums.get(n, self._last[n]) for n in names]))
-        for name, value in zip(names, values.tolist()):
-            if name in self._sums:
+        packed = _pack_scalars(
+            [self._sums.get(n, self._last[n]) for n in names])
+        counters = len(self._sums)  # the names' head: the rest are gauges
+        self._sums = self._last = None
+        return names, counters, packed
+
+    @staticmethod
+    def publish(packed, entry: dict) -> None:
+        if packed is None:
+            return
+        names, counters, values = packed
+        registry = default_registry()
+        # one array, one fetch (a round trip a scalar was 6 ms each on the
+        # v5e's host), of a program that ran right behind the step whose
+        # loss the loop has just fetched
+        values = np.asarray(values).tolist()  # ldt: ignore[LDT1704] -- log-point fetch of scalars packed behind the step the drain waited for
+        for i, (name, value) in enumerate(zip(names, values)):
+            if i < counters:
                 registry.counter(name).inc(value)
             else:
                 registry.gauge(name).set(value)
                 entry[name] = round(value, 4)
-        self._sums = self._last = None
 
 
 @jax.jit
@@ -1746,10 +1761,17 @@ class _StepsInFlight:
     begins and where it ends. Counter ``train_steps_dispatched_total``;
     counter ``train_dispatch_starved_total``: steps whose dispatch began
     with nothing in flight although the loop had not just emptied the queue
-    itself (a ``train.drain``, an epoch's start, the sampled transform
-    await), so the chips had run dry because the host was late; gauge
-    ``train_steps_in_flight_max``: the most in flight after a dispatch since
-    the last log point, which is how a run learns the runtime's limit."""
+    itself (a ``train.drain`` that found no step to follow, an epoch's
+    start, the sampled transform await), so the chips had run dry because
+    the host was late; gauge ``train_steps_in_flight_max``: the most in
+    flight after a dispatch since the last log point, which is how a run
+    learns the runtime's limit. A ``train.drain`` waits for the step its
+    drain point is for, never for a newer one, and counts in one of two:
+    ``train_drain_ahead_total``, the next step was already dispatched and
+    is in flight when the wait returns (the steady state: the step after it
+    begins with ``in_flight`` 1), and ``train_drain_empty_total``, the
+    drained step was the newest, so the queue is empty (the epoch's last
+    step, ``max_steps``, a due checkpoint, a preemption, a chaos hook)."""
 
     def __init__(self):
         registry = default_registry()
@@ -1757,6 +1779,10 @@ class _StepsInFlight:
         self._emptied = True  # the run's first step finds an empty queue
         self._dispatched = registry.counter("train_steps_dispatched_total")
         self._starved = registry.counter("train_dispatch_starved_total")
+        self._drains = {
+            ahead: registry.counter(f"train_drain_{name}_total")
+            for ahead, name in ((True, "ahead"), (False, "empty"))
+        }
         self._max_gauge = registry.gauge("train_steps_in_flight_max")
         self._max_after = 0
         self._min_began = None
@@ -1793,6 +1819,14 @@ class _StepsInFlight:
         self._pending.clear()
         self._emptied = True
 
+    def drained(self, ahead: bool) -> None:
+        """A ``train.drain`` has returned. ``ahead``: a newer step was
+        dispatched before the wait and is still the device's to run, so the
+        queue is not empty and a dispatch that finds it so is starved."""
+        self._drains[ahead].inc()
+        if not ahead:
+            self.emptied()
+
     def publish(self, entry: dict):
         """At a log point: the gauge rides the log line and the interval's
         extremes start again. Returns the fewest in flight where a dispatch
@@ -1802,6 +1836,31 @@ class _StepsInFlight:
         entry["train_steps_in_flight_max"] = self._max_after
         fewest, self._max_after, self._min_began = self._min_began, 0, None
         return fewest
+
+
+@dataclasses.dataclass
+class _DrainPoint:
+    """The step a drain point is for, held from its dispatch until the loop
+    has waited for it: what its ``train.drain`` fetches and what its
+    progress record is written from, while ``loss`` and ``gnorm`` in the
+    loop already name the next step's."""
+
+    step: int  # steps done with it: the record's "step"
+    loss: Any
+    gnorm: Any
+    stats: Any  # ``_StepStats.pack()``'s, enqueued behind the step
+    log: bool  # a log point; else a ``sync_every`` drain alone
+
+
+def _host_device():
+    """The CPU backend's device of this process, where the loop evaluates
+    telemetry it must not enqueue on the accelerator; None where the
+    process has no CPU backend (``JAX_PLATFORMS`` names the accelerator
+    alone), which leaves such a value to the default device."""
+    try:
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError:
+        return None
 
 
 class _SlowIntervals:
@@ -1959,6 +2018,120 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         for state in ("warm", "cold")
     }
     loader = None
+    # A drain point's step waits here until the loop has fetched its loss:
+    # at most one, and with it at most one step in flight behind a drain.
+    owed: Optional[_DrainPoint] = None
+    host = _host_device()
+
+    def checkpoint_due(abs_step: int) -> bool:
+        # ">= saved + N" rather than "% N" so data_echo's multi-step jumps
+        # can't skip the trigger.
+        return (
+            ckpt is not None
+            and config.checkpoint_every_steps > 0
+            and abs_step >= journal.saved_step + config.checkpoint_every_steps
+        )
+
+    def nothing_may_follow(steps_done: int) -> bool:
+        """Whether the step just dispatched has to be the device's last
+        before the loop has seen it finish: the run stops with it, or its
+        state is about to be used (the step after it would donate that
+        state). Read from the loop's own variables before the next dispatch;
+        the epoch's last step shows when ``next`` finds no batch."""
+        return (
+            0 < config.max_steps <= steps_done
+            or chaos is not None  # its hook may stop or kill at any step
+            or (preempt is not None and preempt.requested)
+            or checkpoint_due(resume_global_step + steps_done)
+        )
+
+    def drain(point: _DrainPoint, ahead: bool) -> None:
+        # The loop thread waiting for the device: for the point's own step,
+        # never a newer one. ``ahead``: the next step is already dispatched
+        # and runs while everything up to the dispatch after it happens.
+        obs_phase("train.drain", step=point.step - 1)
+        _ = float(point.loss)  # ldt: ignore[LDT1704] -- deliberate bounded drain: fetch at sync_every/log points keeps dispatch depth finite
+        flight.drained(ahead)
+
+    def progress(point: _DrainPoint) -> None:
+        # Per-step progress — the reference's live tqdm it/s + loss
+        # (lance_iterable.py:106,116-117). Console/JSONL only; wandb stays
+        # on the per-epoch axis. Called after the point's drain, so every
+        # fetch here is of something ready with the point's step (its loss,
+        # its gradient norm, the stats packed behind it): with a newer step
+        # in flight none of them waits for it. The wall-clock rate (not the
+        # dispatch-time upper bound) leads the progress line, so it agrees
+        # with the epoch metrics' wall-clock rate on async backends.
+        obs_phase("train.log", step=point.step)
+        with obs_span("loop.log_entry", step=point.step):
+            w = timer.window(batch_size=config.batch_size)
+            wt = w["loader_s"] + w["step_s"]
+            entry = {
+                "step": point.step,
+                "epoch": epoch,
+                "loss": round(float(point.loss), 4),  # ldt: ignore[LDT1704] -- log-interval telemetry fetch of the already-drained scalar
+                "images_per_sec": w["images_per_sec_wall"],
+                "images_per_sec_dispatch": w["images_per_sec_dispatch"],
+                "loader_stall_pct": (
+                    100.0 * w["loader_s"] / wt if wt else 0.0
+                ),
+            }
+            if "placement_h2d_s" in w:
+                # H2D dispatch time this window (runs on the placement
+                # thread, overlapping the step) as a share of the same
+                # loader+step denominator — the transfer cost the pre-r7
+                # accounting folded invisibly into loader_stall_pct.
+                entry["h2d_pct"] = (
+                    100.0 * w["placement_h2d_s"] / wt if wt else 0.0
+                )
+            # Data-service windows (RemoteLoader counters attached to the
+            # timer): svc_client_stall_s, …
+            entry.update({
+                k: round(v, 4) if isinstance(v, float) else v
+                for k, v in w.items() if k.startswith("svc_")
+            })
+        if lr_fn is not None:
+            # Schedules count optimizer updates, not micro-steps; base_step
+            # carries the restored position across resume. Telemetry, taken
+            # on the host: the schedule's few eager programs run on the CPU
+            # backend, so nothing queues behind the step in flight.
+            updates = (base_step + point.step) // max(config.grad_accum, 1)
+            with obs_span("loop.log_lr", step=point.step), \
+                    jax.default_device(host):
+                entry["lr"] = float(
+                    lr_fn(updates) if callable(lr_fn) else lr_fn
+                )
+        if point.gnorm is not None:
+            entry["grad_norm"] = round(float(point.gnorm), 4)  # ldt: ignore[LDT1704] -- log-interval divergence telemetry, rides the loss drain
+        with obs_span("loop.stats_fetch", step=point.step):
+            step_stats.publish(point.stats, entry)
+        in_flight_min = flight.publish(entry)
+        if attention_fused is not None:
+            entry["attention_fused"] = attention_fused
+            # the steps so far were traced: each splash kernel they built
+            # says once what tiling it runs
+            for line in splash_tilings_built():
+                logger.log(line, to_wandb=False)
+        if config.data_echo > 1:
+            # The windowed rate counts echoed steps; report the unique-data
+            # rate next to it (as the epoch metrics do) so the live stream
+            # is never silently inflated.
+            entry["data_echo"] = config.data_echo
+            entry["unique_images_per_sec"] = (
+                entry["images_per_sec"] / config.data_echo
+            )
+        with obs_span("loop.log_write", step=point.step):
+            logger.log(entry, to_wandb=False)
+        slow.check(point.step, epoch, in_flight_min, logger)
+        obs_phase("train.bookkeep")
+
+    def settle(point: _DrainPoint, ahead: bool) -> None:
+        drain(point, ahead)
+        if point.log:
+            progress(point)
+        else:
+            obs_phase("train.bookkeep")
+
     for epoch in range(start_epoch, config.epochs):
         # Mid-epoch resume cursor: batches of THIS epoch already consumed
         # by the checkpointed run (first epoch after a restart only).
@@ -2032,8 +2205,12 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                       epoch_step=epoch_step)
             batch = next(it, None)
             if batch is None:
-                obs_phase("train.epoch_end", epoch=epoch)
                 timer.loader_stop()
+                if owed is not None:
+                    # the epoch's last step was a drain point: none follows
+                    settle(owed, ahead=False)
+                    owed = None
+                obs_phase("train.epoch_end", epoch=epoch)
                 break
             obs_phase("train.bookkeep")
             timer.loader_stop()
@@ -2124,24 +2301,47 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                     loss_sum = loss_sum + loss
                 with obs_span("loop.stats_add", step=global_step):
                     step_stats.add(extras[0])
+                if owed is not None:
+                    # The step after the owed one is in the queue: the
+                    # device has it to run while the loop waits for the owed
+                    # one, writes its line and goes on to the next dispatch.
+                    # Until then the loop waits for nothing enqueued behind
+                    # the step just dispatched.
+                    settle(owed, ahead=True)
+                    owed = None
                 # Bound the async dispatch queue (each in-flight step pins
                 # its global batch on device) — independent of logging, so
                 # neither log_every=0 nor a huge log_every can unbound
-                # device memory. A scalar value fetch drains the queue and
-                # hands the log line its loss in one D2H. Also fetch at log
-                # points (log_every may exceed or not divide sync_every), so
-                # the drain lands INSIDE the timed step segment and the
-                # progress window's rate stays honest.
+                # device memory. A drain point's step is waited for (a
+                # scalar value fetch, which hands the log line its loss in
+                # the same D2H) once the step after it is dispatched, so
+                # the wait leaves one step in flight and not an empty
+                # queue; here and now only where nothing may follow it. Log
+                # points are drain points too (log_every may exceed or not
+                # divide sync_every). Either way the wait lands INSIDE a
+                # timed step segment (all but the one for an epoch's last
+                # step, which the loop owes until ``next`` finds no batch),
+                # and both edges of a progress window lie behind the same
+                # one step in flight, so the window's rate stays honest.
                 sync_every = min(config.log_every or 50, 50)
-                if (global_step + 1) % sync_every == 0 or (
-                    config.log_every
-                    and (global_step + 1) % config.log_every == 0
-                ):
-                    # The loop thread waiting for the device.
-                    obs_phase("train.drain", step=global_step)
-                    _ = float(loss)  # ldt: ignore[LDT1704] -- deliberate bounded drain: fetch at sync_every/log points keeps dispatch depth finite
-                    obs_phase("train.bookkeep")
-                    flight.emptied()
+                log_point = bool(config.log_every) and (
+                    (global_step + 1) % config.log_every == 0
+                )
+                last = None  # a drain point after which nothing may follow
+                if (global_step + 1) % sync_every == 0 or log_point:
+                    packed = None
+                    if log_point:
+                        # ahead of the next step in the device's queue
+                        with obs_span("loop.stats_pack", step=global_step):
+                            packed = step_stats.pack()
+                    point = _DrainPoint(global_step + 1, loss, gnorm, packed,
+                                        log_point)
+                    if nothing_may_follow(global_step + 1):
+                        drain(point, ahead=False)
+                        obs_phase("train.bookkeep")
+                        last = point
+                    else:
+                        owed = point
                 timer.step_stop()
                 global_step += 1
                 epoch_step += 1
@@ -2153,79 +2353,8 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                                  batch, loss)
                 if 0 < config.max_steps <= global_step:
                     stop = True
-                if config.log_every and global_step % config.log_every == 0:
-                    # Per-step progress — the reference's live tqdm it/s +
-                    # loss (lance_iterable.py:106,116-117). Console/JSONL
-                    # only; wandb stays on the per-epoch axis. The loss D2H
-                    # is cheap: the fetch above already materialised it.
-                    # The wall-clock rate (not the dispatch-time upper
-                    # bound) leads the progress line, so it agrees with the
-                    # epoch metrics' wall-clock rate on async backends.
-                    obs_phase("train.log", step=global_step)
-                    with obs_span("loop.log_entry", step=global_step):
-                        w = timer.window(batch_size=config.batch_size)
-                        wt = w["loader_s"] + w["step_s"]
-                        entry = {
-                            "step": global_step,
-                            "epoch": epoch,
-                            "loss": round(float(loss), 4),  # ldt: ignore[LDT1704] -- log-interval telemetry fetch of the already-drained scalar
-                            "images_per_sec": w["images_per_sec_wall"],
-                            "images_per_sec_dispatch":
-                                w["images_per_sec_dispatch"],
-                            "loader_stall_pct": (
-                                100.0 * w["loader_s"] / wt if wt else 0.0
-                            ),
-                        }
-                        if "placement_h2d_s" in w:
-                            # H2D dispatch time this window (runs on the
-                            # placement thread, overlapping the step) as a
-                            # share of the same loader+step denominator — the
-                            # transfer cost the pre-r7 accounting folded
-                            # invisibly into loader_stall_pct.
-                            entry["h2d_pct"] = (
-                                100.0 * w["placement_h2d_s"] / wt
-                                if wt else 0.0
-                            )
-                        # Data-service windows (RemoteLoader counters
-                        # attached to the timer): svc_client_stall_s, …
-                        entry.update({
-                            k: round(v, 4) if isinstance(v, float) else v
-                            for k, v in w.items() if k.startswith("svc_")
-                        })
-                    if lr_fn is not None:
-                        # Schedules count optimizer updates, not
-                        # micro-steps; base_step carries the restored
-                        # position across resume.
-                        updates = (base_step + global_step) // max(
-                            config.grad_accum, 1
-                        )
-                        with obs_span("loop.log_lr", step=global_step):
-                            entry["lr"] = float(
-                                lr_fn(updates) if callable(lr_fn) else lr_fn
-                            )
-                    if gnorm is not None:
-                        entry["grad_norm"] = round(float(gnorm), 4)  # ldt: ignore[LDT1704] -- log-interval divergence telemetry, rides the loss drain
-                    with obs_span("loop.stats_fetch", step=global_step):
-                        step_stats.publish(entry)
-                    in_flight_min = flight.publish(entry)
-                    if attention_fused is not None:
-                        entry["attention_fused"] = attention_fused
-                        # the steps so far were traced: each splash kernel
-                        # they built says once what tiling it runs
-                        for line in splash_tilings_built():
-                            logger.log(line, to_wandb=False)
-                    if config.data_echo > 1:
-                        # The windowed rate counts echoed steps; report the
-                        # unique-data rate next to it (as the epoch metrics
-                        # do) so the live stream is never silently inflated.
-                        entry["data_echo"] = config.data_echo
-                        entry["unique_images_per_sec"] = (
-                            entry["images_per_sec"] / config.data_echo
-                        )
-                    with obs_span("loop.log_write", step=global_step):
-                        logger.log(entry, to_wandb=False)
-                    slow.check(global_step, epoch, in_flight_min, logger)
-                    obs_phase("train.bookkeep")
+                if last is not None and last.log:
+                    progress(last)
                 if stop:
                     break
             # Step boundary: the journal always pairs the post-step model
@@ -2251,25 +2380,24 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                     # silently lost data.
                     cursor_base = {"epoch": epoch, "step": 0}
                 journal.cursor_base = cursor_base
-                if (
-                    ckpt is not None
-                    and config.checkpoint_every_steps > 0
-                    and journal.abs_step
-                    >= journal.saved_step + config.checkpoint_every_steps
-                ):
+                if checkpoint_due(journal.abs_step):
                     # Async step checkpoint (the epoch-boundary save awaits
-                    # via ckpt.close()); ">= saved + N" rather than "% N" so
-                    # data_echo's multi-step jumps can't skip the trigger.
+                    # via ckpt.close()).
                     if ckpt.save(journal.abs_step, state,
                                  cursor=journal.make_cursor()):
                         journal.saved_step = journal.abs_step
                 if chaos is not None:
                     chaos.on_step(global_step)
                 if preempt is not None and preempt.requested and not stop:
-                    # Orchestrated preemption (SIGTERM): the in-flight step
-                    # has finished; drain the loader/placement ring below and
+                    # Orchestrated preemption (SIGTERM): no step follows.
+                    # The steps in flight finish before the epoch's loss sum
+                    # is fetched; drain the loader/placement ring below and
                     # let train()'s finally take the awaited emergency
                     # checkpoint.
+                    if owed is not None:
+                        # the flag came up behind the drain point's test
+                        settle(owed, ahead=False)
+                        owed = None
                     journal.preempted = True
                     logger.log({"preempted": True,
                                 "at_step": journal.abs_step,
