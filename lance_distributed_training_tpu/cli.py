@@ -177,8 +177,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "compressed latent with causal convolutions, 8 query "
                         "heads over 2 key/value heads, an MLP router with a "
                         "state carried from layer to layer, 1 of 16 experts "
-                        "a token) and olmoe_tiny, moonlight_tiny, "
-                        "phi4_mini_flash_tiny, zaya_tiny")
+                        "a token), qwen3_next_80b_a3b (Qwen3-Next-80B-A3B: "
+                        "three gated-delta-rule linear-attention layers to "
+                        "one gated softmax-attention layer of 16 query heads "
+                        "over 2 key/value heads of 256, 10 of 512 experts a "
+                        "token beside a gated shared one; the first log line "
+                        "says delta=fused kernel or chunked) and olmoe_tiny, "
+                        "moonlight_tiny, phi4_mini_flash_tiny, zaya_tiny, "
+                        "qwen3_next_tiny")
     p.add_argument("--num_layers", type=int, default=0,
                    help=">0: this many layers of a masked_lm/causal_lm "
                         "transformer preset in place of its own depth, at "
@@ -187,10 +193,12 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--expert_share", type=str, default=None,
                    metavar="RANK/RANKS",
                    help="the experts of each dropless expert layer (olmoe_*, "
-                        "moonlight_*, zaya*) that this process holds as rank "
+                        "moonlight_*, zaya*, qwen3_next_*) that this process "
+                        "holds as rank "
                         "RANK of RANKS that share the layer: E/RANKS of them "
                         "from RANK*E/RANKS on (moonlight_16b_a3b 0/8: experts "
-                        "0-7 of 64; zaya1_8b 0/2: experts 0-7 of 16). The "
+                        "0-7 of 64; zaya1_8b 0/2: experts 0-7 of 16; "
+                        "qwen3_next_80b_a3b 0/16: experts 0-31 of 512). The "
                         "router stays whole; what absent experts would add "
                         "is left out. With --vocab_size as the vocabulary's "
                         "slice and --num_layers, one chip's share of an "
@@ -200,7 +208,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="the published layers [FIRST, END) that this process "
                         "holds of a preset whose layers differ by kind "
                         "(phi4_mini_flash*: 14:20 is M, S, M*, F*, G, X, one "
-                        "of each), as a pipeline stage would; a layer keeps "
+                        "of each; qwen3_next_*: 0:4 is three linear-"
+                        "attention layers and one attention layer, a whole "
+                        "period), as a pipeline stage would; a layer keeps "
                         "its published index, and a span in which a G or X "
                         "layer has no M* or F* before it is refused. With "
                         "--vocab_size as the vocabulary's slice, one chip's "

@@ -13,8 +13,9 @@ Mixture-of-Experts layers.
   mesh, impossible at a published sparse model's shape (8,192 tokens, 64
   experts, 8 a token: terabytes).
 * :class:`DroplessMoE` — the **published-shape** form (the OLMoE,
-  Moonlight and ZAYA1 presets): top-k routing with no capacity and no dropped
-  token; the ``T * k`` assignments are sorted by expert, the tokens gathered, the
+  Moonlight, ZAYA1 and Qwen3-Next presets): top-k routing with no capacity
+  and no dropped token; the ``T * k`` assignments are sorted by expert, the
+  tokens gathered, the
   three SwiGLU products run as grouped matrix multiplications over the
   ragged groups (``jax.lax.ragged_dot``), and each token's k results
   gathered back through the inverse permutation and summed with their
@@ -31,6 +32,7 @@ Mixture-of-Experts layers.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Callable, NamedTuple
 
 import flax.linen as nn
@@ -300,15 +302,18 @@ class DroplessMoE(nn.Module):
     """Top-k routed SwiGLU experts without capacity: ``[B, S, H] -> [B, S, H]``,
     ``Σ_k w_k · down_k(silu(gate_k(x)) · up_k(x))`` over each token's
     ``experts_per_token`` chosen experts, no biases, plus ``shared(x)``
-    where ``shared_dim`` > 0 (one :class:`SwiGLU` every token passes).
+    where ``shared_dim`` > 0 (one :class:`SwiGLU` every token passes; under
+    ``shared_gate`` times ``sigmoid(x w_g)``, Qwen3-Next's).
 
     The logits are the layer's own (one bias-free f32 product, ``router``)
     or, where the call is given ``logits`` [B, S, E], the caller's (ZAYA1's
     :class:`StateRouter`, whose state rides between layers). Two scorings.
-    ``"softmax"`` (OLMoE, ZAYA1): the k largest router probabilities, weighted
-    by the softmax values themselves. ``"sigmoid"`` (the DeepSeek-V3 family,
-    Moonlight): ``s = sigmoid(logits)``, the weights the chosen ``s`` divided
-    by their sum under ``norm_topk`` and times ``routed_scale``. Under either,
+    ``"softmax"`` (OLMoE, ZAYA1, Qwen3-Next): the k largest router
+    probabilities, weighted by the softmax values themselves, divided by
+    their sum under ``norm_topk`` (Qwen3-Next). ``"sigmoid"`` (the
+    DeepSeek-V3 family, Moonlight): ``s = sigmoid(logits)``, the weights the
+    chosen ``s`` divided by their sum under ``norm_topk`` and times
+    ``routed_scale``. Under either,
     ``bias_update_rate`` > 0 (Moonlight, ZAYA1) chooses the k largest of ``s +
     b`` where ``b`` is a selection bias that takes no gradient
     (``router_state``/``bias``; it moves against the load by that rate after
@@ -332,7 +337,10 @@ class DroplessMoE(nn.Module):
     ``moe_stats``/``over_usual`` says which, ``row_fill`` how much of the
     built list was live; where twice an even share is already ``T * k``, one
     expert a token with half of them held, there is the one list and no
-    ``cond``). Between the ``[T, H]`` tokens and the ``[R, H]``
+    ``cond``; where the worst case is more than four usual lists, a
+    sixteenth of ten experts a token held, it is built ``usual`` rows at a
+    time and no array of ``T * k`` rows exists). Between the ``[T, H]``
+    tokens and the ``[R, H]``
     built rows lie two operations that are each other's transposes: tokens
     -> rows (a live row is its token, a dead one zeros) and rows -> tokens
     (a token is the sum of its live rows, in f32, slot after slot). The sum
@@ -365,6 +373,7 @@ class DroplessMoE(nn.Module):
     routed_scale: float = 1.0
     bias_update_rate: float = 0.0  # > 0: the selection bias and its update
     shared_dim: int = 0
+    shared_gate: bool = False  # the shared expert times sigmoid(x w_g)
     first_expert: int = 0
     held_experts: int = 0  # 0: all of them
 
@@ -400,9 +409,9 @@ class DroplessMoE(nn.Module):
                 top_p = jnp.take_along_axis(probs, top_e, axis=-1)
             else:
                 top_p, top_e = jax.lax.top_k(probs, k)  # [T, k]
+            if self.norm_topk:
+                top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
             if not softmax:
-                if self.norm_topk:
-                    top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
                 top_p = top_p * self.routed_scale
 
         def experts(xs, group_sizes, w_gate, w_up, w_down):
@@ -455,6 +464,9 @@ class DroplessMoE(nn.Module):
             valid = pos < ends[-1]
 
             def with_rows(rows):
+                if rows > 4 * usual:
+                    return in_pieces(-(-rows // usual))
+
                 def run(tokens, top_p, w_gate, w_up, w_down, order, pos,
                         valid, ends, group_sizes):
                     with jax.named_scope("moe.dispatch"):
@@ -478,6 +490,44 @@ class DroplessMoE(nn.Module):
             usual = min(-(-2 * t * k * held // (e * 128)) * 128, t * k)
             over = (ends[-1] > usual).astype(jnp.float32)
             fill = 100.0 * ends[-1] / jnp.where(over > 0, t * k, usual)
+
+            def in_pieces(pieces):
+                """The worst case where it is more than four usual lists (a
+                sixteenth of 10 experts a token held: eight): the whole
+                sorted list, ``usual`` rows at a time, each piece the groups'
+                part that falls inside it, summed in f32. Exact as the one
+                long list is, and no array of ``T * k`` rows exists, in a
+                branch that sizes the step's memory and hardly ever runs."""
+                def run(tokens, top_p, w_gate, w_up, w_down, order, pos,
+                        valid, ends, group_sizes):
+                    del group_sizes
+                    heads = jnp.pad(order, (0, pieces * usual - t * k))
+
+                    @jax.checkpoint
+                    def piece(i, tokens, top_p, w_gate, w_up, w_down):
+                        lo = i * usual
+                        with jax.named_scope("moe.dispatch"):
+                            inside = valid & (pos >= lo) & (pos < lo + usual)
+                            way = _Way(
+                                jax.lax.dynamic_slice_in_dim(heads, lo, usual),
+                                lo + jnp.arange(usual) < ends[-1],
+                                jnp.clip(pos - lo, 0, usual - 1), inside)
+                            sizes = jnp.diff(jnp.clip(ends, lo, lo + usual),
+                                             prepend=lo)
+                            xs = _tokens_to_rows(tokens.astype(self.dtype),
+                                                 way)
+                        out = experts(xs, sizes, w_gate, w_up, w_down)
+                        with jax.named_scope("moe.combine"):
+                            return _sum_back(out, top_p, way)
+
+                    def add(y, i):
+                        return y + piece(i, tokens, top_p, w_gate, w_up,
+                                         w_down), None
+
+                    return jax.lax.scan(
+                        add, jnp.zeros((t, h), jnp.float32),
+                        jnp.arange(pieces))[0]
+                return run
 
             def either(make, inputs, *args):
                 """``make(rows)(*args)`` with the worst-case list if the
@@ -556,6 +606,19 @@ class DroplessMoE(nn.Module):
         y = y.astype(self.dtype)
         if self.shared_dim:
             with jax.named_scope("moe.shared"):
-                y = y + SwiGLU(self.shared_dim, self.dtype, self.kernel_init,
-                               name="shared")(tokens)
+                shared = SwiGLU(self.shared_dim, self.dtype, self.kernel_init,
+                                name="shared")(tokens)
+                if self.shared_gate:
+                    gate = nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=self.dtype,
+                        param_dtype=jnp.float32, kernel_init=self.kernel_init,
+                        dot_general=partial(
+                            jax.lax.dot_general,
+                            preferred_element_type=jnp.float32),
+                        name="shared_gate")(tokens))
+                    self.sow("moe_stats", "shared_gate_mean",
+                             (gate[:, 0] * w).sum() / n)
+                    shared = (gate * shared.astype(jnp.float32)).astype(
+                        self.dtype)
+                y = y + shared
         return y.reshape(b, s, h)

@@ -37,6 +37,8 @@ from .transformer import (
     moonlight_tiny,
     phi4_mini_flash,
     phi4_mini_flash_tiny,
+    qwen3_next_80b_a3b,
+    qwen3_next_tiny,
     olmoe_1b_7b,
     olmoe_tiny,
     zaya1_8b,
@@ -333,10 +335,13 @@ def _masked_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
 # two weights of the paper (arXiv:2409.02060, section 4.1); Moonlight's
 # (all but its first) sow the sequence-wise balance term, with DeepSeek-V3's
 # weight (arXiv:2412.19437, section 4.2: alpha 0.0001). ZAYA1's are balanced
-# by the selection bias alone: what they sow has no weight here.
+# by the selection bias alone: what they sow has no weight here. Qwen3-Next's
+# take the balance term at its published class's default weight
+# (router_aux_loss_coef 0.001) and no z term.
 _SWITCH_AUX = {"load_balance": 0.01}
 _OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
 _MOONLIGHT_AUX = {"seq_balance": 0.0001}
+_QWEN3_NEXT_AUX = {"load_balance": 0.001}
 _CAUSAL_LMS: dict = {
     "gpt_base": (gpt_base, 50257, _SWITCH_AUX),
     "gpt_small": (gpt_small, 50257, _SWITCH_AUX),
@@ -348,6 +353,8 @@ _CAUSAL_LMS: dict = {
     "phi4_mini_flash_tiny": (phi4_mini_flash_tiny, 512, {}),
     "zaya1_8b": (zaya1_8b, 262272, {}),
     "zaya_tiny": (zaya_tiny, 512, {}),
+    "qwen3_next_80b_a3b": (qwen3_next_80b_a3b, 151936, _QWEN3_NEXT_AUX),
+    "qwen3_next_tiny": (qwen3_next_tiny, 512, _QWEN3_NEXT_AUX),
 }
 
 
@@ -382,7 +389,8 @@ def _expert_load(sown: dict) -> dict:
     worst-case list (``over_usual``) and how full the built lists were
     (``row_fill``: live rows over built rows, in percent, mean over the
     layers); with a selection bias its largest magnitude over the layers;
-    with one expert a token the mean weight that expert got."""
+    with one expert a token the mean weight that expert got; with a gate
+    on the shared expert its mean over the live tokens and the layers."""
     by_name = _sown_by_name(sown)
 
     def load(name, total, prefix):
@@ -403,20 +411,37 @@ def _expert_load(sown: dict) -> dict:
             by_name["bias_abs_max"]).max()
     if "top1_prob" in by_name:
         out["router_top1_prob_mean"] = jnp.stack(by_name["top1_prob"]).mean()
+    if "shared_gate_mean" in by_name:
+        out["shared_gate_mean"] = jnp.stack(
+            by_name["shared_gate_mean"]).mean()
     return out
 
 
-def _mixer_stats(sown: dict, scan_fused: Optional[bool] = None) -> dict:
+def _mixer_stats(sown: dict, scan_fused: Optional[bool] = None,
+                 delta_fused: Optional[bool] = None) -> dict:
     """A stack's step scalars from what its mixers and layers sow into
     ``mixer_stats``. SambaY's: the largest magnitude in a state-space
     layer's state at a row's end, the least and the largest differential
     lambda over the attention layers, and whether the scan runs its kernel
     (``scan_fused``). ZAYA's: the largest key temperature, and the least and
-    the largest of the scales on the residual sums' two sides."""
+    the largest of the scales on the residual sums' two sides. Qwen3-Next's:
+    whether the gated delta rule runs its kernel (``delta_fused``), the
+    largest magnitude in a linear-attention layer's state at a row's end, the
+    least ``exp(g)`` a token and head saw, the mean write strength, and the
+    mean of the attention layers' output gate."""
     by_name = _sown_by_name(sown)
     out = {}
     if scan_fused is not None:
         out["ssm_scan_fused"] = jnp.float32(scan_fused)
+    if delta_fused is not None:
+        out["delta_fused"] = jnp.float32(delta_fused)
+    for name, over in (("delta_state_abs_max", jnp.max),
+                       ("delta_decay_min", jnp.min),
+                       ("delta_beta_mean", jnp.mean)):
+        if name in by_name:
+            out[name] = over(jnp.stack(by_name[name]))
+    if "attn_gate" in by_name:
+        out["attn_gate_mean"] = jnp.stack(by_name["attn_gate"]).mean()
     if "ssm_state_abs_max" in by_name:
         out["ssm_state_abs_max"] = jnp.stack(
             by_name["ssm_state_abs_max"]).max()
@@ -441,7 +466,8 @@ def _layer_span(span: Optional[str], ctor) -> dict:
     if not ctor.keywords.get("layer_kinds"):
         raise ValueError("layer_span states which published layers of a "
                          "preset with layers of several kinds are held "
-                         "(phi4_mini_flash*); --num_layers cuts the others")
+                         "(phi4_mini_flash*, qwen3_next_*); --num_layers "
+                         "cuts the others")
     try:
         first, end = (int(x) for x in span.split(":"))
     except ValueError:
@@ -497,8 +523,8 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                     expert_share: Optional[str] = None,
                     layer_span: Optional[str] = None) -> Task:
     """Decoder-only next-token prediction (the GPT presets on the encoder
-    trunk, the OLMoE, Moonlight, Phi-4-mini-flash and ZAYA1 presets on the
-    decoder stack) over the same packed
+    trunk, the OLMoE, Moonlight, Phi-4-mini-flash, ZAYA1 and Qwen3-Next
+    presets on the decoder stack) over the same packed
     token columns as masked-LM (``create_text_token_dataset``) — the text arm
     beyond the reference's vision-only scope, sharing the trainer, samplers
     and storage unchanged. The shift by one token is applied to the targets
@@ -522,7 +548,7 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
     if expert_share is not None and not dropless:
         raise ValueError("expert_share states which of a dropless "
                          "preset's experts are held (olmoe_*, "
-                         "moonlight_*, zaya*)")
+                         "moonlight_*, zaya*, qwen3_next_*)")
     if decoder:
         if num_experts:
             raise ValueError(
@@ -542,6 +568,11 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
 
         scans = model.scan_shape  # a span that cannot run is refused here
         scan_fused = bool(scans) and scan_fused_applies(seq_len, *scans)
+    delta_fused = None
+    if decoder and model.delta_shape:
+        from ..ops.delta import delta_fused_applies
+
+        delta_fused = delta_fused_applies(seq_len, *model.delta_shape)
     sows = ((["aux_loss", "moe_stats", "router_state"] if dropless
              else ["aux_loss"] if num_experts > 0 else [])
             + ["mixer_stats"] * kinds)
@@ -581,7 +612,8 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
             if dropless:
                 stats.update(_expert_load(sown["moe_stats"]))
             if kinds:
-                stats.update(_mixer_stats(sown["mixer_stats"], scan_fused))
+                stats.update(_mixer_stats(sown["mixer_stats"], scan_fused,
+                                          delta_fused))
             # the bias as the routers left it: the step's new state
             state = ({"batch_stats": sown["router_state"]}
                      if "router_state" in sown else None)
@@ -812,8 +844,9 @@ def get_task(
 ) -> Task:
     """``vocab_size=None`` means "the model's own default" (bert_*: 30522,
     gpt_*: 50257, olmoe_1b_7b: 50304, moonlight_16b_a3b: 163840,
-    phi4_mini_flash: 200064, zaya1_8b: 262272, olmoe_tiny, moonlight_tiny,
-    phi4_mini_flash_tiny and zaya_tiny: 512, clip_tiny: 1000,
+    phi4_mini_flash: 200064, zaya1_8b: 262272, qwen3_next_80b_a3b: 151936,
+    olmoe_tiny, moonlight_tiny, phi4_mini_flash_tiny, zaya_tiny and
+    qwen3_next_tiny: 512, clip_tiny: 1000,
     clip_resnet50_bert: 30522);
     explicit values always apply verbatim.
     ``param_dtype`` overrides the parameter/optimizer-state dtype (ResNet
@@ -823,7 +856,8 @@ def get_task(
     and ``expert_share`` (``"rank/ranks"``, the dropless causal_lm presets)
     the experts of each layer that this rank of an expert-parallel job
     holds; ``layer_span`` (``"first:end"``, the presets whose layers differ
-    by kind: phi4_mini_flash*) the published layers a pipeline stage holds;
+    by kind: phi4_mini_flash*, qwen3_next_*) the published layers a pipeline
+    stage holds;
     with ``vocab_size`` as its slice of the vocabulary that is the share a
     configuration states."""
     if expert_share is not None and task_type != "causal_lm":
@@ -831,7 +865,8 @@ def get_task(
                          "with dropless expert layers")
     if layer_span is not None and task_type != "causal_lm":
         raise ValueError("layer_span applies to the causal_lm presets whose "
-                         "layers differ by kind (phi4_mini_flash*)")
+                         "layers differ by kind (phi4_mini_flash*, "
+                         "qwen3_next_*)")
     if num_layers and (task_type not in ("masked_lm", "causal_lm")
                        or pipeline_parallelism > 1):
         raise ValueError(

@@ -26,8 +26,9 @@ __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "bert_small", "gpt_base", "gpt_small", "olmoe_1b_7b",
            "olmoe_tiny", "moonlight_16b_a3b", "moonlight_tiny",
            "phi4_mini_flash", "phi4_mini_flash_tiny", "sambay_layers",
-           "zaya1_8b", "zaya_tiny", "dot_product_attention", "RMSNorm",
-           "rotary_embedding"]
+           "zaya1_8b", "zaya_tiny", "qwen3_next_80b_a3b", "qwen3_next_tiny",
+           "qwen3_next_layers", "dot_product_attention", "RMSNorm",
+           "rotary_embedding", "causal_depthwise_conv"]
 
 
 def dot_product_attention(q, k, v, mask=None, dtype=jnp.bfloat16,
@@ -87,18 +88,38 @@ def _attention_masks(attention_mask, segment_ids, attention_fn):
 
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis: statistics
-    in f32, result in ``dtype``, a learned f32 scale and no bias."""
+    in f32, result in ``dtype``, a learned f32 scale and no bias. With
+    ``unit_offset`` the scale is ``1 + w`` with ``w`` learned from 0 (the
+    Qwen3-Next family's form: weight decay then pulls the scale to 1)."""
 
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones_init(),
-                           (x.shape[-1],), jnp.float32)
+        if self.unit_offset:
+            scale = 1.0 + self.param("scale", nn.initializers.zeros_init(),
+                                     (x.shape[-1],), jnp.float32)
+        else:
+            scale = self.param("scale", nn.initializers.ones_init(),
+                               (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
+
+
+def causal_depthwise_conv(x, taps, bias=None):
+    """A depthwise causal convolution along the sequence: ``x`` [B, S, D],
+    ``taps`` [K, D] f32 with ``taps[K - 1]`` this token's and ``taps[0]``
+    the token ``K - 1`` before (a ``Conv1d`` with ``groups = D`` and ``K -
+    1`` zeros before the row), ``bias`` [D] or None; the sums in f32, [B, S,
+    D] f32. Nothing here knows where a document ends inside a row."""
+    k = taps.shape[0]
+    back = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(back[:, j:j + x.shape[1]].astype(jnp.float32) * taps[j]
+            for j in range(k))
+    return y if bias is None else y + bias
 
 
 def rotary_embedding(x, positions, theta: float, width: int = 0):
@@ -459,10 +480,8 @@ class MambaMixer(nn.Module):
                 (self.conv, self.inner), jnp.float32)
             bias = self.param("conv_bias", nn.initializers.zeros_init(),
                               (self.inner,), jnp.float32)
-            back = jnp.pad(x, ((0, 0), (self.conv - 1, 0), (0, 0)))
-            x = nn.silu(sum(
-                back[:, k:k + x.shape[1]].astype(jnp.float32) * taps[k]
-                for k in range(self.conv)) + bias).astype(self.dtype)
+            x = nn.silu(causal_depthwise_conv(x, taps, bias)).astype(
+                self.dtype)
         with jax.named_scope("ssm.project"):
             dbc = dense(r + 2 * n, name="x_proj", dot_general=f32_out)(x)
             dt = jax.nn.softplus(dense(
@@ -509,6 +528,168 @@ class GatedMemoryUnit(nn.Module):
                         param_dtype=jnp.float32, kernel_init=self.kernel_init)
         gate = nn.silu(dense(memory.shape[-1], name="in_proj")(u))
         return dense(u.shape[-1], name="out_proj")(memory * gate)
+
+
+class GatedDeltaNet(nn.Module):
+    """Qwen3-Next's linear-attention mixer (Gated Delta Networks,
+    arXiv:2412.06464), ``key_heads`` key heads of ``key_dim`` serving
+    ``value_heads`` value heads of ``value_dim`` (key head ``j`` the value
+    heads ``j * value_heads / key_heads`` onwards):
+
+        [q~; k~; v~; z] = u W_qkvz          [b; a] = u W_ba
+        [q; k; v] = silu(conv([q~; k~; v~]))    depthwise, causal, no bias
+        beta = sigmoid(b)     g = -exp(A_log) * softplus(a + dt_bias)
+        q <- q / sqrt(|q|^2 + 1e-6) / sqrt(key_dim),  k <- k / sqrt(|k|^2
+        + 1e-6)                                          a head
+        o = the gated delta rule over (q, k, v, g, beta)  (:mod:`..ops.delta`)
+        out = (rmsnorm(o) * w_n * silu(z)) W_out         a value head
+
+    The fused projection is laid out ``[q; k; v; z]`` (the published
+    checkpoint interleaves it by key head: a loader would permute). ``g``,
+    ``beta``, the L2 norms, the convolution's sums and the gated norm in
+    float32, the products' operands in ``dtype``. No biases. What lies ahead
+    of the output projection is made again in the backward pass from the
+    normed stream and the rule's output. Nothing here knows where a document
+    ends inside a row: the convolution and the state run across it (ROADMAP
+    R4)."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv: int
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, u):
+        from jax.ad_checkpoint import checkpoint_name
+
+        from ..ops.delta import gated_delta_rule
+
+        b, s, h = u.shape
+        hk, hv, dk, dv = (self.key_heads, self.value_heads, self.key_dim,
+                          self.value_dim)
+        keys, values = hk * dk, hv * dv
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=jnp.float32, kernel_init=self.kernel_init)
+        w_qkvz = self.param("in_proj_qkvz", self.kernel_init,
+                            (h, 2 * keys + 2 * values), jnp.float32)
+        w_ba = self.param("in_proj_ba", self.kernel_init, (h, 2 * hv),
+                          jnp.float32)
+        taps = self.param("conv_kernel", self.kernel_init,
+                          (self.conv, 2 * keys + values), jnp.float32)
+        a_log = self.param(
+            "A_log", lambda key, shape, dtype: jnp.log(jax.random.uniform(
+                key, shape, dtype, 1e-3, 16.0)), (hv,), jnp.float32)
+        dt_bias = self.param("dt_bias", nn.initializers.ones_init(), (hv,),
+                             jnp.float32)
+        scale = self.param("norm_scale", nn.initializers.ones_init(), (dv,),
+                           jnp.float32)
+
+        def unit(t):  # [B, S, heads * dk] -> [B, S, heads, dk], |t| = 1
+            t = t.reshape(b, s, hk, dk).astype(jnp.float32)
+            return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+        # Ahead of the output projection everything but the rule is a
+        # product of 12,288 columns or elementwise over [S, 8,192] and [S,
+        # 4,096], much of it in f32: the backward pass makes it again from
+        # the normed stream and the rule's output and keeps nothing else
+        # (0.8 GiB a layer and row of 8,192 tokens, for one more product).
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     "gdn_rule_out"))
+        def mix(u, w_qkvz, w_ba, taps, a_log, dt_bias, scale):
+            with jax.named_scope("gdn.project"):
+                qkvz = jnp.dot(u, w_qkvz.astype(self.dtype))
+                ba = jnp.dot(u, w_ba.astype(self.dtype),
+                             preferred_element_type=jnp.float32)
+            with jax.named_scope("gdn.conv"):
+                mixed = nn.silu(causal_depthwise_conv(
+                    qkvz[..., :2 * keys + values], taps)).astype(self.dtype)
+            with jax.named_scope("gdn.gates"):
+                beta = nn.sigmoid(ba[..., :hv])
+                g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
+                q = (unit(mixed[..., :keys]) * dk ** -0.5).astype(self.dtype)
+                k = unit(mixed[..., keys:2 * keys]).astype(self.dtype)
+                v = mixed[..., 2 * keys:].reshape(b, s, hv, dv)
+            with jax.named_scope("gdn.kernel"):
+                o, last = gated_delta_rule(q, k, v, g, beta)
+            o = checkpoint_name(o, "gdn_rule_out")
+            with jax.named_scope("gdn.norm"):
+                o = o.astype(jnp.float32)
+                o = o * jax.lax.rsqrt(
+                    jnp.mean(o * o, -1, keepdims=True) + self.norm_eps
+                ) * scale
+                z = qkvz[..., 2 * keys + values:].reshape(b, s, hv, dv)
+                gated = (o * nn.silu(z.astype(jnp.float32))).astype(
+                    self.dtype).reshape(b, s, values)
+            return gated, (jnp.abs(last).max(), jnp.exp(g.min()),
+                           beta.mean())
+
+        gated, (state_max, decay_min, beta_mean) = mix(
+            u.astype(self.dtype), w_qkvz, w_ba, taps, a_log, dt_bias, scale)
+        self.sow("mixer_stats", "delta_state_abs_max", state_max)
+        self.sow("mixer_stats", "delta_decay_min", decay_min)
+        self.sow("mixer_stats", "delta_beta_mean", beta_mean)
+        with jax.named_scope("gdn.project"):
+            return dense(h, name="out_proj")(gated)
+
+
+class GatedAttention(nn.Module):
+    """Qwen3-Next's softmax-attention mixer: ``num_heads`` query heads over
+    ``kv_heads`` key and value heads, all ``head_dim`` wide (which is not
+    ``hidden / num_heads``), a ``1 + w`` RMSNorm over each head's queries
+    and keys (one scale a head width, all heads), rotary positions on the
+    first ``rotary_dim`` elements of a head, and the attention output times
+    ``sigmoid(gate)``, the gate from the second half of a query projection
+    twice as wide (a head's ``[q; gate]`` side by side). No biases."""
+
+    num_heads: int
+    kv_heads: int
+    head_dim: int
+    rotary_dim: int
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
+        b, s, h = x.shape
+        n, g, d = self.num_heads, self.kv_heads, self.head_dim
+        dense = partial(nn.DenseGeneral, dtype=self.dtype,
+                        param_dtype=jnp.float32, use_bias=False,
+                        kernel_init=self.kernel_init)
+        norm = partial(RMSNorm, self.norm_eps, self.dtype, True)
+        with jax.named_scope("attn.project"):
+            q_gate = dense(features=(n, 2 * d), name="query")(x)
+            k = dense(features=(g, d), name="key")(x)
+            v = dense(features=(g, d), name="value")(x)
+            pos = jnp.arange(s) if position_ids is None else position_ids
+            q = rotary_embedding(norm(name="q_norm")(q_gate[..., :d]), pos,
+                                 self.rope_theta, self.rotary_dim)
+            k = rotary_embedding(norm(name="k_norm")(k), pos,
+                                 self.rope_theta, self.rotary_dim)
+            # [B, S, H, D] -> [B, H, S, D]
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        attn = self.attention_fn
+        if attn is None:  # dense off a TPU; knows grouped heads
+            from ..ops.flash import make_flash_attention
+
+            attn = make_flash_attention(causal=True, forced=False)
+        kwargs = {} if segment_ids is None else {"segment_ids": segment_ids}
+        with jax.named_scope("attn.kernel"):
+            out = attn(q, k, v, mask=mask, **kwargs)
+        with jax.named_scope("attn.gate"):
+            gate = nn.sigmoid(q_gate[..., d:].astype(jnp.float32))
+            self.sow("mixer_stats", "attn_gate", gate.mean())
+            out = (out.transpose(0, 2, 1, 3).astype(jnp.float32) * gate
+                   ).astype(self.dtype)
+        with jax.named_scope("attn.project"):
+            return dense(features=h, axis=(-2, -1), name="out")(out)
 
 
 class EncoderBlock(nn.Module):
@@ -640,7 +821,10 @@ class DecoderBlock(nn.Module):
     layer (arXiv:2511.17127, which ``cca`` sizes): a
     :class:`ConvolutionalAttention`, an expert layer whose router is a
     :class:`..moe.StateRouter` with a state handed from layer to layer, and
-    learned scales and shifts on both sides of both residual sums.
+    learned scales and shifts on both sides of both residual sums; or one
+    of Qwen3-Next's two (``delta`` and ``gated`` size them): ``"D"`` a
+    :class:`GatedDeltaNet`, ``"A"`` a :class:`GatedAttention`, both under
+    RMSNorm in its ``1 + w`` form (``norm_offset``).
     ``dense_dim`` is the feed-forward (0: the dropless expert layer that
     ``moe`` describes; > 0: a dense SwiGLU of that width), ``layer_norm`` the
     norm (LayerNorm, else RMSNorm). The call takes and returns, beside ``x``,
@@ -664,6 +848,9 @@ class DecoderBlock(nn.Module):
     hybrid: tuple = ()  # (kv_heads, window, inner, states, conv, dt_rank)
     layer_norm: bool = False
     cca: tuple = ()  # (kv_heads, head_dim, rotary_dim, router_dim)
+    delta: tuple = ()  # (key_heads, value_heads, key_dim, value_dim, conv)
+    gated: tuple = ()  # (kv_heads, head_dim, rotary_dim)
+    norm_offset: bool = False  # RMSNorm's scale is 1 + w
 
     def _hybrid_mixer(self, y, mask, segment_ids, handed, init):
         """``(mixer's output, what is handed on)`` of a SambaY layer."""
@@ -704,13 +891,23 @@ class DecoderBlock(nn.Module):
                  live=None, handed=(None, None, None)):
         from .moe import DroplessMoE, StateRouter, SwiGLU
 
-        norm = partial(RMSNorm, self.norm_eps, self.dtype)
+        norm = partial(RMSNorm, self.norm_eps, self.dtype, self.norm_offset)
         if self.layer_norm:
             norm = partial(nn.LayerNorm, epsilon=self.norm_eps,
                            dtype=self.dtype, param_dtype=jnp.float32)
         init = nn.initializers.truncated_normal(self.init_std)
         y = norm(name="ln_attn")(x)
-        if self.kind == "C":
+        if self.kind == "D":
+            with jax.named_scope("linear_attention"):
+                y = GatedDeltaNet(*self.delta, self.norm_eps, self.dtype,
+                                  init, name="gdn")(y)
+        elif self.kind == "A":
+            with jax.named_scope("attention"):
+                y = GatedAttention(
+                    self.num_heads, *self.gated, self.norm_eps,
+                    self.rope_theta, self.dtype, self.attention_fn, init,
+                    name="attn")(y, mask, segment_ids, position_ids)
+        elif self.kind == "C":
             with jax.named_scope("attention"):
                 y = ConvolutionalAttention(
                     self.num_heads, *self.cca[:3], self.rope_theta,
@@ -762,7 +959,9 @@ class TransformerDecoder(nn.Module):
     A stack with ``layer_kinds`` names every published layer's kind:
     SambaY's (Phi-4-mini-flash) differ by layer, under LayerNorm
     (``layer_norm``), with no position term at all; ZAYA1's are all ``"C"``,
-    under RMSNorm. Both tie the head to the embedding (``tied_head``).
+    under RMSNorm; Qwen3-Next's are three ``"D"`` to one ``"A"``, under
+    RMSNorm's ``1 + w`` form, with a head of its own. SambaY's and ZAYA1's
+    tie the head to the embedding (``tied_head``).
     ``first_layer`` says which published layers are held (``num_layers`` of
     them from there: one pipeline stage's), and what a layer hands on (M*'s
     and F*'s tensors, a router's state) rides from layer to layer beside
@@ -792,6 +991,9 @@ class TransformerDecoder(nn.Module):
     first_layer: int = 0  # published index of the first layer held here
     hybrid: tuple = ()  # DecoderBlock's, for every SambaY layer
     cca: tuple = ()  # DecoderBlock's, for every "C" layer
+    delta: tuple = ()  # DecoderBlock's, for every "D" layer
+    gated: tuple = ()  # DecoderBlock's, for every "A" layer
+    norm_offset: bool = False  # RMSNorm's scale is 1 + w, everywhere
     layer_norm: bool = False  # LayerNorm in place of RMSNorm, everywhere
     tied_head: bool = False  # the head is the embedding, no matrix of its own
 
@@ -824,6 +1026,8 @@ class TransformerDecoder(nn.Module):
         by layer. Empty for a span without attention."""
         if self.cca:
             return ((self.cca[1],) * 2,)
+        if self.gated:
+            return ((self.gated[1],) * 2,) if "A" in self.held_kinds else ()
         if self.layer_kinds:
             d = self.hidden_size // self.num_heads
             return ((d, 2 * d),) if set(self.held_kinds) & {
@@ -839,6 +1043,16 @@ class TransformerDecoder(nn.Module):
         None for a stack that holds no state-space layer."""
         if set(self.held_kinds) & {"M", "M*"}:
             return tuple(self.hybrid[2:4])
+        return None
+
+    @property
+    def delta_shape(self) -> Optional[tuple]:
+        """``(value heads, key_dim, value_dim)`` of the gated delta rule the
+        held layers run (what :func:`..ops.delta.delta_fused_applies`
+        chooses its path by), or None for a stack that holds no such
+        layer."""
+        if "D" in self.held_kinds:
+            return tuple(self.delta[1:4])
         return None
 
     @property
@@ -872,13 +1086,15 @@ class TransformerDecoder(nn.Module):
                 dense_dim=self.dense_dim if i < self.dense_layers else 0,
                 moe=self.moe, kind=kind, depth=self.first_layer + i,
                 hybrid=self.hybrid, layer_norm=self.layer_norm,
-                cca=self.cca, name=f"layer_{i}")(
+                cca=self.cca, delta=self.delta, gated=self.gated,
+                norm_offset=self.norm_offset, name=f"layer_{i}")(
                     x, mask, seg_kwarg, position_ids, live, handed)
         if self.layer_norm:
             x = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
                              param_dtype=jnp.float32, name="ln_final")(x)
         else:
-            x = RMSNorm(self.norm_eps, self.dtype, name="ln_final")(x)
+            x = RMSNorm(self.norm_eps, self.dtype, self.norm_offset,
+                        name="ln_final")(x)
         with jax.named_scope("lm_head"):
             # bf16 operands on the matrix unit, f32 sums and f32 logits
             if self.tied_head:  # the held rows of the embedding
@@ -984,3 +1200,35 @@ zaya_tiny = partial(
     expert_dim=32, num_experts=8, experts_per_token=1, rope_theta=5000000.0,
     moe=_ZAYA_ROUTER, layer_kinds=("C",) * 3, cca=(2, 16, 8, 32),
     tied_head=True)
+
+
+def qwen3_next_layers(layers: int, interval: int = 4) -> tuple:
+    """Qwen3-Next's layout (``full_attention_interval``): layer ``i`` is
+    gated softmax attention (A) where ``(i + 1) % interval == 0`` and a Gated
+    DeltaNet (D) otherwise."""
+    return tuple("A" if (i + 1) % interval == 0 else "D"
+                 for i in range(layers))
+
+
+# Qwen3-Next-80B-A3B (Qwen/Qwen3-Next-80B-A3B-Instruct config.json, model_type
+# qwen3_next; Gated Delta Networks, arXiv:2412.06464, for the recurrence): 48
+# layers, three Gated DeltaNets (16 key heads serving 32 value heads of 128,
+# a causal convolution over 4) to one gated attention layer (16 query heads
+# over 2 key/value heads of 256, rotary on a quarter of a head, theta 1e7);
+# every layer 512 SwiGLU experts of 512 with 10 a token by softmax scores
+# renormalised over the chosen, beside one shared expert of 512 under a
+# sigmoid gate; RMSNorm 1e-6 with a scale of 1 + w; the head untied.
+_QWEN3_NEXT_EXPERTS = (("norm_topk", True), ("shared_gate", True))
+qwen3_next_80b_a3b = partial(
+    TransformerDecoder, hidden_size=2048, num_layers=48, num_heads=16,
+    expert_dim=512, num_experts=512, experts_per_token=10, norm_eps=1e-6,
+    rope_theta=10000000.0,
+    moe=_QWEN3_NEXT_EXPERTS + (("shared_dim", 512),),
+    layer_kinds=qwen3_next_layers(48), delta=(16, 32, 128, 128, 4),
+    gated=(2, 256, 64), norm_offset=True)
+qwen3_next_tiny = partial(
+    TransformerDecoder, hidden_size=64, num_layers=4, num_heads=4,
+    expert_dim=32, num_experts=64, experts_per_token=4, norm_eps=1e-6,
+    rope_theta=10000000.0, moe=_QWEN3_NEXT_EXPERTS + (("shared_dim", 32),),
+    layer_kinds=qwen3_next_layers(4), delta=(2, 4, 16, 16, 4),
+    gated=(2, 32, 8), norm_offset=True)

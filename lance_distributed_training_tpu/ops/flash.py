@@ -356,6 +356,12 @@ _TIMED = {
     # 4.58 and the library's blocked kernel on repeated keys 5.29 to 5.79)
     _Shape(8192, 128, 128, 8, True, 0): SplashTiling(
         (1024, 1024, 512), (1024, 1024, 512), (1024, 1024)),
+    # Qwen3-Next's gated attention: 16 query heads over 2 key and value
+    # heads, all 256 wide (17.09 ms a layer where square 512s take 18.71 and
+    # the library's blocked kernel on repeated keys 20.25; twice the VMEM a
+    # block of 128-wide heads: Mosaic refuses every block of 2,048)
+    _Shape(8192, 256, 256, 16, True, 0): SplashTiling(
+        (1024, 1024, 256), (1024, 1024, 1024), (1024, 1024)),
 }
 
 
@@ -491,7 +497,9 @@ def fused_attention_applies(seq: int, head_dim: int, mesh=None,
     in 40 query heads over 20 key heads, causal, in a window of 512 or over
     F*'s keys; and ``unequal_attention`` again for heads in groups whose
     values are as wide as their keys: compressed convolutional attention's 8
-    query heads over 2 key and value heads of 128, causal, at 8,192 tokens.
+    query heads over 2 key and value heads of 128, and Qwen3-Next's gated
+    attention's 16 query heads over 2 key and value heads of 256, both
+    causal, at 8,192 tokens.
     Everything else is dense attention, as before: the CPU, a
     ViT's 197 tokens, a tensor-parallel mesh, and several devices with no
     mesh to say how the batch is split."""

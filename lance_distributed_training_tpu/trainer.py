@@ -194,7 +194,7 @@ class TrainConfig:
     # share of a stated deployment that this chip runs. None: all of them
     layer_span: Optional[str] = None  # "first:end": the published layers
     # [first, end) that this pipeline stage holds of a preset whose layers
-    # differ by kind (phi4_mini_flash*); None: all of them
+    # differ by kind (phi4_mini_flash*, qwen3_next_*); None: all of them
     prefetch: int = 2
     producer_threads: int = 4  # decode-producer threads
     placement_depth: int = 2  # device-resident batches the placement ring
@@ -401,6 +401,19 @@ def _scan_path(task: Task, config: TrainConfig) -> Optional[str]:
     from .ops.scan import scan_fused_applies
 
     return ("fused kernel" if scan_fused_applies(config.seq_len, *scans)
+            else "chunked")
+
+
+def _delta_path(task: Task, config: TrainConfig) -> Optional[str]:
+    """How a stack's linear-attention layers run the gated delta rule at
+    ``seq_len`` (by ``ops.delta.delta_fused_applies``, the test each call
+    makes), None for a model that holds none."""
+    shape = getattr(task.model, "delta_shape", None)
+    if not shape:
+        return None
+    from .ops.delta import delta_fused_applies
+
+    return ("fused kernel" if delta_fused_applies(config.seq_len, *shape)
             else "chunked")
 
 
@@ -1550,6 +1563,9 @@ def _train(config: TrainConfig) -> dict:
         scan_path = _scan_path(task, config)
         if scan_path:
             start_line["scan"] = scan_path
+        delta_path = _delta_path(task, config)
+        if delta_path:
+            start_line["delta"] = delta_path
         logger.log(start_line, to_wandb=False)
         if config.metrics_port is not None and jax.process_index() == 0:
             from .obs.http import MetricsHTTPServer

@@ -51,6 +51,8 @@ SHAPES = {
     # heads as wide in values as in keys, in groups: the library's blocked
     # kernel with the keys and values repeated is timed beside the splash ones
     "zaya": dict(q=(8, 128), k=(2, 128), v=(2, 128), window=0),
+    # Qwen3-Next's gated attention: heads of 256, twice the VMEM a block
+    "qwen3_next": dict(q=(16, 256), k=(2, 256), v=(2, 256), window=0),
 }
 KERNELS = ("fwd", "dkv", "dq")
 CALLS = 20  # after one call of warm-up

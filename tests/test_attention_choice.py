@@ -320,6 +320,56 @@ def test_unequal_attention_compiles_for_a_v5e_at_zaya1s_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_unequal_attention_compiles_for_a_v5e_at_qwen3_nexts_widths(
+        one_chip):
+    """Gated attention's heads as the Qwen3-Next cell runs them: 16 query
+    heads over 2 key and value heads, all 256 wide, 8,192 tokens, causal, at
+    the tiling timed for the shape: the three kernels, and no ``[B, H, S,
+    S]`` tensor."""
+    def spec(heads):
+        return jax.ShapeDtypeStruct((1, heads, 8192, 256), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    tiling, timed = flash.splash_tiling(8192, 256, 256, 16, True)
+    assert timed and tiling.dq is not None
+
+    def loss(q, k, v):
+        out = flash.unequal_attention(q, k, v, causal=True)
+        assert out.shape == (1, 16, 8192, 256)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec(16), spec(2), spec(2)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "8192,8192]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_the_delta_rules_kernel_compiles_for_a_v5e_at_qwen3_nexts_widths(
+        one_chip):
+    """One group of the Gated DeltaNet's heads as the Qwen3-Next cell runs
+    them (4 key heads serving 8 value heads of 128, a row of 8,192 tokens in
+    128 chunks of 64), forward and backward: the two kernels, under a quarter
+    of the 3.5 GiB that all 32 heads' preparation would hold at once."""
+    from lance_distributed_training_tpu.ops import delta
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        o, last = delta.delta_kernel(q, k, v, g, beta)
+        assert o.shape == (1, 8192, 8, 128) and last.shape == (1, 8, 128, 128)
+        return o.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
+        spec(1, 8192, 4, 128), spec(1, 8192, 4, 128), spec(1, 8192, 8, 128),
+        spec(1, 8192, 8, dtype=jnp.float32),
+        spec(1, 8192, 8, dtype=jnp.float32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 7 << 27
+
+
 @pytest.mark.parametrize("rows,seq,hidden,vocab,tied", [
     (1, 8192, 2560, 25008, True),  # c4-phi4flash-vp8-prepacked-8k's head
     (2, 4096, 2048, 50304, False),  # c4-olmoe-prepacked-4k's
@@ -566,35 +616,41 @@ def test_heads_of_equal_width_never_reach_the_splash_kernel(
 # -- heads in groups, as wide in values as in keys ----------------------------
 
 
+@pytest.mark.parametrize("heads,width", [(8, 128), (16, 256)],
+                         ids=["zaya1", "qwen3_next"])
 @pytest.mark.parametrize("platform,kernel", [("tpu", True), ("cpu", False)])
 def test_grouped_heads_of_equal_width_take_the_kernel_on_a_tpu_alone(
-        monkeypatch, platform, kernel):
+        monkeypatch, platform, kernel, heads, width):
     """8 query heads over 2 key and value heads of 128 at 8,192 tokens (ZAYA1's
-    cell): the rule says kernel from the shapes, the call obeys it with the
-    splash kernels (this shape raised ``NotImplementedError`` before PR 38),
-    and off the chip the same call is dense attention over repeated heads."""
+    cell), 16 over 2 of 256 (Qwen3-Next's): the rule says kernel from the
+    shapes, the call obeys it with the splash kernels (this shape raised
+    ``NotImplementedError`` before PR 38), and off the chip the same call is
+    dense attention over repeated heads."""
     monkeypatch.setattr(jax, "default_backend", lambda: platform)
     attention = flash.make_flash_attention(
         causal=True, mesh=_meshes()["one device"], forced=False)
-    assert attention.fused(8192, 128, 128) is kernel
-    q = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.bfloat16)
+    assert attention.fused(8192, width, width) is kernel
+    q = jax.ShapeDtypeStruct((1, heads, 8192, width), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 2, 8192, width), jnp.bfloat16)
     traced = str(jax.make_jaxpr(attention)(q, kv, kv))
     assert ("pallas_call" in traced and "splash" in traced) is kernel
     assert jax.eval_shape(attention, q, kv, kv).shape == q.shape
 
 
-def test_grouped_heads_of_equal_width_in_the_kernel_equal_dense(monkeypatch):
+@pytest.mark.parametrize("width", [128, 256])
+def test_grouped_heads_of_equal_width_in_the_kernel_equal_dense(monkeypatch,
+                                                                width):
     """The call the rule makes on a TPU, in interpret mode, against dense
     attention over repeated keys and values: forward and gradients, with a
-    key-validity mask (the last row's tail is padding)."""
+    key-validity mask (the last row's tail is padding); heads of 128
+    (ZAYA1's) and of 256 (Qwen3-Next's)."""
     from jax.experimental.pallas import tpu as pltpu
 
     seq = 256
     keys = jax.random.split(jax.random.key(38), 4)
-    q = jax.random.normal(keys[0], (2, 4, seq, 128), jnp.float32)
-    k = jax.random.normal(keys[1], (2, 2, seq, 128), jnp.float32)
-    v = jax.random.normal(keys[2], (2, 2, seq, 128), jnp.float32)
+    q = jax.random.normal(keys[0], (2, 4, seq, width), jnp.float32)
+    k = jax.random.normal(keys[1], (2, 2, seq, width), jnp.float32)
+    v = jax.random.normal(keys[2], (2, 2, seq, width), jnp.float32)
     w = jax.random.normal(keys[3], q.shape, jnp.float32)
     valid = jnp.arange(seq)[None, :] < jnp.asarray([seq, seq - 40])[:, None]
     mask = valid[:, None, None, :]

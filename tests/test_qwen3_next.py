@@ -1,0 +1,858 @@
+"""Qwen3-Next-80B-A3B's decoder layers (three Gated DeltaNets to one gated
+attention layer, every layer top-k experts beside a gated shared one, under
+RMSNorm's ``1 + w`` form; here ``qwen3_next_tiny``: 4 layers, 2 key heads
+serving 4 value heads of 16, 4 query heads over 2 key/value heads of 32 with
+rotary on 8, 64 experts of 32 with 4 a token) against the plain float32
+reference the benchmark keeps in ``benchmark/reference/
+qwen3-next-80b-a3b-c4.py``, on seeded weights, on the CPU.
+
+*Is the reference the model?* Where ``torch`` and ``transformers`` import,
+the reference's three sub-layers equal the published ``Qwen3NextGatedDeltaNet``,
+``Qwen3NextAttention`` and ``Qwen3NextSparseMoeBlock`` on copied weights.
+*Is the program's mathematics the reference's?* The program computed in
+float32 against the reference, whole and under a share of the experts:
+logits, loss and every parameter group's gradient to ``F32_TOL``. *Does the
+share add up?* The four quarters' routed parts and the shared expert, once,
+are the uncut layer. Then what only these layers have: the rule's chunked
+form, its recurrence and its kernel (interpret mode) agree in values and
+gradients; nothing before token t moves when token t does; the rotary turn
+takes a quarter of a head; and the configuration's file holds the published
+widths and the parameters the program counts.
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from lance_distributed_training_tpu.models import get_task, tasks
+from lance_distributed_training_tpu.models.moe import DroplessMoE
+from lance_distributed_training_tpu.models.transformer import (
+    GatedAttention,
+    RMSNorm,
+    causal_depthwise_conv,
+    qwen3_next_tiny,
+    rotary_embedding,
+)
+from lance_distributed_training_tpu.ops import delta
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEQ, ROWS, VOCAB, EXPERTS, TOP_K = 96, 2, 512, 64, 4  # 96: a chunk and a half
+F32_TOL = 2e-4  # float32 against float32: summation order and grouping only
+GROUPS = ("router", "w_gate", "w_up", "w_down", "shared", "shared_gate",
+          "in_proj_qkvz", "in_proj_ba", "conv_kernel", "gates", "out_proj",
+          "query", "key", "value", "out", "scales", "tok_embed", "lm_head")
+SHARES = (None, "1/4")  # whole; experts 16..31 of 64
+
+
+def _load_reference(first: int = 0):
+    path = os.path.join(ROOT, "benchmark", "reference",
+                        "qwen3-next-80b-a3b-c4.py")
+    spec = importlib.util.spec_from_file_location(
+        f"qwen3_next_reference_{first}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    module.ROTARY, module.KEY_DIM, module.TOP_K = 8, 16, TOP_K
+    module.FIRST = first
+    return module
+
+
+@pytest.fixture(scope="module", params=SHARES, ids=["whole", "share"])
+def share(request):
+    return request.param
+
+
+@pytest.fixture(scope="module")
+def ref(share):
+    return _load_reference(first=16 if share else 0)
+
+
+def _task(share, **changes):
+    if not changes:
+        return get_task("causal_lm", model_name="qwen3_next_tiny",
+                        seq_len=SEQ, expert_share=share)
+    tasks._CAUSAL_LMS["qwen3_next_tiny_changed"] = (
+        functools.partial(qwen3_next_tiny, **changes), VOCAB,
+        tasks._QWEN3_NEXT_AUX)
+    try:
+        return get_task("causal_lm", model_name="qwen3_next_tiny_changed",
+                        seq_len=SEQ, expert_share=share)
+    finally:
+        del tasks._CAUSAL_LMS["qwen3_next_tiny_changed"]
+
+
+@pytest.fixture(scope="module")
+def f32_task(share):
+    return _task(share, dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def bf16_task(share):
+    return _task(share)
+
+
+@pytest.fixture(scope="module")
+def variables(ref, bf16_task):
+    """Seeded, perturbed as the benchmark's check perturbs them, and with
+    every expert's last matrix 32 times as large: at these widths an expert
+    adds a few percent of the stream's scale where at the published ones (32
+    times as wide, same 0.02) it adds as much as the stream holds, and a
+    token that takes another expert has to show."""
+    variables = ref.perturb(
+        jax.jit(bf16_task.init_variables)(jax.random.key(3)),
+        jax.random.key(4))
+    return dict(variables, params=jax.tree_util.tree_map_with_path(
+        lambda path, x: 32 * x if path[-1].key == "w_down" else x,
+        variables["params"]))
+
+
+@pytest.fixture(scope="module")
+def batch():
+    ids = np.random.default_rng(5).integers(2, VOCAB, (ROWS, SEQ))
+    mask = np.ones((ROWS, SEQ), np.int8)
+    mask[-1, SEQ - 5:] = 0  # a padded tail: live tokens only in the losses
+    return {"input_ids": ids.astype(np.int32), "attention_mask": mask}
+
+
+def _groups(tree) -> dict:
+    """Parameter groups, layers together: the router, the held experts'
+    three, the shared expert and its gate, the linear-attention layers'
+    projections, taps and per-head gates (``A_log``, ``dt_bias``), the
+    attention layer's projections, every learned scale, the embedding and
+    the head."""
+    out: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        keys = [k.key for k in path if hasattr(k, "key")]
+        last = keys[-1]
+        if last.endswith("scale"):
+            name = "scales"
+        elif last in ("A_log", "dt_bias"):
+            name = "gates"
+        else:
+            name = next(k for k in (
+                "router", "w_gate", "w_up", "w_down", "shared_gate", "shared",
+                "in_proj_qkvz", "in_proj_ba", "conv_kernel", "out_proj",
+                "query", "key", "value", "out", "tok_embed", "lm_head")
+                if k in keys)
+        out.setdefault(name, []).append(jnp.ravel(leaf))
+    return {k: jnp.concatenate(v) for k, v in out.items()}
+
+
+def _relative(got, want) -> float:
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def _one_program(fn, *args):
+    """One jitted program, waited for (``tests/test_olmoe.py`` tells why)."""
+    return jax.block_until_ready(jax.jit(fn)(*args))
+
+
+def _reference(ref, variables, batch, dtype=None):
+    """``(logits, the tokens the comparison keeps)`` in one program, as
+    ``benchmark/run.py`` makes them (``live`` reads what ``forward`` noted
+    while it was traced)."""
+    def both(v):
+        want = ref.forward(v, batch, dtype=dtype)
+        return want, ref.live(batch, want)
+
+    return _one_program(both, variables)
+
+
+def _spread_error(got, want_and_live) -> float:
+    """The benchmark's statistic (``benchmark/run.py`` ``check_model``)."""
+    want, live = want_and_live
+    live = live[..., None]
+    n = live.sum() * want.shape[-1]
+    mean = jnp.where(live, want, 0).sum() / n
+    spread = jnp.sqrt(jnp.where(live, (want - mean) ** 2, 0).sum() / n)
+    return float(jnp.where(live, jnp.abs(got - want), 0).max() / spread)
+
+
+def _logits(task, variables, batch):
+    return _one_program(
+        lambda v: task.forward(v, batch, False, None)[0][0], variables)
+
+
+def _program_loss(task, batch):
+    def loss(v):
+        outputs, _ = task.forward(v, batch, True, None)
+        return task.loss(outputs, batch)
+
+    return loss
+
+
+# -- the mathematics, float32 against float32, whole and under a share -------
+
+
+@pytest.fixture(scope="module")
+def want(ref, variables, batch):
+    return _reference(ref, variables, batch)
+
+
+def test_logits_match_reference_in_float32(f32_task, variables, batch, want):
+    assert _spread_error(_logits(f32_task, variables, batch), want) < F32_TOL
+    assert 0.1 < float(want[1].mean()) < 1  # tokens stay to be compared
+
+
+@pytest.fixture(scope="module")
+def reference_loss_and_grads(ref, variables, batch):
+    loss, grads = _one_program(
+        jax.value_and_grad(lambda v: ref.loss(v, batch)), variables)
+    return loss, _groups(grads["params"])
+
+
+@pytest.fixture(scope="module")
+def reference_grads(reference_loss_and_grads):
+    return reference_loss_and_grads[1]
+
+
+def test_loss_matches_reference(f32_task, variables, batch,
+                                reference_loss_and_grads):
+    got = _one_program(_program_loss(f32_task, batch), variables)
+    want = reference_loss_and_grads[0]
+    assert abs(float(got) - float(want)) < F32_TOL * float(want)
+
+
+@pytest.fixture(scope="module")
+def f32_grads(f32_task, variables, batch):
+    grads = _one_program(jax.grad(_program_loss(f32_task, batch)), variables)
+    return _groups(grads["params"])
+
+
+@pytest.mark.parametrize("group", GROUPS)
+def test_gradient_matches_reference_in_float32(group, f32_grads,
+                                               reference_grads):
+    assert float(jnp.linalg.norm(reference_grads[group])) > 0
+    assert _relative(f32_grads[group], reference_grads[group]) < F32_TOL
+
+
+@pytest.fixture(scope="module")
+def rows_of_the_check():
+    """Eight rows for the two tests of ``TOLERANCE``: the statistic is a
+    maximum over the tokens that stay, a sixth of them where every expert is
+    held, and at this width a reading moves with any change in the order of
+    the arithmetic; the chip's readings at the published widths are what
+    ``TOLERANCE`` lies between (the reference's file has them)."""
+    ids = np.random.default_rng(5).integers(2, VOCAB, (8, SEQ))
+    return {"input_ids": ids.astype(np.int32),
+            "attention_mask": np.ones((8, SEQ), np.int8)}
+
+
+@pytest.fixture(scope="module")
+def want_of_the_check(ref, variables, rows_of_the_check):
+    return _reference(ref, variables, rows_of_the_check)
+
+
+def test_logits_of_the_program_as_it_runs(ref, bf16_task, variables,
+                                          rows_of_the_check,
+                                          want_of_the_check):
+    reading = _spread_error(_logits(bf16_task, variables, rows_of_the_check),
+                            want_of_the_check)
+    print(f"program in bf16 reads {reading:.3f}")
+    assert reading < ref.TOLERANCE
+
+
+def test_reference_in_the_precision_below_fails_the_benchmark_comparison(
+        ref, variables, rows_of_the_check, want_of_the_check):
+    """The reference with every tensor in bf16, and ``g``, ``beta``, the
+    rule's state, the router's logits and scores and the logits rounded to
+    bf16 where they stand, reads over ``TOLERANCE`` against itself in
+    float32 on the tokens the comparison keeps."""
+    low, _ = _reference(ref, variables, rows_of_the_check,
+                        dtype=jnp.bfloat16)
+    reading = _spread_error(low, want_of_the_check)
+    print(f"reference in bf16 reads {reading:.3f}")
+    assert reading > ref.TOLERANCE
+
+
+def test_a_training_step_reports_its_gauges(bf16_task, variables, batch,
+                                            share):
+    def step(v):
+        outputs, _ = bf16_task.forward(v, batch, True, None)
+        return bf16_task.stats(outputs)
+
+    stats = {k: float(v) for k, v in _one_program(step, variables).items()}
+    assert {"delta_fused", "delta_state_abs_max", "delta_decay_min",
+            "delta_beta_mean", "attn_gate_mean", "shared_gate_mean",
+            "moe_assignments_total"} <= set(stats)
+    assert stats["delta_fused"] == 0  # the CPU: the plain chunked form
+    assert stats["moe_assignments_total"] == 4 * ROWS * SEQ * TOP_K
+    assert 0 < stats["delta_decay_min"] < 0.5  # some head forgets fast
+    assert stats["delta_state_abs_max"] > 1e-3
+    for name in ("delta_beta_mean", "attn_gate_mean", "shared_gate_mean"):
+        assert 0.4 < stats[name] < 0.6  # sigmoids of small arguments
+    assert ("moe_local_fallback_total" in stats) is bool(share)
+
+
+def test_the_first_log_line_names_the_delta_path():
+    from lance_distributed_training_tpu import trainer
+
+    config = trainer.TrainConfig(
+        dataset_path="", task_type="causal_lm",
+        model_name="qwen3_next_tiny", seq_len=SEQ)
+    assert trainer._delta_path(_task(None), config) == "chunked"
+    config = trainer.TrainConfig(
+        dataset_path="", task_type="causal_lm", model_name="olmoe_tiny",
+        seq_len=SEQ)
+    olmoe = get_task("causal_lm", model_name="olmoe_tiny", seq_len=SEQ)
+    assert trainer._delta_path(olmoe, config) is None
+
+
+# -- the share ---------------------------------------------------------------
+
+
+def test_the_four_shares_add_up_to_the_uncut_reference_layer():
+    """The four ranks' routed parts, with the shared expert (which every
+    rank computes alike) counted once, are the whole expert layer as the
+    reference computes it uncut: all 64 experts on every token under the
+    top-4 mask, weighted by the scores over their sum."""
+    ref = _load_reference(first=0)
+    x = jax.random.normal(jax.random.key(0), (ROWS, SEQ, 64))
+
+    def layer(**kw):
+        return DroplessMoE(num_experts=EXPERTS, expert_dim=32,
+                           experts_per_token=TOP_K, dtype=jnp.float32,
+                           norm_topk=True, shared_dim=32, shared_gate=True,
+                           **kw)
+
+    whole = layer().init(jax.random.key(3), x)["params"]
+    whole = jax.tree.map(lambda w: 8 * w, whole)  # a router that decides
+    tokens = x.reshape(-1, 64)
+    want = _one_program(lambda p: ref._sparse_block(tokens, p)[0],
+                        whole).reshape(x.shape)
+    np.testing.assert_allclose(_one_program(
+        lambda p: layer().apply({"params": p}, x), whole), want,
+                               rtol=2e-5, atol=2e-4)
+    none_held = dict(whole, **{name: whole[name][:0]
+                               for name in ("w_gate", "w_up", "w_down")})
+    shared = _one_program(lambda p: ref._sparse_block(tokens, p)[0],
+                          none_held).reshape(x.shape)
+    assert float(jnp.abs(shared).max()) > 0.1
+    parts = []
+    for rank in range(4):
+        held = slice(16 * rank, 16 * rank + 16)
+        params = dict(whole, **{name: whole[name][held]
+                                for name in ("w_gate", "w_up", "w_down")})
+        parts.append(_one_program(
+            lambda p: layer(first_expert=16 * rank, held_experts=16).apply(
+                {"params": p}, x), params) - shared)
+        assert float(jnp.abs(parts[-1]).max()) > 0
+    np.testing.assert_allclose(sum(parts) + shared, want, rtol=2e-5,
+                               atol=2e-4)
+
+
+@pytest.mark.parametrize("crowded", [False, True],
+                         ids=["usual_list", "worst_case_in_pieces"])
+def test_a_sixteenth_held_builds_the_worst_case_in_pieces(crowded):
+    """4 of 64 experts held with 4 a token: twice an even share is an eighth
+    of the assignments, so the worst case (a step whose routing sends more
+    than that here) is the sorted list in eight pieces, not one array of
+    ``T * k`` rows. Both branches are the reference's held part, values and
+    gradients; a router that sends nearly every assignment here takes the
+    worst case."""
+    ref = _load_reference(first=0)
+    x = jax.random.normal(jax.random.key(0), (2, 128, 64))
+    layer = DroplessMoE(num_experts=EXPERTS, expert_dim=32,
+                        experts_per_token=TOP_K, dtype=jnp.float32,
+                        norm_topk=True, shared_dim=32, shared_gate=True,
+                        first_expert=0, held_experts=4)
+    params = layer.init(jax.random.key(3), x)["params"]
+    params = jax.tree.map(lambda w: 8 * w, params)
+    if crowded:  # every token's four largest logits are the held experts'
+        params["router"]["kernel"] = params["router"]["kernel"].at[:, :4].set(
+            jnp.abs(x).mean() * jnp.sign(x.reshape(-1, 64).mean(0))[:, None])
+        x = x + 2 * jnp.sign(x.reshape(-1, 64).mean(0))
+    ct = jax.random.normal(jax.random.key(5), x.shape)
+
+    def program(p, x):
+        y, sown = layer.apply({"params": p}, x, mutable=["moe_stats",
+                                                         "aux_loss"])
+        return (y * ct).sum(), (y, sown["moe_stats"])
+
+    def reference(p, x):
+        y = ref._sparse_block(x.reshape(-1, 64), p)[0].reshape(x.shape)
+        return (y * ct).sum(), y
+
+    (_, (got, stats)), g_got = _one_program(
+        jax.value_and_grad(program, argnums=(0, 1), has_aux=True), params, x)
+    (_, want), g_want = _one_program(
+        jax.value_and_grad(reference, argnums=(0, 1), has_aux=True), params,
+        x)
+    assert float(stats["over_usual"][0]) == float(crowded)
+    assert (float(stats["held_sizes"][0].sum()) > 128) is crowded
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-4)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        assert _relative(a, b) < F32_TOL
+    text = str(jax.make_jaxpr(lambda p, x: program(p, x)[0])(params, x))
+    assert "1024,64" not in text  # no array of T * k rows
+
+
+# -- ops/delta.py: recurrence, chunked form, kernel ---------------------------
+
+
+def _recurrence(q, k, v, g, beta):
+    """The rule a token at a time, written out here a second time: not the
+    reference's, not the program's."""
+    rep = v.shape[2] // q.shape[2]
+    q, k = (jnp.repeat(t, rep, axis=2) for t in (q, k))
+
+    def token(s, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        s = s * jnp.exp(g_t)[..., None, None]
+        delta_t = (v_t - jnp.einsum("bhkv,bhk->bhv", s, k_t)) * b_t[..., None]
+        s = s + k_t[..., :, None] * delta_t[..., None, :]
+        return s, jnp.einsum("bhkv,bhk->bhv", s, q_t)
+
+    rows, _, heads, d_k = q.shape
+    last, o = jax.lax.scan(
+        token, jnp.zeros((rows, heads, d_k, v.shape[-1]), jnp.float32),
+        tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), last
+
+
+def _rule_inputs(seq, key_heads=2, heads=4, d=16, rows=2, seed=0):
+    """Unit keys, queries over sqrt(d), and decays a token from near 0 to
+    -20, log-uniformly."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q = jax.random.normal(ks[0], (rows, seq, key_heads, d))
+    k = jax.random.normal(ks[1], (rows, seq, key_heads, d))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(d)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (rows, seq, heads, d))
+    g = -jnp.exp(jax.random.uniform(ks[3], (rows, seq, heads),
+                                    minval=np.log(1e-3), maxval=np.log(20.0)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (rows, seq, heads)))
+    return q, k, v, g, beta
+
+
+RULE_INPUTS = ("q", "k", "v", "g", "beta")
+
+
+@pytest.fixture(scope="module", params=[128, 100],
+                ids=["whole_chunks", "a_ragged_tail"])
+def rule_case(request):
+    args = _rule_inputs(request.param)
+    ct = jax.random.normal(jax.random.key(9), args[2].shape)
+
+    def both(fn):
+        def run(*a):
+            o, last = fn(*a)
+            return (o * ct).sum(), (o, last)
+        (_, out), grads = _one_program(jax.value_and_grad(
+            run, argnums=range(5), has_aux=True), *args)
+        return out, grads
+
+    with jax.default_matmul_precision("highest"):
+        want, g_want = both(_recurrence)
+        got, g_got = both(delta.delta_chunked)
+        return (args, want, got, g_want, g_got,
+                _one_program(delta.gated_delta_rule, *args))
+
+
+def test_the_chunked_form_is_the_recurrence(rule_case):
+    args, (o_want, s_want), (o_got, s_got), _, _, (o_rule, _) = rule_case
+    assert float(args[3].min()) < -15 and float(args[3].max()) > -0.01
+    np.testing.assert_allclose(o_got, o_want, atol=2e-5)
+    np.testing.assert_allclose(s_got, s_want, atol=5e-5)
+    np.testing.assert_allclose(o_rule, o_got, atol=1e-6)  # off a TPU
+
+
+@pytest.mark.parametrize("which", range(5), ids=RULE_INPUTS)
+def test_the_chunked_forms_gradient_is_the_recurrences(which, rule_case):
+    want, got = rule_case[3][which], rule_case[4][which]
+    assert _relative(got, want) < F32_TOL
+
+
+def test_the_head_groups_go_one_after_the_other_and_agree():
+    """More value heads than ``GROUP_H``: the rule maps over groups of them,
+    and gives what one call over all of them gives."""
+    args = _rule_inputs(64, key_heads=8, heads=16, d=8, rows=2, seed=2)
+    assert args[2].shape[2] > delta.GROUP_H
+
+    def both(fn):
+        def run(*a):
+            o, last = fn(*a)
+            return (o ** 2).sum(), (o, last)
+        return _one_program(jax.value_and_grad(
+            run, argnums=range(5), has_aux=True), *args)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (o, last)), g_got = both(delta.gated_delta_rule)
+        (_, (o_want, last_want)), g_want = both(delta.delta_chunked)
+    np.testing.assert_allclose(o, o_want, atol=1e-6)
+    np.testing.assert_allclose(last, last_want, atol=1e-6)
+    for got, want in zip(g_got, g_want):
+        assert _relative(got, want) < 1e-5
+
+
+def test_the_unit_lower_inverse_is_the_inverse():
+    """Keys all alike, no decay, full write strength: the powers of the
+    strictly lower part grow like binomials (a product over the whole
+    chunk's powers cancels in float32 there); by blocks of 16 the inverse
+    stays the bidiagonal matrix it is."""
+    c = delta.CHUNK
+    a = jnp.tril(jnp.ones((c, c), jnp.float32), -1)
+    inv = jax.jit(delta._unit_lower_inverse)(a[None])[0]
+    want = jnp.eye(c) - jnp.eye(c, k=-1)
+    np.testing.assert_allclose(inv, want, atol=1e-4)
+    rng = jax.random.normal(jax.random.key(0), (3, c, c)) * 0.3
+    a = jnp.tril(rng, -1)
+    with jax.default_matmul_precision("highest"):
+        inv = jax.jit(delta._unit_lower_inverse)(a)
+        np.testing.assert_allclose(inv @ (jnp.eye(c) + a),
+                                   jnp.broadcast_to(jnp.eye(c), a.shape),
+                                   atol=1e-4)
+
+
+def test_the_kernel_in_interpret_mode_is_the_chunked_form():
+    """One small shape at the kernel's widths (heads of 128, 256 tokens, one
+    key head serving two value heads): values and every gradient."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    args = _rule_inputs(256, key_heads=1, heads=2, d=128, rows=1, seed=1)
+    ct = jax.random.normal(jax.random.key(9), args[2].shape)
+
+    def both(fn):
+        def run(*a):
+            o, last = fn(*a)
+            return (o * ct).sum(), (o, last)
+        return jax.jit(jax.value_and_grad(run, argnums=range(5),
+                                          has_aux=True))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (o_want, s_want)), g_want = both(delta.delta_chunked)
+        with pltpu.force_tpu_interpret_mode():
+            (_, (o_got, s_got)), g_got = jax.block_until_ready(
+                both(delta.delta_kernel))
+    np.testing.assert_allclose(o_got, o_want, atol=1e-5)
+    np.testing.assert_allclose(s_got, s_want, atol=1e-5)
+    for name, got, want in zip(RULE_INPUTS, g_got, g_want):
+        assert _relative(got, want) < 1e-4, name
+
+
+def test_the_kernel_is_for_a_tpu_and_whole_chunks_of_whole_lane_groups(
+        monkeypatch):
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    applies = delta.delta_fused_applies
+    assert applies(8192, 32, 128, 128, platform="tpu")
+    assert not applies(8192, 32, 128, 128, platform="cpu")
+    assert not applies(8192 + 32, 32, 128, 128, platform="tpu")
+    assert not applies(8192, 32, 64, 128, platform="tpu")
+    assert not applies(8192, 32, 128, 96, platform="tpu")
+    with pytest.raises(ValueError, match="whole chunks"):
+        delta.delta_kernel(*_rule_inputs(100, d=128))
+    with pytest.raises(ValueError, match="Hk dividing Hv"):
+        delta.gated_delta_rule(*_rule_inputs(64, key_heads=3, heads=4))
+
+
+# -- causality ---------------------------------------------------------------
+
+
+def test_moving_token_t_moves_nothing_before_t(f32_task, variables, batch):
+    t = 70  # in the second chunk
+    moved = dict(batch, input_ids=batch["input_ids"].copy())
+    moved["input_ids"][0, t] = (moved["input_ids"][0, t] + 7) % VOCAB
+    a = _logits(f32_task, variables, batch)
+    b = _logits(f32_task, variables, moved)
+    assert float(jnp.abs(a[0, :t] - b[0, :t]).max()) == 0
+    assert float(jnp.abs(a[0, t:] - b[0, t:]).min(0).max()) > 0
+    assert float(jnp.abs(a[1] - b[1]).max()) == 0  # rows apart
+
+
+def test_the_rule_and_the_convolution_look_back_only():
+    q, k, v, g, beta = _rule_inputs(128)
+    t = 70
+    chunked = jax.jit(delta.delta_chunked)
+    o = chunked(q, k, v, g, beta)[0]
+    for i, moved in enumerate((q.at[:, t].add(0.1), k.at[:, t].add(0.1),
+                               v.at[:, t].add(1.0), g.at[:, t].add(-1.0),
+                               beta.at[:, t].multiply(0.5))):
+        args = [q, k, v, g, beta]
+        args[i] = moved
+        other = chunked(*args)[0]
+        assert float(jnp.abs(other[:, :t] - o[:, :t]).max()) == 0, i
+        assert float(jnp.abs(other[:, t:] - o[:, t:]).max()) > 0, i
+    x = jax.random.normal(jax.random.key(0), (2, 16, 8))
+    taps = jax.random.normal(jax.random.key(1), (4, 8))
+    y = causal_depthwise_conv(x, taps)
+    y_moved = causal_depthwise_conv(x.at[:, 9].add(1.0), taps)
+    changed = np.asarray(jnp.abs(y_moved - y).max((0, 2)) > 0)
+    assert changed.tolist() == [9 <= i <= 12 for i in range(16)]
+    # ``taps[K - 1]`` is this token's, ``taps[0]`` the token three before
+    np.testing.assert_allclose(
+        y[:, 5], sum(taps[j] * x[:, 5 - 3 + j] for j in range(4)), rtol=1e-5)
+    np.testing.assert_allclose(y[:, 0], taps[3] * x[:, 0], rtol=1e-5)
+    np.testing.assert_allclose(
+        causal_depthwise_conv(x, taps, bias=jnp.ones((8,))), y + 1)
+
+
+# -- the gated attention ------------------------------------------------------
+
+
+def _gated(attention_fn=None):
+    return GatedAttention(4, 2, 32, 8, 1e-6, 1e7, jnp.float32, attention_fn)
+
+
+def test_the_gated_attention_layer_has_the_parts_it_names():
+    """A head's queries and gate side by side in a projection twice as wide;
+    one ``1 + w`` scale a head width for queries and one for keys; rotary on
+    the first 8 of 32; ``sigmoid(gate)`` on the output."""
+    mixer = _gated()
+    x = jax.random.normal(jax.random.key(0), (2, 16, 64))
+    params = mixer.init(jax.random.key(1), x)["params"]
+    assert jax.tree.map(lambda p: p.shape, params) == {
+        "query": {"kernel": (64, 4, 64)}, "key": {"kernel": (64, 2, 32)},
+        "value": {"kernel": (64, 2, 32)}, "out": {"kernel": (4, 32, 64)},
+        "q_norm": {"scale": (32,)}, "k_norm": {"scale": (32,)}}
+    assert not np.asarray(params["q_norm"]["scale"]).any()  # w from 0
+    seen = {}
+
+    def spy(q, k, v, mask=None, **kw):
+        seen.update(q=q, k=k, v=v)
+        return jnp.ones_like(q)  # the output is then the gate alone
+
+    params = dict(params, q_norm={"scale": jnp.full((32,), 0.5)})
+    out = _gated(spy).apply({"params": params}, x)
+    q_gate = jnp.einsum("bsh,hnd->bsnd", x, params["query"]["kernel"])
+    q0 = q_gate[..., :32]
+    normed = 1.5 * q0 * jax.lax.rsqrt(
+        jnp.mean(q0 * q0, -1, keepdims=True) + 1e-6)
+    turned = rotary_embedding(normed[..., :8], jnp.arange(16), 1e7)
+    np.testing.assert_allclose(seen["q"].transpose(0, 2, 1, 3)[..., :8],
+                               turned, atol=1e-5)
+    np.testing.assert_allclose(seen["q"].transpose(0, 2, 1, 3)[..., 8:],
+                               normed[..., 8:], atol=1e-5)  # left as it is
+    assert float(jnp.abs(turned[:, 1:] - normed[:, 1:, :, :8]).max()) > 0.1
+    assert seen["k"].shape == (2, 2, 16, 32) == seen["v"].shape
+    gate = jax.nn.sigmoid(q_gate[..., 32:])
+    np.testing.assert_allclose(
+        out, jnp.einsum("bsnd,ndh->bsh", gate, params["out"]["kernel"]),
+        atol=1e-5)
+
+
+def test_rmsnorm_with_a_unit_offset_scales_by_one_plus_w():
+    x = jax.random.normal(jax.random.key(0), (3, 16))
+    norm = RMSNorm(1e-6, jnp.float32, True)
+    params = norm.init(jax.random.key(1), x)
+    assert not np.asarray(params["params"]["scale"]).any()
+    plain = RMSNorm(1e-6, jnp.float32)
+    ones = plain.init(jax.random.key(1), x)
+    np.testing.assert_allclose(norm.apply(params, x), plain.apply(ones, x))
+    w = jnp.linspace(-0.5, 0.5, 16)
+    np.testing.assert_allclose(
+        norm.apply({"params": {"scale": w}}, x),
+        plain.apply({"params": {"scale": 1 + w}}, x), rtol=1e-6)
+
+
+# -- parity with the published modules ----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def published():
+    """The published classes at the tiny preset's sizes, float32."""
+    torch = pytest.importorskip("torch")
+    pytest.importorskip("transformers")
+    try:
+        from transformers.models.qwen3_next import modeling_qwen3_next as hf
+        from transformers.models.qwen3_next.configuration_qwen3_next import (
+            Qwen3NextConfig,
+        )
+    except ImportError as e:  # an older transformers
+        pytest.skip(f"no qwen3_next in this transformers: {e}")
+    config = Qwen3NextConfig(
+        hidden_size=64, num_hidden_layers=4, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=32, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16,
+        linear_value_head_dim=16, linear_conv_kernel_dim=4,
+        num_experts=EXPERTS, num_experts_per_tok=TOP_K,
+        moe_intermediate_size=32, shared_expert_intermediate_size=32,
+        partial_rotary_factor=0.25, rope_theta=1e7, vocab_size=VOCAB,
+        rms_norm_eps=1e-6, norm_topk_prob=True)
+    config._attn_implementation = "eager"
+    torch.manual_seed(0)
+    return torch, hf, config
+
+
+def _np(t):
+    return jnp.asarray(t.detach().numpy())
+
+
+PARITY = 1e-4  # float32 torch against float32 jax.numpy: summation order
+
+
+def test_reference_linear_attention_is_the_published_module(published):
+    torch, hf, config = published
+    ref = _load_reference()
+    module = hf.Qwen3NextGatedDeltaNet(config, layer_idx=0).float()
+    with torch.no_grad():
+        module.A_log.uniform_(-6.0, 2.0)  # decays from slow to fast
+        module.norm.weight.uniform_(0.75, 1.25)
+    x = torch.randn(2, SEQ, 64)
+    with torch.no_grad():
+        want = module(x)
+    hk, rep, dk, dv = 2, 2, 16, 16
+    # the checkpoint's fused projection is interleaved by key head: [q, k,
+    # the head's two values, their two gates]; the program's is [q; k; v; z]
+    w = _np(module.in_proj_qkvz.weight).T.reshape(64, hk, -1)
+    parts = jnp.split(w, [dk, 2 * dk, 2 * dk + rep * dv], axis=-1)
+    qkvz = jnp.concatenate([p.reshape(64, -1) for p in parts], axis=-1)
+    ba = _np(module.in_proj_ba.weight).T.reshape(64, hk, 2 * rep)
+    ba = jnp.concatenate([ba[..., :rep].reshape(64, -1),
+                          ba[..., rep:].reshape(64, -1)], axis=-1)
+    params = {
+        "in_proj_qkvz": qkvz, "in_proj_ba": ba,
+        "conv_kernel": _np(module.conv1d.weight)[:, 0, :].T,
+        "A_log": _np(module.A_log), "dt_bias": _np(module.dt_bias),
+        "norm_scale": _np(module.norm.weight),
+        "out_proj": {"kernel": _np(module.out_proj.weight).T}}
+    with jax.default_matmul_precision("highest"):
+        got, _ = _one_program(
+            lambda p, x: ref._linear_attention(x, p, lambda t: t), params,
+            _np(x))
+    np.testing.assert_allclose(got, _np(want), atol=PARITY)
+    assert float(jnp.abs(_np(want)).max()) > 0.05
+
+
+def test_reference_gated_attention_is_the_published_module(published):
+    torch, hf, config = published
+    ref = _load_reference()
+    module = hf.Qwen3NextAttention(config, layer_idx=3).float()
+    with torch.no_grad():
+        module.q_norm.weight.uniform_(-0.25, 0.25)
+        module.k_norm.weight.uniform_(-0.25, 0.25)
+    seq = 24
+    x = torch.randn(2, seq, 64)
+    positions = torch.arange(seq)[None].expand(2, -1)
+    cos_sin = hf.Qwen3NextRotaryEmbedding(config)(x, positions)
+    causal = torch.full((seq, seq), float("-inf")).triu(1)[None, None]
+    with torch.no_grad():
+        want, _ = module(x, cos_sin, causal.expand(2, 1, -1, -1))
+    params = {
+        "query": {"kernel": _np(module.q_proj.weight).T.reshape(64, 4, 64)},
+        "key": {"kernel": _np(module.k_proj.weight).T.reshape(64, 2, 32)},
+        "value": {"kernel": _np(module.v_proj.weight).T.reshape(64, 2, 32)},
+        "out": {"kernel": _np(module.o_proj.weight).T.reshape(4, 32, 64)},
+        "q_norm": {"scale": _np(module.q_norm.weight)},
+        "k_norm": {"scale": _np(module.k_norm.weight)}}
+
+    def allow_rows(start, n):
+        at = start + jnp.arange(n)
+        return jnp.broadcast_to(
+            jnp.arange(seq)[None, :] <= at[:, None], (2, n, seq))
+
+    with jax.default_matmul_precision("highest"):
+        got = ref._gated_attention(_np(x), params, jnp.arange(seq),
+                                   allow_rows)
+    np.testing.assert_allclose(got, _np(want), atol=PARITY)
+    assert float(jnp.abs(_np(want)).max()) > 0.05
+
+
+def test_reference_expert_layer_is_the_published_module(published):
+    torch, hf, config = published
+    ref = _load_reference()
+    module = hf.Qwen3NextSparseMoeBlock(config).float()
+    with torch.no_grad():
+        module.gate.weight.mul_(8.0)  # a router that decides
+    x = torch.randn(2, SEQ, 64)
+    with torch.no_grad():
+        want, router_logits = module(x)
+
+    def stack(name):
+        return jnp.stack([_np(getattr(e, name).weight).T
+                          for e in module.experts])
+
+    shared = module.shared_expert
+    params = {
+        "router": {"kernel": _np(module.gate.weight).T},
+        "w_gate": stack("gate_proj"), "w_up": stack("up_proj"),
+        "w_down": stack("down_proj"),
+        "shared": {name: {"kernel": _np(getattr(shared, f"{name}_proj"
+                                                ).weight).T}
+                   for name in ("gate", "up", "down")},
+        "shared_gate": {"kernel": _np(module.shared_expert_gate.weight).T}}
+    with jax.default_matmul_precision("highest"):
+        got, logits, _, chosen = ref._sparse_block(
+            _np(x).reshape(-1, 64), params)
+    np.testing.assert_allclose(logits, _np(router_logits), atol=PARITY)
+    assert int(chosen.sum()) == 2 * SEQ * TOP_K
+    np.testing.assert_allclose(got.reshape(2, SEQ, 64), _np(want),
+                               atol=PARITY)
+
+
+# -- the configuration's file against the program ----------------------------
+
+
+@pytest.fixture(scope="module")
+def config():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3-next-80b-a3b-c4.json")) as f:
+        return json.load(f)
+
+
+def test_the_cut_holds_the_parameters_the_file_states(config):
+    task = get_task(**config["task"])
+    shapes = jax.eval_shape(task.init_variables, jax.random.key(0))
+    held = sum(int(np.prod(leaf.shape))
+               for leaf in jax.tree.leaves(shapes["params"]))
+    assert held == config["held_parameters"] == 625_667_136
+    count = {
+        (layer, name): sum(int(np.prod(leaf.shape))
+                           for leaf in jax.tree.leaves(part))
+        for layer in ("layer_0", "layer_3")
+        for name, part in shapes["params"][layer].items()}
+    assert count == {
+        ("layer_0", "gdn"): 33_718_464, ("layer_3", "attn"): 27_263_488,
+        ("layer_0", "moe"): 104_859_648, ("layer_3", "moe"): 104_859_648,
+        ("layer_0", "ln_attn"): 2048, ("layer_0", "ln_mlp"): 2048,
+        ("layer_3", "ln_attn"): 2048, ("layer_3", "ln_mlp"): 2048}
+    assert "batch_stats" not in shapes  # no selection bias: no state
+    assert task.model.held_kinds == ("D", "D", "D", "A")
+
+
+def test_every_width_is_the_published_one(config):
+    """The catalog row's ``config`` (copied into the test: the guide is not
+    part of the repository), key by key, but for the three keys ``reduced``
+    names, which the file gives beside their published values."""
+    published = {
+        "decoder_sparse_step": 1, "full_attention_interval": 4,
+        "head_dim": 256, "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 5120, "linear_conv_kernel_dim": 4,
+        "linear_key_head_dim": 128, "linear_num_key_heads": 16,
+        "linear_num_value_heads": 32, "linear_value_head_dim": 128,
+        "max_position_embeddings": 262144, "mlp_only_layers": [],
+        "model_type": "qwen3_next", "moe_intermediate_size": 512,
+        "norm_topk_prob": True, "num_attention_heads": 16,
+        "num_experts": 512, "num_experts_per_tok": 10,
+        "num_hidden_layers": 48, "num_key_value_heads": 2,
+        "partial_rotary_factor": 0.25, "rms_norm_eps": 1e-06,
+        "rope_scaling": None, "rope_theta": 10000000,
+        "shared_expert_intermediate_size": 512, "tie_word_embeddings": False,
+        "use_sliding_window": False, "vocab_size": 151936}
+    reduced = {"num_hidden_layers": 4, "num_experts": 32,
+               "vocab_size": 18992}
+    assert sorted(config["reduced"]) == sorted(reduced)
+    for key, value in published.items():
+        assert config[key] == reduced.get(key, value), key
+        assert config["model"][key] == reduced.get(key, value), key
+        if key in reduced:
+            assert config["model"][f"{key}_published"] == value
+    model = get_task(**config["task"]).model
+    assert (model.hidden_size, model.num_heads, model.expert_dim,
+            model.num_experts, model.experts_per_token, model.rope_theta,
+            model.delta, model.gated, model.norm_eps, model.norm_offset,
+            model.tied_head) == (
+        2048, 16, 512, 512, 10, 1e7, (16, 32, 128, 128, 4), (2, 256, 64),
+        1e-6, True, False)
+    moe = dict(model.moe)
+    assert (moe["held_experts"], moe["first_expert"], moe["shared_dim"],
+            moe["norm_topk"], moe["shared_gate"]) == (32, 0, 512, True, True)
+    assert len(model.layer_kinds) == 48
+    assert [i for i, kind in enumerate(model.layer_kinds) if kind == "A"] \
+        == list(range(3, 48, 4))
