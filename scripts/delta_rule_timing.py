@@ -1,18 +1,21 @@
 """The gated delta rule's two forms timed against each other on the chip, at
 the shapes the Qwen3-Next cell calls them (one row of 8,192 tokens, 16 key
-heads serving 32 value heads of 128, bf16), forward and backward in one
-program as the layer runs them (a group of ``GROUP_H`` heads at a time, the
-forward made again in the backward pass):
+heads serving 32 value heads of 128, bf16), forward alone and forward +
+backward in one program as the layer runs them (both forms make the forward
+again in the backward pass: two forwards and one backward):
 
     chiprun --chips 1 -- python3 scripts/delta_rule_timing.py
 
-* ``chunked``: ``ops/delta.py`` ``delta_chunked``, the sequential part a
-  ``lax.scan`` over the chunks in XLA;
-* ``kernel, block_h=N``: ``delta_kernel``, the sequential part a Pallas kernel
-  pair with ``N`` heads' states in VMEM a grid step.
+* ``chunked``: ``ops/delta.py`` ``gated_delta_rule`` off the kernel path:
+  ``delta_chunked`` a group of ``GROUP_H`` heads at a time, the chunks'
+  preparation and the ``lax.scan`` over them in XLA;
+* ``kernel, block_h=N``: ``delta_kernel``, preparation and recurrence in the
+  Pallas kernels (``delta_rule_fwd``; ``delta_rule_fwd_kept`` and
+  ``delta_rule_bwd`` in the backward pass) with ``N`` heads' states in VMEM
+  a grid step.
 
 Times are the host's clock around ``CALLS`` calls that end in
-``block_until_ready`` (one program a call, 10 to 100 ms each: the dispatch
+``block_until_ready`` (one program a call, 5 to 100 ms each: the dispatch
 is noise), so it wants a TPU and fails without one. It also prints how far
 the two forms' outputs and gradients lie from the plain chunked form computed
 in float32. Not tier-1; ``PERF.md`` section 6 holds the table it gave.
@@ -20,6 +23,7 @@ in float32. Not tier-1; ``PERF.md`` section 6 holds the table it gave.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -28,6 +32,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 CALLS = 10
+BLOCKS = (2, 4, 8)  # value heads a grid step
 ROWS, SEQ, KEY_HEADS, HEADS, DIM = 1, 8192, 16, 32, 128
 
 
@@ -69,56 +74,51 @@ def main() -> None:
         return jax.jit(jax.value_and_grad(run, argnums=range(5),
                                           has_aux=True))
 
-    def grouped(form):
-        """As ``gated_delta_rule`` runs a form: by groups of heads."""
-        def run(*a):
-            original = delta.delta_fused_applies
-            delta.delta_fused_applies = lambda *s, **k: form == "kernel"
-            try:
-                return delta.gated_delta_rule(*a)
-            finally:
-                delta.delta_fused_applies = original
-        return run
+    def chunked(*a):
+        """``gated_delta_rule`` where the kernels do not apply."""
+        original = delta.delta_fused_applies
+        delta.delta_fused_applies = lambda *s, **k: False
+        try:
+            return delta.gated_delta_rule(*a)
+        finally:
+            delta.delta_fused_applies = original
+
+    def timed(fn, *a):
+        out = jax.block_until_ready(fn(*a))
+        t0 = time.monotonic()
+        for _ in range(CALLS):
+            last = fn(*a)
+        jax.block_until_ready(last)
+        return out, (time.monotonic() - t0) / CALLS * 1e3
 
     with jax.default_matmul_precision("highest"):
         (_, (o32, s32)), g32 = jax.block_until_ready(
-            program(grouped("chunked"))(*args32))
-    forms = {"chunked": grouped("chunked")}
-    for block_h in (2, 4, 8):
-        def kernel(*a, block_h=block_h):
-            original = delta.BLOCK_H
-            delta.BLOCK_H = block_h
-            try:
-                return grouped("kernel")(*a)
-            finally:
-                delta.BLOCK_H = original
-        forms[f"kernel, block_h={block_h}"] = kernel
+            program(chunked)(*args32))
+    forms = {"chunked": chunked}
+    for block_h in BLOCKS:
+        forms[f"kernel, block_h={block_h}"] = functools.partial(
+            delta.delta_kernel, block_h=block_h)
     out_dir = os.path.join("chiprun_out", "delta_timing")
     os.makedirs(out_dir, exist_ok=True)
     rows = []
     for name, form in forms.items():
-        fn = program(form)
         t0 = time.monotonic()
         try:
-            (_, (o, last)), grads = jax.block_until_ready(fn(*args))
+            _, fwd_ms = timed(jax.jit(form), *args)
+            ((_, (o, last)), grads), ms = timed(program(form), *args)
         except Exception as e:  # a refusal is a row of the table
             rows.append({"form": name, "error": " ".join(str(e).split())[:300]})
             print(json.dumps(rows[-1]), flush=True)
             continue
-        compile_s = time.monotonic() - t0
-        t0 = time.monotonic()
-        for _ in range(CALLS):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        ms = (time.monotonic() - t0) / CALLS * 1e3
+        seconds = time.monotonic() - t0
 
         def far(a, b):
             a, b = a.astype(jnp.float32), b.astype(jnp.float32)
             return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
         rows.append({
-            "form": name, "fwd_bwd_ms": round(ms, 3),
-            "compile_s": round(compile_s, 1),
+            "form": name, "fwd_ms": round(fwd_ms, 3),
+            "fwd_bwd_ms": round(ms, 3), "row_s": round(seconds, 1),
             "o_rel": far(o, o32), "state_rel": far(last, s32),
             **{f"d{n}_rel": far(a, b)
                for n, a, b in zip(("q", "k", "v", "g", "beta"), grads, g32)}})

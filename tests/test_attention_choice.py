@@ -348,10 +348,17 @@ def test_unequal_attention_compiles_for_a_v5e_at_qwen3_nexts_widths(
 
 def test_the_delta_rules_kernel_compiles_for_a_v5e_at_qwen3_nexts_widths(
         one_chip):
-    """One group of the Gated DeltaNet's heads as the Qwen3-Next cell runs
-    them (4 key heads serving 8 value heads of 128, a row of 8,192 tokens in
-    128 chunks of 64), forward and backward: the two kernels, under a quarter
-    of the 3.5 GiB that all 32 heads' preparation would hold at once."""
+    """A Gated DeltaNet's rule as the Qwen3-Next cell calls it (two rows of
+    8,192 tokens, 16 key heads serving 32 value heads of 128, every head in
+    one call), forward and backward: the three kernels (the forward, the
+    forward again for what the backward pass starts from, the backward), and
+    outside them nothing of a chunk's preparation. XLA held the decay mask, ``A``, its
+    inverse and the steps of the inversion in float32, ``[.., 64, 64]`` a
+    chunk and head (``[.., 8192, 64]`` a head), 3.5 GiB a row at these
+    widths and a group of eight heads at a time for it; the kernels make
+    them in VMEM, and what they keep for the backward pass (a chunk's
+    starting state, two chunks' inverses side by side) leaves the program's
+    temporaries under the limit one group of eight had."""
     from lance_distributed_training_tpu.ops import delta
 
     def spec(*shape, dtype=jnp.bfloat16):
@@ -359,14 +366,17 @@ def test_the_delta_rules_kernel_compiles_for_a_v5e_at_qwen3_nexts_widths(
 
     def loss(q, k, v, g, beta):
         o, last = delta.delta_kernel(q, k, v, g, beta)
-        assert o.shape == (1, 8192, 8, 128) and last.shape == (1, 8, 128, 128)
+        assert o.shape == v.shape and last.shape == (2, 32, 128, 128)
         return o.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
-        spec(1, 8192, 4, 128), spec(1, 8192, 4, 128), spec(1, 8192, 8, 128),
-        spec(1, 8192, 8, dtype=jnp.float32),
-        spec(1, 8192, 8, dtype=jnp.float32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+        spec(2, 8192, 16, 128), spec(2, 8192, 16, 128),
+        spec(2, 8192, 32, 128), spec(2, 8192, 32, dtype=jnp.float32),
+        spec(2, 8192, 32, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "while(" not in text  # no loop over groups of heads
+    assert not re.search(r"f32\[[0-9,]*(64,64|8192,64)\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 7 << 27
 
 

@@ -492,31 +492,95 @@ def test_the_head_groups_go_one_after_the_other_and_agree():
         assert _relative(got, want) < 1e-5
 
 
-def test_the_unit_lower_inverse_is_the_inverse():
+def _inverse_in_a_kernel(a):
+    """``delta._blocks_inverse`` as the rule's kernels run it: on two ``[C,
+    C]`` matrices side by side along the lanes, in VMEM (interpret mode)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(a_ref, inv_ref):
+        inv_ref[0] = delta._blocks_inverse(a_ref[0])
+
+    c = a.shape[-1]
+    pairs = jnp.concatenate([a, a[::-1]], -1)  # [n, C, 2C]
+    spec = pl.BlockSpec((1, c, 2 * c), lambda i: (i, 0, 0))
+    with pltpu.force_tpu_interpret_mode():
+        inv = jax.block_until_ready(pl.pallas_call(
+            kernel, grid=(a.shape[0],), in_specs=[spec], out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct(pairs.shape, a.dtype))(pairs))
+    np.testing.assert_array_equal(inv[..., :c], inv[::-1, :, c:])
+    return inv[..., :c]
+
+
+@pytest.mark.parametrize("inverse", [
+    lambda a: jax.jit(delta._unit_lower_inverse)(a), _inverse_in_a_kernel],
+    ids=["in_xla", "in_a_kernel"])
+def test_the_unit_lower_inverse_is_the_inverse(inverse):
     """Keys all alike, no decay, full write strength: the powers of the
     strictly lower part grow like binomials (a product over the whole
     chunk's powers cancels in float32 there); by blocks of 16 the inverse
     stays the bidiagonal matrix it is."""
     c = delta.CHUNK
     a = jnp.tril(jnp.ones((c, c), jnp.float32), -1)
-    inv = jax.jit(delta._unit_lower_inverse)(a[None])[0]
-    want = jnp.eye(c) - jnp.eye(c, k=-1)
-    np.testing.assert_allclose(inv, want, atol=1e-4)
-    rng = jax.random.normal(jax.random.key(0), (3, c, c)) * 0.3
-    a = jnp.tril(rng, -1)
     with jax.default_matmul_precision("highest"):
-        inv = jax.jit(delta._unit_lower_inverse)(a)
+        inv = inverse(a[None])[0]
+        want = jnp.eye(c) - jnp.eye(c, k=-1)
+        np.testing.assert_allclose(inv, want, atol=1e-4)
+        rng = jax.random.normal(jax.random.key(0), (3, c, c)) * 0.3
+        a = jnp.tril(rng, -1)
+        inv = inverse(a)
         np.testing.assert_allclose(inv @ (jnp.eye(c) + a),
                                    jnp.broadcast_to(jnp.eye(c), a.shape),
                                    atol=1e-4)
 
 
-def test_the_kernel_in_interpret_mode_is_the_chunked_form():
-    """One small shape at the kernel's widths (heads of 128, 256 tokens, one
-    key head serving two value heads): values and every gradient."""
+def _alike(args):
+    """The same inputs with a chunk's keys nearly one direction, written at
+    nearly full strength and hardly forgotten: ``I + A`` is then far from
+    the identity, the case ``delta._blocks_inverse`` goes by blocks for."""
+    q, k, v, g, beta = args
+    k = k[:, :1] + 0.05 * k
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    return q, k, v, g * 1e-3, 0.9 + 0.1 * beta
+
+
+def _slow_and_fast(args):
+    """The same inputs with ``g`` from -1e-4 to -20 a token: the first chunk
+    barely decays (-1e-4 every token), the second underflows (-20 every
+    token: ``exp`` of a chunk's sum is 0 in float32), the rest mix both."""
+    q, k, v, g, beta = args
+    c = delta.CHUNK
+    spread = -jnp.exp(jax.random.uniform(
+        jax.random.key(11), g.shape, minval=np.log(1e-4), maxval=np.log(20.0)))
+    g = spread.at[:, :c].set(-1e-4).at[:, c:2 * c].set(-20.0)
+    assert float(jnp.exp(g[:, c:2 * c].sum(1)).max()) == 0
+    return q, k, v, g, beta
+
+
+KERNEL_CASES = {
+    # name: (_rule_inputs' arguments, heads a grid step, what is done to them)
+    "two_heads_of_one_key_head": (
+        dict(seq=256, key_heads=1, heads=2, d=128, rows=1, seed=1), 8, None),
+    "two_rows_a_block_of_two_key_heads": (
+        dict(seq=128, key_heads=2, heads=4, d=128, rows=2, seed=3), 4, None),
+    "keys_nearly_alike": (
+        dict(seq=128, key_heads=1, heads=2, d=128, rows=1, seed=4), 8, _alike),
+    "decays_from_1e-4_to_20_a_token": (
+        dict(seq=256, key_heads=1, heads=2, d=128, rows=1, seed=5), 8,
+        _slow_and_fast),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_kernel_in_interpret_mode_is_the_chunked_form(case):
+    """Small shapes at the kernel's widths (heads of 128): values, the
+    final state and every gradient, all finite."""
     from jax.experimental.pallas import tpu as pltpu
 
-    args = _rule_inputs(256, key_heads=1, heads=2, d=128, rows=1, seed=1)
+    shapes, block_h, change = KERNEL_CASES[case]
+    args = _rule_inputs(**shapes)
+    if change:
+        args = change(args)
     ct = jax.random.normal(jax.random.key(9), args[2].shape)
 
     def both(fn):
@@ -529,8 +593,10 @@ def test_the_kernel_in_interpret_mode_is_the_chunked_form():
     with jax.default_matmul_precision("highest"):
         (_, (o_want, s_want)), g_want = both(delta.delta_chunked)
         with pltpu.force_tpu_interpret_mode():
-            (_, (o_got, s_got)), g_got = jax.block_until_ready(
-                both(delta.delta_kernel))
+            (_, (o_got, s_got)), g_got = jax.block_until_ready(both(
+                functools.partial(delta.delta_kernel, block_h=block_h)))
+    for t in (o_got, s_got, *g_got):
+        assert bool(jnp.isfinite(t).all())
     np.testing.assert_allclose(o_got, o_want, atol=1e-5)
     np.testing.assert_allclose(s_got, s_want, atol=1e-5)
     for name, got, want in zip(RULE_INPUTS, g_got, g_want):
@@ -544,6 +610,7 @@ def test_the_kernel_is_for_a_tpu_and_whole_chunks_of_whole_lane_groups(
     assert applies(8192, 32, 128, 128, platform="tpu")
     assert not applies(8192, 32, 128, 128, platform="cpu")
     assert not applies(8192 + 32, 32, 128, 128, platform="tpu")
+    assert not applies(8192 + 64, 32, 128, 128, platform="tpu")  # no pair
     assert not applies(8192, 32, 64, 128, platform="tpu")
     assert not applies(8192, 32, 128, 96, platform="tpu")
     with pytest.raises(ValueError, match="whole chunks"):
