@@ -182,7 +182,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "one gated softmax-attention layer of 16 query heads "
                         "over 2 key/value heads of 256, 10 of 512 experts a "
                         "token beside a gated shared one; the first log line "
-                        "says delta=fused kernel or chunked) and olmoe_tiny, "
+                        "says delta=fused kernel or chunked and conv=fused "
+                        "kernel or plain) and olmoe_tiny, "
                         "moonlight_tiny, phi4_mini_flash_tiny, zaya_tiny, "
                         "qwen3_next_tiny")
     p.add_argument("--num_layers", type=int, default=0,

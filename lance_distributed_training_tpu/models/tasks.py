@@ -418,13 +418,16 @@ def _expert_load(sown: dict) -> dict:
 
 
 def _mixer_stats(sown: dict, scan_fused: Optional[bool] = None,
-                 delta_fused: Optional[bool] = None) -> dict:
+                 delta_fused: Optional[bool] = None,
+                 conv_fused: Optional[bool] = None) -> dict:
     """A stack's step scalars from what its mixers and layers sow into
     ``mixer_stats``. SambaY's: the largest magnitude in a state-space
     layer's state at a row's end, the least and the largest differential
     lambda over the attention layers, and whether the scan runs its kernel
-    (``scan_fused``). ZAYA's: the largest key temperature, and the least and
-    the largest of the scales on the residual sums' two sides. Qwen3-Next's:
+    (``scan_fused``). SambaY's and Qwen3-Next's: whether the depthwise
+    causal convolution and its SiLU run theirs (``conv_fused``). ZAYA's: the
+    largest key temperature, and the least and the largest of the scales on
+    the residual sums' two sides. Qwen3-Next's:
     whether the gated delta rule runs its kernel (``delta_fused``), the
     largest magnitude in a linear-attention layer's state at a row's end, the
     least ``exp(g)`` a token and head saw, the mean write strength, and the
@@ -435,6 +438,8 @@ def _mixer_stats(sown: dict, scan_fused: Optional[bool] = None,
         out["ssm_scan_fused"] = jnp.float32(scan_fused)
     if delta_fused is not None:
         out["delta_fused"] = jnp.float32(delta_fused)
+    if conv_fused is not None:
+        out["conv_fused"] = jnp.float32(conv_fused)
     for name, over in (("delta_state_abs_max", jnp.max),
                        ("delta_decay_min", jnp.min),
                        ("delta_beta_mean", jnp.mean)):
@@ -573,6 +578,11 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
         from ..ops.delta import delta_fused_applies
 
         delta_fused = delta_fused_applies(seq_len, *model.delta_shape)
+    conv_fused = None
+    if decoder and model.conv_shape:
+        from ..ops.conv import conv_fused_applies
+
+        conv_fused = conv_fused_applies(seq_len, *model.conv_shape)
     sows = ((["aux_loss", "moe_stats", "router_state"] if dropless
              else ["aux_loss"] if num_experts > 0 else [])
             + ["mixer_stats"] * kinds)
@@ -613,7 +623,7 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                 stats.update(_expert_load(sown["moe_stats"]))
             if kinds:
                 stats.update(_mixer_stats(sown["mixer_stats"], scan_fused,
-                                          delta_fused))
+                                          delta_fused, conv_fused))
             # the bias as the routers left it: the step's new state
             state = ({"batch_stats": sown["router_state"]}
                      if "router_state" in sown else None)

@@ -22,6 +22,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.conv import causal_conv_silu, causal_depthwise_conv
+
 __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "bert_small", "gpt_base", "gpt_small", "olmoe_1b_7b",
            "olmoe_tiny", "moonlight_16b_a3b", "moonlight_tiny",
@@ -107,19 +109,6 @@ class RMSNorm(nn.Module):
         x32 = x.astype(jnp.float32)
         var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
-
-
-def causal_depthwise_conv(x, taps, bias=None):
-    """A depthwise causal convolution along the sequence: ``x`` [B, S, D],
-    ``taps`` [K, D] f32 with ``taps[K - 1]`` this token's and ``taps[0]``
-    the token ``K - 1`` before (a ``Conv1d`` with ``groups = D`` and ``K -
-    1`` zeros before the row), ``bias`` [D] or None; the sums in f32, [B, S,
-    D] f32. Nothing here knows where a document ends inside a row."""
-    k = taps.shape[0]
-    back = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = sum(back[:, j:j + x.shape[1]].astype(jnp.float32) * taps[j]
-            for j in range(k))
-    return y if bias is None else y + bias
 
 
 def rotary_embedding(x, positions, theta: float, width: int = 0):
@@ -451,7 +440,12 @@ class MambaMixer(nn.Module):
     (m silu(z)) W_out``. ``dt``, the exponent, the state and the sum over
     the states in float32, the products' operands in ``dtype``. Returns
     ``(out, m)``: SambaY's gated memory units read ``m``, the scan's output
-    before the gate."""
+    before the gate. The convolution, its bias and its SiLU are one call,
+    :func:`..ops.conv.causal_conv_silu`, on the projection ``[x; z]`` whole:
+    on one TPU device at rows of whole 128-token tiles and channels in whole
+    lane groups a Pallas kernel pair that reads ``x``'s columns where they
+    lie, one pass over HBM forward and one backward; everywhere else the
+    plain ``silu(causal_depthwise_conv(x) + b_c)`` in XLA."""
 
     inner: int
     states: int
@@ -471,7 +465,6 @@ class MambaMixer(nn.Module):
                           preferred_element_type=jnp.float32)
         with jax.named_scope("ssm.project"):
             xz = dense(2 * self.inner, name="in_proj")(u)
-            x, z = xz[..., :self.inner], xz[..., self.inner:]
         with jax.named_scope("ssm.conv"):
             edge = 1.0 / math.sqrt(self.conv)  # a depthwise Conv1d's own
             taps = self.param(
@@ -480,8 +473,8 @@ class MambaMixer(nn.Module):
                 (self.conv, self.inner), jnp.float32)
             bias = self.param("conv_bias", nn.initializers.zeros_init(),
                               (self.inner,), jnp.float32)
-            x = nn.silu(causal_depthwise_conv(x, taps, bias)).astype(
-                self.dtype)
+            # the first ``inner`` columns of ``xz``, read where they lie
+            x = causal_conv_silu(xz, taps, bias, dtype=self.dtype)
         with jax.named_scope("ssm.project"):
             dbc = dense(r + 2 * n, name="x_proj", dot_general=f32_out)(x)
             dt = jax.nn.softplus(dense(
@@ -501,7 +494,7 @@ class MambaMixer(nn.Module):
         with jax.named_scope("ssm.gate"):
             m = (y.astype(jnp.float32) + skip * x.astype(jnp.float32)
                  ).astype(self.dtype)
-            gated = m * nn.silu(z)
+            gated = m * nn.silu(xz[..., self.inner:])
         with jax.named_scope("ssm.project"):
             return dense(u.shape[-1], name="out_proj")(gated), m
 
@@ -551,7 +544,14 @@ class GatedDeltaNet(nn.Module):
     of the output projection is made again in the backward pass from the
     normed stream and the rule's output. Nothing here knows where a document
     ends inside a row: the convolution and the state run across it (ROADMAP
-    R4)."""
+    R4). The convolution and its SiLU are one call,
+    :func:`..ops.conv.causal_conv_silu`, on the fused projection whole: on
+    one TPU device at rows of whole 128-token tiles and channels in whole
+    lane groups a Pallas kernel pair that reads the first ``2 keys + values``
+    columns where they lie (forward, the recomputed forward and a backward
+    pass that keeps nothing but the projection); everywhere else the plain
+    ``silu(causal_depthwise_conv(.))`` in XLA, as the rule beside it is
+    :func:`..ops.delta.delta_chunked` there."""
 
     key_heads: int
     value_heads: int
@@ -606,8 +606,8 @@ class GatedDeltaNet(nn.Module):
                 ba = jnp.dot(u, w_ba.astype(self.dtype),
                              preferred_element_type=jnp.float32)
             with jax.named_scope("gdn.conv"):
-                mixed = nn.silu(causal_depthwise_conv(
-                    qkvz[..., :2 * keys + values], taps)).astype(self.dtype)
+                # the first 2 keys + values columns, read where they lie
+                mixed = causal_conv_silu(qkvz, taps, dtype=self.dtype)
             with jax.named_scope("gdn.gates"):
                 beta = nn.sigmoid(ba[..., :hv])
                 g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
@@ -1053,6 +1053,19 @@ class TransformerDecoder(nn.Module):
         layer."""
         if "D" in self.held_kinds:
             return tuple(self.delta[1:4])
+        return None
+
+    @property
+    def conv_shape(self) -> Optional[tuple]:
+        """``(channels, taps)`` of the depthwise causal convolution the held
+        state-space or linear-attention layers run (what
+        :func:`..ops.conv.conv_fused_applies` chooses its path by), or None
+        for a stack that holds neither."""
+        if "D" in self.held_kinds:
+            key_heads, value_heads, key_dim, value_dim, taps = self.delta
+            return (2 * key_heads * key_dim + value_heads * value_dim, taps)
+        if set(self.held_kinds) & {"M", "M*"}:
+            return (self.hybrid[2], self.hybrid[4])
         return None
 
     @property

@@ -417,6 +417,20 @@ def _delta_path(task: Task, config: TrainConfig) -> Optional[str]:
             else "chunked")
 
 
+def _conv_path(task: Task, config: TrainConfig) -> Optional[str]:
+    """How a stack's state-space or linear-attention layers run their
+    depthwise causal convolution and its SiLU at ``seq_len`` (by
+    ``ops.conv.conv_fused_applies``, the test each call makes), None for a
+    model that holds neither."""
+    shape = getattr(task.model, "conv_shape", None)
+    if not shape:
+        return None
+    from .ops.conv import conv_fused_applies
+
+    return ("fused kernel" if conv_fused_applies(config.seq_len, *shape)
+            else "plain")
+
+
 def lr_schedule_fn(config: TrainConfig, total_steps: Optional[int] = None):
     """The learning-rate schedule from the config knobs: a float (constant)
     or an ``optax`` schedule callable over OPTIMIZER updates (data steps are
@@ -1566,6 +1580,9 @@ def _train(config: TrainConfig) -> dict:
         delta_path = _delta_path(task, config)
         if delta_path:
             start_line["delta"] = delta_path
+        conv_path = _conv_path(task, config)
+        if conv_path:
+            start_line["conv"] = conv_path
         logger.log(start_line, to_wandb=False)
         if config.metrics_port is not None and jax.process_index() == 0:
             from .obs.http import MetricsHTTPServer

@@ -380,6 +380,44 @@ def test_the_delta_rules_kernel_compiles_for_a_v5e_at_qwen3_nexts_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 7 << 27
 
 
+@pytest.mark.parametrize("rows,wide,width,has_bias", [
+    (2, 12288, 8192, False),  # c4-qwen3next-ep16-prepacked-8k's projection
+    (1, 10240, 5120, True),  # c4-phi4flash-vp8-prepacked-8k's
+])
+def test_the_convolutions_kernels_compile_for_a_v5e_at_the_cells_widths(
+        one_chip, rows, wide, width, has_bias):
+    """The depthwise causal convolution and its SiLU as the two cells call
+    them, forward and backward: two kernels, each reading the first columns
+    of the fused projection where they lie. No slice of the projection is
+    written out in front of them, and nothing of ``[8192, width]`` is kept
+    in f32: the plain form's padded copy, its four shifted f32 products and their
+    cotangents were some ten passes over HBM."""
+    from lance_distributed_training_tpu.ops import conv
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, taps, bias, ct):
+        y = conv.conv_kernel(x, taps, bias)
+        assert y.shape == (rows, 8192, width) and y.dtype == jnp.bfloat16
+        return (y * ct).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2)[:2 + has_bias])).lower(
+        spec(rows, 8192, wide), spec(4, width, dtype=jnp.float32),
+        spec(width, dtype=jnp.float32) if has_bias else None,
+        spec(rows, 8192, width)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    assert all(f"bf16[{rows},8192,{wide}]" in line for line in calls)
+    assert not re.search(rf"bf16\[{rows},8192,\d+\]\S* slice\(", text)
+    # the output, its cotangent and dx before the unread columns' zeros, each
+    # bf16; one f32 array of that shape would be two more
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * (
+        rows * 8192 * width * 2)
+
+
 @pytest.mark.parametrize("rows,seq,hidden,vocab,tied", [
     (1, 8192, 2560, 25008, True),  # c4-phi4flash-vp8-prepacked-8k's head
     (2, 4096, 2048, 50304, False),  # c4-olmoe-prepacked-4k's

@@ -25,7 +25,7 @@ import pytest
 
 from lance_distributed_training_tpu.models import get_task, tasks
 from lance_distributed_training_tpu.models import transformer
-from lance_distributed_training_tpu.ops import flash, scan
+from lance_distributed_training_tpu.ops import conv, flash, scan
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32_TOL = 2e-4  # summation order only (measured 1e-7 to 3e-6)
@@ -155,6 +155,7 @@ def test_loss_matches_reference_and_the_step_reports_its_mixers(whole):
     (_, got, stats, _), (_, want, _, _) = whole
     assert abs(float(got) - float(want)) < F32_TOL * float(want)
     assert float(stats["ssm_scan_fused"]) == 0.0
+    assert float(stats["conv_fused"]) == 0.0  # the CPU: the plain form
     assert float(stats["ssm_state_abs_max"]) > 0
     # four attention layers, depths 1, 3, 5, 7: lambda near lambda_init
     assert 0.2 < float(stats["diff_lambda_min"]) < float(
@@ -314,7 +315,8 @@ def test_the_scan_rule(monkeypatch):
 @pytest.fixture(scope="module")
 def kernel_run(ref, variables):
     """Logits, loss and gradients of published layers 2 to 7 of the tiny
-    stack (M, S, M*, F*, G, X) with the scan kernel and ``unequal_attention``
+    stack (M, S, M*, F*, G, X) with the scan's and the convolution's kernels
+    and ``unequal_attention``
     bound as the rules bind them on a TPU (queries and keys of 8, values of
     16, a window of 16 in a row of 128), in interpret mode, one program; and
     the reference's on the same batch."""
@@ -341,6 +343,7 @@ def kernel_run(ref, variables):
     with pytest.MonkeyPatch.context() as patch, \
             pltpu.force_tpu_interpret_mode():
         patch.setattr(scan, "scan_fused_applies", lambda *a, **k: True)
+        patch.setattr(conv, "conv_fused_applies", lambda *a, **k: True)
         traced = jax.jit(program).trace(v)
         got = jax.block_until_ready(traced.lower().compile()(v))
     old = ref.FIRST_LAYER, ref.KINDS
@@ -357,6 +360,7 @@ def kernel_run(ref, variables):
 def test_with_both_kernels_logits_and_loss_match_reference(kernel_run):
     text, (logits, loss, *_), (want_logits, want_loss, *_), live = kernel_run
     assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    assert "causal_conv_silu_fwd" in text and "causal_conv_silu_bwd" in text
     # a dead slot means nothing: the kernel lets it see the dead keys only
     assert _relative(logits * live, want_logits * live) < F32_TOL
     assert abs(float(loss) - float(want_loss)) < F32_TOL * float(want_loss)

@@ -279,10 +279,11 @@ def test_a_training_step_reports_its_gauges(bf16_task, variables, batch,
         return bf16_task.stats(outputs)
 
     stats = {k: float(v) for k, v in _one_program(step, variables).items()}
-    assert {"delta_fused", "delta_state_abs_max", "delta_decay_min",
-            "delta_beta_mean", "attn_gate_mean", "shared_gate_mean",
-            "moe_assignments_total"} <= set(stats)
+    assert {"delta_fused", "conv_fused", "delta_state_abs_max",
+            "delta_decay_min", "delta_beta_mean", "attn_gate_mean",
+            "shared_gate_mean", "moe_assignments_total"} <= set(stats)
     assert stats["delta_fused"] == 0  # the CPU: the plain chunked form
+    assert stats["conv_fused"] == 0  # and the convolution's plain form
     assert stats["moe_assignments_total"] == 4 * ROWS * SEQ * TOP_K
     assert 0 < stats["delta_decay_min"] < 0.5  # some head forgets fast
     assert stats["delta_state_abs_max"] > 1e-3
