@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import argparse
 
+from .models.transformer import CAUSAL_LMS
 from .trainer import TrainConfig, train
 
 
@@ -165,27 +166,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--no_wandb", action="store_true")
     p.add_argument("--model_name", type=str, default=None,
                    help="default per task: resnet50 / bert_base / gpt_base / "
-                        "clip_resnet50_bert; causal_lm also has olmoe_1b_7b "
-                        "(OLMoE-1B-7B at its published sizes: rotary RMSNorm "
-                        "decoder, 64 dropless SwiGLU experts, 8 a token), "
-                        "moonlight_16b_a3b (Moonlight-16B-A3B: latent "
-                        "attention, a leading dense layer, 64 experts with "
-                        "6 a token by sigmoid scores beside a shared one), "
-                        "phi4_mini_flash (Phi-4-mini-flash-reasoning: "
-                        "SambaY's Mamba, differential attention and gated "
-                        "memory layers), zaya1_8b (ZAYA1-8B: attention in a "
-                        "compressed latent with causal convolutions, 8 query "
-                        "heads over 2 key/value heads, an MLP router with a "
-                        "state carried from layer to layer, 1 of 16 experts "
-                        "a token), qwen3_next_80b_a3b (Qwen3-Next-80B-A3B: "
-                        "three gated-delta-rule linear-attention layers to "
-                        "one gated softmax-attention layer of 16 query heads "
-                        "over 2 key/value heads of 256, 10 of 512 experts a "
-                        "token beside a gated shared one; the first log line "
-                        "says delta=fused kernel or chunked and conv=fused "
-                        "kernel or plain) and olmoe_tiny, "
-                        "moonlight_tiny, phi4_mini_flash_tiny, zaya_tiny, "
-                        "qwen3_next_tiny")
+                        "clip_resnet50_bert; causal_lm has "
+                        + ", ".join(sorted(CAUSAL_LMS)) + " (models/"
+                        "transformer.py states each preset's source and "
+                        "sizes; the first log line says which form each of "
+                        "its kernels runs: attention=, scan=, delta=, conv=)")
     p.add_argument("--num_layers", type=int, default=0,
                    help=">0: this many layers of a masked_lm/causal_lm "
                         "transformer preset in place of its own depth, at "
@@ -193,13 +178,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "that does not fit); 0 keeps the preset's depth")
     p.add_argument("--expert_share", type=str, default=None,
                    metavar="RANK/RANKS",
-                   help="the experts of each dropless expert layer (olmoe_*, "
-                        "moonlight_*, zaya*, qwen3_next_*) that this process "
-                        "holds as rank "
+                   help="the experts of each dropless expert layer (a "
+                        "causal_lm preset with experts of its own) that this "
+                        "process holds as rank "
                         "RANK of RANKS that share the layer: E/RANKS of them "
-                        "from RANK*E/RANKS on (moonlight_16b_a3b 0/8: experts "
-                        "0-7 of 64; zaya1_8b 0/2: experts 0-7 of 16; "
-                        "qwen3_next_80b_a3b 0/16: experts 0-31 of 512). The "
+                        "from RANK*E/RANKS on (0/8 of 64 experts: experts "
+                        "0-7). The "
                         "router stays whole; what absent experts would add "
                         "is left out. With --vocab_size as the vocabulary's "
                         "slice and --num_layers, one chip's share of an "
@@ -207,11 +191,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--layer_span", type=str, default=None,
                    metavar="FIRST:END",
                    help="the published layers [FIRST, END) that this process "
-                        "holds of a preset whose layers differ by kind "
-                        "(phi4_mini_flash*: 14:20 is M, S, M*, F*, G, X, one "
-                        "of each; qwen3_next_*: 0:4 is three linear-"
-                        "attention layers and one attention layer, a whole "
-                        "period), as a pipeline stage would; a layer keeps "
+                        "holds of a preset whose layers differ by kind, "
+                        "as a pipeline stage would; a layer keeps "
                         "its published index, and a span in which a G or X "
                         "layer has no M* or F* before it is refused. With "
                         "--vocab_size as the vocabulary's slice, one chip's "

@@ -28,21 +28,10 @@ from ..ops.image import normalize_images, random_flip
 from . import resnet as _resnet
 from .clip import CLIP, clip_contrastive_loss, clip_resnet50_bert, clip_tiny
 from .transformer import (
+    CAUSAL_LMS,
     TransformerDecoder,
     bert_base,
     bert_small,
-    gpt_base,
-    gpt_small,
-    moonlight_16b_a3b,
-    moonlight_tiny,
-    phi4_mini_flash,
-    phi4_mini_flash_tiny,
-    qwen3_next_80b_a3b,
-    qwen3_next_tiny,
-    olmoe_1b_7b,
-    olmoe_tiny,
-    zaya1_8b,
-    zaya_tiny,
 )
 
 __all__ = ["Task", "get_task", "TASK_REGISTRY"]
@@ -64,6 +53,10 @@ class Task:
     # fill); None for a task with nothing to report. ``loss`` and ``stats``
     # take the outputs of any ``forward``; ``metric`` those of an eval
     # forward (``train=False``), which are what they were for every task
+    kernels: dict = dataclasses.field(default_factory=dict)  # which kernels
+    # a sequence model's layers run at the task's seq_len, by name: True the
+    # fused kernel, False the plain form (the model's ``kernels``: each mixer
+    # asks its op's own rule); empty for a task with none to choose
 
 
 # ---------------------------------------------------------------- classification
@@ -325,39 +318,11 @@ def _masked_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
 
     return Task("masked_lm", model, init_variables, forward, loss, metric,
                 metric_name="masked_token_accuracy",
-                stats=lambda outputs: getattr(outputs[0], "stats", {}))
+                stats=lambda outputs: getattr(outputs[0], "stats", {}),
+                kernels=model.kernels(seq_len))
 
 
 # ---------------------------------------------------------------- causal LM
-# preset -> (constructor, its own vocabulary, weight of each auxiliary term
-# its expert layers sow). The GPT presets have expert layers only under
-# --num_experts (MoEMLP); every OLMoE layer is one (DroplessMoE), with the
-# two weights of the paper (arXiv:2409.02060, section 4.1); Moonlight's
-# (all but its first) sow the sequence-wise balance term, with DeepSeek-V3's
-# weight (arXiv:2412.19437, section 4.2: alpha 0.0001). ZAYA1's are balanced
-# by the selection bias alone: what they sow has no weight here. Qwen3-Next's
-# take the balance term at its published class's default weight
-# (router_aux_loss_coef 0.001) and no z term.
-_SWITCH_AUX = {"load_balance": 0.01}
-_OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
-_MOONLIGHT_AUX = {"seq_balance": 0.0001}
-_QWEN3_NEXT_AUX = {"load_balance": 0.001}
-_CAUSAL_LMS: dict = {
-    "gpt_base": (gpt_base, 50257, _SWITCH_AUX),
-    "gpt_small": (gpt_small, 50257, _SWITCH_AUX),
-    "olmoe_1b_7b": (olmoe_1b_7b, 50304, _OLMOE_AUX),
-    "olmoe_tiny": (olmoe_tiny, 512, _OLMOE_AUX),
-    "moonlight_16b_a3b": (moonlight_16b_a3b, 163840, _MOONLIGHT_AUX),
-    "moonlight_tiny": (moonlight_tiny, 512, _MOONLIGHT_AUX),
-    "phi4_mini_flash": (phi4_mini_flash, 200064, {}),
-    "phi4_mini_flash_tiny": (phi4_mini_flash_tiny, 512, {}),
-    "zaya1_8b": (zaya1_8b, 262272, {}),
-    "zaya_tiny": (zaya_tiny, 512, {}),
-    "qwen3_next_80b_a3b": (qwen3_next_80b_a3b, 151936, _QWEN3_NEXT_AUX),
-    "qwen3_next_tiny": (qwen3_next_tiny, 512, _QWEN3_NEXT_AUX),
-}
-
-
 def _weighted_aux(sown: dict, weights: dict):
     """Σ over the terms' names of weight × (that term summed over the
     layers that sowed it); a term without a weight is left out."""
@@ -371,119 +336,82 @@ def _weighted_aux(sown: dict, weights: dict):
                jnp.zeros((), jnp.float32))
 
 
-def _sown_by_name(sown: dict) -> dict:
-    """What the layers sowed into one collection, by the name it was sown
-    under: a list with one entry a layer that sowed it, in layer order."""
+_OVER_LAYERS = {"max": jnp.max, "min": jnp.min, "total": jnp.sum}
+# The scalars sown before PR 44's rule, by the name each was sown under: the
+# names it is published by (where they are not its own), and the order in
+# which the step has always reduced them, so that its lowered text stays
+# what tests/test_zaya.py holds by hash. The expert layers' (``moe_stats``):
+# assignment counts ([E] a layer), under a share the same of the experts held
+# here, how many layers built the worst-case list and how full the built
+# lists were; a selection bias's largest magnitude; with one expert a token
+# the mean weight it got; a shared expert's gate. The mixers'
+# (``mixer_stats``): the gated delta rule's state, decay and write strength,
+# the attention layers' output gate, a state-space layer's state,
+# differential lambda, ZAYA's key temperature and residual scales.
+_SOWN_AS = {
+    "group_sizes": ("moe_assignments_total", "moe_expert_load_max",
+                    "moe_expert_load_mean"),
+    "held_sizes": ("moe_local_assignments_total", "moe_local_load_max",
+                   "moe_local_load_mean"),
+    "over_usual": ("moe_local_fallback_total",),
+    "row_fill": ("moe_local_row_fill_pct",),
+    "bias_abs_max": ("moe_router_bias_abs_max",),
+    "top1_prob": ("router_top1_prob_mean",),
+    "shared_gate_mean": ("shared_gate_mean",),
+    "delta_state_abs_max": ("delta_state_abs_max",),
+    "delta_decay_min": ("delta_decay_min",),
+    "delta_beta_mean": ("delta_beta_mean",),
+    "attn_gate": ("attn_gate_mean",),
+    "ssm_state_abs_max": ("ssm_state_abs_max",),
+    "diff_lambda": ("diff_lambda_min", "diff_lambda_max"),
+    "cca_key_temperature": ("cca_key_temperature_max",),
+    "residual_scale": ("residual_scale_min", "residual_scale_max"),
+}
+
+
+def _step_stats(sown: dict) -> dict:
+    """The step's scalars from what the layers sowed into ``moe_stats`` and
+    ``mixer_stats``: each is published under the name it was sown by, its
+    values (one a layer that sowed it) reduced as the name ends, ``_max`` to
+    the largest, ``_min`` the least, ``_total`` the sum, anything else
+    (``_mean``, ``_pct``) the mean. So a layer that sows a new scalar edits
+    nothing here."""
     by_name: dict = {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
-        by_name.setdefault(path[-2].key, []).append(leaf)
-    return by_name
-
-
-def _expert_load(sown: dict) -> dict:
-    """The step's expert-load scalars from what the expert layers sow into
-    ``moe_stats``: from their assignment counts (``group_sizes``, [E] a
-    layer) all assignments, and the busiest and the mean expert over all
-    layers; under a share the same of the experts held here
-    (``held_sizes``: ``moe_local_*``) and how many layers built the
-    worst-case list (``over_usual``) and how full the built lists were
-    (``row_fill``: live rows over built rows, in percent, mean over the
-    layers); with a selection bias its largest magnitude over the layers;
-    with one expert a token the mean weight that expert got; with a gate
-    on the shared expert its mean over the live tokens and the layers."""
-    by_name = _sown_by_name(sown)
-
-    def load(name, total, prefix):
-        sizes = jnp.stack(by_name[name]).astype(jnp.float32)
-        return {total: sizes.sum(), f"{prefix}_max": sizes.max(),
-                f"{prefix}_mean": sizes.mean()}
-
-    out = load("group_sizes", "moe_assignments_total", "moe_expert_load")
-    if "held_sizes" in by_name:
-        out.update(load("held_sizes", "moe_local_assignments_total",
-                        "moe_local_load"))
-        # layers whose held rows overflowed the usual list this step
-        out["moe_local_fallback_total"] = jnp.stack(
-            by_name["over_usual"]).sum()
-        out["moe_local_row_fill_pct"] = jnp.stack(by_name["row_fill"]).mean()
-    if "bias_abs_max" in by_name:
-        out["moe_router_bias_abs_max"] = jnp.stack(
-            by_name["bias_abs_max"]).max()
-    if "top1_prob" in by_name:
-        out["router_top1_prob_mean"] = jnp.stack(by_name["top1_prob"]).mean()
-    if "shared_gate_mean" in by_name:
-        out["shared_gate_mean"] = jnp.stack(
-            by_name["shared_gate_mean"]).mean()
-    return out
-
-
-def _mixer_stats(sown: dict, scan_fused: Optional[bool] = None,
-                 delta_fused: Optional[bool] = None,
-                 conv_fused: Optional[bool] = None) -> dict:
-    """A stack's step scalars from what its mixers and layers sow into
-    ``mixer_stats``. SambaY's: the largest magnitude in a state-space
-    layer's state at a row's end, the least and the largest differential
-    lambda over the attention layers, and whether the scan runs its kernel
-    (``scan_fused``). SambaY's and Qwen3-Next's: whether the depthwise
-    causal convolution and its SiLU run theirs (``conv_fused``). ZAYA's: the
-    largest key temperature, and the least and the largest of the scales on
-    the residual sums' two sides. Qwen3-Next's:
-    whether the gated delta rule runs its kernel (``delta_fused``), the
-    largest magnitude in a linear-attention layer's state at a row's end, the
-    least ``exp(g)`` a token and head saw, the mean write strength, and the
-    mean of the attention layers' output gate."""
-    by_name = _sown_by_name(sown)
+    for collection in ("moe_stats", "mixer_stats"):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+                sown.get(collection, {})):
+            by_name.setdefault(path[-2].key, []).append(leaf)
     out = {}
-    if scan_fused is not None:
-        out["ssm_scan_fused"] = jnp.float32(scan_fused)
-    if delta_fused is not None:
-        out["delta_fused"] = jnp.float32(delta_fused)
-    if conv_fused is not None:
-        out["conv_fused"] = jnp.float32(conv_fused)
-    for name, over in (("delta_state_abs_max", jnp.max),
-                       ("delta_decay_min", jnp.min),
-                       ("delta_beta_mean", jnp.mean)):
-        if name in by_name:
-            out[name] = over(jnp.stack(by_name[name]))
-    if "attn_gate" in by_name:
-        out["attn_gate_mean"] = jnp.stack(by_name["attn_gate"]).mean()
-    if "ssm_state_abs_max" in by_name:
-        out["ssm_state_abs_max"] = jnp.stack(
-            by_name["ssm_state_abs_max"]).max()
-    if "diff_lambda" in by_name:
-        lam = jnp.stack(by_name["diff_lambda"])
-        out.update(diff_lambda_min=lam.min(), diff_lambda_max=lam.max())
-    if "cca_key_temperature" in by_name:
-        out["cca_key_temperature_max"] = jnp.stack(
-            by_name["cca_key_temperature"]).max()
-    if "residual_scale" in by_name:
-        scale = jnp.stack(by_name["residual_scale"])
-        out.update(residual_scale_min=scale.min(),
-                   residual_scale_max=scale.max())
+    for sown_as in [*(n for n in _SOWN_AS if n in by_name),
+                    *(n for n in by_name if n not in _SOWN_AS)]:
+        values = jnp.stack(by_name[sown_as]).astype(jnp.float32)
+        for name in _SOWN_AS.get(sown_as, (sown_as,)):
+            out[name] = _OVER_LAYERS.get(name.rsplit("_", 1)[-1],
+                                         jnp.mean)(values)
     return out
 
 
-def _layer_span(span: Optional[str], ctor) -> dict:
+def _layer_span(span: Optional[str], model) -> dict:
     """``--layer_span first:end`` as the stack's fields: the published
     layers ``[first, end)`` of a preset whose layers differ by kind."""
     if span is None:
         return {}
-    if not ctor.keywords.get("layer_kinds"):
+    kinds = getattr(model, "layer_kinds", ())
+    if not kinds:
         raise ValueError("layer_span states which published layers of a "
-                         "preset with layers of several kinds are held "
-                         "(phi4_mini_flash*, qwen3_next_*); --num_layers "
-                         "cuts the others")
+                         "preset with layers of several kinds are held; "
+                         "--num_layers cuts the others")
     try:
         first, end = (int(x) for x in span.split(":"))
     except ValueError:
         raise ValueError(f"layer_span is 'first:end', got {span!r}") from None
-    if not 0 <= first < end <= len(ctor.keywords["layer_kinds"]):
+    if not 0 <= first < end <= len(kinds):
         raise ValueError(f"layer_span {span} is not inside the preset's "
-                         f"{len(ctor.keywords['layer_kinds'])} layers")
+                         f"{len(kinds)} layers")
     return {"first_layer": first, "num_layers": end - first}
 
 
-def _expert_share(share: Optional[str], ctor) -> tuple:
+def _expert_share(share: Optional[str], experts: int) -> tuple:
     """``--expert_share r/n`` as DroplessMoE's fields: rank ``r`` of ``n``
     that share each expert layer holds the ``E / n`` experts from ``r * E /
     n`` on."""
@@ -494,7 +422,6 @@ def _expert_share(share: Optional[str], ctor) -> tuple:
     except ValueError:
         raise ValueError(f"expert_share is 'rank/ranks', got {share!r}") \
             from None
-    experts = ctor.keywords["num_experts"]
     if not 0 <= rank < ranks or experts % ranks:
         raise ValueError(
             f"expert_share {share}: rank in [0, ranks), and ranks divides "
@@ -527,33 +454,29 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                     moe_every: int = 2, num_layers: int = 0,
                     expert_share: Optional[str] = None,
                     layer_span: Optional[str] = None) -> Task:
-    """Decoder-only next-token prediction (the GPT presets on the encoder
-    trunk, the OLMoE, Moonlight, Phi-4-mini-flash, ZAYA1 and Qwen3-Next
-    presets on the decoder stack) over the same packed
+    """Decoder-only next-token prediction (``CAUSAL_LMS``: the GPT presets
+    on the encoder trunk, the others on the decoder stack) over the same packed
     token columns as masked-LM (``create_text_token_dataset``) — the text arm
     beyond the reference's vision-only scope, sharing the trainer, samplers
     and storage unchanged. The shift by one token is applied to the targets
     and their weights and never to the ``[B, S, V]`` logits: a slice of
     ``S - 1`` rows makes XLA re-lay them (and their cotangent) off the
     layout the head wrote, in loops where ``V`` is no multiple of 128."""
-    if model_name not in _CAUSAL_LMS:
+    if model_name not in CAUSAL_LMS:
         raise ValueError(f"Invalid model name: {model_name} "
-                         f"(have {sorted(_CAUSAL_LMS)})")
-    ctor, own_vocab, aux_weights = _CAUSAL_LMS[model_name]
-    decoder = ctor.func is TransformerDecoder  # no table to size by seq_len
-    kinds = bool(ctor.keywords.get("layer_kinds"))  # mixers that sow stats
-    sambay = bool(ctor.keywords.get("hybrid"))
-    # every layer but the leading dense ones has dropless experts
-    dropless = decoder and ctor.keywords["num_experts"] > 0
+                         f"(have {sorted(CAUSAL_LMS)})")
+    ctor, own_vocab, aux_weights = CAUSAL_LMS[model_name]
+    model = ctor(vocab_size=vocab_size or own_vocab,
+                 attention_fn=attention_fn, remat=remat)
+    decoder = isinstance(model, TransformerDecoder)  # no table to size by
+    # seq_len. Every layer but the leading dense ones has dropless experts
+    dropless = decoder and model.num_experts > 0
     if num_layers and layer_span is not None:
         raise ValueError("num_layers and layer_span both state the depth")
-    kwargs = dict(vocab_size=vocab_size or own_vocab,
-                  attention_fn=attention_fn, remat=remat,
-                  **_depth(num_layers), **_layer_span(layer_span, ctor))
+    changes = {**_depth(num_layers), **_layer_span(layer_span, model)}
     if expert_share is not None and not dropless:
         raise ValueError("expert_share states which of a dropless "
-                         "preset's experts are held (olmoe_*, "
-                         "moonlight_*, zaya*, qwen3_next_*)")
+                         "preset's experts are held")
     if decoder:
         if num_experts:
             raise ValueError(
@@ -561,31 +484,20 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                 f"{'expert ' if dropless else ''}layers; --num_experts "
                 "adds switch experts to the BERT/GPT presets only")
         if dropless:
-            kwargs["moe"] = ctor.keywords.get("moe", ()) + _expert_share(
-                expert_share, ctor)
+            changes["moe"] = model.moe + _expert_share(
+                expert_share, model.num_experts)
     else:
-        kwargs.update(max_len=seq_len, num_experts=num_experts,
-                      moe_every=moe_every)
-    model = ctor(**kwargs)
-    scan_fused = None
-    if sambay:
-        from ..ops.scan import scan_fused_applies
-
-        scans = model.scan_shape  # a span that cannot run is refused here
-        scan_fused = bool(scans) and scan_fused_applies(seq_len, *scans)
-    delta_fused = None
-    if decoder and model.delta_shape:
-        from ..ops.delta import delta_fused_applies
-
-        delta_fused = delta_fused_applies(seq_len, *model.delta_shape)
-    conv_fused = None
-    if decoder and model.conv_shape:
-        from ..ops.conv import conv_fused_applies
-
-        conv_fused = conv_fused_applies(seq_len, *model.conv_shape)
-    sows = ((["aux_loss", "moe_stats", "router_state"] if dropless
-             else ["aux_loss"] if num_experts > 0 else [])
-            + ["mixer_stats"] * kinds)
+        changes.update(max_len=seq_len, num_experts=num_experts,
+                       moe_every=moe_every)
+    model = model.clone(**changes)
+    kernels = model.kernels(seq_len)  # a span that cannot run is refused here
+    # All but attention's (a gauge the trainer sets once, on the host) ride
+    # the step's stats as constants: ``<name>_fused``, the scan's under the
+    # name it has had
+    fused = {{"scan": "ssm_scan_fused"}.get(name, f"{name}_fused"): on
+             for name, on in kernels.items() if name != "attention"}
+    sows = (["aux_loss", "moe_stats", "router_state", "mixer_stats"]
+            if decoder else ["aux_loss"] if num_experts > 0 else [])
 
     def init_variables(rng):
         ids = jnp.zeros((1, seq_len), jnp.int32)
@@ -618,16 +530,11 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
             aux = _weighted_aux(sown.get("aux_loss", {}), aux_weights)
             if not decoder:
                 return (logits, aux), None
-            stats = {}
-            if dropless:
-                stats.update(_expert_load(sown["moe_stats"]))
-            if kinds:
-                stats.update(_mixer_stats(sown["mixer_stats"], scan_fused,
-                                          delta_fused, conv_fused))
             # the bias as the routers left it: the step's new state
             state = ({"batch_stats": sown["router_state"]}
                      if "router_state" in sown else None)
-            return (logits, aux, stats), state
+            stats = {name: jnp.float32(on) for name, on in fused.items()}
+            return (logits, aux, {**stats, **_step_stats(sown)}), state
         logits = model.apply(variables, ids, mask, train=train,
                              segment_ids=seg, position_ids=pos)
         return (logits, jnp.zeros((), jnp.float32)), None
@@ -666,7 +573,8 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
 
     return Task("causal_lm", model, init_variables, forward, loss, metric,
                 metric_name="next_token_accuracy",
-                stats=(lambda outputs: outputs[2]) if decoder else None)
+                stats=(lambda outputs: outputs[2]) if decoder else None,
+                kernels=kernels)
 
 
 # ------------------------------------------------------- pipelined masked LM
@@ -853,11 +761,8 @@ def get_task(
     layer_span: Optional[str] = None,
 ) -> Task:
     """``vocab_size=None`` means "the model's own default" (bert_*: 30522,
-    gpt_*: 50257, olmoe_1b_7b: 50304, moonlight_16b_a3b: 163840,
-    phi4_mini_flash: 200064, zaya1_8b: 262272, qwen3_next_80b_a3b: 151936,
-    olmoe_tiny, moonlight_tiny, phi4_mini_flash_tiny, zaya_tiny and
-    qwen3_next_tiny: 512, clip_tiny: 1000,
-    clip_resnet50_bert: 30522);
+    a causal_lm preset: its record's in ``transformer.CAUSAL_LMS``,
+    clip_tiny: 1000, clip_resnet50_bert: 30522);
     explicit values always apply verbatim.
     ``param_dtype`` overrides the parameter/optimizer-state dtype (ResNet
     family only; e.g. ``jnp.bfloat16`` halves weight HBM). ``num_layers``
@@ -866,8 +771,7 @@ def get_task(
     and ``expert_share`` (``"rank/ranks"``, the dropless causal_lm presets)
     the experts of each layer that this rank of an expert-parallel job
     holds; ``layer_span`` (``"first:end"``, the presets whose layers differ
-    by kind: phi4_mini_flash*, qwen3_next_*) the published layers a pipeline
-    stage holds;
+    by kind) the published layers a pipeline stage holds;
     with ``vocab_size`` as its slice of the vocabulary that is the share a
     configuration states."""
     if expert_share is not None and task_type != "causal_lm":
@@ -875,8 +779,7 @@ def get_task(
                          "with dropless expert layers")
     if layer_span is not None and task_type != "causal_lm":
         raise ValueError("layer_span applies to the causal_lm presets whose "
-                         "layers differ by kind (phi4_mini_flash*, "
-                         "qwen3_next_*)")
+                         "layers differ by kind")
     if num_layers and (task_type not in ("masked_lm", "causal_lm")
                        or pipeline_parallelism > 1):
         raise ValueError(

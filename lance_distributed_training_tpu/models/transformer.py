@@ -15,14 +15,16 @@ sequence-parallel ring variant (:mod:`..parallel.ring_attention`) can swap in.
 from __future__ import annotations
 
 import math
+from contextlib import nullcontext
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ..ops.conv import causal_conv_silu, causal_depthwise_conv
+from .moe import DroplessMoE, MoEMLP, StateRouter, SwiGLU
 
 __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "bert_small", "gpt_base", "gpt_small", "olmoe_1b_7b",
@@ -30,7 +32,8 @@ __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "phi4_mini_flash", "phi4_mini_flash_tiny", "sambay_layers",
            "zaya1_8b", "zaya_tiny", "qwen3_next_80b_a3b", "qwen3_next_tiny",
            "qwen3_next_layers", "dot_product_attention", "RMSNorm",
-           "rotary_embedding", "causal_depthwise_conv"]
+           "rotary_embedding", "causal_depthwise_conv", "LayerKind",
+           "LAYER_KINDS", "Preset", "CAUSAL_LMS"]
 
 
 def dot_product_attention(q, k, v, mask=None, dtype=jnp.bfloat16,
@@ -88,6 +91,15 @@ def _attention_masks(attention_mask, segment_ids, attention_fn):
     return segment_attention_mask(segment_ids), None
 
 
+def _attention_kernel(attention_fn, seq_len: int, d_qk: int, d_v: int) -> dict:
+    """An attention mixer's answer to ``kernels``: whether a call with these
+    shapes runs the fused kernel, asked of the function it was bound
+    (``ops.flash.make_flash_attention``'s ``fused``, the test each call
+    makes); one without the attribute (ring attention, none) runs dense."""
+    fused = getattr(attention_fn, "fused", None)
+    return {"attention": bool(fused and fused(seq_len, d_qk, d_v))}
+
+
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis: statistics
     in f32, result in ``dtype``, a learned f32 scale and no bias. With
@@ -140,10 +152,17 @@ class SelfAttention(nn.Module):
     causal: bool = False  # decoder (GPT) attention; custom attention_fns
     # must bind their own causality (e.g. make_flash_attention(causal=True))
     use_bias: bool = True
-    qk_norm_eps: float = 0.0  # >0: RMSNorm with a learned scale on the
+    norm_eps: float = 0.0  # >0: RMSNorm with a learned scale on the
     # whole query and key projections, before the split into heads
     rope_theta: float = 0.0  # >0: rotary positions on queries and keys
     kernel_init: Callable = nn.linear.default_kernel_init
+
+    def kernels(self, seq_len: int, width: int) -> dict:
+        """Which kernels a call at ``seq_len`` on a stream ``width`` wide
+        runs, by name: every mixer class has this method."""
+        head_dim = width // self.num_heads
+        return _attention_kernel(self.attention_fn, seq_len, head_dim,
+                                 head_dim)
 
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
@@ -156,8 +175,8 @@ class SelfAttention(nn.Module):
         q = dense(features=(self.num_heads, head_dim), name="query")(x)
         k = dense(features=(self.num_heads, head_dim), name="key")(x)
         v = dense(features=(self.num_heads, head_dim), name="value")(x)
-        if self.qk_norm_eps > 0:
-            norm = partial(RMSNorm, self.qk_norm_eps, self.dtype)
+        if self.norm_eps > 0:
+            norm = partial(RMSNorm, self.norm_eps, self.dtype)
             q = norm(name="q_norm")(q.reshape(b, s, h)).reshape(q.shape)
             k = norm(name="k_norm")(k.reshape(b, s, h)).reshape(k.shape)
         if self.rope_theta > 0:
@@ -200,6 +219,10 @@ class LatentAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
     kernel_init: Callable = nn.linear.default_kernel_init
+
+    def kernels(self, seq_len: int, width: int) -> dict:
+        return _attention_kernel(self.attention_fn, seq_len,
+                                 self.nope_dim + self.rope_dim, self.v_dim)
 
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
@@ -265,6 +288,11 @@ class DifferentialAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
     kernel_init: Callable = nn.linear.default_kernel_init
+
+    def kernels(self, seq_len: int, width: int) -> dict:
+        head_dim = width // self.num_heads
+        return _attention_kernel(self.attention_fn, seq_len, head_dim,
+                                 2 * head_dim)
 
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, shared=None):
@@ -350,6 +378,10 @@ class ConvolutionalAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
     kernel_init: Callable = nn.linear.default_kernel_init
+
+    def kernels(self, seq_len: int, width: int) -> dict:
+        return _attention_kernel(self.attention_fn, seq_len, self.head_dim,
+                                 self.head_dim)
 
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
@@ -454,6 +486,13 @@ class MambaMixer(nn.Module):
     dtype: Any = jnp.bfloat16
     kernel_init: Callable = nn.linear.default_kernel_init
 
+    def kernels(self, seq_len: int, width: int) -> dict:
+        from ..ops.conv import conv_fused_applies
+        from ..ops.scan import scan_fused_applies
+
+        return {"scan": scan_fused_applies(seq_len, self.inner, self.states),
+                "conv": conv_fused_applies(seq_len, self.inner, self.conv)}
+
     @nn.compact
     def __call__(self, u):
         from ..ops.scan import selective_scan
@@ -515,6 +554,9 @@ class GatedMemoryUnit(nn.Module):
     dtype: Any = jnp.bfloat16
     kernel_init: Callable = nn.linear.default_kernel_init
 
+    def kernels(self, seq_len: int, width: int) -> dict:
+        return {}  # two products and a gate: XLA's own
+
     @nn.compact
     def __call__(self, u, memory):
         dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
@@ -561,6 +603,16 @@ class GatedDeltaNet(nn.Module):
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     kernel_init: Callable = nn.linear.default_kernel_init
+
+    def kernels(self, seq_len: int, width: int) -> dict:
+        from ..ops.conv import conv_fused_applies
+        from ..ops.delta import delta_fused_applies
+
+        convolved = (2 * self.key_heads * self.key_dim
+                     + self.value_heads * self.value_dim)
+        return {"delta": delta_fused_applies(seq_len, self.value_heads,
+                                             self.key_dim, self.value_dim),
+                "conv": conv_fused_applies(seq_len, convolved, self.conv)}
 
     @nn.compact
     def __call__(self, u):
@@ -656,6 +708,10 @@ class GatedAttention(nn.Module):
     attention_fn: Optional[Callable] = None
     kernel_init: Callable = nn.linear.default_kernel_init
 
+    def kernels(self, seq_len: int, width: int) -> dict:
+        return _attention_kernel(self.attention_fn, seq_len, self.head_dim,
+                                 self.head_dim)
+
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
         b, s, h = x.shape
@@ -713,8 +769,6 @@ class EncoderBlock(nn.Module):
         x = x + y
         y = norm(name="ln_mlp")(x)
         if self.num_experts > 0:
-            from .moe import MoEMLP
-
             y = MoEMLP(self.num_experts, self.mlp_dim,
                        self.capacity_factor, self.dtype, name="moe")(y)
         else:
@@ -752,6 +806,13 @@ class TransformerEncoder(nn.Module):
     moe_every: int = 2
     capacity_factor: float = 1.25
     causal: bool = False  # decoder-only (GPT) variant: autoregressive mask
+
+    def kernels(self, seq_len: int) -> dict:
+        """Which kernels the layers run at ``seq_len``, by name: what their
+        one kind of attention says (:meth:`TransformerDecoder.kernels`)."""
+        return SelfAttention(
+            self.num_heads, attention_fn=self.attention_fn,
+            parent=None).kernels(seq_len, self.hidden_size)
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, train: bool = True,
@@ -807,29 +868,68 @@ class TransformerEncoder(nn.Module):
         return tied_head(x)
 
 
+class LayerKind(NamedTuple):
+    """How a :class:`DecoderBlock` of one kind runs its mixer: the class (a
+    preset's ``parts`` size it), its name among the layer's parameters, the
+    named scope around its call, the fields the kind itself sets, how many
+    of ``(mask, segment_ids, position_ids)`` the call takes after the normed
+    stream, and which entry of ``handed`` it takes last (``takes``) or its
+    second output becomes (``hands``)."""
+
+    mixer: type
+    name: str
+    scope: str = ""  # the mixer's own scopes only
+    fixed: tuple = ()  # (name, value) pairs
+    sequence: int = 0
+    takes: Optional[int] = None
+    hands: Optional[int] = None
+
+
+# what rides from layer to layer beside the stream (``handed``): an M*
+# layer's scan output, an F* layer's keys and values, a router's state
+_MEMORY, _KEYS_VALUES, _ROUTER_STATE = range(3)
+# A layer kind is an entry here, its mixer's class above and, where the class
+# has sizes, a ``partial`` in the preset's ``parts``. "": rotary attention
+# with a norm on queries and keys (OLMoE's); "L": latent attention
+# (Moonlight's); SambaY's six (arXiv:2507.06607): "M" a Mamba mixer, "M*" one
+# that hands on its scan output, "S" window and "F*" full differential
+# attention, the latter handing on its keys and values, "G" a gated memory
+# unit over M*'s output, "X" differential attention over F*'s keys and
+# values; "C": ZAYA1's attention in a latent (arXiv:2511.17127; the block
+# gives such a layer its router and residual scales); Qwen3-Next's two: "D" a
+# gated DeltaNet, "A" gated attention.
+LAYER_KINDS: dict = {
+    "": LayerKind(SelfAttention, "attn", "attention",
+                  (("causal", True), ("use_bias", False)), 3),
+    "L": LayerKind(LatentAttention, "attn", "attention", sequence=3),
+    "M": LayerKind(MambaMixer, "ssm"),
+    "M*": LayerKind(MambaMixer, "ssm", hands=_MEMORY),
+    "S": LayerKind(DifferentialAttention, "attn", "attention", sequence=2),
+    "F*": LayerKind(DifferentialAttention, "attn", "attention",
+                    (("window", 0),), 2, hands=_KEYS_VALUES),
+    "G": LayerKind(GatedMemoryUnit, "gmu", "gmu", takes=_MEMORY),
+    "X": LayerKind(DifferentialAttention, "attn", "attention",
+                   (("window", 0),), 2, takes=_KEYS_VALUES),
+    "C": LayerKind(ConvolutionalAttention, "attn", "attention", sequence=3),
+    "D": LayerKind(GatedDeltaNet, "gdn", "linear_attention"),
+    "A": LayerKind(GatedAttention, "attn", "attention", sequence=3),
+}
+
+
 class DecoderBlock(nn.Module):
     """Pre-norm decoder layer ``x + mixer(norm(x))``, ``x + ffn(norm(x))``,
-    bias-free. The choices a layer makes are fields. ``kind`` is its mixer:
-    ``""`` rotary attention, by ``latent`` (None: :class:`SelfAttention` with
-    a norm on queries and keys, OLMoE's; a ``(kv_rank, nope_dim, rope_dim,
-    v_dim)``: :class:`LatentAttention`); or one of SambaY's five
-    (arXiv:2507.06607, which ``hybrid`` sizes): ``"M"`` a :class:`MambaMixer`,
-    ``"M*"`` one that hands on its scan output, ``"S"`` window and ``"F*"``
-    full :class:`DifferentialAttention`, the latter handing on its keys and
-    values, ``"G"`` a :class:`GatedMemoryUnit` over M*'s output, ``"X"``
-    differential attention over F*'s keys and values; or ``"C"``, ZAYA1's
-    layer (arXiv:2511.17127, which ``cca`` sizes): a
-    :class:`ConvolutionalAttention`, an expert layer whose router is a
-    :class:`..moe.StateRouter` with a state handed from layer to layer, and
-    learned scales and shifts on both sides of both residual sums; or one
-    of Qwen3-Next's two (``delta`` and ``gated`` size them): ``"D"`` a
-    :class:`GatedDeltaNet`, ``"A"`` a :class:`GatedAttention`, both under
-    RMSNorm in its ``1 + w`` form (``norm_offset``).
+    bias-free. The choices a layer makes are fields. ``kind`` is its mixer,
+    by :data:`LAYER_KINDS`; ``parts`` holds, for each class among the
+    layer's parts that has sizes of its own, a ``partial`` of the class over
+    them, under the class's own field names. A ``"C"`` layer (ZAYA1's) also
+    has an expert layer whose router is a :class:`..moe.StateRouter` with a
+    state handed from layer to layer, and learned scales and shifts on both
+    sides of both residual sums.
     ``dense_dim`` is the feed-forward (0: the dropless expert layer that
     ``moe`` describes; > 0: a dense SwiGLU of that width), ``layer_norm`` the
-    norm (LayerNorm, else RMSNorm). The call takes and returns, beside ``x``,
-    what is handed on: ``(m, (k, v), r)``, each None until its layer has
-    run."""
+    norm (LayerNorm, else RMSNorm, with ``norm_offset`` in its ``1 + w``
+    form). The call takes and returns, beside ``x``, what is handed on:
+    ``(m, (k, v), r)``, each None until its layer has run."""
 
     num_heads: int
     expert_dim: int
@@ -840,37 +940,32 @@ class DecoderBlock(nn.Module):
     init_std: float = 0.02
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
-    latent: Optional[tuple] = None
     dense_dim: int = 0
     moe: tuple = ()  # further fields of DroplessMoE, as (name, value) pairs
     kind: str = ""
     depth: int = 0  # the layer's published index
-    hybrid: tuple = ()  # (kv_heads, window, inner, states, conv, dt_rank)
     layer_norm: bool = False
-    cca: tuple = ()  # (kv_heads, head_dim, rotary_dim, router_dim)
-    delta: tuple = ()  # (key_heads, value_heads, key_dim, value_dim, conv)
-    gated: tuple = ()  # (kv_heads, head_dim, rotary_dim)
+    parts: tuple = ()  # partial(Class, **its own sizes), one a sized class
     norm_offset: bool = False  # RMSNorm's scale is 1 + w
 
-    def _hybrid_mixer(self, y, mask, segment_ids, handed, init):
-        """``(mixer's output, what is handed on)`` of a SambaY layer."""
-        memory, keys_values, state = handed
-        kv_heads, window, *ssm = self.hybrid
-        if self.kind in ("M", "M*"):
-            y, m = MambaMixer(*ssm, self.dtype, init, name="ssm")(y)
-            return y, (m if self.kind == "M*" else memory, keys_values, state)
-        if self.kind == "G":
-            with jax.named_scope("gmu"):
-                return GatedMemoryUnit(self.dtype, init, name="gmu")(
-                    y, memory), handed
-        with jax.named_scope("attention"):
-            y, own = DifferentialAttention(
-                self.num_heads, kv_heads, self.depth,
-                window if self.kind == "S" else 0, self.norm_eps, self.dtype,
-                self.attention_fn, init, name="attn")(
-                y, mask, segment_ids,
-                keys_values if self.kind == "X" else None)
-        return y, (memory, own if self.kind == "F*" else keys_values, state)
+    def part(self, cls, **fields):
+        """``cls`` as this layer holds it: with the sizes ``parts`` states
+        for it, those of the layer's own fields that it declares, the
+        layer's initialiser, and ``fields``."""
+        sized = next((p for p in self.parts if p.func is cls), cls)
+        own = {name: getattr(self, name) for name in (
+            "num_heads", "num_experts", "expert_dim", "experts_per_token",
+            "norm_eps", "rope_theta", "dtype", "attention_fn", "depth")
+            if name in cls.__dataclass_fields__}
+        return sized(**{
+            **own, "kernel_init": nn.initializers.truncated_normal(
+                self.init_std), **fields})
+
+    def mixer(self, **fields):
+        """The layer's mixer, by its kind: what ``__call__`` runs, and what
+        the stack asks which kernels it runs."""
+        kind = LAYER_KINDS[self.kind]
+        return self.part(kind.mixer, **dict(kind.fixed), **fields)
 
     def _merge(self, x, y, name):
         """The residual sum ``x + y``; a ZAYA layer's is ``(a_r x + b_r) +
@@ -889,65 +984,34 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None, segment_ids=None, position_ids=None,
                  live=None, handed=(None, None, None)):
-        from .moe import DroplessMoE, StateRouter, SwiGLU
-
         norm = partial(RMSNorm, self.norm_eps, self.dtype, self.norm_offset)
         if self.layer_norm:
             norm = partial(nn.LayerNorm, epsilon=self.norm_eps,
                            dtype=self.dtype, param_dtype=jnp.float32)
-        init = nn.initializers.truncated_normal(self.init_std)
+        kind, handed = LAYER_KINDS[self.kind], list(handed)
         y = norm(name="ln_attn")(x)
-        if self.kind == "D":
-            with jax.named_scope("linear_attention"):
-                y = GatedDeltaNet(*self.delta, self.norm_eps, self.dtype,
-                                  init, name="gdn")(y)
-        elif self.kind == "A":
-            with jax.named_scope("attention"):
-                y = GatedAttention(
-                    self.num_heads, *self.gated, self.norm_eps,
-                    self.rope_theta, self.dtype, self.attention_fn, init,
-                    name="attn")(y, mask, segment_ids, position_ids)
-        elif self.kind == "C":
-            with jax.named_scope("attention"):
-                y = ConvolutionalAttention(
-                    self.num_heads, *self.cca[:3], self.rope_theta,
-                    self.dtype, self.attention_fn, init, name="attn")(
-                    y, mask, segment_ids, position_ids)
-        elif self.kind:
-            y, handed = self._hybrid_mixer(y, mask, segment_ids, handed, init)
-        else:
-            with jax.named_scope("attention"):
-                if self.latent is None:
-                    mixer = SelfAttention(
-                        self.num_heads, self.dtype,
-                        attention_fn=self.attention_fn, causal=True,
-                        use_bias=False, qk_norm_eps=self.norm_eps,
-                        rope_theta=self.rope_theta, kernel_init=init,
-                        name="attn")
-                else:
-                    mixer = LatentAttention(
-                        self.num_heads, *self.latent, self.norm_eps,
-                        self.rope_theta, self.dtype, self.attention_fn, init,
-                        name="attn")
-                y = mixer(y, mask, segment_ids, position_ids)
+        taken = () if kind.takes is None else (handed[kind.takes],)
+        with jax.named_scope(kind.scope) if kind.scope else nullcontext():
+            y = self.mixer(name=kind.name)(
+                y, *(mask, segment_ids, position_ids)[:kind.sequence], *taken)
+        if isinstance(y, tuple):  # beside the output, what could be handed on
+            y, own = y
+            if kind.hands is not None:
+                handed[kind.hands] = own
         x = self._merge(x, y, "attn")
         y = norm(name="ln_mlp")(x)
         if self.dense_dim:
             with jax.named_scope("mlp.dense"):
-                y = SwiGLU(self.dense_dim, self.dtype, init, name="mlp")(y)
+                y = self.part(SwiGLU, mlp_dim=self.dense_dim, name="mlp")(y)
         else:
             logits = None  # the expert layer's own router
             if self.kind == "C":
                 with jax.named_scope("moe.router"):
-                    logits, state = StateRouter(
-                        self.num_experts, self.cca[3], self.norm_eps, init,
-                        name="router")(y, handed[2])
-                handed = (*handed[:2], state)
-            y = DroplessMoE(self.num_experts, self.expert_dim,
-                            self.experts_per_token, self.dtype,
-                            kernel_init=init, name="moe",
-                            **dict(self.moe))(y, live, logits)
-        return self._merge(x, y, "mlp"), handed
+                    logits, handed[_ROUTER_STATE] = self.part(
+                        StateRouter, name="router")(y, handed[_ROUTER_STATE])
+            y = self.part(DroplessMoE, name="moe", **dict(self.moe))(
+                y, live, logits)
+        return self._merge(x, y, "mlp"), tuple(handed)
 
 
 class TransformerDecoder(nn.Module):
@@ -955,13 +1019,14 @@ class TransformerDecoder(nn.Module):
     position table, no biases, a final norm and a head. Each layer is a
     :class:`DecoderBlock`; OLMoE's are all alike (RMSNorm, rotary attention,
     an expert layer, an untied ``lm_head``), Moonlight's take latent
-    attention, and a dense SwiGLU in the first ``dense_layers`` of them.
-    A stack with ``layer_kinds`` names every published layer's kind:
-    SambaY's (Phi-4-mini-flash) differ by layer, under LayerNorm
-    (``layer_norm``), with no position term at all; ZAYA1's are all ``"C"``,
-    under RMSNorm; Qwen3-Next's are three ``"D"`` to one ``"A"``, under
-    RMSNorm's ``1 + w`` form, with a head of its own. SambaY's and ZAYA1's
-    tie the head to the embedding (``tied_head``).
+    attention (``kind`` ``"L"``), and a dense SwiGLU in the first
+    ``dense_layers`` of them.
+    A stack with ``layer_kinds`` names every published layer's kind
+    (:data:`LAYER_KINDS`): SambaY's (Phi-4-mini-flash) differ by layer, under
+    LayerNorm (``layer_norm``), with no position term at all; ZAYA1's are all
+    ``"C"``, under RMSNorm; Qwen3-Next's are three ``"D"`` to one ``"A"``,
+    under RMSNorm's ``1 + w`` form, with a head of its own. SambaY's and
+    ZAYA1's tie the head to the embedding (``tied_head``).
     ``first_layer`` says which published layers are held (``num_layers`` of
     them from there: one pipeline stage's), and what a layer hands on (M*'s
     and F*'s tensors, a router's state) rides from layer to layer beside
@@ -983,26 +1048,23 @@ class TransformerDecoder(nn.Module):
     dtype: Any = jnp.bfloat16
     remat: bool = False
     attention_fn: Optional[Callable] = None
-    latent: Optional[tuple] = None  # DecoderBlock's, for every layer
     dense_layers: int = 0  # this many leading layers are dense, dense_dim wide
     dense_dim: int = 0
     moe: tuple = ()  # DecoderBlock's, for every expert layer
-    layer_kinds: tuple = ()  # every published layer's DecoderBlock.kind
+    kind: str = ""  # every layer's DecoderBlock.kind, where they are alike
+    layer_kinds: tuple = ()  # else every published layer's
     first_layer: int = 0  # published index of the first layer held here
-    hybrid: tuple = ()  # DecoderBlock's, for every SambaY layer
-    cca: tuple = ()  # DecoderBlock's, for every "C" layer
-    delta: tuple = ()  # DecoderBlock's, for every "D" layer
-    gated: tuple = ()  # DecoderBlock's, for every "A" layer
+    parts: tuple = ()  # DecoderBlock's, for every layer
     norm_offset: bool = False  # RMSNorm's scale is 1 + w, everywhere
     layer_norm: bool = False  # LayerNorm in place of RMSNorm, everywhere
     tied_head: bool = False  # the head is the embedding, no matrix of its own
 
     @property
     def held_kinds(self) -> tuple:
-        """The kinds of the layers held here, in order ("" without
+        """The kinds of the layers held here, in order (``kind`` without
         ``layer_kinds``); a G or X with no M* or F* before it is refused."""
         if not self.layer_kinds:
-            return ("",) * self.num_layers
+            return (self.kind,) * self.num_layers
         span = f"{self.first_layer}:{self.first_layer + self.num_layers}"
         kinds = self.layer_kinds[self.first_layer:
                                  self.first_layer + self.num_layers]
@@ -1019,61 +1081,29 @@ class TransformerDecoder(nn.Module):
                     "span does not hold before it")
         return kinds
 
-    @property
-    def attention_shapes(self) -> tuple:
-        """``(head_dim, value_dim)`` of every kind of attention the held
-        layers run: what the attention function chooses its path by, layer
-        by layer. Empty for a span without attention."""
-        if self.cca:
-            return ((self.cca[1],) * 2,)
-        if self.gated:
-            return ((self.gated[1],) * 2,) if "A" in self.held_kinds else ()
-        if self.layer_kinds:
-            d = self.hidden_size // self.num_heads
-            return ((d, 2 * d),) if set(self.held_kinds) & {
-                "S", "F*", "X"} else ()
-        if self.latent is None:
-            return ((self.hidden_size // self.num_heads,) * 2,)
-        return ((self.latent[1] + self.latent[2], self.latent[3]),)
+    def _layer(self, i: int, kind: str, block=DecoderBlock, **fields):
+        """The ``i``-th held layer's block."""
+        return block(
+            self.num_heads, self.expert_dim, self.num_experts,
+            self.experts_per_token, self.norm_eps, self.rope_theta,
+            self.init_std, self.dtype, attention_fn=self.attention_fn,
+            dense_dim=self.dense_dim if i < self.dense_layers else 0,
+            moe=self.moe, kind=kind, depth=self.first_layer + i,
+            layer_norm=self.layer_norm, parts=self.parts,
+            norm_offset=self.norm_offset, **fields)
 
-    @property
-    def scan_shape(self) -> Optional[tuple]:
-        """``(channels, states)`` of the selective scan the held layers run
-        (what :func:`..ops.scan.scan_fused_applies` chooses its path by), or
-        None for a stack that holds no state-space layer."""
-        if set(self.held_kinds) & {"M", "M*"}:
-            return tuple(self.hybrid[2:4])
-        return None
-
-    @property
-    def delta_shape(self) -> Optional[tuple]:
-        """``(value heads, key_dim, value_dim)`` of the gated delta rule the
-        held layers run (what :func:`..ops.delta.delta_fused_applies`
-        chooses its path by), or None for a stack that holds no such
-        layer."""
-        if "D" in self.held_kinds:
-            return tuple(self.delta[1:4])
-        return None
-
-    @property
-    def conv_shape(self) -> Optional[tuple]:
-        """``(channels, taps)`` of the depthwise causal convolution the held
-        state-space or linear-attention layers run (what
-        :func:`..ops.conv.conv_fused_applies` chooses its path by), or None
-        for a stack that holds neither."""
-        if "D" in self.held_kinds:
-            key_heads, value_heads, key_dim, value_dim, taps = self.delta
-            return (2 * key_heads * key_dim + value_heads * value_dim, taps)
-        if set(self.held_kinds) & {"M", "M*"}:
-            return (self.hybrid[2], self.hybrid[4])
-        return None
-
-    @property
-    def attention_head_dim(self) -> int:
-        """Width of a head's queries and keys where every held attention
-        layer has the same (:attr:`attention_shapes` is the whole answer)."""
-        (head_dim, _), = set(self.attention_shapes)
-        return head_dim
+    def kernels(self, seq_len: int) -> dict:
+        """Which kernels the held layers run at ``seq_len``, by name
+        (``attention``, ``scan``, ``delta``, ``conv``): every layer's mixer
+        is asked, and a name is True where each mixer that has it says so.
+        A span that holds no mixer with some kernel has no entry for it."""
+        answer: dict = {}
+        for i, kind in enumerate(self.held_kinds):
+            mixer = self._layer(i, kind, parent=None).mixer(parent=None)
+            for name, fused in mixer.kernels(seq_len,
+                                             self.hidden_size).items():
+                answer[name] = answer.get(name, True) and fused
+        return answer
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, train: bool = True,
@@ -1091,17 +1121,8 @@ class TransformerDecoder(nn.Module):
             block = nn.remat(DecoderBlock, static_argnums=())
         handed = (None, None, None)
         for i, kind in enumerate(self.held_kinds):
-            x, handed = block(
-                self.num_heads, self.expert_dim, self.num_experts,
-                self.experts_per_token, self.norm_eps, self.rope_theta,
-                self.init_std, self.dtype,
-                attention_fn=self.attention_fn, latent=self.latent,
-                dense_dim=self.dense_dim if i < self.dense_layers else 0,
-                moe=self.moe, kind=kind, depth=self.first_layer + i,
-                hybrid=self.hybrid, layer_norm=self.layer_norm,
-                cca=self.cca, delta=self.delta, gated=self.gated,
-                norm_offset=self.norm_offset, name=f"layer_{i}")(
-                    x, mask, seg_kwarg, position_ids, live, handed)
+            x, handed = self._layer(i, kind, block, name=f"layer_{i}")(
+                x, mask, seg_kwarg, position_ids, live, handed)
         if self.layer_norm:
             x = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
                              param_dtype=jnp.float32, name="ln_final")(x)
@@ -1151,12 +1172,16 @@ _MOONLIGHT_ROUTER = (("scoring", "sigmoid"), ("norm_topk", True),
 moonlight_16b_a3b = partial(
     TransformerDecoder, hidden_size=2048, num_layers=27, num_heads=16,
     expert_dim=1408, num_experts=64, experts_per_token=6, rope_theta=50000.0,
-    latent=(512, 128, 64, 128), dense_layers=1, dense_dim=11264,
+    kind="L", parts=(partial(LatentAttention, kv_rank=512, nope_dim=128,
+                             rope_dim=64, v_dim=128),),
+    dense_layers=1, dense_dim=11264,
     moe=_MOONLIGHT_ROUTER + (("shared_dim", 2816),))
 moonlight_tiny = partial(
     TransformerDecoder, hidden_size=64, num_layers=3, num_heads=4,
     expert_dim=32, num_experts=8, experts_per_token=2, rope_theta=50000.0,
-    latent=(32, 16, 8, 16), dense_layers=1, dense_dim=128,
+    kind="L", parts=(partial(LatentAttention, kv_rank=32, nope_dim=16,
+                             rope_dim=8, v_dim=16),),
+    dense_layers=1, dense_dim=128,
     moe=_MOONLIGHT_ROUTER + (("shared_dim", 32),))
 
 
@@ -1187,12 +1212,16 @@ phi4_mini_flash = partial(
     TransformerDecoder, hidden_size=2560, num_layers=32, num_heads=40,
     expert_dim=0, num_experts=0, experts_per_token=0, rope_theta=0.0,
     dense_layers=32, dense_dim=10240, layer_kinds=sambay_layers(32),
-    hybrid=(20, 512, 5120, 16, 4, 160), layer_norm=True, tied_head=True)
+    parts=(partial(MambaMixer, inner=5120, states=16, conv=4, dt_rank=160),
+           partial(DifferentialAttention, kv_heads=20, window=512)),
+    layer_norm=True, tied_head=True)
 phi4_mini_flash_tiny = partial(
     TransformerDecoder, hidden_size=64, num_layers=8, num_heads=8,
     expert_dim=0, num_experts=0, experts_per_token=0, rope_theta=0.0,
     dense_layers=8, dense_dim=128, layer_kinds=sambay_layers(8),
-    hybrid=(4, 16, 128, 8, 4, 4), layer_norm=True, tied_head=True)
+    parts=(partial(MambaMixer, inner=128, states=8, conv=4, dt_rank=4),
+           partial(DifferentialAttention, kv_heads=4, window=16)),
+    layer_norm=True, tied_head=True)
 # ZAYA1-8B (Zyphra/ZAYA1-8B config.json, model_type zaya; arXiv:2510.04476 for
 # the attention, arXiv:2511.17127 for the rest): 40 layers alike, each
 # compressed convolutional attention (8 query heads over 2 key/value heads of
@@ -1207,11 +1236,15 @@ zaya1_8b = partial(
     TransformerDecoder, hidden_size=2048, num_layers=40, num_heads=8,
     expert_dim=2048, num_experts=16, experts_per_token=1,
     rope_theta=5000000.0, moe=_ZAYA_ROUTER, layer_kinds=("C",) * 40,
-    cca=(2, 128, 64, 256), tied_head=True)
+    parts=(partial(ConvolutionalAttention, kv_heads=2, head_dim=128,
+                   rotary_dim=64), partial(StateRouter, width=256)),
+    tied_head=True)
 zaya_tiny = partial(
     TransformerDecoder, hidden_size=64, num_layers=3, num_heads=4,
     expert_dim=32, num_experts=8, experts_per_token=1, rope_theta=5000000.0,
-    moe=_ZAYA_ROUTER, layer_kinds=("C",) * 3, cca=(2, 16, 8, 32),
+    moe=_ZAYA_ROUTER, layer_kinds=("C",) * 3,
+    parts=(partial(ConvolutionalAttention, kv_heads=2, head_dim=16,
+                   rotary_dim=8), partial(StateRouter, width=32)),
     tied_head=True)
 
 
@@ -1237,11 +1270,55 @@ qwen3_next_80b_a3b = partial(
     expert_dim=512, num_experts=512, experts_per_token=10, norm_eps=1e-6,
     rope_theta=10000000.0,
     moe=_QWEN3_NEXT_EXPERTS + (("shared_dim", 512),),
-    layer_kinds=qwen3_next_layers(48), delta=(16, 32, 128, 128, 4),
-    gated=(2, 256, 64), norm_offset=True)
+    layer_kinds=qwen3_next_layers(48),
+    parts=(partial(GatedDeltaNet, key_heads=16, value_heads=32, key_dim=128,
+                   value_dim=128, conv=4),
+           partial(GatedAttention, kv_heads=2, head_dim=256, rotary_dim=64)),
+    norm_offset=True)
 qwen3_next_tiny = partial(
     TransformerDecoder, hidden_size=64, num_layers=4, num_heads=4,
     expert_dim=32, num_experts=64, experts_per_token=4, norm_eps=1e-6,
     rope_theta=10000000.0, moe=_QWEN3_NEXT_EXPERTS + (("shared_dim", 32),),
-    layer_kinds=qwen3_next_layers(4), delta=(2, 4, 16, 16, 4),
-    gated=(2, 32, 8), norm_offset=True)
+    layer_kinds=qwen3_next_layers(4),
+    parts=(partial(GatedDeltaNet, key_heads=2, value_heads=4, key_dim=16,
+                   value_dim=16, conv=4),
+           partial(GatedAttention, kv_heads=2, head_dim=32, rotary_dim=8)),
+    norm_offset=True)
+
+
+class Preset(NamedTuple):
+    """A ``causal_lm`` preset: the constructor (called with ``vocab_size``
+    and whatever a task changes), the vocabulary that is the model's own, and
+    the weight of each auxiliary term its expert layers sow into
+    ``aux_loss`` (a term without a weight is left out of the loss)."""
+
+    ctor: Callable
+    vocab_size: int
+    aux_weights: dict
+
+
+# The GPT presets have expert layers only under --num_experts (MoEMLP: the
+# switch balance term); every OLMoE layer is one, with the two weights of the
+# paper (arXiv:2409.02060, section 4.1); Moonlight's (all but its first) sow
+# the sequence-wise balance term, with DeepSeek-V3's weight (arXiv:2412.19437,
+# section 4.2: alpha 0.0001). ZAYA1's are balanced by the selection bias
+# alone. Qwen3-Next's take the balance term at its published class's default
+# weight (router_aux_loss_coef 0.001) and no z term.
+_SWITCH_AUX = {"load_balance": 0.01}
+_OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
+_MOONLIGHT_AUX = {"seq_balance": 0.0001}
+_QWEN3_NEXT_AUX = {"load_balance": 0.001}
+CAUSAL_LMS: dict = {
+    "gpt_base": Preset(gpt_base, 50257, _SWITCH_AUX),
+    "gpt_small": Preset(gpt_small, 50257, _SWITCH_AUX),
+    "olmoe_1b_7b": Preset(olmoe_1b_7b, 50304, _OLMOE_AUX),
+    "olmoe_tiny": Preset(olmoe_tiny, 512, _OLMOE_AUX),
+    "moonlight_16b_a3b": Preset(moonlight_16b_a3b, 163840, _MOONLIGHT_AUX),
+    "moonlight_tiny": Preset(moonlight_tiny, 512, _MOONLIGHT_AUX),
+    "phi4_mini_flash": Preset(phi4_mini_flash, 200064, {}),
+    "phi4_mini_flash_tiny": Preset(phi4_mini_flash_tiny, 512, {}),
+    "zaya1_8b": Preset(zaya1_8b, 262272, {}),
+    "zaya_tiny": Preset(zaya_tiny, 512, {}),
+    "qwen3_next_80b_a3b": Preset(qwen3_next_80b_a3b, 151936, _QWEN3_NEXT_AUX),
+    "qwen3_next_tiny": Preset(qwen3_next_tiny, 512, _QWEN3_NEXT_AUX),
+}

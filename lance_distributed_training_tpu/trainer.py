@@ -194,7 +194,7 @@ class TrainConfig:
     # share of a stated deployment that this chip runs. None: all of them
     layer_span: Optional[str] = None  # "first:end": the published layers
     # [first, end) that this pipeline stage holds of a preset whose layers
-    # differ by kind (phi4_mini_flash*, qwen3_next_*); None: all of them
+    # differ by kind; None: all of them
     prefetch: int = 2
     producer_threads: int = 4  # decode-producer threads
     placement_depth: int = 2  # device-resident batches the placement ring
@@ -370,65 +370,15 @@ def _task_from_config(config: TrainConfig, mesh=None) -> Task:
     )
 
 
-def _attention_fused(task: Task, config: TrainConfig) -> Optional[float]:
-    """1.0 where a sequence model's attention at ``seq_len`` runs the fused
-    kernel (in every layer that has attention, where the layers differ), 0.0
-    where it runs dense (or ring) attention, None for a task without
-    attention of its own to choose (a span of layers that holds none
-    included): asked of the function the model was bound
-    (``ops.flash.make_flash_attention``), which decides each call by the
-    same test. Published as the gauge ``attention_fused``."""
-    if config.task_type not in ("masked_lm", "causal_lm"):
-        return None
-    model = task.model
-    fused = getattr(getattr(model, "attention_fn", None), "fused", None)
-    shapes = getattr(model, "attention_shapes", (
-        (model.hidden_size // model.num_heads,) * 2,))
-    if not shapes:
-        return None
-    on = bool(fused and all(fused(config.seq_len, *s) for s in shapes))
-    default_registry().gauge("attention_fused").set(float(on))
-    return float(on)
-
-
-def _scan_path(task: Task, config: TrainConfig) -> Optional[str]:
-    """How a stack's state-space layers run their scan at ``seq_len`` (by
-    ``ops.scan.scan_fused_applies``, the test each call makes), None for a
-    model that holds none."""
-    scans = getattr(task.model, "scan_shape", None)
-    if not scans:
-        return None
-    from .ops.scan import scan_fused_applies
-
-    return ("fused kernel" if scan_fused_applies(config.seq_len, *scans)
-            else "chunked")
-
-
-def _delta_path(task: Task, config: TrainConfig) -> Optional[str]:
-    """How a stack's linear-attention layers run the gated delta rule at
-    ``seq_len`` (by ``ops.delta.delta_fused_applies``, the test each call
-    makes), None for a model that holds none."""
-    shape = getattr(task.model, "delta_shape", None)
-    if not shape:
-        return None
-    from .ops.delta import delta_fused_applies
-
-    return ("fused kernel" if delta_fused_applies(config.seq_len, *shape)
-            else "chunked")
-
-
-def _conv_path(task: Task, config: TrainConfig) -> Optional[str]:
-    """How a stack's state-space or linear-attention layers run their
-    depthwise causal convolution and its SiLU at ``seq_len`` (by
-    ``ops.conv.conv_fused_applies``, the test each call makes), None for a
-    model that holds neither."""
-    shape = getattr(task.model, "conv_shape", None)
-    if not shape:
-        return None
-    from .ops.conv import conv_fused_applies
-
-    return ("fused kernel" if conv_fused_applies(config.seq_len, *shape)
-            else "plain")
+def _kernel_paths(task: Task, config: TrainConfig) -> dict:
+    """The first log line's word for the form each of the model's kernels
+    runs at ``seq_len``, by the kernel's name (``Task.kernels``: the mixers'
+    own answer, which asks the test each call makes): ``attention=``,
+    ``scan=``, ``delta=``, ``conv=``."""
+    plain = {"attention": "ring" if config.seq_parallelism > 1 else "dense",
+             "conv": "plain"}
+    return {name: "fused kernel" if fused else plain.get(name, "chunked")
+            for name, fused in sorted(task.kernels.items())}
 
 
 def lr_schedule_fn(config: TrainConfig, total_steps: Optional[int] = None):
@@ -1353,7 +1303,6 @@ def _train(config: TrainConfig) -> dict:
         else None
     )
     task = _task_from_config(config, mesh)
-    attention_fused = _attention_fused(task, config)
 
     rng = jax.random.key(config.seed)
     rng, init_rng = jax.random.split(rng)
@@ -1477,6 +1426,14 @@ def _train(config: TrainConfig) -> dict:
     )
     timer = StepTimer()
     results: dict = {}
+    if "attention" in task.kernels:
+        # 1.0 where the model's attention at seq_len runs the fused kernel
+        # in every layer that has attention, 0.0 where it runs dense (or
+        # ring) attention; absent for a task without attention of its own to
+        # choose. A gauge, and an entry of every log line and of the results
+        results["attention_fused"] = float(task.kernels["attention"])
+        default_registry().gauge("attention_fused").set(
+            results["attention_fused"])
     total_start = time.perf_counter()
     global_step = 0
 
@@ -1569,21 +1526,8 @@ def _train(config: TrainConfig) -> dict:
         # the exporter port, the metrics_port log write, or a pool-spawn
         # error must all still run the finally (logger/ckpt close, and the
         # exporter's bound port once started).
-        start_line = {**device_info, "compile_cache_dir": cache_dir}
-        if attention_fused is not None:
-            start_line["attention"] = (
-                "fused kernel" if attention_fused
-                else "ring" if config.seq_parallelism > 1 else "dense")
-        scan_path = _scan_path(task, config)
-        if scan_path:
-            start_line["scan"] = scan_path
-        delta_path = _delta_path(task, config)
-        if delta_path:
-            start_line["delta"] = delta_path
-        conv_path = _conv_path(task, config)
-        if conv_path:
-            start_line["conv"] = conv_path
-        logger.log(start_line, to_wandb=False)
+        logger.log({**device_info, "compile_cache_dir": cache_dir,
+                    **_kernel_paths(task, config)}, to_wandb=False)
         if config.metrics_port is not None and jax.process_index() == 0:
             from .obs.http import MetricsHTTPServer
 
@@ -1664,11 +1608,8 @@ def _train(config: TrainConfig) -> dict:
             resume_global_step=resume_global_step,
             preempt=preempt, chaos=chaos, trace=trace, journal=journal,
             tuner=tuner, batch_cache=batch_cache, folder_fp=folder_fp,
-            attention_fused=attention_fused,
         )
         results.update(device_info)
-        if attention_fused is not None:
-            results["attention_fused"] = attention_fused
         return results
     except BaseException as exc:
         run_exc = exc
@@ -1962,7 +1903,8 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 index_pool=None, lr_fn=None, val_pool=None, *,
                 resume_epoch_step=0, resume_global_step=0, preempt=None,
                 chaos=None, trace=None, journal=None, tuner=None,
-                batch_cache=None, folder_fp=None, attention_fused=None):
+                batch_cache=None, folder_fp=None):
+    known = dict(results)  # before the first step: attention_fused
     if journal is None:
         journal = _CkptJournal(resume_global_step)
     step_stats = _StepStats()
@@ -2139,8 +2081,8 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         with obs_span("loop.stats_fetch", step=point.step):
             step_stats.publish(point.stats, entry)
         in_flight_min = flight.publish(entry)
-        if attention_fused is not None:
-            entry["attention_fused"] = attention_fused
+        if "attention_fused" in known:
+            entry["attention_fused"] = known["attention_fused"]
             # the steps so far were traced: each splash kernel they built
             # says once what tiling it runs
             for line in splash_tilings_built():
@@ -2495,7 +2437,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             epoch_metrics["val_acc"] = evaluate(state, val_loader, eval_step)
         logger.log(epoch_metrics, step=epoch)
         history.append(dict(epoch_metrics))
-        results = epoch_metrics
+        results = {**known, **epoch_metrics}
         if (
             ckpt is not None
             and (epoch + 1) % config.checkpoint_every == 0

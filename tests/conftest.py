@@ -124,6 +124,28 @@ def pytest_collection_modifyitems(items):
             item.add_marker(pytest.mark.fast)
 
 
+def register_preset(name: str, base: str, **changes) -> dict:
+    """The ``causal_lm`` preset ``base`` under ``name``, with fields of its
+    constructor changed (``moe``: a dict laid over the base's name-value
+    pairs; ``parts``: a dict, class to the sizes of it that change). Returns
+    the table: whoever registers deletes, ``del table[name]``."""
+    import functools
+
+    from lance_distributed_training_tpu.models.transformer import CAUSAL_LMS
+
+    base = CAUSAL_LMS[base]
+    if "moe" in changes:
+        changes["moe"] = tuple({**dict(base.ctor.keywords["moe"]),
+                                **changes["moe"]}.items())
+    if "parts" in changes:
+        changes["parts"] = tuple(
+            functools.partial(part, **changes["parts"].get(part.func, {}))
+            for part in base.ctor.keywords["parts"])
+    CAUSAL_LMS[name] = base._replace(
+        ctor=functools.partial(base.ctor, **changes))
+    return CAUSAL_LMS
+
+
 def make_jpeg(rng: np.ndarray, size: int = 32) -> bytes:
     """A small random JPEG payload (stands in for FOOD101 images)."""
     from PIL import Image

@@ -236,23 +236,28 @@ def test_the_first_log_line_names_the_convolutions_path(model, says):
     config = trainer.TrainConfig(dataset_path="", task_type="causal_lm",
                                  model_name=model, seq_len=128)
     task = get_task("causal_lm", model_name=model, seq_len=128)
-    assert trainer._conv_path(task, config) == says
-    assert (task.model.conv_shape is None) is (says is None)
+    assert trainer._kernel_paths(task, config).get("conv") == says
+    assert ("conv" in task.kernels) is (says is not None)
 
 
 @pytest.mark.parametrize("model,shape", [
     ("qwen3_next_80b_a3b", (8192, 4)), ("phi4_mini_flash", (5120, 4)),
     ("qwen3_next_tiny", (128, 4)), ("phi4_mini_flash_tiny", (128, 4))])
-def test_a_stack_knows_its_convolutions_shape(model, shape):
-    from lance_distributed_training_tpu.models import tasks
+def test_a_stack_knows_its_convolutions_shape(model, shape, monkeypatch):
+    """The mixer asks the op's own rule with its channels and taps."""
+    from lance_distributed_training_tpu.models.transformer import CAUSAL_LMS
 
-    assert tasks._CAUSAL_LMS[model][0](vocab_size=512).conv_shape == shape
+    asked = []
+    monkeypatch.setattr(conv, "conv_fused_applies",
+                        lambda seq, *shape: asked.append(shape) or True)
+    assert CAUSAL_LMS[model].ctor(vocab_size=512).kernels(256)["conv"] is True
+    assert set(asked) == {shape}
 
 
 def test_a_span_without_a_mixer_has_no_convolution():
     task = get_task("causal_lm", model_name="qwen3_next_tiny", seq_len=128,
                     layer_span="3:4")  # the gated attention layer alone
-    assert task.model.conv_shape is None
+    assert set(task.kernels) == {"attention"}
 
 
 @pytest.mark.parametrize("model", ["qwen3_next_tiny", "phi4_mini_flash_tiny"])

@@ -21,7 +21,6 @@ a training step and takes no gradient.
 
 from __future__ import annotations
 
-import functools
 import importlib.util
 import json
 import os
@@ -30,8 +29,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import register_preset
 
-from lance_distributed_training_tpu.models import get_task, moe, tasks
+from lance_distributed_training_tpu.models import get_task, moe, transformer
 from lance_distributed_training_tpu.models.moe import DroplessMoE, SwiGLU
 from lance_distributed_training_tpu.models.transformer import moonlight_tiny
 
@@ -61,10 +61,7 @@ def ref():
 
 def _register(name, **changes):
     """``moonlight_tiny`` under a name of its own, with fields changed."""
-    moe = dict(moonlight_tiny.keywords["moe"], **changes.pop("moe", {}))
-    tasks._CAUSAL_LMS[name] = (
-        functools.partial(moonlight_tiny, moe=tuple(moe.items()), **changes),
-        VOCAB, tasks._MOONLIGHT_AUX)
+    return register_preset(name, "moonlight_tiny", **changes)
 
 
 @pytest.fixture(scope="module")
@@ -74,7 +71,7 @@ def f32_task():
         yield get_task("causal_lm", model_name="moonlight_tiny_f32",
                        seq_len=SEQ, expert_share=SHARE)
     finally:
-        del tasks._CAUSAL_LMS["moonlight_tiny_f32"]
+        del transformer.CAUSAL_LMS["moonlight_tiny_f32"]
 
 
 @pytest.fixture(scope="module")
@@ -236,7 +233,7 @@ def test_broken_variant_fails_the_float32_comparison(variant, ref, variables,
         task = get_task("causal_lm", model_name=name, seq_len=SEQ,
                         expert_share=SHARE)
     finally:
-        del tasks._CAUSAL_LMS[name]
+        del transformer.CAUSAL_LMS[name]
     # the same parameters: what a variant does not use, it does not read
     got = task.forward(variables, batch, False, None)[0][0]
     want = ref.forward(variables, batch)
@@ -285,7 +282,7 @@ def kernel_task():
                        seq_len=KERNEL_SEQ, attention_fn=attention,
                        expert_share=SHARE)
     finally:
-        del tasks._CAUSAL_LMS["moonlight_tiny_f32_kernel"]
+        del transformer.CAUSAL_LMS["moonlight_tiny_f32_kernel"]
 
 
 @pytest.fixture(scope="module")
@@ -364,8 +361,13 @@ def test_unequal_heads_take_the_splash_path_and_the_rule_sees_them():
                                              value_dim=100)
     assert not flash.fused_attention_applies(8192, 192, platform="cpu",
                                              value_dim=128)
-    model = moonlight_tiny(vocab_size=VOCAB)
-    assert model.attention_head_dim == 24  # 16 without position + 8 rotary
+    asked = []
+    attention = flash.make_flash_attention(causal=True, forced=False)
+    attention.fused = lambda *shape: asked.append(shape) or False
+    model = moonlight_tiny(vocab_size=VOCAB, attention_fn=attention)
+    assert model.kernels(SEQ) == {"attention": False}
+    # queries and keys 16 without position + 8 rotary, values 16
+    assert set(asked) == {(SEQ, 24, 16)}
 
 
 # -- the share ---------------------------------------------------------------

@@ -25,7 +25,6 @@ attention (TPU interpret mode), the path the benchmark's cell trains on and
 
 from __future__ import annotations
 
-import functools
 import importlib.util
 import os
 
@@ -33,10 +32,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import register_preset
 
-from lance_distributed_training_tpu.models import get_task, tasks
+from lance_distributed_training_tpu.models import get_task
 from lance_distributed_training_tpu.models.moe import DroplessMoE
-from lance_distributed_training_tpu.models.transformer import olmoe_tiny
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ, ROWS, VOCAB, TOP_K = 32, 4, 512, 2
@@ -66,13 +65,11 @@ def ref():
 def f32_task():
     """The same task computed in float32, under a preset name of its own."""
     name = "olmoe_tiny_f32"
-    tasks._CAUSAL_LMS[name] = (
-        functools.partial(olmoe_tiny, dtype=jnp.float32), VOCAB,
-        tasks._OLMOE_AUX)
+    presets = register_preset(name, "olmoe_tiny", dtype=jnp.float32)
     try:
         yield get_task("causal_lm", model_name=name, seq_len=SEQ)
     finally:
-        del tasks._CAUSAL_LMS[name]
+        del presets[name]
 
 
 @pytest.fixture(scope="module")
@@ -309,9 +306,7 @@ def kernel_task():
         patch.setattr(jax, "default_backend", lambda: "tpu")
         attention = flash.make_flash_attention(causal=True)
     name = "olmoe_tiny_f32_kernel"
-    tasks._CAUSAL_LMS[name] = (
-        functools.partial(olmoe_tiny, dtype=jnp.float32), VOCAB,
-        tasks._OLMOE_AUX)
+    presets = register_preset(name, "olmoe_tiny", dtype=jnp.float32)
     with pytest.MonkeyPatch.context() as patch:
         # the cell's 4,096 tokens run the library's blocked kernel; keep
         # these 128 on it (tests/test_attention_choice.py holds the
@@ -321,7 +316,7 @@ def kernel_task():
             yield get_task("causal_lm", model_name=name, seq_len=KERNEL_SEQ,
                            attention_fn=attention)
         finally:
-            del tasks._CAUSAL_LMS[name]
+            del presets[name]
 
 
 @pytest.fixture(scope="module")

@@ -22,8 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import register_preset
 
-from lance_distributed_training_tpu.models import get_task, tasks
+from lance_distributed_training_tpu.models import get_task
 from lance_distributed_training_tpu.models import transformer
 from lance_distributed_training_tpu.ops import conv, flash, scan
 
@@ -48,10 +49,7 @@ def ref():
 
 
 def _register(name, **kwargs):
-    ctor, vocab, aux = tasks._CAUSAL_LMS["phi4_mini_flash_tiny"]
-    tasks._CAUSAL_LMS[name] = (
-        transformer.partial(ctor.func, **{**ctor.keywords, **kwargs}),
-        vocab, aux)
+    return register_preset(name, "phi4_mini_flash_tiny", **kwargs)
 
 
 @pytest.fixture(scope="module")
@@ -60,7 +58,7 @@ def f32_task():
     try:
         yield get_task("causal_lm", model_name="phi4_tiny_f32", seq_len=SEQ)
     finally:
-        del tasks._CAUSAL_LMS["phi4_tiny_f32"]
+        del transformer.CAUSAL_LMS["phi4_tiny_f32"]
 
 
 @pytest.fixture(scope="module")
@@ -184,7 +182,8 @@ def test_each_kind_of_layer_alone_matches_reference(ref, kind):
     v = jax.random.normal(keys[3], (b, 2, s, 16))
     block = transformer.DecoderBlock(
         8, 0, 0, 0, dtype=jnp.float32, dense_dim=128, kind=kind, depth=depth,
-        hybrid=(4, 16, 128, 8, 4, 4), layer_norm=True)
+        parts=transformer.phi4_mini_flash_tiny.keywords["parts"],
+        layer_norm=True)
     handed = (memory, (k, v), None)  # no router state: no layer here has one
     params = ref.perturb(block.init(keys[4], x, handed=handed),
                          jax.random.key(8))["params"]
@@ -332,7 +331,7 @@ def kernel_run(ref, variables):
                         seq_len=KERNEL_SEQ, attention_fn=attention,
                         layer_span="2:8")
     finally:
-        del tasks._CAUSAL_LMS["phi4_tiny_f32_kernel"]
+        del transformer.CAUSAL_LMS["phi4_tiny_f32_kernel"]
     held = variables["params"]  # published layer i + 2 is held as layer_i
     v = {"params": {
         **{k: p for k, p in held.items() if not k.startswith("layer_")},
@@ -389,7 +388,7 @@ def _span_task(span, **kwargs):
 def f32_preset():
     _register("phi4_tiny_f32_span", dtype=jnp.float32)
     yield
-    del tasks._CAUSAL_LMS["phi4_tiny_f32_span"]
+    del transformer.CAUSAL_LMS["phi4_tiny_f32_span"]
 
 
 def test_window_layer_sees_the_window_and_no_further(f32_preset, ref):
@@ -529,11 +528,16 @@ def test_the_published_layout_and_the_cells_span():
     model = transformer.phi4_mini_flash(vocab_size=25008, first_layer=14,
                                         num_layers=6)
     assert model.held_kinds == ("M", "S", "M*", "F*", "G", "X")
-    assert model.attention_shapes == ((64, 128),)
-    assert model.attention_head_dim == 64
+    asked = []
+    attention = flash.make_flash_attention(causal=True, forced=False)
+    attention.fused = lambda *shape: asked.append(shape) or False
+    assert set(model.clone(attention_fn=attention).kernels(8192)) == {
+        "attention", "scan", "conv"}
+    assert set(asked) == {(8192, 64, 128)}  # S, F* and X alike
     # a span of state-space layers and memory units has no attention to ask
-    assert transformer.phi4_mini_flash(
-        vocab_size=8, first_layer=16, num_layers=1).attention_shapes == ()
+    assert set(transformer.phi4_mini_flash(
+        vocab_size=8, first_layer=16, num_layers=1).kernels(8192)) == {
+        "scan", "conv"}
 
 
 def test_the_cells_share_counts_697_million_parameters():

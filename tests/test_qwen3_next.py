@@ -31,14 +31,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import register_preset
 
-from lance_distributed_training_tpu.models import get_task, tasks
+from lance_distributed_training_tpu.models import get_task
 from lance_distributed_training_tpu.models.moe import DroplessMoE
 from lance_distributed_training_tpu.models.transformer import (
     GatedAttention,
+    GatedDeltaNet,
     RMSNorm,
     causal_depthwise_conv,
-    qwen3_next_tiny,
     rotary_embedding,
 )
 from lance_distributed_training_tpu.ops import delta
@@ -78,14 +79,13 @@ def _task(share, **changes):
     if not changes:
         return get_task("causal_lm", model_name="qwen3_next_tiny",
                         seq_len=SEQ, expert_share=share)
-    tasks._CAUSAL_LMS["qwen3_next_tiny_changed"] = (
-        functools.partial(qwen3_next_tiny, **changes), VOCAB,
-        tasks._QWEN3_NEXT_AUX)
+    presets = register_preset("qwen3_next_tiny_changed", "qwen3_next_tiny",
+                              **changes)
     try:
         return get_task("causal_lm", model_name="qwen3_next_tiny_changed",
                         seq_len=SEQ, expert_share=share)
     finally:
-        del tasks._CAUSAL_LMS["qwen3_next_tiny_changed"]
+        del presets["qwen3_next_tiny_changed"]
 
 
 @pytest.fixture(scope="module")
@@ -298,12 +298,12 @@ def test_the_first_log_line_names_the_delta_path():
     config = trainer.TrainConfig(
         dataset_path="", task_type="causal_lm",
         model_name="qwen3_next_tiny", seq_len=SEQ)
-    assert trainer._delta_path(_task(None), config) == "chunked"
+    assert trainer._kernel_paths(_task(None), config)["delta"] == "chunked"
     config = trainer.TrainConfig(
         dataset_path="", task_type="causal_lm", model_name="olmoe_tiny",
         seq_len=SEQ)
     olmoe = get_task("causal_lm", model_name="olmoe_tiny", seq_len=SEQ)
-    assert trainer._delta_path(olmoe, config) is None
+    assert "delta" not in trainer._kernel_paths(olmoe, config)
 
 
 # -- the share ---------------------------------------------------------------
@@ -914,10 +914,12 @@ def test_every_width_is_the_published_one(config):
     model = get_task(**config["task"]).model
     assert (model.hidden_size, model.num_heads, model.expert_dim,
             model.num_experts, model.experts_per_token, model.rope_theta,
-            model.delta, model.gated, model.norm_eps, model.norm_offset,
-            model.tied_head) == (
-        2048, 16, 512, 512, 10, 1e7, (16, 32, 128, 128, 4), (2, 256, 64),
-        1e-6, True, False)
+            model.norm_eps, model.norm_offset, model.tied_head) == (
+        2048, 16, 512, 512, 10, 1e7, 1e-6, True, False)
+    assert {p.func: p.keywords for p in model.parts} == {
+        GatedDeltaNet: dict(key_heads=16, value_heads=32, key_dim=128,
+                            value_dim=128, conv=4),
+        GatedAttention: dict(kv_heads=2, head_dim=256, rotary_dim=64)}
     moe = dict(model.moe)
     assert (moe["held_experts"], moe["first_expert"], moe["shared_dim"],
             moe["norm_topk"], moe["shared_gate"]) == (32, 0, 512, True, True)

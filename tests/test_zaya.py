@@ -20,7 +20,6 @@ and factoring the router out left the other models' steps as they were.
 
 from __future__ import annotations
 
-import functools
 import hashlib
 import importlib.util
 import json
@@ -30,8 +29,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import register_preset
 
-from lance_distributed_training_tpu.models import get_task, tasks
+from lance_distributed_training_tpu.models import get_task, transformer
 from lance_distributed_training_tpu.models.moe import DroplessMoE
 from lance_distributed_training_tpu.models.transformer import (
     ConvolutionalAttention,
@@ -69,10 +69,7 @@ def ref(share):
 
 def _register(name, **changes):
     """``zaya_tiny`` under a name of its own, with fields changed."""
-    moe = dict(zaya_tiny.keywords["moe"], **changes.pop("moe", {}))
-    tasks._CAUSAL_LMS[name] = (
-        functools.partial(zaya_tiny, moe=tuple(moe.items()), **changes),
-        VOCAB, {})
+    return register_preset(name, "zaya_tiny", **changes)
 
 
 def _task(share, name="zaya_tiny", seq=SEQ, **changes):
@@ -86,7 +83,7 @@ def _task(share, name="zaya_tiny", seq=SEQ, **changes):
                         seq_len=seq, expert_share=share,
                         attention_fn=attention_fn)
     finally:
-        del tasks._CAUSAL_LMS["zaya_tiny_changed"]
+        del transformer.CAUSAL_LMS["zaya_tiny_changed"]
 
 
 @pytest.fixture(scope="module")
@@ -275,7 +272,8 @@ def test_a_token_near_a_tie_marks_the_tokens_that_read_it_layer_by_layer():
 BROKEN = {
     "no_selection_bias": {"moe": {"bias_update_rate": 0.0}},
     "two_experts_a_token": {"experts_per_token": 2},
-    "rotary_over_the_whole_head": {"cca": (2, 16, 16, 32)},
+    "rotary_over_the_whole_head": {
+        "parts": {ConvolutionalAttention: {"rotary_dim": 16}}},
     "rotary_theta_of_another_model": {"rope_theta": 10000.0},
 }
 
@@ -599,6 +597,10 @@ def test_every_width_is_the_published_one(config):
     model = get_task(**config["task"]).model
     assert (model.hidden_size, model.num_heads, model.expert_dim,
             model.num_experts, model.experts_per_token, model.rope_theta,
-            model.cca, model.norm_eps, model.tied_head) == (
-        2048, 8, 2048, 16, 1, 5e6, (2, 128, 64, 256), 1e-5, True)
+            model.norm_eps, model.tied_head) == (
+        2048, 8, 2048, 16, 1, 5e6, 1e-5, True)
+    assert {p.func.__name__: p.keywords for p in model.parts} == {
+        "ConvolutionalAttention": dict(kv_heads=2, head_dim=128,
+                                       rotary_dim=64),
+        "StateRouter": dict(width=256)}
     assert dict(model.moe)["held_experts"] == 8
