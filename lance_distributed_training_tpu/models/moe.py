@@ -39,7 +39,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-__all__ = ["SwiGLU", "MoEMLP", "DroplessMoE", "StateRouter"]
+__all__ = ["SwiGLU", "MoEMLP", "DroplessMoE", "StateRouter",
+           "router_product"]
 
 
 class SwiGLU(nn.Module):
@@ -236,10 +237,12 @@ _sum_back.defvjp(
     _sum_back_bwd)
 
 
-def _f32_dense(features: int, kernel_init, name: str):
-    """A bias-free product of a router, f32 throughout: on a TPU an f32
-    product at default precision is one bf16 pass, and the eighth and ninth
-    expert of a token are often closer than that."""
+def router_product(features: int, kernel_init, name: str):
+    """A bias-free product of a router (this file's, and the one a decoder
+    block makes of its input where the router reads ahead of attention), f32
+    throughout: on a TPU an f32 product at default precision is one bf16
+    pass, and the eighth and ninth expert of a token are often closer than
+    that."""
     return nn.Dense(features, use_bias=False, dtype=jnp.float32,
                     param_dtype=jnp.float32,
                     precision=jax.lax.Precision.HIGHEST,
@@ -260,9 +263,9 @@ class _RouterMLP(nn.Module):
 
         y = RMSNorm(self.norm_eps, jnp.float32, name="norm")(r)
         for name in ("mlp_1", "mlp_2"):
-            y = nn.gelu(_f32_dense(self.width, self.kernel_init, name)(y),
+            y = nn.gelu(router_product(self.width, self.kernel_init, name)(y),
                         approximate=False)
-        return _f32_dense(self.num_experts, self.kernel_init, "mlp_3")(y)
+        return router_product(self.num_experts, self.kernel_init, "mlp_3")(y)
 
 
 class StateRouter(nn.Module):
@@ -287,7 +290,7 @@ class StateRouter(nn.Module):
 
     @nn.compact
     def __call__(self, u, carried=None):
-        r = _f32_dense(self.width, self.kernel_init, "down")(
+        r = router_product(self.width, self.kernel_init, "down")(
             u.astype(jnp.float32))
         mix = self.param("depth_mix", nn.initializers.ones_init(),
                          (self.width,), jnp.float32)
@@ -301,13 +304,15 @@ class StateRouter(nn.Module):
 class DroplessMoE(nn.Module):
     """Top-k routed SwiGLU experts without capacity: ``[B, S, H] -> [B, S, H]``,
     ``Σ_k w_k · down_k(silu(gate_k(x)) · up_k(x))`` over each token's
-    ``experts_per_token`` chosen experts, no biases, plus ``shared(x)``
+    ``experts_per_token`` chosen experts (``activation`` ``"relu"``: ReLU in
+    SiLU's place, SmallThinker's ReGLU), no biases, plus ``shared(x)``
     where ``shared_dim`` > 0 (one :class:`SwiGLU` every token passes; under
     ``shared_gate`` times ``sigmoid(x w_g)``, Qwen3-Next's).
 
     The logits are the layer's own (one bias-free f32 product, ``router``)
     or, where the call is given ``logits`` [B, S, E], the caller's (ZAYA1's
-    :class:`StateRouter`, whose state rides between layers). Two scorings.
+    :class:`StateRouter`, whose state rides between layers; SmallThinker's
+    product of the block's input, made ahead of attention). Two scorings.
     ``"softmax"`` (OLMoE, ZAYA1, Qwen3-Next): the k largest router
     probabilities, weighted by the softmax values themselves, divided by
     their sum under ``norm_topk`` (Qwen3-Next). ``"sigmoid"`` (the
@@ -376,6 +381,7 @@ class DroplessMoE(nn.Module):
     shared_gate: bool = False  # the shared expert times sigmoid(x w_g)
     first_expert: int = 0
     held_experts: int = 0  # 0: all of them
+    activation: str = "silu"  # the experts' gate: "silu" (SwiGLU) or "relu"
 
     @nn.compact
     def __call__(self, x, live=None, logits=None):
@@ -394,7 +400,7 @@ class DroplessMoE(nn.Module):
 
         with jax.named_scope("moe.router"):
             if logits is None:  # the layer's own router: one product
-                logits = _f32_dense(e, self.kernel_init, "router")(
+                logits = router_product(e, self.kernel_init, "router")(
                     tokens.astype(jnp.float32))
             else:
                 logits = logits.reshape(t, e)
@@ -414,13 +420,15 @@ class DroplessMoE(nn.Module):
             if not softmax:
                 top_p = top_p * self.routed_scale
 
+        act = {"silu": nn.silu, "relu": nn.relu}[self.activation]
+
         def experts(xs, group_sizes, w_gate, w_up, w_down):
             with jax.named_scope("moe.experts"):
                 gate = jax.lax.ragged_dot(xs, w_gate.astype(self.dtype),
                                           group_sizes)
                 up = jax.lax.ragged_dot(xs, w_up.astype(self.dtype),
                                         group_sizes)
-                return jax.lax.ragged_dot(nn.silu(gate) * up,
+                return jax.lax.ragged_dot(act(gate) * up,
                                           w_down.astype(self.dtype),
                                           group_sizes)
 

@@ -24,14 +24,15 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.conv import causal_conv_silu, causal_depthwise_conv
-from .moe import DroplessMoE, MoEMLP, StateRouter, SwiGLU
+from .moe import DroplessMoE, MoEMLP, StateRouter, SwiGLU, router_product
 
 __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "bert_small", "gpt_base", "gpt_small", "olmoe_1b_7b",
            "olmoe_tiny", "moonlight_16b_a3b", "moonlight_tiny",
            "phi4_mini_flash", "phi4_mini_flash_tiny", "sambay_layers",
            "zaya1_8b", "zaya_tiny", "qwen3_next_80b_a3b", "qwen3_next_tiny",
-           "qwen3_next_layers", "dot_product_attention", "RMSNorm",
+           "qwen3_next_layers", "smallthinker_21b_a3b", "smallthinker_tiny",
+           "smallthinker_layers", "dot_product_attention", "RMSNorm",
            "rotary_embedding", "causal_depthwise_conv", "LayerKind",
            "LAYER_KINDS", "Preset", "CAUSAL_LMS"]
 
@@ -748,6 +749,64 @@ class GatedAttention(nn.Module):
             return dense(features=h, axis=(-2, -1), name="out")(out)
 
 
+class GroupedAttention(nn.Module):
+    """SmallThinker's softmax-attention mixer: ``num_heads`` query heads over
+    ``kv_heads`` key and value heads, all ``head_dim`` wide (which is not
+    ``hidden / num_heads``: 28 heads of 128 on a stream of 2,560), no norm on
+    queries or keys, no gate, no biases. Two kinds of layer are this class:
+    with ``rotary`` a rotary turn over the whole head and, with ``window`` >
+    0, a causal band (a query sees itself and the ``window - 1`` keys before
+    it); without either, the whole causal row and no position term at all
+    (``position_ids`` is not read). Scores over ``sqrt(head_dim)``."""
+
+    num_heads: int
+    kv_heads: int
+    head_dim: int
+    window: int = 0
+    rotary: bool = True
+    rope_theta: float = 10000.0
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    def kernels(self, seq_len: int, width: int) -> dict:
+        return _attention_kernel(self.attention_fn, seq_len, self.head_dim,
+                                 self.head_dim)
+
+    @nn.compact
+    def __call__(self, x, mask=None, segment_ids=None, position_ids=None):
+        b, s, h = x.shape
+        n, g, d = self.num_heads, self.kv_heads, self.head_dim
+        dense = partial(nn.DenseGeneral, dtype=self.dtype,
+                        param_dtype=jnp.float32, use_bias=False,
+                        kernel_init=self.kernel_init)
+        with jax.named_scope("attn.project"):
+            q = dense(features=(n, d), name="query")(x)
+            k = dense(features=(g, d), name="key")(x)
+            v = dense(features=(g, d), name="value")(x)
+            if self.rotary:
+                pos = jnp.arange(s) if position_ids is None else position_ids
+                q = rotary_embedding(q, pos, self.rope_theta)
+                k = rotary_embedding(k, pos, self.rope_theta)
+            # [B, S, H, D] -> [B, H, S, D]
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        attn = self.attention_fn
+        if attn is None:  # dense off a TPU; knows windows and grouped heads
+            from ..ops.flash import make_flash_attention
+
+            attn = make_flash_attention(causal=True, forced=False)
+        kwargs = {"window": self.window} if self.window else {}
+        if segment_ids is not None:
+            kwargs["segment_ids"] = segment_ids
+        if self.window:
+            self.sow("mixer_stats", "attn_window", jnp.float32(self.window))
+        with jax.named_scope("attn.window" if self.window else "attn.full"):
+            out = attn(q, k, v, mask=mask, **kwargs)
+        with jax.named_scope("attn.out"):
+            return dense(features=h, axis=(-2, -1), name="out")(
+                out.transpose(0, 2, 1, 3))
+
+
 class EncoderBlock(nn.Module):
     num_heads: int
     mlp_dim: int
@@ -897,7 +956,9 @@ _MEMORY, _KEYS_VALUES, _ROUTER_STATE = range(3)
 # unit over M*'s output, "X" differential attention over F*'s keys and
 # values; "C": ZAYA1's attention in a latent (arXiv:2511.17127; the block
 # gives such a layer its router and residual scales); Qwen3-Next's two: "D" a
-# gated DeltaNet, "A" gated attention.
+# gated DeltaNet, "A" gated attention; SmallThinker's two: "W" grouped rotary
+# attention in a window, "N" the same heads over the whole causal row with no
+# position term.
 LAYER_KINDS: dict = {
     "": LayerKind(SelfAttention, "attn", "attention",
                   (("causal", True), ("use_bias", False)), 3),
@@ -913,6 +974,9 @@ LAYER_KINDS: dict = {
     "C": LayerKind(ConvolutionalAttention, "attn", "attention", sequence=3),
     "D": LayerKind(GatedDeltaNet, "gdn", "linear_attention"),
     "A": LayerKind(GatedAttention, "attn", "attention", sequence=3),
+    "W": LayerKind(GroupedAttention, "attn", "attention", sequence=3),
+    "N": LayerKind(GroupedAttention, "attn", "attention",
+                   (("window", 0), ("rotary", False)), 3),
 }
 
 
@@ -924,7 +988,9 @@ class DecoderBlock(nn.Module):
     them, under the class's own field names. A ``"C"`` layer (ZAYA1's) also
     has an expert layer whose router is a :class:`..moe.StateRouter` with a
     state handed from layer to layer, and learned scales and shifts on both
-    sides of both residual sums.
+    sides of both residual sums. With ``router_early`` (SmallThinker's) the
+    expert layer's logits are one f32 product of the block's input, made
+    before ``ln_attn`` and attention, whatever the mixer.
     ``dense_dim`` is the feed-forward (0: the dropless expert layer that
     ``moe`` describes; > 0: a dense SwiGLU of that width), ``layer_norm`` the
     norm (LayerNorm, else RMSNorm, with ``norm_offset`` in its ``1 + w``
@@ -947,6 +1013,7 @@ class DecoderBlock(nn.Module):
     layer_norm: bool = False
     parts: tuple = ()  # partial(Class, **its own sizes), one a sized class
     norm_offset: bool = False  # RMSNorm's scale is 1 + w
+    router_early: bool = False  # the router reads x, ahead of attention
 
     def part(self, cls, **fields):
         """``cls`` as this layer holds it: with the sizes ``parts`` states
@@ -989,6 +1056,13 @@ class DecoderBlock(nn.Module):
             norm = partial(nn.LayerNorm, epsilon=self.norm_eps,
                            dtype=self.dtype, param_dtype=jnp.float32)
         kind, handed = LAYER_KINDS[self.kind], list(handed)
+        logits = None  # the expert layer's own router
+        if self.router_early and not self.dense_dim:
+            with jax.named_scope("moe.router"):
+                logits = router_product(
+                    self.num_experts, nn.initializers.truncated_normal(
+                        self.init_std), "router")(x.astype(jnp.float32))
+            self.sow("moe_stats", "router_early", jnp.float32(1))
         y = norm(name="ln_attn")(x)
         taken = () if kind.takes is None else (handed[kind.takes],)
         with jax.named_scope(kind.scope) if kind.scope else nullcontext():
@@ -1004,7 +1078,6 @@ class DecoderBlock(nn.Module):
             with jax.named_scope("mlp.dense"):
                 y = self.part(SwiGLU, mlp_dim=self.dense_dim, name="mlp")(y)
         else:
-            logits = None  # the expert layer's own router
             if self.kind == "C":
                 with jax.named_scope("moe.router"):
                     logits, handed[_ROUTER_STATE] = self.part(
@@ -1025,7 +1098,9 @@ class TransformerDecoder(nn.Module):
     (:data:`LAYER_KINDS`): SambaY's (Phi-4-mini-flash) differ by layer, under
     LayerNorm (``layer_norm``), with no position term at all; ZAYA1's are all
     ``"C"``, under RMSNorm; Qwen3-Next's are three ``"D"`` to one ``"A"``,
-    under RMSNorm's ``1 + w`` form, with a head of its own. SambaY's and
+    under RMSNorm's ``1 + w`` form, with a head of its own; SmallThinker's
+    one ``"N"`` to three ``"W"``, every layer's router ahead of its
+    attention (``router_early``), a head of its own. SambaY's and
     ZAYA1's tie the head to the embedding (``tied_head``).
     ``first_layer`` says which published layers are held (``num_layers`` of
     them from there: one pipeline stage's), and what a layer hands on (M*'s
@@ -1056,6 +1131,7 @@ class TransformerDecoder(nn.Module):
     first_layer: int = 0  # published index of the first layer held here
     parts: tuple = ()  # DecoderBlock's, for every layer
     norm_offset: bool = False  # RMSNorm's scale is 1 + w, everywhere
+    router_early: bool = False  # DecoderBlock's, for every expert layer
     layer_norm: bool = False  # LayerNorm in place of RMSNorm, everywhere
     tied_head: bool = False  # the head is the embedding, no matrix of its own
 
@@ -1090,7 +1166,8 @@ class TransformerDecoder(nn.Module):
             dense_dim=self.dense_dim if i < self.dense_layers else 0,
             moe=self.moe, kind=kind, depth=self.first_layer + i,
             layer_norm=self.layer_norm, parts=self.parts,
-            norm_offset=self.norm_offset, **fields)
+            norm_offset=self.norm_offset, router_early=self.router_early,
+            **fields)
 
     def kernels(self, seq_len: int) -> dict:
         """Which kernels the held layers run at ``seq_len``, by name
@@ -1286,6 +1363,40 @@ qwen3_next_tiny = partial(
     norm_offset=True)
 
 
+def smallthinker_layers(layers: int, period: int = 4) -> tuple:
+    """SmallThinker's layout (``rope_layout`` and ``sliding_window_layout``,
+    one list): layer ``i`` is full attention without a position term (N)
+    where ``i % period == 0`` and rotary attention in a window (W)
+    otherwise."""
+    return tuple("N" if i % period == 0 else "W" for i in range(layers))
+
+
+# SmallThinker-21BA3B-Instruct (PowerInfer/SmallThinker-21BA3B-Instruct
+# config.json; the published class is remote code, so what the config cannot
+# confirm is listed as assumed in the benchmark's configuration): 52 layers,
+# one full position-free attention layer to three rotary ones in a window of
+# 4,096 (theta 1.5e6), 28 query heads over 4 key/value heads of 128 on a
+# stream of 2,560; every layer 64 ReGLU experts of 768 with 6 a token, chosen
+# by a router that reads the layer's input ahead of attention, weighted by a
+# softmax over the chosen six (DroplessMoE's softmax over all, renormalised
+# over the chosen); no shared expert; RMSNorm 1e-6; the head untied.
+_SMALLTHINKER_EXPERTS = (("norm_topk", True), ("activation", "relu"))
+smallthinker_21b_a3b = partial(
+    TransformerDecoder, hidden_size=2560, num_layers=52, num_heads=28,
+    expert_dim=768, num_experts=64, experts_per_token=6, norm_eps=1e-6,
+    rope_theta=1500000.0, moe=_SMALLTHINKER_EXPERTS,
+    layer_kinds=smallthinker_layers(52),
+    parts=(partial(GroupedAttention, kv_heads=4, head_dim=128, window=4096),),
+    router_early=True)
+smallthinker_tiny = partial(
+    TransformerDecoder, hidden_size=64, num_layers=4, num_heads=4,
+    expert_dim=32, num_experts=16, experts_per_token=2, norm_eps=1e-6,
+    rope_theta=1500000.0, moe=_SMALLTHINKER_EXPERTS,
+    layer_kinds=smallthinker_layers(4),
+    parts=(partial(GroupedAttention, kv_heads=2, head_dim=32, window=16),),
+    router_early=True)
+
+
 class Preset(NamedTuple):
     """A ``causal_lm`` preset: the constructor (called with ``vocab_size``
     and whatever a task changes), the vocabulary that is the model's own, and
@@ -1303,11 +1414,14 @@ class Preset(NamedTuple):
 # the sequence-wise balance term, with DeepSeek-V3's weight (arXiv:2412.19437,
 # section 4.2: alpha 0.0001). ZAYA1's are balanced by the selection bias
 # alone. Qwen3-Next's take the balance term at its published class's default
-# weight (router_aux_loss_coef 0.001) and no z term.
+# weight (router_aux_loss_coef 0.001) and no z term, and SmallThinker's the
+# same (its config names none: the benchmark's configuration lists it as
+# assumed).
 _SWITCH_AUX = {"load_balance": 0.01}
 _OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
 _MOONLIGHT_AUX = {"seq_balance": 0.0001}
 _QWEN3_NEXT_AUX = {"load_balance": 0.001}
+_SMALLTHINKER_AUX = {"load_balance": 0.001}
 CAUSAL_LMS: dict = {
     "gpt_base": Preset(gpt_base, 50257, _SWITCH_AUX),
     "gpt_small": Preset(gpt_small, 50257, _SWITCH_AUX),
@@ -1321,4 +1435,7 @@ CAUSAL_LMS: dict = {
     "zaya_tiny": Preset(zaya_tiny, 512, {}),
     "qwen3_next_80b_a3b": Preset(qwen3_next_80b_a3b, 151936, _QWEN3_NEXT_AUX),
     "qwen3_next_tiny": Preset(qwen3_next_tiny, 512, _QWEN3_NEXT_AUX),
+    "smallthinker_21b_a3b": Preset(smallthinker_21b_a3b, 151936,
+                                   _SMALLTHINKER_AUX),
+    "smallthinker_tiny": Preset(smallthinker_tiny, 512, _SMALLTHINKER_AUX),
 }

@@ -362,6 +362,18 @@ _TIMED = {
     # block of 128-wide heads: Mosaic refuses every block of 2,048)
     _Shape(8192, 256, 256, 16, True, 0): SplashTiling(
         (1024, 1024, 256), (1024, 1024, 1024), (1024, 1024)),
+    # SmallThinker's grouped attention, 28 query heads over 4 key and value
+    # heads of 128 at its 16,384-token row. The full layer: 59.24 ms a call
+    # where square 512s take 82.28 (every block of 2,048 queries but dkv's
+    # is refused, and every forward key block of 4,096 beside 2,048 queries)
+    _Shape(16384, 128, 128, 28, True, 0): SplashTiling(
+        (1024, 2048, 512), (2048, 2048, 512), (1024, 2048)),
+    # its band of 4,096 keys, eight 512-blocks wide: 32.13 ms a call where
+    # square 512s take 36.15; wider than Phi-4's one block, it wants square
+    # blocks of 1,024 as the causal shapes do, and key blocks of 2,048 lose
+    # again (more of a block lies outside the band)
+    _Shape(16384, 128, 128, 28, True, 4096): SplashTiling(
+        (1024, 1024, 512), (1024, 1024, 256), (1024, 1024)),
 }
 
 
@@ -499,7 +511,8 @@ def fused_attention_applies(seq: int, head_dim: int, mesh=None,
     values are as wide as their keys: compressed convolutional attention's 8
     query heads over 2 key and value heads of 128, and Qwen3-Next's gated
     attention's 16 query heads over 2 key and value heads of 256, both
-    causal, at 8,192 tokens.
+    causal, at 8,192 tokens, and SmallThinker's 28 query heads over 4 key and
+    value heads of 128 at 16,384 tokens, causal and in a window of 4,096.
     Everything else is dense attention, as before: the CPU, a
     ViT's 197 tokens, a tensor-parallel mesh, and several devices with no
     mesh to say how the batch is split."""

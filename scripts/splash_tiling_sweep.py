@@ -42,7 +42,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # q, k, v as the cells' layers call unequal_attention (one row of 8,192
-# tokens, segment ids present and all ones)
+# tokens, or of a shape's own ``seq``; segment ids present and all ones)
 SEQ = 8192
 SHAPES = {
     "moonlight": dict(q=(16, 192), k=(16, 192), v=(16, 128), window=0),
@@ -53,15 +53,28 @@ SHAPES = {
     "zaya": dict(q=(8, 128), k=(2, 128), v=(2, 128), window=0),
     # Qwen3-Next's gated attention: heads of 256, twice the VMEM a block
     "qwen3_next": dict(q=(16, 256), k=(2, 256), v=(2, 256), window=0),
+    # SmallThinker's grouped attention at its 16,384-token row: the N layer's
+    # whole causal row, and the W layers' band of 4,096 (eight 512-blocks)
+    "smallthinker_full": dict(q=(28, 128), k=(4, 128), v=(4, 128), window=0,
+                              seq=16384),
+    "smallthinker_window": dict(q=(28, 128), k=(4, 128), v=(4, 128),
+                                window=4096, seq=16384),
 }
 KERNELS = ("fwd", "dkv", "dq")
 CALLS = 20  # after one call of warm-up
 OUT = os.path.join("chiprun_out", "splash_sweep")
 
 
+def seq_of(shape: dict) -> int:
+    return shape.get("seq", SEQ)
+
+
 def candidates(window: int):
     """``(block_q, block_kv, block_kv_compute)`` triples to try."""
-    if window:
+    if window >= 2048:  # a band several blocks wide
+        qs, kvs = (256, 512, 1024, 2048), (512, 1024, 2048)
+        computes = (256, 512, 1024)
+    elif window:
         qs, kvs, computes = (128, 256, 512), (128, 256, 512), (128, 256, 512)
     else:
         qs, kvs = (256, 512, 1024, 2048), (512, 1024, 2048, 4096)
@@ -116,10 +129,11 @@ def make_program(index, shape, tiling, sharding=None):
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v, ids)
 
     program.__name__ = f"sweep_{index}"
-    specs = [jax.ShapeDtypeStruct((1, heads, SEQ, width), jnp.bfloat16,
+    seq = seq_of(shape)
+    specs = [jax.ShapeDtypeStruct((1, heads, seq, width), jnp.bfloat16,
                                   sharding=sharding)
              for heads, width in (shape["q"], shape["k"], shape["v"])]
-    specs.append(jax.ShapeDtypeStruct((1, SEQ), jnp.int32, sharding=sharding))
+    specs.append(jax.ShapeDtypeStruct((1, seq), jnp.int32, sharding=sharding))
     return jax.jit(program).lower(*specs).compile()
 
 
@@ -197,11 +211,12 @@ class Sweep:
         import jax.numpy as jnp
 
         keys = jax.random.split(jax.random.key(0), 3)
-        arrays = [jax.random.normal(key, (1, heads, SEQ, width),
+        seq = seq_of(self.shape)
+        arrays = [jax.random.normal(key, (1, heads, seq, width),
                                     jnp.bfloat16)
                   for key, (heads, width) in zip(keys, (
                       self.shape["q"], self.shape["k"], self.shape["v"]))]
-        return (*arrays, jnp.ones((1, SEQ), jnp.int32))
+        return (*arrays, jnp.ones((1, seq), jnp.int32))
 
     def stage(self, label, tilings):
         """Compile and time one program a tiling; a row each."""
@@ -267,7 +282,10 @@ def best(rows, kernel):
     return tuple(ranked[0][kernel]) if ranked else None
 
 
-def sweep_shape(name, compile_only, sharding):
+def sweep_shape(name, compile_only, sharding, record_stages=True):
+    """Without ``record_stages`` the two stages no entry of ``ops/flash.py``
+    can take are left out (the library's blocked kernel on repeated keys, the
+    fused backward): timed for the record at PRs 34, 38 and 41."""
     sweep = Sweep(name, compile_only, sharding)
     window = SHAPES[name]["window"]
     default = (512, 512, 512)
@@ -276,7 +294,7 @@ def sweep_shape(name, compile_only, sharding):
     base = sweep.stage("today", [tiling_of(default, default, default)])
     if not compile_only and not base[0].get("fwd_ms"):
         raise SystemExit(f"no splash kernel found in the trace: {base}")
-    if SHAPES[name]["q"][1] == SHAPES[name]["v"][1]:
+    if record_stages and SHAPES[name]["q"][1] == SHAPES[name]["v"][1]:
         # program ms is what compares: its kernels have other names
         sweep.stage("blocked", [512, 1024, 2048])
     blocks = sweep.stage("blocks", [tiling_of(t, t, t) for t in triples])
@@ -290,6 +308,8 @@ def sweep_shape(name, compile_only, sharding):
     timed = [r for r in sweep.rows if "error" not in r]
     top = {k: best(timed, k) or default for k in KERNELS}
     sweep.stage("best", [tiling_of(top["fwd"], top["dkv"], top["dq"])])
+    if not record_stages:
+        return sweep.rows
     # the fused backward: one kernel for dk, dv and dq's partials
     sweep.stage("fused", [tiling_of(top["fwd"], t)
                           for t in fused_candidates(window)])
@@ -314,6 +334,9 @@ def main() -> None:
     parser.add_argument("--shapes", default=",".join(SHAPES))
     parser.add_argument("--compile_only", action="store_true",
                         help="compile for a described v5e; time nothing")
+    parser.add_argument("--no_record_stages", action="store_true",
+                        help="leave out the blocked kernel and the fused "
+                        "backward, which no entry can take")
     args = parser.parse_args()
     sharding = None
     if args.compile_only:
@@ -333,7 +356,8 @@ def main() -> None:
         print(f"device: {device.device_kind} x {jax.device_count()}")
     for name in args.shapes.split(","):
         t0 = time.monotonic()
-        rows = sweep_shape(name, args.compile_only, sharding)
+        rows = sweep_shape(name, args.compile_only, sharding,
+                           not args.no_record_stages)
         with open(os.path.join(OUT, f"{name}.md"), "w") as f:
             f.write(table(rows) + "\n")
         print(f"\n## {name} ({time.monotonic() - t0:.0f} s, "

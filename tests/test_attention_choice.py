@@ -346,6 +346,34 @@ def test_unequal_attention_compiles_for_a_v5e_at_qwen3_nexts_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@pytest.mark.parametrize("window", [0, 4096], ids=["full", "window"])
+def test_unequal_attention_compiles_for_a_v5e_at_smallthinkers_widths(
+        one_chip, window):
+    """Grouped attention's heads as the SmallThinker cell runs them: 28 query
+    heads over 4 key and value heads of 128 at a 16,384-token row, once over
+    the whole causal row and once in a band of 4,096, each at the tiling
+    timed for its shape: the three kernels, and no ``[B, H, S, S]``
+    tensor."""
+    def spec(heads):
+        return jax.ShapeDtypeStruct((1, heads, 16384, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    tiling, timed = flash.splash_tiling(16384, 128, 128, 28, True, window)
+    assert timed and tiling.dq is not None
+
+    def loss(q, k, v):
+        out = flash.unequal_attention(q, k, v, causal=True, window=window)
+        assert out.shape == (1, 28, 16384, 128)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec(28), spec(4), spec(4)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "16384,16384]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_the_delta_rules_kernel_compiles_for_a_v5e_at_qwen3_nexts_widths(
         one_chip):
     """A Gated DeltaNet's rule as the Qwen3-Next cell calls it (two rows of
@@ -464,6 +492,8 @@ CELL_SHAPES = [  # seq, d_qk, d_v, heads, causal, window
     (8192, 64, 128, 40, True, 0),  # Phi-4-mini-flash, F* and X
     (8192, 64, 128, 40, True, 512),  # Phi-4-mini-flash, S
     (8192, 128, 128, 8, True, 0),  # ZAYA1's heads in groups, equal widths
+    (16384, 128, 128, 28, True, 0),  # SmallThinker's full layer, 28 over 4
+    (16384, 128, 128, 28, True, 4096),  # and its band, eight 512-blocks wide
 ]
 UNTIMED_SHAPES = [  # and the square block each runs
     ((4096, 192, 128, 16, True, 0), 512),  # a shorter row of the same heads

@@ -5,7 +5,7 @@ its mixers say, and both the step's gauges and the first log line are made
 from that one answer.
 
 *Does every preset answer with the kernels its layers have, and does the
-first log line say them?* The twelve presets and the two spans the
+first log line say them?* The fourteen presets and the three spans the
 benchmark's cells hold. *Does a kind nobody wrote into the task or the
 trainer train?* A toy mixer with sizes, a kernel and a sown gauge of its
 own, patched into the two tables here, through ``get_task`` and
@@ -43,10 +43,13 @@ KERNELS = {
     ("zaya1_8b", None): ATTENTION, ("zaya_tiny", None): ATTENTION,
     ("qwen3_next_80b_a3b", None): QWEN3_NEXT,
     ("qwen3_next_tiny", None): QWEN3_NEXT,
-    # the spans of the cells c4-phi4flash-vp8-prepacked-8k and
-    # c4-qwen3next-ep16-prepacked-8k
+    ("smallthinker_21b_a3b", None): ATTENTION,
+    ("smallthinker_tiny", None): ATTENTION,
+    # the spans of the cells c4-phi4flash-vp8-prepacked-8k,
+    # c4-qwen3next-ep16-prepacked-8k and c4-smallthinker-ep4-prepacked-16k
     ("phi4_mini_flash", "14:20"): SAMBAY,
     ("qwen3_next_80b_a3b", "0:4"): QWEN3_NEXT,
+    ("smallthinker_21b_a3b", "0:4"): ATTENTION,
 }
 
 
