@@ -13,9 +13,9 @@ Mixture-of-Experts layers.
   mesh, impossible at a published sparse model's shape (8,192 tokens, 64
   experts, 8 a token: terabytes).
 * :class:`DroplessMoE` — the **published-shape** form (the OLMoE,
-  Moonlight, ZAYA1 and Qwen3-Next presets): top-k routing with no capacity
-  and no dropped token; the ``T * k`` assignments are sorted by expert, the
-  tokens gathered, the
+  Moonlight, ZAYA1, Qwen3-Next and SmallThinker presets): top-k routing
+  with no capacity and no dropped token; the ``T * k`` assignments are sorted
+  by expert, the tokens gathered, the
   three SwiGLU products run as grouped matrix multiplications over the
   ragged groups (``jax.lax.ragged_dot``), and each token's k results
   gathered back through the inverse permutation and summed with their
@@ -24,7 +24,11 @@ Mixture-of-Experts layers.
   it holds (one rank's share of an expert-parallel layer: the router stays
   whole, the layer computes what its own experts give and leaves out what
   the absent ones would add; the exchange that brings a deployment's rank
-  the other ranks' tokens is not here: ROADMAP D12). Its router is one
+  the other ranks' tokens is not here: ROADMAP D12; its rows -> tokens sum
+  is :func:`..ops.rows.sum_rows`: a loop over the k slots off the TPU, under
+  a mesh, with one expert a token and for the worst-case list, one sort, one
+  gather and a Pallas kernel that reads each built row once for the usual
+  list on one TPU device). Its router is one
   product of its own, or the logits the caller hands it:
 * :class:`StateRouter` — ZAYA1's, an MLP over a narrow state that is mixed
   with the previous layer's and handed on to the next.
@@ -33,11 +37,13 @@ Mixture-of-Experts layers.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..ops.rows import Way as _Way, sum_rows
 
 __all__ = ["SwiGLU", "MoEMLP", "DroplessMoE", "StateRouter",
            "router_product"]
@@ -155,38 +161,6 @@ _permute_rows.defvjp(
     lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
 
 
-class _Way(NamedTuple):
-    """Where the ``R`` built rows of a rank's sorted list and the ``T`` tokens
-    find each other: integers and booleans only."""
-
-    head: jax.Array  # [R] the assignment (token * k + slot) a row stands for
-    live: jax.Array  # [R] the row is in a held expert's group
-    pos: jax.Array  # [T, k] where each assignment sits in the whole list
-    valid: jax.Array  # [T, k] it sits in a held expert's group
-
-
-def _sum_slots(rows, way, weights=None):
-    """Each token's live rows summed in f32, [R, H] -> [T, H] f32, each row
-    times its assignment's weight (``weights`` [T, k]) first if given: one
-    loop over the k slots, each turn a gather of ``[T, H]`` in the rows' own
-    type with a token's absent slot left out. Nothing is ``[T, k, H]``, and
-    nothing is rounded between the product and the sum. (Written out as k
-    gathers it ran no faster and cost the compiler 10 s a start: PERF.md.)"""
-    t, k = way.pos.shape
-    pos = jnp.minimum(way.pos, rows.shape[0] - 1).T  # [k, T]
-    held = way.valid.T
-    scale = None if weights is None else jnp.asarray(weights).T
-
-    def slot(j, y):
-        row = jnp.take(rows, pos[j], axis=0).astype(jnp.float32)
-        if scale is not None:
-            row = row * scale[j][:, None]
-        return y + jnp.where(held[j][:, None], row, 0)
-
-    zero = jnp.zeros((t, rows.shape[1]), jnp.float32)
-    return slot(0, zero) if k == 1 else jax.lax.fori_loop(0, k, slot, zero)
-
-
 @jax.custom_vjp
 def _tokens_to_rows(x, way):
     """The built rows from the tokens ``x`` [T, H]: row r is the token its
@@ -201,7 +175,7 @@ def _tokens_to_rows(x, way):
 def _rows_to_tokens(rows, way):
     """Each token the sum of its live rows, [R, H] -> [T, H], summed in f32
     and returned in the rows' type."""
-    return _sum_slots(rows, way).astype(rows.dtype)
+    return sum_rows(rows, way, dtype=rows.dtype)
 
 
 _tokens_to_rows.defvjp(
@@ -219,7 +193,7 @@ def _sum_back(out, top_p, way):
     tokens. Its cotangents come from the R rows that :func:`_tokens_to_rows`
     makes of the ``[T, H]`` one, and ``top_p``'s is a gather of R dot
     products: nothing is ``[T, k, H]``."""
-    return _sum_slots(out, way, top_p)
+    return sum_rows(out, way, top_p)
 
 
 def _sum_back_bwd(res, g):
@@ -348,7 +322,11 @@ class DroplessMoE(nn.Module):
     tokens and the ``[R, H]``
     built rows lie two operations that are each other's transposes: tokens
     -> rows (a live row is its token, a dead one zeros) and rows -> tokens
-    (a token is the sum of its live rows, in f32, slot after slot). The sum
+    (a token is the sum of its live rows, in f32: :mod:`..ops.rows`, slot
+    after slot in a loop that gathers and masks ``[T, H]`` k times, or, for
+    the usual list on one TPU device, the rows sorted by token and summed by
+    a kernel that reads each once: ``rows_sum_applies`` chooses from the
+    shapes, and the gauge ``rows_sum_fused`` says). The sum
     back weights each row by its assignment's ``top_p`` in f32 on the way, and
     its cotangents come from the R rows of the ``[T, H]`` one: nothing
     shaped ``[T, k, H]`` is gathered, multiplied or broadcast, forward or

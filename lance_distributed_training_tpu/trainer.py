@@ -1434,6 +1434,12 @@ def _train(config: TrainConfig) -> dict:
         results["attention_fused"] = float(task.kernels["attention"])
         default_registry().gauge("attention_fused").set(
             results["attention_fused"])
+    if getattr(task.model, "experts_per_token", 0):
+        # 0 until a step is traced whose expert layers sum their rows back
+        # by the kernel (ops/rows.py sets it to 1 as it builds one): like
+        # attention_fused a gauge and an entry of every log line
+        results["rows_sum_fused"] = 0.0
+        default_registry().gauge("rows_sum_fused").set(0.0)
     total_start = time.perf_counter()
     global_step = 0
 
@@ -2087,6 +2093,9 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             # says once what tiling it runs
             for line in splash_tilings_built():
                 logger.log(line, to_wandb=False)
+        if "rows_sum_fused" in known:
+            entry["rows_sum_fused"] = default_registry().gauge(
+                "rows_sum_fused").value
         if config.data_echo > 1:
             # The windowed rate counts echoed steps; report the unique-data
             # rate next to it (as the epoch metrics do) so the live stream
@@ -2460,6 +2469,9 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
 
     obs_phase("train.shutdown")  # final eval, then train()'s teardown
     results["history"] = history
+    if "rows_sum_fused" in results:  # as the traced steps left it
+        results["rows_sum_fused"] = default_registry().gauge(
+            "rows_sum_fused").value
     results["steps"] = global_step  # train steps executed this run
     results["global_step"] = journal.abs_step  # absolute, across restarts
     results["total_time"] = time.perf_counter() - total_start

@@ -446,6 +446,41 @@ def test_the_convolutions_kernels_compile_for_a_v5e_at_the_cells_widths(
         rows * 8192 * width * 2)
 
 
+@pytest.mark.parametrize("tokens,k,built,width", [
+    (16384, 10, 20480, 2048),  # c4-qwen3next-ep16-prepacked-8k's usual list
+    (16384, 6, 49152, 2560),  # c4-smallthinker-ep4-prepacked-16k's
+    (8192, 6, 12288, 2048),  # c4-moonlight-ep8-prepacked-8k's
+])
+def test_the_rows_kernel_compiles_for_a_v5e_at_the_cells_shapes(
+        one_chip, tokens, k, built, width):
+    """Rows -> tokens as the three share cells call it, weighted into f32
+    (the sum back) and plain into bf16 (the cotangent of tokens -> rows): one
+    kernel each, no loop over the slots, nothing with a slot axis beside
+    ``[T, H]``, and beside the rows in their tokens' order no scratch."""
+    from lance_distributed_training_tpu.ops import rows as ops
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(rows, head, live, weights):
+        way = ops.Way(head, live, jnp.zeros((tokens, k), jnp.int32),
+                      jnp.zeros((tokens, k), bool))
+        return (ops.rows_kernel(rows, way, weights),
+                ops.rows_kernel(rows, way, dtype=rows.dtype))
+
+    compiled = jax.jit(both).lower(
+        spec(built, width), spec(built, dtype=jnp.int32),
+        spec(built, dtype=jnp.bool_),
+        spec(tokens, k, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "while(" not in text
+    assert not re.search(rf"\[({k},{tokens}|{tokens},{k}),{width}\]", text)
+    # the ordered rows, bf16, and nothing else of the rows' or tokens' size
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * (
+        built * width * 2)
+
+
 @pytest.mark.parametrize("rows,seq,hidden,vocab,tied", [
     (1, 8192, 2560, 25008, True),  # c4-phi4flash-vp8-prepacked-8k's head
     (2, 4096, 2048, 50304, False),  # c4-olmoe-prepacked-4k's
