@@ -491,11 +491,13 @@ def _causal_lm_task(vocab_size: Optional[int], model_name: str, seq_len: int,
                        moe_every=moe_every)
     model = model.clone(**changes)
     kernels = model.kernels(seq_len)  # a span that cannot run is refused here
-    # All but attention's (a gauge the trainer sets once, on the host) ride
-    # the step's stats as constants: ``<name>_fused``, the scan's under the
-    # name it has had
+    # All but attention's (a gauge the trainer sets once, on the host) and
+    # the gated norm's (the first log line says it; no gauge) ride the
+    # step's stats as constants: ``<name>_fused``, the scan's under the name
+    # it has had
     fused = {{"scan": "ssm_scan_fused"}.get(name, f"{name}_fused"): on
-             for name, on in kernels.items() if name != "attention"}
+             for name, on in kernels.items()
+             if name not in ("attention", "norm")}
     sows = (["aux_loss", "moe_stats", "router_state", "mixer_stats"]
             if decoder else ["aux_loss"] if num_experts > 0 else [])
 

@@ -594,7 +594,17 @@ class GatedDeltaNet(nn.Module):
     columns where they lie (forward, the recomputed forward and a backward
     pass that keeps nothing but the projection); everywhere else the plain
     ``silu(causal_depthwise_conv(.))`` in XLA, as the rule beside it is
-    :func:`..ops.delta.delta_chunked` there."""
+    :func:`..ops.delta.delta_chunked` there. The two norms run where their
+    rows already sit. On the chip the unit norms of ``q`` and ``k`` are made
+    by the rule's kernels as they load a key head's rows (and their
+    derivative by the rule's backward kernel as it writes ``dq`` and
+    ``dk``), which read ``q``, ``k`` and ``v`` from the convolved columns in
+    place (:func:`..ops.delta.gated_delta_rule_packed`), and the gated
+    RMSNorm is one kernel each way that reads ``z`` from the projection's
+    last columns and keeps ``o``, ``z`` and the scale
+    (:func:`..ops.norm.gated_rms_norm`); off the chip, under a mesh and at
+    heads that are not whole lane groups both are the ``jax.numpy`` lines
+    they were, float32 with the same epsilons."""
 
     key_heads: int
     value_heads: int
@@ -608,20 +618,24 @@ class GatedDeltaNet(nn.Module):
     def kernels(self, seq_len: int, width: int) -> dict:
         from ..ops.conv import conv_fused_applies
         from ..ops.delta import delta_fused_applies
+        from ..ops.norm import norm_fused_applies
 
         convolved = (2 * self.key_heads * self.key_dim
                      + self.value_heads * self.value_dim)
         return {"delta": delta_fused_applies(seq_len, self.value_heads,
                                              self.key_dim, self.value_dim),
-                "conv": conv_fused_applies(seq_len, convolved, self.conv)}
+                "conv": conv_fused_applies(seq_len, convolved, self.conv),
+                "norm": norm_fused_applies(seq_len, self.value_heads,
+                                           self.value_dim)}
 
     @nn.compact
     def __call__(self, u):
         from jax.ad_checkpoint import checkpoint_name
 
-        from ..ops.delta import gated_delta_rule
+        from ..ops.delta import gated_delta_rule_packed
+        from ..ops.norm import gated_rms_norm
 
-        b, s, h = u.shape
+        h = u.shape[-1]
         hk, hv, dk, dv = (self.key_heads, self.value_heads, self.key_dim,
                           self.value_dim)
         keys, values = hk * dk, hv * dv
@@ -640,10 +654,6 @@ class GatedDeltaNet(nn.Module):
                              jnp.float32)
         scale = self.param("norm_scale", nn.initializers.ones_init(), (dv,),
                            jnp.float32)
-
-        def unit(t):  # [B, S, heads * dk] -> [B, S, heads, dk], |t| = 1
-            t = t.reshape(b, s, hk, dk).astype(jnp.float32)
-            return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
 
         # Ahead of the output projection everything but the rule is a
         # product of 12,288 columns or elementwise over [S, 8,192] and [S,
@@ -664,20 +674,16 @@ class GatedDeltaNet(nn.Module):
             with jax.named_scope("gdn.gates"):
                 beta = nn.sigmoid(ba[..., :hv])
                 g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
-                q = (unit(mixed[..., :keys]) * dk ** -0.5).astype(self.dtype)
-                k = unit(mixed[..., keys:2 * keys]).astype(self.dtype)
-                v = mixed[..., 2 * keys:].reshape(b, s, hv, dv)
             with jax.named_scope("gdn.kernel"):
-                o, last = gated_delta_rule(q, k, v, g, beta)
+                # q, k, v where they lie in mixed's columns, q and k raw:
+                # their norms are the rule's (off the chip, ahead of it)
+                o, last = gated_delta_rule_packed(
+                    mixed, g, beta, key_heads=hk, key_dim=dk, qk_norm=True)
             o = checkpoint_name(o, "gdn_rule_out")
             with jax.named_scope("gdn.norm"):
-                o = o.astype(jnp.float32)
-                o = o * jax.lax.rsqrt(
-                    jnp.mean(o * o, -1, keepdims=True) + self.norm_eps
-                ) * scale
-                z = qkvz[..., 2 * keys + values:].reshape(b, s, hv, dv)
-                gated = (o * nn.silu(z.astype(jnp.float32))).astype(
-                    self.dtype).reshape(b, s, values)
+                # z where it lies in the projection's last columns
+                gated = gated_rms_norm(o, qkvz, scale, eps=self.norm_eps,
+                                       dtype=self.dtype)
             return gated, (jnp.abs(last).max(), jnp.exp(g.min()),
                            beta.mean())
 

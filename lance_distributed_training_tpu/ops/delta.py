@@ -8,11 +8,13 @@ first mixer here that :mod:`.scan`'s elementwise state cannot express.
 
 per row and value head, with ``q``, ``k`` ``[B, L, Hk, d_k]``, ``v`` ``[B, L,
 Hv, d_v]``, ``g`` (<= 0) and ``beta`` ``[B, L, Hv]``; key head ``j`` serves
-the value heads ``j * Hv / Hk`` onwards (``repeat_interleave``). The caller
-norms and scales ``q`` and ``k``. Token by token that is ``L`` dependent
-steps of vector work; a chunk of ``C`` tokens at a time (``gamma`` the
-running sum of ``g`` inside the chunk, ``D_ij = exp(gamma_i - gamma_j)`` for
-``i >= j``) it is matrix products:
+the value heads ``j * Hv / Hk`` onwards (``repeat_interleave``). ``q`` and
+``k`` come normed and scaled, or the rule does it (``qk_norm``: a head's
+``q / sqrt(|q|^2 + 1e-6) / sqrt(d_k)`` and ``k / sqrt(|k|^2 + 1e-6)`` in
+float32, rounded once to the operands' type). Token by token that is ``L``
+dependent steps of vector work; a chunk of ``C`` tokens at a time (``gamma``
+the running sum of ``g`` inside the chunk, ``D_ij = exp(gamma_i - gamma_j)``
+for ``i >= j``) it is matrix products:
 
     A  = strictly-lower(diag(beta) (K K' * D))      T = (I + A)^-1
     W  = T diag(beta) (exp(gamma) * K)              U = T diag(beta) V
@@ -49,7 +51,14 @@ float32). What is left is sequential over the chunks, four products of ``[C,
   chunk's cotangents through the recurrence and on through its preparation
   to ``dq``, ``dk``, ``dv``, ``d gamma`` and ``d beta`` (``-T' G T'`` for
   the inverse). Only the running sum that makes ``gamma`` of ``g``, its
-  transpose and the two layouts of the per-token scalars stay in XLA.
+  transpose and the two layouts of the per-token scalars stay in XLA. Under
+  ``qk_norm`` the kernels norm each key head's ``[2C, d_k]`` tile as they
+  load it and ``delta_rule_bwd`` turns the cotangents of the normed ``q``
+  and ``k`` into those of the raw ones before it writes them, and ``q``,
+  ``k``, ``v`` may be the columns ``[q; k; v]`` of one array (a layer's
+  convolved projection, :func:`gated_delta_rule_packed`), which the block
+  specifications walk where they lie: no slice and no normed copy is
+  written out in front of the call.
 
 Both forms cast where the other does: ``g``, its sums and exponentials, the
 inverse (its products at the highest precision) and the state are float32;
@@ -70,8 +79,9 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["gated_delta_rule", "delta_chunked", "delta_kernel",
-           "delta_fused_applies", "CHUNK", "BLOCK_H", "GROUP_H"]
+__all__ = ["gated_delta_rule", "gated_delta_rule_packed", "delta_chunked",
+           "delta_kernel", "delta_fused_applies", "unit_heads", "CHUNK",
+           "BLOCK_H", "GROUP_H"]
 
 CHUNK = 64  # tokens a chunk: the published implementation's
 BLOCK_H = 8  # heads a grid step holds (on the v5e 5% ahead of 4, 8% of 2:
@@ -83,6 +93,7 @@ _PAIR = 2 * CHUNK  # tokens a grid step of the kernels: two chunks, whose
 # [C, C] matrices lie side by side along the 128 lanes
 _ABREAST = 4  # heads of a block whose chains of products a kernel writes
 # side by side, step by step (the v5e has four matrix units)
+_NORM_EPS = 1e-6  # under the root of a head's unit norm
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
 _NN = (((1,), (0,)), ((), ()))  # a @ b
@@ -99,6 +110,27 @@ def _check(q, k, v, g, beta):
             "the gated delta rule takes q, k [B, L, Hk, d_k], v [B, L, Hv, "
             "d_v] with Hk dividing Hv, and g, beta [B, L, Hv]; got "
             f"{q.shape}, {k.shape}, {v.shape}, {g.shape}, {beta.shape}")
+
+
+def _unit(t):
+    """``(t r, r)`` float32 for ``r = (|t|^2 + 1e-6)^-1/2`` over the last
+    axis: a head's unit vector and what made it one."""
+    t = t.astype(_F32)
+    r = jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + _NORM_EPS)
+    return t * r, r
+
+
+def unit_heads(t):
+    """``t`` ``[..., d]`` in float32 over ``sqrt(|t|^2 + 1e-6)``: the plain
+    form of a head's unit norm (the caller scales and rounds)."""
+    return _unit(t)[0]
+
+
+def _normed(t, is_key):
+    """``q`` (``is_key`` 0) or ``k`` ``[B, L, Hk, d_k]`` raw as the rule
+    takes it: a unit vector a head, ``q`` over ``sqrt(d_k)``, in its type."""
+    unit = unit_heads(t)
+    return (unit if is_key else unit * t.shape[-1] ** -0.5).astype(t.dtype)
 
 
 # -- what does not depend on the state ----------------------------------------
@@ -323,6 +355,24 @@ def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=_F32)
 
 
+def _unit_tile(t, scale=None):
+    """:func:`unit_heads` of one head's rows ``[2C, d]`` as a kernel loads
+    them, times ``scale``, rounded once to their type; and what the way
+    back needs of it, float32: the unit rows ``u = r t`` and ``r = (|t|^2 +
+    1e-6)^-1/2`` times ``scale``."""
+    unit, r = _unit(t)
+    if scale is None:
+        return unit.astype(t.dtype), (unit, r)
+    return (unit * scale).astype(t.dtype), (unit, r * scale)
+
+
+def _unit_tile_back(kept, d_normed):
+    """The cotangent of a head's raw rows from that of their normed selves,
+    float32: ``r (d - u sum(d * u))``."""
+    unit, r = kept
+    return r * (d_normed - unit * jnp.sum(d_normed * unit, 1, keepdims=True))
+
+
 class _Head(NamedTuple):
     """What a grid step reads for one value head: its number in the block,
     its key head's ``q``, ``k`` ``[2C, d_k]`` and their two pairs of score
@@ -339,6 +389,7 @@ class _Head(NamedTuple):
     g_row: jax.Array
     b_col: jax.Array
     b_row: jax.Array
+    normed: Optional[tuple] = None  # of q and of k, for the way back
 
 
 class _Pair(NamedTuple):
@@ -404,10 +455,11 @@ def _pairs(heads, t_invs=None):
     return parts
 
 
-def _heads(refs, block_h, rep, d_k, d_v):
+def _heads(refs, block_h, rep, d_k, d_v, qk_norm):
     """A block's value heads, ``_ABREAST`` or so at a time (whole key
-    heads'), each with what the kernel reads for it; a key head's two pairs
-    of score matrices are made once for the value heads it serves."""
+    heads'), each with what the kernel reads for it; a key head's rows are
+    normed (``qk_norm``) and its two pairs of score matrices made once for
+    the value heads it serves."""
     q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref, br_ref = refs
     g_cols, g_rows = gc_ref[0, 0, 0], gr_ref[0, 0, 0]  # [2C, bh], [bh, 2C]
     b_cols, b_rows = bc_ref[0, 0, 0], br_ref[0, 0, 0]
@@ -417,16 +469,20 @@ def _heads(refs, block_h, rep, d_k, d_v):
         for j in range(start // rep, min(start + abreast, block_h) // rep):
             q = q_ref[0, :, j * d_k:(j + 1) * d_k]
             k = k_ref[0, :, j * d_k:(j + 1) * d_k]
+            normed = None
+            if qk_norm:
+                (q, k), normed = zip(_unit_tile(q, d_k ** -0.5),
+                                     _unit_tile(k))
             kk, qk = _unstack(_dot(k, k, _NT)), _unstack(_dot(q, k, _NT))
             heads += [
                 _Head(h, q, k, v_ref[0, :, h * d_v:(h + 1) * d_v], kk, qk,
                       g_cols[:, h:h + 1], g_rows[h:h + 1],
-                      b_cols[:, h:h + 1], b_rows[h:h + 1])
+                      b_cols[:, h:h + 1], b_rows[h:h + 1], normed)
                 for h in range(j * rep, (j + 1) * rep)]
         yield heads
 
 
-def _fwd_kernel(*refs, block_h, rep, d_k, d_v, keep):
+def _fwd_kernel(*refs, block_h, rep, d_k, d_v, qk_norm, keep):
     """The rule forward over one pair of chunks: ``o`` and the state at the
     row's end or, for a backward pass (``keep``), the state each chunk
     starts from and the pair's inverses and nothing else."""
@@ -438,7 +494,7 @@ def _fwd_kernel(*refs, block_h, rep, d_k, d_v, keep):
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    for heads in _heads(ins, block_h, rep, d_k, d_v):
+    for heads in _heads(ins, block_h, rep, d_k, d_v, qk_norm):
         at_h = [head.h for head in heads]
         dtype = heads[0].k.dtype
         c = heads[0].k.shape[0] // 2
@@ -480,12 +536,13 @@ def _place(into, h, part, axis):
     return jnp.where(at == h, part, into)
 
 
-def _bwd_kernel(*refs, block_h, rep, d_k, d_v):
+def _bwd_kernel(*refs, block_h, rep, d_k, d_v, qk_norm):
     """A pair of chunks' cotangents, from ``do`` and the state's, carried
     through the recurrence (the second chunk, then the first) and on through
     the chunks' preparation to ``dq``, ``dk``, ``dv`` and, by columns and by
     rows, ``d gamma`` and ``d beta``: ``-T' G T'`` for the inverse, row and
-    column sums of ``dD * D`` for the mask."""
+    column sums of ``dD * D`` for the mask; under ``qk_norm`` on through
+    the norm of ``q`` and ``k`` to the raw rows' cotangents."""
     from jax.experimental import pallas as pl
 
     ins = refs[:7]
@@ -508,7 +565,7 @@ def _bwd_kernel(*refs, block_h, rep, d_k, d_v):
     dg_cols = jnp.zeros(dgc_ref.shape[3:], _F32)
     dg_rows = jnp.zeros(dgr_ref.shape[3:], _F32)
     db_cols, db_rows = dg_cols, dg_rows
-    for heads in _heads(ins, block_h, rep, d_k, d_v):
+    for heads in _heads(ins, block_h, rep, d_k, d_v, qk_norm):
         at_h = [head.h for head in heads]
         dtype = heads[0].k.dtype
         parts = _pairs(heads, [t_ref[0, h, 0] for h in at_h])
@@ -561,10 +618,10 @@ def _bwd_kernel(*refs, block_h, rep, d_k, d_v):
                    parts, heads, dt_ks, dt_vs, scaleds)]
         das = [jnp.where(row > col, -mm(da, _stack(part.t_inv), _NT), 0.0)
                for da, part in zip(das, parts)]
-        for (h, q, k, v, kk, qk, g_col, g_row, b_col, b_row), part, d_last, \
-                (du_op, dw_op, dq_g, dk_g), dp, dt_k, dt_v, scaled, da in zip(
-                    heads, parts, d_lasts, stacked, dps, dt_ks, dt_vs,
-                    scaleds, das):
+        for (h, q, k, v, kk, qk, g_col, g_row, b_col, b_row, normed), part, \
+                d_last, (du_op, dw_op, dq_g, dk_g), dp, dt_k, dt_v, scaled, \
+                da in zip(heads, parts, d_lasts, stacked, dps, dt_ks, dt_vs,
+                          scaleds, das):
             if h % rep == 0:
                 dq = dk = jnp.zeros((2 * c, d_k), _F32)
                 dkk = dqk = jnp.zeros((c, 2 * c), _F32)
@@ -601,22 +658,66 @@ def _bwd_kernel(*refs, block_h, rep, d_k, d_v):
             db_cols, db_rows = (_place(db_cols, h, db_col, 1),
                                 _place(db_rows, h, db_row, 0))
             if h % rep == rep - 1:  # the key head's last value head
-                j = h // rep
+                at = (0, slice(None), slice(h // rep * d_k,
+                                            (h // rep + 1) * d_k))
                 dkk_op = _stack(dkk.astype(dtype))
                 dqk_op = _stack(dqk.astype(dtype))
-                dq_ref[0, :, j * d_k:(j + 1) * d_k] = (
-                    dq + _dot(dqk_op, k, _NN)).astype(dq_ref.dtype)
-                dk_ref[0, :, j * d_k:(j + 1) * d_k] = (
-                    dk + _dot(dkk_op, k, _NN) + _dot(dkk_op, k, _TN)
-                    + _dot(dqk_op, q, _TN)).astype(dk_ref.dtype)
+                dq = dq + _dot(dqk_op, k, _NN)
+                dk = (dk + _dot(dkk_op, k, _NN) + _dot(dkk_op, k, _TN)
+                      + _dot(dqk_op, q, _TN))
+                if qk_norm:  # q and k are the normed rows: back to the raw
+                    dq = _unit_tile_back(normed[0], dq)
+                    dk = _unit_tile_back(normed[1], dk)
+                dq_ref[at] = dq.astype(dq_ref.dtype)
+                dk_ref[at] = dk.astype(dk_ref.dtype)
     dgc_ref[0, 0, 0], dgr_ref[0, 0, 0] = dg_cols, dg_rows
     dbc_ref[0, 0, 0], dbr_ref[0, 0, 0] = db_cols, db_rows
 
 
+class _Layout(NamedTuple):
+    """What the kernels' callers fix: the heads and their widths, the heads
+    a grid step holds, whether the kernels norm ``q`` and ``k``, and whether
+    ``q``, ``k``, ``v`` are the columns ``[q; k; v]`` of one array."""
+    key_heads: int
+    heads: int
+    d_k: int
+    d_v: int
+    block_h: int
+    qk_norm: bool
+    packed: bool
+
+    @property
+    def rep(self):
+        return self.heads // self.key_heads
+
+    @property
+    def keys(self):
+        return self.key_heads * self.d_k
+
+    @property
+    def values(self):
+        return self.heads * self.d_v
+
+    @property
+    def key_block(self):  # the lanes of a grid step's key heads
+        return self.block_h // self.rep * self.d_k
+
+    @property
+    def value_block(self):
+        return self.block_h * self.d_v
+
+    def in_place(self, width: int) -> bool:
+        """Do ``[q; k; v]`` in ``width`` columns start at whole blocks of a
+        grid step's heads? (``k`` does: a block's key heads divide them.)"""
+        return (width == 2 * self.keys + self.values
+                and 2 * self.keys % self.value_block == 0)
+
+
 def _pallas(kernel, name, operands, in_specs, out_specs, out_shape, state,
-            flops_a_pair):
+            flops_a_pair, packed):
     """One of the rule's kernels over grid (row, block of heads, pair of
-    chunks), with a block's states (``state``: their shape) in scratch."""
+    chunks), with a block's states (``state``: their shape) in scratch;
+    ``packed``: the first three operands are one array."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -633,93 +734,101 @@ def _pallas(kernel, name, operands, in_specs, out_specs, out_shape, state,
             flops=steps * flops_a_pair,
             transcendentals=steps * _PAIR * (CHUNK + 4),
             bytes_accessed=sum(math.prod(t.shape) * t.dtype.itemsize
-                               for t in (*operands, *out_shape))),
+                               for t in (*operands[2 * packed:],
+                                         *out_shape))),
         name=name)(*operands)
 
 
-def _specs(block_h, rep, d_k, d_v, order):
+def _specs(layout, order):
     """The ``BlockSpec`` of each kind of array a kernel takes or gives, for
     grid (row, block of heads, step); ``order`` maps a step to its pair of
-    chunks."""
+    chunks. ``ins`` reads ``q``, ``k``, ``v`` from arrays of their own or
+    (``packed``) from the columns ``[q; k; v]`` of one."""
     from jax.experimental import pallas as pl
 
-    def tokens(width):  # [B, L, H * d]: a pair of a block's heads' lanes
+    def tokens(width, first=0):  # [B, L, .]: a pair of a block's heads' lanes
         return pl.BlockSpec((1, _PAIR, width),
-                            lambda b, i, j: (b, order(j), i))
+                            lambda b, i, j: (b, order(j), first + i))
 
     def scalars(*shape):  # [B, N / 2, G, 2C, bh] or [B, N / 2, G, bh, 2C]
         return pl.BlockSpec((1, 1, 1, *shape),
                             lambda b, i, j: (b, order(j), i, 0, 0))
 
     def matrices(per_pair, *shape):  # [B, Hv, ., ., .]: some a head and pair
-        return pl.BlockSpec((1, block_h, per_pair, *shape),
+        return pl.BlockSpec((1, layout.block_h, per_pair, *shape),
                             lambda b, i, j: (b, i, order(j), 0, 0))
 
-    keys, values = tokens(block_h // rep * d_k), tokens(block_h * d_v)
+    block_h, d_k, d_v = layout.block_h, layout.d_k, layout.d_v
+    keys, values = tokens(layout.key_block), tokens(layout.value_block)
     cols, rows = scalars(_PAIR, block_h), scalars(block_h, _PAIR)
+    read = [keys, keys, values]
+    if layout.packed:
+        read = [keys, tokens(layout.key_block,
+                             layout.keys // layout.key_block),
+                tokens(layout.value_block,
+                       2 * layout.keys // layout.value_block)]
     return dict(
-        ins=[keys, keys, values, cols, rows, cols, rows], keys=keys,
+        ins=read + [cols, rows, cols, rows], keys=keys,
         values=values, cols=cols, rows=rows, states=matrices(2, d_k, d_v),
         inverses=matrices(1, CHUNK, _PAIR),
         last=pl.BlockSpec((1, block_h, d_k, d_v),
                           lambda b, i, j: (b, i, 0, 0)))
 
 
-def _sizes(q, v, g_cols):
-    rows, seq, keys = q.shape
-    pairs, groups, _, block_h = g_cols.shape[1:]
-    heads = groups * block_h
-    return rows, pairs, heads, block_h, keys, v.shape[2] // heads
-
-
-@functools.partial(jax.jit, static_argnums=(7, 8))
-def _rule_forward(q, k, v, g_cols, g_rows, b_cols, b_rows, key_heads, keep):
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _rule_forward(arrays, scalars, layout, keep):
     """``(o [B, L, Hv * d_v], S_last)`` or, for a backward pass (``keep``),
     the state each chunk starts from (in the operands' type: it is only
-    ever an operand again) and each pair of chunks' inverses."""
-    rows, pairs, heads, block_h, keys, d_v = _sizes(q, v, g_cols)
-    d_k, rep = keys // key_heads, heads // key_heads
-    spec = _specs(block_h, rep, d_k, d_v, lambda j: j)
+    ever an operand again) and each pair of chunks' inverses. ``arrays`` is
+    ``(q, k, v)`` flat or, packed, the one array three times."""
+    rows, pairs = scalars[0].shape[:2]
+    heads, block_h, d_k, d_v = (layout.heads, layout.block_h, layout.d_k,
+                                layout.d_v)
+    rep, dtype = layout.rep, arrays[0].dtype
+    spec = _specs(layout, lambda j: j)
     if keep:
         out_specs = [spec["states"], spec["inverses"]]
         out_shape = [
-            jax.ShapeDtypeStruct((rows, heads, 2 * pairs, d_k, d_v), q.dtype),
+            jax.ShapeDtypeStruct((rows, heads, 2 * pairs, d_k, d_v), dtype),
             jax.ShapeDtypeStruct((rows, heads, pairs, CHUNK, _PAIR), _F32)]
     else:
         out_specs = [spec["values"], spec["last"]]
-        out_shape = [jax.ShapeDtypeStruct(v.shape, q.dtype),
-                     jax.ShapeDtypeStruct((rows, heads, d_k, d_v), _F32)]
+        out_shape = [jax.ShapeDtypeStruct(
+            (rows, pairs * _PAIR, layout.values), dtype),
+            jax.ShapeDtypeStruct((rows, heads, d_k, d_v), _F32)]
     return _pallas(
         functools.partial(_fwd_kernel, block_h=block_h, rep=rep, d_k=d_k,
-                          d_v=d_v, keep=keep),
+                          d_v=d_v, qk_norm=layout.qk_norm, keep=keep),
         "delta_rule_fwd_kept" if keep else "delta_rule_fwd",
-        (q, k, v, g_cols, g_rows, b_cols, b_rows), spec["ins"], out_specs,
-        out_shape, (block_h, d_k, d_v),
+        (*arrays, *scalars), spec["ins"], out_specs, out_shape,
+        (block_h, d_k, d_v),
         2 * _PAIR * ((_PAIR * d_k * (1 if keep else 2)) // rep
                      + 10 * 6 * _PAIR * CHUNK + _PAIR * (d_k + d_v)
                      + (2 if keep else 3) * d_k * d_v
-                     + (0 if keep else _PAIR * d_v)))
+                     + (0 if keep else _PAIR * d_v)), layout.packed)
 
 
-@functools.partial(jax.jit, static_argnums=(10,))
-def _rule_backward(q, k, v, g_cols, g_rows, b_cols, b_rows, starts, inverses,
-                   do, key_heads):
-    rows, pairs, heads, block_h, keys, d_v = _sizes(q, v, g_cols)
-    d_k, rep = keys // key_heads, heads // key_heads
-    spec = _specs(block_h, rep, d_k, d_v, lambda j: pairs - 1 - j)
-    grads = (q, k, v, g_cols, g_rows, b_cols, b_rows)
+@functools.partial(jax.jit, static_argnums=(5,))
+def _rule_backward(arrays, scalars, starts, inverses, do, layout):
+    rows, pairs = scalars[0].shape[:2]
+    seq, dtype = pairs * _PAIR, arrays[0].dtype
+    block_h, rep, d_k, d_v = (layout.block_h, layout.rep, layout.d_k,
+                              layout.d_v)
+    spec = _specs(layout, lambda j: pairs - 1 - j)
     return _pallas(
         functools.partial(_bwd_kernel, block_h=block_h, rep=rep, d_k=d_k,
-                          d_v=d_v),
-        "delta_rule_bwd", (*grads, starts, inverses, do),
+                          d_v=d_v, qk_norm=layout.qk_norm),
+        "delta_rule_bwd", (*arrays, *scalars, starts, inverses, do),
         spec["ins"] + [spec["states"], spec["inverses"], spec["values"]],
         [spec["keys"], spec["keys"], spec["values"], spec["cols"],
          spec["rows"], spec["cols"], spec["rows"]],
-        [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in grads],
+        [jax.ShapeDtypeStruct((rows, seq, width), dtype)
+         for width in (layout.keys, layout.keys, layout.values)]
+        + [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in scalars],
         (block_h, d_k, d_v),
         2 * _PAIR * ((6 * _PAIR * d_k) // rep + 2 * 6 * _PAIR * CHUNK
                      + 3 * _PAIR * (d_k + d_v) + 7 * d_k * d_v
-                     + 2 * _PAIR * d_v))
+                     + 2 * _PAIR * d_v), layout.packed)
 
 
 def _scalars(g, beta, block_h):
@@ -745,36 +854,46 @@ def _per_token(cols, by_rows):
                         both.shape[3] * both.shape[4])
 
 
-def _flat(t):  # [B, L, H, d] -> [B, L, H * d]: a head is a group of lanes
-    return t.reshape(*t.shape[:2], -1)
+def _operands(arrays, layout):
+    """``(q, k, v)`` flat (a head is a group of lanes of ``[B, L, H * d]``)
+    or, packed, the one array for each of the three."""
+    return arrays * 3 if layout.packed else tuple(
+        t.reshape(*t.shape[:2], -1) for t in arrays)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _rule(q, k, v, g, beta, block_h):
-    o, last = _rule_forward(_flat(q), _flat(k), _flat(v),
-                            *_scalars(g, beta, block_h), q.shape[2], False)
-    return o.reshape(v.shape), last
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rule(arrays, g, beta, layout):
+    """``arrays``: ``(q, k, v)`` ``[B, L, H, d]`` or, packed, ``(qkv,)``
+    ``[B, L, 2 keys + values]``. ``(o [B, L, Hv * d_v], S_last)``."""
+    return _rule_forward(_operands(arrays, layout),
+                         _scalars(g, beta, layout.block_h), layout, False)
 
 
-def _rule_fwd(q, k, v, g, beta, block_h):
+def _rule_fwd(arrays, g, beta, layout):
     # nothing kept but the inputs: the layer makes its forward again in the
     # backward pass whatever is kept here, and a custom call's outputs are
     # written whether or not anything reads them
-    return _rule(q, k, v, g, beta, block_h), (q, k, v, g, beta)
+    return _rule(arrays, g, beta, layout), (arrays, g, beta)
 
 
-def _rule_bwd(block_h, residuals, cotangents):
-    q, k, v, g, beta = residuals
+def _rule_bwd(layout, residuals, cotangents):
+    arrays, g, beta = residuals
     do, _ = cotangents  # the state at a row's end takes no gradient
-    flat = (_flat(q), _flat(k), _flat(v), *_scalars(g, beta, block_h))
-    starts, inverses = _rule_forward(*flat, q.shape[2], True)
+    operands = _operands(arrays, layout)
+    scalars = _scalars(g, beta, layout.block_h)
+    starts, inverses = _rule_forward(operands, scalars, layout, True)
+    do = do.astype(arrays[0].dtype)
     dq, dk, dv, dg_cols, dg_rows, db_cols, db_rows = _rule_backward(
-        *flat, starts, inverses, _flat(do.astype(q.dtype)), q.shape[2])
+        operands, scalars, starts, inverses, do, layout)
     # gamma is the running sum of g inside a chunk: g_t reaches every gamma
     # from t to the chunk's end
     dg = jax.lax.cumsum(_per_token(dg_cols, dg_rows), axis=2, reverse=True)
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
-            dg.reshape(g.shape).astype(g.dtype),
+    if layout.packed:  # the one pass over the three that is left in XLA
+        grads = (jnp.concatenate([dq, dk, dv], -1),)
+    else:
+        grads = tuple(d.reshape(t.shape) for d, t in zip((dq, dk, dv),
+                                                         arrays))
+    return (grads, dg.reshape(g.shape).astype(g.dtype),
             _per_token(db_cols, db_rows).reshape(g.shape).astype(beta.dtype))
 
 
@@ -789,21 +908,65 @@ def _block_h(heads: int, block_h: int, rep: int) -> int:
                if heads % b == 0)
 
 
-def delta_kernel(q, k, v, g, beta, *, block_h: int = BLOCK_H):
-    """The rule in Pallas kernels, preparation and recurrence both (``L``
-    whole pairs of chunks of ``CHUNK``, ``d_k`` and ``d_v`` whole 128-lane
-    groups); differentiable, by its own rule."""
-    _check(q, k, v, g, beta)
-    seq, key_heads, d_k = q.shape[1:]
-    heads, d_v = v.shape[2:]
+def _refuse_ragged(seq, d_k, d_v):
     if seq % _PAIR or d_k % _LANES or d_v % _LANES:
         raise ValueError(
             f"the delta-rule kernel takes rows of whole chunks of {CHUNK}, "
             f"two at a time, and heads in whole groups of {_LANES} lanes; "
             f"got {seq} tokens and heads of {d_k} and {d_v}")
-    o, last = _rule(q, k, v, g, beta,
-                    _block_h(heads, block_h, heads // key_heads))
-    return o, jax.lax.stop_gradient(last)
+
+
+def delta_kernel(q, k, v, g, beta, *, block_h: int = BLOCK_H,
+                 qk_norm: bool = False):
+    """The rule in Pallas kernels, preparation and recurrence both (``L``
+    whole pairs of chunks of ``CHUNK``, ``d_k`` and ``d_v`` whole 128-lane
+    groups) and, under ``qk_norm``, the norm of ``q`` and ``k`` ahead of
+    them; differentiable, by its own rule."""
+    _check(q, k, v, g, beta)
+    seq, key_heads, d_k = q.shape[1:]
+    heads, d_v = v.shape[2:]
+    _refuse_ragged(seq, d_k, d_v)
+    layout = _Layout(key_heads, heads, d_k, d_v,
+                     _block_h(heads, block_h, heads // key_heads),
+                     bool(qk_norm), False)
+    o, last = _rule((q, k, v), g, beta, layout)
+    return o.reshape(v.shape), jax.lax.stop_gradient(last)
+
+
+def delta_kernel_packed(qkv, g, beta, *, key_heads: int, key_dim: int,
+                        block_h: int = BLOCK_H, qk_norm: bool = False):
+    """:func:`delta_kernel` on ``qkv`` ``[B, L, 2 keys + values]``, the
+    columns ``[q; k; v]`` of one array, read where they lie (each of the
+    three starting at a whole block of a grid step's heads; else sliced
+    out, as anywhere the kernels refuse)."""
+    rows, seq, width = qkv.shape
+    heads, keys = g.shape[2], key_heads * key_dim
+    d_v = (width - 2 * keys) // heads
+    _refuse_ragged(seq, key_dim, d_v)
+    layout = _Layout(key_heads, heads, key_dim, d_v,
+                     _block_h(heads, block_h, heads // key_heads),
+                     bool(qk_norm), False)
+    if layout.in_place(width):
+        arrays, layout = (qkv,), layout._replace(packed=True)
+    else:
+        arrays = _columns(qkv, key_heads, key_dim, heads)
+    o, last = _rule(arrays, g, beta, layout)
+    return o.reshape(rows, seq, heads, d_v), jax.lax.stop_gradient(last)
+
+
+def _columns(qkv, key_heads, key_dim, heads, normed=None):
+    """``q``, ``k`` ``[B, L, Hk, d_k]`` and ``v`` ``[B, L, Hv, d_v]`` sliced
+    out of ``qkv`` ``[B, L, 2 keys + values]``, each of the first two
+    through ``normed`` before the next is sliced (the order a layer wrote
+    them in before the rule took its projection whole)."""
+    rows, seq, width = qkv.shape
+    keys = key_heads * key_dim
+    q_k = []
+    for i in range(2):
+        t = qkv[..., i * keys:(i + 1) * keys].reshape(rows, seq, key_heads,
+                                                      key_dim)
+        q_k.append(normed(t, i) if normed else t)
+    return (*q_k, qkv[..., 2 * keys:].reshape(rows, seq, heads, -1))
 
 
 def delta_fused_applies(seq: int, heads: int, d_k: int, d_v: int, mesh=None,
@@ -824,21 +987,24 @@ def delta_fused_applies(seq: int, heads: int, d_k: int, d_v: int, mesh=None,
     return jax.device_count() == 1
 
 
-def gated_delta_rule(q, k, v, g, beta):
+def gated_delta_rule(q, k, v, g, beta, *, qk_norm: bool = False):
     """``(o, S_last)`` by the kernels where :func:`delta_fused_applies` says
     so for these shapes: every head at once, nothing of a chunk's
-    preparation outliving its grid step. Elsewhere by the plain chunked
-    form, whose preparation XLA keeps in float32 (the decay mask, ``A``, its
-    inverse and every step of the inversion, each ``[Hv, L, C]``: 3.5 GiB a
-    row for 32 heads of 128 at 8,192 tokens if every head's is alive at
-    once), so there a row's heads go ``GROUP_H`` value heads at a time, one
-    group after the other, and a group's forward is made again in the
-    backward pass."""
+    preparation outliving its grid step, and under ``qk_norm`` the norm of
+    ``q`` and ``k`` inside them. Elsewhere by the plain chunked form (the
+    norm ahead of it in ``jax.numpy``), whose preparation XLA keeps in
+    float32 (the decay mask, ``A``, its inverse and every step of the
+    inversion, each ``[Hv, L, C]``: 3.5 GiB a row for 32 heads of 128 at
+    8,192 tokens if every head's is alive at once), so there a row's heads
+    go ``GROUP_H`` value heads at a time, one group after the other, and a
+    group's forward is made again in the backward pass."""
     _check(q, k, v, g, beta)
     rows, seq, key_heads, d_k = q.shape
     heads, d_v = v.shape[2:]
     if delta_fused_applies(seq, heads, d_k, d_v):
-        return delta_kernel(q, k, v, g, beta)
+        return delta_kernel(q, k, v, g, beta, qk_norm=qk_norm)
+    if qk_norm:
+        q, k = _normed(q, 0), _normed(k, 1)
     form = jax.checkpoint(delta_chunked)
     rep = heads // key_heads
     if heads <= GROUP_H or heads % GROUP_H or GROUP_H % rep:
@@ -855,3 +1021,21 @@ def gated_delta_rule(q, k, v, g, beta):
     o = jnp.moveaxis(o.reshape(rows, heads // GROUP_H, seq, GROUP_H, d_v),
                      1, 2).reshape(rows, seq, heads, d_v)
     return o, last.reshape(rows, heads, d_k, d_v)
+
+
+def gated_delta_rule_packed(qkv, g, beta, *, key_heads: int, key_dim: int,
+                            qk_norm: bool = False):
+    """:func:`gated_delta_rule` for a layer that holds ``q``, ``k`` and
+    ``v`` as the columns ``[q; k; v]`` of one array ``[B, L, 2 keys +
+    values]`` (its convolved projection). Where the kernels run they read
+    the three where they lie; elsewhere the slices, the plain norm and the
+    plain rule, in the order a layer wrote them before this existed."""
+    rows, seq, width = qkv.shape
+    heads = g.shape[2]
+    d_v = (width - 2 * key_heads * key_dim) // heads
+    if delta_fused_applies(seq, heads, key_dim, d_v):
+        return delta_kernel_packed(qkv, g, beta, key_heads=key_heads,
+                                   key_dim=key_dim, qk_norm=qk_norm)
+    q, k, v = _columns(qkv, key_heads, key_dim, heads,
+                       _normed if qk_norm else None)
+    return gated_delta_rule(q, k, v, g, beta)

@@ -374,9 +374,9 @@ def _kernel_paths(task: Task, config: TrainConfig) -> dict:
     """The first log line's word for the form each of the model's kernels
     runs at ``seq_len``, by the kernel's name (``Task.kernels``: the mixers'
     own answer, which asks the test each call makes): ``attention=``,
-    ``scan=``, ``delta=``, ``conv=``."""
+    ``scan=``, ``delta=``, ``conv=``, ``norm=``."""
     plain = {"attention": "ring" if config.seq_parallelism > 1 else "dense",
-             "conv": "plain"}
+             "conv": "plain", "norm": "plain"}
     return {name: "fused kernel" if fused else plain.get(name, "chunked")
             for name, fused in sorted(task.kernels.items())}
 

@@ -408,6 +408,71 @@ def test_the_delta_rules_kernel_compiles_for_a_v5e_at_qwen3_nexts_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 7 << 27
 
 
+def test_the_delta_rule_norms_in_its_kernels_for_a_v5e_at_qwen3_nexts_widths(
+        one_chip):
+    """The rule as the Qwen3-Next cell calls it since PR 48: ``q``, ``k``
+    and ``v`` read off the convolved projection ``[2, 8192, 8192]`` where
+    they lie, the unit norms of ``q`` and ``k`` made by the three kernels.
+    No slice of the projection is written out in front of them, nothing of
+    ``[8192, 2048]`` exists in f32 (the plain norm kept both of ``q`` and
+    ``k`` so), and beside what the kernels keep for the backward pass the
+    program holds the three cotangents and their concatenation."""
+    from lance_distributed_training_tpu.ops import delta
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(qkv, g, beta):
+        o, last = delta.delta_kernel_packed(qkv, g, beta, key_heads=16,
+                                            key_dim=128, qk_norm=True)
+        assert o.shape == (2, 8192, 32, 128) and o.dtype == jnp.bfloat16
+        return o.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=range(3))).lower(
+        spec(2, 8192, 8192), spec(2, 8192, 32, dtype=jnp.float32),
+        spec(2, 8192, 32, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert all(line.count("bf16[2,8192,8192]{2,1,0}") == 3 for line in calls)
+    assert not re.search(r"bf16\[2,8192,\d+\]\S* slice\(", text)
+    assert not re.search(r"f32\[2,8192,(2048|16,128)\]", text)
+    assert "rsqrt" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 7 << 27
+
+
+def test_the_gated_norms_kernels_compile_for_a_v5e_at_qwen3_nexts_widths(
+        one_chip):
+    """The gated RMSNorm as the Qwen3-Next cell calls it, forward and
+    backward: two kernels, the gate read from the last 4,096 of the fused
+    projection's 12,288 columns where they lie, nothing of ``[8192, 4096]``
+    kept in f32 (the plain form held ``o``, its norm and the gate so)."""
+    from lance_distributed_training_tpu.ops import norm
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(o, z, scale, ct):
+        y = norm.norm_kernel(o.reshape(2, 8192, 32, 128), z, scale)
+        assert y.shape == (2, 8192, 4096) and y.dtype == jnp.bfloat16
+        return (y * ct).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec(2, 8192, 4096), spec(2, 8192, 12288),
+        spec(128, dtype=jnp.float32), spec(2, 8192, 4096)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert all("bf16[2,8192,12288]" in line for line in calls)
+    assert not re.search(r"bf16\[2,8192,\d+\]\S* slice\(", text)
+    # the output, its cotangent's product, do and dz before the unread
+    # columns' zeros, each bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5 * (
+        2 * 8192 * 4096 * 2)
+
+
 @pytest.mark.parametrize("rows,wide,width,has_bias", [
     (2, 12288, 8192, False),  # c4-qwen3next-ep16-prepacked-8k's projection
     (1, 10240, 5120, True),  # c4-phi4flash-vp8-prepacked-8k's

@@ -26,13 +26,13 @@ import pytest
 
 from lance_distributed_training_tpu import trainer
 from lance_distributed_training_tpu.models import get_task, transformer
-from lance_distributed_training_tpu.ops import conv, delta, flash, scan
+from lance_distributed_training_tpu.ops import conv, delta, flash, norm, scan
 
 ATTENTION = {"attention"}
 SAMBAY = {"attention", "scan", "conv"}
-QWEN3_NEXT = {"attention", "delta", "conv"}
+QWEN3_NEXT = {"attention", "delta", "conv", "norm"}
 PLAIN = {"attention": "dense", "scan": "chunked", "delta": "chunked",
-         "conv": "plain"}
+         "conv": "plain", "norm": "plain"}
 KERNELS = {
     ("gpt_base", None): ATTENTION, ("gpt_small", None): ATTENTION,
     ("olmoe_1b_7b", None): ATTENTION, ("olmoe_tiny", None): ATTENTION,
@@ -68,7 +68,8 @@ def test_a_preset_answers_with_its_kernels_and_the_first_line_says_them(
     for op, rule in ((flash, "fused_attention_applies"),
                      (scan, "scan_fused_applies"),
                      (delta, "delta_fused_applies"),
-                     (conv, "conv_fused_applies")):
+                     (conv, "conv_fused_applies"),
+                     (norm, "norm_fused_applies")):
         monkeypatch.setattr(op, rule, lambda *a, **k: True)
     task = get_task("causal_lm", model_name=model, seq_len=8192,
                     layer_span=span)

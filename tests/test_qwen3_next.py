@@ -42,7 +42,7 @@ from lance_distributed_training_tpu.models.transformer import (
     causal_depthwise_conv,
     rotary_embedding,
 )
-from lance_distributed_training_tpu.ops import delta
+from lance_distributed_training_tpu.ops import conv, delta, norm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ, ROWS, VOCAB, EXPERTS, TOP_K = 96, 2, 512, 64, 4  # 96: a chunk and a half
@@ -284,6 +284,7 @@ def test_a_training_step_reports_its_gauges(bf16_task, variables, batch,
             "shared_gate_mean", "moe_assignments_total"} <= set(stats)
     assert stats["delta_fused"] == 0  # the CPU: the plain chunked form
     assert stats["conv_fused"] == 0  # and the convolution's plain form
+    assert "norm_fused" not in stats  # the first log line's word alone
     assert stats["moe_assignments_total"] == 4 * ROWS * SEQ * TOP_K
     assert 0 < stats["delta_decay_min"] < 0.5  # some head forgets fast
     assert stats["delta_state_abs_max"] > 1e-3
@@ -298,12 +299,37 @@ def test_the_first_log_line_names_the_delta_path():
     config = trainer.TrainConfig(
         dataset_path="", task_type="causal_lm",
         model_name="qwen3_next_tiny", seq_len=SEQ)
-    assert trainer._kernel_paths(_task(None), config)["delta"] == "chunked"
+    paths = trainer._kernel_paths(_task(None), config)
+    assert paths["delta"] == "chunked" and paths["norm"] == "plain"
     config = trainer.TrainConfig(
         dataset_path="", task_type="causal_lm", model_name="olmoe_tiny",
         seq_len=SEQ)
     olmoe = get_task("causal_lm", model_name="olmoe_tiny", seq_len=SEQ)
-    assert "delta" not in trainer._kernel_paths(olmoe, config)
+    assert not {"delta", "norm"} & set(trainer._kernel_paths(olmoe, config))
+
+
+@pytest.mark.parametrize("backend,devices,says", [
+    ("tpu", 1, "fused kernel"), ("tpu", 4, "plain"), ("cpu", 1, "plain")])
+def test_the_first_log_line_names_the_norms_path(monkeypatch, backend,
+                                                 devices, says):
+    """The cell's span at the cell's row on a described one-device TPU runs
+    the gated norm's kernels beside the rule's (which hold the unit norms
+    and have no word of their own) and the convolution's; several devices
+    or another platform, the plain lines."""
+    from lance_distributed_training_tpu import trainer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda *a: devices)
+    config = trainer.TrainConfig(
+        dataset_path="", task_type="causal_lm",
+        model_name="qwen3_next_80b_a3b", seq_len=8192)
+    task = get_task("causal_lm", model_name="qwen3_next_80b_a3b",
+                    seq_len=8192, layer_span="0:4", expert_share="0/16",
+                    attention_fn=lambda *a, **k: None)
+    paths = trainer._kernel_paths(task, config)
+    assert paths["norm"] == paths["conv"] == says
+    assert paths["delta"] == ("fused kernel" if says != "plain"
+                              else "chunked")
 
 
 # -- the share ---------------------------------------------------------------
@@ -604,6 +630,191 @@ def test_the_kernel_in_interpret_mode_is_the_chunked_form(case):
         assert _relative(got, want) < 1e-4, name
 
 
+def _raw_rule_inputs(seq, key_heads, heads, rows=1, seed=0, d=128):
+    """``[q; k; v]`` as a layer's convolved projection holds them (raw: the
+    queries some four times the keys' length, nothing a unit vector), and
+    ``g`` and ``beta`` as :func:`_rule_inputs` makes them."""
+    _, _, v, g, beta = _rule_inputs(seq, key_heads, heads, d, rows, seed)
+    q, k = jax.random.normal(jax.random.key(seed + 100),
+                             (2, rows, seq, key_heads * d))
+    return jnp.concatenate([2.0 * q, 0.5 * k, v.reshape(rows, seq, -1)],
+                           -1), g, beta
+
+
+def _plain_norm_then_chunked(key_heads, d=128):
+    """``unit`` ahead of ``delta_chunked``: the layer's lines off the chip,
+    on ``[q; k; v]``."""
+    def form(qkv, g, beta):
+        q, k, v = delta._columns(qkv, key_heads, d, g.shape[2])
+        q = delta.unit_heads(q) * d ** -0.5
+        return delta.delta_chunked(q, delta.unit_heads(k), v, g, beta)
+    return form
+
+
+NORM_CASES = {
+    # name: (_raw_rule_inputs' arguments, heads a grid step, in place?)
+    "16_key_heads_serving_32_value_heads": (
+        dict(seq=128, key_heads=16, heads=32), 8, True),
+    "a_value_head_a_key_head_two_rows": (
+        dict(seq=256, key_heads=2, heads=2, rows=2, seed=6), 8, True),
+    # a block of four value heads is 512 columns and v starts at 256
+    "v_off_a_whole_block_is_sliced_out": (
+        dict(seq=128, key_heads=1, heads=4, seed=7), 4, False),
+}
+
+
+@pytest.fixture(scope="module", params=NORM_CASES)
+def norm_case(request):
+    """The rule's kernels with the norm inside, reading ``[q; k; v]`` where
+    they lie, against the plain norm and the chunked form in f32: ``(o,
+    S_last)`` and the gradients of ``q``, ``k``, ``v`` (the three column
+    ranges of ``d qkv``), ``g`` and ``beta``, each way."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    shapes, block_h, in_place = NORM_CASES[request.param]
+    key_heads = shapes["key_heads"]
+    args = _raw_rule_inputs(**shapes)
+    ct = jax.random.normal(jax.random.key(9), (
+        *args[1].shape, 128))
+
+    def both(fn):
+        def run(*a):
+            o, last = fn(*a)
+            return (o * ct).sum(), (o, last)
+        return jax.jit(jax.value_and_grad(run, argnums=range(3),
+                                          has_aux=True))
+
+    kernel = both(functools.partial(
+        delta.delta_kernel_packed, key_heads=key_heads, key_dim=128,
+        block_h=block_h, qk_norm=True))
+    text = str(kernel.trace(*args).jaxpr)
+    with jax.default_matmul_precision("highest"):
+        (_, want), g_want = both(_plain_norm_then_chunked(key_heads))(*args)
+        with pltpu.force_tpu_interpret_mode():
+            (_, got), g_got = jax.block_until_ready(kernel(*args))
+    keys = key_heads * 128
+
+    def five(grads):  # d qkv by its column ranges, then dg and d beta
+        return (grads[0][..., :keys], grads[0][..., keys:2 * keys],
+                grads[0][..., 2 * keys:], *grads[1:])
+    return got, want, five(g_got), five(g_want), text, in_place
+
+
+def test_the_kernels_norm_q_and_k_as_they_load_them(norm_case):
+    (o_got, s_got), (o_want, s_want), _, _, text, in_place = norm_case
+    assert bool(jnp.isfinite(o_got).all())
+    np.testing.assert_allclose(o_got, o_want, atol=1e-5)
+    np.testing.assert_allclose(s_got, s_want, atol=1e-5)
+    # in place: no slice of the projection ahead of the kernels' call
+    assert ("slice" not in text.split("pallas_call")[0]) is in_place
+
+
+@pytest.mark.parametrize("which", range(5), ids=RULE_INPUTS)
+def test_the_kernels_turn_the_cotangents_back_through_the_norm(which,
+                                                               norm_case):
+    got, want = norm_case[2][which], norm_case[3][which]
+    assert got.shape == want.shape and bool(jnp.isfinite(got).all())
+    assert _relative(got, want) < 1e-4
+
+
+def test_arrays_of_their_own_are_normed_in_the_kernels_too():
+    """``delta_kernel(q, k, v, .., qk_norm=True)`` on three arrays is the
+    packed call on their concatenation, and ``gated_delta_rule`` hands the
+    word through."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    qkv, g, beta = _raw_rule_inputs(seq=128, key_heads=1, heads=2, seed=8)
+    q, k, v = delta._columns(qkv, 1, 128, 2)
+
+    def loss(fn, *a):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (fn(*a)[0] ** 2).sum(), argnums=range(len(a))))(*a)
+
+    with jax.default_matmul_precision("highest"), \
+            pltpu.force_tpu_interpret_mode(), \
+            pytest.MonkeyPatch.context() as patch:
+        packed, g_packed = loss(functools.partial(
+            delta.delta_kernel_packed, key_heads=1, key_dim=128,
+            qk_norm=True), qkv, g, beta)
+        patch.setattr(delta, "delta_fused_applies", lambda *a, **k: True)
+        apart, g_apart = loss(functools.partial(
+            delta.gated_delta_rule, qk_norm=True), q, k, v, g, beta)
+    assert float(packed) == float(apart)
+    np.testing.assert_array_equal(
+        jnp.concatenate([t.reshape(1, 128, -1) for t in g_apart[:3]], -1),
+        g_packed[0])
+    for a, b in zip(g_apart[3:], g_packed[1:]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_kernels_round_the_normed_rows_where_the_plain_norm_does():
+    """bf16 operands: the kernels' normed ``q`` and ``k`` are the plain
+    norm's bit for bit (f32 statistics, one rounding), so ``o`` and the
+    state are those of the kernels fed the plain norm's rows, and so are the
+    gradients that do not pass the norm; ``d qkv``'s first two ranges pass
+    it in f32 here and in bf16 there, a rounding apart."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    qkv, g, beta = _raw_rule_inputs(seq=128, key_heads=1, heads=2, seed=12)
+    qkv = qkv.astype(jnp.bfloat16)
+    ct = jax.random.normal(jax.random.key(9), (1, 128, 2, 128))
+
+    def both(fn):
+        def run(*a):
+            o, last = fn(*a)
+            return (o.astype(jnp.float32) * ct).sum(), (o, last)
+        return jax.jit(jax.value_and_grad(run, argnums=range(3),
+                                          has_aux=True))(qkv, g, beta)
+
+    def plain_norm(qkv, g, beta):
+        return delta.delta_kernel(
+            *delta._columns(qkv, 1, 128, 2, delta._normed), g, beta)
+
+    with pltpu.force_tpu_interpret_mode():
+        (_, (o_want, s_want)), g_want = both(plain_norm)
+        (_, (o_got, s_got)), g_got = both(functools.partial(
+            delta.delta_kernel_packed, key_heads=1, key_dim=128,
+            qk_norm=True))
+    assert o_got.dtype == g_got[0].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(o_got, o_want)
+    np.testing.assert_array_equal(s_got, s_want)
+    np.testing.assert_array_equal(g_got[0][..., 256:], g_want[0][..., 256:])
+    for got, want in zip(g_got[1:], g_want[1:]):
+        np.testing.assert_array_equal(got, want)
+    assert _relative(g_got[0].astype(jnp.float32),
+                     g_want[0].astype(jnp.float32)) < 2 ** -7
+
+
+def test_off_the_chip_the_packed_rule_is_the_layers_lines_to_the_letter():
+    """``gated_delta_rule_packed`` here, on the CPU, lowers to what the
+    layer wrote before it: each of q and k sliced, normed and rounded before
+    the next, the slice of v, the chunked rule."""
+    qkv, g, beta = _raw_rule_inputs(seq=96, key_heads=2, heads=4, rows=2,
+                                    d=16)
+    qkv = qkv.astype(jnp.bfloat16)
+
+    def before(qkv, g, beta):
+        b, s, _ = qkv.shape
+
+        def unit(t):
+            t = t.reshape(b, s, 2, 16).astype(jnp.float32)
+            return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+        q = (unit(qkv[..., :32]) * 16 ** -0.5).astype(jnp.bfloat16)
+        k = unit(qkv[..., 32:64]).astype(jnp.bfloat16)
+        v = qkv[..., 64:].reshape(b, s, 4, 16)
+        return delta.gated_delta_rule(q, k, v, g, beta)
+
+    def now(qkv, g, beta):
+        return delta.gated_delta_rule_packed(qkv, g, beta, key_heads=2,
+                                             key_dim=16, qk_norm=True)
+
+    def text(fn):
+        return jax.jit(fn).lower(qkv, g, beta).as_text().replace(
+            fn.__name__, "f")
+    assert text(now) == text(before)
+
+
 def test_the_kernel_is_for_a_tpu_and_whole_chunks_of_whole_lane_groups(
         monkeypatch):
     monkeypatch.setattr(jax, "device_count", lambda *a: 1)
@@ -618,6 +829,201 @@ def test_the_kernel_is_for_a_tpu_and_whole_chunks_of_whole_lane_groups(
         delta.delta_kernel(*_rule_inputs(100, d=128))
     with pytest.raises(ValueError, match="Hk dividing Hv"):
         delta.gated_delta_rule(*_rule_inputs(64, key_heads=3, heads=4))
+
+
+# -- ops/norm.py: the gated RMSNorm ------------------------------------------
+
+
+def _norm_inputs(rows=2, seq=256, heads=4, d=128, ahead=256, seed=0,
+                 dtype=jnp.float32):
+    """The rule's output, a projection whose last ``heads * d`` columns are
+    the gate behind ``ahead`` others, a scale near 1 and a cotangent."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    o = 3.0 * jax.random.normal(ks[0], (rows, seq, heads, d))
+    z = jax.random.normal(ks[1], (rows, seq, ahead + heads * d))
+    scale = 1.0 + 0.1 * jax.random.normal(ks[2], (d,))
+    ct = jax.random.normal(ks[3], (rows, seq, heads * d))
+    return (o.astype(dtype), z.astype(dtype), scale), ct.astype(dtype)
+
+
+def _the_layers_norm(o, z, scale, eps=1e-6, dtype=jnp.float32):
+    """The lines ``GatedDeltaNet`` had under ``gdn.norm``."""
+    b, s, hv, dv = o.shape
+    o = o.astype(jnp.float32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) * scale
+    z = z[..., z.shape[2] - hv * dv:].reshape(b, s, hv, dv)
+    return (o * jax.nn.silu(z.astype(jnp.float32))).astype(dtype).reshape(
+        b, s, hv * dv)
+
+
+def _norm_both(form, args, ct):
+    def loss(*a):
+        y = form(*a)
+        return (y.astype(jnp.float32) * ct).sum(), y
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=range(3), has_aux=True))(*args)
+    return y, grads
+
+
+NORM_TILES = {
+    # name: (_norm_inputs' arguments, block_s, block_d, step_rows)
+    "two_blocks_each_way": (dict(), 128, 256, 64),
+    "the_gate_alone": (dict(ahead=0), 256, 512, 32),
+    "a_gate_behind_one_head": (dict(ahead=128, heads=3, seq=128), 64, 1024, 8),
+    "heads_of_two_lane_groups": (dict(d=256, heads=2, ahead=512), 128, 256,
+                                 64),
+}
+
+
+@pytest.mark.parametrize("case", NORM_TILES)
+def test_the_gated_norms_kernels_in_interpret_mode_are_the_layers_lines(case):
+    """Values and the gradients of ``o``, ``z`` (zero ahead of the gate's
+    columns) and ``scale``, the gate read from the last columns of a wider
+    array."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    shapes, block_s, block_d, step_rows = NORM_TILES[case]
+    args, ct = _norm_inputs(**shapes)
+    y_want, g_want = _norm_both(_the_layers_norm, args, ct)
+    with pltpu.force_tpu_interpret_mode():
+        y_got, g_got = jax.block_until_ready(_norm_both(functools.partial(
+            norm.norm_kernel, block_s=block_s, block_d=block_d,
+            step_rows=step_rows), args, ct))
+    np.testing.assert_allclose(y_got, y_want, rtol=1e-5, atol=1e-5)
+    ahead = shapes.get("ahead", 256)
+    assert float(jnp.abs(g_got[1][..., :ahead]).sum()) == 0
+    for name, got, want in zip(("o", "z", "scale"), g_got, g_want):
+        assert got.shape == want.shape, name
+        assert _relative(got, want) < 1e-5, name
+
+
+def test_the_gated_norm_casts_a_bf16_row_once_each_way():
+    """bf16 operands: f32 inside, one rounding of the output and of each
+    cotangent, as the plain lines round."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    args, ct = _norm_inputs(rows=1, seq=128, dtype=jnp.bfloat16)
+    args = (*args[:2], args[2].astype(jnp.float32))
+    form = functools.partial(_the_layers_norm, dtype=jnp.bfloat16)
+    y_want, g_want = _norm_both(form, args, ct)
+    with pltpu.force_tpu_interpret_mode():
+        y_got, g_got = jax.block_until_ready(_norm_both(functools.partial(
+            norm.norm_kernel, block_s=128, block_d=256), args, ct))
+    assert y_got.dtype == g_got[0].dtype == g_got[1].dtype == jnp.bfloat16
+    assert g_got[2].dtype == jnp.float32
+    # the same f32 values rounded once: a unit in the last place at most
+    np.testing.assert_allclose(y_got.astype(jnp.float32),
+                               y_want.astype(jnp.float32), rtol=2 ** -7)
+    for got, want in zip(g_got, g_want):
+        assert _relative(got, want) < 2 ** -7
+
+
+def test_off_the_chip_the_gated_norm_is_the_layers_lines_to_the_letter():
+    args, _ = _norm_inputs(heads=4, d=16, ahead=96, seq=32,
+                           dtype=jnp.bfloat16)
+
+    def before(o, z, scale):
+        return _the_layers_norm(o, z, scale, 1e-6, jnp.bfloat16)
+
+    def now(o, z, scale):
+        return norm.gated_rms_norm(o, z, scale, eps=1e-6, dtype=jnp.bfloat16)
+
+    def text(fn):
+        return jax.jit(fn).lower(*args).as_text().replace(fn.__name__, "f")
+    assert text(now) == text(before)
+
+
+def test_the_gated_norms_rule(monkeypatch):
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    applies = norm.norm_fused_applies
+    assert applies(8192, 32, 128, platform="tpu")  # the cell's
+    assert applies(8192, 6, 256, platform="tpu")
+    assert not applies(8192, 32, 128, platform="cpu")
+    assert not applies(8192, 32, 128)  # here: the CPU
+    assert not applies(8192 + 64, 32, 128, platform="tpu")  # a ragged row
+    assert not applies(8192, 4, 16, platform="tpu")  # qwen3_next_tiny's
+    assert not applies(8192, 32, 192, platform="tpu")
+    mesh = jax.make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    assert applies(8192, 32, 128, mesh=mesh, platform="tpu")
+
+    class MeshOfTwo:
+        size = 2
+    assert not applies(8192, 32, 128, mesh=MeshOfTwo(), platform="tpu")
+    monkeypatch.setattr(jax, "device_count", lambda *a: 4)
+    assert not applies(8192, 32, 128, platform="tpu")
+    args, _ = _norm_inputs(seq=100)
+    with pytest.raises(ValueError, match="whole tiles of 8"):
+        norm.norm_kernel(*args)
+    args, _ = _norm_inputs(d=96)
+    with pytest.raises(ValueError, match="whole groups of 128"):
+        norm.norm_kernel(*args)
+
+
+def test_a_gate_off_a_whole_head_is_sliced_in_front_of_the_kernel():
+    """64 columns ahead of the gate (no block of whole heads walks them in
+    place): the op hands the kernel the slice, and ``dz`` comes back as
+    wide as ``z``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    args, ct = _norm_inputs(rows=1, seq=128, ahead=64)
+    y_want, g_want = _norm_both(_the_layers_norm, args, ct)
+    with pytest.MonkeyPatch.context() as patch, \
+            pltpu.force_tpu_interpret_mode():
+        patch.setattr(norm, "norm_fused_applies", lambda *a, **k: True)
+        y_got, g_got = jax.block_until_ready(_norm_both(
+            norm.gated_rms_norm, args, ct))
+    np.testing.assert_allclose(y_got, y_want, rtol=1e-5, atol=1e-5)
+    assert g_got[1].shape == args[1].shape
+    for got, want in zip(g_got, g_want):
+        assert _relative(got, want) < 1e-5
+
+
+# -- the layer with every kernel bound, as the chip binds them ----------------
+
+
+def test_the_mixer_with_its_kernels_bound_is_its_plain_self():
+    """Forward, the recomputed forward and the backward pass through a
+    Gated DeltaNet of two key heads serving four value heads of 128 with the
+    rule's, the convolution's and the gated norm's kernels bound: the rule
+    reads ``q``, ``k``, ``v`` off the convolved projection in place and
+    norms inside, the norm reads ``z`` off the fused one. (The kernels by
+    ``interpret=True``: the layer runs them under ``jax.checkpoint``, which
+    cannot take the callbacks of the TPU interpreter.)"""
+    from jax.experimental import pallas as pl
+
+    mixer = GatedDeltaNet(2, 4, 128, 128, 4, dtype=jnp.float32)
+    u = jax.random.normal(jax.random.key(1), (2, 128, 64))
+    variables = mixer.init(jax.random.key(2), u)
+
+    def program():  # a function of its own a trace: jit keeps traces by it
+        def loss(v, u):
+            out = mixer.apply(v, u, mutable=["mixer_stats"])[0]
+            return (out * jnp.cos(jnp.arange(out.shape[-1]))).sum(), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))
+
+    with jax.default_matmul_precision("highest"):
+        (_, want), g_want = program()(variables, u)
+        with pytest.MonkeyPatch.context() as patch:
+            for op, rule in ((conv, "conv_fused_applies"),
+                             (delta, "delta_fused_applies"),
+                             (norm, "norm_fused_applies")):
+                patch.setattr(op, rule, lambda *a, **k: True)
+            patch.setattr(pl, "pallas_call", functools.partial(
+                pl.pallas_call, interpret=True))
+            traced = program().trace(variables, u)
+            (_, got), g_got = jax.block_until_ready(
+                traced.lower().compile()(variables, u))
+    text = str(traced.jaxpr)
+    for call in ("_conv_forward", "_rule_forward", "_rule_backward",
+                 "_norm_forward", "_norm_backward"):
+        assert call in text, call
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    flat_got, _ = jax.tree_util.tree_flatten_with_path(g_got)
+    for (path, a), b in zip(flat_got, jax.tree_util.tree_leaves(g_want)):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-3, atol=1e-4 * float(jnp.abs(b).max()) + 1e-7,
+            err_msg=jax.tree_util.keystr(path))
 
 
 # -- causality ---------------------------------------------------------------
@@ -659,6 +1065,22 @@ def test_the_rule_and_the_convolution_look_back_only():
     np.testing.assert_allclose(y[:, 0], taps[3] * x[:, 0], rtol=1e-5)
     np.testing.assert_allclose(
         causal_depthwise_conv(x, taps, bias=jnp.ones((8,))), y + 1)
+    # the gated norm: a token's output reads its own row of o and of z, and
+    # of the row its own head alone through o
+    from jax.experimental.pallas import tpu as pltpu
+
+    (o, z, scale), _ = _norm_inputs(rows=1, seq=128, heads=2)
+    with pltpu.force_tpu_interpret_mode():
+        forms = (norm.gated_rms_norm_plain, functools.partial(
+            norm.norm_kernel, block_s=64, block_d=128, step_rows=16))
+        for form in forms:
+            y = form(o, z, scale).reshape(1, 128, 2, 128)
+            for moved in (form(o.at[:, t, 1].add(1.0), z, scale),
+                          form(o, z.at[:, t, 256 + 128:].add(1.0), scale)):
+                changed = np.asarray(jnp.abs(
+                    moved.reshape(y.shape) - y).max((0, 3)) > 0)
+                assert changed[t].tolist() == [False, True]
+                assert not changed[:t].any() and not changed[t + 1:].any()
 
 
 # -- the gated attention ------------------------------------------------------
