@@ -170,7 +170,8 @@ def build_parser() -> argparse.ArgumentParser:
                         + ", ".join(sorted(CAUSAL_LMS)) + " (models/"
                         "transformer.py states each preset's source and "
                         "sizes; the first log line says which form each of "
-                        "its kernels runs: attention=, scan=, delta=, conv=)")
+                        "its kernels runs: attention=, scan=, delta=, ssd=, conv=, "
+                        "norm=)")
     p.add_argument("--num_layers", type=int, default=0,
                    help=">0: this many layers of a masked_lm/causal_lm "
                         "transformer preset in place of its own depth, at "

@@ -32,7 +32,8 @@ __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "phi4_mini_flash", "phi4_mini_flash_tiny", "sambay_layers",
            "zaya1_8b", "zaya_tiny", "qwen3_next_80b_a3b", "qwen3_next_tiny",
            "qwen3_next_layers", "smallthinker_21b_a3b", "smallthinker_tiny",
-           "smallthinker_layers", "dot_product_attention", "RMSNorm",
+           "smallthinker_layers", "granite4_h_micro", "granite4_h_tiny",
+           "granite4_layers", "dot_product_attention", "RMSNorm",
            "rotary_embedding", "causal_depthwise_conv", "LayerKind",
            "LAYER_KINDS", "Preset", "CAUSAL_LMS"]
 
@@ -547,6 +548,103 @@ def _dt_bias_init(key, shape, dtype):
     return step + jnp.log(-jnp.expm1(-step))
 
 
+class Mamba2Mixer(nn.Module):
+    """Mamba-2's mixer (the state-space dual, arXiv:2405.21060) as Granite
+    4.0-H runs it (``GraniteMoeHybridMambaLayer``), ``heads`` heads of
+    ``head_dim`` (``inner`` columns in all) over one group of ``states``:
+
+        [xBC; z] = u W_in          dt = softplus(u W_dt + dt_bias)    a head
+        [x; B; C] = silu(conv([xBC]) + b_c)       depthwise, causal
+        h_t = exp(dt_t A) h_{t-1} + dt_t x_t (x) B_t,   A = -exp(A_log) a head
+        y_t = h_t C_t + D x_t                           (:mod:`..ops.ssd`)
+        out = (w_n * g / sqrt(mean(g^2) + eps)) W_out,  g = y * silu(z)
+
+    a decay that is a scalar a head where :class:`MambaMixer` (Mamba-1) has
+    one a channel and state, a state ``[head_dim, states]`` a head, ``B`` and
+    ``C`` shared by all heads, and one norm over all ``inner`` columns with
+    the gate inside its statistic (:func:`..ops.norm.gate_then_rms_norm`; a
+    Gated DeltaNet's norms a head and gates afterwards). The published
+    projection is one matrix ``[z; xBC; dt]``; here the convolved columns
+    come first, ``[xBC; z]`` (``inner + 2 states`` then ``inner``: whole lane
+    groups at the published sizes, so :func:`..ops.conv.causal_conv_silu`
+    reads them where they lie, and on the chip the dual's kernel pair reads
+    ``x``, ``B`` and ``C`` from the convolved columns in place,
+    :func:`..ops.ssd.ssd_packed`), and the ``heads`` columns of ``dt`` are a
+    product of their own with float32 output, as a Gated DeltaNet's
+    ``in_proj_ba`` is: a loader would permute. ``dt``, the decay, the state
+    and the norm in float32, the products' operands in ``dtype``. No biases
+    but the convolution's and ``dt``'s. Nothing here knows where a document
+    ends inside a row: the convolution and the state run across it (ROADMAP
+    R4)."""
+
+    inner: int
+    heads: int
+    head_dim: int
+    states: int
+    conv: int
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    def kernels(self, seq_len: int, width: int) -> dict:
+        from ..ops.conv import conv_fused_applies
+        from ..ops.ssd import ssd_fused_applies
+
+        return {"ssd": ssd_fused_applies(seq_len, self.heads, self.head_dim,
+                                         self.states),
+                "conv": conv_fused_applies(
+                    seq_len, self.inner + 2 * self.states, self.conv),
+                "norm": False}  # the gate inside the statistic: plain lines
+
+    @nn.compact
+    def __call__(self, u):
+        from ..ops.norm import gate_then_rms_norm
+        from ..ops.ssd import ssd_packed
+
+        if self.inner != self.heads * self.head_dim:
+            raise ValueError(
+                f"a Mamba-2 mixer's {self.heads} heads of {self.head_dim} "
+                f"are its inner width, not {self.inner}")
+        h = u.shape[-1]
+        convolved = self.inner + 2 * self.states
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=jnp.float32, kernel_init=self.kernel_init)
+        with jax.named_scope("ssd.project"):
+            xbcz = dense(convolved + self.inner, name="in_proj_xbcz")(u)
+            dt = jax.nn.softplus(dense(
+                self.heads, name="in_proj_dt", dot_general=partial(
+                    jax.lax.dot_general, preferred_element_type=jnp.float32)
+                )(u) + self.param("dt_bias", nn.initializers.ones_init(),
+                                  (self.heads,), jnp.float32))
+        with jax.named_scope("ssd.conv"):
+            taps = self.param("conv_kernel", self.kernel_init,
+                              (self.conv, convolved), jnp.float32)
+            bias = self.param("conv_bias", nn.initializers.zeros_init(),
+                              (convolved,), jnp.float32)
+            # the first inner + 2 states columns, read where they lie
+            mixed = causal_conv_silu(xbcz, taps, bias, dtype=self.dtype)
+        a = -jnp.exp(self.param(
+            "A_log", lambda key, shape, dtype: jnp.log(
+                jnp.arange(1, shape[0] + 1, dtype=dtype)),
+            (self.heads,), jnp.float32))
+        skip = self.param("D", nn.initializers.ones_init(), (self.heads,),
+                          jnp.float32)
+        with jax.named_scope("ssd.kernel"):
+            # x, B and C where they lie in mixed's columns
+            y, last = ssd_packed(mixed, dt, a, skip, head_dim=self.head_dim)
+        self.sow("mixer_stats", "ssd_state_abs_max", jnp.abs(last).max())
+        self.sow("mixer_stats", "ssd_decay_min", jnp.exp((dt * a).min()))
+        self.sow("mixer_stats", "ssd_dt_mean", dt.mean())
+        with jax.named_scope("ssd.norm"):
+            scale = self.param("norm_scale", nn.initializers.ones_init(),
+                               (self.inner,), jnp.float32)
+            # z where it lies in the projection's last columns
+            gated = gate_then_rms_norm(y, xbcz, scale, eps=self.norm_eps,
+                                       dtype=self.dtype)
+        with jax.named_scope("ssd.project"):
+            return dense(h, name="out_proj")(gated)
+
+
 class GatedMemoryUnit(nn.Module):
     """SambaY's gated memory unit (arXiv:2507.06607): ``(m silu(u W_1))
     W_2``, with ``m`` an earlier layer's scan output at the same token in
@@ -756,14 +854,19 @@ class GatedAttention(nn.Module):
 
 
 class GroupedAttention(nn.Module):
-    """SmallThinker's softmax-attention mixer: ``num_heads`` query heads over
-    ``kv_heads`` key and value heads, all ``head_dim`` wide (which is not
-    ``hidden / num_heads``: 28 heads of 128 on a stream of 2,560), no norm on
-    queries or keys, no gate, no biases. Two kinds of layer are this class:
-    with ``rotary`` a rotary turn over the whole head and, with ``window`` >
-    0, a causal band (a query sees itself and the ``window - 1`` keys before
-    it); without either, the whole causal row and no position term at all
-    (``position_ids`` is not read). Scores over ``sqrt(head_dim)``."""
+    """SmallThinker's softmax-attention mixer, and Granite 4.0-H's:
+    ``num_heads`` query heads over ``kv_heads`` key and value heads, all
+    ``head_dim`` wide (which need not be ``hidden / num_heads``: 28 heads of
+    128 on a stream of 2,560), no norm on queries or keys, no gate, no
+    biases. Two kinds of layer are this class: with ``rotary`` a rotary turn
+    over the whole head and, with ``window`` > 0, a causal band (a query sees
+    itself and the ``window - 1`` keys before it); without either, the whole
+    causal row and no position term at all (``position_ids`` is not read).
+    Scores times ``score_scale``, or over ``sqrt(head_dim)`` where that is 0
+    (Granite's ``attention_multiplier`` is 1/64 on heads of 64, not 1/8): the
+    attention functions divide by the root themselves, so the queries are
+    multiplied by ``score_scale sqrt(head_dim)`` ahead of them, a power of
+    two there and exact in bf16."""
 
     num_heads: int
     kv_heads: int
@@ -774,6 +877,7 @@ class GroupedAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
     kernel_init: Callable = nn.linear.default_kernel_init
+    score_scale: float = 0.0  # 0: 1 / sqrt(head_dim)
 
     def kernels(self, seq_len: int, width: int) -> dict:
         return _attention_kernel(self.attention_fn, seq_len, self.head_dim,
@@ -790,6 +894,8 @@ class GroupedAttention(nn.Module):
             q = dense(features=(n, d), name="query")(x)
             k = dense(features=(g, d), name="key")(x)
             v = dense(features=(g, d), name="value")(x)
+            if self.score_scale:
+                q = q * (self.score_scale * math.sqrt(d))
             if self.rotary:
                 pos = jnp.arange(s) if position_ids is None else position_ids
                 q = rotary_embedding(q, pos, self.rope_theta)
@@ -956,7 +1062,7 @@ _MEMORY, _KEYS_VALUES, _ROUTER_STATE = range(3)
 # A layer kind is an entry here, its mixer's class above and, where the class
 # has sizes, a ``partial`` in the preset's ``parts``. "": rotary attention
 # with a norm on queries and keys (OLMoE's); "L": latent attention
-# (Moonlight's); SambaY's six (arXiv:2507.06607): "M" a Mamba mixer, "M*" one
+# (Moonlight's); SambaY's six (arXiv:2507.06607): "M" a Mamba-1 mixer, "M*" one
 # that hands on its scan output, "S" window and "F*" full differential
 # attention, the latter handing on its keys and values, "G" a gated memory
 # unit over M*'s output, "X" differential attention over F*'s keys and
@@ -964,7 +1070,8 @@ _MEMORY, _KEYS_VALUES, _ROUTER_STATE = range(3)
 # gives such a layer its router and residual scales); Qwen3-Next's two: "D" a
 # gated DeltaNet, "A" gated attention; SmallThinker's two: "W" grouped rotary
 # attention in a window, "N" the same heads over the whole causal row with no
-# position term.
+# position term (Granite 4.0-H's attention layers too, under a score scale of
+# their own); "M2": a Mamba-2 mixer, Granite 4.0-H's other nine layers in ten.
 LAYER_KINDS: dict = {
     "": LayerKind(SelfAttention, "attn", "attention",
                   (("causal", True), ("use_bias", False)), 3),
@@ -983,6 +1090,7 @@ LAYER_KINDS: dict = {
     "W": LayerKind(GroupedAttention, "attn", "attention", sequence=3),
     "N": LayerKind(GroupedAttention, "attn", "attention",
                    (("window", 0), ("rotary", False)), 3),
+    "M2": LayerKind(Mamba2Mixer, "ssm", "state_space"),
 }
 
 
@@ -993,8 +1101,11 @@ class DecoderBlock(nn.Module):
     layer's parts that has sizes of its own, a ``partial`` of the class over
     them, under the class's own field names. A ``"C"`` layer (ZAYA1's) also
     has an expert layer whose router is a :class:`..moe.StateRouter` with a
-    state handed from layer to layer, and learned scales and shifts on both
-    sides of both residual sums. With ``router_early`` (SmallThinker's) the
+    state handed from layer to layer. ``residual_scales`` (ZAYA1's) puts
+    learned scales and shifts on both sides of both residual sums;
+    ``branch_scale`` other than 1 (Granite's ``residual_multiplier``)
+    multiplies both branches ahead of their sums. With ``router_early``
+    (SmallThinker's) the
     expert layer's logits are one f32 product of the block's input, made
     before ``ln_attn`` and attention, whatever the mixer.
     ``dense_dim`` is the feed-forward (0: the dropless expert layer that
@@ -1020,6 +1131,8 @@ class DecoderBlock(nn.Module):
     parts: tuple = ()  # partial(Class, **its own sizes), one a sized class
     norm_offset: bool = False  # RMSNorm's scale is 1 + w
     router_early: bool = False  # the router reads x, ahead of attention
+    residual_scales: bool = False  # learned a and b on both residual sums
+    branch_scale: float = 1.0  # x + branch_scale * branch
 
     def part(self, cls, **fields):
         """``cls`` as this layer holds it: with the sizes ``parts`` states
@@ -1041,10 +1154,14 @@ class DecoderBlock(nn.Module):
         return self.part(kind.mixer, **dict(kind.fixed), **fields)
 
     def _merge(self, x, y, name):
-        """The residual sum ``x + y``; a ZAYA layer's is ``(a_r x + b_r) +
-        (a_o y + b_o)`` in f32, with learned vectors, a from 1 and b from 0."""
-        if self.kind != "C":
-            return x + y
+        """The residual sum ``x + y``, or ``x + branch_scale y`` in f32
+        rounded once; under ``residual_scales`` ``(a_r x + b_r) + (a_o y +
+        b_o)`` in f32, with learned vectors, a from 1 and b from 0."""
+        if not self.residual_scales:
+            if self.branch_scale == 1.0:
+                return x + y
+            return (x.astype(jnp.float32) + self.branch_scale * y.astype(
+                jnp.float32)).astype(self.dtype)
         ones, zeros = nn.initializers.ones_init(), nn.initializers.zeros_init()
         a_r, b_r, a_o, b_o = (
             self.param(f"{name}_{part}", init, x.shape[-1:], jnp.float32)
@@ -1106,8 +1223,13 @@ class TransformerDecoder(nn.Module):
     ``"C"``, under RMSNorm; Qwen3-Next's are three ``"D"`` to one ``"A"``,
     under RMSNorm's ``1 + w`` form, with a head of its own; SmallThinker's
     one ``"N"`` to three ``"W"``, every layer's router ahead of its
-    attention (``router_early``), a head of its own. SambaY's and
-    ZAYA1's tie the head to the embedding (``tied_head``).
+    attention (``router_early``), a head of its own; Granite 4.0-H's nine
+    ``"M2"`` to one ``"N"``, a dense SwiGLU in every layer, and the model's
+    four multipliers: the embedding's (``embed_scale``), both branches'
+    ahead of their residual sums (``branch_scale``), the attention scores'
+    (``GroupedAttention.score_scale``) and the logits' (``logit_scale``), each
+    absent from a stack's arithmetic at its default. SambaY's, ZAYA1's and
+    Granite's tie the head to the embedding (``tied_head``).
     ``first_layer`` says which published layers are held (``num_layers`` of
     them from there: one pipeline stage's), and what a layer hands on (M*'s
     and F*'s tensors, a router's state) rides from layer to layer beside
@@ -1140,6 +1262,10 @@ class TransformerDecoder(nn.Module):
     router_early: bool = False  # DecoderBlock's, for every expert layer
     layer_norm: bool = False  # LayerNorm in place of RMSNorm, everywhere
     tied_head: bool = False  # the head is the embedding, no matrix of its own
+    residual_scales: bool = False  # DecoderBlock's, for every layer
+    branch_scale: float = 1.0  # DecoderBlock's, for every layer
+    embed_scale: float = 1.0  # the embedding's rows times this
+    logit_scale: float = 1.0  # the logits times this
 
     @property
     def held_kinds(self) -> tuple:
@@ -1173,13 +1299,15 @@ class TransformerDecoder(nn.Module):
             moe=self.moe, kind=kind, depth=self.first_layer + i,
             layer_norm=self.layer_norm, parts=self.parts,
             norm_offset=self.norm_offset, router_early=self.router_early,
-            **fields)
+            residual_scales=self.residual_scales,
+            branch_scale=self.branch_scale, **fields)
 
     def kernels(self, seq_len: int) -> dict:
         """Which kernels the held layers run at ``seq_len``, by name
-        (``attention``, ``scan``, ``delta``, ``conv``): every layer's mixer
-        is asked, and a name is True where each mixer that has it says so.
-        A span that holds no mixer with some kernel has no entry for it."""
+        (``attention``, ``scan``, ``delta``, ``ssd``, ``conv``, ``norm``):
+        every layer's mixer is asked, and a name is True where each mixer
+        that has it says so. A span that holds no mixer with some kernel has
+        no entry for it."""
         answer: dict = {}
         for i, kind in enumerate(self.held_kinds):
             mixer = self._layer(i, kind, parent=None).mixer(parent=None)
@@ -1195,7 +1323,10 @@ class TransformerDecoder(nn.Module):
         embed = nn.Embed(self.vocab_size, self.hidden_size,
                          param_dtype=jnp.float32, embedding_init=init,
                          name="tok_embed")
-        x = embed(input_ids).astype(self.dtype)
+        x = embed(input_ids)
+        if self.embed_scale != 1.0:
+            x = x * self.embed_scale  # in the table's f32
+        x = x.astype(self.dtype)
         live = None if attention_mask is None else attention_mask > 0
         mask, seg_kwarg = _attention_masks(attention_mask, segment_ids,
                                            self.attention_fn)
@@ -1215,15 +1346,18 @@ class TransformerDecoder(nn.Module):
         with jax.named_scope("lm_head"):
             # bf16 operands on the matrix unit, f32 sums and f32 logits
             if self.tied_head:  # the held rows of the embedding
-                return jnp.einsum(
+                logits = jnp.einsum(
                     "bsh,vh->bsv", x, embed.embedding.astype(self.dtype),
                     preferred_element_type=jnp.float32)
-            return nn.Dense(
-                self.vocab_size, use_bias=False, dtype=self.dtype,
-                param_dtype=jnp.float32, kernel_init=init,
-                dot_general=partial(jax.lax.dot_general,
-                                    preferred_element_type=jnp.float32),
-                name="lm_head")(x)
+            else:
+                logits = nn.Dense(
+                    self.vocab_size, use_bias=False, dtype=self.dtype,
+                    param_dtype=jnp.float32, kernel_init=init,
+                    dot_general=partial(jax.lax.dot_general,
+                                        preferred_element_type=jnp.float32),
+                    name="lm_head")(x)
+            return (logits if self.logit_scale == 1.0
+                    else logits * self.logit_scale)
 
 
 bert_base = partial(TransformerEncoder, hidden_size=768, num_layers=12,
@@ -1321,14 +1455,14 @@ zaya1_8b = partial(
     rope_theta=5000000.0, moe=_ZAYA_ROUTER, layer_kinds=("C",) * 40,
     parts=(partial(ConvolutionalAttention, kv_heads=2, head_dim=128,
                    rotary_dim=64), partial(StateRouter, width=256)),
-    tied_head=True)
+    tied_head=True, residual_scales=True)
 zaya_tiny = partial(
     TransformerDecoder, hidden_size=64, num_layers=3, num_heads=4,
     expert_dim=32, num_experts=8, experts_per_token=1, rope_theta=5000000.0,
     moe=_ZAYA_ROUTER, layer_kinds=("C",) * 3,
     parts=(partial(ConvolutionalAttention, kv_heads=2, head_dim=16,
                    rotary_dim=8), partial(StateRouter, width=32)),
-    tied_head=True)
+    tied_head=True, residual_scales=True)
 
 
 def qwen3_next_layers(layers: int, interval: int = 4) -> tuple:
@@ -1403,6 +1537,44 @@ smallthinker_tiny = partial(
     router_early=True)
 
 
+def granite4_layers(layers: int, period: int = 10, at: int = 5) -> tuple:
+    """Granite 4.0-H's layout (``layer_types``): layer ``i`` is grouped
+    attention without a position term (N) where ``i % period == at`` and a
+    Mamba-2 mixer (M2) otherwise: attention at 5, 15, 25, 35 of 40."""
+    return tuple("N" if i % period == at else "M2" for i in range(layers))
+
+
+# granite-4.0-h-micro (ibm-granite/granite-4.0-h-micro config.json,
+# model_type granitemoehybrid with num_local_experts 0; the state-space dual,
+# arXiv:2405.21060, for the recurrence): 40 layers, nine Mamba-2 mixers (64
+# heads of 64 over one group of 128 states, a causal convolution over 4 with a
+# bias, one gated norm over all 4,096 columns) to one attention layer (32
+# query heads over 8 key/value heads of 64, no position term, scores times
+# 1/64); every layer a dense SwiGLU of 8,192 (the "shared" MLP alone);
+# RMSNorm 1e-5; the embedding times 12, both branches times 0.22, the tied
+# head's logits over 8.
+_GRANITE4_SCALES = dict(embed_scale=12.0, branch_scale=0.22,
+                        logit_scale=0.125)
+granite4_h_micro = partial(
+    TransformerDecoder, hidden_size=2048, num_layers=40, num_heads=32,
+    expert_dim=0, num_experts=0, experts_per_token=0, rope_theta=0.0,
+    dense_layers=40, dense_dim=8192, layer_kinds=granite4_layers(40),
+    parts=(partial(Mamba2Mixer, inner=4096, heads=64, head_dim=64,
+                   states=128, conv=4),
+           partial(GroupedAttention, kv_heads=8, head_dim=64,
+                   score_scale=0.015625)),
+    tied_head=True, **_GRANITE4_SCALES)
+granite4_h_tiny = partial(
+    TransformerDecoder, hidden_size=64, num_layers=4, num_heads=4,
+    expert_dim=0, num_experts=0, experts_per_token=0, rope_theta=0.0,
+    dense_layers=4, dense_dim=128, layer_kinds=granite4_layers(4, 4, 1),
+    parts=(partial(Mamba2Mixer, inner=64, heads=4, head_dim=16, states=16,
+                   conv=4),
+           partial(GroupedAttention, kv_heads=2, head_dim=16,
+                   score_scale=0.015625)),
+    tied_head=True, **_GRANITE4_SCALES)
+
+
 class Preset(NamedTuple):
     """A ``causal_lm`` preset: the constructor (called with ``vocab_size``
     and whatever a task changes), the vocabulary that is the model's own, and
@@ -1422,7 +1594,7 @@ class Preset(NamedTuple):
 # alone. Qwen3-Next's take the balance term at its published class's default
 # weight (router_aux_loss_coef 0.001) and no z term, and SmallThinker's the
 # same (its config names none: the benchmark's configuration lists it as
-# assumed).
+# assumed). Granite 4.0-H micro has no experts and no auxiliary term.
 _SWITCH_AUX = {"load_balance": 0.01}
 _OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
 _MOONLIGHT_AUX = {"seq_balance": 0.0001}
@@ -1444,4 +1616,6 @@ CAUSAL_LMS: dict = {
     "smallthinker_21b_a3b": Preset(smallthinker_21b_a3b, 151936,
                                    _SMALLTHINKER_AUX),
     "smallthinker_tiny": Preset(smallthinker_tiny, 512, _SMALLTHINKER_AUX),
+    "granite4_h_micro": Preset(granite4_h_micro, 100352, {}),
+    "granite4_h_tiny": Preset(granite4_h_tiny, 512, {}),
 }

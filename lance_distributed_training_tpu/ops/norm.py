@@ -27,6 +27,18 @@ in float32 whatever the operands' type, one cast to ``dtype`` at the end,
 :func:`gated_rms_norm` chooses between them from the platform and the shapes
 (:func:`norm_fused_applies`), as :mod:`.conv`, :mod:`.scan` and :mod:`.delta`
 do: no flag.
+
+A Mamba-2 layer's gated norm (Granite 4.0-H's) is another function of the
+same three arrays, :func:`gate_then_rms_norm`: the gate is inside the
+statistic and the group is the whole row,
+
+    y = scale * g / sqrt(mean(g^2) + eps),   g = o * silu(z)     all ``H * d``
+
+so neither kernel above computes it (theirs norm a head's ``d`` and gate
+afterwards). It has the plain form alone, on the chip too: a row of 4,096
+columns is 32 lane groups whose sum of squares a kernel would have to finish
+before it scales any of them, two passes over the tile where the head-wise
+norm makes one.
 """
 
 from __future__ import annotations
@@ -41,8 +53,9 @@ import jax.numpy as jnp
 from .conv import _block
 from .scan import _pallas  # the same grid: (row, channel block, sequence)
 
-__all__ = ["gated_rms_norm", "gated_rms_norm_plain", "norm_kernel",
-           "norm_fused_applies", "BLOCK_S", "BLOCK_D", "STEP_ROWS"]
+__all__ = ["gated_rms_norm", "gated_rms_norm_plain", "gate_then_rms_norm",
+           "norm_kernel", "norm_fused_applies", "BLOCK_S", "BLOCK_D",
+           "STEP_ROWS"]
 
 # the tile and the step inside it, timed on the v5e (PERF.md section 6, PR 48)
 BLOCK_S = 1024  # tokens a grid step holds
@@ -64,6 +77,20 @@ def gated_rms_norm_plain(o, z, scale, *, eps: float = 1e-6, dtype=None):
     z = z[..., z.shape[2] - heads * d:].reshape(rows, seq, heads, d)
     return (o * jax.nn.silu(z.astype(_F32))).astype(dtype).reshape(
         rows, seq, heads * d)
+
+
+def gate_then_rms_norm(o, z, scale, *, eps: float = 1e-5, dtype=None):
+    """``scale * rmsnorm(o * silu(z))`` over all of a token's columns: ``o``
+    [B, S, W] (or [B, S, H, d], flattened), ``z`` [B, S, W'] whose last ``W``
+    columns are the gate, ``scale`` [W]; the gate, the statistic and the
+    scale in float32, [B, S, W] in ``dtype`` (``o``'s if None). Plain
+    ``jax.numpy``, everywhere."""
+    dtype = dtype or o.dtype
+    o = o.reshape(*o.shape[:2], -1).astype(_F32)
+    z = z[..., z.shape[2] - o.shape[2]:].astype(_F32)
+    gated = o * jax.nn.silu(z)
+    return (gated * jax.lax.rsqrt(jnp.mean(gated * gated, -1, keepdims=True)
+                                  + eps) * scale).astype(dtype)
 
 
 def _heads(width, d):

@@ -374,7 +374,7 @@ def _kernel_paths(task: Task, config: TrainConfig) -> dict:
     """The first log line's word for the form each of the model's kernels
     runs at ``seq_len``, by the kernel's name (``Task.kernels``: the mixers'
     own answer, which asks the test each call makes): ``attention=``,
-    ``scan=``, ``delta=``, ``conv=``, ``norm=``."""
+    ``scan=``, ``delta=``, ``ssd=``, ``conv=``, ``norm=``."""
     plain = {"attention": "ring" if config.seq_parallelism > 1 else "dense",
              "conv": "plain", "norm": "plain"}
     return {name: "fused kernel" if fused else plain.get(name, "chunked")

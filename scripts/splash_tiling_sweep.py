@@ -59,6 +59,8 @@ SHAPES = {
                               seq=16384),
     "smallthinker_window": dict(q=(28, 128), k=(4, 128), v=(4, 128),
                                 window=4096, seq=16384),
+    # Granite 4.0-H's grouped attention: 32 query heads over 8 of 64
+    "granite": dict(q=(32, 64), k=(8, 64), v=(8, 64), window=0),
 }
 KERNELS = ("fwd", "dkv", "dq")
 CALLS = 20  # after one call of warm-up

@@ -295,25 +295,37 @@ def test_unequal_attention_compiles_for_a_v5e_at_phi4_flashs_widths(
         shape._replace(window=512 - window))
 
 
-def test_unequal_attention_compiles_for_a_v5e_at_zaya1s_widths(one_chip):
-    """Compressed convolutional attention's heads as the ZAYA1 cell runs
-    them: 8 query heads over 2 key and value heads, all 128 wide, 8,192
-    tokens, causal, at the tiling timed for the shape: the three kernels, and
-    no ``[B, H, S, S]`` tensor."""
+@pytest.mark.parametrize("heads,groups,width,scale,is_timed", [
+    (8, 2, 128, 0.0, True),  # c4-zaya1-ep2-prepacked-8k's
+    # c4-granite4h-vp8-prepacked-8k's, scores / 64; no sweep has timed it
+    (32, 8, 64, 0.015625, False),
+], ids=["zaya1", "granite4h"])
+def test_unequal_attention_compiles_for_a_v5e_at_grouped_heads_of_equal_widths(
+        one_chip, heads, groups, width, scale, is_timed):
+    """Heads in groups whose values are as wide as their keys, 8,192 tokens,
+    causal, at the tiling timed for the shape: compressed convolutional
+    attention's 8 query heads over 2 key and value heads of 128 as the ZAYA1
+    cell runs them, and grouped attention's 32 over 8 of 64 as the Granite
+    cell does (at the rule's square 512s: attention is 3.4% of that cell's
+    work and no sweep has timed its shape), its score scale folded into the
+    queries as ``GroupedAttention`` folds it (a power of two: no second
+    rounding). The three kernels, and no ``[B, H, S, S]`` tensor."""
     def spec(heads):
-        return jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16,
+        return jax.ShapeDtypeStruct((1, heads, 8192, width), jnp.bfloat16,
                                     sharding=one_chip)
 
-    tiling, timed = flash.splash_tiling(8192, 128, 128, 8, True)
-    assert timed and tiling.dq is not None
+    tiling, timed = flash.splash_tiling(8192, width, width, heads, True)
+    assert timed is is_timed and tiling.dq is not None
 
     def loss(q, k, v):
+        if scale:
+            q = q * (scale * width ** 0.5)
         out = flash.unequal_attention(q, k, v, causal=True)
-        assert out.shape == (1, 8, 8192, 128)
+        assert out.shape == (1, heads, 8192, width)
         return out.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        spec(8), spec(2), spec(2)).compile()
+        spec(heads), spec(groups), spec(groups)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
     assert "8192,8192]" not in text
@@ -473,9 +485,43 @@ def test_the_gated_norms_kernels_compile_for_a_v5e_at_qwen3_nexts_widths(
         2 * 8192 * 4096 * 2)
 
 
+def test_the_duals_kernels_compile_for_a_v5e_at_granites_widths(one_chip):
+    """The state-space dual as the Granite cell calls it: one row of 8,192
+    tokens, 64 heads of 64 over 128 states, ``x``, ``b`` and ``c`` read from
+    the 4,352 convolved columns where they lie, forward and backward: two
+    kernels, no slice of the projection in front of them, and nothing of
+    ``[chunks, heads, 128, 128]`` in HBM (the plain form's decay masks: 268
+    MB a layer in float32)."""
+    from lance_distributed_training_tpu.ops import ssd
+
+    def spec(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(xbc, dt, a, d, ct):
+        y, last = ssd.ssd_kernel_packed(xbc, dt, a, d, head_dim=64)
+        assert y.shape == (1, 8192, 64, 64) and y.dtype == jnp.bfloat16
+        assert last.shape == (1, 64, 64, 128)
+        return (y * ct).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        spec(1, 8192, 4352, dtype=jnp.bfloat16), spec(1, 8192, 64), spec(64),
+        spec(64), spec(1, 8192, 64, 64, dtype=jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    assert all("bf16[1,8192,4352]" in line for line in calls)
+    assert not re.search(r"bf16\[1,8192,\d+\]\S* slice\(", text)
+    assert not re.search(r"\[(1,)?64,64,128,128\]", text)
+    # the state a chunk starts from, float32: 64 chunks of 2 MiB, and little
+    # else (dx, db, dc and the per-token scalars' two layouts)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * (
+        64 * 64 * 64 * 128 * 4)
+
+
 @pytest.mark.parametrize("rows,wide,width,has_bias", [
     (2, 12288, 8192, False),  # c4-qwen3next-ep16-prepacked-8k's projection
     (1, 10240, 5120, True),  # c4-phi4flash-vp8-prepacked-8k's
+    (1, 8448, 4352, True),  # c4-granite4h-vp8-prepacked-8k's: 34 lane groups
 ])
 def test_the_convolutions_kernels_compile_for_a_v5e_at_the_cells_widths(
         one_chip, rows, wide, width, has_bias):
@@ -596,6 +642,7 @@ CELL_SHAPES = [  # seq, d_qk, d_v, heads, causal, window
     (16384, 128, 128, 28, True, 4096),  # and its band, eight 512-blocks wide
 ]
 UNTIMED_SHAPES = [  # and the square block each runs
+    ((8192, 64, 64, 32, True, 0), 512),  # Granite 4.0-H's 32 over 8 of 64
     ((4096, 192, 128, 16, True, 0), 512),  # a shorter row of the same heads
     ((8192, 128, 64, 8, True, 0), 512),  # other widths
     ((1024, 64, 128, 8, True, 256), 512),  # another band

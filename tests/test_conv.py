@@ -20,6 +20,7 @@ import pytest
 from lance_distributed_training_tpu.models.tasks import get_task
 from lance_distributed_training_tpu.models.transformer import (
     GatedDeltaNet,
+    Mamba2Mixer,
     MambaMixer,
 )
 from lance_distributed_training_tpu.ops import conv
@@ -67,6 +68,9 @@ def _kernel_run(args, ct, block_s, block_d=128, dtype=None):
 # the rows handed on inside a tile are walked too), x a slice of a wider array
 CASES = list(itertools.product((256, 640), (False, True), (1, 2), (2, 3),
                                (False, True)))
+# a Mamba-2 layer's convolved columns at the published sizes, 34 lane groups
+# with a bias, ahead of further columns of the projection
+CASES.append((4352, True, 1, 1, True))
 
 
 @pytest.mark.parametrize(
@@ -229,7 +233,7 @@ def test_a_projection_of_ragged_width_is_sliced_in_front_of_the_kernel():
 
 @pytest.mark.parametrize("model,says", [
     ("qwen3_next_tiny", "plain"), ("phi4_mini_flash_tiny", "plain"),
-    ("olmoe_tiny", None)])
+    ("granite4_h_tiny", "plain"), ("olmoe_tiny", None)])
 def test_the_first_log_line_names_the_convolutions_path(model, says):
     from lance_distributed_training_tpu import trainer
 
@@ -242,7 +246,8 @@ def test_the_first_log_line_names_the_convolutions_path(model, says):
 
 @pytest.mark.parametrize("model,shape", [
     ("qwen3_next_80b_a3b", (8192, 4)), ("phi4_mini_flash", (5120, 4)),
-    ("qwen3_next_tiny", (128, 4)), ("phi4_mini_flash_tiny", (128, 4))])
+    ("qwen3_next_tiny", (128, 4)), ("phi4_mini_flash_tiny", (128, 4)),
+    ("granite4_h_micro", (4352, 4)), ("granite4_h_tiny", (96, 4))])
 def test_a_stack_knows_its_convolutions_shape(model, shape, monkeypatch):
     """The mixer asks the op's own rule with its channels and taps."""
     from lance_distributed_training_tpu.models.transformer import CAUSAL_LMS
@@ -280,6 +285,9 @@ MIXERS = {
     # a Gated DeltaNet: 2 x 128 + 256 = 512 channels out of 768, no bias
     "gated_delta_net": (
         lambda: GatedDeltaNet(1, 2, 128, 128, 4, dtype=jnp.float32), 64),
+    # Mamba-2: 128 + 2 x 64 = 256 channels out of 384, a bias
+    "mamba2": (lambda: Mamba2Mixer(128, 2, 64, 64, 4, dtype=jnp.float32),
+               64),
 }
 
 

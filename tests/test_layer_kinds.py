@@ -5,7 +5,7 @@ its mixers say, and both the step's gauges and the first log line are made
 from that one answer.
 
 *Does every preset answer with the kernels its layers have, and does the
-first log line say them?* The fourteen presets and the three spans the
+first log line say them?* The sixteen presets and the four spans the
 benchmark's cells hold. *Does a kind nobody wrote into the task or the
 trainer train?* A toy mixer with sizes, a kernel and a sown gauge of its
 own, patched into the two tables here, through ``get_task`` and
@@ -26,13 +26,24 @@ import pytest
 
 from lance_distributed_training_tpu import trainer
 from lance_distributed_training_tpu.models import get_task, transformer
-from lance_distributed_training_tpu.ops import conv, delta, flash, norm, scan
+from lance_distributed_training_tpu.ops import (
+    conv,
+    delta,
+    flash,
+    norm,
+    scan,
+    ssd,
+)
 
 ATTENTION = {"attention"}
 SAMBAY = {"attention", "scan", "conv"}
 QWEN3_NEXT = {"attention", "delta", "conv", "norm"}
+GRANITE4 = {"attention", "ssd", "conv", "norm"}
 PLAIN = {"attention": "dense", "scan": "chunked", "delta": "chunked",
-         "conv": "plain", "norm": "plain"}
+         "ssd": "chunked", "conv": "plain", "norm": "plain"}
+# a kernel a mixer names and no rule can choose: Mamba-2's gated norm has the
+# gate inside its statistic and the plain lines alone (ops/norm.py)
+PLAIN_ONLY = {"granite4_h_micro": {"norm"}, "granite4_h_tiny": {"norm"}}
 KERNELS = {
     ("gpt_base", None): ATTENTION, ("gpt_small", None): ATTENTION,
     ("olmoe_1b_7b", None): ATTENTION, ("olmoe_tiny", None): ATTENTION,
@@ -45,11 +56,17 @@ KERNELS = {
     ("qwen3_next_tiny", None): QWEN3_NEXT,
     ("smallthinker_21b_a3b", None): ATTENTION,
     ("smallthinker_tiny", None): ATTENTION,
+    ("granite4_h_micro", None): GRANITE4,
+    ("granite4_h_tiny", None): GRANITE4,
     # the spans of the cells c4-phi4flash-vp8-prepacked-8k,
-    # c4-qwen3next-ep16-prepacked-8k and c4-smallthinker-ep4-prepacked-16k
+    # c4-qwen3next-ep16-prepacked-8k, c4-smallthinker-ep4-prepacked-16k and
+    # c4-granite4h-vp8-prepacked-8k
     ("phi4_mini_flash", "14:20"): SAMBAY,
     ("qwen3_next_80b_a3b", "0:4"): QWEN3_NEXT,
     ("smallthinker_21b_a3b", "0:4"): ATTENTION,
+    ("granite4_h_micro", "0:10"): GRANITE4,
+    # a span of Mamba-2 layers alone has no attention to report
+    ("granite4_h_micro", "6:10"): GRANITE4 - {"attention"},
 }
 
 
@@ -68,14 +85,18 @@ def test_a_preset_answers_with_its_kernels_and_the_first_line_says_them(
     for op, rule in ((flash, "fused_attention_applies"),
                      (scan, "scan_fused_applies"),
                      (delta, "delta_fused_applies"),
+                     (ssd, "ssd_fused_applies"),
                      (conv, "conv_fused_applies"),
                      (norm, "norm_fused_applies")):
         monkeypatch.setattr(op, rule, lambda *a, **k: True)
     task = get_task("causal_lm", model_name=model, seq_len=8192,
                     layer_span=span)
-    assert task.kernels == dict.fromkeys(KERNELS[model, span], True)
-    assert trainer._kernel_paths(task, config) == dict.fromkeys(
-        KERNELS[model, span], "fused kernel")
+    fused = {name: name not in PLAIN_ONLY.get(model, ())
+             for name in KERNELS[model, span]}
+    assert task.kernels == fused
+    assert trainer._kernel_paths(task, config) == {
+        name: "fused kernel" if on else PLAIN[name]
+        for name, on in fused.items()}
 
 
 def test_the_table_holds_the_presets_listed_here():

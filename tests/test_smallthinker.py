@@ -722,16 +722,19 @@ def test_a_reader_reads_the_scopes_the_layers_name(metric, config):
 def test_the_manifest_lists_the_cell_and_its_seven_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    cell = manifest["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        "c4-smallthinker-ep4-prepacked-16k", "smallthinker-21b-a3b-c4",
-        "c4-prepacked-16k-ep4", 1)
+    cell = next(w for w in manifest["workloads"]
+                if w["name"] == "c4-smallthinker-ep4-prepacked-16k")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "smallthinker-21b-a3b-c4", "c4-prepacked-16k-ep4", 1)
     mine = [m for m in manifest["per_layer"]
             if m.get("workloads") == [cell["name"]]]
     assert sorted(m["name"] for m in mine) == sorted(_READS)
-    assert manifest["per_layer"][-len(mine):] == mine  # appended, in a block
+    first = manifest["per_layer"].index(mine[0])  # appended in a block
+    assert manifest["per_layer"][first:first + len(mine)] == mine
     assert {m["moves"] for m in mine} == {"samples_per_s_chip"}
-    assert manifest["configs"][-1]["reduced"] == [
+    config = next(c for c in manifest["configs"]
+                  if c["name"] == "smallthinker-21b-a3b-c4")
+    assert config["reduced"] == [
         "num_hidden_layers", "moe_num_primary_experts", "vocab_size"]
 
 
