@@ -17,7 +17,10 @@ Mixture-of-Experts layers.
   with no capacity and no dropped token; the ``T * k`` assignments are sorted
   by expert, the tokens gathered, the
   three SwiGLU products run as grouped matrix multiplications over the
-  ragged groups (``jax.lax.ragged_dot``), and each token's k results
+  ragged groups (:func:`..ops.grouped.grouped_product`:
+  ``jax.lax.ragged_dot``, or on one TPU device, at a shape its table has, the
+  Pallas grouped matmul and the gauge ``grouped_products_fused`` says), and
+  each token's k results
   gathered back through the inverse permutation and summed with their
   weights. Nothing larger than ``[T * k, width]`` exists. It holds all of
   its experts (OLMoE on one chip) or is told which contiguous range of them
@@ -43,6 +46,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.grouped import grouped_product
 from ..ops.rows import Way as _Way, sum_rows
 
 __all__ = ["SwiGLU", "MoEMLP", "DroplessMoE", "StateRouter",
@@ -402,13 +406,13 @@ class DroplessMoE(nn.Module):
 
         def experts(xs, group_sizes, w_gate, w_up, w_down):
             with jax.named_scope("moe.experts"):
-                gate = jax.lax.ragged_dot(xs, w_gate.astype(self.dtype),
-                                          group_sizes)
-                up = jax.lax.ragged_dot(xs, w_up.astype(self.dtype),
-                                        group_sizes)
-                return jax.lax.ragged_dot(act(gate) * up,
-                                          w_down.astype(self.dtype),
-                                          group_sizes)
+                gate = grouped_product(xs, w_gate.astype(self.dtype),
+                                       group_sizes)
+                up = grouped_product(xs, w_up.astype(self.dtype),
+                                     group_sizes)
+                return grouped_product(act(gate) * up,
+                                       w_down.astype(self.dtype),
+                                       group_sizes)
 
         with jax.named_scope("moe.dispatch"):
             # Stable sort of the T*k assignments by expert: row i of the

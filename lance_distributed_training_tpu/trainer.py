@@ -689,6 +689,9 @@ def _loader_buffer_pool(config: TrainConfig):
 
 
 _TEXT_TASKS = ("masked_lm", "causal_lm", "contrastive")
+# gauges a traced step of a model with expert layers sets to 1 where it runs
+# the kernel form: like attention_fused on every log line and in the results
+_EXPERT_GAUGES = ("rows_sum_fused", "grouped_products_fused")
 
 
 def _token_pack_config(config: TrainConfig, mesh=None):
@@ -1436,10 +1439,11 @@ def _train(config: TrainConfig) -> dict:
             results["attention_fused"])
     if getattr(task.model, "experts_per_token", 0):
         # 0 until a step is traced whose expert layers sum their rows back
-        # by the kernel (ops/rows.py sets it to 1 as it builds one): like
-        # attention_fused a gauge and an entry of every log line
-        results["rows_sum_fused"] = 0.0
-        default_registry().gauge("rows_sum_fused").set(0.0)
+        # by the kernel (ops/rows.py sets it to 1 as it builds one), or
+        # multiply their groups by the Pallas grouped matmul (ops/grouped.py)
+        for name in _EXPERT_GAUGES:
+            results[name] = 0.0
+            default_registry().gauge(name).set(0.0)
     total_start = time.perf_counter()
     global_step = 0
 
@@ -2093,9 +2097,9 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             # says once what tiling it runs
             for line in splash_tilings_built():
                 logger.log(line, to_wandb=False)
-        if "rows_sum_fused" in known:
-            entry["rows_sum_fused"] = default_registry().gauge(
-                "rows_sum_fused").value
+        for name in _EXPERT_GAUGES:
+            if name in known:
+                entry[name] = default_registry().gauge(name).value
         if config.data_echo > 1:
             # The windowed rate counts echoed steps; report the unique-data
             # rate next to it (as the epoch metrics do) so the live stream
@@ -2469,9 +2473,9 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
 
     obs_phase("train.shutdown")  # final eval, then train()'s teardown
     results["history"] = history
-    if "rows_sum_fused" in results:  # as the traced steps left it
-        results["rows_sum_fused"] = default_registry().gauge(
-            "rows_sum_fused").value
+    for name in _EXPERT_GAUGES:
+        if name in results:  # as the traced steps left it
+            results[name] = default_registry().gauge(name).value
     results["steps"] = global_step  # train steps executed this run
     results["global_step"] = journal.abs_step  # absolute, across restarts
     results["total_time"] = time.perf_counter() - total_start

@@ -592,6 +592,43 @@ def test_the_rows_kernel_compiles_for_a_v5e_at_the_cells_shapes(
         built * width * 2)
 
 
+def _grouped_entries():
+    from lance_distributed_training_tpu.ops import grouped
+
+    return sorted(grouped.TILINGS.items())
+
+
+@pytest.mark.parametrize("shape,tiling", _grouped_entries(),
+                         ids=["x".join(map(str, s))
+                              for s, _ in _grouped_entries()])
+def test_the_grouped_products_kernels_compile_for_a_v5e_at_every_entry(
+        one_chip, shape, tiling):
+    """A grouped product and both its cotangents at each shape and tiling of
+    ``ops/grouped.py``'s table, bf16 as the cells call it: three kernels
+    (``gmm``, ``gmm`` against the transposed matrices, ``tgmm``) inside
+    Mosaic's default VMEM, no ``ragged-dot`` and no copy of the matrices in
+    another layout."""
+    from lance_distributed_training_tpu.ops import grouped
+
+    rows, groups, k, n = shape
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(xs, w, sizes, ct):
+        y, back = jax.vjp(lambda xs, w: grouped.kernel_product(
+            xs, w, sizes, tiling), xs, w)
+        return y, *back(ct)
+
+    compiled = jax.jit(both).lower(
+        spec(rows, k), spec(groups, k, n), spec(groups, dtype=jnp.int32),
+        spec(rows, n)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "ragged-dot" not in text
+    assert not re.search(rf"bf16\[{groups},{n},{k}\]", text)
+
+
 @pytest.mark.parametrize("rows,seq,hidden,vocab,tied", [
     (1, 8192, 2560, 25008, True),  # c4-phi4flash-vp8-prepacked-8k's head
     (2, 4096, 2048, 50304, False),  # c4-olmoe-prepacked-4k's

@@ -108,3 +108,40 @@ def test_moe_end_to_end_train(tmp_path):
         num_experts=2, model_parallelism=2, no_wandb=True, eval_at_end=False,
     ))
     assert np.isfinite(results["loss"])
+
+
+def test_dropless_layer_with_the_grouped_kernels_bound_is_its_plain_self(
+        monkeypatch):
+    """``DroplessMoE`` holding all its experts (OLMoE's branch), f32: the
+    three grouped products by ``ops/grouped.py``'s kernel form (the
+    library's Pallas grouped matmul, interpret mode) against
+    ``jax.lax.ragged_dot``, output and every gradient, and the gauge."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from lance_distributed_training_tpu.models.moe import DroplessMoE
+    from lance_distributed_training_tpu.obs.registry import default_registry
+    from lance_distributed_training_tpu.ops import grouped
+
+    layer = DroplessMoE(8, 256, 2, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.key(0), (1, 128, 128))
+    variables = layer.init(jax.random.key(1), x)
+
+    def run(params, x):
+        y, _ = layer.apply({"params": params}, x,
+                           mutable=["aux_loss", "moe_stats"])
+        return (y * y).sum(), y
+
+    gauge = default_registry().gauge("grouped_products_fused")
+    gauge.set(0.0)
+    step = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)
+    want = jax.jit(step)(variables["params"], x)
+    assert gauge.value == 0.0
+    monkeypatch.setattr(grouped, "grouped_tiling", lambda *shape, **_:
+                        grouped.Tiling(*((128, 128, 128),) * 3))
+    with pltpu.force_tpu_interpret_mode():
+        got = jax.block_until_ready(jax.jit(lambda p, x: step(p, x))(
+            variables["params"], x))
+    assert gauge.value == 1.0
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
