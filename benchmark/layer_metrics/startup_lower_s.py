@@ -1,0 +1,10 @@
+"""Seconds of lowering (jaxprs to MLIR modules, the Mosaic kernels among
+them) from ``train()``'s entry to the window's first edge: the union, thread
+by thread, of the ``jax.lower`` spans. The compile cache saves none of it."""
+
+from reduce import startup
+
+
+def read(ctx):
+    found = startup.to_edge(ctx, ("jax.lower",))
+    return None if found is None else startup.union_s(found)

@@ -416,41 +416,102 @@ def end_phase() -> None:
     default_tracer().end_phase()
 
 
-# -- XLA compiles, seen by the program itself --------------------------------
+# -- tracing, lowering and compiling, seen by the program itself --------------
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# JAX's duration event -> (span name, counter of its seconds)
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("jax.trace", "jax_trace_seconds_total"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("jax.lower", "jax_lower_seconds_total"),
+    "/jax/core/compile/backend_compile_duration":
+        ("xla.compile", "xla_compile_seconds_total"),
+}
+# What JAX records, on the compiling thread and inside the compile, about the
+# persistent cache (jax/_src/compiler.py, compile_or_get_cached). The first
+# fires for every program the cache is asked for, the second when it had it.
+# `/jax/compilation_cache/cache_misses` is no test for a miss: it fires only
+# when an entry is written, which a compile under
+# jax_persistent_cache_min_compile_time_secs never is.
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+# A trace or a lowering shorter than this leaves no span (the counters take
+# it): a model's step traces thousands of `jnp` functions inside its own
+# trace, microseconds each, which the step's span covers; together they would
+# push a whole start-up out of the ring.
+_MIN_TRACE_SPAN_S = 1e-3
 _COMPILE_LISTENER_ON = False  # jax.monitoring has no unregister: once a process
 
 
 def watch_xla_compiles() -> None:
-    """Register (once per process) a ``jax.monitoring`` listener that turns
-    every backend compile — or load from the persistent compile cache —
-    into an ``xla.compile`` span (attr ``fun_name``; parent = the phase or
-    span the compiling thread is inside, so the step number comes with it)
-    and into the counters ``xla_compiles_total`` /
-    ``xla_compile_seconds_total``. JAX reports the duration when the compile
-    ends, on the compiling thread: the span is back-dated from there."""
+    """Register (once per process) the ``jax.monitoring`` listeners that turn
+    what JAX does to a program before it can run into spans, back-dated from
+    JAX's duration events on the thread that did the work (parent = the
+    phase or span that thread is inside, so the step number comes with it):
+
+    * ``jax.trace`` and ``jax.lower`` (attr ``fun_name``): a function traced
+      to a jaxpr, a jaxpr lowered to an MLIR module. A ``jit`` met inside
+      another's trace is traced inside it and ends first, so traces nest by
+      interval on their thread: a reader takes the union of a kind's
+      intervals, thread by thread, never the sum of durations. One under a
+      millisecond leaves no span.
+    * ``xla.compile`` (attrs ``fun_name``, ``cache``, on a hit
+      ``retrieval_s``): the backend compile or the load from the persistent
+      compile cache. ``cache`` is ``hit`` or ``miss`` where JAX asked a
+      cache directory for the program, ``off`` where it did not (no
+      directory, or a program JAX does not cache).
+
+    Counters: ``xla_compiles_total`` / ``xla_compile_seconds_total`` (every
+    ``xla.compile``), ``compile_cache_hits_total`` /
+    ``compile_cache_misses_total``, ``jax_trace_seconds_total`` /
+    ``jax_lower_seconds_total`` (plain sums: a nested trace counts in its
+    own and in the one around it)."""
     global _COMPILE_LISTENER_ON
     with _DEFAULT_LOCK:
         if _COMPILE_LISTENER_ON:
             return
         _COMPILE_LISTENER_ON = True
-    import jax.monitoring
+    import jax
 
     from .registry import default_registry
 
+    asked = threading.local()  # .cache: this thread's compile in progress
+
+    def on_event(event: str, **kw) -> None:
+        if event == _CACHE_REQUEST:
+            # JAX asks whenever caching is enabled, a directory or not
+            asked.cache = {"cache": "miss"} \
+                if jax.config.jax_compilation_cache_dir else None
+        elif event == _CACHE_HIT and getattr(asked, "cache", None):
+            asked.cache["cache"] = "hit"
+
     def on_duration(event: str, duration: float, **kw) -> None:
-        if event != _COMPILE_EVENT:
+        if event == _CACHE_RETRIEVAL and getattr(asked, "cache", None):
+            asked.cache["retrieval_s"] = round(duration, 6)
+            return
+        if event not in _JAX_EVENTS:
             return
         end = time.monotonic_ns()
-        default_tracer().record_complete(
-            "xla.compile", end - int(duration * 1e9), end,
-            fun_name=str(kw.get("fun_name", "?")),
-        )
+        name, seconds_total = _JAX_EVENTS[event]
         registry = default_registry()
-        registry.counter("xla_compiles_total").inc()
-        registry.counter("xla_compile_seconds_total").inc(duration)
+        registry.counter(seconds_total).inc(duration)
+        attrs = {}
+        if name == "xla.compile":
+            attrs = getattr(asked, "cache", None) or {"cache": "off"}
+            asked.cache = None
+            registry.counter("xla_compiles_total").inc()
+            if attrs["cache"] != "off":
+                registry.counter(
+                    "compile_cache_hits_total" if attrs["cache"] == "hit"
+                    else "compile_cache_misses_total").inc()
+        elif duration < _MIN_TRACE_SPAN_S:
+            return
+        default_tracer().record_complete(
+            name, end - int(duration * 1e9), end,
+            fun_name=str(kw.get("fun_name", "?")), **attrs)
 
+    jax.monitoring.register_event_listener(on_event)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 

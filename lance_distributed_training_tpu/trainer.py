@@ -1065,7 +1065,14 @@ def maybe_enable_compile_cache(platform: str, *,
     says otherwise: its persistent cache stores AOT machine code whose
     round-trip is unsound for shard_map collective programs and across
     hosts (see tests/conftest.py).
+
+    The cache's key and directory are settled here, so this is also where
+    the process starts to record what it traces, lowers and compiles
+    (``obs/spans.watch_xla_compiles``, once a process): a caller that
+    compiles before ``train()`` (the benchmark's model check) has those
+    programs in the span file too, outside every phase.
     """
+    watch_xla_compiles()
     if os.environ.get("LDT_TRACE_PATH"):
         # A traced run reads the step's scopes (forward, optimizer, ...) from
         # the executable's metadata, and JAX leaves metadata out of the
@@ -1082,6 +1089,41 @@ def maybe_enable_compile_cache(platform: str, *,
         return None
     jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
     return _CHECKOUT_CACHE_DIR
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since the OS started this process: its start time in
+    ``/proc/self/stat`` (clock ticks since boot) against the boot clock.
+    None where the OS has no such file."""
+    try:
+        with open("/proc/self/stat") as f:
+            # after "pid (comm)", which may hold spaces: state is field 3,
+            # starttime field 22
+            started = int(f.read().rsplit(")", 1)[1].split()[19])
+        return round(time.clock_gettime(time.CLOCK_BOOTTIME)
+                     - started / os.sysconf("SC_CLK_TCK"), 3)
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def _cache_census(cache_dir: Optional[str]) -> dict:
+    """What the compile cache holds as ``train()`` is entered (one
+    ``scandir``), and whether this process keys it by metadata too."""
+    entries = size = 0
+    if cache_dir:
+        try:
+            with os.scandir(cache_dir) as found:  # ldt: ignore[LDT003] -- counted and summed: the order never shows
+                for entry in found:
+                    entries += 1
+                    size += entry.stat().st_size
+        except OSError:
+            pass  # no directory yet: JAX makes it at its first write
+    return {
+        "cache_dir": cache_dir, "cache_entries": entries,
+        "cache_bytes": size,
+        "cache_key_metadata": bool(
+            jax.config.jax_compilation_cache_include_metadata_in_key),
+    }
 
 
 class _CkptJournal:
@@ -1121,14 +1163,14 @@ def train(config: TrainConfig) -> dict:
     loop's flat ``train.*`` phases, ``train.shutdown`` at the end. They tile
     the thread's time, so a trace reader never has to guess what the loop
     was doing in a gap."""
-    obs_phase("startup.devices")
+    entry = obs_phase("startup.devices", process_age_s=_process_age_s())
     try:
-        return _train(config)
+        return _train(config, entry)
     finally:
         end_phase()
 
 
-def _train(config: TrainConfig) -> dict:
+def _train(config: TrainConfig, entry: dict) -> dict:
     if config.val_fraction:
         # Validate the combo BEFORE any dataset I/O so a bad config fails
         # with its own message, not a dataset-open error.
@@ -1265,9 +1307,10 @@ def _train(config: TrainConfig) -> dict:
     devices = jax.devices()
     if config.no_ddp:
         devices = devices[:1]
-    cache_dir = maybe_enable_compile_cache(devices[0].platform,
-                                           enabled=config.compile_cache)
-    watch_xla_compiles()  # xla.compile spans + xla_compiles_total
+    # places the cache and turns on the jax.trace / jax.lower / xla.compile
+    # spans and their counters, if a caller has not already
+    entry.update(_cache_census(maybe_enable_compile_cache(
+        devices[0].platform, enabled=config.compile_cache)))
     mesh = get_mesh(
         devices,
         model_parallelism=config.model_parallelism,
@@ -1536,8 +1579,8 @@ def _train(config: TrainConfig) -> dict:
         # the exporter port, the metrics_port log write, or a pool-spawn
         # error must all still run the finally (logger/ckpt close, and the
         # exporter's bound port once started).
-        logger.log({**device_info, "compile_cache_dir": cache_dir,
-                    **_kernel_paths(task, config)}, to_wandb=False)
+        logger.log({**device_info, **_kernel_paths(task, config)},
+                   to_wandb=False)
         if config.metrics_port is not None and jax.process_index() == 0:
             from .obs.http import MetricsHTTPServer
 
@@ -1907,6 +1950,113 @@ class _SlowIntervals:
         }}, to_wandb=False)
 
 
+def _union_s(intervals) -> float:
+    """Seconds covered by ``(thread, start_ns, end_ns)`` intervals: the union
+    on each thread, summed over threads. Traces nest (a ``jit`` met inside
+    another's trace), so their durations may not simply be added."""
+    total, reach = 0, {}
+    for thread, start, end in sorted(intervals):
+        start = max(start, reach.get(thread, start))
+        if end > start:
+            total += end - start
+            reach[thread] = end
+    return total / 1e9
+
+
+class _StartupRecord:
+    """One ``startup`` line a run, traced or not: where the time from the
+    process's start to the end of the first ``train.step`` went. Summed once,
+    when that step's dispatch has returned, from what the tracer's ring holds
+    anyway (as ``_SlowIntervals`` does), and written at the first log point:
+
+    * ``process_age_s`` at ``train()``'s entry and the compile cache found
+      there (the attributes of the ``startup.devices`` phase);
+    * ``phases``: seconds of the loop thread's phases from entry to the end
+      of the first ``train.step``, by name; they tile, so they add up to
+      ``entry_to_first_step_s``;
+    * ``in_train`` and ``before_train`` (what the process did before it
+      entered ``train()``: a caller's own programs): seconds of tracing,
+      lowering, compiling and loading from the cache, each the union of its
+      spans on each thread, and how many programs hit, missed or went past
+      the cache;
+    * ``programs``: everything of ``PROGRAM_S`` or more, in order, with
+      ``at_s`` from entry (negative: before ``train()``)."""
+
+    PROGRAM_S = 0.5
+    KINDS = {"jax.trace": "trace", "jax.lower": "lower",
+             "xla.compile": "compile"}
+
+    def __init__(self):
+        self.due = True  # the first train.step has not returned yet
+        self._line = None
+
+    def first_step_done(self) -> None:
+        self.due = False
+        tracer = default_tracer()
+        thread = threading.get_ident() % 2**31
+        spans = tracer.spans()
+        own = [s for s in spans if s.thread_id == thread and s.parent_id == 0
+               and s.name.startswith(("startup.", "train."))]
+        entries = [s for s in own if s.name == "startup.devices"]
+        if not entries:
+            return  # the ring is too short for this start-up: no record
+        entry = entries[-1]
+        own = [s for s in own if s.start_ns >= entry.start_ns]
+        end = next(s.end_ns for s in own if s.name == "train.step")
+        own = [s for s in own if s.end_ns <= end]
+        phases: dict = {}
+        for s in own:
+            phases[s.name] = phases.get(s.name, 0) + s.end_ns - s.start_ns
+        programs = [s for s in spans if s.name in self.KINDS
+                    and s.start_ns < end]
+        before = [s for s in programs if s.end_ns <= entry.start_ns]
+        attrs = dict(entry.attrs or {})
+        self._line = {"startup": {
+            "process_age_s": attrs.pop("process_age_s", None),
+            "entry_to_first_step_s": round((end - entry.start_ns) / 1e9, 6),
+            "phases": {k: round(v / 1e9, 6) for k, v in phases.items()},
+            "in_train": self._sums(
+                [s for s in programs if s.end_ns > entry.start_ns],
+                entry.start_ns, end),
+            "before_train": self._sums(before, 0, entry.start_ns),
+            "programs": [
+                {"fun_name": s.attrs["fun_name"], "kind": self.KINDS[s.name],
+                 **({"cache": s.attrs["cache"]} if "cache" in s.attrs
+                    else {}),
+                 "seconds": round((s.end_ns - s.start_ns) / 1e9, 3),
+                 "at_s": round((s.start_ns - entry.start_ns) / 1e9, 3)}
+                for s in sorted(programs, key=lambda s: s.start_ns)
+                if s.end_ns - s.start_ns >= self.PROGRAM_S * 1e9],
+            "cache": {k[len("cache_"):]: v for k, v in attrs.items()
+                      if k.startswith("cache_")},
+            "spans_dropped": tracer.dropped,
+        }}
+
+    @staticmethod
+    def _sums(programs, lo: int, hi: int) -> dict:
+        def seconds(spans) -> float:
+            return round(_union_s((s.thread_id, max(s.start_ns, lo),
+                                   min(s.end_ns, hi)) for s in spans), 6)
+
+        compiles = [s for s in programs if s.name == "xla.compile"]
+        loaded = [s for s in compiles if s.attrs["cache"] == "hit"]
+        answers = [s.attrs["cache"] for s in compiles]
+        return {
+            "trace_s": seconds(s for s in programs if s.name == "jax.trace"),
+            "lower_s": seconds(s for s in programs if s.name == "jax.lower"),
+            "compile_s": seconds(s for s in compiles
+                                 if s.attrs["cache"] != "hit"),
+            "cache_load_s": seconds(loaded),
+            "hits": len(loaded), "misses": answers.count("miss"),
+            "off": answers.count("off"),
+        }
+
+    def write(self, logger) -> None:
+        line, self._line = self._line, None
+        if line is not None:
+            logger.log(line, to_wandb=False)
+
+
 def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 eval_step, logger, timer, worker_pool, ckpt, start_epoch,
                 total_start, n_devices, results, global_step, profiling,
@@ -1920,6 +2070,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
     step_stats = _StepStats()
     flight = _StepsInFlight()
     slow = _SlowIntervals()
+    startup = _StartupRecord()
     # Device-decode transform stage (--device_decode): one jitted kernel
     # call replacing a batch's coefficient pages with the decoded image —
     # device work dispatched from the consumer thread, so it overlaps the
@@ -2111,6 +2262,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
         with obs_span("loop.log_write", step=point.step):
             logger.log(entry, to_wandb=False)
         slow.check(point.step, epoch, in_flight_min, logger)
+        startup.write(logger)
         obs_phase("train.bookkeep")
 
     def settle(point: _DrainPoint, ahead: bool) -> None:
@@ -2285,6 +2437,8 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
                 at_step["in_flight_after"] = flight.dispatched(loss)
                 gnorm = extras.pop(0) if config.log_grad_norm else None
                 obs_phase("train.bookkeep")
+                if startup.due:
+                    startup.first_step_done()
                 with obs_span("loop.loss_sum", step=global_step):
                     loss_sum = loss_sum + loss
                 with obs_span("loop.stats_add", step=global_step):
@@ -2472,6 +2626,7 @@ def _train_loop(config, dataset, val_dataset, mesh, state, rng, train_step,
             break
 
     obs_phase("train.shutdown")  # final eval, then train()'s teardown
+    startup.write(logger)  # a run that never came to a log point
     results["history"] = history
     for name in _EXPERT_GAUGES:
         if name in results:  # as the traced steps left it

@@ -766,6 +766,366 @@ def test_span_file_is_written_in_batches(tmp_path, by):
     assert tr.spans()[-1].name == "after close"
 
 
+# -- start-up read from inside (PR 51) ----------------------------------------
+
+JAX_KINDS = ("jax.trace", "jax.lower", "xla.compile")
+
+
+def _union_ns(spans):
+    """Nanoseconds some span of the list is open, by a sweep over start and
+    end points (the program's own sum walks sorted intervals)."""
+    points = sorted([(s.start_ns, 1) for s in spans]
+                    + [(s.end_ns, -1) for s in spans],
+                    key=lambda p: (p[0], -p[1]))
+    depth = total = last = 0
+    for t, step in points:
+        if depth:
+            total += t - last
+        depth, last = depth + step, t
+    return total
+
+
+@pytest.fixture()
+def compile_cache(tmp_path):
+    """A persistent compile cache under ``tmp_path`` that takes every program,
+    however quick its compile; afterwards none again (see conftest.py: the
+    suite runs without one, and nothing but the small element-wise programs
+    of the test is ever read back from this one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    min_s = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    compilation_cache.reset_cache()
+    try:
+        yield tmp_path / "cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
+        compilation_cache.reset_cache()
+
+
+def _fresh(name):
+    """A new function object a call, the same program every time: a fresh
+    ``jit`` of it is traced, lowered and handed to the backend anew (the
+    in-memory caches go by the object), under the same cache key."""
+    def body(x):
+        for i in range(40):  # long enough to trace and lower to leave spans
+            x = jnp.cos(x) * 5 + i
+        return x
+
+    body.__name__ = body.__qualname__ = name
+    return jax.jit(body)
+
+
+def test_compile_leaves_trace_lower_compile_and_what_the_cache_said(
+        tracer, compile_cache):
+    """XLA:CPU takes part in the persistent cache under this JAX, so the hit
+    is a real one."""
+    spans_mod.watch_xla_compiles()
+    x = np.arange(9.0, dtype=np.float32)  # no program of its own
+    tracer.phase("cold")
+    _fresh("cached_once")(x).block_until_ready()
+    tracer.phase("warm")
+    _fresh("cached_once")(x).block_until_ready()
+    tracer.end_phase()
+    got = tracer.spans()
+    phases = {s.name: s for s in got if s.name in ("cold", "warm")}
+    own = {name: [s for s in got if s.parent_id == phase.span_id
+                  and "cached_once" in s.attrs["fun_name"]]
+           for name, phase in phases.items()}
+    for name, outcome in (("cold", "miss"), ("warm", "hit")):
+        by_kind = {s.name: s for s in own[name]}
+        assert sorted(by_kind) == sorted(JAX_KINDS), (name, own[name])
+        assert len(own[name]) == 3
+        assert by_kind["xla.compile"].attrs["cache"] == outcome
+        assert ("retrieval_s" in by_kind["xla.compile"].attrs) \
+            == (outcome == "hit")
+        assert "cache" not in by_kind["jax.trace"].attrs
+        # in the order JAX does them, inside the phase of their thread
+        assert phases[name].start_ns <= by_kind["jax.trace"].end_ns \
+            <= by_kind["jax.lower"].end_ns <= by_kind["xla.compile"].end_ns \
+            <= phases[name].end_ns
+    assert own["warm"][-1].attrs["retrieval_s"] >= 0
+    assert any(f.name.startswith("jit_cached_once")
+               for f in compile_cache.iterdir())
+
+
+def test_compile_without_a_cache_directory_reads_off(tracer):
+    spans_mod.watch_xla_compiles()
+    assert not jax.config.jax_compilation_cache_dir
+    hits = default_registry().counter("compile_cache_hits_total")
+    misses = default_registry().counter("compile_cache_misses_total")
+    before = hits.value, misses.value
+    _fresh("never_cached")(np.arange(5.0, dtype=np.float32))
+    (compiled,) = [s for s in tracer.spans() if s.name == "xla.compile"]
+    assert compiled.attrs == {"fun_name": "jit(never_cached)", "cache": "off"}
+    assert (hits.value, misses.value) == before
+
+
+def test_cache_events_stay_with_the_thread_that_compiles(tracer):
+    """JAX's events fed by hand: what the cache said to one thread's compile
+    rides that thread's span and no other's, and is used once."""
+    import jax.monitoring as monitoring
+
+    spans_mod.watch_xla_compiles()
+    jax.config.update("jax_compilation_cache_dir", "/nonexistent/ldt-test")
+    try:
+        asked = threading.Event()
+        done = threading.Event()
+
+        def other():
+            monitoring.record_event(spans_mod._CACHE_REQUEST)
+            monitoring.record_event(spans_mod._CACHE_HIT)
+            monitoring.record_event_duration_secs(
+                spans_mod._CACHE_RETRIEVAL, 0.25)
+            asked.set()
+            assert done.wait(timeout=30)
+            for fun_name in ("theirs", "theirs_again"):
+                monitoring.record_event_duration_secs(
+                    "/jax/core/compile/backend_compile_duration", 0.5,
+                    fun_name=fun_name)
+
+        t = threading.Thread(target=other)
+        t.start()
+        assert asked.wait(timeout=30)
+        monitoring.record_event(spans_mod._CACHE_REQUEST)
+        monitoring.record_event_duration_secs(
+            "/jax/core/compile/backend_compile_duration", 0.125,
+            fun_name="mine")
+        done.set()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)
+    got = {s.attrs["fun_name"]: s for s in tracer.spans()}
+    assert got["mine"].attrs["cache"] == "miss"
+    assert got["theirs"].attrs == {"fun_name": "theirs", "cache": "hit",
+                                   "retrieval_s": 0.25}
+    assert got["theirs_again"].attrs["cache"] == "off"
+    assert got["mine"].thread_id != got["theirs"].thread_id
+    # back-dated from the event by its duration
+    assert got["theirs"].end_ns - got["theirs"].start_ns == 500_000_000
+
+
+def test_a_jit_inside_a_trace_nests_and_the_union_is_the_outer_one(tracer):
+    spans_mod.watch_xla_compiles()
+    def doubled(x):
+        for i in range(60):  # a trace of more than a millisecond
+            x = x * 2 + i
+        return x
+
+    inner = jax.jit(doubled)
+
+    def outer_never_seen(x):
+        return inner(x).sum() + inner(x[:3]).sum()  # two shapes: two traces
+
+    tracer.phase("tracing")
+    jax.jit(outer_never_seen).lower(np.arange(6.0, dtype=np.float32))
+    tracer.end_phase()
+    got = tracer.spans()
+    holder = next(s for s in got if s.name == "tracing")
+    traces = [s for s in got if s.name == "jax.trace"
+              and s.attrs["fun_name"] in ("outer_never_seen", "doubled")]
+    outer = next(s for s in traces if s.attrs["fun_name"] != "doubled")
+    nested = [s for s in traces if s is not outer]
+    assert len(nested) == 2
+    assert {s.parent_id for s in traces} == {holder.span_id}
+    assert {s.thread_id for s in traces} == {holder.thread_id}
+    for s in nested:  # ended first, and lies inside the outer one
+        assert outer.start_ns <= s.start_ns and s.end_ns <= outer.end_ns
+    assert sum(s.end_ns - s.start_ns for s in traces) \
+        > outer.end_ns - outer.start_ns
+    assert _union_ns(traces) == outer.end_ns - outer.start_ns
+    # lowered once, as one module; nothing compiled
+    assert [s.attrs["fun_name"] for s in got if s.name == "jax.lower"] \
+        == ["jit(outer_never_seen)"]
+    assert not [s for s in got if s.name == "xla.compile"]
+
+
+def test_a_trace_under_a_millisecond_counts_and_leaves_no_span(tracer):
+    """JAX's events fed by hand: the thousands of `jnp` functions a step
+    traces inside its own trace would push a start-up out of the ring."""
+    import jax.monitoring as monitoring
+
+    spans_mod.watch_xla_compiles()
+    registry = default_registry()
+    events = {"jax_trace_seconds_total":
+              "/jax/core/compile/jaxpr_trace_duration",
+              "jax_lower_seconds_total":
+              "/jax/core/compile/jaxpr_to_mlir_module_duration",
+              "xla_compile_seconds_total":
+              "/jax/core/compile/backend_compile_duration"}
+    before = {n: registry.counter(n).value for n in events}
+    for event in events.values():
+        monitoring.record_event_duration_secs(event, 0.0005, fun_name="tiny")
+        monitoring.record_event_duration_secs(event, 0.002, fun_name="long")
+    for name in events:
+        assert registry.counter(name).value - before[name] \
+            == pytest.approx(0.0025)
+    assert sorted((s.name, s.attrs["fun_name"]) for s in tracer.spans()) == [
+        ("jax.lower", "long"), ("jax.trace", "long"),
+        ("xla.compile", "long"), ("xla.compile", "tiny")]
+
+
+def test_the_four_counters_match_the_spans(tracer, compile_cache):
+    spans_mod.watch_xla_compiles()
+    registry = default_registry()
+    names = ("compile_cache_hits_total", "compile_cache_misses_total",
+             "jax_trace_seconds_total", "jax_lower_seconds_total",
+             "xla_compiles_total", "xla_compile_seconds_total")
+    before = {n: registry.counter(n).value for n in names}
+    x = np.arange(11.0, dtype=np.float32)
+    for _ in range(3):  # a miss, then two hits
+        _fresh("counted")(x).block_until_ready()
+    got = tracer.spans()
+    rose = {n: registry.counter(n).value - before[n] for n in names}
+    compiles = [s for s in got if s.name == "xla.compile"]
+    assert rose["compile_cache_misses_total"] == sum(
+        1 for s in compiles if s.attrs["cache"] == "miss") == 1
+    assert rose["compile_cache_hits_total"] == sum(
+        1 for s in compiles if s.attrs["cache"] == "hit") == 2
+    assert rose["xla_compiles_total"] == len(compiles) == 3
+    for counter, name in (("jax_trace_seconds_total", "jax.trace"),
+                          ("jax_lower_seconds_total", "jax.lower"),
+                          ("xla_compile_seconds_total", "xla.compile")):
+        spans_s = sum(s.end_ns - s.start_ns for s in got
+                      if s.name == name) / 1e9
+        # a span's times are whole nanoseconds, the counter's a float; the
+        # counters also take the `jnp` functions traced in microseconds
+        # inside `counted`'s trace, which leave no span
+        short = 0.0 if name == "xla.compile" else 40 * 3 * 1e-3
+        slack = 1e-6 * len(got)
+        assert spans_s - slack <= rose[counter] <= spans_s + short + slack
+        assert spans_s > 0
+
+
+def test_placing_the_cache_twice_registers_one_listener(monkeypatch):
+    """``maybe_enable_compile_cache`` is where the listeners go on: the
+    benchmark calls it before its model check, ``train()`` calls it again."""
+    import jax.monitoring as monitoring
+
+    from lance_distributed_training_tpu import trainer
+
+    registered = []
+    monkeypatch.setattr(spans_mod, "_COMPILE_LISTENER_ON", False)
+    monkeypatch.setattr(monitoring, "register_event_listener",
+                        lambda f: registered.append(("event", f)))
+    monkeypatch.setattr(monitoring, "register_event_duration_secs_listener",
+                        lambda f: registered.append(("duration", f)))
+    monkeypatch.delenv("LDT_TRACE_PATH", raising=False)
+    assert trainer.maybe_enable_compile_cache("cpu") is None
+    assert trainer.maybe_enable_compile_cache("cpu") is None
+    spans_mod.watch_xla_compiles()
+    assert sorted(kind for kind, _ in registered) == ["duration", "event"]
+
+
+@pytest.mark.parametrize("case", ["untraced", "traced", "no_log_point"])
+def test_train_writes_one_startup_record(case, image_dataset, tmp_path,
+                                         monkeypatch):
+    """One ``startup`` line a run, with ``LDT_TRACE_PATH`` unset too, summed
+    from the ring: its phases tile entry to the end of the first
+    ``train.step``. A run with no log point writes it as it shuts down."""
+    import json
+
+    from lance_distributed_training_tpu import trainer
+
+    metadata_in_key = jax.config.jax_compilation_cache_include_metadata_in_key
+    if case == "traced":
+        monkeypatch.setenv("LDT_TRACE_PATH", str(tmp_path / "spans.jsonl"))
+    else:
+        monkeypatch.delenv("LDT_TRACE_PATH", raising=False)
+    monkeypatch.setenv("LDT_METRICS_PATH", str(tmp_path / "metrics.jsonl"))
+    fresh = SpanTracer(capacity=1 << 16)
+    monkeypatch.setattr(spans_mod, "_DEFAULT", fresh)
+    try:
+        trainer.train(_image_run_config(
+            image_dataset, epochs=1,
+            log_every=0 if case == "no_log_point" else 2))
+    finally:
+        fresh.close()
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          metadata_in_key)
+    lines = [json.loads(x) for x in open(tmp_path / "metrics.jsonl")]
+    (at,) = [i for i, ln in enumerate(lines) if "startup" in ln]
+    record = lines[at]["startup"]
+    if case == "no_log_point":
+        assert not [ln for ln in lines if "images_per_sec_dispatch" in ln]
+    else:  # right behind the first progress line
+        assert [i for i, ln in enumerate(lines)
+                if "images_per_sec_dispatch" in ln][0] == at - 1
+    assert set(record) == {
+        "process_age_s", "entry_to_first_step_s", "phases", "in_train",
+        "before_train", "programs", "cache", "spans_dropped"}
+    got = fresh.spans()
+    entry = next(s for s in got if s.name == "startup.devices")
+    first_step = next(s for s in got if s.name == "train.step")
+    assert record["entry_to_first_step_s"] == pytest.approx(
+        (first_step.end_ns - entry.start_ns) / 1e9, abs=1e-5)
+    assert sum(record["phases"].values()) == pytest.approx(
+        record["entry_to_first_step_s"], abs=1e-3)
+    assert list(record["phases"]) == [
+        "startup.devices", "startup.dataset", "startup.state",
+        "startup.loader", "train.loader", "train.bookkeep", "train.step"]
+    assert record["phases"]["train.step"] == pytest.approx(
+        (first_step.end_ns - first_step.start_ns) / 1e9, abs=1e-5)
+    # the entry phase carries the process's age and the cache as found
+    assert entry.attrs["process_age_s"] == record["process_age_s"] > 0
+    assert record["cache"] == {
+        "dir": None, "entries": 0, "bytes": 0,
+        "key_metadata": case == "traced"}
+    assert {k: entry.attrs[k] for k in entry.attrs if k != "process_age_s"} \
+        == {"cache_" + k: v for k, v in record["cache"].items()}
+    sums = record["in_train"]
+    assert set(sums) == set(record["before_train"]) == {
+        "trace_s", "lower_s", "compile_s", "cache_load_s", "hits", "misses",
+        "off"}
+    inside = [s for s in got if s.name in JAX_KINDS
+              and entry.start_ns <= s.start_ns and s.end_ns <= first_step.end_ns
+              and s.thread_id == entry.thread_id]
+    others = [s for s in got if s.name in JAX_KINDS
+              and s.thread_id != entry.thread_id
+              and s.start_ns < first_step.end_ns]
+    if not others:  # the loop thread's alone: the unions, by another route
+        for key, name in (("trace_s", "jax.trace"), ("lower_s", "jax.lower"),
+                          ("compile_s", "xla.compile")):
+            assert sums[key] == pytest.approx(_union_ns(
+                [s for s in inside if s.name == name]) / 1e9, abs=1e-5)
+    assert sums["hits"] == sums["misses"] == 0 and sums["off"] >= 2
+    assert sums["cache_load_s"] == 0.0
+    assert 0 < sums["compile_s"] < record["entry_to_first_step_s"]
+    assert 0 < sums["trace_s"] and 0 < sums["lower_s"]
+    # the step's own compile is inside its phase, and long or not, every
+    # program listed took half a second and says what it was
+    for program in record["programs"]:
+        assert program["seconds"] >= 0.5
+        assert program["kind"] in ("trace", "lower", "compile")
+        assert ("cache" in program) == (program["kind"] == "compile")
+    assert record["spans_dropped"] == 0
+    if case == "traced":
+        names = {json.loads(x)["name"]
+                 for x in open(tmp_path / "spans.jsonl")}
+        assert set(JAX_KINDS) <= names
+
+
+def test_benchmark_startup_readers_check_passes():
+    """The twelve readers of start-up read the hand-made spans as worked out
+    by eye and the recorded ones as a sweep gives them, tile, and read None
+    on a span file from before PR 51."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, "benchmark", "check_startup.py")],
+        cwd=repo, capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == "startup ok"
+
+
 def test_benchmark_loop_readers_check_passes():
     """The five readers of the loop's calls read the hand-made spans as
     worked out by eye, and None on a span file from before PR 35."""
