@@ -27,9 +27,19 @@ type, as each cotangent is. Two forms:
 :func:`grouped_product` chooses between them from the platform, the devices
 and the call's static shapes (:func:`grouped_tiling`), as :mod:`.rows` and
 :mod:`.conv` do: no flag, and never from the step's group sizes. The table is
-the rule: an entry is a shape at which ``scripts/grouped_products_sweep.py``
-timed the three kernels ahead of XLA's three together on the v5e (PERF.md
-section 6, PR 50), with the tilings it found.
+the rule, and this is the rule of admission to it: a shape gets an entry iff
+``scripts/grouped_products_sweep.py`` timed the layer's **whole program** on
+the v5e (the three products, their gate, forward and ``value_and_grad`` in
+one program, the f32 -> bf16 casts and the layout copies inside) under the
+kernels, at the entry's tilings and with every kernel under 14 MiB of VMEM,
+ahead of the same program under XLA's kernel by :data:`AHEAD` or more **on
+even groups and on the cell's skew alike**, and the shape's cell then read
+the gain. Single forms find the tilings and admit nothing: inside a layer
+XLA's kernel also pays a layout copy of each matrix for each form it
+differentiates into, which a form timed alone does not show. The file an
+entry rests on is committed (``scripts/grouped_sweep/<shape>.jsonl``) and
+``tests/test_grouped.py`` holds the table to it (PERF.md section 6, PRs 50
+and 52).
 """
 
 from __future__ import annotations
@@ -40,9 +50,10 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["Tiling", "TILINGS", "fits", "grouped_product", "grouped_tiling",
-           "kernel_product"]
+__all__ = ["AHEAD", "Tiling", "TILINGS", "fits", "grouped_product",
+           "grouped_tiling", "kernel_product"]
 
+AHEAD = 0.05  # the rule of admission's margin over XLA's whole-layer time
 _LANES = 128
 _TYPES = (jnp.bfloat16, jnp.float32)  # what the library's kernels multiply
 
@@ -67,6 +78,24 @@ TILINGS: dict = {
                                    (128, 1024, 1408)),
     (12288, 8, 1408, 2048): Tiling((128, 1408, 2048), (128, 2048, 1408),
                                    (128, 1408, 1024)),
+    # OLMoE-1B-7B's list of two rows of 4,096 tokens, all 64 experts held
+    # and every row live: groups of 1,024 rows on average, rows in tiles of
+    # 256, the matrices' tile a whole matrix but ``tgmm``'s, which is half
+    (65536, 64, 2048, 1024): Tiling((256, 2048, 1024), (256, 1024, 2048),
+                                    (256, 1024, 1024)),
+    (65536, 64, 1024, 2048): Tiling((256, 1024, 2048), (256, 2048, 1024),
+                                    (256, 1024, 1024)),
+    # Qwen3-Next-80B-A3B's usual list as one rank of sixteen holds it: 32
+    # experts of 512 columns, whole matrices. ``gmm`` over K = 512 alone
+    # loses to XLA's kernel (0.69 : 0.53 ms) and the layer whole still wins
+    (20480, 32, 2048, 512): Tiling((256, 2048, 512), (128, 512, 2048),
+                                   (256, 2048, 512)),
+    (20480, 32, 512, 2048): Tiling((128, 512, 2048), (256, 2048, 512),
+                                   (256, 512, 2048)),
+    # ZAYA1-8B's usual list as one rank of two holds it: square experts, so
+    # gate, up and down are one call; half a matrix a tile
+    (8192, 8, 2048, 2048): Tiling((128, 1024, 2048), (256, 2048, 1024),
+                                  (256, 1024, 1024)),
 }
 
 

@@ -31,11 +31,15 @@ forward and ``value_and_grad`` in one program (XLA merges the repeated
 forward, so: each form once) through ``jax.lax.ragged_dot`` and through
 ``ops/grouped.py``'s kernel form at the fastest tilings that leave the
 kernels room (:func:`vmem_bytes`): what the casts, the layout copies and the
-zeroing cost when the products share a program. Group sizes are the live
-rows spread over the held experts evenly (``even``) or so that the largest
-group over the mean is what the cell's ledger line reads (``skewed``: a
-seeded softmax of normal draws, sharpened until it is); the rows past the
-last group are dead.
+zeroing cost when the products share a program. **They are the verdict**
+(:func:`verdict`; ``ops/grouped.py``'s rule of admission): a shape gets an
+entry in ``TILINGS`` iff the kernels' whole-layer program is ahead of XLA's
+by ``ops/grouped.py``'s ``AHEAD`` on even groups and on the cell's skew
+alike; the single forms are the way to find the tilings. Group sizes are the
+live rows spread over the held experts evenly (``even``) or so that the
+largest group over the mean is what the cell's ledger line reads
+(``skewed``: a seeded softmax of normal draws, sharpened until it is); the
+rows past the last group are dead.
 
     chiprun --chips 1 --timeout 1800 -- python3 scripts/grouped_products_sweep.py
     python3 scripts/grouped_products_sweep.py --compile_only   # no chip:
@@ -43,9 +47,15 @@ last group are dead.
 
 Times are device times from the trace's ``XLA Modules`` line, never the host
 clock, so the timing mode wants a TPU and fails without one. Writes
-``chiprun_out/grouped_sweep/<shape>.jsonl`` (a line a program) and
-``summary.md``. Not tier-1; ``PERF.md`` section 6 holds the table it gave,
-and ``ops/grouped.py``'s ``TILINGS`` the entries that came of it.
+``chiprun_out/grouped_sweep/<shape>.jsonl`` (a line a program) and appends
+the shape's table, with its verdict line, to ``summary.md``. The file an
+entry of ``TILINGS`` rests on is copied to ``scripts/grouped_sweep/`` and
+committed there (no commit takes anything under ``chiprun_out/``), where
+``tests/test_grouped.py`` holds the table to it. A
+stage's trace (tens of MiB) is deleted once its ``XLA Modules`` line is read
+unless ``--keep_traces``: the chip tool brings back 64 MiB and no more, so
+one shape a call. Not tier-1; ``PERF.md`` section 6 holds the tables it
+gave, and ``ops/grouped.py``'s ``TILINGS`` the entries that came of them.
 """
 
 from __future__ import annotations
@@ -227,9 +237,10 @@ def refusal(error: Exception) -> str:
 
 
 class Sweep:
-    def __init__(self, name, compile_only, sharding):
+    def __init__(self, name, compile_only, sharding, keep_traces=False):
         self.name, self.sizes = name, SHAPES[name]
         self.compile_only, self.sharding = compile_only, sharding
+        self.keep_traces = keep_traces
         self.rows: list = []
         self.arrays: dict = {}
         self.index = 0
@@ -296,7 +307,8 @@ class Sweep:
             times: dict = {}
             for name, _, dur in device_events(profile)["XLA Modules"]:
                 times.setdefault(name.split("(")[0], []).append(dur / 1e6)
-            shutil.rmtree(profile)  # tens of MiB a stage
+            if not self.keep_traces:  # tens of MiB a stage
+                shutil.rmtree(profile)
             for row in rows:
                 runs = times.get(f"jit_gp_{row['index']}", [])
                 row["calls"] = len(runs)
@@ -314,8 +326,8 @@ def in_room(row) -> bool:
         row["form"], row["tiling"]) <= VMEM_ROOM
 
 
-def sweep_shape(name, compile_only, sharding):
-    sweep = Sweep(name, compile_only, sharding)
+def sweep_shape(name, compile_only, sharding, keep_traces=False):
+    sweep = Sweep(name, compile_only, sharding, keep_traces)
     sizes = SHAPES[name]
     rows, _, _, hidden, width, _ = sizes
     wide = name == "moonlight"
@@ -356,13 +368,15 @@ def sweep_shape(name, compile_only, sharding):
             ({"form": "whole", "side": "", "tiling": kernel},
              lambda index: make_whole(index, sizes, kernel, sharding))])
         return sweep.rows, None
-    best = {}
+    best, next_best = {}, {}
     for side, form in itertools.product(("in", "out"), FORMS):
         ranked = sorted((r for r in timed if r.get("ms") and in_room(r)
                          and (r["side"], r["form"]) == (side, form)),
                         key=lambda r: r["ms"])
         if ranked:
             best[side, form] = tuple(ranked[0]["tiling"])
+            next_best[side, form] = tuple(ranked[min(1, len(ranked) - 1)][
+                "tiling"])
     # the winners and XLA's again on even groups, and the layer whole
     again = [product(form, side, t) for (side, form), t in best.items()
              if side in sides]
@@ -370,14 +384,20 @@ def sweep_shape(name, compile_only, sharding):
               for side, form in itertools.product(sides, FORMS)]
     both_sides(sweep.stage("forms", "even", again))
     if len(best) == 6:
-        kernel = {side: tuple(best[side, form] for form in FORMS)
-                  for side in ("in", "out")}
+        # the layer whole: XLA's, the fastest tiling of each form and, should
+        # Mosaic refuse that inside a layer's program, each form's runner-up
+        choices = [None]
+        for pick in (best, next_best):
+            kernel = {side: tuple(pick[side, form] for form in FORMS)
+                      for side in ("in", "out")}
+            if kernel not in choices:
+                choices.append(kernel)
         for kind in ("skewed", "even"):
             sweep.stage("whole", kind, [
                 ({"form": "whole", "side": "", "tiling": choice},
                  lambda index, c=choice: make_whole(index, sizes, c,
                                                     sharding))
-                for choice in (None, kernel)])
+                for choice in choices])
     return sweep.rows, best
 
 
@@ -393,6 +413,45 @@ def layer_ms(rows, kind, pick):
     return (2 * (2 * ms("in", "fwd") + ms("out", "fwd"))
             + 2 * (ms("in", "dlhs") + ms("in", "drhs"))
             + ms("out", "dlhs") + ms("out", "drhs"))
+
+
+def verdict(rows) -> dict:
+    """The rule of admission over a shape's rows: the whole-layer program's
+    ms through XLA's kernel and through the Pallas kernels on ``even`` and
+    ``skewed`` groups, ``entry`` (the kernels ahead by ``grouped.AHEAD`` on
+    both) and ``tiling``: of the kernels' choices timed, the admitted one
+    that is fastest over both, else the fastest."""
+    from lance_distributed_training_tpu.ops.grouped import AHEAD
+
+    whole = [r for r in rows if r["stage"] == "whole" and r.get("ms")]
+    xla = {r["sizes"]: r["ms"] for r in whole if not r["tiling"]}
+    ours: dict = {}
+    for r in whole:
+        if r["tiling"]:
+            ours.setdefault(json.dumps(r["tiling"]), {})[r["sizes"]] = r["ms"]
+    kinds = ("even", "skewed")
+
+    def admitted(ms):
+        return all(xla.get(k) and ms.get(k) and ms[k] <= (1 - AHEAD) * xla[k]
+                   for k in kinds)
+
+    ranked = sorted(ours.items(), key=lambda c: (
+        not admitted(c[1]), sum(c[1].get(k, float("inf")) for k in kinds)))
+    tiling, ms = ranked[0] if ranked else ("null", {})
+    return {**{k: (xla.get(k), ms.get(k)) for k in kinds},
+            "entry": admitted(ms), "tiling": json.loads(tiling)}
+
+
+def entries(name, rows) -> dict:
+    """What ``TILINGS`` may hold for a shape by its rows: nothing, or the
+    admitted tilings under the two calls' keys (gate and up; down)."""
+    says = verdict(rows)
+    if not says["entry"]:
+        return {}
+    built, groups, _, hidden, width, _ = SHAPES[name]
+    return {(built, groups, *kn): tuple(map(tuple, says["tiling"][side]))
+            for side, kn in (("in", (hidden, width)),
+                             ("out", (width, hidden)))}
 
 
 def summary(name, rows, best) -> str:
@@ -422,7 +481,13 @@ def summary(name, rows, best) -> str:
                              f"{'kernel' if r['tiling'] else 'XLA'} | "
                              + (f" | {r['ms']} | " if r["tiling"]
                                 else f"{r['ms']} | | ") + "|")
-    lines += ["", f"best tilings: {best}", ""]
+    says = verdict(rows)
+    lines += ["", f"best tilings: {best}", "",
+              f"{name}: whole, XLA | whole, kernel: " + ", ".join(
+                  f"{kind} {says[kind][0]} | {says[kind][1]}"
+                  for kind in ("even", "skewed"))
+              + f"; entry: {'yes' if says['entry'] else 'no'}"
+              + f" at {says['tiling']}", ""]
     return "\n".join(lines)
 
 
@@ -431,6 +496,9 @@ def main() -> None:
     parser.add_argument("--shapes", default=",".join(SHAPES))
     parser.add_argument("--compile_only", action="store_true",
                         help="compile for a described v5e; time nothing")
+    parser.add_argument("--keep_traces", action="store_true",
+                        help="leave each stage's trace under the output "
+                             "directory (tens of MiB a stage)")
     args = parser.parse_args()
     sharding = None
     if args.compile_only:
@@ -451,7 +519,8 @@ def main() -> None:
     os.makedirs(OUT, exist_ok=True)
     for name in args.shapes.split(","):
         t0 = time.monotonic()
-        rows, best = sweep_shape(name, args.compile_only, sharding)
+        rows, best = sweep_shape(name, args.compile_only, sharding,
+                                 args.keep_traces)
         refused = [r for r in rows if "error" in r]
         print(f"\n## {name} ({time.monotonic() - t0:.0f} s, {len(rows)} "
               f"programs, {len(refused)} refused)\n")

@@ -146,6 +146,49 @@ def register_preset(name: str, base: str, **changes) -> dict:
     return CAUSAL_LMS
 
 
+def grouped_kernels_are_the_plain_form(task, variables, batch, groups,
+                                       tol, monkeypatch) -> None:
+    """A causal stack with ``ops/grouped.py`` choosing the library's kernels
+    (interpret mode; one device; tiles of a whole width at test size), as a
+    cell's shape with an entry does on the chip, against itself on
+    ``jax.lax.ragged_dot``: logits, loss and every gradient group that
+    ``groups`` makes of a tree of variables, each one jitted program waited
+    for; ``grouped_products_fused`` reads 0 on the CPU path, 1 then."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    from lance_distributed_training_tpu.obs.registry import default_registry
+    from lance_distributed_training_tpu.ops import grouped
+
+    def loss(v):
+        outputs, _ = task.forward(v, batch, True, None)
+        return task.loss(outputs, batch)
+
+    def run(v):
+        logits = task.forward(v, batch, False, None)[0][0]
+        return logits, *jax.value_and_grad(loss)(v)
+
+    gauge = default_registry().gauge("grouped_products_fused")
+    gauge.set(0.0)
+    want = jax.block_until_ready(jax.jit(run)(variables))
+    assert gauge.value == 0.0
+    monkeypatch.setattr(
+        grouped, "grouped_tiling", lambda rows, groups, k, n, **_:
+        grouped.Tiling((128, k, n), (128, n, k), (128, k, n)))
+    with pltpu.force_tpu_interpret_mode():  # a new jit: traced anew
+        got = jax.block_until_ready(jax.jit(lambda v: run(v))(variables))
+    assert gauge.value == 1.0
+    spread = float(jnp.std(want[0]))
+    assert float(jnp.abs(got[0] - want[0]).max()) < tol * spread
+    assert abs(float(got[1]) - float(want[1])) < tol * float(want[1])
+    got, want = groups(got[2]), groups(want[2])
+    assert got.keys() == want.keys()
+    for group in want:
+        error = float(jnp.linalg.norm(got[group] - want[group])
+                      / jnp.linalg.norm(want[group]))
+        assert error < tol, (group, error)
+
+
 def make_jpeg(rng: np.ndarray, size: int = 32) -> bytes:
     """A small random JPEG payload (stands in for FOOD101 images)."""
     from PIL import Image

@@ -626,7 +626,8 @@ def test_the_grouped_products_kernels_compile_for_a_v5e_at_every_entry(
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3
     assert "ragged-dot" not in text
-    assert not re.search(rf"bf16\[{groups},{n},{k}\]", text)
+    if n != k:  # a square expert's transpose has the matrices' own shape
+        assert not re.search(rf"bf16\[{groups},{n},{k}\]", text)
 
 
 @pytest.mark.parametrize("rows,seq,hidden,vocab,tied", [

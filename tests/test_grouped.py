@@ -12,10 +12,17 @@ ragged last tile. *Does the rule choose as it says?* Every entry of the table
 fits its shape and names a cell's call; a shape without an entry, a mesh,
 several devices and a CPU take the plain form. *Does the layer call it?*
 ``DroplessMoE`` with the kernels bound against its plain self, whole and
-under a share, and the gauge.
+under a share, and the gauge. *Does the table hold to its rule of admission?*
+Every entry is the tilings at which a committed sweep file
+(``scripts/grouped_sweep/<shape>.jsonl``) shows the whole-layer program
+ahead of XLA's on even and on skewed groups; a shape without such a file
+has no entry.
 """
 
 from __future__ import annotations
+
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -75,9 +82,27 @@ CASES = {  # rows built, K, N, the groups' sizes, tilings
 }
 
 
+def _entry_case(shape):
+    """An entry's call cut down for the interpreter: its K, N and tile
+    shapes, four of its widest row tiles, three groups that end inside
+    tiles and one of no rows; every row live where the cell's list has no
+    dead rows (all 64 of OLMoE's experts held), a dead tail elsewhere."""
+    _, groups, k, n = shape
+    tiling = ops.TILINGS[shape]
+    rows = 4 * max(tm for tm, _, _ in tiling)
+    live = rows if groups == 64 else rows - rows // 8 - 5
+    first = rows // 4 + 37
+    return rows, k, n, (first, 0, live - first - 100, 100), tiling
+
+
+CASES.update({"entry_" + "x".join(map(str, shape)): _entry_case(shape)
+              for shape in sorted(ops.TILINGS)})
+
+
 @pytest.mark.parametrize("case", CASES)
 def test_the_kernels_in_interpret_mode_are_the_plain_form(case):
     rows, k, n, sizes, tiling = CASES[case]
+    assert ops.fits(rows, k, n, tiling)
     keys = jax.random.split(jax.random.key(len(case)), 3)
     xs = jax.random.normal(keys[0], (rows, k))
     w = jax.random.normal(keys[1], (len(sizes), k, n)) * 0.1
@@ -138,6 +163,40 @@ def test_every_entry_fits_its_shape_and_is_a_cells_call():
         assert isinstance(tiling, ops.Tiling)
         assert ops.fits(rows, k, n, tiling), (shape, tiling)
         assert all(tm in (128, 256, 512) for tm, _, _ in tiling)
+
+
+SWEEPS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "scripts", "grouped_sweep")
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_table_holds_to_its_rule_of_admission(cell):
+    """An entry rests on a committed file of
+    ``scripts/grouped_products_sweep.py`` in which the layer's whole program
+    (three products, their gate, forward and ``value_and_grad``) under the
+    kernels *at the entry's tilings* is ahead of XLA's by ``AHEAD`` on even
+    groups and on the cell's skew alike; the one shape with no such file
+    has no entry (SmallThinker's: behind on even groups in PR 50's
+    sweep)."""
+    rows, groups, hidden, width = CELLS[cell]
+    calls = {"in": (rows, groups, hidden, width),
+             "out": (rows, groups, width, hidden)}
+    held = {side: ops.TILINGS.get(call) for side, call in calls.items()}
+    path = os.path.join(SWEEPS, f"{cell}.jsonl")
+    if not os.path.exists(path):
+        assert cell == "smallthinker" and not any(held.values())
+        return
+    assert all(held.values()), "gate, up and down run one form"
+    with open(path) as f:
+        whole = [r for r in map(json.loads, f)
+                 if r["stage"] == "whole" and r.get("ms")]
+    xla = {r["sizes"]: r["ms"] for r in whole if not r["tiling"]}
+    ours = {r["sizes"]: r["ms"] for r in whole if r["tiling"] and all(
+        ops.Tiling(*map(tuple, r["tiling"][side])) == held[side]
+        for side in calls)}
+    assert set(xla) == set(ours) == {"even", "skewed"}, (xla, ours)
+    for kind in ("even", "skewed"):
+        assert ours[kind] <= (1 - ops.AHEAD) * xla[kind], (kind, xla, ours)
 
 
 @pytest.mark.parametrize("tiling,says", [
@@ -211,6 +270,38 @@ def test_off_the_tpu_the_chooser_is_ragged_dot_and_the_gauge_stays():
     np.testing.assert_array_equal(ops.grouped_product(xs, w, gs),
                                   jax.lax.ragged_dot(xs, w, gs))
     assert default_registry().gauge("grouped_products_fused").value == 0.0
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cell_without_an_entry_lowers_ragged_dot_on_one_tpu_device(
+        cell, monkeypatch):
+    """On one TPU device (the rule's two questions answered for it here) a
+    call at the cell's full shapes lowers, value and both cotangents, to
+    ``jax.lax.ragged_dot``'s text where the table has no entry
+    (SmallThinker's step is the parent's) and traces the kernels where it has;
+    the worst-case list lowers ``ragged_dot``'s in every cell."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    rows, groups, hidden, width = CELLS[cell]
+
+    def call(rows):
+        return (jax.ShapeDtypeStruct((rows, hidden), jnp.bfloat16),
+                jax.ShapeDtypeStruct((groups, hidden, width), jnp.bfloat16),
+                jax.ShapeDtypeStruct((groups,), jnp.int32))
+
+    def lowered(form, rows):
+        return jax.jit(jax.grad(lambda xs, w, gs: form(xs, w, gs).astype(
+            jnp.float32).sum(), argnums=(0, 1))).lower(*call(rows)).as_text()
+
+    if (rows, groups, hidden, width) in ops.TILINGS:
+        # Mosaic lowers for a TPU alone: the traced program says it
+        assert "pallas_call" in str(jax.make_jaxpr(ops.grouped_product)(
+            *call(rows)))
+    else:
+        assert lowered(ops.grouped_product, rows) == lowered(
+            jax.lax.ragged_dot, rows)
+    assert lowered(ops.grouped_product, 4 * rows) == lowered(
+        jax.lax.ragged_dot, 4 * rows)
 
 
 def _bound(monkeypatch):

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import register_preset
+from conftest import grouped_kernels_are_the_plain_form, register_preset
 
 from lance_distributed_training_tpu.models import get_task, moe, transformer
 from lance_distributed_training_tpu.models.moe import DroplessMoE, SwiGLU
@@ -375,35 +375,11 @@ def test_unequal_heads_take_the_splash_path_and_the_rule_sees_them():
 
 def test_the_grouped_products_kernels_are_the_plain_form_and_the_gauge_says(
         f32_task, variables, batch, monkeypatch):
-    """The share's stack with ``ops/grouped.py`` choosing the library's
-    kernels (interpret mode; tiles of a whole width at test size) against
-    itself on ``jax.lax.ragged_dot``, f32: logits, loss and every group's
-    gradient; ``grouped_products_fused`` reads 0 on the CPU path, 1 then."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    from lance_distributed_training_tpu.obs.registry import default_registry
-    from lance_distributed_training_tpu.ops import grouped
-
-    def run(v):
-        logits = f32_task.forward(v, batch, False, None)[0][0]
-        return logits, *jax.value_and_grad(_program_loss(f32_task, batch))(v)
-
-    gauge = default_registry().gauge("grouped_products_fused")
-    gauge.set(0.0)
-    want = _one_program(run, variables)
-    assert gauge.value == 0.0
-    monkeypatch.setattr(
-        grouped, "grouped_tiling", lambda rows, groups, k, n, **_:
-        grouped.Tiling((128, k, n), (128, n, k), (128, k, n)))
-    with pltpu.force_tpu_interpret_mode():
-        got = _one_program(lambda v: run(v), variables)  # traced anew
-    assert gauge.value == 1.0
-    spread = float(jnp.std(want[0]))
-    assert float(jnp.abs(got[0] - want[0]).max()) < F32_TOL * spread
-    assert abs(float(got[1]) - float(want[1])) < F32_TOL * float(want[1])
-    got, want = _groups(got[2]["params"]), _groups(want[2]["params"])
-    for group in GROUPS:
-        assert _relative(got[group], want[group]) < F32_TOL, group
+    """The share's stack, as the cell's shape runs on the chip since PR 50:
+    logits, loss and every group's gradient."""
+    grouped_kernels_are_the_plain_form(
+        f32_task, variables, batch, lambda v: _groups(v["params"]), F32_TOL,
+        monkeypatch)
 
 
 # -- the share ---------------------------------------------------------------

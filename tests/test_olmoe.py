@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import register_preset
+from conftest import grouped_kernels_are_the_plain_form, register_preset
 
 from lance_distributed_training_tpu.models import get_task
 from lance_distributed_training_tpu.models.moe import DroplessMoE
@@ -362,6 +362,17 @@ def test_gradient_with_the_flash_kernel_matches_reference(
 
 
 # -- the dropless property ---------------------------------------------------
+
+
+# -- the grouped products' kernel form ---------------------------------------
+
+
+def test_the_grouped_products_kernels_are_the_plain_form_and_the_gauge_says(
+        f32_task, variables, batch, monkeypatch):
+    """As the cell's shape runs on the chip since PR 52; all experts are
+    held, so every built row is live."""
+    grouped_kernels_are_the_plain_form(f32_task, variables, batch, _groups,
+                                       F32_TOL, monkeypatch)
 
 
 def _layer(dtype=jnp.float32):

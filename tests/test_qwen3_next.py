@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import register_preset
+from conftest import grouped_kernels_are_the_plain_form, register_preset
 
 from lance_distributed_training_tpu.models import get_task
 from lance_distributed_training_tpu.models.moe import DroplessMoE
@@ -231,6 +231,19 @@ def test_gradient_matches_reference_in_float32(group, f32_grads,
                                                reference_grads):
     assert float(jnp.linalg.norm(reference_grads[group])) > 0
     assert _relative(f32_grads[group], reference_grads[group]) < F32_TOL
+
+
+# -- the grouped products' kernel form ---------------------------------------
+
+
+@pytest.mark.slow  # the stack twice in interpret mode: 17-54 s a case
+def test_the_grouped_products_kernels_are_the_plain_form_and_the_gauge_says(
+        f32_task, variables, batch, monkeypatch):
+    """Whole and under the share, as the cell's shape runs on the chip since
+    PR 52."""
+    grouped_kernels_are_the_plain_form(
+        f32_task, variables, batch, lambda v: _groups(v["params"]), F32_TOL,
+        monkeypatch)
 
 
 @pytest.fixture(scope="module")
