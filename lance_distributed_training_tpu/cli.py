@@ -171,7 +171,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "transformer.py states each preset's source and "
                         "sizes; the first log line says which form each of "
                         "its kernels runs: attention=, scan=, delta=, ssd=, conv=, "
-                        "norm=)")
+                        "norm=, and yarn= where a layer's rotary turn runs "
+                        "YaRN's table: laguna_s_2_1, laguna_tiny)")
     p.add_argument("--num_layers", type=int, default=0,
                    help=">0: this many layers of a masked_lm/causal_lm "
                         "transformer preset in place of its own depth, at "
@@ -183,8 +184,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "causal_lm preset with experts of its own) that this "
                         "process holds as rank "
                         "RANK of RANKS that share the layer: E/RANKS of them "
-                        "from RANK*E/RANKS on (0/8 of 64 experts: experts "
-                        "0-7). The "
+                        "from RANK*E/RANKS on (0/8 of 64 experts, or 0/32 of "
+                        "laguna_s_2_1's 256: experts 0-7). The "
                         "router stays whole; what absent experts would add "
                         "is left out. With --vocab_size as the vocabulary's "
                         "slice and --num_layers, one chip's share of an "
@@ -193,7 +194,8 @@ def build_parser() -> argparse.ArgumentParser:
                    metavar="FIRST:END",
                    help="the published layers [FIRST, END) that this process "
                         "holds of a preset whose layers differ by kind, "
-                        "as a pipeline stage would; a layer keeps "
+                        "as a pipeline stage would (laguna_s_2_1 0:5: the "
+                        "leading dense layer and one period); a layer keeps "
                         "its published index, and a span in which a G or X "
                         "layer has no M* or F* before it is refused. With "
                         "--vocab_size as the vocabulary's slice, one chip's "

@@ -33,8 +33,9 @@ __all__ = ["TransformerEncoder", "TransformerDecoder", "bert_base",
            "zaya1_8b", "zaya_tiny", "qwen3_next_80b_a3b", "qwen3_next_tiny",
            "qwen3_next_layers", "smallthinker_21b_a3b", "smallthinker_tiny",
            "smallthinker_layers", "granite4_h_micro", "granite4_h_tiny",
-           "granite4_layers", "dot_product_attention", "RMSNorm",
-           "rotary_embedding", "causal_depthwise_conv", "LayerKind",
+           "granite4_layers", "laguna_s_2_1", "laguna_tiny", "laguna_layers",
+           "dot_product_attention", "RMSNorm", "rotary_embedding",
+           "yarn_frequencies", "causal_depthwise_conv", "LayerKind",
            "LAYER_KINDS", "Preset", "CAUSAL_LMS"]
 
 
@@ -125,26 +126,61 @@ class RMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
 
 
-def rotary_embedding(x, positions, theta: float, width: int = 0):
+def rotary_embedding(x, positions, theta: float, width: int = 0,
+                     inv_freq=None, factor: float = 1.0):
     """Rotary position embedding, in f32: ``x`` [B, S, H, D], ``positions``
     [B, S] or [S]. Over the whole head dimension, or with ``width`` > 0 over
     its first ``width`` elements, the others left as they are (a partial
     rotary factor: ``width = factor * D``). Half-rotation pairing (element
     ``i`` turns with element ``i + width/2``, as the published decoder
-    implementations pair them), angle ``position * theta^(-2i/width)``."""
+    implementations pair them), angle ``position * theta^(-2i/width)``, or
+    ``position * inv_freq[i]`` where a table of ``width / 2`` inverse
+    frequencies is given (:func:`yarn_frequencies`); ``factor`` other than 1
+    multiplies cos and sin both (YaRN's attention factor)."""
     if 0 < width < x.shape[-1]:
         return jnp.concatenate([
-            rotary_embedding(x[..., :width], positions, theta),
+            rotary_embedding(x[..., :width], positions, theta, 0, inv_freq,
+                             factor),
             x[..., width:]], axis=-1)
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freq = jnp.asarray(inv_freq, jnp.float32)
     angle = positions.astype(jnp.float32)[..., None] * freq  # [(B,) S, D/2]
     cos = jnp.cos(angle)[..., None, :]
     sin = jnp.sin(angle)[..., None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x32 = x.astype(jnp.float32)
     a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
+
+
+def yarn_frequencies(width: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0) -> tuple:
+    """YaRN's ``width / 2`` inverse frequencies (Peng et al.,
+    arXiv:2309.00071, as ``transformers`` ``_compute_yarn_parameters`` has
+    them): pair ``j``'s own ``f_j = theta^(-2j/width)`` where it turns more
+    than ``beta_fast`` times over ``original_max`` positions, ``f_j /
+    factor`` where fewer than ``beta_slow``, a linear ramp over the pairs
+    between. Python floats: a constant of the program."""
+    def pair_that_turns(rotations):
+        return width * math.log(original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), width - 1)
+    if low == high:
+        high += 0.001  # the ramp's width is never 0
+    table = []
+    for j in range(width // 2):
+        f = theta ** (-2.0 * j / width)
+        ramp = min(max((j - low) / (high - low), 0.0), 1.0)
+        table.append(f / factor * ramp + f * (1.0 - ramp))
+    return tuple(table)
 
 
 class SelfAttention(nn.Module):
@@ -854,14 +890,21 @@ class GatedAttention(nn.Module):
 
 
 class GroupedAttention(nn.Module):
-    """SmallThinker's softmax-attention mixer, and Granite 4.0-H's:
+    """SmallThinker's softmax-attention mixer, Granite 4.0-H's and Laguna's:
     ``num_heads`` query heads over ``kv_heads`` key and value heads, all
     ``head_dim`` wide (which need not be ``hidden / num_heads``: 28 heads of
-    128 on a stream of 2,560), no norm on queries or keys, no gate, no
-    biases. Two kinds of layer are this class: with ``rotary`` a rotary turn
-    over the whole head and, with ``window`` > 0, a causal band (a query sees
-    itself and the ``window - 1`` keys before it); without either, the whole
-    causal row and no position term at all (``position_ids`` is not read).
+    128 on a stream of 2,560), no norm on queries or keys, no biases. The
+    kinds of layer that are this class differ by fields: with ``rotary`` a
+    rotary turn over the whole head, or over its first ``rotary_dim``
+    elements, at ``rope_theta``'s frequencies or, under ``yarn`` (its five
+    numbers: factor, original positions, beta fast and slow, the factor on
+    cos and sin), YaRN's table of them; with ``window`` > 0 a causal band (a
+    query sees itself and the ``window - 1`` keys before it); without either,
+    the whole causal row and no position term at all (``position_ids`` is
+    not read). With ``head_gate`` (Laguna's, the headwise form of
+    arXiv:2505.06708) head ``n``'s output times ``sigmoid(x w_n)``, one
+    scalar a head and token from a product of the mixer's own input, ahead of
+    the output projection.
     Scores times ``score_scale``, or over ``sqrt(head_dim)`` where that is 0
     (Granite's ``attention_multiplier`` is 1/64 on heads of 64, not 1/8): the
     attention functions divide by the root themselves, so the queries are
@@ -878,6 +921,10 @@ class GroupedAttention(nn.Module):
     attention_fn: Optional[Callable] = None
     kernel_init: Callable = nn.linear.default_kernel_init
     score_scale: float = 0.0  # 0: 1 / sqrt(head_dim)
+    head_gate: bool = False  # a sigmoid gate a head, from the mixer's input
+    rotary_dim: int = 0  # 0: the whole head turns
+    yarn: tuple = ()  # (factor, original positions, beta fast, beta slow,
+    # the factor on cos and sin); empty: rope_theta's own frequencies
 
     def kernels(self, seq_len: int, width: int) -> dict:
         return _attention_kernel(self.attention_fn, seq_len, self.head_dim,
@@ -898,8 +945,13 @@ class GroupedAttention(nn.Module):
                 q = q * (self.score_scale * math.sqrt(d))
             if self.rotary:
                 pos = jnp.arange(s) if position_ids is None else position_ids
-                q = rotary_embedding(q, pos, self.rope_theta)
-                k = rotary_embedding(k, pos, self.rope_theta)
+                turn = dict(positions=pos, theta=self.rope_theta,
+                            width=self.rotary_dim)
+                if self.yarn:
+                    turn.update(inv_freq=yarn_frequencies(
+                        self.rotary_dim or d, self.rope_theta,
+                        *self.yarn[:4]), factor=self.yarn[4])
+                q, k = (rotary_embedding(t, **turn) for t in (q, k))
             # [B, S, H, D] -> [B, H, S, D]
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         attn = self.attention_fn
@@ -914,9 +966,16 @@ class GroupedAttention(nn.Module):
             self.sow("mixer_stats", "attn_window", jnp.float32(self.window))
         with jax.named_scope("attn.window" if self.window else "attn.full"):
             out = attn(q, k, v, mask=mask, **kwargs)
+        out = out.transpose(0, 2, 1, 3)
+        if self.head_gate:
+            with jax.named_scope("attn.gate"):
+                gate = nn.sigmoid(dense(features=n, name="gate")(x).astype(
+                    jnp.float32))  # [B, S, N]
+                self.sow("mixer_stats", "attn_gate", gate.mean())
+                out = (out.astype(jnp.float32) * gate[..., None]).astype(
+                    self.dtype)
         with jax.named_scope("attn.out"):
-            return dense(features=h, axis=(-2, -1), name="out")(
-                out.transpose(0, 2, 1, 3))
+            return dense(features=h, axis=(-2, -1), name="out")(out)
 
 
 class EncoderBlock(nn.Module):
@@ -1071,7 +1130,10 @@ _MEMORY, _KEYS_VALUES, _ROUTER_STATE = range(3)
 # gated DeltaNet, "A" gated attention; SmallThinker's two: "W" grouped rotary
 # attention in a window, "N" the same heads over the whole causal row with no
 # position term (Granite 4.0-H's attention layers too, under a score scale of
-# their own); "M2": a Mamba-2 mixer, Granite 4.0-H's other nine layers in ten.
+# their own); "M2": a Mamba-2 mixer, Granite 4.0-H's other nine layers in ten;
+# Laguna's two, one class under two sets of sizes (a preset's ``parts`` has
+# an entry for each kind): "GW" grouped attention in a window, "GF" the same
+# class over the whole causal row, each with a gate a head.
 LAYER_KINDS: dict = {
     "": LayerKind(SelfAttention, "attn", "attention",
                   (("causal", True), ("use_bias", False)), 3),
@@ -1091,6 +1153,9 @@ LAYER_KINDS: dict = {
     "N": LayerKind(GroupedAttention, "attn", "attention",
                    (("window", 0), ("rotary", False)), 3),
     "M2": LayerKind(Mamba2Mixer, "ssm", "state_space"),
+    "GW": LayerKind(GroupedAttention, "attn", "attention", sequence=3),
+    "GF": LayerKind(GroupedAttention, "attn", "attention",
+                    (("window", 0),), 3),
 }
 
 
@@ -1099,9 +1164,12 @@ class DecoderBlock(nn.Module):
     bias-free. The choices a layer makes are fields. ``kind`` is its mixer,
     by :data:`LAYER_KINDS`; ``parts`` holds, for each class among the
     layer's parts that has sizes of its own, a ``partial`` of the class over
-    them, under the class's own field names. A ``"C"`` layer (ZAYA1's) also
-    has an expert layer whose router is a :class:`..moe.StateRouter` with a
-    state handed from layer to layer. ``residual_scales`` (ZAYA1's) puts
+    them, under the class's own field names, or a ``(kind, partial)`` pair
+    where layers of one class differ in size by their kind (Laguna's window
+    layers have 72 query heads, its full ones 48, another theta and rotary
+    width): a layer takes the pair made for its kind first. A ``"C"`` layer
+    (ZAYA1's) also has an expert layer whose router is a
+    :class:`..moe.StateRouter` with a state handed from layer to layer. ``residual_scales`` (ZAYA1's) puts
     learned scales and shifts on both sides of both residual sums;
     ``branch_scale`` other than 1 (Granite's ``residual_multiplier``)
     multiplies both branches ahead of their sums. With ``router_early``
@@ -1128,7 +1196,8 @@ class DecoderBlock(nn.Module):
     kind: str = ""
     depth: int = 0  # the layer's published index
     layer_norm: bool = False
-    parts: tuple = ()  # partial(Class, **its own sizes), one a sized class
+    parts: tuple = ()  # partial(Class, **its own sizes), one a sized class,
+    # or (kind, partial): that class's sizes in the layers of that kind
     norm_offset: bool = False  # RMSNorm's scale is 1 + w
     router_early: bool = False  # the router reads x, ahead of attention
     residual_scales: bool = False  # learned a and b on both residual sums
@@ -1136,13 +1205,18 @@ class DecoderBlock(nn.Module):
 
     def part(self, cls, **fields):
         """``cls`` as this layer holds it: with the sizes ``parts`` states
-        for it, those of the layer's own fields that it declares, the
-        layer's initialiser, and ``fields``."""
-        sized = next((p for p in self.parts if p.func is cls), cls)
+        for it (an entry made for the layer's kind first, else one for the
+        class), those of the layer's own fields that it declares and the
+        entry does not state, the layer's initialiser, and ``fields``."""
+        by_kind = [p[1] for p in self.parts
+                   if isinstance(p, tuple) and p[0] == self.kind]
+        by_class = [p for p in self.parts if not isinstance(p, tuple)]
+        sized = next((p for p in by_kind + by_class if p.func is cls), cls)
+        stated = getattr(sized, "keywords", {})
         own = {name: getattr(self, name) for name in (
             "num_heads", "num_experts", "expert_dim", "experts_per_token",
             "norm_eps", "rope_theta", "dtype", "attention_fn", "depth")
-            if name in cls.__dataclass_fields__}
+            if name in cls.__dataclass_fields__ and name not in stated}
         return sized(**{
             **own, "kernel_init": nn.initializers.truncated_normal(
                 self.init_std), **fields})
@@ -1228,8 +1302,11 @@ class TransformerDecoder(nn.Module):
     four multipliers: the embedding's (``embed_scale``), both branches'
     ahead of their residual sums (``branch_scale``), the attention scores'
     (``GroupedAttention.score_scale``) and the logits' (``logit_scale``), each
-    absent from a stack's arithmetic at its default. SambaY's, ZAYA1's and
-    Granite's tie the head to the embedding (``tied_head``).
+    absent from a stack's arithmetic at its default; Laguna's one ``"GF"`` to
+    three ``"GW"``, one class whose sizes differ by the layer's kind (48
+    query heads and a YaRN half-rotary turn, 72 and a whole one in a window
+    of 512), a leading dense layer and a head of its own. SambaY's, ZAYA1's
+    and Granite's tie the head to the embedding (``tied_head``).
     ``first_layer`` says which published layers are held (``num_layers`` of
     them from there: one pipeline stage's), and what a layer hands on (M*'s
     and F*'s tensors, a router's state) rides from layer to layer beside
@@ -1251,8 +1328,8 @@ class TransformerDecoder(nn.Module):
     dtype: Any = jnp.bfloat16
     remat: bool = False
     attention_fn: Optional[Callable] = None
-    dense_layers: int = 0  # this many leading layers are dense, dense_dim wide
-    dense_dim: int = 0
+    dense_layers: int = 0  # the published layers before this one are dense,
+    dense_dim: int = 0  # dense_dim wide, held here or not
     moe: tuple = ()  # DecoderBlock's, for every expert layer
     kind: str = ""  # every layer's DecoderBlock.kind, where they are alike
     layer_kinds: tuple = ()  # else every published layer's
@@ -1295,7 +1372,8 @@ class TransformerDecoder(nn.Module):
             self.num_heads, self.expert_dim, self.num_experts,
             self.experts_per_token, self.norm_eps, self.rope_theta,
             self.init_std, self.dtype, attention_fn=self.attention_fn,
-            dense_dim=self.dense_dim if i < self.dense_layers else 0,
+            dense_dim=(self.dense_dim
+                       if self.first_layer + i < self.dense_layers else 0),
             moe=self.moe, kind=kind, depth=self.first_layer + i,
             layer_norm=self.layer_norm, parts=self.parts,
             norm_offset=self.norm_offset, router_early=self.router_early,
@@ -1315,6 +1393,17 @@ class TransformerDecoder(nn.Module):
                                              self.hidden_size).items():
                 answer[name] = answer.get(name, True) and fused
         return answer
+
+    @property
+    def yarn(self) -> tuple:
+        """The YaRN rule of the first held mixer that turns under one (its
+        ``yarn`` field), or ``()``: the first log line says it."""
+        for i, kind in enumerate(self.held_kinds):
+            rule = getattr(self._layer(i, kind, parent=None).mixer(
+                parent=None), "yarn", ())
+            if rule:
+                return rule
+        return ()
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, train: bool = True,
@@ -1575,6 +1664,56 @@ granite4_h_tiny = partial(
     tied_head=True, **_GRANITE4_SCALES)
 
 
+def laguna_layers(layers: int, period: int = 4) -> tuple:
+    """Laguna's layout (``layer_types``): layer ``i`` is full attention (GF)
+    where ``i % period == 0`` and window attention (GW) otherwise."""
+    return tuple("GF" if i % period == 0 else "GW" for i in range(layers))
+
+
+# Laguna-S-2.1 (poolside/Laguna-S-2.1 config.json, model_type laguna; no class
+# of it in the container, so what the config cannot confirm is listed as
+# assumed in the benchmark's configuration): 48 layers on a stream of 3,072,
+# one full attention layer of 48 query heads (rotary on half of a head, theta
+# 5e5 under YaRN: factor 128 over 8,192 positions, cos and sin times
+# 1.4852 = 0.1 ln 128 + 1) to three window-512 layers of 72 (plain rotary,
+# theta 1e4), all over 8 key/value heads of 128, a sigmoid gate a head; layer
+# 0 a dense SwiGLU of 12,288, the others 256 experts of 1,024 with 10 a token
+# by sigmoid scores renormalised and times 2.5, no selection bias and no
+# balance term, beside one shared SwiGLU of 1,024; RMSNorm 1e-6; the head
+# untied.
+_LAGUNA_EXPERTS = (("scoring", "sigmoid"), ("norm_topk", True),
+                   ("routed_scale", 2.5))
+laguna_s_2_1 = partial(
+    TransformerDecoder, hidden_size=3072, num_layers=48, num_heads=48,
+    expert_dim=1024, num_experts=256, experts_per_token=10, norm_eps=1e-6,
+    dense_layers=1, dense_dim=12288,
+    moe=_LAGUNA_EXPERTS + (("shared_dim", 1024),),
+    layer_kinds=laguna_layers(48),
+    parts=(("GW", partial(GroupedAttention, num_heads=72, kv_heads=8,
+                          head_dim=128, window=512, rope_theta=10000.0,
+                          head_gate=True)),
+           ("GF", partial(GroupedAttention, kv_heads=8, head_dim=128,
+                          rope_theta=500000.0, rotary_dim=64,
+                          yarn=(128.0, 8192, 32.0, 1.0, 1.4852030263919618),
+                          head_gate=True))))
+# at test size: 6 query heads in the window layers and 4 in the full ones over
+# 2 key/value heads of 16, a window of 16, YaRN over 64 positions of 8 of a
+# head's 16 (its ramp over all four pairs), 16 experts with 4 a token
+laguna_tiny = partial(
+    TransformerDecoder, hidden_size=64, num_layers=5, num_heads=4,
+    expert_dim=32, num_experts=16, experts_per_token=4, norm_eps=1e-6,
+    dense_layers=1, dense_dim=128,
+    moe=_LAGUNA_EXPERTS + (("shared_dim", 32),),
+    layer_kinds=laguna_layers(5),
+    parts=(("GW", partial(GroupedAttention, num_heads=6, kv_heads=2,
+                          head_dim=16, window=16, rope_theta=10000.0,
+                          head_gate=True)),
+           ("GF", partial(GroupedAttention, kv_heads=2, head_dim=16,
+                          rope_theta=64.0, rotary_dim=8,
+                          yarn=(8.0, 64, 4.0, 1.0, 1.2079441541679836),
+                          head_gate=True))))
+
+
 class Preset(NamedTuple):
     """A ``causal_lm`` preset: the constructor (called with ``vocab_size``
     and whatever a task changes), the vocabulary that is the model's own, and
@@ -1594,7 +1733,9 @@ class Preset(NamedTuple):
 # alone. Qwen3-Next's take the balance term at its published class's default
 # weight (router_aux_loss_coef 0.001) and no z term, and SmallThinker's the
 # same (its config names none: the benchmark's configuration lists it as
-# assumed). Granite 4.0-H micro has no experts and no auxiliary term.
+# assumed). Granite 4.0-H micro has no experts and no auxiliary term;
+# Laguna's experts sow the sequence-wise term and the loss takes none (its
+# config names no weight: the benchmark's configuration lists it as assumed).
 _SWITCH_AUX = {"load_balance": 0.01}
 _OLMOE_AUX = {"load_balance": 0.01, "router_z": 0.001}
 _MOONLIGHT_AUX = {"seq_balance": 0.0001}
@@ -1618,4 +1759,6 @@ CAUSAL_LMS: dict = {
     "smallthinker_tiny": Preset(smallthinker_tiny, 512, _SMALLTHINKER_AUX),
     "granite4_h_micro": Preset(granite4_h_micro, 100352, {}),
     "granite4_h_tiny": Preset(granite4_h_tiny, 512, {}),
+    "laguna_s_2_1": Preset(laguna_s_2_1, 100352, {}),
+    "laguna_tiny": Preset(laguna_tiny, 512, {}),
 }

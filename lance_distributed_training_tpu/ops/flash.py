@@ -374,6 +374,19 @@ _TIMED = {
     # again (more of a block lies outside the band)
     _Shape(16384, 128, 128, 28, True, 4096): SplashTiling(
         (1024, 1024, 512), (1024, 1024, 256), (1024, 1024)),
+    # Laguna's gated grouped attention over 8 key and value heads of 128 at
+    # 8,192 tokens. The window layers' 72 query heads in a band of 512: one
+    # block wide, as Phi-4's, and square 512s stay but for dkv's scores 256
+    # keys at a time: 13.91 ms a call where square 512s take 13.96 (every
+    # block of 128 or 256 is slower; the nine-fold repetition of keys and
+    # values is in both)
+    _Shape(8192, 128, 128, 72, True, 512): SplashTiling(
+        (512, 512, 512), (512, 512, 256), (512, 512)),
+    # the full layers' 48 query heads over the whole causal row: 27.77 ms a
+    # call where square 512s take 34.04 (every forward block of 2,048 queries
+    # beside 1,024 keys or more is refused, and every key block of 4,096)
+    _Shape(8192, 128, 128, 48, True, 0): SplashTiling(
+        (1024, 2048, 512), (1024, 1024, 1024), (1024, 1024)),
 }
 
 
@@ -512,7 +525,9 @@ def fused_attention_applies(seq: int, head_dim: int, mesh=None,
     query heads over 2 key and value heads of 128, and Qwen3-Next's gated
     attention's 16 query heads over 2 key and value heads of 256, both
     causal, at 8,192 tokens, and SmallThinker's 28 query heads over 4 key and
-    value heads of 128 at 16,384 tokens, causal and in a window of 4,096.
+    value heads of 128 at 16,384 tokens, causal and in a window of 4,096, and
+    Laguna's 72 and 48 query heads over 8 key and value heads of 128 at 8,192
+    tokens, in a window of 512 and causal.
     Everything else is dense attention, as before: the CPU, a
     ViT's 197 tokens, a tensor-parallel mesh, and several devices with no
     mesh to say how the batch is split."""

@@ -374,11 +374,17 @@ def _kernel_paths(task: Task, config: TrainConfig) -> dict:
     """The first log line's word for the form each of the model's kernels
     runs at ``seq_len``, by the kernel's name (``Task.kernels``: the mixers'
     own answer, which asks the test each call makes): ``attention=``,
-    ``scan=``, ``delta=``, ``ssd=``, ``conv=``, ``norm=``."""
+    ``scan=``, ``delta=``, ``ssd=``, ``conv=``, ``norm=``; and ``yarn=`` where
+    some held layer's rotary turn runs YaRN's table (Laguna's full layers)."""
     plain = {"attention": "ring" if config.seq_parallelism > 1 else "dense",
              "conv": "plain", "norm": "plain"}
-    return {name: "fused kernel" if fused else plain.get(name, "chunked")
-            for name, fused in sorted(task.kernels.items())}
+    paths = {name: "fused kernel" if fused else plain.get(name, "chunked")
+             for name, fused in sorted(task.kernels.items())}
+    yarn = getattr(task.model, "yarn", ())
+    if yarn:
+        paths["yarn"] = ("factor {:g} over {} positions, beta {:g}/{:g}, "
+                         "cos and sin x {:.4f}").format(*yarn)
+    return paths
 
 
 def lr_schedule_fn(config: TrainConfig, total_steps: Optional[int] = None):

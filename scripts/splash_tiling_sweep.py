@@ -61,6 +61,11 @@ SHAPES = {
                                 window=4096, seq=16384),
     # Granite 4.0-H's grouped attention: 32 query heads over 8 of 64
     "granite": dict(q=(32, 64), k=(8, 64), v=(8, 64), window=0),
+    # Laguna's two kinds of layer over 8 key/value heads of 128: 72 query
+    # heads in a band of 512 (one block wide, as Phi-4's), 48 over the whole
+    # causal row
+    "laguna_window": dict(q=(72, 128), k=(8, 128), v=(8, 128), window=512),
+    "laguna_full": dict(q=(48, 128), k=(8, 128), v=(8, 128), window=0),
 }
 KERNELS = ("fwd", "dkv", "dq")
 CALLS = 20  # after one call of warm-up

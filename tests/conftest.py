@@ -127,8 +127,9 @@ def pytest_collection_modifyitems(items):
 def register_preset(name: str, base: str, **changes) -> dict:
     """The ``causal_lm`` preset ``base`` under ``name``, with fields of its
     constructor changed (``moe``: a dict laid over the base's name-value
-    pairs; ``parts``: a dict, class to the sizes of it that change). Returns
-    the table: whoever registers deletes, ``del table[name]``."""
+    pairs; ``parts``: a dict, class to the sizes of it that change, or, for a
+    preset whose parts are ``(kind, partial)`` pairs, layer kind to them).
+    Returns the table: whoever registers deletes, ``del table[name]``."""
     import functools
 
     from lance_distributed_training_tpu.models.transformer import CAUSAL_LMS
@@ -138,8 +139,11 @@ def register_preset(name: str, base: str, **changes) -> dict:
         changes["moe"] = tuple({**dict(base.ctor.keywords["moe"]),
                                 **changes["moe"]}.items())
     if "parts" in changes:
+        sizes = changes["parts"]
         changes["parts"] = tuple(
-            functools.partial(part, **changes["parts"].get(part.func, {}))
+            (part[0], functools.partial(part[1], **sizes.get(part[0], {})))
+            if isinstance(part, tuple)
+            else functools.partial(part, **sizes.get(part.func, {}))
             for part in base.ctor.keywords["parts"])
     CAUSAL_LMS[name] = base._replace(
         ctor=functools.partial(base.ctor, **changes))

@@ -768,6 +768,13 @@ TREES_ON_THE_PARENT = {
     "smallthinker_21b_a3b": "043c74b46a0845c7",
     "smallthinker_tiny": "3a1e005b8b2f40cf",
     "zaya1_8b": "0389cb0806ba9873", "zaya_tiny": "633344e5db1b353a",
+    # hashed on the parent of PR 53 (commit 9e1392c): sizes found by a
+    # layer's kind before its class, ``GroupedAttention``'s ``head_gate``,
+    # ``rotary_dim`` and ``yarn`` at their defaults, and the rotary turn's
+    # table and factor leave this model's two, and the fourteen above, as
+    # they were
+    "granite4_h_micro": "f64c8ad29890f3e9",
+    "granite4_h_tiny": "34756dc3ac75d858",
 }
 LOWERED_ON_THE_PARENT = {
     ("smallthinker_tiny", None, False): "5affa78410ae5c12",
@@ -776,6 +783,11 @@ LOWERED_ON_THE_PARENT = {
     ("olmoe_tiny", None, True): "3a30b8b4fd566d4e",
     ("qwen3_next_tiny", None, True): "6c5176c365f91e7b",
     ("moonlight_tiny", None, False): "af9422493615d9e6",
+    # hashed on the parent of PR 53 (commit 9e1392c), as the two trees above
+    ("granite4_h_tiny", None, False): "6e17611f8711f9a4",
+    ("granite4_h_tiny", None, True): "6ac2b8f90d60b00f",
+    ("smallthinker_tiny", None, True): "22073aa4eb832d8e",
+    ("moonlight_tiny", None, True): "5aed9823be060478",
 }
 
 
@@ -802,10 +814,11 @@ def test_another_presets_step_lowers_as_before_this_model(name, share, remat):
 
 
 def test_the_table_of_presets_gained_two():
+    """Every preset but the newest model's two is in the table above."""
     from lance_distributed_training_tpu.models.transformer import CAUSAL_LMS
 
     assert set(CAUSAL_LMS) == set(TREES_ON_THE_PARENT) | {
-        "granite4_h_micro", "granite4_h_tiny"}
+        "laguna_s_2_1", "laguna_tiny"}
 
 
 # -- the configuration's file against the program ----------------------------

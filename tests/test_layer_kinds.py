@@ -5,7 +5,7 @@ its mixers say, and both the step's gauges and the first log line are made
 from that one answer.
 
 *Does every preset answer with the kernels its layers have, and does the
-first log line say them?* The sixteen presets and the four spans the
+first log line say them?* The eighteen presets and the five spans the
 benchmark's cells hold. *Does a kind nobody wrote into the task or the
 trainer train?* A toy mixer with sizes, a kernel and a sown gauge of its
 own, patched into the two tables here, through ``get_task`` and
@@ -44,6 +44,13 @@ PLAIN = {"attention": "dense", "scan": "chunked", "delta": "chunked",
 # a kernel a mixer names and no rule can choose: Mamba-2's gated norm has the
 # gate inside its statistic and the plain lines alone (ops/norm.py)
 PLAIN_ONLY = {"granite4_h_micro": {"norm"}, "granite4_h_tiny": {"norm"}}
+# what the first line says beside the kernels' forms: the YaRN rule of a
+# stack whose full layers turn under one (trainer._kernel_paths)
+ALSO_SAID = {
+    "laguna_s_2_1": {"yarn": "factor 128 over 8192 positions, beta 32/1, "
+                             "cos and sin x 1.4852"},
+    "laguna_tiny": {"yarn": "factor 8 over 64 positions, beta 4/1, "
+                            "cos and sin x 1.2079"}}
 KERNELS = {
     ("gpt_base", None): ATTENTION, ("gpt_small", None): ATTENTION,
     ("olmoe_1b_7b", None): ATTENTION, ("olmoe_tiny", None): ATTENTION,
@@ -58,13 +65,15 @@ KERNELS = {
     ("smallthinker_tiny", None): ATTENTION,
     ("granite4_h_micro", None): GRANITE4,
     ("granite4_h_tiny", None): GRANITE4,
+    ("laguna_s_2_1", None): ATTENTION, ("laguna_tiny", None): ATTENTION,
     # the spans of the cells c4-phi4flash-vp8-prepacked-8k,
-    # c4-qwen3next-ep16-prepacked-8k, c4-smallthinker-ep4-prepacked-16k and
-    # c4-granite4h-vp8-prepacked-8k
+    # c4-qwen3next-ep16-prepacked-8k, c4-smallthinker-ep4-prepacked-16k,
+    # c4-granite4h-vp8-prepacked-8k and c4-laguna-ep32-prepacked-8k
     ("phi4_mini_flash", "14:20"): SAMBAY,
     ("qwen3_next_80b_a3b", "0:4"): QWEN3_NEXT,
     ("smallthinker_21b_a3b", "0:4"): ATTENTION,
     ("granite4_h_micro", "0:10"): GRANITE4,
+    ("laguna_s_2_1", "0:5"): ATTENTION,
     # a span of Mamba-2 layers alone has no attention to report
     ("granite4_h_micro", "6:10"): GRANITE4 - {"attention"},
 }
@@ -80,7 +89,8 @@ def test_a_preset_answers_with_its_kernels_and_the_first_line_says_them(
     assert set(task.kernels) == KERNELS[model, span]
     # here, on the CPU, every op's own rule says no
     assert trainer._kernel_paths(task, config) == {
-        name: PLAIN[name] for name in KERNELS[model, span]}
+        **{name: PLAIN[name] for name in KERNELS[model, span]},
+        **ALSO_SAID.get(model, {})}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for op, rule in ((flash, "fused_attention_applies"),
                      (scan, "scan_fused_applies"),
@@ -95,8 +105,8 @@ def test_a_preset_answers_with_its_kernels_and_the_first_line_says_them(
              for name in KERNELS[model, span]}
     assert task.kernels == fused
     assert trainer._kernel_paths(task, config) == {
-        name: "fused kernel" if on else PLAIN[name]
-        for name, on in fused.items()}
+        **{name: "fused kernel" if on else PLAIN[name]
+           for name, on in fused.items()}, **ALSO_SAID.get(model, {})}
 
 
 def test_the_table_holds_the_presets_listed_here():
