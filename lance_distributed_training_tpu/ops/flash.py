@@ -21,7 +21,10 @@ and keeps the weights for the backward pass. Two Pallas kernels avoid that:
   and grouped heads take this kernel whatever their widths: for 8 query
   heads over 2 key and value heads of 128 (compressed convolutional
   attention) it ran 1.28 times as fast as the blocked kernel on repeated
-  keys and values (PERF.md section 6, PR 38).
+  keys and values (PERF.md section 6, PR 38). It takes them in the heads
+  they have and finds a block's key head from its query head itself; a
+  call that still copies them for it (keys in another number of heads than
+  values) is counted in ``attention_kv_repeat_total``.
   Its forward, dkv and dq kernels each run the block sizes
   :func:`splash_tiling` has for the call's shapes: what the chip timed
   fastest for them, else the untuned square blocks of 512, counted in
@@ -342,7 +345,11 @@ def _square(block: int) -> SplashTiling:
 # What the v5e ran fastest, kernel by kernel, at the shapes the cells call
 # (scripts/splash_tiling_sweep.py; its tables are in PERF.md section 6), each
 # inside its cell's step too: a tiling that compiles alone can be refused
-# there (scoped VMEM), so an entry is one a step has run.
+# there (scoped VMEM), so an entry is one a step has run. The grouped shapes'
+# times below were read with keys and values repeated a head a query head
+# outside the kernels, on both sides of every comparison; with the kernels
+# taking them in their own heads Laguna's band was timed again (12.05 ms a
+# call for its entry, 12.08 for square 512s) and the others were not.
 _TIMED = {
     # Moonlight's latent attention
     _Shape(8192, 192, 128, 16, True, 0): SplashTiling(
@@ -378,8 +385,7 @@ _TIMED = {
     # 8,192 tokens. The window layers' 72 query heads in a band of 512: one
     # block wide, as Phi-4's, and square 512s stay but for dkv's scores 256
     # keys at a time: 13.91 ms a call where square 512s take 13.96 (every
-    # block of 128 or 256 is slower; the nine-fold repetition of keys and
-    # values is in both)
+    # block of 128 or 256 is slower)
     _Shape(8192, 128, 128, 72, True, 512): SplashTiling(
         (512, 512, 512), (512, 512, 256), (512, 512)),
     # the full layers' 48 query heads over the whole causal row: 27.77 ms a
@@ -417,9 +423,14 @@ def splash_tilings_built() -> list:
 
 @functools.lru_cache(maxsize=None)  # a few KB of block tables a mask: every
 # mask a process builds stays, so no program's kernel is built twice
-def _splash_kernel(shape: _Shape, tiling: Optional[SplashTiling] = None):
+def _splash_kernel(shape: _Shape, tiling: Optional[SplashTiling] = None,
+                   kv_heads: Optional[int] = None, repeat: int = 1):
     """The library's kernel object for one call's shapes, at the tiling
-    :func:`splash_tiling` has for them (a test may hand it one)."""
+    :func:`splash_tiling` has for them (a test may hand it one). The object
+    is a mask a query head and knows nothing of ``kv_heads``, the heads its
+    keys and values will come in (None: one a query head), nor of ``repeat``,
+    the most either was repeated outside it to get there: they are here for
+    the call's log line and its counter."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
@@ -435,9 +446,13 @@ def _splash_kernel(shape: _Shape, tiling: Optional[SplashTiling] = None):
     # model that reads non-zero here is running untuned
     default_registry().counter("attention_tiling_fallback_total").inc(
         source == "rule")
+    # counts calls that still copy keys and values for their groups in XLA
+    # (differential attention's: half as many value heads as key heads)
+    default_registry().counter("attention_kv_repeat_total").inc(repeat > 1)
     _built.append({
         "attention_tiling": " ".join(f"{k}={v}" for k, v in zip(
             shape._fields, shape)),
+        "kv_heads": kv_heads or shape.heads, "repeat": repeat,
         "fwd": "/".join(map(str, tiling.fwd)),
         "dkv": "/".join(map(str, tiling.dkv)),
         "dq": "/".join(map(str, tiling.dq)) if tiling.dq else "fused in dkv",
@@ -463,7 +478,10 @@ def _splash_kernel(shape: _Shape, tiling: Optional[SplashTiling] = None):
 
 def _expand_heads(t, heads: int):
     """Grouped heads: ``t`` ``[B, G, S, D]`` with each of its ``G`` heads
-    repeated for the ``heads / G`` query heads that share it, in order."""
+    repeated for the ``heads / G`` query heads that share it, in order: a
+    copy in HBM, and a sum over the copies on the way back. Dense attention
+    takes its keys and values so, and the splash kernels where keys and
+    values come in different numbers of heads."""
     return t if t.shape[1] == heads else jnp.repeat(
         t, heads // t.shape[1], axis=1)
 
@@ -478,6 +496,16 @@ def unequal_attention(q, k, v, segment_ids=None, *, causal: bool = False,
     ``Hv`` divide ``H``: a key or value head serves the query heads of its
     group), ``segment_ids`` ``[B, S]`` int32 or None, output ``[B, H, S,
     Dv]``; scores over ``sqrt(Dqk)``.
+    Where ``Hk == Hv`` the kernels take keys and values in those heads: a
+    block's index maps find its key head from its query head (``h // (H /
+    G)``, ``jnp.repeat``'s order), and the dkv kernel sums a group's dK and
+    dV in its f32 scratch and writes them once, after the group's last
+    query head. Their one demand is as many key heads as value heads, so
+    differential attention's 20 and 10 under 40 query heads are both
+    repeated to a head a query head, as all were before PR 54, and the call
+    is counted in ``attention_kv_repeat_total`` (values repeated twice for
+    the 20 key heads made the kernels' scopes 1.5 ms a step shorter and the
+    step 0.1 ms longer: PERF.md section 6, PR 54).
     ``window`` > 0: causal, and a query sees the ``window`` keys up to its
     own. The kernels' block sizes come from the shapes
     (:func:`splash_tiling`); ``tiling`` is for a test or a timing that wants
@@ -488,9 +516,11 @@ def unequal_attention(q, k, v, segment_ids=None, *, causal: bool = False,
     )
 
     _, heads, seq, d = q.shape
+    kv_heads = k.shape[1] if k.shape[1] == v.shape[1] else heads
     kernel = _splash_kernel(
-        _Shape(seq, d, v.shape[3], heads, causal, window), tiling)
-    k, v = _expand_heads(k, heads), _expand_heads(v, heads)
+        _Shape(seq, d, v.shape[3], heads, causal, window), tiling, kv_heads,
+        kv_heads // min(k.shape[1], v.shape[1]))
+    k, v = _expand_heads(k, kv_heads), _expand_heads(v, kv_heads)
     # the kernel has no scale of its own
     q = (q.astype(jnp.float32) * (1.0 / float(d) ** 0.5)).astype(q.dtype)
     ids = None if segment_ids is None else segment_ids.astype(jnp.int32)

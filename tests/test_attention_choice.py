@@ -295,37 +295,49 @@ def test_unequal_attention_compiles_for_a_v5e_at_phi4_flashs_widths(
         shape._replace(window=512 - window))
 
 
-@pytest.mark.parametrize("heads,groups,width,scale,is_timed", [
-    (8, 2, 128, 0.0, True),  # c4-zaya1-ep2-prepacked-8k's
+@pytest.mark.parametrize("heads,groups,width,scale,is_timed,window", [
+    (8, 2, 128, 0.0, True, 0),  # c4-zaya1-ep2-prepacked-8k's
     # c4-granite4h-vp8-prepacked-8k's, scores / 64; no sweep has timed it
-    (32, 8, 64, 0.015625, False),
-], ids=["zaya1", "granite4h"])
+    (32, 8, 64, 0.015625, False, 0),
+    # c4-laguna-ep32-prepacked-8k's: the window layers' nine query heads a
+    # key head in a band of 512, the full layers' six over the causal row
+    (72, 8, 128, 0.0, True, 512),
+    (48, 8, 128, 0.0, True, 0),
+], ids=["zaya1", "granite4h", "laguna_window", "laguna_full"])
 def test_unequal_attention_compiles_for_a_v5e_at_grouped_heads_of_equal_widths(
-        one_chip, heads, groups, width, scale, is_timed):
+        one_chip, heads, groups, width, scale, is_timed, window):
     """Heads in groups whose values are as wide as their keys, 8,192 tokens,
     causal, at the tiling timed for the shape: compressed convolutional
     attention's 8 query heads over 2 key and value heads of 128 as the ZAYA1
-    cell runs them, and grouped attention's 32 over 8 of 64 as the Granite
+    cell runs them, grouped attention's 32 over 8 of 64 as the Granite
     cell does (at the rule's square 512s: attention is 3.4% of that cell's
     work and no sweep has timed its shape), its score scale folded into the
     queries as ``GroupedAttention`` folds it (a power of two: no second
-    rounding). The three kernels, and no ``[B, H, S, S]`` tensor."""
+    rounding), and Laguna's 72 over 8 of 128 in a band of 512 and 48 over 8
+    causal. The three kernels take the keys and values in their own heads
+    (Mosaic's checks on the index maps that find a block's key head and on
+    dkv's scratch kept over a group), no ``[B, H, S, S]`` tensor, and the
+    cotangents of keys and values come back in the heads they went in."""
     def spec(heads):
         return jax.ShapeDtypeStruct((1, heads, 8192, width), jnp.bfloat16,
                                     sharding=one_chip)
 
-    tiling, timed = flash.splash_tiling(8192, width, width, heads, True)
+    tiling, timed = flash.splash_tiling(8192, width, width, heads, True,
+                                        window)
     assert timed is is_timed and tiling.dq is not None
 
     def loss(q, k, v):
         if scale:
             q = q * (scale * width ** 0.5)
-        out = flash.unequal_attention(q, k, v, causal=True)
+        out = flash.unequal_attention(q, k, v, causal=True, window=window)
         assert out.shape == (1, heads, 8192, width)
         return out.astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        spec(heads), spec(groups), spec(groups)).compile()
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec(heads), spec(groups), spec(groups))
+    _, (_, dk, dv) = lowered.out_info
+    assert dk.shape == dv.shape == (1, groups, 8192, width)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
     assert "8192,8192]" not in text
@@ -737,18 +749,23 @@ def test_a_shapes_kernel_is_built_once_and_a_window_has_its_own(shape):
     assert flash.splash_tilings_built() == []
 
 
-def _windowed_grouped_heads_equal_dense(window, tiling):
-    """4 query heads of 64 over 2 key heads and 1 value of 128, 512 tokens,
-    segment ids: forward and gradients against dense attention under the
-    same mask."""
+def _windowed_grouped_heads_equal_dense(window, tiling, heads=(4, 2, 1),
+                                        widths=(64, 128)):
+    """4 query heads of 64 over 2 key heads and 1 value of 128 (differential
+    attention's form: the kernels get four heads of each, repeated outside
+    them), or other ``heads`` and ``widths``; 512 tokens,
+    segment ids: forward and all three gradients, each in the heads its
+    array came in, against dense attention under the same mask on keys and
+    values repeated a head a query head."""
     from jax.experimental.pallas import tpu as pltpu
 
     seq = 512
+    (h, hk, hv), (d_qk, d_v) = heads, widths
     keys = jax.random.split(jax.random.key(window), 4)
-    q = jax.random.normal(keys[0], (2, 4, seq, 64), jnp.float32)
-    k = jax.random.normal(keys[1], (2, 2, seq, 64), jnp.float32)
-    v = jax.random.normal(keys[2], (2, 1, seq, 128), jnp.float32)
-    w = jax.random.normal(keys[3], (2, 4, seq, 128), jnp.float32)
+    q = jax.random.normal(keys[0], (2, h, seq, d_qk), jnp.float32)
+    k = jax.random.normal(keys[1], (2, hk, seq, d_qk), jnp.float32)
+    v = jax.random.normal(keys[2], (2, hv, seq, d_v), jnp.float32)
+    w = jax.random.normal(keys[3], (2, h, seq, d_v), jnp.float32)
     seg = _segments(seq)
     live = (seg > 0)[:, None, :, None]  # dead queries mean nothing
     at = jnp.arange(seq)
@@ -758,8 +775,8 @@ def _windowed_grouped_heads_equal_dense(window, tiling):
 
     def dense(q, k, v):
         return jnp.where(live, dot_product_attention(
-            q, jnp.repeat(k, 2, 1), jnp.repeat(v, 4, 1), mask=mask,
-            dtype=jnp.float32, causal=True), 0)
+            q, jnp.repeat(k, h // hk, 1), jnp.repeat(v, h // hv, 1),
+            mask=mask, dtype=jnp.float32, causal=True), 0)
 
     def kernel(q, k, v):
         return jnp.where(live, flash.unequal_attention(
@@ -778,11 +795,19 @@ def _windowed_grouped_heads_equal_dense(window, tiling):
         np.testing.assert_allclose(g, wnt, atol=5e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("window", [0, 100, 128, 300])
+@pytest.mark.parametrize("window,heads,widths", [
+    *((window, (4, 2, 1), (64, 128)) for window in (0, 100, 128, 300)),
+    # keys and values in as many heads, four query heads to each: nothing
+    # is repeated, the kernels find a block's key head and sum its dK and dV
+    (0, (8, 2, 2), (128, 128)),
+    (128, (8, 2, 2), (128, 128)),
+    (100, (6, 2, 2), (64, 64)),  # Laguna's tiny preset: three to a head
+])
 def test_unequal_attention_in_a_window_over_grouped_heads_equals_dense(
-        window):
+        window, heads, widths):
     """In square blocks of 128 (a window of 100 leaves whole blocks out)."""
-    _windowed_grouped_heads_equal_dense(window, flash._square(128))
+    _windowed_grouped_heads_equal_dense(window, flash._square(128), heads,
+                                        widths)
 
 
 @pytest.mark.parametrize("window,tiling", [
@@ -847,6 +872,125 @@ def test_the_fused_backward_rounds_dq_where_two_kernels_do_not(heads, d_qk):
     np.testing.assert_allclose(error["fused"][1:], error["square"][1:],
                                rtol=0.01)
     assert error["fused"][0] > 1.02 * error["two kernels"][0], error
+
+
+def _kernel_operands(jaxpr) -> list:
+    """The operands' shapes of every ``pallas_call`` under ``jaxpr``, a list
+    a call."""
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append([tuple(var.aval.shape) for var in eqn.invars])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            calls += _kernel_operands(sub)
+    return calls
+
+
+@pytest.mark.parametrize("heads,window", [
+    ((72, 8, 8), 128), ((48, 8, 8), 0),  # Laguna's W and F layers
+    ((28, 4, 4), 128), ((28, 4, 4), 0),  # SmallThinker's W and N layers
+    # Phi-4-mini-flash's S and F*: 20 key heads and 10 value heads, and the
+    # kernels want as many of each, so both still go a head a query head
+    ((40, 20, 10), 128), ((40, 20, 10), 0),
+    ((16, 16, 16), 0),  # Moonlight's: nothing to repeat, before or now
+], ids=str)
+def test_the_kernels_take_keys_and_values_in_the_heads_they_have(heads,
+                                                                 window):
+    """The cells' head counts at 256 tokens, queries and keys of 64 and
+    values of 128 so that a shape names its array: each of the call's three
+    kernels (forward, dq, dkv) takes one ``[H, S, 64]`` operand, the
+    queries, and keys and values of ``Hk == Hv`` heads; ``[H, S, 128]`` is
+    the output's cotangent alone, backward, and nothing forward. No key or
+    value reaches a kernel a head a query head, so no such copy is made for
+    it and none is summed over on the way back."""
+    (h, hk, hv), seq = heads, 256
+    q, k, v = (jax.ShapeDtypeStruct((1, n, seq, d), jnp.bfloat16)
+               for n, d in ((h, 64), (hk, 64), (hv, 128)))
+    held = hk if hk == hv else h
+
+    def loss(q, k, v):
+        return flash.unequal_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32).sum()
+
+    traced = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        q, k, v)
+    assert [g.shape for g in traced.out_avals[1:]] == [
+        q.shape, k.shape, v.shape]
+    calls = _kernel_operands(traced.jaxpr)
+    assert len(calls) == 3
+    for n, shapes in enumerate(calls):
+        assert shapes.count((held, seq, 64)) == 1 + (held == h), shapes
+        assert shapes.count((held, seq, 128)) == 1 + (held == h and n > 0)
+        if held < h:
+            assert shapes.count((h, seq, 64)) == 1, shapes
+            assert shapes.count((h, seq, 128)) == (n > 0), shapes
+
+
+@pytest.mark.parametrize("heads,widths,window,on_the_parent", [
+    ((16, 16, 16), (192, 128), 0, "e0e3986c039c5b10"),  # Moonlight's
+    ((40, 20, 10), (64, 128), 512, "4d1287acfe1186ad"),  # Phi-4's S
+    ((40, 20, 10), (64, 128), 0, "28f04e047fb1b3db"),  # and its F* and X
+], ids=["moonlight", "phi4_window", "phi4_causal"])
+def test_a_call_that_groups_nothing_new_is_traced_as_before(
+        heads, widths, window, on_the_parent):
+    """Moonlight's 16 heads of 192 over 16 and 16 values of 128 (nothing was
+    repeated and nothing is) and Phi-4-mini-flash's 40 of 64 over 20 and 10
+    values of 128 (both repeated to 40, as before), at 8,192 tokens with
+    segment ids, forward and backward. The jaxpr's text (every operation
+    around the three kernels and inside them) hashed on the parent of PR 54
+    (commit 43a824d) with these lines; the lowered text differs by the line
+    numbers of this repo's frames inside Mosaic's serialized modules and by
+    nothing else, so it is the jaxpr that is held."""
+    import hashlib
+
+    def loss(q, k, v, ids):
+        return flash.unequal_attention(
+            q, k, v, ids, causal=True, window=window).astype(
+                jnp.float32).sum()
+
+    (h, hk, hv), (d_qk, d_v) = heads, widths
+    q, k, v = (jax.ShapeDtypeStruct((1, n, 8192, d), jnp.bfloat16)
+               for n, d in ((h, d_qk), (hk, d_qk), (hv, d_v)))
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        q, k, v, ids))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == on_the_parent
+
+
+@pytest.mark.parametrize("heads,kv_heads,repeat", [
+    ((6, 2, 2), 2, 1),  # grouped: the kernels' own index maps
+    ((6, 3, 1), 6, 6),  # values in fewer heads than keys: a head a query
+    ((6, 1, 3), 6, 6),  # head for both, or keys in fewer than values
+    ((6, 6, 6), 6, 1),  # a head a query head as they come
+], ids=str)
+def test_a_call_says_its_key_heads_and_counts_what_it_still_repeats(
+        heads, kv_heads, repeat):
+    """``attention_kv_repeat_total`` counts the traced calls that copy keys
+    or values outside the kernels, and the call's log line says into how
+    many heads they went and the most either was repeated: 1 in the grouped
+    cells, 4 in Phi-4-mini-flash's (20 key heads twice, 10 value heads four
+    times, to its 40 query heads). (A row of 384 tokens
+    that no other test of this file traces: the function is jitted, and a
+    call traced before builds nothing and says nothing.)"""
+    from lance_distributed_training_tpu.obs.registry import default_registry
+
+    repeats = default_registry().counter("attention_kv_repeat_total")
+    fallbacks = default_registry().counter("attention_tiling_fallback_total")
+    flash._splash_kernel.cache_clear()
+    flash.splash_tilings_built()
+    before = repeats.value, fallbacks.value
+    q, k, v = (jax.ShapeDtypeStruct((1, n, 384, 64), jnp.float32)
+               for n in heads)
+    out = jax.eval_shape(functools.partial(flash.unequal_attention,
+                                           causal=True), q, k, v)
+    assert out.shape == q.shape
+    assert repeats.value - before[0] == (repeat > 1)
+    assert fallbacks.value - before[1] == 1  # nobody timed 384 tokens
+    (line,) = flash.splash_tilings_built()
+    assert (line["kv_heads"], line["repeat"]) == (kv_heads, repeat)
+    assert line["attention_tiling"] == (
+        "seq=384 d_qk=64 d_v=64 heads=6 causal=True window=0")
+    assert list(line)[:3] == ["attention_tiling", "kv_heads", "repeat"]
 
 
 @pytest.mark.parametrize("shape,causal", [
